@@ -2,9 +2,10 @@
 
 Parity: reference ``accelerator/real_accelerator.py:39,57``
 (``get_accelerator``/``set_accelerator``).  Selection honours the
-``DSTPU_ACCELERATOR`` env var ("tpu" | "cpu"); default is TPU when a TPU
-backend is importable, else the CPU (XLA-on-host) accelerator — which is the
-same class pointed at CPU devices, since JAX abstracts both.
+``DSTPU_ACCELERATOR`` env var ("tpu" | "cpu"); default follows
+``jax.default_backend()`` — the CPU (XLA-on-host) accelerator is the same
+class pointed at CPU devices, since JAX abstracts both.  A backend that
+fails to initialise raises; it is never read as "cpu".
 """
 
 import os
@@ -26,12 +27,8 @@ def get_accelerator():
 
     accelerator_name = os.environ.get("DSTPU_ACCELERATOR", None)
     if accelerator_name is None:
-        try:
-            import jax
-            platform = jax.default_backend()
-        except Exception:
-            platform = "cpu"
-        accelerator_name = "cpu" if platform == "cpu" else "tpu"
+        import jax
+        accelerator_name = "cpu" if jax.default_backend() == "cpu" else "tpu"
 
     if accelerator_name == "cpu":
         from .cpu_accelerator import CPU_Accelerator

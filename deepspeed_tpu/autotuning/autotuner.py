@@ -229,17 +229,18 @@ class Autotuner:
         if model_spec is not None and not trial_cpu:
             # do NOT initialise the TPU backend in the parent: libtpu is
             # exclusive per process, and a parent holding the device would
-            # starve every trial subprocess.  Probe the count out of line.
+            # starve every trial subprocess.  Probe the count out of line;
+            # a probe that fails is an error, not "one device".
             import subprocess
             import sys
-            try:
-                out = subprocess.run(
-                    [sys.executable, "-c",
-                     "import jax; print(jax.device_count())"],
-                    capture_output=True, text=True, timeout=180)
-                dp = max(1, int(out.stdout.strip().splitlines()[-1]))
-            except Exception:
-                dp = 1
+            out = subprocess.run(
+                [sys.executable, "-c",
+                 "import jax; print(jax.device_count())"],
+                capture_output=True, text=True, timeout=180)
+            if out.returncode != 0:
+                raise RuntimeError("device-count probe failed: "
+                                   + out.stderr.strip()[-500:])
+            dp = int(out.stdout.strip().splitlines()[-1])
         else:
             dp = max(1, jax.device_count())
         # only the in-process default runner cannot apply model-knob

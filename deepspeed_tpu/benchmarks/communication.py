@@ -32,10 +32,7 @@ def build_collective_fn(coll, mesh, axis="world"):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n = mesh.shape[axis]
 

@@ -67,6 +67,7 @@ from deepspeed_tpu.inference.transport import (TransportError,
                                                payload_from_wire,
                                                recv_frame, send_frame,
                                                unpack_value)
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -359,6 +360,7 @@ def main(argv=None):
     parser.add_argument("--fd", type=int, required=True,
                         help="inherited socketpair fd from the router")
     args = parser.parse_args(argv)
+    enable_compile_cache()
     sock = socket.socket(fileno=args.fd)
     FleetWorker(sock).serve()
     return 0

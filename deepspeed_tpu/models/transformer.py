@@ -705,6 +705,7 @@ class CausalTransformerLM:
             attn = attention(q, k, v, causal=True,
                              softmax_scale=c.attn_scale, impl=c.attn_impl,
                              block_q=c.attn_block_q, block_k=c.attn_block_k,
+                             interpret=on_cpu and c.attn_impl == "pallas",
                              logit_softcap=c.attn_logit_softcap)
         else:
             raise ValueError(

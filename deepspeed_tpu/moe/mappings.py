@@ -17,9 +17,9 @@ analogue of the reference's ``mpu is None`` bail-out (``mappings.py:94``).
 from functools import partial
 
 import jax
+from jax.lax import axis_size
 
 from deepspeed_tpu.comm import comm
-from deepspeed_tpu.ops._shard_map import axis_size
 
 
 def _tp_bound() -> bool:

@@ -21,7 +21,7 @@ its own sink.  Here every subsystem writes into ONE process-local
   engine ``step()``; when the gap since the last beat exceeds a
   configurable multiple of the rolling-median step time it logs and emits
   a structured ``stall`` event.  This turns the silent-hang failure class
-  (ROUND5_NOTES: 88 consecutive probe timeouts with zero in-band evidence)
+  (a hung backend leaves zero in-band evidence)
   into an observable one.
 
 Every event is one JSON object per line with at minimum ``ts`` (unix

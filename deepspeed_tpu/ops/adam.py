@@ -57,14 +57,10 @@ def reference_impl(params, grads, state: AdamState, lr=1e-3, beta1=0.9,
 
 
 def fused_adam(params, grads, state, **kw):
-    """Dispatching entry: Pallas on TPU, jnp elsewhere."""
-    try:
-        import jax
-        if jax.default_backend() not in ("cpu",):
-            from deepspeed_tpu.ops.pallas.fused_adam import fused_adam_pallas
-            return fused_adam_pallas(params, grads, state, **kw)
-    except ImportError:
-        pass
+    """Dispatching entry: Pallas on TPU, jnp on the CPU."""
+    if jax.default_backend() != "cpu":
+        from deepspeed_tpu.ops.pallas.fused_adam import fused_adam_pallas
+        return fused_adam_pallas(params, grads, state, **kw)
     return reference_impl(params, grads, state, **kw)
 
 

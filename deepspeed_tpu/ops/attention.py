@@ -4,10 +4,10 @@ Parity role: reference ``csrc/transformer`` fused training attention
 (``ds_transformer_cuda.cpp``) and ``deepspeed/ops/sparse_attention`` — the
 compute-bound inner loop of the transformer.  TPU design: a Pallas
 flash-attention kernel (tiled online-softmax over VMEM blocks feeding the MXU)
-with a jnp reference implementation that is also the CPU/CI fallback and the
-test oracle.
+with a jnp reference implementation that is the CPU path and the test
+oracle.
 
-``attention()`` is the public entry: picks Pallas on TPU, jnp elsewhere.
+``attention()`` is the public entry: picks Pallas on TPU, jnp on the CPU.
 """
 
 import functools
@@ -16,6 +16,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from deepspeed_tpu.parallel.topology import BATCH_AXES, TP_AXIS
 
 
 def reference_attention(q, k, v, causal=True, bias=None, segment_ids=None,
@@ -54,8 +57,8 @@ def reference_attention(q, k, v, causal=True, bias=None, segment_ids=None,
     return out.astype(orig_dtype)
 
 
-# jnp reference doubles as the fallback; the Pallas kernel lives in
-# ops/pallas/flash_attention.py and is substituted when running on TPU.
+# the Pallas kernel lives in ops/pallas/flash_attention.py and is
+# substituted when running on TPU.
 reference_impl = reference_attention
 
 
@@ -87,38 +90,57 @@ def attention(q, k, v, causal=True, softmax_scale=None, impl="auto",
               block_q=None, block_k=None, alibi_slopes=None, window=None,
               interpret=False, logit_softcap=None):
     """Dispatching attention entry point — the ONE place the
-    pallas-vs-reference policy (and its loud fallback) lives.
+    pallas-vs-reference policy lives.
+
+    Where the flash kernel cannot serve the call (a shape that does not
+    tile or does not divide over the mesh, ``logit_softcap``),
+    ``impl="pallas"`` raises and ``impl="auto"`` — which takes the kernel
+    everywhere but on the CPU — takes the reference and logs that once per
+    reason through :func:`_warn_fallback`, never silently.  A kernel that
+    fails to trace raises under every ``impl``.
 
     ``block_q``/``block_k`` tune the Pallas flash tiles (None = kernel
-    defaults).  They MUST be static (they pick the Pallas grid) — a traced
-    value here would poison the `or` below with a
-    TracerBoolConversionError that the fallback except would silently turn
-    into the jnp path.  ``alibi_slopes`` ([H]) and ``window`` (traced
-    scalar, 0/None = unlimited) ride the flash kernel's in-kernel bias on
-    the Pallas path and a materialized :func:`alibi_window_bias` on the
-    reference path.  ``interpret`` (static) runs the kernel in the Pallas
-    interpreter (CPU CI)."""
-    use_pallas = False
-    if impl == "pallas":
-        use_pallas = True
-    elif impl == "auto":
-        use_pallas = jax.default_backend() not in ("cpu",)
+    defaults) and MUST be static (they pick the Pallas grid).
+    ``alibi_slopes`` ([H]) and ``window`` (traced scalar, 0/None =
+    unlimited) ride the flash kernel's in-kernel bias on the Pallas path
+    and a materialized :func:`alibi_window_bias` on the reference path.
+    ``interpret`` (static) runs the kernel in the Pallas interpreter (CPU
+    CI)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention, flash_tiles)
+    block_q = block_q or DEFAULT_BLOCK_Q
+    block_k = block_k or DEFAULT_BLOCK_K
+    mesh = _mesh_to_shard_over()
+    batch_ways = head_ways = 1
+    if mesh is not None:
+        batch_ways = math.prod(mesh.shape.get(a, 1) for a in BATCH_AXES)
+        head_ways = mesh.shape.get(TP_AXIS, 1)
+    why_not = None
     if logit_softcap:
         # tanh capping lives inside the softmax loop; the flash kernel
         # does not implement it yet — XLA fuses the jnp path fine
-        use_pallas = False
+        why_not = "logit_softcap is not implemented in the kernel"
+    elif not flash_tiles(q.shape[1], q.shape[2], k.shape[2],
+                         block_q, block_k):
+        why_not = (f"q{q.shape} k{k.shape} does not tile "
+                   f"block_q={block_q} block_k={block_k}")
+    elif q.shape[0] % batch_ways or k.shape[2] % head_ways:
+        why_not = (f"q{q.shape} k{k.shape} does not divide over the mesh "
+                   f"({batch_ways} batch x {head_ways} head shards)")
+    if impl == "pallas" and why_not:
+        raise ValueError(f"impl='pallas': {why_not}")
+    use_pallas = impl == "pallas"
+    if impl == "auto" and jax.default_backend() != "cpu":
+        use_pallas = why_not is None
+        if why_not:
+            _warn_fallback(why_not)
     if use_pallas:
-        try:
-            from deepspeed_tpu.ops.pallas.flash_attention import (
-                DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention)
-            return flash_attention(q, k, v, causal=causal,
-                                   softmax_scale=softmax_scale,
-                                   block_q=block_q or DEFAULT_BLOCK_Q,
-                                   block_k=block_k or DEFAULT_BLOCK_K,
-                                   alibi_slopes=alibi_slopes, window=window,
-                                   interpret=interpret)
-        except Exception as e:                      # pragma: no cover
-            _warn_fallback(f"{type(e).__name__}: {e}")
+        flash = functools.partial(
+            flash_attention, causal=causal, softmax_scale=softmax_scale,
+            block_q=block_q, block_k=block_k, interpret=interpret)
+        if mesh is None:
+            return flash(q, k, v, alibi_slopes=alibi_slopes, window=window)
+        return _flash_per_shard(flash, mesh, q, k, v, alibi_slopes, window)
     bias = None
     if alibi_slopes is not None or window is not None:
         bias = alibi_window_bias(q.shape[1], k.shape[1],
@@ -128,10 +150,43 @@ def attention(q, k, v, causal=True, softmax_scale=None, impl="auto",
                                logit_softcap=logit_softcap)
 
 
+def _mesh_to_shard_over():
+    """The ambient ``with mesh:`` mesh when XLA would have to partition
+    the call over it; None on one device and inside a ``shard_map`` body
+    (ring / ulysses / pipeline), where the arrays are already per shard."""
+    from deepspeed_tpu.runtime.zero.stage_plan import active_mesh
+    mesh = active_mesh()
+    if mesh is None or mesh.size == 1 or \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return mesh
+
+
+def _flash_per_shard(flash, mesh, q, k, v, alibi_slopes, window):
+    """XLA cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so on a mesh the kernel runs per shard
+    under ``shard_map``: batch over the data axes, heads over tp — the
+    layout the model's activations already have.  The sequence dim stays
+    whole (sequence parallelism is ring/ulysses' job)."""
+    batch_axes = tuple(a for a in BATCH_AXES if a in mesh.axis_names)
+    head_axis = TP_AXIS if TP_AXIS in mesh.axis_names else None
+    qkv = P(batch_axes or None, None, head_axis, None)
+    if alibi_slopes is not None:
+        alibi_slopes = jnp.asarray(alibi_slopes, jnp.float32)
+    if window is not None:
+        window = jnp.asarray(window)
+    # an absent (None) slopes/window is an empty pytree under its spec
+    return jax.shard_map(
+        lambda q, k, v, slopes, win: flash(q, k, v, alibi_slopes=slopes,
+                                           window=win),
+        mesh=mesh, in_specs=(qkv, qkv, qkv, P(head_axis), P()),
+        out_specs=qkv, check_vma=False)(q, k, v, alibi_slopes, window)
+
+
 @functools.lru_cache(maxsize=8)
 def _warn_fallback(reason: str):
     """A silent fallback once hid a tracer bug that disabled the flash
-    kernel entirely (-30% train throughput); never swallow quietly."""
+    kernel entirely (-30% train throughput); never choose quietly."""
     from deepspeed_tpu.utils.logging import logger
-    logger.warning(f"flash attention unavailable, using jnp reference "
-                   f"attention: {reason}")
+    logger.warning(f"impl='auto' chose jnp reference attention over the "
+                   f"flash kernel: {reason}")

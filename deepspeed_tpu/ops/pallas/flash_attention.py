@@ -17,8 +17,7 @@ Backward: custom VJP using the saved log-sum-exp, as two Pallas kernels —
 recomputing the probabilities tile-by-tile instead of materialising the
 [B,H,S,S] score matrix.  ``_flash_bwd`` (jnp einsums) is the test oracle
 only: non-tiling shapes never reach the custom VJP, because
-``flash_attention()`` routes them to ``reference_attention`` (whose
-autodiff handles their gradient) before the VJP is involved.
+``flash_attention()`` refuses them.
 """
 
 import functools
@@ -27,13 +26,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu is only importable on TPU-capable installs
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -138,20 +131,17 @@ def _fwd_impl(q_ref, k_ref, v_ref, slope_ref, window_ref, o_ref, lse_ref,
     lse_ref[0] = m + jnp.log(l_safe)
 
 
-def _scalar_specs(shape):
+def _scalar_specs():
     """Block specs for the per-(batch·head) bias scalars.
 
     The scalars ride as FULL ``[B*H, 1]`` arrays — a ``(1, 1)`` VMEM block of
     a ``[B*H, 1]`` array violates Mosaic's last-two-dims tiling rule (must
-    tile (8, 128) or equal the array dims).  On TPU they live in SMEM (the
-    scalar memory, where dynamic scalar reads are native); kernels index them
-    with ``pl.program_id(0)``.
+    tile (8, 128) or equal the array dims).  They live in SMEM (the scalar
+    memory, where dynamic scalar reads are native); kernels index them with
+    ``pl.program_id(0)``.
     """
-    if _HAS_PLTPU:
-        smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-        return [smem, smem]
-    full = pl.BlockSpec(shape, lambda *_: (0,) * len(shape))
-    return [full, full]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return [smem, smem]
 
 
 def _bias_inputs(alibi_slopes, window, B, H):
@@ -197,7 +187,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret=False,
             block_k=block_k, seq_len=S,
             use_slope=alibi_slopes is not None,
             use_window=window is not None)
-        in_specs += _scalar_specs(slopes_bh.shape)
+        in_specs += _scalar_specs()
         args += [slopes_bh, w_bh]
 
     out, lse = pl.pallas_call(
@@ -371,7 +361,7 @@ def _flash_bwd_pallas(scale, causal, res, g, block_q, block_k,
 
     slopes_bh, w_bh = _bias_inputs(alibi_slopes, window, B, H)
     scalar_specs = ([] if slopes_bh is None
-                    else _scalar_specs(slopes_bh.shape))
+                    else _scalar_specs())
     scalar_args = [] if slopes_bh is None else [slopes_bh, w_bh]
 
     kv_spec = pl.BlockSpec((1, S, D), lambda bh, i, g=group: (bh // g, 0, 0))
@@ -507,12 +497,22 @@ def _flash_attention_bwd(scale, causal, block_q, block_k, interpret,
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
+def flash_tiles(seq_len, n_heads, n_kv_heads, block_q=DEFAULT_BLOCK_Q,
+                block_k=DEFAULT_BLOCK_K):
+    """Whether the kernel's grid covers this shape exactly."""
+    return (seq_len % min(block_q, seq_len) == 0
+            and seq_len % min(block_k, seq_len) == 0
+            and n_heads % n_kv_heads == 0)
+
+
 def flash_attention(q, k, v, causal=True, softmax_scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                     interpret=False, alibi_slopes=None, window=None):
-    """q: [B, S, H, D]; k/v: [B, S, Hkv, D].  Falls back to the jnp reference
-    when the shape doesn't tile (S not divisible by the block size).
-    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU CI).
+    """q: [B, S, H, D]; k/v: [B, S, Hkv, D].  Raises ``ValueError`` when the
+    shape doesn't tile (:func:`flash_tiles`) — choosing another
+    implementation is ``ops.attention.attention``'s decision, not the
+    kernel's.  ``interpret=True`` runs the kernel in the Pallas interpreter
+    (CPU CI).
 
     ``alibi_slopes`` ([H] fp32, treated as CONSTANT — stop_gradient; ALiBi
     slopes are a deterministic function of the head count, never learned)
@@ -523,14 +523,13 @@ def flash_attention(q, k, v, causal=True, softmax_scale=None,
     ``csrc/transformer/inference``)."""
     B, S, H, D = q.shape
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
+    if not flash_tiles(S, H, k.shape[2], block_q, block_k):
+        raise ValueError(
+            f"flash attention cannot tile q{q.shape} k{k.shape} with "
+            f"block_q={block_q} block_k={block_k}: the sequence must be a "
+            "multiple of both blocks and the kv heads must divide the heads")
     block_q = min(block_q, S)
     block_k = min(block_k, S)
-    if S % block_q or S % block_k or H % k.shape[2]:
-        from deepspeed_tpu.ops.attention import (alibi_window_bias,
-                                                 reference_attention)
-        bias = alibi_window_bias(S, S, slopes=alibi_slopes, window=window)
-        return reference_attention(q, k, v, causal=causal,
-                                   softmax_scale=softmax_scale, bias=bias)
     window_f = (None if window is None
                 else jnp.asarray(window, jnp.float32))
     if alibi_slopes is not None:

@@ -16,12 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _BLOCK_ROWS = 512        # 512x128 fp32 x 7 live buffers ≈ 1.8 MB VMEM

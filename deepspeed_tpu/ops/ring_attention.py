@@ -33,9 +33,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.ops._shard_map import axis_size, shard_map
 from deepspeed_tpu.parallel.topology import BATCH_AXES, SP_AXIS
 from deepspeed_tpu.runtime.zero.stage_plan import active_mesh
 
@@ -413,14 +413,16 @@ def ring_attention(q, k, v, causal=True, softmax_scale=None, mesh=None,
         n = mesh.shape[SP_AXIS]
         perm, inv = zigzag_perm(q.shape[1], n)
         qz, kz, vz = (x[:, perm] for x in (q, k, v))
-        body = shard_map(
+        body = jax.shard_map(
             lambda q, k, v: zigzag_ring_attention_local(
                 q, k, v, SP_AXIS, softmax_scale),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)
         return body(qz, kz, vz)[:, inv]
-    body = shard_map(
+    body = jax.shard_map(
         # positional call: custom_vjp nondiff_argnums are positional
         lambda q, k, v: ring_attention_local(q, k, v, SP_AXIS, causal,
                                              softmax_scale),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     return body(q, k, v)

@@ -490,11 +490,8 @@ class DeepSpeedConfig:
         self.mesh_config = self._parse_mesh(pd.get(C.MESH, {}))
 
         if world_size is None:
-            try:
-                import jax
-                world_size = jax.device_count()
-            except Exception:
-                world_size = 1
+            import jax
+            world_size = jax.device_count()
         self.world_size = world_size
 
         # effective data-parallel degree for the batch triangle (EP overlays

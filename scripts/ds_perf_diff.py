@@ -25,7 +25,7 @@ before any baseline has been seeded.
 
 ``--check`` additionally audits baseline FRESHNESS: when the newest
 on-chip train evidence (the latest ``bench == "train"`` ledger row, or
-``BENCH_onchip_latest.json`` next to the ledger) is older than the last
+``captured_unix`` in the file ``--onchip`` names) is older than the last
 ``--stale-runs`` cpu-only bench runs, it prints an explicit
 ``STALE-BASELINE`` warning — the cpu gate keeps ratcheting while the
 on-chip numbers it is meant to stand in for go quietly out of date.
@@ -218,8 +218,9 @@ def check_stale_baseline(rows, onchip_path, stale_runs):
     if len(recent) < stale_runs:
         return None
     if evidence_ts is None:
+        no_file = f", no {onchip_path}" if onchip_path else ""
         return (f"STALE-BASELINE: no on-chip train evidence at all (no "
-                f"train ledger rows, no {onchip_path}) behind the last "
+                f"train ledger rows{no_file}) behind the last "
                 f"{stale_runs} cpu bench run(s) — the cpu gate has "
                 f"nothing on-chip to stand in for; re-run the on-chip "
                 f"train bench (ROADMAP.md open follow-up: 'Re-measure "
@@ -253,8 +254,8 @@ def main(argv=None):
                          "train evidence is older than this many cpu "
                          "runs (default 3; --check only)")
     ap.add_argument("--onchip", default=None,
-                    help="on-chip evidence file (default "
-                         "BENCH_onchip_latest.json next to the ledger)")
+                    help="on-chip evidence file: JSON with captured_unix "
+                         "(default: the ledger's train rows only)")
     ap.add_argument("--json", action="store_true",
                     help="emit the full diff as JSON")
     args = ap.parse_args(argv)
@@ -273,10 +274,7 @@ def main(argv=None):
             print(p, file=sys.stderr)
         return 2
     if args.check:
-        onchip = args.onchip or os.path.join(
-            os.path.dirname(os.path.abspath(args.ledger)),
-            "BENCH_onchip_latest.json")
-        warn = check_stale_baseline(rows, onchip, args.stale_runs)
+        warn = check_stale_baseline(rows, args.onchip, args.stale_runs)
         if warn:
             print(warn)
     baseline_rows, current_rows, current = split_runs(rows)

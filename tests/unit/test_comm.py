@@ -15,13 +15,8 @@ from deepspeed_tpu.comm.backend import ReduceOp
 
 
 def _run(fn, x, mesh, in_spec, out_spec):
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map(fn, mesh=mesh, in_specs=(in_spec,),
-                           out_specs=out_spec, check_vma=False)
-    else:   # older jax: the experimental spelling (check_rep, not check_vma)
-        from jax.experimental.shard_map import shard_map
-        sm = shard_map(fn, mesh=mesh, in_specs=(in_spec,),
-                       out_specs=out_spec, check_rep=False)
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=(in_spec,),
+                       out_specs=out_spec, check_vma=False)
     return jax.jit(sm)(x)
 
 

@@ -41,14 +41,8 @@ HIDDEN = 16
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    try:
-        from jax import shard_map as sm
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ----------------------------------------------------------------------

@@ -212,10 +212,7 @@ def test_sparse_tensor_roundtrip_and_allreduce():
     np.testing.assert_allclose(np.asarray(st.to_dense()), dense)
 
     # allreduce over a 4-way dp mesh
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     devs = jax.devices()[:4]
     mesh = Mesh(np.array(devs), ("dp",))
     per_dev = np.zeros((4, 10, 4), np.float32)

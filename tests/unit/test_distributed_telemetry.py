@@ -414,7 +414,7 @@ def test_traced_verb_records_duration(tmp_path, mesh_1d):
         TelemetryConfig({"enabled": True, "output_path": str(tmp_path),
                          "job_name": "verb"}), rank=0)
     import deepspeed_tpu.comm as dist
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     x = jax.numpy.ones((8, 4), jax.numpy.float32)
     sm = shard_map(lambda v: dist.all_reduce(v, group="fsdp"),

@@ -109,7 +109,7 @@ def test_flash_bwd_pallas_gqa_group_reduce():
 
 
 def test_flash_bwd_long_sequence_vs_autodiff():
-    """S=4096 grad-vs-oracle (VERDICT round-1 done-criterion): the tiled
+    """S=4096 grad-vs-oracle (round-1 done-criterion): the tiled
     backward never materialises the [S, S] score matrix."""
     B, S, H, D = 1, 4096, 1, 16
     rng = jax.random.key(3)

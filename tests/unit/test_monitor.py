@@ -232,7 +232,7 @@ def test_engine_telemetry_smoke(tmp_path, mesh_1d):
     # collectives; the grad reduce lands via the trace-time census), so
     # drive one explicitly for the traced-verb path too
     import deepspeed_tpu.comm as dist
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     x = jax.numpy.ones((8, 4), jax.numpy.float32)
     sm = shard_map(lambda v: dist.all_reduce(v, group="fsdp"), mesh=mesh_1d,

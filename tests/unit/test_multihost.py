@@ -107,7 +107,7 @@ def test_two_process_zero_offload():
     """Multi-host ZeRO-Offload: each process hosts the fp32 master +
     moments for ONLY its addressable fsdp shards (ShardedFlatLayout),
     updates them with the C++ Adam, and reassembles the global device
-    params — VERDICT r1 item 10."""
+    params — round-1 review item 10."""
     extra = textwrap.dedent("""\
         from deepspeed_tpu.runtime.zero.offload import ShardedFlatLayout
         assert isinstance(engine._offload.layout, ShardedFlatLayout)

@@ -1,6 +1,6 @@
 """Analytic WORK metrics for the perf-motivated schedules.
 
-VERDICT r3 item 9: zig-zag ring and interleaved PP had correctness
+Round-3 review item 9: zig-zag ring and interleaved PP had correctness
 evidence (output equality) but nothing asserting the *work* distribution
 they exist to improve.  These tests pin the analytic invariants:
 
