@@ -1,0 +1,337 @@
+"""Traffic kinds ``open_loop`` and ``closed_loop``: the program's server
+(``init_inference(...).create_serving_engine(...)``: ``add_request`` and
+``step``) driven from one thread, as its own host loop is.
+
+The benchmark keeps its own clock: a request is timed from when it was
+DUE, not from when it was admitted, and every output token is stamped when
+it reaches the host.  The program's step returns finished requests only, so
+two of its methods are watched (PERF.md §7 asks the tracing PR for public
+ones): ``_run_step`` (every device dispatch, prefill or decode) and
+``_sample`` (every token, with the logits row it was taken from).
+"""
+
+import time
+from dataclasses import dataclass, field
+
+import jax.numpy as jnp
+import numpy as np
+
+import deepspeed_tpu
+from deepspeed_tpu.inference.robustness import RequestRejected
+
+from chipbench import cells, device, sut, traffic, tracing
+
+# Engine (bf16 weights, bf16 activations, keys and values stored as bf16
+# pages, logits returned in float32) against the float32 reference on the
+# same weights: prefill, then CHECK_DECODE_TOKENS decode steps through the
+# paged cache, compared on logits rows as max |difference| over the largest
+# |reference logit| of the row set.  bf16 carries 8 bits (2**-8 = 0.4 % a
+# rounding); over 16 layers of residual adds the rows land 1-2 % off on the
+# chip (PERF.md §6).  4 % passes that and fails a cache that drops or
+# misplaces a page (errors of the order of the logits themselves) or 8-bit
+# weights.
+LOGIT_TOL = 0.04
+CHECK_PROMPTS = 3
+CHECK_DECODE_TOKENS = 24
+TRACE_SECONDS = 6.0
+
+
+class Probe:
+    """Watches the engine's dispatches and samples on the benchmark's
+    clock."""
+
+    def __init__(self, engine, clock):
+        self.engine, self.clock = engine, clock
+        self.token_times = {}       # req_id -> [host time of each token]
+        self.prefill_start = {}     # req_id -> host time its prefill began
+        self.keep_logits = {}       # req_id -> [float32 rows], if asked
+        self.dispatches = []        # every _run_step since reset()
+        self.new_tokens = self.new_prompt_tokens = 0
+        self._run_step, self._sample = engine._run_step, engine._sample
+        engine._run_step, engine._sample = self.run_step, self.sample
+
+    def close(self):
+        self.engine._run_step = self._run_step
+        self.engine._sample = self._sample
+
+    def run_step(self, ids, tables, lengths, phase="decode"):
+        batch, tokens = ids.shape
+        record = {"phase": phase, "t0": self.clock(), "tokens": tokens}
+        if phase != "prefill":    # a prefill's sizes come with its sample
+            record["contexts"] = self.engine.lengths[
+                self.engine.lengths > 0] + 1
+        self.dispatches.append(record)
+        with tracing.annotate("chipbench/" + phase):
+            return self._run_step(ids, tables, lengths, phase=phase)
+
+    def sample(self, req, row):
+        now = self.clock()
+        rid = req.req_id
+        times = self.token_times.setdefault(rid, [])
+        if not times:       # the prefill that just ran was this request's
+            last = self.dispatches[-1]
+            self.prefill_start[rid] = last["t0"]
+            last["real"] = min(len(req.prompt), last["tokens"])
+            last["context"] = len(req.prompt)
+            self.new_prompt_tokens += len(req.prompt)
+        times.append(now)
+        self.new_tokens += 1
+        if rid in self.keep_logits:
+            self.keep_logits[rid].append(np.array(row, np.float32))
+        return self._sample(req, row)
+
+
+def _check_against_reference(cell, engine, probe, params, seed):
+    """Prefill + decode through the paged cache against the reference's
+    full forward pass, on logits.  Returns the largest error."""
+    lengths = traffic.quantile_grid(cell.mix["prompt_tokens"], CHECK_PROMPTS)
+    vocab = cell.config["vocab_size"]
+    prompts = {}
+    done = {}
+    for i, n in enumerate(lengths):
+        rid = f"check-{i}"
+        prompts[rid] = traffic.rng_for(seed, 5, i).integers(
+            0, vocab, int(n), dtype=np.int32)
+        probe.keep_logits[rid] = []
+        engine.add_request(rid, prompts[rid],
+                           max_new_tokens=CHECK_DECODE_TOKENS)
+    while len(done) < len(prompts):
+        done.update(engine.step())
+    worst = 0.0
+    for rid, prompt in prompts.items():
+        rows = np.stack(probe.keep_logits.pop(rid))
+        ids = np.asarray(done[rid], np.int32)[None, :-1]
+        want = np.asarray(cell.reference.logits(
+            params, jnp.asarray(ids), cell.config, last=len(rows)))[0]
+        scale = max(1.0, float(np.max(np.abs(want))))
+        worst = max(worst, float(np.max(np.abs(rows - want))) / scale)
+    return worst
+
+
+def _warm_up(cell, engine, seed):
+    """One prefill of every padded length the engine's scheduler gives the
+    mix's prompts (the check's prompts have compiled some already), and
+    the decode shape."""
+    dist = cell.mix["prompt_tokens"]
+    longest = {}    # padded length -> the longest prompt that pads to it
+    for n in range(int(dist["min"]), int(dist["max"]) + 1):
+        longest[engine.scheduler.prefill_padded_len(n)] = n
+    pending = set()
+    for i, n in enumerate(longest.values()):
+        ids = traffic.rng_for(seed, 6, i).integers(
+            0, cell.config["vocab_size"], n, dtype=np.int32)
+        engine.add_request(f"warm-{i}", ids, max_new_tokens=2)
+        pending.add(f"warm-{i}")
+    while pending:
+        pending -= set(engine.step())
+
+
+@dataclass
+class Served:
+    """What the traffic loop saw, on the benchmark's clock."""
+    window: tuple = (None, None)
+    end: float = 0.0
+    compiles: int = 0
+    iterations: list = field(default_factory=list)
+    due: dict = field(default_factory=dict)         # req_id -> due time
+    cycle: dict = field(default_factory=dict)       # req_id -> stream cycle
+    submitted: dict = field(default_factory=dict)   # req_id -> submit time
+    refused: set = field(default_factory=set)
+    finished: set = field(default_factory=set)
+
+
+def _serve_traffic(mix, stream, engine, probe, seed, seconds, tracer,
+                   counter):
+    """Ramp (part of set-up), window, grace: one thread submits what is
+    due and steps the engine.  Open loop: requests are due on the stream's
+    schedule and the window is the schedule's cycle 1.  Closed loop: each
+    of ``clients`` callers sends its next request when the last came back,
+    and the window opens at the first loop boundary after the ramp."""
+    clock = probe.clock
+    open_loop = mix["kind"] == "open_loop"
+    ramp_s, grace_s = float(mix["ramp_s"]), float(mix["grace_s"])
+    temperature = mix.get("sampling", {}).get("temperature", 0.0)
+    out = Served()
+    outstanding = 0
+    start = clock()
+    next_request, next_due = next(stream), start
+    window_start = start + ramp_s if open_loop else None
+    compiles_before = None
+
+    def submit(request, due_at):
+        rid, cycle, ids, answer, _ = request
+        out.due[rid], out.cycle[rid] = due_at, cycle
+        out.submitted[rid] = clock()
+        try:
+            engine.add_request(rid, ids, max_new_tokens=answer,
+                               temperature=temperature,
+                               seed=int(seed) % (2 ** 31) + rid)
+        except RequestRejected:
+            out.refused.add(rid)
+
+    while True:
+        probe.new_tokens = probe.new_prompt_tokens = 0
+        first_dispatch = len(probe.dispatches)
+        it0 = clock()
+        with tracing.annotate("chipbench/submit"):
+            if open_loop:       # a request's gap is the wait AFTER it
+                while next_due <= clock():
+                    submit(next_request, next_due)
+                    next_due += next_request[4]
+                    next_request = next(stream)
+            else:
+                while outstanding < int(mix["clients"]):
+                    submit(next_request, clock())
+                    next_request = next(stream)
+                    outstanding += 1
+        now = clock()
+        if window_start is None and now - start >= ramp_s:
+            window_start = now
+        if window_start is not None and now >= window_start:
+            if compiles_before is None:
+                compiles_before = counter.compiles
+            tracer.tick()
+            if now >= window_start + seconds:
+                waiting = [r for r, c in out.cycle.items() if c == 1
+                           and r not in probe.token_times
+                           and r not in out.refused]
+                if not open_loop or not waiting or \
+                        now >= window_start + seconds + grace_s:
+                    break
+        if not engine.queue and not engine.n_active:
+            with tracing.annotate("chipbench/wait"):
+                time.sleep(max(0.0, next_due - clock()))
+            continue
+        s0 = clock()
+        with tracing.annotate("chipbench/step"):
+            done = engine.step()
+        it1 = clock()
+        out.finished.update(done)
+        outstanding -= len(done)
+        out.iterations.append({
+            "kind": "serve", "t0": it0, "t1": it1, "step_s": it1 - s0,
+            "tokens": probe.new_tokens + probe.new_prompt_tokens,
+            "generated": probe.new_tokens,
+            "dispatches": probe.dispatches[first_dispatch:],
+            "active": engine.n_active, "queued": len(engine.queue),
+            "traced": tracer.active})
+    tracer.stop()
+    out.end = clock()
+    out.window = (window_start, window_start + seconds)
+    out.compiles = counter.compiles - compiles_before
+    return out
+
+
+def _ms(values, q):
+    return float(np.percentile(values, q)) * 1000.0 if values else None
+
+
+def run(cell, seed, seconds, trace, started, devices, peaks):
+    cfg, mix = cell.config, cell.mix
+    clock = time.perf_counter
+    counter = device.CompileCounter()
+    model = sut.build_model(cell)
+    dtype = cfg["serve"]["dtype"]
+    params = sut.seeded_weights(model, seed, sut.DTYPES[dtype], devices)
+    n_params = sut.count_params(params)
+    inference = deepspeed_tpu.init_inference(model=model, params=params,
+                                             dtype=dtype)
+    engine = inference.create_serving_engine(
+        max_batch=int(mix["max_batch"]), **cfg["serve"]["engine"])
+    probe = Probe(engine, clock)
+
+    t0 = clock()
+    logit_error = _check_against_reference(cell, engine, probe, params, seed)
+    reference_s = clock() - t0
+    _warm_up(cell, engine, seed)
+    engine.pop_terminated()
+    compiles_warm = counter.compiles
+
+    open_loop = mix["kind"] == "open_loop"
+    tracer = tracing.Tracer(trace, TRACE_SECONDS)
+    served = _serve_traffic(
+        mix, traffic.RequestStream(mix, cfg["vocab_size"], seed, seconds),
+        engine, probe, seed, seconds, tracer, counter)
+    window_start, window_end = served.window
+    setup_s = window_start - started
+
+    # ---- what the window holds ----------------------------------------
+    steps = [it for it in served.iterations
+             if it["t0"] >= window_start and it["t1"] <= window_end]
+    terminated = engine.pop_terminated()
+    live = {r.req_id for r in engine.queue} | \
+        {r.req_id for r in engine.slots if r is not None}
+    lost = [r for r in served.submitted
+            if r not in served.finished and r not in live
+            and r not in terminated and r not in served.refused]
+    leaks = engine.leak_report()
+    probe.close()
+
+    due = served.due
+    in_window = [r for r, c in served.cycle.items() if c == 1] if open_loop \
+        else [r for r, t in served.submitted.items()
+              if window_start <= t < window_end]
+    failed = [r for r in in_window
+              if r in served.refused or r in terminated
+              or (open_loop and r not in probe.token_times)]
+    worst = served.end - min((due[r] for r in in_window),
+                             default=served.end)
+    ttft = [(probe.token_times[r][0] - due[r]) if r not in failed else worst
+            for r in in_window if r in failed or r in probe.token_times]
+    queue_wait = [probe.prefill_start[r] - due[r] for r in in_window
+                  if r in probe.prefill_start]
+    gaps = [b - a for times in probe.token_times.values()
+            for a, b in zip(times, times[1:])
+            if window_start <= b <= window_end]
+    lateness = [served.submitted[r] - due[r] for r in in_window]
+    busy = sum(s["t1"] - s["t0"] for s in steps)
+    tokens = sum(s["tokens"] for s in steps)
+
+    end_to_end = {"setup_s": setup_s}
+    if open_loop:
+        end_to_end["tpot_p90_ms"] = _ms(gaps, 90)
+        end_to_end["tpot_p95_ms"] = _ms(gaps, 95)
+    else:
+        end_to_end["serve_tok_s"] = tokens / busy if busy else None
+    correct = (logit_error <= LOGIT_TOL and not lost and not leaks
+               and not failed and bool(steps))
+
+    device.log(
+        iterations=len(steps), requests_in_window=len(in_window),
+        failed=len(failed), lost=lost, leaks=leaks,
+        logit_error=logit_error, logit_tol=LOGIT_TOL,
+        reference_s=round(reference_s, 2), n_params=n_params,
+        tokens_in_window=tokens,
+        generated_in_window=sum(s["generated"] for s in steps),
+        prefills_in_window=sum(
+            d["phase"] == "prefill" for s in steps for d in s["dispatches"]),
+        ttft_ms={**{q: _ms(ttft, q) for q in (50, 90, 99)},
+                 "mean": float(np.mean(ttft)) * 1000.0 if ttft else None},
+        tpot_ms={q: _ms(gaps, q) for q in (50, 90, 92.5, 95, 97.5, 99)}, token_gaps=len(gaps),
+        queue_wait_ms={q: _ms(queue_wait, q) for q in (50, 90)},
+        generator_late_ms={q: _ms(lateness, q) for q in (50, 100)},
+        active_and_queued=[(s["active"], s["queued"])
+                           for s in steps[::max(1, len(steps) // 16)]],
+        step_s=[round(s["t1"] - s["t0"], 4) for s in steps][:400],
+        step_tokens=[s["tokens"] for s in steps][:400],
+        compiles_in_window=served.compiles,
+        compiles_in_warm_up=compiles_warm,
+        cache_hits=counter.cache_hits, compiles_total=counter.compiles)
+
+    report = device.device_report(devices)
+    engine_cfg = cfg["serve"]["engine"]
+    run_record = cells.Run(
+        chips=len(devices), peaks=peaks,
+        model={"n_params": n_params, "n_layers": cfg["num_hidden_layers"],
+               "heads": cfg["num_attention_heads"],
+               "kv_heads": cfg["num_key_value_heads"],
+               "head_dim": cfg["hidden_size"] // cfg["num_attention_heads"],
+               "page_size": engine_cfg["page_size"], "kv_bytes": 2},
+        steps=steps, traced_steps=[s for s in steps if s["traced"]],
+        samples={"queue_wait_ms": [w * 1000.0 for w in queue_wait],
+                 "ttft_ms": [t * 1000.0 for t in ttft]},
+        counters={"compiles": served.compiles},
+        memory_peak_bytes=report["memory_peak_bytes"], trace=tracer.trace())
+    return {"correct": bool(correct), "attempted": len(in_window),
+            "failed": len(failed), "end_to_end": end_to_end,
+            "run": run_record, "device": report}

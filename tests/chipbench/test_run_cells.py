@@ -1,0 +1,106 @@
+"""The command end to end on the CPU at toy sizes, kernels interpreted:
+one run of each traffic kind through made-up cells that were added as
+files only (``tree.py``), and the ways it must refuse to run."""
+
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+import tree
+
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+@pytest.fixture(scope="module")
+def checkout(tmp_path_factory):
+    return tree.make(tmp_path_factory.mktemp("chipbench_tree"))
+
+
+def _check_line(line, metrics):
+    assert set(line) - {"breakdown"} == RESULT_KEYS
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    assert set(line["metrics"]) == set(metrics)
+    for value in line["metrics"].values():
+        assert set(value) == {"value", "unit"}
+    assert {"platform", "kind", "count", "memory_peak_bytes"} <= \
+        set(line["device"])
+
+
+@pytest.mark.parametrize("cell,metrics", [
+    ("tiny-train", {"train_tok_s_chip", "setup_s"}),
+    ("tiny-chat", {"tpot_p90_ms", "setup_s"}),
+    ("tiny-doc", {"serve_tok_s", "setup_s"}),
+])
+def test_one_run_of_each_traffic_kind(checkout, cell, metrics):
+    line, earlier = tree.run(checkout, cell, seed=2 ** 31 + 5)
+    _check_line(line, metrics)
+    assert all(v["value"] > 0 for v in line["metrics"].values())
+    log = earlier[-1]
+    assert log["compiles_in_window"] == 0
+    assert log["step_s"], "the per-step seconds are printed before the result"
+    if cell == "tiny-train":
+        assert log["loss_rel_error"] <= log["loss_rtol"]
+    else:
+        assert log["logit_error"] <= log["logit_tol"]
+        assert log["lost"] == [] and log["leaks"] == {}
+
+
+def test_traced_run_reads_the_made_up_metrics(checkout):
+    line, _ = tree.run(checkout, "tiny-train", trace=1)
+    assert {"busy_s", "window_s"} <= set(line["device"])
+    assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
+    metrics = line["metrics"]
+    # read through reducers the benchmark has, from new files ...
+    assert metrics["tiny-train.step_ms.train"]["value"] > 0
+    assert metrics["tiny-train.compiles.train"]["value"] == 0
+    # ... and through the reducer the made-up cell brought with it
+    assert metrics["tiny-train.window_steps"]["value"] == \
+        2 * line["attempted"]
+    # a reader that finds nothing (no device plane in a CPU trace) returns
+    # nothing and the metric is left out of the line
+    assert "tiny-train.flash_roofline.train" not in metrics
+    assert "step_ms.train" not in metrics       # another cell's metric
+
+
+def test_traced_serving_run_reads_samples(checkout):
+    line, earlier = tree.run(checkout, "tiny-chat", trace=1)
+    metrics = line["metrics"]
+    assert metrics["tiny-chat.step_ms.chat"]["value"] > 0
+    assert metrics["tiny-chat.ttft_p90_ms"]["value"] == pytest.approx(
+        earlier[-1]["ttft_ms"]["90"])
+
+
+def _command(cwd, workload, **env):
+    return subprocess.run(
+        [sys.executable, "chipbench/run.py", "--workload", workload,
+         "--seed", "1", "--seconds", "1", "--trace", "0"], cwd=cwd,
+        env=dict(os.environ, **env), capture_output=True, text=True,
+        timeout=240)
+
+
+def test_no_tpu_is_an_error_not_a_fallback(checkout):
+    done = _command(checkout, "tiny-train", JAX_PLATFORMS="cpu",
+                    PYTHONPATH=tree.REPO)
+    assert done.returncode != 0
+    assert "no accelerator" in done.stderr
+    assert '"correct"' not in done.stdout
+
+
+def test_benchmark_files_alone_do_not_run(tmp_path):
+    shutil.copytree(os.path.join(tree.REPO, "chipbench"),
+                    tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(tree.REPO, "BENCHMARK.json"), tmp_path)
+    done = _command(tmp_path, "train-pythia-1.4b-s2048",
+                    JAX_PLATFORMS="cpu", PYTHONPATH="")
+    assert done.returncode != 0 and '"correct"' not in done.stdout
+
+
+def test_unknown_workload_is_refused(checkout):
+    done = _command(checkout, "no-such-cell", JAX_PLATFORMS="cpu",
+                    PYTHONPATH=tree.REPO)
+    assert done.returncode != 0 and "no workload" in done.stderr

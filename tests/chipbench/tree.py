@@ -1,0 +1,129 @@
+"""A copy of the benchmark in a scratch directory with made-up cells added
+AS FILES ONLY: tiny configurations, tiny traffic mixes, a per-layer metric
+and a reducer of their own, plus entries in ``BENCHMARK.json``.  Nothing
+that the benchmark already has is edited, which is what a later PR that
+brings a cell has to be able to do."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(HERE))
+DATA = os.path.join(HERE, "data")
+
+CELLS = [   # name, configuration, traffic, a real cell whose metrics it takes
+    ("tiny-train", "tiny-neox", "tiny-pretrain", "train-pythia-1.4b-s2048"),
+    ("tiny-chat", "tiny-olmo2", "tiny-open", "serve-olmo2-1b-chat"),
+    ("tiny-doc", "tiny-olmo2", "tiny-closed", "serve-olmo2-1b-docbatch"),
+]
+
+MADE_UP_REDUCER = '''"""A reducer a later PR might bring: steps in the window."""
+
+
+def read(run, scale=1):
+    return len(run.steps) * scale
+'''
+
+
+def make(tmp):
+    tmp = str(tmp)
+    shutil.copytree(os.path.join(REPO, "chipbench"),
+                    os.path.join(tmp, "chipbench"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = _listing(tmp)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for config in ("tiny-neox", "tiny-olmo2"):
+        shutil.copy(os.path.join(DATA, config + ".json"),
+                    os.path.join(tmp, "chipbench", "configs"))
+        bench["configs"].append({
+            "name": config, "source": "made up for the tests",
+            "file": f"chipbench/configs/{config}.json", "reduced": [],
+            "why": "toy width"})
+    for mix in ("tiny-pretrain", "tiny-open", "tiny-closed"):
+        shutil.copy(os.path.join(DATA, mix + ".json"),
+                    os.path.join(tmp, "chipbench", "traffic"))
+    added = {}
+    for name, config, mix, like in CELLS:
+        bench["workloads"].append({"name": name, "config": config,
+                                   "traffic": mix, "chips": 1,
+                                   "why": "made up for the tests"})
+        for metric in bench["end_to_end"]:
+            if like in metric.get("workloads", ()):
+                metric["workloads"].append(name)
+        # a per-layer metric is a file: one of its own for the new cell,
+        # reading through a reducer the benchmark already has
+        for metric in list(bench["per_layer"]):
+            if like not in metric.get("workloads", ()):
+                continue
+            spec = _json(tmp, "chipbench", "layer_metrics",
+                         metric["name"] + ".json")
+            new = dict(spec, name=f"{name}.{metric['name']}",
+                       workloads=[name])
+            added[new["name"]] = new
+    # ... and one with a reducer of its own
+    added["tiny-train.window_steps"] = {
+        "name": "tiny-train.window_steps", "layer": "made up",
+        "unit": "count", "better": "higher", "source": "program_counter",
+        "moves": "train_tok_s_chip", "workloads": ["tiny-train"],
+        "reducer": "made_up_steps", "args": {"scale": 2}}
+    # ... and one that reads a list of samples no metric of the benchmark
+    # reads yet (time to first token left the end-to-end metrics, PERF.md)
+    added["tiny-chat.ttft_p90_ms"] = {
+        "name": "tiny-chat.ttft_p90_ms", "layer": "made up", "unit": "ms",
+        "better": "lower", "source": "host_clock", "moves": "tpot_p90_ms",
+        "workloads": ["tiny-chat"], "reducer": "sample_percentile",
+        "args": {"sample": "ttft_ms", "q": 90}}
+    with open(os.path.join(tmp, "chipbench", "reducers",
+                           "made_up_steps.py"), "w") as f:
+        f.write(MADE_UP_REDUCER)
+    for spec in added.values():
+        with open(os.path.join(tmp, "chipbench", "layer_metrics",
+                               spec["name"] + ".json"), "w") as f:
+            json.dump(spec, f)
+        bench["per_layer"].append({k: spec[k] for k in (
+            "name", "unit", "better", "source", "layer", "moves",
+            "workloads")})
+    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    after = _listing(tmp)
+    changed = [p for p in before if before[p] != after.get(p)]
+    assert not changed, f"adding cells edited existing files: {changed}"
+    return tmp
+
+
+def _json(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+def _listing(tmp):
+    out = {}
+    for folder, _, files in os.walk(os.path.join(tmp, "chipbench")):
+        for name in files:
+            path = os.path.join(folder, name)
+            with open(path, "rb") as f:
+                out[path] = f.read()
+    return out
+
+
+def run(tmp, workload, seed=1, seconds=2, trace=0, timeout=240):
+    """The command's ``main`` in a process of its own, allowed onto the
+    CPU by the tests' own switch (``require_tpu=False``): the command line
+    has no such option."""
+    argv = ["--workload", workload, "--seed", str(seed), "--seconds",
+            str(seconds), "--trace", str(trace)]
+    code = (f"import sys; sys.path.insert(0, {tmp!r}); "
+            f"from chipbench import run; "
+            f"run.main({argv!r}, require_tpu=False)")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+               XLA_FLAGS="")
+    done = subprocess.run([sys.executable, "-c", code], cwd=tmp, env=env,
+                          capture_output=True, text=True, timeout=timeout)
+    assert done.returncode == 0, done.stderr[-3000:]
+    lines = [line for line in done.stdout.splitlines() if line.strip()]
+    return json.loads(lines[-1]), [json.loads(line) for line in lines
+                                   if line.startswith("{")][:-1]
