@@ -296,25 +296,41 @@ class RequestTrace:
     t_terminal: float = -1.0
     n_generated: int = 0
     reason: str = ""            # typed reason for abnormal terminals
+    # when the request reached the front end, if it told us
+    # (``add_request(arrived_at=)``); queue wait and TTFT count from here
+    t_arrive: float = -1.0
+    # engine-clock time each output token reached the host, stamped where
+    # the engine's per-step report stamps it (``ServingEngine._emit``)
+    token_times: List[float] = field(default_factory=list)
+
+    @property
+    def _t_start(self) -> float:
+        return self.t_arrive if self.t_arrive >= 0 else self.t_admit
 
     def queue_wait_ms(self) -> Optional[float]:
         if self.t_prefill_start < 0:
             return None
-        return (self.t_prefill_start - self.t_admit) * 1000.0
+        return (self.t_prefill_start - self._t_start) * 1000.0
 
     def ttft_ms(self) -> Optional[float]:
         if self.t_first_token < 0:
             return None
-        return (self.t_first_token - self.t_admit) * 1000.0
+        return (self.t_first_token - self._t_start) * 1000.0
+
+    def tpot_gaps_ms(self) -> List[float]:
+        """The gaps between successive output tokens as they reached the
+        host: the distribution ``tpot`` is (tokens of one multi-token
+        dispatch arrive together, so all but one of their gaps are 0)."""
+        t = self.token_times
+        return [(b - a) * 1000.0 for a, b in zip(t, t[1:])]
 
     def tpot_ms(self) -> Optional[float]:
-        """Mean time per output token AFTER the first (the decode-rate
-        half of the TTFT/TPOT split)."""
-        if self.t_first_token < 0 or self.t_terminal < 0 or \
-                self.n_generated < 2:
+        """Mean gap between successive output tokens (the decode-rate
+        half of the TTFT/TPOT split); :meth:`tpot_gaps_ms` has them all."""
+        t = self.token_times
+        if len(t) < 2:
             return None
-        return (self.t_terminal - self.t_first_token) * 1000.0 / \
-            (self.n_generated - 1)
+        return (t[-1] - t[0]) * 1000.0 / (len(t) - 1)
 
     def e2e_ms(self) -> Optional[float]:
         if self.t_terminal < 0:
@@ -370,13 +386,16 @@ class RequestTracer:
         return req_id if self.epoch is None else f"{self.epoch}:{req_id}"
 
     def admit(self, req_id, deadline: float = 0.0,
-              now: Optional[float] = None) -> RequestTrace:
+              now: Optional[float] = None,
+              arrived_at: Optional[float] = None) -> RequestTrace:
         now = self._clock() if now is None else now
         key = self._key(req_id)
         if key in self.open:
             self.errors.append(f"double admit for {key!r}")
             return self.open[key]
         tr = RequestTrace(key, t_admit=now, deadline=float(deadline))
+        if arrived_at is not None:
+            tr.t_arrive = float(arrived_at)
         self.open[key] = tr
         self.admitted += 1
         return tr
@@ -399,6 +418,14 @@ class RequestTracer:
             return None
         tr.t_first_token = self._clock()
         return tr
+
+    def tokens(self, req_id, n: int = 1):
+        """``n`` output tokens of ``req_id`` just reached the host.  No
+        error on an unknown id: the engine's report is the authority, a
+        closed trace simply stops collecting."""
+        tr = self.open.get(self._key(req_id))
+        if tr is not None:
+            tr.token_times.extend([self._clock()] * n)
 
     def terminal(self, req_id, terminal: str, n_generated: int = 0,
                  reason: str = "") -> Optional[RequestTrace]:

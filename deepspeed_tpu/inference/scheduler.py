@@ -199,21 +199,42 @@ class SchedulerBase:
     def _decode_once(self, ready: List[int]) -> Dict[Any, List[int]]:
         """One token for every ready slot (the pre-scheduler per-token
         step body, masked to ``ready``)."""
+        eng = self.engine
+        tel = eng.telemetry
+        with tel.span("serve/decode",
+                      attrs={"batch": eng.max_batch, "ready": len(ready),
+                             "tokens": 1}):
+            with tel.span("serve/decode/build"):
+                last = np.zeros((eng.max_batch, 1), np.int32)
+                tables = np.zeros_like(eng.tables)
+                lengths = np.zeros_like(eng.lengths)
+                for slot in ready:
+                    req = eng.slots[slot]
+                    last[slot, 0] = req.last_token
+                    tables[slot] = eng.tables[slot]
+                    lengths[slot] = eng.lengths[slot]
+                args = (jnp.asarray(last), jnp.asarray(tables),
+                        jnp.asarray(lengths))
+            logits, eng.caches, _ = eng._run_step(*args)
+            self._decode_sizes(lengths, ready)
+            with tel.span("serve/decode/fetch"):
+                logits_np = np.asarray(logits[:, 0])
+            self.sched_stats["decode_steps"] += 1
+            with tel.span("serve/decode/sample"):
+                return self._sample_and_finish(ready, logits_np)
+
+    def _decode_sizes(self, lengths, ready):
+        """Into the report's newest dispatch: the context each ready slot
+        attends over, the new token included."""
+        self.engine._report["dispatches"][-1]["contexts"] = \
+            [int(lengths[s]) + 1 for s in ready]
+
+    def _sample_and_finish(self, ready, logits_np):
+        """The host half of a one-token decode step: append, sample the
+        next token of every ready slot, evict faulted slots, finish the
+        done ones (which may admit, and prefill, queued requests)."""
         from deepspeed_tpu.inference.robustness import EVICT_FAULT
         eng = self.engine
-        last = np.zeros((eng.max_batch, 1), np.int32)
-        tables = np.zeros_like(eng.tables)
-        lengths = np.zeros_like(eng.lengths)
-        for slot in ready:
-            req = eng.slots[slot]
-            last[slot, 0] = req.last_token
-            tables[slot] = eng.tables[slot]
-            lengths[slot] = eng.lengths[slot]
-        logits, eng.caches, _ = eng._run_step(
-            jnp.asarray(last), jnp.asarray(tables), jnp.asarray(lengths))
-        logits_np = np.asarray(logits[:, 0])
-        self.sched_stats["decode_steps"] += 1
-
         # finishing frees slots, which admits (and may prefill) queued
         # requests — defer that until after the loop so a mid-loop
         # admission is never mistaken for a slot this decode step served
@@ -323,43 +344,47 @@ class SchedulerBase:
                 self._build_chunk_fn(use_filters),
                 f"serve/decode_chunk:{int(use_filters)}")
         chunk_fn = self._chunk_fns[use_filters]
-        last = np.zeros(eng.max_batch, np.int32)
-        temps = np.zeros(eng.max_batch, np.float32)
-        seeds = np.zeros(eng.max_batch, np.uint32)
-        gen_counts = np.zeros(eng.max_batch, np.int32)
-        top_ks = np.zeros(eng.max_batch, np.int32)
-        top_ps = np.ones(eng.max_batch, np.float32)
-        tables = np.zeros_like(eng.tables)
-        lengths = np.zeros_like(eng.lengths)
-        for slot in ready:
-            req = eng.slots[slot]
-            last[slot] = req.last_token
-            temps[slot] = max(0.0, req.temperature)
-            seeds[slot] = np.uint32(req.seed)
-            gen_counts[slot] = len(req.out)
-            top_ks[slot] = req.top_k
-            top_ps[slot] = req.top_p
-            tables[slot] = eng.tables[slot]
-            lengths[slot] = eng.lengths[slot]
-        args = (eng.params, eng.caches, jnp.asarray(tables),
-                jnp.asarray(lengths), jnp.asarray(last),
-                jnp.asarray(temps), jnp.asarray(seeds),
-                jnp.asarray(gen_counts), jnp.asarray(top_ks),
-                jnp.asarray(top_ps))
-        with eng.telemetry.span("serve/step",
-                                attrs={"backend": eng.attention_backend,
-                                       "phase": "decode_chunk",
-                                       "batch": int(eng.max_batch),
-                                       "tokens": int(K)}), \
-                eng._prof_track("serve_step"):
-            if eng.mesh is not None:
-                with eng.mesh:
-                    toks, eng.caches = chunk_fn(*args)
-            else:
-                toks, eng.caches = chunk_fn(*args)
-        toks = np.asarray(toks)
-        self.sched_stats["decode_steps"] += 1
+        tel = eng.telemetry
+        with tel.span("serve/decode",
+                      attrs={"batch": eng.max_batch, "ready": len(ready),
+                             "tokens": K}):
+            with tel.span("serve/decode/build"):
+                last = np.zeros(eng.max_batch, np.int32)
+                temps = np.zeros(eng.max_batch, np.float32)
+                seeds = np.zeros(eng.max_batch, np.uint32)
+                gen_counts = np.zeros(eng.max_batch, np.int32)
+                top_ks = np.zeros(eng.max_batch, np.int32)
+                top_ps = np.ones(eng.max_batch, np.float32)
+                tables = np.zeros_like(eng.tables)
+                lengths = np.zeros_like(eng.lengths)
+                for slot in ready:
+                    req = eng.slots[slot]
+                    last[slot] = req.last_token
+                    temps[slot] = max(0.0, req.temperature)
+                    seeds[slot] = np.uint32(req.seed)
+                    gen_counts[slot] = len(req.out)
+                    top_ks[slot] = req.top_k
+                    top_ps[slot] = req.top_p
+                    tables[slot] = eng.tables[slot]
+                    lengths[slot] = eng.lengths[slot]
+                args = (eng.params, eng.caches, jnp.asarray(tables),
+                        jnp.asarray(lengths), jnp.asarray(last),
+                        jnp.asarray(temps), jnp.asarray(seeds),
+                        jnp.asarray(gen_counts), jnp.asarray(top_ks),
+                        jnp.asarray(top_ps))
+            toks, eng.caches = eng._dispatch(chunk_fn, args, "decode_chunk",
+                                             eng.max_batch, K)
+            self._decode_sizes(lengths, ready)
+            with tel.span("serve/decode/fetch"):
+                toks = np.asarray(toks)
+            self.sched_stats["decode_steps"] += 1
+            with tel.span("serve/decode/sample"):
+                return self._commit_chunk(ready, toks)
 
+    def _commit_chunk(self, ready, toks):
+        """The host half of a chunked decode step: append each ready
+        slot's tokens up to EOS or its budget, finish the done ones."""
+        eng = self.engine
         done_slots, done_now = [], {}
         for slot in ready:
             req = eng.slots[slot]
@@ -368,6 +393,7 @@ class SchedulerBase:
             # chunk's carry (per-token step() semantics, K times)
             seq = [req.last_token] + toks[slot, :-1].tolist()
             finished = False
+            before = len(req.out)
             for tok in seq:
                 req.out.append(int(tok))
                 eng.lengths[slot] += 1
@@ -376,6 +402,12 @@ class SchedulerBase:
                         len(req.out) >= req.max_new_tokens:
                     finished = True
                     break
+            # sampled on the device, on the host as of the fetch: the
+            # first appended token was emitted a chunk ago, the carry
+            # (when the request goes on) is new
+            new = len(req.out) - before - 1 + (not finished)
+            if new:
+                eng._emit(req.req_id, new)
             if finished:
                 done_slots.append(slot)
             else:
@@ -509,14 +541,10 @@ class ChunkedScheduler(SchedulerBase):
         return jax.jit(propose, donate_argnums=(1,))
 
     def _run_draft(self, ids, tables, lengths, phase):
-        eng = self.engine
-        with eng.telemetry.span("serve/step",
-                                attrs={"backend": "draft", "phase": phase,
-                                       "batch": int(ids.shape[0]),
-                                       "tokens": int(ids.shape[1])}), \
-                eng._prof_track("serve_step"):
-            out, self.draft_caches, _ = self._draft_step_fn(
-                self.draft_params, ids, self.draft_caches, tables, lengths)
+        out, self.draft_caches, _ = self.engine._dispatch(
+            self._draft_step_fn,
+            (self.draft_params, ids, self.draft_caches, tables, lengths),
+            phase, *ids.shape, backend="draft")
         return out
 
     # -- admission hooks -------------------------------------------------
@@ -581,35 +609,46 @@ class ChunkedScheduler(SchedulerBase):
         The final target chunk samples the first token and completes the
         admission sequence (trim + prefix insert)."""
         eng = self.engine
+        tel = eng.telemetry
         P = len(req.prompt)
         if req.prefilled < P:
             start = req.prefilled
             toks = req.prompt[start:start + self.chunk]
             n = len(toks)
-            ids = np.zeros((1, self.chunk), np.int32)
-            ids[0, :n] = toks
-            t0 = eng._clock()
-            logits, eng.caches, _ = eng._run_step(
-                jnp.asarray(ids), jnp.asarray(eng.tables[slot:slot + 1]),
-                jnp.full((1,), start, jnp.int32), phase="prefill")
-            # chunk-active wall time feeds the critical path's prefill
-            # stage; the wait BETWEEN chunks lands in the gap stage —
-            # the split that separates scheduler wins from kernel wins
-            eng.attrib.chunk(req.req_id, (eng._clock() - t0) * 1000.0)
-            req.prefilled = start + n
-            eng.lengths[slot] = req.prefilled
-            self.sched_stats["prefill_chunks"] += 1
-            eng._serve_event("serve/prefill_chunk", req_id=req.req_id,
-                             slot=slot, start=start, tokens=n,
-                             remaining=P - req.prefilled,
-                             slo_class=req.slo_class)
-            if req.prefilled >= P:
-                # the last prompt token's logits seed sampling — same
-                # contract as the monolithic prefill
-                req.last_token = eng._sample(
-                    req, np.asarray(logits[0, n - 1]))
-                eng._note_first_token(slot, req)
-                eng._complete_prefill(slot, req)
+            with tel.span("serve/prefill", req_id=req.req_id,
+                          attrs={"bucket": self.chunk, "real": n,
+                                 "cached": start}):
+                with tel.span("serve/prefill/build"):
+                    ids = np.zeros((1, self.chunk), np.int32)
+                    ids[0, :n] = toks
+                    args = (jnp.asarray(ids),
+                            jnp.asarray(eng.tables[slot:slot + 1]),
+                            jnp.full((1,), start, jnp.int32))
+                t0 = eng._clock()
+                logits, eng.caches, _ = eng._run_step(*args,
+                                                      phase="prefill")
+                eng._prefill_done(n, start + n)
+                # chunk-active wall time feeds the critical path's
+                # prefill stage; the wait BETWEEN chunks lands in the gap
+                # stage — the split that separates scheduler wins from
+                # kernel wins
+                eng.attrib.chunk(req.req_id, (eng._clock() - t0) * 1000.0)
+                req.prefilled = start + n
+                eng.lengths[slot] = req.prefilled
+                self.sched_stats["prefill_chunks"] += 1
+                eng._serve_event("serve/prefill_chunk", req_id=req.req_id,
+                                 slot=slot, start=start, tokens=n,
+                                 remaining=P - req.prefilled,
+                                 slo_class=req.slo_class)
+                if req.prefilled >= P:
+                    # the last prompt token's logits seed sampling — same
+                    # contract as the monolithic prefill
+                    with tel.span("serve/prefill/fetch"):
+                        row = np.asarray(logits[0, n - 1])
+                    with tel.span("serve/prefill/sample"):
+                        req.last_token = eng._sample(req, row)
+                        eng._note_first_token(slot, req)
+                        eng._complete_prefill(slot, req)
             return
         # target done -> catch the draft up on its own cache
         start = req.draft_filled
@@ -733,55 +772,75 @@ class ChunkedScheduler(SchedulerBase):
         window 0: position 0 of the ragged window is causally identical
         to a T=1 decode, so their host sampling (and its RNG stream) is
         untouched."""
+        eng = self.engine
+        tel = eng.telemetry
+        G = self.gamma
+        # one span tree like every decode step; this one has two
+        # dispatches (draft proposal, target verify), each with its fetch
+        with tel.span("serve/decode",
+                      attrs={"batch": eng.max_batch, "ready": len(ready),
+                             "tokens": 1 + G}):
+            with tel.span("serve/decode/build"):
+                win = np.zeros(eng.max_batch, np.int32)
+                specs = []
+                for s in ready:
+                    req = eng.slots[s]
+                    if s in self._spec_slots and req.temperature <= 0.0:
+                        w = min(G, req.max_new_tokens - len(req.out) - 1)
+                        if w > 0:
+                            win[s] = w
+                            specs.append(s)
+                props = np.zeros((eng.max_batch, G), np.int32)
+            if specs:
+                self._propose(specs, props)
+            ids = np.zeros((eng.max_batch, 1 + G), np.int32)
+            tables = np.zeros_like(eng.tables)
+            lengths = np.zeros_like(eng.lengths)
+            for s in ready:
+                ids[s, 0] = eng.slots[s].last_token
+                tables[s] = eng.tables[s]
+                lengths[s] = eng.lengths[s]
+            for s in specs:
+                ids[s, 1:1 + win[s]] = props[s, :win[s]]
+            logits, eng.caches, _ = eng._run_step(
+                jnp.asarray(ids), jnp.asarray(tables), jnp.asarray(lengths),
+                phase="spec_verify")
+            self._decode_sizes(lengths, ready)
+            with tel.span("serve/decode/fetch"):
+                logits_np = np.asarray(logits)
+            self.sched_stats["decode_steps"] += 1
+            with tel.span("serve/decode/sample"):
+                return self._accept_and_finish(ready, specs, win, props,
+                                               logits_np)
+
+    def _propose(self, specs, props):
+        """The draft model's ``gamma`` greedy proposals for the ``specs``
+        slots, into ``props``."""
+        eng = self.engine
+        G = self.gamma
+        dlast = np.zeros(eng.max_batch, np.int32)
+        dtables = np.zeros_like(self.draft_tables)
+        dlengths = np.zeros(eng.max_batch, np.int32)
+        for s in specs:
+            dlast[s] = eng.slots[s].last_token
+            dtables[s] = self.draft_tables[s]
+            dlengths[s] = self.draft_lengths[s]
+        toks, self.draft_caches = eng._dispatch(
+            self._propose_fn,
+            (self.draft_params, self.draft_caches, jnp.asarray(dtables),
+             jnp.asarray(dlengths), jnp.asarray(dlast)),
+            "spec_draft", eng.max_batch, G + 1, backend="draft")
+        with eng.telemetry.span("serve/decode/fetch"):
+            props[:, :] = np.asarray(toks)[:, :G]
+        eng._serve_event("serve/spec_draft", slots=len(specs), window=G)
+
+    def _accept_and_finish(self, ready, specs, win, props, logits_np):
+        """The host half of a speculative step: per-token semantics for
+        the slots that rode at window 0, longest-matching-prefix accept
+        for the speculating ones, then evictions and finishes."""
         from deepspeed_tpu.inference.robustness import EVICT_FAULT
         eng = self.engine
         G = self.gamma
-        win = np.zeros(eng.max_batch, np.int32)
-        specs = []
-        for s in ready:
-            req = eng.slots[s]
-            if s in self._spec_slots and req.temperature <= 0.0:
-                w = min(G, req.max_new_tokens - len(req.out) - 1)
-                if w > 0:
-                    win[s] = w
-                    specs.append(s)
-        props = np.zeros((eng.max_batch, G), np.int32)
-        if specs:
-            dlast = np.zeros(eng.max_batch, np.int32)
-            dtables = np.zeros_like(self.draft_tables)
-            dlengths = np.zeros(eng.max_batch, np.int32)
-            for s in specs:
-                dlast[s] = eng.slots[s].last_token
-                dtables[s] = self.draft_tables[s]
-                dlengths[s] = self.draft_lengths[s]
-            with eng.telemetry.span(
-                    "serve/step",
-                    attrs={"backend": "draft", "phase": "spec_draft",
-                           "batch": int(eng.max_batch),
-                           "tokens": int(G + 1)}), \
-                    eng._prof_track("serve_step"):
-                toks, self.draft_caches = self._propose_fn(
-                    self.draft_params, self.draft_caches,
-                    jnp.asarray(dtables), jnp.asarray(dlengths),
-                    jnp.asarray(dlast))
-            props[:, :] = np.asarray(toks)[:, :G]
-            eng._serve_event("serve/spec_draft", slots=len(specs),
-                             window=G)
-        ids = np.zeros((eng.max_batch, 1 + G), np.int32)
-        tables = np.zeros_like(eng.tables)
-        lengths = np.zeros_like(eng.lengths)
-        for s in ready:
-            ids[s, 0] = eng.slots[s].last_token
-            tables[s] = eng.tables[s]
-            lengths[s] = eng.lengths[s]
-        for s in specs:
-            ids[s, 1:1 + win[s]] = props[s, :win[s]]
-        logits, eng.caches, _ = eng._run_step(
-            jnp.asarray(ids), jnp.asarray(tables), jnp.asarray(lengths),
-            phase="spec_verify")
-        logits_np = np.asarray(logits)
-        self.sched_stats["decode_steps"] += 1
-
         done_slots, fault_slots = [], []
         done_now: Dict[Any, List[int]] = {}
         accepted_total = rejected_total = 0
@@ -822,6 +881,10 @@ class ChunkedScheduler(SchedulerBase):
             self.sched_stats["spec_proposed"] += w
             self.sched_stats["spec_accepted"] += m
             self.sched_stats["spec_rejected"] += w - m
+            # on the host as of the verify fetch: the m accepted tokens
+            # and, when the request goes on, the token after them
+            if m + (not finished):
+                eng._emit(req.req_id, m + (not finished))
             if finished:
                 done_slots.append(s)
             else:

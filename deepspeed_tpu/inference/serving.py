@@ -27,6 +27,7 @@ rest of the batch keeps serving; ``drain()`` quiesces the engine and
 request state, so recovered requests stay bit-identical.
 """
 
+import collections
 import contextlib
 import functools
 import math
@@ -48,7 +49,8 @@ from deepspeed_tpu.comm.quantize import CommQuantizer
 from deepspeed_tpu.inference.prefix_cache import PrefixCache, PrefixMatch
 from deepspeed_tpu.inference.scheduler import SLO_CLASSES, create_scheduler
 from deepspeed_tpu.monitor.attribution import RequestAttributor
-from deepspeed_tpu.monitor.telemetry import get_telemetry
+from deepspeed_tpu.monitor.telemetry import (get_telemetry,
+                                             register_compiled)
 from deepspeed_tpu.ops.decode_attention import use_pallas
 from deepspeed_tpu.ops.paged_attention import (PageAllocationError,
                                                PagedAllocator,
@@ -62,6 +64,11 @@ from deepspeed_tpu.utils.logging import logger
 # from the request's point of view a drain IS a shed, just engine-initiated.
 _TERMINAL_BY_STATUS = {"shed": "shed", "drained": "shed",
                        "deadline": "deadline", "evicted": "evict"}
+
+
+# per-step reports ``ServingEngine.step_reports()`` keeps: ten minutes of
+# 140 ms steps
+STEP_REPORTS_KEPT = 4096
 
 
 def _round_ms(v):
@@ -331,9 +338,20 @@ class ServingEngine:
         self._paged_call = functools.partial(
             self.model.apply_with_paged_cache,
             attn_backend=self.attention_impl, attn_interpret=attn_interpret)
-        # one jit serves prefill (B=1, bucketed T) and decode (B=max_batch,
-        # T=1) alike: jax.jit caches a compilation per input shape
-        self._step_fn = jax.jit(self._paged_call, donate_argnums=(2,))
+
+        # two named jits over the one call, so a device trace's
+        # ``XLA Modules`` line tells prefill (B=1, bucketed T:
+        # ``jit_serve_prefill``) from decode (B=max_batch, T=1, and the
+        # speculative verify window: ``jit_serve_decode``); each caches a
+        # compilation per input shape
+        def serve_prefill(params, ids, caches, tables, lengths):
+            return self._paged_call(params, ids, caches, tables, lengths)
+
+        def serve_decode(params, ids, caches, tables, lengths):
+            return self._paged_call(params, ids, caches, tables, lengths)
+
+        self._prefill_fn = jax.jit(serve_prefill, donate_argnums=(2,))
+        self._step_fn = jax.jit(serve_decode, donate_argnums=(2,))
         self._rng = {}
         # multi-token decode: one device program advances every slot
         # ``decode_chunk`` tokens (sampling included) per host round-trip,
@@ -349,6 +367,15 @@ class ServingEngine:
         # health()["recompile_storm"].  Telemetry must be bound first.
         self._storm_flagged = False
         self._step_fn = self._wrap_compiled(self._step_fn, "serve/step_fn")
+        self._prefill_fn = self._wrap_compiled(self._prefill_fn,
+                                               "serve/prefill_fn")
+        # the engine's own account of each step(): what it dispatched and
+        # which tokens reached the host when (docs/telemetry.md).  The
+        # open report collects from the moment the last step() returned,
+        # so a prefill that add_request ran inline is in the next one
+        self._report = self._new_report()
+        self.last_step = None
+        self._reports = collections.deque(maxlen=STEP_REPORTS_KEPT)
         self._admission = AdmissionController(self.serving)
         # per-request lifecycle traces on the SAME injectable clock as the
         # deadline machinery — always on (host dict ops), so the
@@ -405,9 +432,38 @@ class ServingEngine:
         return getattr(tel, "profiling", None) if tel is not None else None
 
     def _wrap_compiled(self, fn, site):
-        """Compile-tracing wrapper (no-op without the profiling plane)."""
+        """Register the jitted entry point under its site name
+        (``telemetry.op_scopes``), and compile-trace it with the
+        profiling plane on."""
+        fn = register_compiled(fn, site, mesh=self.mesh)
         prof = self._profiling
         return prof.wrap(fn, site) if prof is not None else fn
+
+    # -- the per-step report ---------------------------------------------
+    @staticmethod
+    def _new_report():
+        return {"t0_ns": None, "t1_ns": None, "dispatches": [],
+                "emitted": [], "prompt_tokens": 0, "active": 0, "queued": 0}
+
+    def _emit(self, req_id, n=1, t_ns=None):
+        """``n`` output tokens of ``req_id`` are on the host (as of
+        ``t_ns``, default now): stamped into the open report
+        (``perf_counter_ns``) and the request's lifecycle trace (the
+        engine clock)."""
+        self._report["emitted"].append(
+            (req_id, n, time.perf_counter_ns() if t_ns is None else t_ns))
+        self.tracer.tokens(req_id, n)
+
+    def step_reports(self):
+        """The last ``STEP_REPORTS_KEPT`` per-step reports, oldest first
+        (``last_step`` is the newest).  Each: ``t0_ns``/``t1_ns`` of the
+        ``step()`` call; ``dispatches`` (``phase``, ``batch``, ``tokens``,
+        ``t0_ns``, ``t1_ns``; a prefill adds ``real`` and ``context``, a
+        decode ``contexts``) and ``emitted`` (``(req_id, n_tokens,
+        t_host_ns)``, stamped when the tokens reached the host) since the
+        previous ``step()`` returned; ``prompt_tokens`` whose keys and
+        values became available; ``active`` and ``queued`` at the end."""
+        return list(self._reports)
 
     def _prof_track(self, span):
         """HBM attribution context for serve_step/prefill spans."""
@@ -490,7 +546,8 @@ class ServingEngine:
                     top_k: int = 0, top_p: float = 1.0,
                     deadline_s: Optional[float] = None,
                     slo_class: Optional[str] = None,
-                    prefill_only: bool = False):
+                    prefill_only: bool = False,
+                    arrived_at: Optional[float] = None):
         """Validate and enqueue one request.  Raises
         :class:`RequestRejected` (typed reason, engine state untouched)
         instead of asserting; ``deadline_s`` is a TTL from now — the
@@ -502,7 +559,10 @@ class ServingEngine:
         ``prefill_only`` (disaggregated fleets): validate and reserve
         exactly as a full request — same buckets, same feasibility — but
         capture a :class:`PrefillHandoff` at prefill completion instead
-        of decoding; collect with :meth:`pop_prefilled`."""
+        of decoding; collect with :meth:`pop_prefilled`.  ``arrived_at``
+        (engine-clock seconds): when the request reached the front end,
+        so its trace's queue wait and TTFT count from arrival, not from
+        this call."""
         cfg = self.serving
         if self.draining:
             self._reject(req_id, REJECT_DRAINING,
@@ -564,7 +624,8 @@ class ServingEngine:
         self.stats["admitted"] += 1
         # lifecycle trace opens HERE: admission is the promise leak_report
         # audits — exactly one serve/request/* terminal closes it
-        self.tracer.admit(req_id, deadline=deadline, now=now)
+        self.tracer.admit(req_id, deadline=deadline, now=now,
+                          arrived_at=arrived_at)
         self.attrib.admit(req_id, now=now)
         self._serve_event("serve/admit", req_id=req_id,
                           queue_depth=len(self.queue),
@@ -1058,19 +1119,32 @@ class ServingEngine:
         self.tables[slot, :len(pages)] = pages
 
     def _run_step(self, ids, tables, lengths, phase="decode"):
-        with self.telemetry.span("serve/step",
-                                 attrs={"backend": self.attention_backend,
-                                        "phase": phase,
-                                        "batch": int(ids.shape[0]),
-                                        "tokens": int(ids.shape[1])}), \
+        """One dispatch of the paged step: the launch only, nothing here
+        waits for the device."""
+        step_fn = self._prefill_fn if phase == "prefill" else self._step_fn
+        return self._dispatch(
+            step_fn, (self.params, ids, self.caches, tables, lengths),
+            phase, *ids.shape)
+
+    def _dispatch(self, fn, args, phase, batch, tokens, backend=None):
+        """Launch jitted ``fn(*args)`` as one ``serve/step`` span and one
+        entry of the open report's ``dispatches`` (the target model's
+        steps, the chunked decode scan, the draft model's)."""
+        t0_ns = time.perf_counter_ns()
+        with self.telemetry.span(
+                "serve/step",
+                attrs={"backend": backend or self.attention_backend,
+                       "phase": phase, "batch": int(batch),
+                       "tokens": int(tokens)}), \
                 self._prof_track("prefill" if phase == "prefill"
-                                 else "serve_step"):
-            if self.mesh is not None:
-                with self.mesh:
-                    return self._step_fn(self.params, ids, self.caches,
-                                         tables, lengths)
-            return self._step_fn(self.params, ids, self.caches, tables,
-                                 lengths)
+                                 else "serve_step"), \
+                (self.mesh if self.mesh is not None
+                 else contextlib.nullcontext()):
+            out = fn(*args)
+        self._report["dispatches"].append(
+            {"phase": phase, "batch": int(batch), "tokens": int(tokens),
+             "t0_ns": t0_ns, "t1_ns": time.perf_counter_ns()})
+        return out
 
     # -- prefix-cache plumbing ------------------------------------------
     def _on_prefix_evict(self, page: int):
@@ -1108,25 +1182,40 @@ class ServingEngine:
         capped at ``len(prompt) - 1`` upstream: the last prompt token
         always prefills, because its logits seed sampling."""
         suffix = req.prompt[cached:]
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :len(suffix)] = suffix
-        t0 = self._clock()
-        logits, self.caches, _ = self._run_step(
-            jnp.asarray(ids),
-            jnp.asarray(self.tables[slot:slot + 1]),
-            jnp.full((1,), cached, jnp.int32), phase="prefill")
-        # monolithic prefill is one dispatch: fold its active wall time
-        # into the critical path's prefill stage (chunked prefills land
-        # here per chunk via the scheduler)
-        self.attrib.chunk(req.req_id, (self._clock() - t0) * 1000.0)
-        self.lengths[slot] = len(req.prompt)
-        req.prefilled = len(req.prompt)
-        req.last_token = self._sample(
-            req, np.asarray(logits[0, len(suffix) - 1]))
-        # the first output token exists as of the sample above — a sampler
-        # fault raises before this line, so an evicted-at-prefill request
-        # correctly reports no TTFT
-        self._note_first_token(slot, req)
+        tel = self.telemetry
+        with tel.span("serve/prefill", req_id=req.req_id,
+                      attrs={"bucket": bucket, "real": len(suffix),
+                             "cached": cached}):
+            with tel.span("serve/prefill/build"):
+                ids = np.zeros((1, bucket), np.int32)
+                ids[0, :len(suffix)] = suffix
+                args = (jnp.asarray(ids),
+                        jnp.asarray(self.tables[slot:slot + 1]),
+                        jnp.full((1,), cached, jnp.int32))
+            t0 = self._clock()
+            logits, self.caches, _ = self._run_step(*args, phase="prefill")
+            self._prefill_done(len(suffix), len(req.prompt))
+            # monolithic prefill is one dispatch: fold its active wall
+            # time into the critical path's prefill stage (chunked
+            # prefills land here per chunk via the scheduler)
+            self.attrib.chunk(req.req_id, (self._clock() - t0) * 1000.0)
+            self.lengths[slot] = len(req.prompt)
+            req.prefilled = len(req.prompt)
+            with tel.span("serve/prefill/fetch"):
+                row = np.asarray(logits[0, len(suffix) - 1])
+            with tel.span("serve/prefill/sample"):
+                req.last_token = self._sample(req, row)
+                # the first output token exists as of the sample above —
+                # a sampler fault raises before this line, so an
+                # evicted-at-prefill request correctly reports no TTFT
+                self._note_first_token(slot, req)
+
+    def _prefill_done(self, real: int, context: int):
+        """Sizes of the prefill dispatch just launched, into the report:
+        ``real`` prompt tokens of its padded ``tokens``, and the
+        ``context`` its keys and values now reach."""
+        self._report["dispatches"][-1].update(real=real, context=context)
+        self._report["prompt_tokens"] += real
 
     def _note_first_token(self, slot: int, req: _Request):
         """TTFT bookkeeping shared by the monolithic prefill and the
@@ -1140,6 +1229,15 @@ class ServingEngine:
                               ttft_ms=_round_ms(tr.ttft_ms()))
 
     def _sample(self, req: _Request, logits: np.ndarray) -> int:
+        """Sample one token on the host from a fetched logits row.  The
+        token is emitted (``_emit``) once it exists, stamped with the time
+        its row was here: a sampler fault emits nothing."""
+        t_ns = time.perf_counter_ns()
+        token = self._sample_host(req, logits)
+        self._emit(req.req_id, 1, t_ns)
+        return token
+
+    def _sample_host(self, req: _Request, logits: np.ndarray) -> int:
         if self.injector is not None:
             self.injector.check("serve_sample")
         if req.temperature <= 0.0:
@@ -1227,27 +1325,44 @@ class ServingEngine:
         returns {} WITHOUT mutating any request (the retry serves
         identically), and raises only after ``serving.step_fault_limit``
         consecutive faults."""
-        self._expire_deadlines()
-        if self.injector is not None:
-            try:
-                self.injector.check("serve_step")
-            except Exception as e:
-                self._consec_step_faults += 1
-                self.stats["step_faults"] += 1
-                self._serve_event("serve/fault", site="serve_step",
-                                  error=str(e))
-                if self._consec_step_faults > \
-                        int(self.serving.step_fault_limit):
-                    raise
-                return {}
-            self._consec_step_faults = 0
-        self._admit()
-        self._check_compile_storm()
-        incidents = getattr(self.telemetry, "incidents", None)
-        if incidents is not None:
-            # SLO burn-rate sweep on the engine's (injectable) clock — a
-            # sustained multi-window miss fraction opens one incident
-            incidents.observe_slo(now=self._clock())
+        report = self._report
+        report["t0_ns"] = time.perf_counter_ns()
+        try:
+            with self.telemetry.span("serve/loop"):
+                return self._step()
+        finally:
+            report["t1_ns"] = time.perf_counter_ns()
+            report["active"] = self.n_active
+            report["queued"] = len(self.queue)
+            self.last_step = report
+            self._reports.append(report)
+            self._report = self._new_report()
+
+    def _step(self):
+        # serve/admit: deadlines, admission and slot fill; a prefill that
+        # slot fill runs is its own serve/prefill span beneath it
+        with self.telemetry.span("serve/admit"):
+            self._expire_deadlines()
+            if self.injector is not None:
+                try:
+                    self.injector.check("serve_step")
+                except Exception as e:
+                    self._consec_step_faults += 1
+                    self.stats["step_faults"] += 1
+                    self._serve_event("serve/fault", site="serve_step",
+                                      error=str(e))
+                    if self._consec_step_faults > \
+                            int(self.serving.step_fault_limit):
+                        raise
+                    return {}
+                self._consec_step_faults = 0
+            self._admit()
+            self._check_compile_storm()
+            incidents = getattr(self.telemetry, "incidents", None)
+            if incidents is not None:
+                # SLO burn-rate sweep on the engine's (injectable) clock —
+                # a sustained multi-window miss fraction opens one incident
+                incidents.observe_slo(now=self._clock())
         return self.scheduler.run_step()
 
     # -- lifecycle / introspection --------------------------------------

@@ -220,6 +220,11 @@ _ACTIVATIONS = {
 }
 
 
+# ``jax.named_scope``s below (``embed``, ``norm``, ``attn``, ``mlp``,
+# ``loss_head``) put the model's parts into every instruction's ``op_name``:
+# an xprof capture groups by them, and ``telemetry.op_scopes`` reads the
+# phase of a compiled step's instructions from the same path.
+@jax.named_scope("norm")
 def _norm(x, weight, eps, use_rms, bias=None):
     xf = x.astype(jnp.float32)
     if use_rms:
@@ -235,6 +240,7 @@ def _norm(x, weight, eps, use_rms, bias=None):
     return out.astype(x.dtype)
 
 
+@jax.named_scope("loss_head")
 def next_token_xent(logits, batch):
     """Next-token cross-entropy shared by the dense model and the pipeline
     default loss.  ``batch``: dict with ``input_ids`` [B,S] (+ optional
@@ -281,6 +287,7 @@ def _softcap(logits, cap):
     return logits
 
 
+@jax.named_scope("loss_head")
 def chunked_next_token_xent(x, head, head_b, batch, chunk_size: int,
                             logit_softcap=None, logit_scale=None):
     """Next-token cross-entropy WITHOUT materializing the full fp32
@@ -647,6 +654,7 @@ class CausalTransformerLM:
             delta = delta * c.residual_scale
         return x + delta
 
+    @jax.named_scope("attn")
     def _attn_delta(self, h, layer, positions):
         """Attention sub-block on pre-normed input; returns the residual
         delta (wo projection applied, no residual add)."""
@@ -725,6 +733,7 @@ class CausalTransformerLM:
             delta = delta * c.residual_scale
         return x + delta, aux
 
+    @jax.named_scope("mlp")
     def _mlp_delta(self, h, layer, rng=None, train=True):
         """FFN sub-block on pre-normed input; returns (delta, aux_loss)."""
         c = self.config
@@ -796,17 +805,18 @@ class CausalTransformerLM:
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
 
-        x = params["tok_embed"][input_ids]
-        if c.embed_scale is not None:   # Gemma: sqrt(d) on the
-            x = x * jnp.asarray(c.embed_scale, x.dtype)  # input side only
+        with jax.named_scope("embed"):
+            x = params["tok_embed"][input_ids]
+            if c.embed_scale is not None:   # Gemma: sqrt(d) on the
+                x = x * jnp.asarray(c.embed_scale, x.dtype)  # input side
 
-        if not c.use_rope and not c.use_alibi:
-            x = x + params["pos_embed"][positions].astype(x.dtype)
-        if c.embed_norm:
-            x = _norm(x, params["embed_norm"], c.norm_eps, c.use_rmsnorm,
-                      params.get("embed_norm_b"))
-        # activation layout: batch over all data axes, sequence over sp
-        x = maybe_constrain(x, P(tuple(BATCH_AXES), SP_AXIS, None))
+            if not c.use_rope and not c.use_alibi:
+                x = x + params["pos_embed"][positions].astype(x.dtype)
+            if c.embed_norm:
+                x = _norm(x, params["embed_norm"], c.norm_eps,
+                          c.use_rmsnorm, params.get("embed_norm_b"))
+            # activation layout: batch over all data axes, sequence over sp
+            x = maybe_constrain(x, P(tuple(BATCH_AXES), SP_AXIS, None))
 
         aux = jnp.float32(0.0)
         # per-layer local-attention windows ride the scan as a side input
@@ -848,14 +858,15 @@ class CausalTransformerLM:
                   params.get("final_norm_b"))
         if return_hidden:
             return x, aux
-        head = (params["tok_embed"].T if c.tie_embeddings
-                else params["lm_head"])
-        logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
-        if "lm_head_b" in params:
-            logits = logits + params["lm_head_b"].astype(jnp.float32)
-        if c.final_logit_scale is not None:   # Cohere logit_scale
-            logits = logits * c.final_logit_scale
-        logits = _softcap(logits, c.final_logit_softcap)
+        with jax.named_scope("loss_head"):
+            head = (params["tok_embed"].T if c.tie_embeddings
+                    else params["lm_head"])
+            logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
+            if "lm_head_b" in params:
+                logits = logits + params["lm_head_b"].astype(jnp.float32)
+            if c.final_logit_scale is not None:   # Cohere logit_scale
+                logits = logits * c.final_logit_scale
+            logits = _softcap(logits, c.final_logit_softcap)
         if return_aux:
             return logits, aux
         return logits

@@ -9,10 +9,18 @@ its own sink.  Here every subsystem writes into ONE process-local
 * :class:`MetricsRegistry` — counters, gauges (with peak tracking), and
   time-window histograms, safe to touch from worker threads (param-stream
   H2D drain, the watchdog).
-* :meth:`Telemetry.span` — a context manager that times its body, records
-  the duration into a histogram, emits a structured ``span`` event, and
-  opens a ``jax.profiler.TraceAnnotation`` so the same region shows up in
-  an xprof capture (no-op fallback when the profiler is unavailable).
+* :meth:`Telemetry.span` — a context manager that times its body on
+  ``time.perf_counter_ns``, ALWAYS records it into the object's
+  :class:`SpanRing` (id, parent, name, t0, t1, step or req_id, attrs) and
+  opens a ``jax.profiler.TraceAnnotation`` so the same region shows up
+  under the device lines of an xprof capture.  With ``enabled`` it also
+  records the duration into a histogram and emits a ``span`` event.
+* :func:`register_compiled` / :func:`op_scopes` — every jitted entry point
+  the engines route through ``_wrap_compiled`` is registered under its
+  site name; ``op_scopes(site)`` maps the compiled program's instruction
+  names (the names a device trace shows: ``fusion.728``) to the step
+  phase (``fwd``, ``bwd``, ``remat``, ``loss_head``, ``optimizer``,
+  ``grad_reduce``, ``other``) their ``jax.named_scope`` path says.
 * :class:`JsonlEventSink` — rank-0-gated JSONL stream with size-based
   rotation.  ``MonitorMaster`` gains it as a fourth writer, so scalar
   monitor events, comm census, HBM gauges, heartbeats and stalls all land
@@ -29,12 +37,18 @@ seconds), ``kind`` and ``name``.  The frozen per-kind schema lives in
 ``scripts/check_telemetry_schema.py`` and is enforced by a tier-1 test.
 """
 
+import contextlib
+import itertools
 import json
 import os
+import re
 import threading
 import time
+import weakref
 from collections import deque
-from contextlib import contextmanager, nullcontext
+from typing import Any, NamedTuple, Optional
+
+import jax
 
 from deepspeed_tpu.utils.logging import logger
 
@@ -45,15 +59,282 @@ EVENT_KINDS = ("span", "gauge", "counter", "comm", "heartbeat", "stall",
                "tune")
 
 
-def _profiler_annotation(name):
-    """An xprof trace annotation for ``name`` — host-side TraceMe, visible
-    in a ``jax.profiler`` capture.  Falls back to a no-op off-TPU / when
-    the profiler is unavailable."""
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return nullcontext()
+# The span vocabulary: every name the program passes to ``Telemetry.span``.
+# Frozen like the event vocabularies — ``scripts/check_telemetry_schema.py``
+# carries the same tuple and a tier-1 test diffs the two; a reader
+# (chipbench/reducers, ds_telemetry_report) may key on any of them.
+SPAN_NAMES = (
+    "checkpoint/load", "checkpoint/save",
+    "engine/forward", "engine/backward", "engine/step",
+    "engine/train_batch", "engine/input", "engine/dispatch",
+    "engine/input_wait", "param_stream/train_step",
+    "serve/loop", "serve/admit", "serve/step",
+    "serve/prefill", "serve/prefill/build", "serve/prefill/fetch",
+    "serve/prefill/sample",
+    "serve/decode", "serve/decode/build", "serve/decode/fetch",
+    "serve/decode/sample",
+)
+
+
+# ----------------------------------------------------------------------
+# the span ring
+# ----------------------------------------------------------------------
+class Span(NamedTuple):
+    """One finished span as :meth:`Telemetry.spans` returns it.  ``t0_ns``
+    and ``t1_ns`` are ``time.perf_counter_ns`` readings; ``parent`` is the
+    id of the span that was open on the same thread (None at the top);
+    ``key`` is the step (training) or the req_id (serving), if given."""
+    id: int
+    parent: Optional[int]
+    name: str
+    t0_ns: int
+    t1_ns: int
+    key: Any
+    attrs: Optional[dict]
+
+
+class SpanRing:
+    """The last ``capacity`` finished spans of one :class:`Telemetry`
+    (the process-wide ``get_telemetry()`` for the engines), oldest first.
+    Recording is one ``deque.append`` of a tuple (atomic under the GIL, so
+    worker threads need no lock); a span enters when it CLOSES, so a
+    parent follows its children."""
+
+    def __init__(self, capacity=65536):
+        self.capacity = int(capacity)
+        self._spans = deque(maxlen=self.capacity)
+
+    def __len__(self):
+        return len(self._spans)
+
+    def record(self, span):
+        """``span``: the seven fields of :class:`Span`, as a tuple."""
+        self._spans.append(span)
+
+    def spans(self, since_ns=None, until_ns=None):
+        """Spans that lie inside ``[since_ns, until_ns]`` (either bound
+        may be None), ordered by start."""
+        out = [Span(*s) for s in tuple(self._spans)
+               if (since_ns is None or s[3] >= since_ns)
+               and (until_ns is None or s[4] <= until_ns)]
+        out.sort(key=lambda s: s.t0_ns)
+        return out
+
+
+# ids are unique in the process and the open span is per thread, whichever
+# Telemetry object a span belongs to: a parent link may cross objects
+_span_ids = itertools.count(1)
+_open = threading.local()     # .top: id of the innermost open span
+
+
+class _OpenSpan:
+    """The context manager :meth:`Telemetry.span` returns.  Hand-written
+    (no generator) because it is on every serving and training step: about
+    2 us a span on the CPU, profiler annotation included."""
+
+    __slots__ = ("tel", "name", "step", "attrs", "req_id", "id", "parent",
+                 "t0", "_ann")
+
+    def __init__(self, tel, name, step, attrs, req_id):
+        self.tel, self.name, self.step = tel, name, step
+        self.attrs, self.req_id = attrs, req_id
+
+    def __enter__(self):
+        self.parent = getattr(_open, "top", None)
+        self.id = _open.top = next(_span_ids)
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        _open.top = self.parent
+        step = self.step
+        tel = self.tel
+        tel.ring.record(
+            (self.id, self.parent, self.name, self.t0, t1,
+             step if step is not None else self.req_id, self.attrs))
+        if tel.enabled:
+            dur_ms = (t1 - self.t0) / 1e6
+            attrs = self.attrs
+            if self.req_id is not None:
+                attrs = dict(attrs or {}, req_id=self.req_id)
+            tel.registry.histogram(f"span/{self.name}").observe(dur_ms)
+            tel.emit("span", self.name, dur_ms=round(dur_ms, 3), step=step,
+                     attrs=attrs or None)
+        return False
+
+
+# ----------------------------------------------------------------------
+# compiled programs by site, and what each of their instructions is for
+# ----------------------------------------------------------------------
+# phases ``op_scopes`` sorts a compiled step's instructions into; the
+# first five are what the trainer's ``jax.named_scope``s (and JAX's own
+# ``transpose(jvp(..))`` / ``rematted_computation`` markers) tell apart
+OP_PHASES = ("fwd", "bwd", "remat", "loss_head", "optimizer", "grad_reduce",
+             "other")
+
+_MODEL_SCOPES = frozenset(("embed", "norm", "attn", "mlp"))
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+# a computation opens with ``[ENTRY] %name (params) -> type {``; an
+# instruction line has `` = ``
+_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+) \(.*\{\s*$")
+
+
+def phase_of(op_name):
+    """The step phase of one instruction from its ``op_name`` (the
+    name-stack path JAX records: ``jit(step)/fwd/transpose(jvp(..))/
+    checkpoint/rematted_computation/mlp/dot_general``)."""
+    parts = op_name.split("/")
+    if "optimizer" in parts:
+        return "optimizer"
+    if "grad_reduce" in parts:
+        return "grad_reduce"
+    if "loss_head" in parts:
+        return "loss_head"
+    backward = "bwd" in parts
+    for i, part in enumerate(parts):
+        if part.startswith("transpose("):
+            backward = True
+        elif part == "rematted_computation":
+            # what follows the marker is the forward, run again; a later
+            # transpose(..) component is the backward proper
+            if not any(p.startswith("transpose(") for p in parts[i + 1:]):
+                return "remat"
+    if backward:
+        return "bwd"
+    if "fwd" in parts or any(p.startswith("jvp(") for p in parts) or \
+            _MODEL_SCOPES.intersection(parts):
+        return "fwd"
+    return "other"
+
+
+def parse_op_scopes(hlo_text):
+    """``{instruction name: phase}`` for every instruction of every
+    computation in a compiled module's text.  An instruction with no
+    ``op_name`` of its own (the compiler made it: a relayout copy, an
+    asynchronous prefetch) takes the commonest phase of the computation it
+    calls, if it calls one, else the phase of the next instruction of its
+    computation that has one, else of the one before: the text of a
+    compiled module is in schedule order, so that is the work it runs
+    beside."""
+    own, calls, members = {}, {}, {}
+    computation = None
+    for line in hlo_text.splitlines():
+        found = _INSTRUCTION.match(line)
+        if not found:
+            opened = _COMPUTATION.match(line)
+            if opened:
+                computation = opened.group(1)
+            continue
+        name = found.group(1)
+        op_name = _OP_NAME.search(line)
+        if op_name:
+            own[name] = phase_of(op_name.group(1))
+        else:
+            own[name] = None
+            callee = _CALLS.search(line)
+            if callee:
+                calls[name] = callee.group(1)
+        members.setdefault(computation, []).append(name)
+    out = {}
+    for name, phase in own.items():
+        if phase is None:
+            inner = [own[m] for m in members.get(calls.get(name), ())
+                     if own.get(m) not in (None, "other")]
+            phase = max(set(inner), key=inner.count) if inner else None
+        out[name] = phase
+    for names in members.values():
+        known = [out[n] if out[n] != "other" else None for n in names]
+        following = None
+        for i in range(len(names) - 1, -1, -1):     # the next one's phase
+            following = known[i] or following
+            if out[names[i]] is None:
+                out[names[i]] = following
+        before = None
+        for i, name in enumerate(names):            # else the last one's
+            before = known[i] or before
+            if out[name] is None:
+                out[name] = before or "other"
+    return out
+
+
+def _abstract(x):
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+    if hasattr(x, "shape") and hasattr(x, "dtype"):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+    return x
+
+
+class CompiledSite:
+    """A jitted entry point under its site name (``engine/train_step:2``,
+    ``serve/step_fn``).  Calls pass straight through; the first one also
+    keeps the shapes it was called with, so that :meth:`op_scopes` can
+    compile the same program again later (under ``mesh``, the context the
+    owner calls it in), outside any timed window, and read its text: with
+    the persistent compile cache on that second ``compile()`` is a cache
+    read."""
+
+    def __init__(self, fn, site, mesh=None):
+        self._fn, self.site, self._mesh = fn, site, mesh
+        self._first_call = self._scopes = None
+
+    def __call__(self, *args, **kwargs):
+        if self._first_call is None:
+            self._first_call = jax.tree_util.tree_map(_abstract,
+                                                      (args, kwargs))
+        return self._fn(*args, **kwargs)
+
+    def __getattr__(self, name):      # .lower, .__wrapped__, ...
+        return getattr(self._fn, name)
+
+    def compiled_text(self):
+        """Text of the program compiled for the first call's shapes, or
+        None before any call."""
+        if self._first_call is None:
+            return None
+        args, kwargs = self._first_call
+        with (self._mesh if self._mesh is not None
+              else contextlib.nullcontext()):
+            return self._fn.lower(*args, **kwargs).compile().as_text()
+
+    def op_scopes(self):
+        if self._scopes is None:
+            text = self.compiled_text()
+            if text is None:
+                return {}
+            self._scopes = parse_op_scopes(text)
+        return self._scopes
+
+
+# site -> CompiledSite, newest wins; weak, so a dropped engine is freed
+_sites = weakref.WeakValueDictionary()
+
+
+def register_compiled(fn, site, mesh=None):
+    """Wrap jitted ``fn`` as the program of ``site``; ``mesh`` is the
+    context its owner calls it in, if any."""
+    wrapped = _sites[site] = CompiledSite(fn, site, mesh)
+    return wrapped
+
+
+def op_scopes(site):
+    """``{instruction name: phase}`` (phase one of :data:`OP_PHASES`) of
+    the program compiled at ``site`` — an exact site name, or a prefix up
+    to a ``:`` (``"engine/train_step"`` finds ``engine/train_step:2``);
+    empty for a site that has not run.  Programs share instruction names
+    (``fusion.3``), so tables are per site.  Parsed from the compiled text
+    once, lazily: call it outside any timed window."""
+    table = {}
+    for name, entry in sorted(_sites.items()):
+        if name == site or name.startswith(site + ":"):
+            table.update(entry.op_scopes())
+    return table
 
 
 # ----------------------------------------------------------------------
@@ -320,14 +601,16 @@ def _coerce_attribution(acfg):
 class Telemetry:
     """Process-local telemetry: registry + (rank-0) JSONL sink + spans.
 
-    Disabled by default; every hot-path caller is expected to gate on
-    ``telemetry.enabled`` (one attribute read) so a disabled run pays a
-    single flag check per step and nothing else.
+    Disabled by default: events, histograms, gauges and the planes are
+    gated on ``telemetry.enabled`` (one attribute read).  Spans alone are
+    always recorded (``ring``) and annotated for the profiler.
     """
 
     def __init__(self):
         self.enabled = False
         self.registry = MetricsRegistry()
+        # every span, enabled or not (the hot path's one always-on record)
+        self.ring = SpanRing()
         self.sink = None
         self.config = None
         self.exporter = None
@@ -501,22 +784,19 @@ class Telemetry:
         if self.sink is not None:
             self.sink.emit(event)
 
-    @contextmanager
-    def span(self, name, step=None, attrs=None):
-        """Timed structured event + xprof trace annotation around the body.
-        The duration also lands in histogram ``span/<name>``."""
-        if not self.enabled:
-            yield
-            return
-        with _profiler_annotation(name):
-            t0 = time.perf_counter()
-            try:
-                yield
-            finally:
-                dur_ms = (time.perf_counter() - t0) * 1000.0
-                self.registry.histogram(f"span/{name}").observe(dur_ms)
-                self.emit("span", name, dur_ms=round(dur_ms, 3), step=step,
-                          attrs=attrs or None)
+    def span(self, name, step=None, attrs=None, req_id=None):
+        """A span around the body, whether or not telemetry is enabled:
+        recorded in this object's span ring on ``time.perf_counter_ns``
+        (read it with :meth:`spans`) and opened as a profiler annotation,
+        so a capture shows it on the host's lines above the device's.
+        Enabled, the duration also lands in histogram ``span/<name>`` and
+        a ``span`` event is emitted."""
+        return _OpenSpan(self, name, step, attrs, req_id)
+
+    def spans(self, since_ns=None, until_ns=None):
+        """Finished spans of this object's ring inside the given
+        ``perf_counter_ns`` bounds, ordered by start."""
+        return self.ring.spans(since_ns, until_ns)
 
     def gauge(self, name, value, step=None):
         """Set gauge ``name`` (peak-tracked) and emit a ``gauge`` event."""

@@ -203,6 +203,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret=False,
             jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",     # the instruction's name in a trace
     )(*args)
 
     out = jnp.swapaxes(out.reshape(B, H, S, D), 1, 2)
@@ -383,6 +384,7 @@ def _flash_bwd_pallas(scale, causal, res, g, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         interpret=interpret,
+        name="flash_attention_dq",
     )(qr, kr, vr, gr, lser, delta, *scalar_args)
 
     full_spec = pl.BlockSpec((1, S, D), lambda bh, ki: (bh, 0, 0))
@@ -414,6 +416,7 @@ def _flash_bwd_pallas(scale, causal, res, g, block_q, block_k,
             jax.ShapeDtypeStruct((B * H, S, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(qr, kr, vr, gr, lser, delta, *scalar_args)
 
     dq = jnp.swapaxes(dq.reshape(B, H, S, D), 1, 2)

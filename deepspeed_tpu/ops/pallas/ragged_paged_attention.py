@@ -118,9 +118,17 @@ def _ragged_kernel(ctx_ref, qlens_ref, qstarts_ref, sot_ref, qot_ref,
             .astype(o_ref.dtype)
 
 
+# fixed names on the device's lines (a trace shows the instruction as
+# ``ragged_paged_attention_decode.<n>``): decode when every sequence brings
+# one query token, prefill otherwise (bucketed or chunked prompts, and the
+# speculative verify window)
+KERNEL_PREFILL = "ragged_paged_attention_prefill"
+KERNEL_DECODE = "ragged_paged_attention_decode"
+
+
 def _ragged_call(qg, k_pages, v_pages, block_tables, ctx_lens, q_lens,
                  q_starts, seq_of_tile, qtile_of_tile, q_tile, scale,
-                 interpret):
+                 interpret, name):
     """Launch the kernel over a q-tile-padded packed query stack.
 
     qg: [total_padded, Hkv, group, D] — every sequence's rows start at a
@@ -171,6 +179,7 @@ def _ragged_call(qg, k_pages, v_pages, block_tables, ctx_lens, q_lens,
         ),
         out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
         interpret=interpret,
+        name=name,
     )(ctx_lens, q_lens, q_starts, sot, qot, tables,
       qg, k_pages, v_pages)
     return out
@@ -225,7 +234,8 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
 
     out = _ragged_call(qp.reshape(total_padded, Hkv, group, D),
                        k_pages, v_pages, block_tables, ctx_lens, q_lens,
-                       starts, sot, qot, q_tile, scale, interpret)
+                       starts, sot, qot, q_tile, scale, interpret,
+                       KERNEL_DECODE if max(q_lens) == 1 else KERNEL_PREFILL)
     out = out.reshape(total_padded, H, D)
     return jnp.concatenate(
         [out[int(starts[s]):int(starts[s]) + ql]
@@ -259,5 +269,6 @@ def ragged_paged_attention_rect(q, k_pages, v_pages, block_tables, lengths,
     q_lens = jnp.full((B,), T, jnp.int32)
     out = _ragged_call(q.reshape(B * Tp, Hkv, group, D),
                        k_pages, v_pages, block_tables, lengths, q_lens,
-                       starts, sot, qot, q_tile, scale, interpret)
+                       starts, sot, qot, q_tile, scale, interpret,
+                       KERNEL_DECODE if T == 1 else KERNEL_PREFILL)
     return out.reshape(B, Tp, H, D)[:, :T]

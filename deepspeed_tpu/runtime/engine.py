@@ -46,7 +46,8 @@ from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.comm.quantize import CommQuantizer
 from deepspeed_tpu.monitor.monitor import MonitorMaster
 from deepspeed_tpu.monitor.telemetry import (MetricsDrain, StepStallWatchdog,
-                                             get_telemetry)
+                                             get_telemetry,
+                                             register_compiled)
 from deepspeed_tpu.parallel import groups
 from deepspeed_tpu.parallel.topology import FSDP_AXIS, build_mesh
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
@@ -706,16 +707,22 @@ class DeepSpeedEngine:
         if qstep is None:
             qstep = step
 
+        # step-phase scopes (telemetry.op_scopes reads them back from the
+        # compiled text): everything traced under ``fwd`` is the forward;
+        # JAX itself marks its backward (``transpose(jvp(..))``) and its
+        # recompute (``rematted_computation``)
         def scaled_loss(p):
-            p_c = self._transformed_compute_params(p, rng, step, qstep)
-            return self._model_scaled_loss(p_c, batch, rng, loss_scale)
+            with jax.named_scope("fwd"):
+                p_c = self._transformed_compute_params(p, rng, step, qstep)
+                return self._model_scaled_loss(p_c, batch, rng, loss_scale)
 
         (_, loss), grads = jax.value_and_grad(scaled_loss, has_aux=True)(params)
         # unscale in fp32, then store at grad_accum_dtype (XLA fuses the
         # round-trip; bf16 storage halves the grad tree / GAS carry)
-        grads = jax.tree_util.tree_map(
-            lambda g: (g.astype(jnp.float32) / loss_scale).astype(
-                self.grad_accum_dtype), grads)
+        with jax.named_scope("bwd"):
+            grads = jax.tree_util.tree_map(
+                lambda g: (g.astype(jnp.float32) / loss_scale).astype(
+                    self.grad_accum_dtype), grads)
         return loss, grads
 
     def _transformed_compute_params(self, p, rng, step, qstep):
@@ -748,6 +755,7 @@ class DeepSpeedEngine:
         loss = self.loss_fn(p_c, batch, rng)
         return (loss * loss_scale).astype(jnp.float32), loss
 
+    @jax.named_scope("optimizer")
     def _apply_update(self, state: TrainState, grads, overflow):
         """Shared optimizer-update tail: clip (inside tx), skip-on-overflow,
         re-constrain placements, loss-scale automaton.  Used by both the fused
@@ -915,17 +923,21 @@ class DeepSpeedEngine:
         """Shared train-step tail: grad placement constraint, overflow
         check, optimizer update, metrics.  Used by both the dense and the
         pipeline engines so their semantics cannot diverge."""
-        grads = self._reduce_grads(grads, state.params)
+        with jax.named_scope("grad_reduce"):
+            grads = self._reduce_grads(grads, state.params)
         fp16 = self._config.fp16_enabled
-        overflow = has_inf_or_nan(grads) if fp16 else jnp.asarray(False)
+        with jax.named_scope("optimizer"):
+            overflow = has_inf_or_nan(grads) if fp16 else jnp.asarray(False)
         new_state, grad_norm = self._apply_update(
             state.replace(rng=rng), grads, overflow)
-        metrics = StepMetrics(
-            loss=loss.astype(jnp.float32),
-            grad_norm=grad_norm.astype(jnp.float32),
-            lr=jnp.asarray(self._schedule_fn(state.global_step), jnp.float32),
-            loss_scale=new_state.loss_scale.cur_scale,
-            overflow=overflow)
+        with jax.named_scope("optimizer"):
+            metrics = StepMetrics(
+                loss=loss.astype(jnp.float32),
+                grad_norm=grad_norm.astype(jnp.float32),
+                lr=jnp.asarray(self._schedule_fn(state.global_step),
+                               jnp.float32),
+                loss_scale=new_state.loss_scale.cur_scale,
+                overflow=overflow)
         return new_state, metrics
 
     def _forward_grads(self, params, scale, step_rng, batch, gas: int,
@@ -940,7 +952,8 @@ class DeepSpeedEngine:
                 mb_rng = jax.random.fold_in(step_rng, idx)
                 loss, grads = self._loss_and_grads(params, scale, mb, mb_rng,
                                                    step=step, qstep=qstep)
-                acc = jax.tree_util.tree_map(jnp.add, acc, grads)
+                with jax.named_scope("bwd"):
+                    acc = jax.tree_util.tree_map(jnp.add, acc, grads)
                 return (acc, rloss + loss), None
 
             zeros = jax.tree_util.tree_map(
@@ -948,7 +961,8 @@ class DeepSpeedEngine:
             (gsum, lsum), _ = jax.lax.scan(
                 micro, (zeros, jnp.float32(0.0)),
                 (jnp.arange(gas), batch))
-            grads = jax.tree_util.tree_map(lambda g: g / gas, gsum)
+            with jax.named_scope("bwd"):
+                grads = jax.tree_util.tree_map(lambda g: g / gas, gsum)
             return lsum / gas, grads
         return self._loss_and_grads(params, scale, batch, step_rng, step=step,
                                     qstep=qstep)
@@ -976,8 +990,12 @@ class DeepSpeedEngine:
         return train_step
 
     def _wrap_compiled(self, fn, site):
-        """Route a jitted entry point through the CompileWatcher so cache
-        misses (recompiles) are timed and emitted as ``compile/*`` events."""
+        """Register a jitted entry point under its site name
+        (``telemetry.op_scopes(site)`` reads the compiled program's
+        instruction names by phase), and with the profiling plane on route
+        it through the CompileWatcher so cache misses (recompiles) are
+        timed and emitted as ``compile/*`` events."""
+        fn = register_compiled(fn, site, mesh=self.mesh)
         if self._profiling is None:
             return fn
         return self._profiling.wrap(fn, site,
@@ -1079,8 +1097,6 @@ class DeepSpeedEngine:
     def forward(self, batch, rng=None):
         """Computes loss (and, functionally, gradients — cached for
         ``backward``).  Returns the unscaled loss."""
-        if not self._tel_enabled:
-            return self._forward_inner(batch, rng)
         with self.telemetry.span("engine/forward", step=self.global_steps), \
                 self._prof_track("fwd"):
             return self._forward_inner(batch, rng)
@@ -1123,8 +1139,6 @@ class DeepSpeedEngine:
     def backward(self, loss=None, allreduce_gradients=True, release_loss=False):
         """Accumulates the gradients computed by the latest ``forward``.
         Parity: reference ``backward:1931`` (scaling by 1/GAS happens here)."""
-        if not self._tel_enabled:
-            return self._backward_inner(loss)
         with self.telemetry.span("engine/backward", step=self.global_steps), \
                 self._prof_track("bwd"):
             return self._backward_inner(loss)
@@ -1154,12 +1168,10 @@ class DeepSpeedEngine:
     def step(self):
         """Applies the optimizer update at the GAS boundary.
         Parity: reference ``step:2142`` → ``_take_model_step:2074``."""
-        if not self._tel_enabled:
-            return self._step_inner()
         with self.telemetry.span("engine/step", step=self.global_steps), \
                 self._prof_track("step"):
             self._step_inner()
-        if self._step_applied:
+        if self._tel_enabled and self._step_applied:
             self._emit_step_telemetry()
 
     def _step_inner(self):
@@ -1204,14 +1216,15 @@ class DeepSpeedEngine:
         the mean loss over the global batch."""
         if self._preempt is not None and self._preempt.requested:
             self._handle_preemption()
-        if not self._tel_enabled:
+        # one path, telemetry on or off: the span is recorded in the
+        # span ring either way (call to return; the loss comes back as a
+        # device value, so this is host time unless a step blocks inside)
+        t0 = time.perf_counter()
+        with self.telemetry.span("engine/train_batch",
+                                 step=self.global_steps), \
+                self._prof_track("train_batch"):
             loss = self._train_batch_inner(data_iter, batch)
-        else:
-            t0 = time.perf_counter()
-            with self.telemetry.span("engine/train_batch",
-                                     step=self.global_steps), \
-                    self._prof_track("train_batch"):
-                loss = self._train_batch_inner(data_iter, batch)
+        if self._tel_enabled:
             self._emit_step_telemetry(step_secs=time.perf_counter() - t0,
                                       metrics=self._last_metrics)
         # step-boundary fault-tolerance hooks: divergence sentinel first
@@ -1240,12 +1253,9 @@ class DeepSpeedEngine:
                 # the worker already collated, gas-stacked, curriculum-
                 # transformed and sharded this batch — just pop it
                 try:
-                    if self._tel_enabled:
-                        with self.telemetry.span(
-                                "engine/input_wait", step=self.global_steps,
-                                attrs={"queued": data_iter.qsize()}):
-                            batch = next(data_iter)
-                    else:
+                    with self.telemetry.span(
+                            "engine/input_wait", step=self.global_steps,
+                            attrs={"queued": data_iter.qsize()}):
                         batch = next(data_iter)
                 except StopIteration:
                     if owns_iter:
@@ -1263,12 +1273,18 @@ class DeepSpeedEngine:
         self.tput_timer.start()
         if self.compression_scheduler is not None:
             self.compression_scheduler.check(self.global_steps)
-        if self.curriculum_scheduler_ is not None and not presharded:
-            batch = self._apply_curriculum(batch, leading_gas_dim=gas > 1)
         if self.progressive_layer_drop is not None:
             self.progressive_layer_drop.update_state(self.global_steps)
         if not presharded:
-            batch = self._shard_batch(batch, leading_gas_dim=gas > 1)
+            # host input prep: curriculum truncation and placement of the
+            # batch on the mesh (the prefetch worker did both already for
+            # a presharded batch; its wait is engine/input_wait)
+            with self.telemetry.span("engine/input",
+                                     step=self.global_steps):
+                if self.curriculum_scheduler_ is not None:
+                    batch = self._apply_curriculum(
+                        batch, leading_gas_dim=gas > 1)
+                batch = self._shard_batch(batch, leading_gas_dim=gas > 1)
         if self._tel_enabled:
             self._last_batch_tokens = _batch_token_count(batch)
         if self._injector is not None and \
@@ -1316,7 +1332,8 @@ class DeepSpeedEngine:
                 overflow=jnp.asarray(overflow_b))
         elif self._offload is not None:
             grad_fn = self._get_compiled_offload_grad_step(gas)
-            with self.mesh:
+            with self.telemetry.span("engine/dispatch",
+                                     step=self.global_steps), self.mesh:
                 loss, grads, overflow, grad_norm, rng = grad_fn(
                     self.state, batch)
             self.state = self.state.replace(rng=rng)
@@ -1330,7 +1347,8 @@ class DeepSpeedEngine:
                 overflow=overflow)
         else:
             step_fn = self._get_compiled_train_step(gas)
-            with self.mesh:
+            with self.telemetry.span("engine/dispatch",
+                                     step=self.global_steps), self.mesh:
                 self.state, metrics = step_fn(self.state, batch)
         self.global_steps += 1
         if self.lr_scheduler is not None:

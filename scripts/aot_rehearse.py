@@ -141,7 +141,9 @@ def serve():
                                   "prefill 32": (1, 32),
                                   "prefill 512": (1, 512)}.items():
         t0 = time.time()
-        compiled = engine._step_fn.lower(
+        # prefill and decode are two named jits over the one paged call
+        step_fn = engine._step_fn if tokens == 1 else engine._prefill_fn
+        compiled = step_fn.lower(
             params, ints(batch, tokens), caches,
             ints(batch, engine.tables.shape[1]), ints(batch)).compile()
         report(f"serve llama-1B {name}", compiled, t0)

@@ -169,6 +169,30 @@ SCHEMA = {
     },
 }
 
+# FROZEN vocabulary of span names the program passes to ``Telemetry.span``
+# — must stay byte-identical to ``deepspeed_tpu.monitor.telemetry.
+# SPAN_NAMES`` (a tier-1 test diffs the two).  Every one is recorded in
+# the always-on span ring and opened as a profiler annotation; with
+# telemetry enabled each close also emits a ``span`` event under the same
+# name.  The stream check does not reject other span names (tools and
+# tests open their own); the tuple is what readers may key on.
+# ``serve/loop`` is one ``ServingEngine.step()``; beneath it
+# ``serve/admit``, ``serve/prefill`` (> build, ``serve/step``, fetch,
+# sample) and ``serve/decode`` (> build, ``serve/step``, fetch, sample);
+# ``engine/train_batch`` holds ``engine/input``, ``engine/dispatch`` and,
+# with the prefetch iterator, ``engine/input_wait``.
+SPAN_NAMES = (
+    "checkpoint/load", "checkpoint/save",
+    "engine/forward", "engine/backward", "engine/step",
+    "engine/train_batch", "engine/input", "engine/dispatch",
+    "engine/input_wait", "param_stream/train_step",
+    "serve/loop", "serve/admit", "serve/step",
+    "serve/prefill", "serve/prefill/build", "serve/prefill/fetch",
+    "serve/prefill/sample",
+    "serve/decode", "serve/decode/build", "serve/decode/fetch",
+    "serve/decode/sample",
+)
+
 # FROZEN vocabulary of serve-kind event names — must stay byte-identical
 # to ``deepspeed_tpu.inference.robustness.SERVE_EVENTS`` (the tier-1 test
 # diffs the two).  The prefix_* names belong to the prefix-cache subsystem
@@ -201,7 +225,8 @@ SERVE_EVENTS = (
     # request's full history is reconstructible from the JSONL stream
     # alone.  The "queued" state is implicit between admitted and
     # prefill_start (queue_wait_ms attr); the "decode" phase is implicit
-    # between first_token and the terminal (tpot_ms attr).  Every admitted
+    # between first_token and the terminal (tpot_ms attr: the mean gap
+    # between successive tokens as they reached the host).  Every admitted
     # request reaches EXACTLY ONE of the four terminals — the
     # trace-completeness invariant leak_report() audits.
     "serve/request/admitted", "serve/request/prefill_start",
