@@ -153,7 +153,8 @@ def phase_device(n_chips):
 # --------------------------------------------------------------------------
 def phase_kernels(flash_shape=(2, 1024, 16, 128), heads=(16, 4),
                   head_dim=128, page_size=16, decode_batch=8,
-                  max_pages=32, prefill_len=128, interpret=False):
+                  max_pages=32, prefill_len=128, big_page=128,
+                  big_prefill_len=1024, interpret=False):
     """Kernels alone, against the float32 oracles."""
     key = jax.random.key(SEED)
     f32 = lambda t: jax.tree_util.tree_map(   # noqa: E731
@@ -182,33 +183,38 @@ def phase_kernels(flash_shape=(2, 1024, 16, 128), heads=(16, 4),
         errors[f"flash_bwd_{name}"] = _error(got, want)
 
     # ragged paged attention: a decode batch of uneven contexts and one
-    # prefill, over one shuffled page pool
+    # prefill, over one shuffled page pool — at the smoke server's page
+    # size, and at the documented default page with a long prefill
     H, Hkv = heads
     n_pages = decode_batch * max_pages + 1
-    pool = PagedKVCache(*(jax.random.normal(
-        jax.random.fold_in(key, 10 + i), (n_pages, Hkv, page_size, head_dim),
-        jnp.bfloat16) for i in range(2)))
     rng = np.random.default_rng(SEED)
-    tables = jnp.asarray(rng.permutation(np.arange(1, n_pages))
-                         .reshape(decode_batch, max_pages), jnp.int32)
-    cap = max_pages * page_size
-    lengths = jnp.asarray(rng.integers(page_size + 1, cap, decode_batch),
-                          jnp.int32)
-    cases = {
-        "ragged_decode": (
-            jax.random.normal(jax.random.fold_in(key, 20),
-                              (decode_batch, 1, H, head_dim), jnp.bfloat16),
-            tables, lengths),
-        "ragged_prefill": (
-            jax.random.normal(jax.random.fold_in(key, 21),
-                              (1, prefill_len, H, head_dim), jnp.bfloat16),
-            tables[:1], jnp.full((1,), prefill_len, jnp.int32)),
-    }
-    for name, (qr, tb, ln) in cases.items():
-        got = paged_decode_attention(qr, pool, tb, ln, impl="pallas",
-                                     interpret=interpret)
-        want = paged_decode_attention(f32(qr), f32(pool), tb, ln, impl="jnp")
-        errors[name] = _error(got, want)
+    for tag, page, plen in (("", page_size, prefill_len),
+                            (f"_p{big_page}", big_page, big_prefill_len)):
+        pool = PagedKVCache(*(jax.random.normal(
+            jax.random.fold_in(key, 10 + i), (n_pages, Hkv, page, head_dim),
+            jnp.bfloat16) for i in range(2)))
+        tables = jnp.asarray(rng.permutation(np.arange(1, n_pages))
+                             .reshape(decode_batch, max_pages), jnp.int32)
+        lengths = jnp.asarray(
+            rng.integers(page + 1, max_pages * page, decode_batch),
+            jnp.int32)
+        cases = {
+            f"ragged_decode{tag}": (
+                jax.random.normal(jax.random.fold_in(key, 20),
+                                  (decode_batch, 1, H, head_dim),
+                                  jnp.bfloat16),
+                tables, lengths),
+            f"ragged_prefill{tag}": (
+                jax.random.normal(jax.random.fold_in(key, 21),
+                                  (1, plen, H, head_dim), jnp.bfloat16),
+                tables[:1], jnp.full((1,), plen, jnp.int32)),
+        }
+        for name, (qr, tb, ln) in cases.items():
+            got = paged_decode_attention(qr, pool, tb, ln, impl="pallas",
+                                         interpret=interpret)
+            want = paged_decode_attention(f32(qr), f32(pool), tb, ln,
+                                          impl="jnp")
+            errors[name] = _error(got, want)
 
     emit("kernels", max_abs_error=errors, tolerance=KERNEL_TOL,
          interpret=interpret)

@@ -544,7 +544,8 @@ class ChunkedScheduler(SchedulerBase):
         out, self.draft_caches, _ = self.engine._dispatch(
             self._draft_step_fn,
             (self.draft_params, ids, self.draft_caches, tables, lengths),
-            phase, *ids.shape, backend="draft")
+            phase, *ids.shape, backend="draft",
+            config=self.draft_model.config)
         return out
 
     # -- admission hooks -------------------------------------------------
@@ -829,7 +830,8 @@ class ChunkedScheduler(SchedulerBase):
             self._propose_fn,
             (self.draft_params, self.draft_caches, jnp.asarray(dtables),
              jnp.asarray(dlengths), jnp.asarray(dlast)),
-            "spec_draft", eng.max_batch, G + 1, backend="draft")
+            "spec_draft", eng.max_batch, G + 1, backend="draft",
+            config=self.draft_model.config)
         with eng.telemetry.span("serve/decode/fetch"):
             props[:, :] = np.asarray(toks)[:, :G]
         eng._serve_event("serve/spec_draft", slots=len(specs), window=G)

@@ -55,6 +55,7 @@ from deepspeed_tpu.ops.decode_attention import use_pallas
 from deepspeed_tpu.ops.paged_attention import (PageAllocationError,
                                                PagedAllocator,
                                                resolve_attention_backend)
+from deepspeed_tpu.ops.pallas.ragged_paged_attention import pick_tiles
 from deepspeed_tpu.runtime.resilience import FaultInjector
 from deepspeed_tpu.utils.logging import logger
 
@@ -375,6 +376,7 @@ class ServingEngine:
         # so a prefill that add_request ran inline is in the next one
         self._report = self._new_report()
         self.last_step = None
+        self._kernel_grids = {}
         self._reports = collections.deque(maxlen=STEP_REPORTS_KEPT)
         self._admission = AdmissionController(self.serving)
         # per-request lifecycle traces on the SAME injectable clock as the
@@ -1126,16 +1128,41 @@ class ServingEngine:
             step_fn, (self.params, ids, self.caches, tables, lengths),
             phase, *ids.shape)
 
-    def _dispatch(self, fn, args, phase, batch, tokens, backend=None):
+    def kernel_grid(self, phase, batch, tokens, config=None):
+        """Grid steps of the ragged paged-attention kernel in one dispatch
+        (every layer of ``config``, default the target model): what the
+        tile picker chose for this compiled shape, 0 on the jnp path.
+        ``decode_chunk`` and ``spec_draft`` run ``tokens`` T=1 forwards."""
+        config = config or self.config
+        key = (phase, int(batch), int(tokens), id(config))
+        if key not in self._kernel_grids:
+            steps = 0
+            if self.attention_impl == "pallas":
+                calls, T = ((tokens, 1) if phase in ("decode_chunk",
+                                                     "spec_draft")
+                            else (1, tokens))
+                steps = calls * config.n_layers * pick_tiles(
+                    [int(T)] * int(batch), config.n_heads // config.kv_heads,
+                    config.kv_heads, self.page_size, config.head_dim,
+                    self.tables.shape[1],
+                    jnp.dtype(self.cache_dtype).itemsize).grid_steps
+            self._kernel_grids[key] = int(steps)
+        return self._kernel_grids[key]
+
+    def _dispatch(self, fn, args, phase, batch, tokens, backend=None,
+                  config=None):
         """Launch jitted ``fn(*args)`` as one ``serve/step`` span and one
         entry of the open report's ``dispatches`` (the target model's
-        steps, the chunked decode scan, the draft model's)."""
+        steps, the chunked decode scan, the draft model's — ``config``
+        is the model whose layers the dispatch runs)."""
         t0_ns = time.perf_counter_ns()
+        kernel_grid = self.kernel_grid(phase, batch, tokens, config)
         with self.telemetry.span(
                 "serve/step",
                 attrs={"backend": backend or self.attention_backend,
                        "phase": phase, "batch": int(batch),
-                       "tokens": int(tokens)}), \
+                       "tokens": int(tokens),
+                       "kernel_grid": kernel_grid}), \
                 self._prof_track("prefill" if phase == "prefill"
                                  else "serve_step"), \
                 (self.mesh if self.mesh is not None
@@ -1143,6 +1170,7 @@ class ServingEngine:
             out = fn(*args)
         self._report["dispatches"].append(
             {"phase": phase, "batch": int(batch), "tokens": int(tokens),
+             "kernel_grid": kernel_grid,
              "t0_ns": t0_ns, "t1_ns": time.perf_counter_ns()})
         return out
 

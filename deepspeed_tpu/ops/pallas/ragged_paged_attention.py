@@ -14,17 +14,33 @@ through the block table and serves a whole mixed batch in one launch:
   batch max and no host-side regrouping into separate prefill and decode
   dispatches.  Internally each sequence's rows are padded only up to the
   next ``q_tile`` multiple.
-* **grid = (q_tiles, kv_heads, pages)**; scalar-prefetched metadata
-  (context lengths, query lengths, padded row starts, tile→sequence /
-  tile→q-tile maps, block tables) steers the BlockSpec index maps, so the
-  K/V index map fetches exactly the owning sequence's pages — shared
-  prefix-cache pages and partial last pages read in place; pages past the
-  tile's causal frontier are clamped to a repeat index (DMA skipped) and
-  their compute is ``pl.when``-predicated off.
-* **Online softmax** (running max / sum / fp32 accumulator in VMEM
-  scratch persisting across the sequential page grid dim), one
-  ``[q_tile·group, D]`` tile per (q-tile, kv-head); GQA comes free by
-  folding each kv head's whole query group into the tile rows.
+* **A grid step is MXU-sized, and its shape is picked from the call's
+  shapes** (:func:`pick_tiles`, one pure function of the query lengths,
+  ``group``, ``Hkv``, ``page_size``, ``D``, the table width and the
+  cache's itemsize, under ``VMEM_BUDGET``).  grid = (q tiles, kv-head
+  blocks, kv steps); one step multiplies ``heads`` kv heads'
+  ``[q_tile·group, D]`` query rows by ``pages`` pages of keys at once
+  (a batched dot over the heads).  A long prefill takes up to 1,024 rows
+  of one head by up to 1,024 keys a step; a T=1 decode takes ALL kv
+  heads of its pages (one page's ``[Hkv, page, D]`` block is contiguous
+  in the pool), two pages of 128 a step; a few rows (speculative verify,
+  small chunks) sit in between; GQA folds each kv head's whole query
+  group into the rows.  The
+  wrapper lays q out as ``[tiles, Hkv, q_tile·group, D]`` (XLA's
+  transpose, outside the kernel) so a tile's rows are the second-minor
+  dimension of its block.
+* **Scalar-prefetched metadata** (context lengths, query lengths,
+  tile→sequence / tile→q-tile maps, block tables) steers the BlockSpec
+  index maps: each of the step's ``pages`` K/V operands resolves its own
+  page through the block table, so exactly the owning sequence's pages
+  are fetched — shared prefix-cache pages and partial last pages read in
+  place; steps past the tile's causal frontier repeat the previous block
+  indices (DMA skipped) and their compute is ``pl.when``-predicated off.
+* **Online softmax** (running max / sum / accumulator in float32 VMEM
+  scratch persisting across the sequential kv grid dim).  ``QK^T`` and
+  ``PV`` take operands in the cache's dtype (bf16 cache: bf16 operands,
+  float32 accumulation; float32 cache: float32 operands), ``p`` is cast
+  to the value dtype for ``PV`` — what the jnp oracle does.
 
 ``ragged_paged_attention`` is the packed front-end (tests/bench/gate);
 ``ragged_paged_attention_rect`` adapts the rectangular ``[B, T, H, D]``
@@ -37,6 +53,7 @@ oracle; ``interpret=True`` runs this kernel on CPU CI.
 
 import functools
 import math
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,23 +63,106 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 
-DEFAULT_Q_TILE = 8
+# what one grid step aims at: rows of the score tile (kv heads x query
+# tokens x group) and keys (pages x page_size) — a compute-bound step
+# (many rows) wants both large; a step with few rows is bound by its K/V
+# traffic and wants no more than ``STEP_KV_BYTES`` of it, because the
+# last step of a sequence fetches whole groups of pages (measured on the
+# v5e, PERF.md §6 PR 25).  ``VMEM_BUDGET`` bounds the step's blocks,
+# scratch and score temporaries by ``TileChoice``'s own count; the
+# compiler is given twice that (the v5e has 128 MiB of VMEM).
+MAX_ROWS = 1024
+TARGET_KEYS = 1024
+STEP_KV_BYTES = 2 * 2 ** 20
+MAX_PAGES_PER_STEP = 8
+VMEM_BUDGET = 16 * 2 ** 20
 
 
-def _ragged_kernel(ctx_ref, qlens_ref, qstarts_ref, sot_ref, qot_ref,
-                   tables_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, scale, page_size, q_tile,
-                   group):
-    """One (q-tile, kv-head, page) step of online-softmax attention.
+class TileChoice(NamedTuple):
+    """Block shapes of one call: ``q_tile`` query tokens of one sequence
+    x ``heads`` kv heads x ``pages`` K/V pages a grid step."""
+    q_tile: int
+    heads: int
+    pages: int
+    grid: Tuple[int, int, int]     # (q tiles, kv-head blocks, kv steps)
+    vmem_bytes: int
 
-    q_ref: [q_tile, 1, group, D] — ``q_tile`` padded query rows of ONE
-    sequence for one kv head's whole group; k_ref/v_ref: [1, 1, page, D]
-    (the page the index map resolved through the block table);
-    o_ref: [q_tile, 1, group, D]; scratch acc/m/l persist across the
-    page grid dim (TPU grids are sequential)."""
+    @property
+    def grid_steps(self) -> int:
+        return self.grid[0] * self.grid[1] * self.grid[2]
+
+
+def _step_vmem_bytes(q_tile, heads, pages, group, page_size, D, itemsize):
+    """VMEM one grid step holds: double-buffered q/out and K/V blocks, the
+    concatenated K/V of a multi-page step, the float32 scratch (max and
+    sum padded to a lane tile) and three score-sized temporaries."""
+    rows = heads * q_tile * group
+    lanes = -(-D // 128) * 128
+    keys = pages * page_size
+    kv = 2 * heads * keys * lanes * itemsize
+    return (2 * 2 * rows * lanes * itemsize
+            + 2 * kv + (kv if pages > 1 else 0)
+            + rows * (lanes + 2 * 128) * 4
+            + 3 * rows * max(keys, 128) * 4)
+
+
+def pick_tiles(q_lens, group, n_kv_heads, page_size, head_dim, table_width,
+               itemsize, q_tile=None) -> TileChoice:
+    """Block shapes for one call, from what the call can see.
+
+    ``q_lens``: host ints, one per sequence (``[T] * B`` for the
+    rectangular path).  Query rows first: up to ``MAX_ROWS // group``
+    tokens a tile, the tiles of the longest sequence evened out; then as
+    many kv heads a step as keep the score tile within ``MAX_ROWS`` rows
+    (all of them at T=1); then pages up to ``TARGET_KEYS`` keys and
+    ``STEP_KV_BYTES`` of K and V a step (a page must fill whole sublane
+    tiles of its dtype to be concatenated with others); pages, then
+    heads, shrink to fit ``VMEM_BUDGET``.  ``q_tile`` overrides the first
+    choice (tests)."""
+    T = max(q_lens)
+    if q_tile is None:
+        cap = max(1, MAX_ROWS // group)
+        q_tile = -(-T // -(-T // cap))
+        if q_tile < T:
+            q_tile = min(T, -(-q_tile // 8) * 8)
+    q_tile = int(min(q_tile, T))
+    rows = q_tile * group
+    divisors = [h for h in range(n_kv_heads, 0, -1) if n_kv_heads % h == 0]
+    heads = next((h for h in divisors if h * rows <= MAX_ROWS), 1)
+    sublanes = 8 * max(1, 4 // itemsize)
+    pages = 1
+    if page_size % sublanes == 0:
+        page_kv_bytes = 2 * heads * page_size * head_dim * itemsize
+        pages = max(1, min(table_width, MAX_PAGES_PER_STEP,
+                           TARGET_KEYS // page_size,
+                           STEP_KV_BYTES // page_kv_bytes))
+
+    def vmem(heads, pages):
+        return _step_vmem_bytes(q_tile, heads, pages, group, page_size,
+                                head_dim, itemsize)
+
+    while pages > 1 and vmem(heads, pages) > VMEM_BUDGET:
+        pages -= 1
+    while heads > 1 and vmem(heads, pages) > VMEM_BUDGET:
+        heads = next(h for h in divisors if h < heads)
+    n_tiles = sum(-(-int(ql) // q_tile) for ql in q_lens)
+    grid = (n_tiles, n_kv_heads // heads, -(-table_width // pages))
+    return TileChoice(q_tile, heads, pages, grid, vmem(heads, pages))
+
+
+def _ragged_kernel(ctx_ref, qlens_ref, sot_ref, qot_ref, tables_ref,
+                   q_ref, *refs, scale, page_size, q_tile, group, pages):
+    """One (q-tile, kv-head block, kv step) of online-softmax attention.
+
+    q_ref: [1, heads, q_tile*group, D] — ``q_tile`` padded query rows of
+    ONE sequence for ``heads`` kv heads' whole groups; then ``pages`` K
+    refs and ``pages`` V refs of [1, heads, page, D] (the pages the index
+    maps resolved through the block table); o_ref like q_ref; scratch
+    acc/m/l persist across the kv grid dim (TPU grids are sequential)."""
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * pages:]
     t = pl.program_id(0)
     i = pl.program_id(2)
-    n_pages = pl.num_programs(2)
     s = sot_ref[t]
     qt = qot_ref[t]
     ctx = ctx_ref[s]          # tokens in the cache INCLUDING the queries
@@ -71,7 +171,7 @@ def _ragged_kernel(ctx_ref, qlens_ref, qstarts_ref, sot_ref, qot_ref,
     kv_hi = ctx - qlen + jnp.minimum(qlen, (qt + 1) * q_tile)
 
     rows = q_tile * group
-    d = q_ref.shape[-1]
+    keys = pages * page_size
 
     @pl.when(i == 0)
     def _init():
@@ -79,43 +179,43 @@ def _ragged_kernel(ctx_ref, qlens_ref, qstarts_ref, sot_ref, qot_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(i * page_size < kv_hi)
+    @pl.when(i * keys < kv_hi)
     def _compute():
-        q = q_ref[:, 0].reshape(rows, d).astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)                # [page, D]
-        v = v_ref[0, 0].astype(jnp.float32)
+        if pages == 1:
+            k, v = k_refs[0][0], v_refs[0][0]              # [heads, page, D]
+        else:
+            k = jnp.concatenate([r[0] for r in k_refs], axis=1)
+            v = jnp.concatenate([r[0] for r in v_refs], axis=1)
+        q = q_ref[0].astype(k.dtype)                       # [heads, rows, D]
         sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [rows, page]
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale   # [heads, rows, keys]
 
         # row r is the sequence's local query token qt*q_tile + r//group
         # at absolute position ctx - qlen + local_t; per-sequence padding
-        # rows (local_t >= qlen) mask to nothing and finalize to zeros
+        # rows (local_t >= qlen) see no key and finalize to zeros
         local_t = qt * q_tile + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 0) // group
-        kpos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1)
-        qpos = ctx - qlen + local_t
-        sc = jnp.where((kpos <= qpos) & (local_t < qlen), sc, _NEG)
+            jnp.int32, (rows, 1), 0) // group
+        last_key = jnp.where(local_t < qlen, ctx - qlen + local_t, -1)
+        kpos = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+        sc = jnp.where((kpos <= last_key - i * keys)[None], sc, _NEG)
 
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        bm = jnp.max(sc, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, bm)
-        p = jnp.exp(sc - m_new)
-        p = jnp.where(m_new <= _NEG / 2, 0.0, p)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        # a row with no key yet keeps m = _NEG: exponentiate against 0 so
+        # its p is exp(_NEG) = 0, not exp(0)
+        p = jnp.exp(sc - jnp.where(m_new <= _NEG / 2, 0.0, m_new))
         corr = jnp.exp(m_prev - m_new)
-        corr = jnp.where(m_prev <= _NEG / 2, 0.0, corr)
         m_ref[...] = m_new
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    @pl.when(i == n_pages - 1)
+    @pl.when(i == pl.num_programs(2) - 1)
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[:, 0] = (acc_ref[...] / l_safe).reshape(q_tile, group, d) \
-            .astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 # fixed names on the device's lines (a trace shows the instruction as
@@ -126,63 +226,91 @@ KERNEL_PREFILL = "ragged_paged_attention_prefill"
 KERNEL_DECODE = "ragged_paged_attention_decode"
 
 
-def _ragged_call(qg, k_pages, v_pages, block_tables, ctx_lens, q_lens,
-                 q_starts, seq_of_tile, qtile_of_tile, q_tile, scale,
+def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
+                 seq_of_tile, qtile_of_tile, tiles: TileChoice, scale,
                  interpret, name):
-    """Launch the kernel over a q-tile-padded packed query stack.
+    """Launch the kernel over a tiled query stack.
 
-    qg: [total_padded, Hkv, group, D] — every sequence's rows start at a
-    q_tile multiple (``q_starts``).  ctx_lens/q_lens may be traced;
-    q_starts / seq_of_tile / qtile_of_tile are host metadata (they size
+    qt: [n_tiles, Hkv, q_tile*group, D] — tile ``t`` holds ``q_tile``
+    rows of sequence ``seq_of_tile[t]`` (its ``qtile_of_tile[t]``-th
+    tile), each row with its kv head's whole group.  ctx_lens/q_lens may
+    be traced; seq_of_tile / qtile_of_tile are host metadata (they size
     the grid)."""
-    total_padded, Hkv, group, D = qg.shape
+    n_tiles, Hkv, rows, D = qt.shape
     page_size = k_pages.shape[2]
-    max_pages = block_tables.shape[1]
-    n_tiles = len(seq_of_tile)
+    width = block_tables.shape[1]
+    q_tile, heads, pages = tiles.q_tile, tiles.heads, tiles.pages
+    assert tiles.grid[0] == n_tiles and rows % q_tile == 0
     ctx_lens = jnp.asarray(ctx_lens, jnp.int32)
     q_lens = jnp.asarray(q_lens, jnp.int32)
-    q_starts = jnp.asarray(q_starts, jnp.int32)
     sot = jnp.asarray(seq_of_tile, jnp.int32)
     qot = jnp.asarray(qtile_of_tile, jnp.int32)
     tables = jnp.asarray(block_tables, jnp.int32)
 
-    def q_map(t, h, i, ctx, qls, qst, sot, qot, tbl):
-        return (qst[sot[t]] // q_tile + qot[t], h, 0, 0)
+    def q_map(t, h, i, ctx, qls, sot, qot, tbl):
+        return (t, h, 0, 0)
 
-    def kv_map(t, h, i, ctx, qls, qst, sot, qot, tbl):
-        # fetch only pages under this tile's causal frontier: clamp to the
-        # last needed page (repeat index -> DMA skipped)
+    def kv_map(j, t, h, i, ctx, qls, sot, qot, tbl):
+        # fetch only pages under this tile's causal frontier: the step's
+        # j-th operand clamps to the last needed page, and steps past the
+        # frontier repeat the last needed step's indices (DMA skipped)
         s = sot[t]
         kv_hi = ctx[s] - qls[s] + jnp.minimum(qls[s], (qot[t] + 1) * q_tile)
         last = jnp.maximum(pl.cdiv(kv_hi, page_size) - 1, 0)
-        return (tbl[s, jnp.minimum(i, last)], h, 0, 0)
+        col = jnp.minimum(jnp.minimum(i, last // pages) * pages + j, last)
+        return (tbl[s, jnp.minimum(col, width - 1)], h, 0, 0)
 
+    kv_specs = [pl.BlockSpec((1, heads, page_size, D),
+                             functools.partial(kv_map, j))
+                for j in range(pages)]
     kernel = functools.partial(_ragged_kernel, scale=scale,
                                page_size=page_size, q_tile=q_tile,
-                               group=group)
-    out = pl.pallas_call(
+                               group=rows // q_tile, pages=pages)
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
-            grid=(n_tiles, Hkv, max_pages),
-            in_specs=[
-                pl.BlockSpec((q_tile, 1, group, D), q_map),
-                pl.BlockSpec((1, 1, page_size, D), kv_map),
-                pl.BlockSpec((1, 1, page_size, D), kv_map),
-            ],
-            out_specs=pl.BlockSpec((q_tile, 1, group, D), q_map),
+            num_scalar_prefetch=5,
+            grid=tiles.grid,
+            in_specs=[pl.BlockSpec((1, heads, rows, D), q_map)]
+            + kv_specs + kv_specs,
+            out_specs=pl.BlockSpec((1, heads, rows, D), q_map),
             scratch_shapes=[
-                pltpu.VMEM((q_tile * group, D), jnp.float32),
-                pltpu.VMEM((q_tile * group, 1), jnp.float32),
-                pltpu.VMEM((q_tile * group, 1), jnp.float32),
+                pltpu.VMEM((heads, rows, D), jnp.float32),
+                pltpu.VMEM((heads, rows, 1), jnp.float32),
+                pltpu.VMEM((heads, rows, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=2 * VMEM_BUDGET),
         interpret=interpret,
         name=name,
-    )(ctx_lens, q_lens, q_starts, sot, qot, tables,
-      qg, k_pages, v_pages)
-    return out
+    )(ctx_lens, q_lens, sot, qot, tables, qt,
+      *([k_pages] * pages), *([v_pages] * pages))
+
+
+def _pick_for(q_lens, q, k_pages, block_tables, q_tile):
+    H, D = q.shape[-2:]
+    Hkv, page_size = k_pages.shape[1:3]
+    return pick_tiles(q_lens, H // Hkv, Hkv, page_size, D,
+                      block_tables.shape[1], k_pages.dtype.itemsize, q_tile)
+
+
+def _to_tiles(q, n_tiles, q_tile, Hkv):
+    """[n_tiles*q_tile, H, D] rows -> [n_tiles, Hkv, q_tile*group, D]."""
+    _, H, D = q.shape
+    group = H // Hkv
+    q = q.reshape(n_tiles, q_tile, Hkv, group, D)
+    return jnp.swapaxes(q, 1, 2).reshape(n_tiles, Hkv, q_tile * group, D)
+
+
+def _from_tiles(out, q_tile):
+    """Inverse of :func:`_to_tiles`."""
+    n_tiles, Hkv, rows, D = out.shape
+    group = rows // q_tile
+    out = out.reshape(n_tiles, Hkv, q_tile, group, D)
+    return jnp.swapaxes(out, 1, 2).reshape(n_tiles * q_tile, Hkv * group, D)
 
 
 def _pack_metadata(q_lens, q_tile):
@@ -201,8 +329,8 @@ def _pack_metadata(q_lens, q_tile):
 
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
-                           q_lens, softmax_scale=None,
-                           q_tile=DEFAULT_Q_TILE, interpret=False):
+                           q_lens, softmax_scale=None, q_tile=None,
+                           interpret=False):
     """Mixed prefill+decode attention over a packed ragged batch.
 
     q: [total_q, H, D] — sequence b's rows are
@@ -211,18 +339,19 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     block_tables: [B, max_pages] int32; ctx_lens: [B] int32 tokens stored
     per sequence INCLUDING the query tokens (may be traced); q_lens: [B]
     host ints — the packed layout is host metadata, like the block
-    tables' shape.  Returns [total_q, H, D].
+    tables' shape.  ``q_tile`` None lets :func:`pick_tiles` choose (every
+    sequence pads to a multiple of the tile the longest one picks).
+    Returns [total_q, H, D].
     """
     total_q, H, D = q.shape
     Hkv = k_pages.shape[1]
-    group = H // Hkv
     q_lens = [int(x) for x in np.asarray(q_lens).reshape(-1)]
     assert q_lens and min(q_lens) >= 1, f"bad q_lens {q_lens}"
     assert sum(q_lens) == total_q, \
         f"q has {total_q} rows but q_lens sums to {sum(q_lens)}"
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-    q_tile = int(min(q_tile, max(q_lens)))
-    starts, sot, qot, total_padded = _pack_metadata(q_lens, q_tile)
+    tiles = _pick_for(q_lens, q, k_pages, block_tables, q_tile)
+    starts, sot, qot, total_padded = _pack_metadata(q_lens, tiles.q_tile)
 
     # scatter each sequence's rows to its q_tile-aligned start (static
     # offsets: this is shape plumbing, not data-dependent control flow)
@@ -232,18 +361,18 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         qp = qp.at[int(starts[s]):int(starts[s]) + ql].set(q[off:off + ql])
         off += ql
 
-    out = _ragged_call(qp.reshape(total_padded, Hkv, group, D),
+    out = _ragged_call(_to_tiles(qp, len(sot), tiles.q_tile, Hkv),
                        k_pages, v_pages, block_tables, ctx_lens, q_lens,
-                       starts, sot, qot, q_tile, scale, interpret,
+                       sot, qot, tiles, scale, interpret,
                        KERNEL_DECODE if max(q_lens) == 1 else KERNEL_PREFILL)
-    out = out.reshape(total_padded, H, D)
+    out = _from_tiles(out, tiles.q_tile)
     return jnp.concatenate(
         [out[int(starts[s]):int(starts[s]) + ql]
          for s, ql in enumerate(q_lens)], axis=0)
 
 
 def ragged_paged_attention_rect(q, k_pages, v_pages, block_tables, lengths,
-                                softmax_scale=None, q_tile=DEFAULT_Q_TILE,
+                                softmax_scale=None, q_tile=None,
                                 interpret=False):
     """Rectangular front-end for the jitted serving path.
 
@@ -256,19 +385,18 @@ def ragged_paged_attention_rect(q, k_pages, v_pages, block_tables, lengths,
     """
     B, T, H, D = q.shape
     Hkv = k_pages.shape[1]
-    group = H // Hkv
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-    q_tile = int(min(q_tile, T))
-    n_qt = -(-T // q_tile)
-    Tp = n_qt * q_tile
+    tiles = _pick_for([T] * B, q, k_pages, block_tables, q_tile)
+    n_qt = -(-T // tiles.q_tile)
+    Tp = n_qt * tiles.q_tile
     if Tp != T:
         q = jnp.pad(q, ((0, 0), (0, Tp - T), (0, 0), (0, 0)))
-    starts = np.arange(B, dtype=np.int32) * Tp
     sot = np.repeat(np.arange(B, dtype=np.int32), n_qt)
     qot = np.tile(np.arange(n_qt, dtype=np.int32), B)
     q_lens = jnp.full((B,), T, jnp.int32)
-    out = _ragged_call(q.reshape(B * Tp, Hkv, group, D),
+    out = _ragged_call(_to_tiles(q.reshape(B * Tp, H, D), B * n_qt,
+                                 tiles.q_tile, Hkv),
                        k_pages, v_pages, block_tables, lengths, q_lens,
-                       starts, sot, qot, q_tile, scale, interpret,
+                       sot, qot, tiles, scale, interpret,
                        KERNEL_DECODE if T == 1 else KERNEL_PREFILL)
-    return out.reshape(B, Tp, H, D)[:, :T]
+    return _from_tiles(out, tiles.q_tile).reshape(B, Tp, H, D)[:, :T]

@@ -96,20 +96,45 @@ def test_flash_on_a_four_chip_mesh(topo):
             x, x, x)
 
 
-@pytest.mark.parametrize("page_size", [16, 128])
-@pytest.mark.parametrize("name,batch,q_len", [("decode", 8, 1),
-                                              ("prefill", 1, 128)])
-def test_ragged_paged_attention(topo, name, batch, q_len, page_size):
+# name, batch, q_len, heads, kv_heads, page_size, table width: the smoke
+# server's GQA widths at both page sizes, then the benchmark's serving
+# cells as the engine dispatches them (OLMo-2 1B: MHA 16 x 128, page 128,
+# ``max_seq`` 4096 -> 32 pages + the overrun column; chat decodes 32 slots,
+# docbatch 16; prefill buckets from 64 to 4096 tokens), the speculative
+# verify window, and a GQA prefill long enough for several q tiles
+RAGGED_SHAPES = [
+    ("decode_p16", 8, 1, 16, 4, 16, 128),
+    ("prefill_p16", 1, 128, 16, 4, 16, 128),
+    ("decode_p128", 8, 1, 16, 4, 128, 16),
+    ("prefill_p128", 1, 128, 16, 4, 128, 16),
+    ("chat_decode_b32", 32, 1, 16, 16, 128, 33),
+    ("docbatch_decode_b16", 16, 1, 16, 16, 128, 33),
+    ("prefill_64", 1, 64, 16, 16, 128, 33),
+    ("prefill_1024", 1, 1024, 16, 16, 128, 33),
+    ("prefill_4096", 1, 4096, 16, 16, 128, 33),
+    ("spec_verify_b32", 32, 5, 16, 16, 128, 33),
+    ("gqa_prefill_2048", 1, 2048, 32, 8, 128, 33),
+]
+
+
+@pytest.mark.parametrize("name,batch,q_len,heads,kv_heads,page_size,width",
+                         RAGGED_SHAPES, ids=[c[0] for c in RAGGED_SHAPES])
+def test_ragged_paged_attention(topo, name, batch, q_len, heads, kv_heads,
+                                page_size, width):
+    """The tile the picker chooses for each shape goes through Mosaic: a
+    lowering failure of a picked tile fails here, not on the chip."""
     chip = SingleDeviceSharding(topo.devices[0])
-    heads, kv_heads, head_dim = 16, 4, 128
-    max_pages = 2048 // page_size
-    pool = _on(chip, (batch * max_pages + 1, kv_heads, page_size, head_dim))
-    _compiled_text(
+    head_dim = 128
+    pool = _on(chip, (batch * (width - 1) + 1, kv_heads, page_size,
+                      head_dim))
+    text = _compiled_text(
         lambda q, k, v, tables, lengths: paged_decode_attention(
             q, PagedKVCache(k, v), tables, lengths, impl="pallas"),
         _on(chip, (batch, q_len, heads, head_dim)), pool, pool,
-        _on(chip, (batch, max_pages), jnp.int32),
+        _on(chip, (batch, width), jnp.int32),
         _on(chip, (batch,), jnp.int32))
+    assert ("ragged_paged_attention_decode" if q_len == 1
+            else "ragged_paged_attention_prefill") in text
 
 
 def test_paged_decode_step_llama_1b(topo):

@@ -162,9 +162,11 @@ def test_flash_runs_per_shard_on_a_mesh(mesh_1d):
 def test_phase_kernels(smoke):
     errors = smoke.phase_kernels(
         flash_shape=(1, 64, 2, 16), heads=(4, 2), head_dim=16, page_size=8,
-        decode_batch=2, max_pages=2, prefill_len=8, interpret=True)
+        decode_batch=2, max_pages=2, prefill_len=8, big_page=16,
+        big_prefill_len=24, interpret=True)
     assert set(errors) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dk",
-                           "flash_bwd_dv", "ragged_decode", "ragged_prefill"}
+                           "flash_bwd_dv", "ragged_decode", "ragged_prefill",
+                           "ragged_decode_p16", "ragged_prefill_p16"}
 
 
 def test_phase_train(smoke, counter, capsys):

@@ -19,7 +19,8 @@ from deepspeed_tpu.ops.paged_attention import (PagedAllocator, PagedKVCache,
                                                paged_decode_attention,
                                                resolve_attention_backend)
 from deepspeed_tpu.ops.pallas.ragged_paged_attention import (
-    ragged_paged_attention, ragged_paged_attention_rect)
+    VMEM_BUDGET, pick_tiles, ragged_paged_attention,
+    ragged_paged_attention_rect)
 
 H, HKV, D, PAGE = 4, 2, 8, 4
 NPAGES = 64
@@ -109,8 +110,9 @@ def test_prefix_cache_shared_pages_read_in_place():
 @pytest.mark.parametrize("T", [1, 5, 8, 12])
 def test_rect_front_end(T):
     """The rectangular wrapper (the jitted serving path's shape) must
-    match the oracle for decode (T=1), in-tile prefill, exact-tile, and
-    the Tp-padding path (T=12 > q_tile=8)."""
+    match the oracle for decode (T=1) and for prefills of a few rows,
+    with the tile the picker chooses (all T rows of a sequence, both kv
+    heads and every page of the table in one grid step at these sizes)."""
     B = 3
     ctx = [T + 3, T, T + 9]
     _, tables, kp, vp = _build_state(ctx)
@@ -122,6 +124,142 @@ def test_rect_front_end(T):
     want = paged_decode_attention(q, PagedKVCache(kp, vp), tables, lengths,
                                   impl="jnp")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+
+
+@pytest.mark.parametrize("q_tile", [3, 8])
+def test_rect_front_end_explicit_q_tile(q_tile):
+    """``q_tile=`` overrides the picker: T=12 splits into several tiles
+    of one sequence, the last one padded (12 = 4 x 3 = 8 + 4)."""
+    T, ctx = 12, [15, 12, 21]
+    _, tables, kp, vp = _build_state(ctx)
+    rng = np.random.default_rng(6)
+    q = jnp.asarray(rng.standard_normal((3, T, H, D)), jnp.float32)
+    lengths = jnp.asarray(ctx, jnp.int32)
+    got = ragged_paged_attention_rect(q, kp, vp, tables, lengths,
+                                     q_tile=q_tile, interpret=True)
+    want = paged_decode_attention(q, PagedKVCache(kp, vp), tables, lengths,
+                                  impl="jnp")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+
+
+# the shapes the serving path really makes: T=1 decode, the speculative
+# verify window (a few rows), chunked prefill (T = chunk over a cached
+# context); MHA and GQA; the tests' page of 8 and the default of 128; both
+# head sizes; bf16 (the served models) and float32 (the tier-1 tiny ones)
+SERVING_SHAPES = [
+    # T, group, page, D, cache dtype
+    (1, 1, 128, 128, jnp.bfloat16),
+    (1, 4, 8, 64, jnp.float32),
+    (1, 1, 8, 128, jnp.float32),
+    (1, 4, 128, 64, jnp.bfloat16),
+    (4, 1, 8, 64, jnp.bfloat16),
+    (4, 4, 128, 128, jnp.float32),
+    (4, 1, 128, 64, jnp.float32),
+    (4, 4, 8, 128, jnp.bfloat16),
+    (12, 1, 128, 128, jnp.float32),
+    (12, 4, 8, 64, jnp.bfloat16),
+    (12, 1, 8, 128, jnp.bfloat16),
+    (12, 4, 128, 64, jnp.float32),
+    (64, 1, 8, 64, jnp.float32),
+    (64, 4, 128, 128, jnp.bfloat16),
+    (64, 1, 128, 64, jnp.bfloat16),
+    (64, 4, 8, 128, jnp.float32),
+]
+
+
+@pytest.mark.parametrize(
+    "T,group,page,d,dtype", SERVING_SHAPES,
+    ids=[f"T{t}-g{g}-p{p}-d{d}-{jnp.dtype(dt).name}"
+         for t, g, p, d, dt in SERVING_SHAPES])
+def test_serving_shapes_match_oracle(T, group, page, d, dtype):
+    """One batch of four slots per shape, through the entry point the
+    jitted step calls: slot 0's context ends mid-page over a cached
+    prefix, slot 1 fills its last page exactly, slot 2 shares slot 0's
+    first pages (a prefix-cache attach), slot 3 is inactive (length 0,
+    its table row all scratch page 0).  Held to the float32 oracle: the
+    float32 cache to rounding, the bf16 cache to ``chip_smoke.py``'s
+    kernel tolerance."""
+    hkv = 2
+    ctx = [T + page + 3, -(-(T + 5) // page) * page, T + 2 * page + 1, T]
+    width = max(-(-c // page) for c in ctx) + 1    # + the overrun column
+    tables = np.zeros((4, width), np.int32)
+    next_page = 1
+    for s in range(3):
+        n = -(-ctx[s] // page)
+        tables[s, :n] = np.arange(next_page, next_page + n)
+        next_page += n
+    tables[2, :1] = tables[0, :1]                  # shared, not copied
+    rng = np.random.default_rng(7)
+    pool = PagedKVCache(*(jnp.asarray(
+        rng.standard_normal((next_page, hkv, page, d)), dtype)
+        for _ in range(2)))
+    q = jnp.asarray(rng.standard_normal((4, T, hkv * group, d)), dtype)
+    tables, lengths = jnp.asarray(tables), jnp.asarray(ctx, jnp.int32)
+    got = paged_decode_attention(q, pool, tables, lengths,
+                                 backend="pallas-interpret")
+    assert got.dtype == q.dtype
+    f32 = lambda t: jax.tree_util.tree_map(      # noqa: E731
+        lambda x: x.astype(jnp.float32), t)
+    want = np.asarray(paged_decode_attention(f32(q), f32(pool), tables,
+                                             lengths, impl="jnp"))
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    err = np.max(np.abs(np.asarray(got, np.float32) - want)) \
+        / max(1.0, np.max(np.abs(want)))
+    assert err <= tol, err
+
+
+# -- the tile picker ---------------------------------------------------------
+
+# B, T, group, Hkv, page, D, table width, itemsize
+PICKER_SHAPES = [
+    ("chat_decode", 32, 1, 1, 16, 128, 128, 33, 2),
+    ("docbatch_decode", 16, 1, 1, 16, 128, 128, 33, 2),
+    ("docbatch_prefill", 1, 4096, 1, 16, 128, 128, 33, 2),
+    ("chat_prefill", 1, 256, 1, 16, 128, 128, 33, 2),
+    ("spec_verify", 32, 5, 1, 16, 128, 128, 33, 2),
+    ("gqa_decode_page16", 8, 1, 4, 4, 16, 128, 129, 2),
+    ("gqa_prefill_page16", 1, 128, 4, 4, 16, 128, 129, 2),
+    ("tiny_float32_page8", 4, 1, 2, 2, 8, 16, 9, 4),
+    ("bf16_page8", 8, 1, 4, 4, 8, 128, 65, 2),
+    ("d64_prefill", 1, 512, 1, 12, 128, 64, 17, 2),
+]
+
+
+@pytest.mark.parametrize("name,B,T,group,hkv,page,d,width,itemsize",
+                         PICKER_SHAPES, ids=[c[0] for c in PICKER_SHAPES])
+def test_pick_tiles(name, B, T, group, hkv, page, d, width, itemsize):
+    """The choice is a pure function of the call's shapes: it fits the
+    VMEM budget it states, whole kv heads divide evenly, the grid covers
+    every query row and every table column, a page that does not fill a
+    sublane tile of its dtype rides alone — and for the two serving
+    cells' shapes the grid is at least 16 times smaller than the fixed
+    8-row x 1-head x 1-page step's."""
+    tiles = pick_tiles([T] * B, group, hkv, page, d, width, itemsize)
+    assert tiles == pick_tiles([T] * B, group, hkv, page, d, width, itemsize)
+    assert tiles.vmem_bytes <= VMEM_BUDGET
+    assert 1 <= tiles.q_tile <= T and hkv % tiles.heads == 0
+    n_qt = -(-T // tiles.q_tile)
+    assert tiles.grid == (B * n_qt, hkv // tiles.heads,
+                          -(-width // tiles.pages))
+    assert tiles.grid_steps == tiles.grid[0] * tiles.grid[1] * tiles.grid[2]
+    assert tiles.grid[2] * tiles.pages >= width
+    if page % (8 * 4 // itemsize):
+        assert tiles.pages == 1
+    if T == 1:
+        assert tiles.heads == hkv                  # a whole page a step
+    fixed_step_grid = B * -(-T // min(8, T)) * hkv * width
+    if name in ("chat_decode", "docbatch_prefill"):
+        assert tiles.grid_steps * 16 <= fixed_step_grid
+    else:
+        assert tiles.grid_steps <= fixed_step_grid
+
+
+def test_pick_tiles_explicit_q_tile_and_mixed_lengths():
+    tiles = pick_tiles([256, 1], 1, 2, 128, 64, 17, 2, q_tile=8)
+    assert tiles.q_tile == 8 and tiles.grid[0] == 32 + 1
+    # the longest sequence picks the tile; every sequence pads to it
+    tiles = pick_tiles([256, 1], 1, 2, 128, 64, 17, 2)
+    assert tiles.q_tile == 256 and tiles.grid[0] == 2
 
 
 def test_backend_selected_entry_point():
@@ -197,6 +335,36 @@ def test_serving_bit_identical_across_backends(tiny):
         return out
 
     assert run("jnp") == run("pallas-interpret")
+
+
+def test_kernel_grid_in_step_report(tiny):
+    """Every dispatch of ``engine.last_step`` carries ``kernel_grid``: the
+    picked grid's steps over all layers for the compiled shape, 0 where
+    the jnp path serves."""
+    from deepspeed_tpu.inference.serving import ServingEngine
+    cfg, model, params = tiny
+
+    def dispatches(backend):
+        eng = ServingEngine(model, params, max_batch=4, page_size=8,
+                            max_seq=64, dtype=jnp.float32,
+                            serving={"attention_backend": backend})
+        eng.add_request("r0", list(range(1, 10)), max_new_tokens=3)
+        while eng.queue or eng.n_active:
+            eng.step()
+        return eng, [d for rep in eng.step_reports()
+                     for d in rep["dispatches"]]
+
+    eng, got = dispatches("pallas-interpret")
+    assert {d["phase"] for d in got} == {"prefill", "decode"}
+    width = eng.tables.shape[1]
+    for d in got:
+        want = pick_tiles([d["tokens"]] * d["batch"],
+                          cfg.n_heads // cfg.kv_heads, cfg.kv_heads, 8,
+                          cfg.head_dim, width, 4).grid_steps * cfg.n_layers
+        assert d["kernel_grid"] == want > 0
+    assert eng.last_step["dispatches"][-1]["kernel_grid"] > 0
+    _, got = dispatches("jnp")
+    assert got and all(d["kernel_grid"] == 0 for d in got)
 
 
 def test_bad_backend_rejected_at_construction(tiny):
