@@ -92,3 +92,4 @@ class Run:
     counters: dict
     memory_peak_bytes: int
     trace: object = None       # chipbench.reduce.Trace of a traced run
+    config: dict = None        # the cell's configuration file, as loaded

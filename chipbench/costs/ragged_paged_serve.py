@@ -1,0 +1,10 @@
+"""Least seconds of the ragged paged-attention kernel over the traced
+dispatches (the records ``serve_cell.Probe`` keeps), all layers."""
+
+from chipbench import roofline
+
+
+def least_seconds(run):
+    return roofline.ragged_paged_serve_seconds(
+        run.model, [d for s in run.traced_steps for d in s["dispatches"]],
+        run.peaks)

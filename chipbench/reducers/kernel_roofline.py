@@ -1,8 +1,13 @@
 """A Pallas kernel's share of its roofline: the least seconds the chip
-could take for the traced calls (``chipbench/roofline.py``, from shapes
-and the published peaks) over the kernel's seconds in the device trace."""
+could take for the traced calls over the kernel's seconds in the device
+trace.  The least seconds are ``chipbench/costs/<cost>.py``:
+``least_seconds(run)``, from shapes and the published peaks
+(``chipbench/roofline.py`` holds the arithmetic of the kernels there are),
+so a new kernel brings its operations and bytes as a file of its own."""
 
-from chipbench import reduce, roofline
+import importlib
+
+from chipbench import reduce
 
 
 def read(run, cost):
@@ -11,16 +16,11 @@ def read(run, cost):
     kernel_s = sum(reduce.op_seconds(run.trace, "pallas").values())
     if not kernel_s:
         return None
-    if cost == "flash_attention_train":
-        m = run.model
-        per_step = reduce.op_count(run.trace, "pallas") / len(run.traced_steps)
-        calls = max(3, round(per_step / (m["gas"] * m["n_layers"])))
-        least = roofline.flash_attention_train_seconds(
-            m, calls, len(run.traced_steps), run.peaks)
-    elif cost == "ragged_paged_serve":
-        least = roofline.ragged_paged_serve_seconds(
-            run.model, [d for s in run.traced_steps
-                        for d in s["dispatches"]], run.peaks)
-    else:
-        raise ValueError(f"unknown cost function {cost!r}")
-    return 100.0 * least / kernel_s
+    try:
+        costs = importlib.import_module("chipbench.costs." + cost)
+    except ModuleNotFoundError as e:
+        if e.name != "chipbench.costs." + cost:
+            raise       # the cost file is there; something it imports is not
+        raise ValueError(f"unknown cost function {cost!r}: no "
+                         f"chipbench/costs/{cost}.py") from e
+    return 100.0 * costs.least_seconds(run) / kernel_s

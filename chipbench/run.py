@@ -72,6 +72,15 @@ def main(argv=None, require_tpu=True):
             for name, value in result["end_to_end"].items()
             if name in units and value is not None}
     print(json.dumps(line), flush=True)
+    # each number compared beside its limit, as the last lines of standard
+    # error (the log lines above go to standard output): of a run that is
+    # not correct the driver's record keeps the end of this, and of the
+    # output the last line's keys alone.  A runner that hands back no
+    # ``compared`` prints the verdict alone.
+    for name, value, limit in result.get("compared", ()):
+        print(f"chipbench: {name} {value} (limit {limit})", file=sys.stderr)
+    print(f"chipbench: correct {result['correct']}", file=sys.stderr,
+          flush=True)
 
 
 if __name__ == "__main__":

@@ -29,8 +29,47 @@ from chipbench import cells, device, sut, traffic, tracing
 # rounding); over 16 layers of residual adds the rows land 1-2 % off on the
 # chip (PERF.md §6).  4 % passes that and fails a cache that drops or
 # misplaces a page (errors of the order of the logits themselves) or 8-bit
-# weights.
+# weights.  Every compared row is held to it, whatever the family.
+#
+# A family that routes tokens to experts (its file says ``ROUTED = True``)
+# may hand back, beside the logits, which rows its own float32 routing
+# leaves decided (chipbench/README.md, "A family that routes").  A top-k is
+# discontinuous: where a token's last chosen and first unchosen selection
+# scores lie closer than the program's rounding, program and reference may
+# each pick another expert and the row's hidden state moves by a whole
+# expert's output, several times the tolerance, with neither side wrong.
+# Such a row is undecided at the stated precision and is left out; it is
+# the reference alone that says so, from its own scores, never from
+# anything the program put out.
+#
+# The harness takes whatever mask the reference hands it; the least rule,
+# which asks the ROW'S OWN token alone, rests on a reckoning that is
+# UNVERIFIED AT ANY SIZE.  A flip at an earlier token of the context
+# reaches the row through attention alone: that token's keys and values in
+# the later layers move by the swapped expert's share of its hidden state
+# (for 8 experts a token of 256, one weight of about 1/8 of the routed
+# output: several percent, say 5 %), and the row reads it through a
+# softmax over T keys that random weights leave near uniform, so the row's
+# attention output moves by about 5 % / T: 0.05 % at T = 100, 0.005 % at
+# 1024, against the 4 % above and the 1-2 % that bf16 alone gives.  If so,
+# a context flip stays inside the tolerance and needs no mask.  The only
+# experiment there is contradicts it, at toy size: one expert of four a
+# token, contexts of 100, served in bf16 on the CPU, decided rows read up
+# to 0.6 after a flip in their context (tests/chipbench/test_routed.py).
+# There the reference masks the rows after an undecided token as well,
+# every row it leaves then reads under 0.02, and so few are left that the
+# floor below fails the run: such a family cannot be judged by this check
+# at those lengths.  A sharply peaked attention on a flipped token would
+# break the reckoning too.  Either shows as a failing DECIDED row: the cure
+# is then a wider rule in the reference (the same pair carries it), never
+# a wider tolerance; whoever adds the first routed cell reads both rules
+# on the chip first (chipbench/README.md).
+#
+# A check that compares nothing is no check: ``correct`` is also false
+# when fewer than MIN_COMPARED_SHARE of all rows were compared, or no row
+# of some prompt.
 LOGIT_TOL = 0.04
+MIN_COMPARED_SHARE = 0.5
 CHECK_PROMPTS = 3
 CHECK_DECODE_TOKENS = 24
 TRACE_SECONDS = 6.0
@@ -81,31 +120,78 @@ class Probe:
         return self._sample(req, row)
 
 
-def _check_against_reference(cell, engine, probe, params, seed):
-    """Prefill + decode through the paged cache against the reference's
-    full forward pass, on logits.  Returns the largest error."""
+def _reference_rows(cell, params, ids, last):
+    """The reference's logits rows ``[last, vocab]`` of one prompt and
+    which of them its routing leaves decided ``[last]`` (all, unless the
+    family routes and its reference says otherwise)."""
+    out = cell.reference.logits(params, jnp.asarray(ids), cell.config,
+                                last=last)
+    if not isinstance(out, tuple):
+        return np.asarray(out)[0], np.ones(last, bool)
+    if not getattr(cell.family, "ROUTED", False):
+        raise TypeError(
+            f"chipbench/reference/{cell.family.REFERENCE}.py returned "
+            f"(logits, decided), but chipbench/families/"
+            f"{cell.config['family']}.py does not declare ROUTED = True")
+    want, decided = out
+    decided = np.asarray(decided)
+    if decided.dtype != bool or decided.shape != (1, last):
+        raise TypeError(f"decided must be a boolean [1, {last}], got "
+                        f"{decided.dtype} {decided.shape}")
+    return np.asarray(want)[0], decided[0]
+
+
+def _serve_check_prompts(cell, engine, probe, seed):
+    """The check's prompts (quantiles of the mix's lengths, tokens from
+    the seed) prefilled and decoded through the paged cache: a request's
+    ids ``[1, S]`` and the logits rows the program sampled from."""
     lengths = traffic.quantile_grid(cell.mix["prompt_tokens"], CHECK_PROMPTS)
     vocab = cell.config["vocab_size"]
-    prompts = {}
+    rids = [f"check-{i}" for i in range(len(lengths))]
     done = {}
-    for i, n in enumerate(lengths):
-        rid = f"check-{i}"
-        prompts[rid] = traffic.rng_for(seed, 5, i).integers(
-            0, vocab, int(n), dtype=np.int32)
+    for i, (rid, n) in enumerate(zip(rids, lengths)):
         probe.keep_logits[rid] = []
-        engine.add_request(rid, prompts[rid],
-                           max_new_tokens=CHECK_DECODE_TOKENS)
-    while len(done) < len(prompts):
+        engine.add_request(rid, traffic.rng_for(seed, 5, i).integers(
+            0, vocab, int(n), dtype=np.int32),
+            max_new_tokens=CHECK_DECODE_TOKENS)
+    while len(done) < len(rids):
         done.update(engine.step())
-    worst = 0.0
-    for rid, prompt in prompts.items():
-        rows = np.stack(probe.keep_logits.pop(rid))
-        ids = np.asarray(done[rid], np.int32)[None, :-1]
-        want = np.asarray(cell.reference.logits(
-            params, jnp.asarray(ids), cell.config, last=len(rows)))[0]
+    return [(np.asarray(done[rid], np.int32)[None, :-1],
+             np.stack(probe.keep_logits.pop(rid))) for rid in rids]
+
+
+def _compare_with_reference(cell, served, params):
+    """What ``_serve_check_prompts`` served against the reference's full
+    forward pass on ``params``, on logits: the largest error over the rows
+    the reference's routing leaves decided, how many rows those are, and
+    whether that is a check (``ok``)."""
+    errors = {True: [], False: []}      # decided? -> each prompt's largest
+    rows_total = rows_compared = 0
+    every_prompt = True
+    for ids, rows in served:
+        want, decided = _reference_rows(cell, params, ids, len(rows))
         scale = max(1.0, float(np.max(np.abs(want))))
-        worst = max(worst, float(np.max(np.abs(rows - want))) / scale)
-    return worst
+        for kind in (True, False):
+            if np.any(decided == kind):
+                errors[kind].append(float(np.max(
+                    np.abs(rows - want)[decided == kind])) / scale)
+        rows_total += len(rows)
+        rows_compared += int(decided.sum())
+        every_prompt = every_prompt and bool(decided.any())
+    # np.max, unlike max(), keeps a NaN: a row that is not a number fails
+    worst = {kind: float(np.max(e)) if e else None
+             for kind, e in errors.items()}
+    ok = (every_prompt and worst[True] <= LOGIT_TOL
+          and rows_compared >= MIN_COMPARED_SHARE * rows_total)
+    return {"ok": bool(ok), "logit_error": worst[True],
+            "logit_error_undecided": worst[False],
+            "rows_compared": rows_compared,
+            "rows_undecided": rows_total - rows_compared}
+
+
+def _check_against_reference(cell, engine, probe, params, seed):
+    return _compare_with_reference(
+        cell, _serve_check_prompts(cell, engine, probe, seed), params)
 
 
 def _warm_up(cell, engine, seed):
@@ -222,6 +308,17 @@ def _serve_traffic(mix, stream, engine, probe, seed, seconds, tracer,
     return out
 
 
+def _dense_sizes(cfg, engine_cfg):
+    """``Run.model`` of a family that brings no ``model_sizes`` of its own:
+    plain multi-head or grouped attention, heads of hidden / heads, bf16
+    pages."""
+    return {"n_layers": cfg["num_hidden_layers"],
+            "heads": cfg["num_attention_heads"],
+            "kv_heads": cfg["num_key_value_heads"],
+            "head_dim": cfg["hidden_size"] // cfg["num_attention_heads"],
+            "page_size": engine_cfg["page_size"], "kv_bytes": 2}
+
+
 def _ms(values, q):
     return float(np.percentile(values, q)) * 1000.0 if values else None
 
@@ -241,7 +338,7 @@ def run(cell, seed, seconds, trace, started, devices, peaks):
     probe = Probe(engine, clock)
 
     t0 = clock()
-    logit_error = _check_against_reference(cell, engine, probe, params, seed)
+    check = _check_against_reference(cell, engine, probe, params, seed)
     reference_s = clock() - t0
     _warm_up(cell, engine, seed)
     engine.pop_terminated()
@@ -293,13 +390,13 @@ def run(cell, seed, seconds, trace, started, devices, peaks):
         end_to_end["tpot_p95_ms"] = _ms(gaps, 95)
     else:
         end_to_end["serve_tok_s"] = tokens / busy if busy else None
-    correct = (logit_error <= LOGIT_TOL and not lost and not leaks
+    correct = (check.pop("ok") and not lost and not leaks
                and not failed and bool(steps))
 
     device.log(
         iterations=len(steps), requests_in_window=len(in_window),
         failed=len(failed), lost=lost, leaks=leaks,
-        logit_error=logit_error, logit_tol=LOGIT_TOL,
+        **check, logit_tol=LOGIT_TOL,
         reference_s=round(reference_s, 2), n_params=n_params,
         tokens_in_window=tokens,
         generated_in_window=sum(s["generated"] for s in steps),
@@ -319,14 +416,11 @@ def run(cell, seed, seconds, trace, started, devices, peaks):
         cache_hits=counter.cache_hits, compiles_total=counter.compiles)
 
     report = device.device_report(devices)
-    engine_cfg = cfg["serve"]["engine"]
+    sizes = getattr(cell.family, "model_sizes", _dense_sizes)(
+        cfg, cfg["serve"]["engine"])
     run_record = cells.Run(
-        chips=len(devices), peaks=peaks,
-        model={"n_params": n_params, "n_layers": cfg["num_hidden_layers"],
-               "heads": cfg["num_attention_heads"],
-               "kv_heads": cfg["num_key_value_heads"],
-               "head_dim": cfg["hidden_size"] // cfg["num_attention_heads"],
-               "page_size": engine_cfg["page_size"], "kv_bytes": 2},
+        chips=len(devices), peaks=peaks, config=cfg,
+        model={"n_params": n_params, **sizes},
         steps=steps, traced_steps=[s for s in steps if s["traced"]],
         samples={"queue_wait_ms": [w * 1000.0 for w in queue_wait],
                  "ttft_ms": [t * 1000.0 for t in ttft]},
@@ -334,4 +428,10 @@ def run(cell, seed, seconds, trace, started, devices, peaks):
         memory_peak_bytes=report["memory_peak_bytes"], trace=tracer.trace())
     return {"correct": bool(correct), "attempted": len(in_window),
             "failed": len(failed), "end_to_end": end_to_end,
-            "run": run_record, "device": report}
+            "run": run_record, "device": report,
+            "compared": [("logit_error", check["logit_error"], LOGIT_TOL),
+                         ("rows_compared", check["rows_compared"],
+                          f">= {MIN_COMPARED_SHARE} of "
+                          f"{check['rows_compared'] + check['rows_undecided']}"),
+                         ("lost", len(lost), 0), ("leaks", len(leaks), 0),
+                         ("failed", len(failed), 0)]}

@@ -129,7 +129,7 @@ def run(cell, seed, seconds, trace, started, devices, peaks):
 
     report = device.device_report(devices)
     run_record = cells.Run(
-        chips=chips, peaks=peaks,
+        chips=chips, peaks=peaks, config=cfg,
         model={"n_params": n_params, "n_layers": cfg["num_hidden_layers"],
                "heads": cfg["num_attention_heads"],
                "head_dim": cfg["hidden_size"] // cfg["num_attention_heads"],
@@ -140,4 +140,7 @@ def run(cell, seed, seconds, trace, started, devices, peaks):
     end_to_end = {"train_tok_s_chip": tok_s_chip, "setup_s": setup_s}
     return {"correct": bool(correct), "attempted": len(steps),
             "failed": 0 if finite else len(steps),
-            "end_to_end": end_to_end, "run": run_record, "device": report}
+            "end_to_end": end_to_end, "run": run_record, "device": report,
+            "compared": [("loss_rel_error", loss_error, loss_rtol),
+                         ("last_loss", losses[-1],
+                          f"< first_loss {warm_losses[0]}")]}

@@ -13,6 +13,7 @@ from deepspeed_tpu.models.transformer import (CausalTransformerLM,
                                               TransformerConfig)
 
 from chipbench import cells
+import tree
 from tree import DATA, REPO
 
 # Both sides in float32 on the CPU: they differ by rounding order alone,
@@ -43,6 +44,21 @@ def test_reference_matches_program(config_name):
     assert float(jnp.max(jnp.abs(ours - want))) < TOLERANCE[config_name]
     last = cell.reference.logits(params, ids, config, last=5)
     assert jnp.allclose(last, want[:, -5:], atol=1e-5)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 2 ** 31 + 3])
+def test_control_in_8_bit_floats_fails_the_serving_tolerance(seed):
+    """``chipbench/control.py`` at toy width: the program serving weights
+    rounded to float8 through the check's own path reads several times
+    ``LOGIT_TOL`` against the reference on the seeded weights, where the
+    program on those reads about 0.01: the comparison tells the two
+    precisions apart (the chip's readings at OLMo-2 1B: PERF.md §4)."""
+    from chipbench import control, serve_cell
+    cell = cells.Cell("tiny-doc", 1, tree.data("tiny-olmo2"),
+                      tree.data("tiny-closed"), [], [])
+    check = control.control_error(cell, seed, jax.devices()[:1])
+    assert check["logit_error"] > 2 * serve_cell.LOGIT_TOL
+    assert check["rows_compared"] == 72 and not check["ok"]
 
 
 def test_references_do_not_import_the_program():

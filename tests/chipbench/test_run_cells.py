@@ -34,6 +34,7 @@ def _check_line(line, metrics):
     ("tiny-train", {"train_tok_s_chip", "setup_s"}),
     ("tiny-chat", {"tpot_p90_ms", "setup_s"}),
     ("tiny-doc", {"serve_tok_s", "setup_s"}),
+    ("tiny-routed", {"serve_tok_s", "setup_s"}),    # a family that routes
 ])
 def test_one_run_of_each_traffic_kind(checkout, cell, metrics):
     line, earlier = tree.run(checkout, cell, seed=2 ** 31 + 5)
@@ -47,6 +48,10 @@ def test_one_run_of_each_traffic_kind(checkout, cell, metrics):
     else:
         assert log["logit_error"] <= log["logit_tol"]
         assert log["lost"] == [] and log["leaks"] == {}
+        # every row of the check is compared, but what a routed family's
+        # reference finds undecided (about one row in 500 of this one's)
+        assert log["rows_compared"] + log["rows_undecided"] == 72
+        assert log["rows_undecided"] <= (2 if cell == "tiny-routed" else 0)
 
 
 def test_traced_run_reads_the_made_up_metrics(checkout):
@@ -72,6 +77,48 @@ def test_traced_serving_run_reads_samples(checkout):
     assert metrics["tiny-chat.step_ms.chat"]["value"] > 0
     assert metrics["tiny-chat.ttft_p90_ms"]["value"] == pytest.approx(
         earlier[-1]["ttft_ms"]["90"])
+
+
+def test_routed_cell_brings_its_sizes_and_kernel_cost(checkout):
+    """A family's ``model_sizes``, the configuration's published widths
+    and a kernel cost that is a file of its own, read by a made-up
+    per-layer metric; and the numbers compared, beside their limits, as
+    the last lines of standard error."""
+    line, _, stderr = tree.run(checkout, "tiny-routed", trace=1,
+                               want_stderr=True)
+    assert line["correct"] is True
+    # 2 layers x the larger of flops over peak and weight bytes over peak
+    assert line["metrics"]["tiny-routed.expert_least_us"]["value"] > 0
+    assert "tiny-routed.step_ms.docbatch" in line["metrics"]
+    last = stderr.strip().splitlines()[-6:]
+    assert last[0].startswith("chipbench: logit_error ")
+    assert last[0].endswith("(limit 0.04)")
+    assert last[1].startswith("chipbench: rows_compared 7")
+    assert last[-1] == "chipbench: correct True"
+
+
+BROKEN = """
+import deepspeed_tpu
+_init = deepspeed_tpu.init_inference
+def init_inference(model, params, dtype):
+    # the program serves a head whose rows are in another order
+    return _init(model=model, dtype=dtype,
+                 params=dict(params, lm_head=params["lm_head"][::-1]))
+deepspeed_tpu.init_inference = init_inference
+"""
+
+
+def test_a_broken_program_is_not_correct(checkout):
+    """The rest of a run, with the program broken underneath: it serves,
+    loses and leaks nothing, and ``correct`` comes out false."""
+    line, earlier, stderr = tree.run(
+        checkout, "tiny-doc", want_stderr=True,
+        prelude=f"exec({BROKEN!r})")
+    log = earlier[-1]
+    assert line["correct"] is False and line["failed"] == 0
+    assert log["logit_error"] > log["logit_tol"]
+    assert log["lost"] == [] and log["leaks"] == {}
+    assert stderr.strip().endswith("chipbench: correct False")
 
 
 def _command(cwd, workload, **env):

@@ -18,7 +18,18 @@ CELLS = [   # name, configuration, traffic, a real cell whose metrics it takes
     ("tiny-train", "tiny-neox", "tiny-pretrain", "train-pythia-1.4b-s2048"),
     ("tiny-chat", "tiny-olmo2", "tiny-open", "serve-olmo2-1b-chat"),
     ("tiny-doc", "tiny-olmo2", "tiny-closed", "serve-olmo2-1b-docbatch"),
+    # a family that routes tokens to experts, with its reference
+    ("tiny-routed", "tiny-routed", "tiny-closed", "serve-olmo2-1b-docbatch"),
 ]
+
+# code a later PR might bring, as files of its own: a family with its
+# reference, the operations of a kernel, a reducer (data/ -> chipbench/)
+NEW_CODE = {
+    "routed/family.py": "families/toy_routed.py",
+    "routed/reference.py": "reference/toy_routed.py",
+    "routed/expert_cost.py": "costs/made_up_experts.py",
+    "routed/least_us.py": "reducers/made_up_least_us.py",
+}
 
 MADE_UP_REDUCER = '''"""A reducer a later PR might bring: steps in the window."""
 
@@ -36,7 +47,10 @@ def make(tmp):
     before = _listing(tmp)
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    for config in ("tiny-neox", "tiny-olmo2"):
+    for source, target in NEW_CODE.items():
+        shutil.copy(os.path.join(DATA, source),
+                    os.path.join(tmp, "chipbench", target))
+    for config in ("tiny-neox", "tiny-olmo2", "tiny-routed"):
         shutil.copy(os.path.join(DATA, config + ".json"),
                     os.path.join(tmp, "chipbench", "configs"))
         bench["configs"].append({
@@ -77,6 +91,13 @@ def make(tmp):
         "better": "lower", "source": "host_clock", "moves": "tpot_p90_ms",
         "workloads": ["tiny-chat"], "reducer": "sample_percentile",
         "args": {"sample": "ttft_ms", "q": 90}}
+    # ... and one that reads the family's own sizes (``model_sizes``) and
+    # the configuration's published ones through a kernel cost of its own
+    added["tiny-routed.expert_least_us"] = {
+        "name": "tiny-routed.expert_least_us", "layer": "made up",
+        "unit": "us", "better": "lower", "source": "program_counter",
+        "moves": "serve_tok_s", "workloads": ["tiny-routed"],
+        "reducer": "made_up_least_us", "args": {"cost": "made_up_experts"}}
     with open(os.path.join(tmp, "chipbench", "reducers",
                            "made_up_steps.py"), "w") as f:
         f.write(MADE_UP_REDUCER)
@@ -100,6 +121,11 @@ def _json(*parts):
         return json.load(f)
 
 
+def data(name):
+    """A toy configuration or traffic mix of ``data/``."""
+    return _json(DATA, name + ".json")
+
+
 def _listing(tmp):
     out = {}
     for folder, _, files in os.walk(os.path.join(tmp, "chipbench")):
@@ -110,14 +136,16 @@ def _listing(tmp):
     return out
 
 
-def run(tmp, workload, seed=1, seconds=2, trace=0, timeout=240):
+def run(tmp, workload, seed=1, seconds=2, trace=0, timeout=240,
+        want_stderr=False, prelude="pass"):
     """The command's ``main`` in a process of its own, allowed onto the
     CPU by the tests' own switch (``require_tpu=False``): the command line
-    has no such option."""
+    has no such option.  ``prelude`` is code run first (a test's way to
+    break the program underneath)."""
     argv = ["--workload", workload, "--seed", str(seed), "--seconds",
             str(seconds), "--trace", str(trace)]
     code = (f"import sys; sys.path.insert(0, {tmp!r}); "
-            f"from chipbench import run; "
+            f"from chipbench import run; {prelude}; "
             f"run.main({argv!r}, require_tpu=False)")
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
                XLA_FLAGS="")
@@ -125,5 +153,6 @@ def run(tmp, workload, seed=1, seconds=2, trace=0, timeout=240):
                           capture_output=True, text=True, timeout=timeout)
     assert done.returncode == 0, done.stderr[-3000:]
     lines = [line for line in done.stdout.splitlines() if line.strip()]
-    return json.loads(lines[-1]), [json.loads(line) for line in lines
-                                   if line.startswith("{")][:-1]
+    out = (json.loads(lines[-1]), [json.loads(line) for line in lines
+                                   if line.startswith("{")][:-1])
+    return out + (done.stderr,) if want_stderr else out
