@@ -50,7 +50,6 @@ def control_error(cell, seed, devices):
     The engine and the rounded weights are freed before the reference
     runs, so the control peaks no higher than a run of the cell."""
     import gc
-    import time
     import jax
     import deepspeed_tpu
     from chipbench import serve_cell, sut
@@ -65,12 +64,8 @@ def control_error(cell, seed, devices):
     engine = deepspeed_tpu.init_inference(
         model=model, params=low, dtype=dtype).create_serving_engine(
         max_batch=int(cell.mix["max_batch"]), **cfg["serve"]["engine"])
-    probe = serve_cell.Probe(engine, time.perf_counter)
-    try:
-        served = serve_cell._serve_check_prompts(cell, engine, probe, seed)
-    finally:
-        probe.close()
-    del engine, probe, low
+    served = serve_cell._serve_check_prompts(cell, engine, seed)
+    del engine, low
     gc.collect()
     sound = sut.seeded_weights(model, seed, sut.DTYPES[dtype], devices)
     return serve_cell._compare_with_reference(cell, served, sound)

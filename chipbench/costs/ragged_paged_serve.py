@@ -1,5 +1,5 @@
 """Least seconds of the ragged paged-attention kernel over the traced
-dispatches (the records ``serve_cell.Probe`` keeps), all layers."""
+dispatches (the ``dispatches`` of the engine's step reports), all layers."""
 
 from chipbench import roofline
 

@@ -163,16 +163,23 @@ def _length(start, end):
     return float(np.sum(end - start))
 
 
-def busy_seconds(trace, kind=None):
-    """Seconds in which an operation (of ``kind``, if given) ran on the
-    device inside the window: the union of the intervals, averaged over
-    the devices."""
+def _selected(trace, kind, name):
+    """Which of the trace's labels are operations of ``kind`` whose name
+    on the device's line begins with ``name`` (either may be None: any)."""
+    return np.asarray([(kind is None or k == kind)
+                       and (name is None or label.startswith(name))
+                       for label, k in zip(trace.labels, trace.kinds)], bool)
+
+
+def busy_seconds(trace, kind=None, name=None):
+    """Seconds in which an operation (of ``kind``, and named ``name...``,
+    if given) ran on the device inside the window: the union of the
+    intervals, averaged over the devices."""
     per_device = []
+    selected = _selected(trace, kind, name)
     for line in trace.ops:
-        mask = None if kind is None else \
-            np.asarray(trace.kinds)[line.label] == kind
-        per_device.append(_length(*_union(*_clipped(line, trace.window,
-                                                    mask))))
+        per_device.append(_length(*_union(*_clipped(
+            line, trace.window, selected[line.label]))))
     return float(np.mean(per_device)) / 1e9 if per_device else 0.0
 
 
@@ -181,12 +188,13 @@ def window_seconds(trace):
     return (hi - lo) / 1e9
 
 
-def op_seconds(trace, kind=None):
+def op_seconds(trace, kind=None, name=None):
     """Summed durations of the window's operations by label, averaged over
-    the devices: {label: seconds}.  Loops and calls are left out: their
-    bodies' operations are events of their own."""
+    the devices: {label: seconds}, of ``kind`` and named ``name...`` if
+    given.  Loops and calls are left out: their bodies' operations are
+    events of their own."""
     totals = np.zeros(len(trace.labels))
-    kinds = np.asarray(trace.kinds)
+    selected = _selected(trace, kind, name)
     for line in trace.ops:
         start, end = line.start, line.start + line.dur
         lo, hi = trace.window
@@ -194,19 +202,19 @@ def op_seconds(trace, kind=None):
         np.add.at(totals, line.label, np.maximum(length, 0))
     totals /= max(1, len(trace.ops)) * 1e9
     out = {}    # two programs may each have a "fusion.3": one label, summed
-    for label, t, k in zip(trace.labels, totals, kinds):
-        if t > 0 and (kind is None or k == kind) and \
+    for label, t, wanted in zip(trace.labels, totals, selected):
+        if t > 0 and wanted and \
                 label.rpartition(":")[2] not in CONTAINERS:
             out[label] = out.get(label, 0.0) + float(t)
     return out
 
 
-def op_count(trace, kind):
-    """Executions of operations of ``kind`` in the window, averaged over
-    the devices."""
-    kinds = np.asarray(trace.kinds)
+def op_count(trace, kind, name=None):
+    """Executions of operations of ``kind`` (named ``name...``, if given)
+    in the window, averaged over the devices."""
+    selected = _selected(trace, kind, name)
     lo, hi = trace.window
-    counts = [int(np.sum((kinds[line.label] == kind) & (line.start >= lo)
+    counts = [int(np.sum(selected[line.label] & (line.start >= lo)
                          & (line.start < hi))) for line in trace.ops]
     return float(np.mean(counts)) if counts else 0.0
 

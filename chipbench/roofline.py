@@ -62,8 +62,8 @@ def ragged_paged_dispatch(new_tokens, contexts, model):
 
 
 def ragged_paged_serve_seconds(model, dispatches, peaks):
-    """Least seconds of the ragged kernel over ``dispatches`` (the records
-    ``serve_cell.Probe`` keeps), all layers."""
+    """Least seconds of the ragged kernel over ``dispatches`` (of the
+    engine's step reports, ``engine.last_step``), all layers."""
     total = 0.0
     for d in dispatches:
         if d["phase"] == "prefill":

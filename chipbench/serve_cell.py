@@ -3,13 +3,19 @@
 ``step``) driven from one thread, as its own host loop is.
 
 The benchmark keeps its own clock: a request is timed from when it was
-DUE, not from when it was admitted, and every output token is stamped when
-it reaches the host.  The program's step returns finished requests only, so
-two of its methods are watched (PERF.md §7 asks the tracing PR for public
-ones): ``_run_step`` (every device dispatch, prefill or decode) and
-``_sample`` (every token, with the logits row it was taken from).
+DUE, not from when it was admitted.  What happened inside a ``step()`` it
+takes from the program's public account of it, ``engine.last_step``
+(docs/telemetry.md): every output token with the time it reached the host
+(``emitted``), every device dispatch with its sizes (``dispatches``) and
+the prompt tokens whose keys and values became available
+(``prompt_tokens``), all on ``time.perf_counter_ns``, the clock of this
+file.  No method of the engine is replaced while traffic runs, so a step
+that takes other arguments, yields several tokens a request or samples on
+the device is counted like any other.  Only the reference check, in
+set-up, wraps ``_sample`` to see the logits rows the program sampled from.
 """
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 
@@ -75,49 +81,58 @@ CHECK_DECODE_TOKENS = 24
 TRACE_SECONDS = 6.0
 
 
-class Probe:
-    """Watches the engine's dispatches and samples on the benchmark's
-    clock."""
+clock = time.perf_counter     # seconds of the clock the engine stamps in ns
 
-    def __init__(self, engine, clock):
-        self.engine, self.clock = engine, clock
+
+class StepReader:
+    """The engine's own per-step reports, read after every ``step()``:
+    the host time of each output token by request, and what the step
+    dispatched."""
+
+    def __init__(self, engine):
+        self.engine = engine
         self.token_times = {}       # req_id -> [host time of each token]
-        self.prefill_start = {}     # req_id -> host time its prefill began
-        self.keep_logits = {}       # req_id -> [float32 rows], if asked
-        self.dispatches = []        # every _run_step since reset()
-        self.new_tokens = self.new_prompt_tokens = 0
-        self._run_step, self._sample = engine._run_step, engine._sample
-        engine._run_step, engine._sample = self.run_step, self.sample
 
-    def close(self):
-        self.engine._run_step = self._run_step
-        self.engine._sample = self._sample
+    def read(self):
+        """``engine.last_step``, the report of the ``step()`` that just
+        returned (with whatever ``add_request`` ran inline since the one
+        before): (tokens generated, prompt tokens made available, the
+        dispatches)."""
+        report = self.engine.last_step
+        generated = 0
+        for rid, n, t_ns in report["emitted"]:
+            self.token_times.setdefault(rid, []).extend([t_ns / 1e9] * n)
+            generated += n
+        return generated, report["prompt_tokens"], report["dispatches"]
 
-    def run_step(self, ids, tables, lengths, phase="decode"):
-        batch, tokens = ids.shape
-        record = {"phase": phase, "t0": self.clock(), "tokens": tokens}
-        if phase != "prefill":    # a prefill's sizes come with its sample
-            record["contexts"] = self.engine.lengths[
-                self.engine.lengths > 0] + 1
-        self.dispatches.append(record)
-        with tracing.annotate("chipbench/" + phase):
-            return self._run_step(ids, tables, lengths, phase=phase)
 
-    def sample(self, req, row):
-        now = self.clock()
-        rid = req.req_id
-        times = self.token_times.setdefault(rid, [])
-        if not times:       # the prefill that just ran was this request's
-            last = self.dispatches[-1]
-            self.prefill_start[rid] = last["t0"]
-            last["real"] = min(len(req.prompt), last["tokens"])
-            last["context"] = len(req.prompt)
-            self.new_prompt_tokens += len(req.prompt)
-        times.append(now)
-        self.new_tokens += 1
-        if rid in self.keep_logits:
-            self.keep_logits[rid].append(np.array(row, np.float32))
-        return self._sample(req, row)
+def _prefill_starts(engine, since_ns):
+    """req_id -> host time its first prefill began: the program's
+    ``serve/prefill`` spans since ``since_ns``."""
+    starts = {}
+    for span in engine.telemetry.spans(since_ns):
+        if span.name == "serve/prefill":
+            starts.setdefault(span.key, span.t0_ns / 1e9)
+    return starts
+
+
+@contextlib.contextmanager
+def _logits_rows(engine):
+    """While open, the float32 logits row of every token the engine
+    samples on the host, by request: ``_sample(req, row)`` is wrapped, for
+    the reference check alone.  The window never runs under it."""
+    rows = {}
+    original = engine._sample
+
+    def sample(req, row):
+        rows.setdefault(req.req_id, []).append(np.array(row, np.float32))
+        return original(req, row)
+
+    engine._sample = sample
+    try:
+        yield rows
+    finally:
+        engine._sample = original
 
 
 def _reference_rows(cell, params, ids, last):
@@ -141,7 +156,7 @@ def _reference_rows(cell, params, ids, last):
     return np.asarray(want)[0], decided[0]
 
 
-def _serve_check_prompts(cell, engine, probe, seed):
+def _serve_check_prompts(cell, engine, seed):
     """The check's prompts (quantiles of the mix's lengths, tokens from
     the seed) prefilled and decoded through the paged cache: a request's
     ids ``[1, S]`` and the logits rows the program sampled from."""
@@ -149,15 +164,15 @@ def _serve_check_prompts(cell, engine, probe, seed):
     vocab = cell.config["vocab_size"]
     rids = [f"check-{i}" for i in range(len(lengths))]
     done = {}
-    for i, (rid, n) in enumerate(zip(rids, lengths)):
-        probe.keep_logits[rid] = []
-        engine.add_request(rid, traffic.rng_for(seed, 5, i).integers(
-            0, vocab, int(n), dtype=np.int32),
-            max_new_tokens=CHECK_DECODE_TOKENS)
-    while len(done) < len(rids):
-        done.update(engine.step())
+    with _logits_rows(engine) as rows:
+        for i, (rid, n) in enumerate(zip(rids, lengths)):
+            engine.add_request(rid, traffic.rng_for(seed, 5, i).integers(
+                0, vocab, int(n), dtype=np.int32),
+                max_new_tokens=CHECK_DECODE_TOKENS)
+        while len(done) < len(rids):
+            done.update(engine.step())
     return [(np.asarray(done[rid], np.int32)[None, :-1],
-             np.stack(probe.keep_logits.pop(rid))) for rid in rids]
+             np.stack(rows[rid])) for rid in rids]
 
 
 def _compare_with_reference(cell, served, params):
@@ -189,9 +204,9 @@ def _compare_with_reference(cell, served, params):
             "rows_undecided": rows_total - rows_compared}
 
 
-def _check_against_reference(cell, engine, probe, params, seed):
+def _check_against_reference(cell, engine, params, seed):
     return _compare_with_reference(
-        cell, _serve_check_prompts(cell, engine, probe, seed), params)
+        cell, _serve_check_prompts(cell, engine, seed), params)
 
 
 def _warm_up(cell, engine, seed):
@@ -226,14 +241,13 @@ class Served:
     finished: set = field(default_factory=set)
 
 
-def _serve_traffic(mix, stream, engine, probe, seed, seconds, tracer,
+def _serve_traffic(mix, stream, engine, reader, seed, seconds, tracer,
                    counter):
     """Ramp (part of set-up), window, grace: one thread submits what is
     due and steps the engine.  Open loop: requests are due on the stream's
     schedule and the window is the schedule's cycle 1.  Closed loop: each
     of ``clients`` callers sends its next request when the last came back,
     and the window opens at the first loop boundary after the ramp."""
-    clock = probe.clock
     open_loop = mix["kind"] == "open_loop"
     ramp_s, grace_s = float(mix["ramp_s"]), float(mix["grace_s"])
     temperature = mix.get("sampling", {}).get("temperature", 0.0)
@@ -256,8 +270,6 @@ def _serve_traffic(mix, stream, engine, probe, seed, seconds, tracer,
             out.refused.add(rid)
 
     while True:
-        probe.new_tokens = probe.new_prompt_tokens = 0
-        first_dispatch = len(probe.dispatches)
         it0 = clock()
         with tracing.annotate("chipbench/submit"):
             if open_loop:       # a request's gap is the wait AFTER it
@@ -279,7 +291,7 @@ def _serve_traffic(mix, stream, engine, probe, seed, seconds, tracer,
             tracer.tick()
             if now >= window_start + seconds:
                 waiting = [r for r, c in out.cycle.items() if c == 1
-                           and r not in probe.token_times
+                           and r not in reader.token_times
                            and r not in out.refused]
                 if not open_loop or not waiting or \
                         now >= window_start + seconds + grace_s:
@@ -292,13 +304,14 @@ def _serve_traffic(mix, stream, engine, probe, seed, seconds, tracer,
         with tracing.annotate("chipbench/step"):
             done = engine.step()
         it1 = clock()
+        # the benchmark's own book-keeping, outside the iteration's time
+        generated, prompt_tokens, dispatches = reader.read()
         out.finished.update(done)
         outstanding -= len(done)
         out.iterations.append({
             "kind": "serve", "t0": it0, "t1": it1, "step_s": it1 - s0,
-            "tokens": probe.new_tokens + probe.new_prompt_tokens,
-            "generated": probe.new_tokens,
-            "dispatches": probe.dispatches[first_dispatch:],
+            "tokens": generated + prompt_tokens, "generated": generated,
+            "dispatches": dispatches,
             "active": engine.n_active, "queued": len(engine.queue),
             "traced": tracer.active})
     tracer.stop()
@@ -325,7 +338,6 @@ def _ms(values, q):
 
 def run(cell, seed, seconds, trace, started, devices, peaks):
     cfg, mix = cell.config, cell.mix
-    clock = time.perf_counter
     counter = device.CompileCounter()
     model = sut.build_model(cell)
     dtype = cfg["serve"]["dtype"]
@@ -335,10 +347,9 @@ def run(cell, seed, seconds, trace, started, devices, peaks):
                                              dtype=dtype)
     engine = inference.create_serving_engine(
         max_batch=int(mix["max_batch"]), **cfg["serve"]["engine"])
-    probe = Probe(engine, clock)
 
     t0 = clock()
-    check = _check_against_reference(cell, engine, probe, params, seed)
+    check = _check_against_reference(cell, engine, params, seed)
     reference_s = clock() - t0
     _warm_up(cell, engine, seed)
     engine.pop_terminated()
@@ -346,9 +357,11 @@ def run(cell, seed, seconds, trace, started, devices, peaks):
 
     open_loop = mix["kind"] == "open_loop"
     tracer = tracing.Tracer(trace, TRACE_SECONDS)
+    reader = StepReader(engine)
+    traffic_began_ns = time.perf_counter_ns()
     served = _serve_traffic(
         mix, traffic.RequestStream(mix, cfg["vocab_size"], seed, seconds),
-        engine, probe, seed, seconds, tracer, counter)
+        engine, reader, seed, seconds, tracer, counter)
     window_start, window_end = served.window
     setup_s = window_start - started
 
@@ -362,7 +375,8 @@ def run(cell, seed, seconds, trace, started, devices, peaks):
             if r not in served.finished and r not in live
             and r not in terminated and r not in served.refused]
     leaks = engine.leak_report()
-    probe.close()
+    token_times = reader.token_times
+    prefill_start = _prefill_starts(engine, traffic_began_ns)
 
     due = served.due
     in_window = [r for r, c in served.cycle.items() if c == 1] if open_loop \
@@ -370,14 +384,14 @@ def run(cell, seed, seconds, trace, started, devices, peaks):
               if window_start <= t < window_end]
     failed = [r for r in in_window
               if r in served.refused or r in terminated
-              or (open_loop and r not in probe.token_times)]
+              or (open_loop and r not in token_times)]
     worst = served.end - min((due[r] for r in in_window),
                              default=served.end)
-    ttft = [(probe.token_times[r][0] - due[r]) if r not in failed else worst
-            for r in in_window if r in failed or r in probe.token_times]
-    queue_wait = [probe.prefill_start[r] - due[r] for r in in_window
-                  if r in probe.prefill_start]
-    gaps = [b - a for times in probe.token_times.values()
+    ttft = [(token_times[r][0] - due[r]) if r not in failed else worst
+            for r in in_window if r in failed or r in token_times]
+    queue_wait = [prefill_start[r] - due[r] for r in in_window
+                  if r in prefill_start]
+    gaps = [b - a for times in token_times.values()
             for a, b in zip(times, times[1:])
             if window_start <= b <= window_end]
     lateness = [served.submitted[r] - due[r] for r in in_window]

@@ -4,11 +4,14 @@ backward pass, a ragged paged-attention decode call and a matrix product,
 under ``chipbench/...`` annotations) and on made-up intervals."""
 
 import os
+import sys
+import types
 
 import numpy as np
 import pytest
 
-from chipbench import reduce
+from chipbench import cells, reduce
+from chipbench.reducers import device_share_pct, kernel_roofline
 from tree import DATA
 
 
@@ -77,3 +80,43 @@ def test_collectives_and_exposure_on_made_up_intervals():
     assert total == pytest.approx(4.0) and exposed == pytest.approx(3.0)
     assert reduce.busy_seconds(t) == pytest.approx(8.0)
     assert reduce.idle_gaps(t) == {"chipbench/step": pytest.approx(2.0)}
+
+
+# the recorded trace's four Pallas calls by name, seconds as recorded: the
+# three flash-attention kernels of a backward pass (``jvp__``,
+# ``transpose_jvp___`` twice) and the ragged decode call (``_lambda_``);
+# the program's kernels carry fixed names since, the mechanism is the same
+KERNEL_SECONDS = {None: 0.007991031, "transpose_jvp_": 0.003236301,
+                  "_lambda_": 0.003878, "jvp__": 0.00087673}
+
+
+@pytest.mark.parametrize("kernel", list(KERNEL_SECONDS) + ["no_such_"])
+def test_kernel_readers_pick_a_kernel_by_name(trace, kernel, monkeypatch):
+    """``kernel_roofline`` and ``device_share_pct`` with ``kernel``: one of
+    the trace's kernels by the beginning of its name; without it, every
+    Pallas call, as before the argument was there."""
+    monkeypatch.setitem(sys.modules, "chipbench.costs.a_millisecond",
+                        types.SimpleNamespace(least_seconds=lambda run: 1e-3))
+    run = cells.Run(chips=1, peaks={}, model={}, steps=[{}], traced_steps=[{}],
+                    samples={}, counters={}, memory_peak_bytes=0, trace=trace)
+    args = {} if kernel is None else {"kernel": kernel}
+    roofline = kernel_roofline.read(run, "a_millisecond", **args)
+    share = device_share_pct.read(run, "pallas", **args)
+    if kernel == "no_such_":
+        assert roofline is None and share is None
+        return
+    seconds = KERNEL_SECONDS[kernel]
+    assert roofline == pytest.approx(100.0 * 1e-3 / seconds, rel=1e-9)
+    # the kernels do not overlap on the device's line: union = sum
+    assert share == pytest.approx(
+        100.0 * seconds / reduce.busy_seconds(trace), rel=1e-6)
+    # (the first forward call began before the first annotation)
+    assert reduce.op_count(trace, "pallas", kernel) == \
+        {None: 11, "transpose_jvp_": 6, "_lambda_": 3, "jvp__": 2}[kernel]
+    if kernel is None:      # today's reading, to the digit
+        assert roofline == 100.0 * 1e-3 / sum(
+            reduce.op_seconds(trace, "pallas").values())
+        assert share == 100.0 * reduce.busy_seconds(trace, "pallas") / \
+            reduce.busy_seconds(trace)
+        outside = device_share_pct.read(run, "pallas", outside=True)
+        assert outside == 100.0 - share

@@ -8,7 +8,6 @@ arithmetic, bit for bit."""
 
 import importlib.util
 import os
-import time
 import types
 
 import jax
@@ -57,12 +56,8 @@ def _check(cell, served, reference_weights, check=None, seed=SEED):
         model=sut.build_model(cell), params=served, dtype=dtype)
     engine = inference.create_serving_engine(
         max_batch=int(cell.mix["max_batch"]), **cell.config["serve"]["engine"])
-    probe = serve_cell.Probe(engine, time.perf_counter)
-    try:
-        return (check or serve_cell._check_against_reference)(
-            cell, engine, probe, reference_weights, seed)
-    finally:
-        probe.close()
+    return (check or serve_cell._check_against_reference)(
+        cell, engine, reference_weights, seed)
 
 
 def _with_router(params, layer, fn):
@@ -222,25 +217,26 @@ def test_bf16_context_rule_passes_the_floor_on_short_contexts(routed, seed):
     assert context["logit_error"] < serve_cell.LOGIT_TOL / 2
 
 
-def _parent_check(cell, engine, probe, params, seed):
-    """``_check_against_reference`` as the parent commit had it."""
+def _parent_check(cell, engine, params, seed):
+    """``_check_against_reference`` as PR 25's commit had it (the rows
+    through ``_logits_rows``, which took its ``Probe``'s place)."""
     lengths = traffic.quantile_grid(cell.mix["prompt_tokens"],
                                     serve_cell.CHECK_PROMPTS)
     vocab = cell.config["vocab_size"]
     prompts = {}
     done = {}
-    for i, n in enumerate(lengths):
-        rid = f"check-{i}"
-        prompts[rid] = traffic.rng_for(seed, 5, i).integers(
-            0, vocab, int(n), dtype=np.int32)
-        probe.keep_logits[rid] = []
-        engine.add_request(rid, prompts[rid],
-                           max_new_tokens=serve_cell.CHECK_DECODE_TOKENS)
-    while len(done) < len(prompts):
-        done.update(engine.step())
+    with serve_cell._logits_rows(engine) as kept:
+        for i, n in enumerate(lengths):
+            rid = f"check-{i}"
+            prompts[rid] = traffic.rng_for(seed, 5, i).integers(
+                0, vocab, int(n), dtype=np.int32)
+            engine.add_request(rid, prompts[rid],
+                               max_new_tokens=serve_cell.CHECK_DECODE_TOKENS)
+        while len(done) < len(prompts):
+            done.update(engine.step())
     worst = 0.0
     for rid, prompt in prompts.items():
-        rows = np.stack(probe.keep_logits.pop(rid))
+        rows = np.stack(kept[rid])
         ids = np.asarray(done[rid], np.int32)[None, :-1]
         want = np.asarray(cell.reference.logits(
             params, jnp.asarray(ids), cell.config, last=len(rows)))[0]

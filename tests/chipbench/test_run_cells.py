@@ -35,6 +35,7 @@ def _check_line(line, metrics):
     ("tiny-chat", {"tpot_p90_ms", "setup_s"}),
     ("tiny-doc", {"serve_tok_s", "setup_s"}),
     ("tiny-routed", {"serve_tok_s", "setup_s"}),    # a family that routes
+    ("tiny-share", {"serve_tok_s", "setup_s"}),     # one chip's share
 ])
 def test_one_run_of_each_traffic_kind(checkout, cell, metrics):
     line, earlier = tree.run(checkout, cell, seed=2 ** 31 + 5)
@@ -52,6 +53,15 @@ def test_one_run_of_each_traffic_kind(checkout, cell, metrics):
         # reference finds undecided (about one row in 500 of this one's)
         assert log["rows_compared"] + log["rows_undecided"] == 72
         assert log["rows_undecided"] <= (2 if cell == "tiny-routed" else 0)
+    if cell == "tiny-share":
+        # the program was built on the share that ``reduced`` names, not
+        # on ``published``: the layers held, and as many rows of embedding
+        # and of head as the slice has (ids, logits and the reference are
+        # over the slice)
+        held = tree.data("tiny-share")
+        d, f = held["hidden_size"], held["intermediate_size"]
+        assert log["n_params"] == 2 * held["vocab_size"] * d + d + \
+            held["num_hidden_layers"] * (4 * d * d + 3 * d * f + 4 * d)
 
 
 def test_traced_run_reads_the_made_up_metrics(checkout):

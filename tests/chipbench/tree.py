@@ -20,6 +20,9 @@ CELLS = [   # name, configuration, traffic, a real cell whose metrics it takes
     ("tiny-doc", "tiny-olmo2", "tiny-closed", "serve-olmo2-1b-docbatch"),
     # a family that routes tokens to experts, with its reference
     ("tiny-routed", "tiny-routed", "tiny-closed", "serve-olmo2-1b-docbatch"),
+    # one chip's share of a model: depth cut and vocabulary sliced, both
+    # listed in ``reduced`` with the published counts beside them
+    ("tiny-share", "tiny-share", "tiny-closed", "serve-olmo2-1b-docbatch"),
 ]
 
 # code a later PR might bring, as files of its own: a family with its
@@ -50,13 +53,14 @@ def make(tmp):
     for source, target in NEW_CODE.items():
         shutil.copy(os.path.join(DATA, source),
                     os.path.join(tmp, "chipbench", target))
-    for config in ("tiny-neox", "tiny-olmo2", "tiny-routed"):
+    for config in sorted({config for _, config, _, _ in CELLS}):
         shutil.copy(os.path.join(DATA, config + ".json"),
                     os.path.join(tmp, "chipbench", "configs"))
+        held = data(config)     # the entry says what the file says
         bench["configs"].append({
-            "name": config, "source": "made up for the tests",
-            "file": f"chipbench/configs/{config}.json", "reduced": [],
-            "why": "toy width"})
+            "name": config, "source": held["source"],
+            "file": f"chipbench/configs/{config}.json",
+            "reduced": held["reduced"], "why": "toy width"})
     for mix in ("tiny-pretrain", "tiny-open", "tiny-closed"):
         shutil.copy(os.path.join(DATA, mix + ".json"),
                     os.path.join(tmp, "chipbench", "traffic"))
