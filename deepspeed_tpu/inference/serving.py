@@ -51,10 +51,10 @@ from deepspeed_tpu.inference.scheduler import SLO_CLASSES, create_scheduler
 from deepspeed_tpu.monitor.attribution import RequestAttributor
 from deepspeed_tpu.monitor.telemetry import (get_telemetry,
                                              register_compiled)
-from deepspeed_tpu.ops.decode_attention import use_pallas
 from deepspeed_tpu.ops.paged_attention import (PageAllocationError,
                                                PagedAllocator,
-                                               resolve_attention_backend)
+                                               resolve_attention_backend,
+                                               resolve_paged_impl)
 from deepspeed_tpu.ops.pallas.ragged_paged_attention import pick_tiles
 from deepspeed_tpu.runtime.resilience import FaultInjector
 from deepspeed_tpu.utils.logging import logger
@@ -332,10 +332,8 @@ class ServingEngine:
         # (``attention_impl``, the serve/backend event) is what every
         # compiled shape runs; softcapped models take the jnp path
         # (ops/paged_attention.py)
-        self.attention_impl = "pallas" if (
-            use_pallas(attn_impl)
-            and not getattr(self.config, "attn_logit_softcap", None)
-        ) else "jnp"
+        self.attention_impl = resolve_paged_impl(
+            attn_impl, getattr(self.config, "attn_logit_softcap", None))
         self._paged_call = functools.partial(
             self.model.apply_with_paged_cache,
             attn_backend=self.attention_impl, attn_interpret=attn_interpret)
@@ -1157,6 +1155,10 @@ class ServingEngine:
         is the model whose layers the dispatch runs)."""
         t0_ns = time.perf_counter_ns()
         kernel_grid = self.kernel_grid(phase, batch, tokens, config)
+        # what the dispatch compiled: the draft model's call binds no
+        # backend, so it resolves its own
+        kv_write = self.attention_impl if config is None else \
+            resolve_paged_impl(None, config.attn_logit_softcap)
         with self.telemetry.span(
                 "serve/step",
                 attrs={"backend": backend or self.attention_backend,
@@ -1170,7 +1172,7 @@ class ServingEngine:
             out = fn(*args)
         self._report["dispatches"].append(
             {"phase": phase, "batch": int(batch), "tokens": int(tokens),
-             "kernel_grid": kernel_grid,
+             "kernel_grid": kernel_grid, "kv_write": kv_write,
              "t0_ns": t0_ns, "t1_ns": time.perf_counter_ns()})
         return out
 

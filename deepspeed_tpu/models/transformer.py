@@ -1001,15 +1001,15 @@ class CausalTransformerLM:
         """Stacked per-layer page pools: leaves [L, P, Hkv, page, D] — one
         scan for homogeneous stacks; MoE / heterogeneous models index the
         same pools per layer in a static loop."""
-        from deepspeed_tpu.ops.paged_attention import init_paged_cache
+        from deepspeed_tpu.ops.paged_attention import PagedKVCache
         c = self.config
         assert not c.use_alibi and not c.local_attn_pattern, \
             "paged serving does not support alibi/local-window models yet"
-        one = init_paged_cache(num_pages, page_size, c.kv_heads, c.head_dim,
-                               dtype)
-        return jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(
-                x[None], (c.n_layers,) + x.shape).copy(), one)
+        # each stack made in place: a broadcast of one layer's pool and a
+        # copy of it held four stacks at once, the process's HBM peak
+        shape = (c.n_layers, num_pages, c.kv_heads, page_size, c.head_dim)
+        return PagedKVCache(k_pages=jnp.zeros(shape, dtype),
+                            v_pages=jnp.zeros(shape, dtype))
 
     def apply_with_paged_cache(self, params, input_ids, caches, block_tables,
                                lengths, *, attn_backend=None,
@@ -1026,9 +1026,9 @@ class CausalTransformerLM:
         fused ragged kernel; interpret runs the kernel on CPU) — static
         kwargs, so the serving engine binds them before jit.
         """
-        from deepspeed_tpu.ops.paged_attention import (PagedKVCache,
-                                                       paged_decode_attention,
-                                                       prefill_paged)
+        from deepspeed_tpu.ops.paged_attention import (paged_decode_attention,
+                                                       resolve_paged_impl,
+                                                       write_paged)
         c = self.config
         B, T = input_ids.shape
         positions = lengths[:, None] + jnp.broadcast_to(
@@ -1043,23 +1043,29 @@ class CausalTransformerLM:
             x = _norm(x, params["embed_norm"], c.norm_eps, c.use_rmsnorm,
                       params.get("embed_norm_b"))
 
-        H, Hkv, dh = c.n_heads, c.kv_heads, c.head_dim
+        H, dh = c.n_heads, c.head_dim
+        # one backend for the write and the read of the pools
+        impl = resolve_paged_impl(attn_backend, c.attn_logit_softcap)
 
-        def body(x, inp):
-            layer, ck, cv = inp
+        def body(carry, inp):
+            # the stacked pools stay ONE buffer through the layers: carried,
+            # written in place, read in place by layer index
+            x, cache = carry
+            layer, i = inp
             h = _pre_norm(x, layer, "attn_norm", c)
             q, k, v = self._qkv(h, layer, B, T, positions)
-            cache, _ = prefill_paged(PagedKVCache(ck, cv), block_tables,
-                                     lengths, k, v)
+            cache = write_paged(cache, i, block_tables, lengths, k, v,
+                                impl=impl, interpret=attn_interpret)
             # NOTE: ALiBi / local-window models are not yet served paged
             # (their additive bias needs per-batch ragged positions the
             # paged kernels don't take); init_paged_caches guards this
             attn = paged_decode_attention(q, cache, block_tables,
                                           lengths + T,
                                           softmax_scale=c.attn_scale,
-                                          impl=attn_backend,
+                                          impl=impl,
                                           interpret=attn_interpret,
-                                          logit_softcap=c.attn_logit_softcap)
+                                          logit_softcap=c.attn_logit_softcap,
+                                          layer=i)
             attn_delta = self._proj(attn.reshape(B, T, H * dh), layer, "wo")
             if "attn_post_norm" in layer:   # Gemma-2 sandwich
                 attn_delta = _norm(attn_delta, layer["attn_post_norm"],
@@ -1075,23 +1081,21 @@ class CausalTransformerLM:
             else:
                 x = x + attn_delta
                 x, _ = self._mlp_block(x, layer, train=False)
-            return x, (cache.k_pages, cache.v_pages)
+            return (x, cache), None
 
         if isinstance(params["layers"], (list, tuple)):
             # MoE / heterogeneous stack: static per-layer loop (expert
             # leaves carry an [E, ...] dim sharded over ep at serve time —
             # the MoE dispatch inside _mlp_block lowers to the same
             # all-to-alls as training, reference megatron_gpt_moe serving)
-            nk, nv = [], []
+            carry = (x, caches)
             for i, layer in enumerate(params["layers"]):
-                x, (k_i, v_i) = body(x, (layer, caches.k_pages[i],
-                                         caches.v_pages[i]))
-                nk.append(k_i)
-                nv.append(v_i)
-            new_k, new_v = jnp.stack(nk), jnp.stack(nv)
+                carry, _ = body(carry, (layer, i))
+            x, caches = carry
         else:
-            x, (new_k, new_v) = jax.lax.scan(
-                body, x, (params["layers"], caches.k_pages, caches.v_pages))
+            (x, caches), _ = jax.lax.scan(
+                body, (x, caches),
+                (params["layers"], jnp.arange(c.n_layers)))
 
         x = _norm(x, params["final_norm"], c.norm_eps, c.use_rmsnorm,
                   params.get("final_norm_b"))
@@ -1103,8 +1107,7 @@ class CausalTransformerLM:
         if c.final_logit_scale is not None:   # Cohere logit_scale
             logits = logits * c.final_logit_scale
         logits = _softcap(logits, c.final_logit_softcap)
-        return logits, PagedKVCache(k_pages=new_k, v_pages=new_v), \
-            lengths + T
+        return logits, caches, lengths + T
 
     # ------------------------------------------------------------------
     # layer-stream contract (training-time parameter offload —

@@ -13,6 +13,10 @@ Layout:
   block_tables:    [B, max_pages_per_seq] int32 — page ids per sequence
   lengths:         [B] int32 — tokens currently stored per sequence
 
+A serving dispatch holds every layer's pool stacked,
+[L, num_pages, Hkv, page_size, D], and passes a layer index
+(``write_paged``, ``paged_decode_attention(layer=)``).
+
 Two compute paths behind one API: the fused ragged Pallas kernel
 (``ops/pallas/ragged_paged_attention.py`` — the K/V index maps read the
 block table so only each sequence's own pages are DMA'd, and one launch
@@ -57,6 +61,17 @@ def resolve_attention_backend(backend):
                      f"expected one of {ATTENTION_BACKENDS}")
 
 
+def resolve_paged_impl(impl, logit_softcap=None):
+    """"pallas" or "jnp": the pair that writes and reads the page pools.
+
+    One decision for both halves of a dispatch (``write_paged`` and
+    ``paged_decode_attention``): the aliased write kernel and the ragged
+    kernel on TPU or where ``impl`` says so; the jnp scatter and gather
+    otherwise, and for softcapped models (the kernel takes no softcap)."""
+    from deepspeed_tpu.ops.decode_attention import use_pallas
+    return "pallas" if use_pallas(impl) and not logit_softcap else "jnp"
+
+
 def init_paged_cache(num_pages, page_size, n_kv_heads, head_dim,
                      dtype=jnp.bfloat16) -> PagedKVCache:
     shape = (num_pages, n_kv_heads, page_size, head_dim)
@@ -83,15 +98,21 @@ def append_paged(cache: PagedKVCache, block_tables, lengths, k_new, v_new
     return PagedKVCache(k_pages=k, v_pages=v), lengths + 1
 
 
+def _row_targets(block_tables, lengths, T, page_size):
+    """(page ids, in-page rows), both [B, T], of the T rows a sequence
+    writes from ``lengths`` on."""
+    pos = lengths[:, None] + jnp.arange(T)[None, :]          # [B, T]
+    return (jnp.take_along_axis(block_tables, pos // page_size, axis=1),
+            pos % page_size)
+
+
 def prefill_paged(cache: PagedKVCache, block_tables, lengths, k_new, v_new
                   ) -> Tuple[PagedKVCache, jnp.ndarray]:
     """Write a whole prompt [B, T, Hkv, D] starting at ``lengths`` (which is
     typically zero)."""
-    B, T = k_new.shape[:2]
-    page_size = cache.k_pages.shape[2]
-    pos = lengths[:, None] + jnp.arange(T)[None, :]          # [B, T]
-    page_idx = jnp.take_along_axis(block_tables, pos // page_size, axis=1)
-    offset = pos % page_size
+    T = k_new.shape[1]
+    page_idx, offset = _row_targets(block_tables, lengths, T,
+                                    cache.k_pages.shape[2])
     # advanced indices (page_idx, offset) around the ':' slice put their
     # broadcast dims first: the set value is [B, T, Hkv, D] = k_new's layout
     k = cache.k_pages.at[page_idx, :, offset].set(
@@ -101,13 +122,43 @@ def prefill_paged(cache: PagedKVCache, block_tables, lengths, k_new, v_new
     return PagedKVCache(k_pages=k, v_pages=v), lengths + T
 
 
+def write_paged(cache: PagedKVCache, layer, block_tables, lengths, k_new,
+                v_new, impl: Optional[str] = None,
+                interpret: bool = False) -> PagedKVCache:
+    """Write rows [B, T, Hkv, D] from ``lengths`` on into layer ``layer``
+    (may be traced) of the STACKED pools [L, P, Hkv, page, D], in place.
+
+    What a serving dispatch calls once a layer, the pools riding its layer
+    loop's carry.  ``impl`` as :func:`resolve_paged_impl` reads it: the
+    aliased ``paged_kv_write`` kernel, or the jnp scatter on the stack.
+    An XLA scatter next to the ragged kernel makes the compiler re-lay
+    the whole pool between the two (docs/serving.md), so the write follows
+    the read's backend."""
+    if resolve_paged_impl(impl) == "pallas":
+        from deepspeed_tpu.ops.pallas.ragged_paged_attention import \
+            paged_kv_write
+        return PagedKVCache(*paged_kv_write(
+            cache.k_pages, cache.v_pages, layer, block_tables, lengths,
+            k_new, v_new, interpret=interpret))
+    page_idx, offset = _row_targets(block_tables, lengths, k_new.shape[1],
+                                    cache.k_pages.shape[3])
+    k = cache.k_pages.at[layer, page_idx, :, offset].set(
+        k_new.astype(cache.k_pages.dtype))
+    v = cache.v_pages.at[layer, page_idx, :, offset].set(
+        v_new.astype(cache.v_pages.dtype))
+    return PagedKVCache(k_pages=k, v_pages=v)
+
+
 def paged_decode_attention(q, cache: PagedKVCache, block_tables, lengths,
                            softmax_scale: Optional[float] = None,
                            impl: Optional[str] = None,
                            interpret: bool = False,
                            logit_softcap: Optional[float] = None,
-                           backend: Optional[str] = None):
+                           backend: Optional[str] = None,
+                           layer=None):
     """q: [B, T, H, D] — the last T tokens of each sequence (T=1 decode).
+    With ``layer`` (may be traced) ``cache`` holds the stacked pools
+    [L, P, Hkv, page, D] and that layer is read in place.
 
     ``impl``: None (auto: Pallas kernel on TPU, jnp elsewhere), "pallas",
     or "jnp"; ``backend`` is the serving-config spelling ("auto" | "jnp" |
@@ -117,28 +168,26 @@ def paged_decode_attention(q, cache: PagedKVCache, block_tables, lengths,
     sequence's pages into its logical view and runs masked attention over
     the valid ragged prefix — it is the oracle the kernel is tested
     against.  ``logit_softcap`` is jnp-only and forces the fallback."""
-    from deepspeed_tpu.ops.decode_attention import use_pallas
     if backend is not None:
         impl, forced = resolve_attention_backend(backend)
         interpret = interpret or forced
-    if use_pallas(impl) and not logit_softcap:
+    if resolve_paged_impl(impl, logit_softcap) == "pallas":
         from deepspeed_tpu.ops.pallas.ragged_paged_attention import \
             ragged_paged_attention_rect
         return ragged_paged_attention_rect(q, cache.k_pages, cache.v_pages,
                                            block_tables, lengths,
                                            softmax_scale=softmax_scale,
-                                           interpret=interpret)
+                                           interpret=interpret, layer=layer)
     B, T, H, D = q.shape
-    Hkv = cache.k_pages.shape[1]
-    page_size = cache.k_pages.shape[2]
+    Hkv, page_size = cache.k_pages.shape[-3:-1]
     max_pages = block_tables.shape[1]
     S = max_pages * page_size
 
-    # [B, max_pages, Hkv, page, D] → [B, Hkv, S, D]
-    k = jnp.swapaxes(cache.k_pages[block_tables], 1, 2) \
-        .reshape(B, Hkv, S, D)
-    v = jnp.swapaxes(cache.v_pages[block_tables], 1, 2) \
-        .reshape(B, Hkv, S, D)
+    # one gather of the sequences' pages (out of the stack where there is
+    # one): [B, max_pages, Hkv, page, D] → [B, Hkv, S, D]
+    pages = block_tables if layer is None else (layer, block_tables)
+    k = jnp.swapaxes(cache.k_pages[pages], 1, 2).reshape(B, Hkv, S, D)
+    v = jnp.swapaxes(cache.v_pages[pages], 1, 2).reshape(B, Hkv, S, D)
     if Hkv != H:
         rep = H // Hkv
         k = jnp.repeat(k, rep, axis=1)

@@ -49,6 +49,13 @@ same kernel — it is what ``paged_decode_attention(backend="pallas")``
 and the deprecated ``paged_attention_pallas`` route through, so there is
 one paged-attention kernel surface.  The jnp gather path remains the
 oracle; ``interpret=True`` runs this kernel on CPU CI.
+
+The serving dispatch hands the kernel the STACKED pools
+``[L, P, Hkv, page, D]`` and a layer index (a sixth scalar-prefetch
+operand the K/V index maps read), and writes each layer's new rows with
+the second kernel of this file, ``paged_kv_write``, whose pool operands
+are aliased to its outputs: between its donated argument and its result a
+dispatch never copies, slices or re-lays a pool (docs/serving.md).
 """
 
 import functools
@@ -92,6 +99,11 @@ class TileChoice(NamedTuple):
         return self.grid[0] * self.grid[1] * self.grid[2]
 
 
+def _sublanes(itemsize):
+    """Rows of one sublane tile of a dtype (8 of float32, 16 of bf16)."""
+    return 8 * max(1, 4 // itemsize)
+
+
 def _step_vmem_bytes(q_tile, heads, pages, group, page_size, D, itemsize):
     """VMEM one grid step holds: double-buffered q/out and K/V blocks, the
     concatenated K/V of a multi-page step, the float32 scratch (max and
@@ -129,7 +141,7 @@ def pick_tiles(q_lens, group, n_kv_heads, page_size, head_dim, table_width,
     rows = q_tile * group
     divisors = [h for h in range(n_kv_heads, 0, -1) if n_kv_heads % h == 0]
     heads = next((h for h in divisors if h * rows <= MAX_ROWS), 1)
-    sublanes = 8 * max(1, 4 // itemsize)
+    sublanes = _sublanes(itemsize)
     pages = 1
     if page_size % sublanes == 0:
         page_kv_bytes = 2 * heads * page_size * head_dim * itemsize
@@ -151,7 +163,8 @@ def pick_tiles(q_lens, group, n_kv_heads, page_size, head_dim, table_width,
 
 
 def _ragged_kernel(ctx_ref, qlens_ref, sot_ref, qot_ref, tables_ref,
-                   q_ref, *refs, scale, page_size, q_tile, group, pages):
+                   layer_ref, q_ref, *refs, scale, page_size, q_tile, group,
+                   pages):
     """One (q-tile, kv-head block, kv step) of online-softmax attention.
 
     q_ref: [1, heads, q_tile*group, D] — ``q_tile`` padded query rows of
@@ -228,16 +241,21 @@ KERNEL_DECODE = "ragged_paged_attention_decode"
 
 def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
                  seq_of_tile, qtile_of_tile, tiles: TileChoice, scale,
-                 interpret, name):
+                 interpret, name, layer=None):
     """Launch the kernel over a tiled query stack.
 
     qt: [n_tiles, Hkv, q_tile*group, D] — tile ``t`` holds ``q_tile``
     rows of sequence ``seq_of_tile[t]`` (its ``qtile_of_tile[t]``-th
     tile), each row with its kv head's whole group.  ctx_lens/q_lens may
     be traced; seq_of_tile / qtile_of_tile are host metadata (they size
-    the grid)."""
+    the grid).  k_pages/v_pages: one layer's pool [P, Hkv, page, D], or
+    the stacked pools [L, P, Hkv, page, D] with ``layer`` (may be traced)
+    the one to read — the index maps pick it, so no layer's pool is ever
+    sliced out of the stack."""
     n_tiles, Hkv, rows, D = qt.shape
-    page_size = k_pages.shape[2]
+    if k_pages.ndim == 4:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    page_size = k_pages.shape[3]
     width = block_tables.shape[1]
     q_tile, heads, pages = tiles.q_tile, tiles.heads, tiles.pages
     assert tiles.grid[0] == n_tiles and rows % q_tile == 0
@@ -246,11 +264,12 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
     sot = jnp.asarray(seq_of_tile, jnp.int32)
     qot = jnp.asarray(qtile_of_tile, jnp.int32)
     tables = jnp.asarray(block_tables, jnp.int32)
+    lay = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def q_map(t, h, i, ctx, qls, sot, qot, tbl):
+    def q_map(t, h, i, ctx, qls, sot, qot, tbl, lay):
         return (t, h, 0, 0)
 
-    def kv_map(j, t, h, i, ctx, qls, sot, qot, tbl):
+    def kv_map(j, t, h, i, ctx, qls, sot, qot, tbl, lay):
         # fetch only pages under this tile's causal frontier: the step's
         # j-th operand clamps to the last needed page, and steps past the
         # frontier repeat the last needed step's indices (DMA skipped)
@@ -258,9 +277,9 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
         kv_hi = ctx[s] - qls[s] + jnp.minimum(qls[s], (qot[t] + 1) * q_tile)
         last = jnp.maximum(pl.cdiv(kv_hi, page_size) - 1, 0)
         col = jnp.minimum(jnp.minimum(i, last // pages) * pages + j, last)
-        return (tbl[s, jnp.minimum(col, width - 1)], h, 0, 0)
+        return (lay[0], tbl[s, jnp.minimum(col, width - 1)], h, 0, 0)
 
-    kv_specs = [pl.BlockSpec((1, heads, page_size, D),
+    kv_specs = [pl.BlockSpec((None, 1, heads, page_size, D),
                              functools.partial(kv_map, j))
                 for j in range(pages)]
     kernel = functools.partial(_ragged_kernel, scale=scale,
@@ -269,7 +288,7 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=6,
             grid=tiles.grid,
             in_specs=[pl.BlockSpec((1, heads, rows, D), q_map)]
             + kv_specs + kv_specs,
@@ -286,13 +305,13 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
             vmem_limit_bytes=2 * VMEM_BUDGET),
         interpret=interpret,
         name=name,
-    )(ctx_lens, q_lens, sot, qot, tables, qt,
+    )(ctx_lens, q_lens, sot, qot, tables, lay, qt,
       *([k_pages] * pages), *([v_pages] * pages))
 
 
 def _pick_for(q_lens, q, k_pages, block_tables, q_tile):
     H, D = q.shape[-2:]
-    Hkv, page_size = k_pages.shape[1:3]
+    Hkv, page_size = k_pages.shape[-3:-1]
     return pick_tiles(q_lens, H // Hkv, Hkv, page_size, D,
                       block_tables.shape[1], k_pages.dtype.itemsize, q_tile)
 
@@ -373,7 +392,7 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
 
 def ragged_paged_attention_rect(q, k_pages, v_pages, block_tables, lengths,
                                 softmax_scale=None, q_tile=None,
-                                interpret=False):
+                                interpret=False, layer=None):
     """Rectangular front-end for the jitted serving path.
 
     q: [B, T, H, D] — the last T tokens of each sequence (T=1 decode,
@@ -381,10 +400,11 @@ def ragged_paged_attention_rect(q, k_pages, v_pages, block_tables, lengths,
     including the T new ones (may be traced — T itself is the static
     shape, so the packed metadata stays host-side).  Same kernel as
     :func:`ragged_paged_attention`; rows past a multiple-of-q_tile pad
-    are masked inside the kernel.
+    are masked inside the kernel.  With ``layer`` (may be traced) the
+    pools are the stacked [L, P, Hkv, page, D] and are read in place.
     """
     B, T, H, D = q.shape
-    Hkv = k_pages.shape[1]
+    Hkv = k_pages.shape[-3]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
     tiles = _pick_for([T] * B, q, k_pages, block_tables, q_tile)
     n_qt = -(-T // tiles.q_tile)
@@ -398,5 +418,130 @@ def ragged_paged_attention_rect(q, k_pages, v_pages, block_tables, lengths,
                                  tiles.q_tile, Hkv),
                        k_pages, v_pages, block_tables, lengths, q_lens,
                        sot, qot, tiles, scale, interpret,
-                       KERNEL_DECODE if T == 1 else KERNEL_PREFILL)
+                       KERNEL_DECODE if T == 1 else KERNEL_PREFILL, layer)
     return _from_tiles(out, tiles.q_tile).reshape(B, Tp, H, D)[:, :T]
+
+
+# ---------------------------------------------------------------------------
+# the write half: new K and V rows merged into their pages, in place
+# ---------------------------------------------------------------------------
+KERNEL_KV_WRITE = "paged_kv_write"
+
+
+class WriteChoice(NamedTuple):
+    """Block shapes of one write: ``rows`` rows of a page x ``heads`` kv
+    heads a grid step, ``blocks`` row blocks a sequence can touch."""
+    rows: int
+    heads: int
+    blocks: int
+
+
+def pick_write_blocks(T, n_kv_heads, page_size, head_dim,
+                      itemsize) -> WriteChoice:
+    """Block shapes for one write, from what the call can see.
+
+    The kernel merges whole tile-aligned row blocks, so a block is a group
+    of whole sublane tiles that divides the page: the smallest that holds
+    a sequence's ``T`` new rows (a decode step moves 16 rows of bf16 a
+    head, not the page), else the page.  ``T`` rows from an arbitrary
+    start touch at most ``ceil((T - 1) / rows) + 1`` blocks.  Heads
+    shrink until K and V, new, old and merged, double-buffered, fit
+    ``VMEM_BUDGET``."""
+    sublanes = _sublanes(itemsize)
+    rows = next((r for r in range(sublanes, page_size, sublanes)
+                 if page_size % r == 0 and r >= T), page_size)
+    lanes = -(-head_dim // 128) * 128
+    divisors = [h for h in range(n_kv_heads, 0, -1) if n_kv_heads % h == 0]
+    heads = next((h for h in divisors
+                  if 2 * 6 * h * rows * lanes * itemsize <= VMEM_BUDGET), 1)
+    return WriteChoice(rows, heads, -(-(T - 1) // rows) + 1)
+
+
+def _kv_write_kernel(layer_ref, starts_ref, tables_ref, kn_ref, vn_ref,
+                     k_ref, v_ref, ko_ref, vo_ref, *, T, rows):
+    """One (sequence, row block, kv-head block): ``out = where(row is new,
+    new, old)`` on [heads, rows, D] of K and of V.  Steps past the
+    sequence's last touched block repeat it (same indices: no DMA, the
+    same merge again)."""
+    off = starts_ref[pl.program_id(0)] % rows
+    j = jnp.minimum(pl.program_id(1), (off + T - 1) // rows)
+    token = j * rows - off + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1), 0)
+    new = ((token >= 0) & (token < T))[None]
+    ko_ref[...] = jnp.where(new, kn_ref[...], k_ref[...])
+    vo_ref[...] = jnp.where(new, vn_ref[...], v_ref[...])
+
+
+def paged_kv_write(k_pages, v_pages, layer, block_tables, lengths, k_new,
+                   v_new, interpret=False):
+    """Write each sequence's new rows into layer ``layer`` of the stacked
+    pools, touching no other byte of them.
+
+    k_pages/v_pages: [L, P, Hkv, page, D], aliased to the outputs (under a
+    jit that donates them, or in a loop's carry, the pools are never
+    copied); k_new/v_new: [B, T, Hkv, D], written at positions
+    ``lengths[b] + arange(T)`` through ``block_tables`` exactly as
+    ``prefill_paged`` resolves them (columns past the table clamp to its
+    last one, the engine's overrun column on the scratch page).  ``layer``
+    and ``lengths`` may be traced.
+
+    XLA lines the new rows up with the pool's row blocks (they are small:
+    ``[B, blocks, Hkv, rows, D]``), the kernel merges whole blocks chosen
+    through the scalar-prefetched layer, starts and tables; it never
+    slices at a dynamic sublane offset.  The grid is sequential: idle
+    slots and bucket padding all land on the scratch page, whose content
+    nobody reads.  Returns (k_pages, v_pages)."""
+    _, _, Hkv, page_size, D = k_pages.shape
+    B, T = k_new.shape[:2]
+    width = block_tables.shape[1]
+    rows, heads, blocks = pick_write_blocks(T, Hkv, page_size, D,
+                                            k_pages.dtype.itemsize)
+    per_page = page_size // rows
+    starts = jnp.asarray(lengths, jnp.int32)
+    tables = jnp.asarray(block_tables, jnp.int32)
+    lay = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def aligned(new, pool):
+        new = new.astype(pool.dtype)
+        if T == 1:      # every row is the one token: no gather
+            return jnp.broadcast_to(new[:, :, :, None],
+                                    (B, 1, Hkv, rows, D))
+        # row r of a sequence's block j holds its token
+        # j*rows + r - start%rows (clipped: the kernel masks the rest)
+        token = jnp.clip(jnp.arange(blocks * rows)[None, :]
+                         - (starts % rows)[:, None], 0, T - 1)
+        new = jnp.take_along_axis(new, token[:, :, None, None], axis=1)
+        return jnp.swapaxes(new.reshape(B, blocks, rows, Hkv, D), 2, 3)
+
+    def block_of(b, j, st):
+        return jnp.minimum(j, (st[b] % rows + T - 1) // rows)
+
+    def new_map(b, j, h, lay, st, tbl):
+        return (b, block_of(b, j, st), h, 0, 0)
+
+    def pool_map(b, j, h, lay, st, tbl):
+        blk = st[b] // rows + block_of(b, j, st)
+        col = jnp.minimum(blk // per_page, width - 1)
+        return (lay[0], tbl[b, col], h, blk % per_page, 0)
+
+    block = (None, None, heads, rows, D)
+    pool_spec = pl.BlockSpec(block, pool_map)
+    return pl.pallas_call(
+        functools.partial(_kv_write_kernel, T=T, rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, blocks, Hkv // heads),
+            in_specs=[pl.BlockSpec(block, new_map)] * 2 + [pool_spec] * 2,
+            out_specs=[pool_spec] * 2,
+        ),
+        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
+        # operands count the scalar-prefetch ones: 5 and 6 are the pools
+        input_output_aliases={5: 0, 6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=2 * VMEM_BUDGET),
+        interpret=interpret,
+        name=KERNEL_KV_WRITE,
+    )(lay, starts, tables, aligned(k_new, k_pages), aligned(v_new, v_pages),
+      k_pages, v_pages)

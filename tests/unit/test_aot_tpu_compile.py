@@ -158,3 +158,110 @@ def test_paged_decode_step_llama_1b(topo):
         params, _on(chip, (batch, 1), jnp.int32), caches,
         _on(chip, (batch, max_pages + 1), jnp.int32),
         _on(chip, (batch,), jnp.int32))
+
+
+# -- a serving dispatch holds the page pools as ONE buffer -------------------
+# OLMo-2 1B as the benchmark's serving cells run it (chipbench/configs/
+# olmo2-1b.json: bf16, pages of 128, 288 pages, table width 33)
+OLMO2_1B = dict(vocab_size=100352, hidden_size=2048, n_layers=16, n_heads=16,
+                ffn_hidden_size=8192, max_seq_len=4096, rope_theta=5e5,
+                norm_eps=1e-6, activation="silu", use_rmsnorm=True,
+                use_rope=True, qk_norm="rms_flat", post_norm_only=True,
+                tie_embeddings=False)
+POOL = (288, 16, 128, 128)
+ONE_LAYER_POOL_BYTES = 2 * int(np.prod(POOL))          # 151 MB
+
+# name, jit name, batch, tokens, model settings over OLMO2_1B
+DISPATCHES = [
+    ("chat_decode_b32", "serve_decode", 32, 1, {}),
+    ("docbatch_decode_b16", "serve_decode", 16, 1, {}),
+    ("prefill_128", "serve_prefill", 1, 128, {}),
+    ("prefill_4096", "serve_prefill", 1, 4096, {}),
+    ("spec_verify_b32_t5", "serve_decode", 32, 5, {}),
+    ("decode_chunk_4", "chunk", 32, 4, {}),
+    # a list-of-layers stack (the static loop MoE models take)
+    ("list_stack_decode", "serve_decode", 32, 1,
+     dict(n_layers=4, moe_num_experts=4, moe_top_k=1)),
+]
+
+
+def _pool_shaped(text, layers):
+    """(name, opcode) of every instruction of the compiled text whose
+    result holds a pool-shaped or stack-shaped array."""
+    import re
+    dims = ",".join(map(str, POOL))
+    shapes = (f"bf16[{dims}]", f"bf16[{layers},{dims}]")
+    found = []
+    for line in text.splitlines():
+        name, eq, rest = line.partition(" = ")
+        op = re.search(r"\s([a-z][a-z0-9\-]*)\(", " " + rest)
+        if not eq or not op:
+            continue
+        result = rest[:op.start()]
+        if any(s in result for s in shapes):
+            opcode = op.group(1)
+            if opcode == "custom-call" and "tpu_custom_call" in rest:
+                opcode = "tpu_custom_call"
+            found.append((name.split()[-1], opcode))
+    return found
+
+
+@pytest.mark.parametrize("name,jit_name,batch,tokens,settings", DISPATCHES,
+                         ids=[c[0] for c in DISPATCHES])
+def test_serving_dispatch_never_copies_the_page_pools(
+        topo, name, jit_name, batch, tokens, settings):
+    """The guard that keeps the pool copies from coming back unseen on a
+    CPU-only check (PERF.md §6, PR 29: ten copies of a layer's pool a
+    layer a dispatch were four fifths of a decode step).  In the compiled
+    program of each dispatch the engine makes, with the caches donated as
+    the engine donates them, nothing but parameters, tuple plumbing, the
+    layer loop and the two kernels has a result of the pool's or the
+    stack's shape: no slice out of the stack, no re-layout, no copy back."""
+    import types
+
+    from deepspeed_tpu.inference.scheduler import SchedulerBase
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = CausalTransformerLM(TransformerConfig(**{**OLMO2_1B,
+                                                     **settings}))
+    layers = model.config.n_layers
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _on(chip, x.shape, x.dtype), tree)
+
+    def ints(*shape):
+        return _on(chip, shape, jnp.int32)
+
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.bfloat16)))
+    caches = on_chip(jax.eval_shape(
+        lambda: model.init_paged_caches(POOL[0], POOL[2])))
+    paged_call = lambda *a: model.apply_with_paged_cache(   # noqa: E731
+        *a, attn_backend="pallas")
+    if jit_name == "chunk":
+        # the scheduler's own K-token scan, the pools in ITS carry
+        sched = types.SimpleNamespace(engine=types.SimpleNamespace(
+            decode_chunk=tokens, _paged_call=paged_call))
+        lowered = SchedulerBase._build_chunk_fn(sched, False).lower(
+            params, caches, ints(batch, 33), ints(batch), ints(batch),
+            _on(chip, (batch,), jnp.float32), ints(batch), ints(batch),
+            ints(batch), _on(chip, (batch,), jnp.float32))
+    else:
+        paged_call.__name__ = jit_name
+        lowered = jax.jit(paged_call, donate_argnums=(2,)).lower(
+            params, ints(batch, tokens), caches, ints(batch, 33),
+            ints(batch))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    allowed = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
+               "tpu_custom_call"}
+    found = _pool_shaped(text, layers)
+    assert found, "the pools are not in the compiled text"
+    assert not [f for f in found if f[1] not in allowed]
+    assert "paged_kv_write" in text
+    assert ("ragged_paged_attention_decode"
+            if tokens == 1 or jit_name == "chunk"
+            else "ragged_paged_attention_prefill") in text
+    if tokens == 1 or jit_name == "chunk":
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < ONE_LAYER_POOL_BYTES, temp

@@ -126,6 +126,32 @@ def test_rect_front_end(T):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("T", [1, 12], ids=["decode", "prefill"])
+def test_stacked_pools_read_by_layer_index(T, layer):
+    """What a serving dispatch calls: the stacked pools [L, P, Hkv, page,
+    D] and a traced layer index give, to the bit, what the 4-D call gives
+    on ``pool[layer]`` (the index maps pick the layer; the kernel body is
+    the same), and the jnp gather out of the stack agrees."""
+    ctx = [T + 3, T, T + 9]
+    _, tables, kp, vp = _build_state(ctx)
+    rng = np.random.default_rng(7)
+    k_all, v_all = (jnp.asarray(rng.standard_normal((3,) + kp.shape),
+                                jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((3, T, H, D)), jnp.float32)
+    lengths = jnp.asarray(ctx, jnp.int32)
+    want = ragged_paged_attention_rect(q, k_all[layer], v_all[layer], tables,
+                                       lengths, interpret=True)
+    stacked = jax.jit(lambda lay, impl: paged_decode_attention(
+        q, PagedKVCache(k_all, v_all), tables, lengths, impl=impl,
+        interpret=True, layer=lay), static_argnums=1)
+    np.testing.assert_array_equal(
+        np.asarray(stacked(jnp.int32(layer), "pallas")), np.asarray(want))
+    np.testing.assert_allclose(
+        np.asarray(stacked(jnp.int32(layer), "jnp")), np.asarray(want),
+        **TOL)
+
+
 @pytest.mark.parametrize("q_tile", [3, 8])
 def test_rect_front_end_explicit_q_tile(q_tile):
     """``q_tile=`` overrides the picker: T=12 splits into several tiles
