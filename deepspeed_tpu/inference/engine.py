@@ -22,6 +22,7 @@ import numpy as np
 
 from deepspeed_tpu import comm as dist
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+from deepspeed_tpu.ops.decode_attention import init_cache
 from deepspeed_tpu.parallel import groups
 from deepspeed_tpu.parallel.topology import TP_AXIS, TopologyConfig
 from deepspeed_tpu.runtime.zero.stage_plan import ZeroShardingPlan
@@ -113,8 +114,8 @@ class InferenceEngine:
         layer-by-layer, double-buffered so the transfer of layer i+1
         overlaps layer i's compute (reference: ZeRO-3 param offload reused
         for inference, docs 2022-09-10-zero-inference.md)."""
-        assert hasattr(self.module, "config") and \
-            hasattr(self.module, "_layer_cached"), \
+        assert all(hasattr(self.module, name)
+                   for name in ("config", "embed", "block", "logits")), \
             "weight streaming needs a CausalTransformerLM-style module"
         c = self.module.config
         np_dtype = np.dtype(jnp.bfloat16 if self.dtype == jnp.bfloat16
@@ -227,37 +228,28 @@ class InferenceEngine:
         return dev
 
     def _streaming_apply_with_cache(self, input_ids, caches):
-        """Layer-streamed twin of ``CausalTransformerLM.apply_with_cache``
-        (list-of-caches layout; weights fetched per layer)."""
-        model, c = self.module, self.module.config
+        """Layer-streamed twin of ``CausalTransformerLM.apply_with_cache``,
+        made of the same embedding, block and head (list-of-caches layout;
+        weights fetched per layer)."""
+        model = self.module
         input_ids = jnp.asarray(input_ids, jnp.int32)
-        B, T = input_ids.shape
         start = caches[0].length
 
         if self._jit_embed is None:
             def embed(rest, ids, start):
                 positions = start + jnp.broadcast_to(
                     jnp.arange(ids.shape[1])[None, :], ids.shape)
-                x = rest["tok_embed"][ids]
-                if not c.use_rope:
-                    x = x + rest["pos_embed"][positions].astype(x.dtype)
-                return x, positions
+                return model.embed(rest, ids, positions), positions
             self._jit_embed = jax.jit(embed)
 
-            def layer_step(layer, x, ck, cv, length, positions):
+            def layer_step(layer, x, cache, positions):
                 layer = self._maybe_dequant(layer)   # int8 streams dequant
-                return model._layer_cached(x, layer, ck, cv, length,
-                                           positions)
+                x, cache, _ = model.block(x, layer, positions,
+                                          model.mix_cached, cache,
+                                          train=False)
+                return x, cache
             self._jit_layer = jax.jit(layer_step)
-
-            def head(rest, x):
-                from deepspeed_tpu.models.transformer import _norm
-                x = _norm(x, rest["final_norm"], c.norm_eps, c.use_rmsnorm,
-                          rest.get("final_norm_b"))
-                hd = (rest["tok_embed"].T if c.tie_embeddings
-                      else rest["lm_head"])
-                return (x @ hd.astype(x.dtype)).astype(jnp.float32)
-            self._jit_head = jax.jit(head)
+            self._jit_head = jax.jit(model.logits)
 
         x, positions = self._jit_embed(self.params, input_ids, start)
         new_caches = []
@@ -266,8 +258,7 @@ class InferenceEngine:
         for i in range(self._n_layers):
             # dispatch layer i (async on device), THEN wait for layer
             # i+1's host/NVMe transfer — so I/O overlaps compute
-            x, cache = self._jit_layer(nxt, x, caches[i].k, caches[i].v,
-                                       start, positions)
+            x, cache = self._jit_layer(nxt, x, caches[i], positions)
             new_caches.append(cache)
             if i + 1 < self._n_layers:
                 nxt = self._fetch_layer(i + 1)
@@ -277,7 +268,6 @@ class InferenceEngine:
         return self._jit_head(self.params, x), new_caches
 
     def _streaming_generate(self, input_ids, max_new_tokens):
-        from deepspeed_tpu.ops.decode_attention import init_cache
         c = self.module.config
         input_ids = jnp.asarray(input_ids, jnp.int32)
         B, S = input_ids.shape
@@ -379,7 +369,6 @@ class InferenceEngine:
         input_ids = jnp.asarray(input_ids)
         if self._streaming:
             if caches is None:
-                from deepspeed_tpu.ops.decode_attention import init_cache
                 c = self.module.config
                 caches = [init_cache(input_ids.shape[0],
                                      self._config.max_out_tokens,

@@ -42,6 +42,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from deepspeed_tpu.ops.paged_attention import ATTENTION_BACKENDS
 from deepspeed_tpu.runtime.config_utils import DeepSpeedConfigModel
 
 # ----------------------------------------------------------------------
@@ -132,10 +133,6 @@ SERVE_EVENTS = (
 # ``ServingEngine._TERMINAL_BY_STATUS`` ("drained" folds into "shed")
 TRACE_TERMINALS = ("finish", "shed", "deadline", "evict")
 
-# the serving.attention_backend vocabulary (mirrors
-# ops/paged_attention.py ATTENTION_BACKENDS; validated at config time so
-# a typo fails construction, not the first jitted step)
-ATTENTION_BACKENDS = ("auto", "jnp", "pallas", "pallas-interpret")
 
 
 class RequestRejected(Exception):
@@ -231,6 +228,8 @@ class ServingRobustnessConfig(DeepSpeedConfigModel):
         if self.overload_policy not in OVERLOAD_POLICIES:
             raise ValueError(
                 f"serving.overload_policy must be one of {OVERLOAD_POLICIES}")
+        # at config time, so a typo fails construction, not the first
+        # jitted step
         if self.attention_backend not in ATTENTION_BACKENDS:
             raise ValueError(
                 f"serving.attention_backend must be one of "

@@ -20,6 +20,7 @@ decoders).  TPU-first design:
 * logits/loss in fp32 (matching the reference's fused softmax numerics).
 """
 
+import functools
 import math
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, Optional, Tuple
@@ -643,25 +644,17 @@ class CausalTransformerLM:
             slopes=alibi_slopes(c.n_heads) if c.use_alibi else None,
             window=layer.get("attn_window"))
 
-    def _attn_block(self, x, layer, positions):
+    # ------------------------------------------------------------------
+    # The mixers: what turns one layer's q, k, v into its attention output,
+    # and what that does to the layer's cache on the way.  The one thing a
+    # forward hands to ``block``: ``mix(q, k, v, layer, cache) -> (attn
+    # [B, T, H, dh], cache)``.  A new kind of cache is one more of these.
+    # ------------------------------------------------------------------
+    def mix_full(self, q, k, v, layer, cache):
+        """Causal attention over the whole sequence, no cache (the trainer,
+        ``apply``)."""
         c = self.config
-        h = _pre_norm(x, layer, "attn_norm", c)
-        delta = self._attn_delta(h, layer, positions)
-        if "attn_post_norm" in layer:   # Gemma-2 sandwich: norm the
-            delta = _norm(delta, layer["attn_post_norm"], c.norm_eps,
-                          c.use_rmsnorm)   # sub-block OUTPUT pre-residual
-        if c.residual_scale is not None:   # Granite residual_multiplier
-            delta = delta * c.residual_scale
-        return x + delta
-
-    @jax.named_scope("attn")
-    def _attn_delta(self, h, layer, positions):
-        """Attention sub-block on pre-normed input; returns the residual
-        delta (wo projection applied, no residual add)."""
-        c = self.config
-        B, S, d = h.shape
-        H, Hkv, dh = c.n_heads, c.kv_heads, c.head_dim
-        q, k, v = self._qkv(h, layer, B, S, positions)
+        H, Hkv = c.n_heads, c.kv_heads
         has_alibi = c.use_alibi
         has_window = "attn_window" in layer
         on_cpu = jax.default_backend() in ("cpu",)
@@ -719,19 +712,72 @@ class CausalTransformerLM:
             raise ValueError(
                 f"unknown attn_impl '{c.attn_impl}'; expected one of "
                 "auto/pallas/reference/ring/ulysses")
-        return self._proj(attn.reshape(B, S, H * dh), layer, "wo")
+        return attn, cache
 
-    def _mlp_block(self, x, layer, rng=None, train=True):
-        """Dense or MoE FFN; returns (x, aux_loss)."""
+    def mix_cached(self, q, k, v, layer, cache):
+        """Append to one layer's dense ``KVCache`` and attend over it
+        (``apply_with_cache``, ``InferenceEngine``'s streamed forward)."""
         c = self.config
-        h = _pre_norm(x, layer, "mlp_norm", c)
-        delta, aux = self._mlp_delta(h, layer, rng=rng, train=train)
-        if "mlp_post_norm" in layer:    # Gemma-2 sandwich
-            delta = _norm(delta, layer["mlp_post_norm"], c.norm_eps,
-                          c.use_rmsnorm)
-        if c.residual_scale is not None:   # Granite residual_multiplier
-            delta = delta * c.residual_scale
-        return x + delta, aux
+        cache = update_cache(cache, k, v)
+        bias = self._cached_attn_bias(layer, q.shape[1], cache.k.shape[2],
+                                      cache.length)
+        attn = decode_attention(q, cache, softmax_scale=c.attn_scale,
+                                bias=bias,
+                                logit_softcap=c.attn_logit_softcap)
+        return attn, cache
+
+    def mix_paged(self, q, k, v, layer, pools, *, index, block_tables,
+                  lengths, impl, interpret):
+        """Write this layer's rows into the STACKED page pools at
+        ``lengths`` and attend over each sequence's ragged prefix, both in
+        place by (traced) layer ``index`` (``apply_with_paged_cache``
+        binds the keywords)."""
+        from deepspeed_tpu.ops.paged_attention import (paged_decode_attention,
+                                                       write_paged)
+        c = self.config
+        pools = write_paged(pools, index, block_tables, lengths, k, v,
+                            impl=impl, interpret=interpret)
+        # NOTE: ALiBi / local-window models are not yet served paged
+        # (their additive bias needs per-batch ragged positions the
+        # paged kernels don't take); init_paged_caches guards this
+        attn = paged_decode_attention(q, pools, block_tables,
+                                      lengths + q.shape[1],
+                                      softmax_scale=c.attn_scale,
+                                      impl=impl, interpret=interpret,
+                                      logit_softcap=c.attn_logit_softcap,
+                                      layer=index)
+        return attn, pools
+
+    def _cached_attn_bias(self, layer, T, S, length):
+        """Decode-path analogue of ``_attn_bias`` over the full cache
+        buffer [S]; query positions are ``length - T + arange(T)``."""
+        c = self.config
+        bias = None
+        if c.use_alibi:
+            bias = (alibi_slopes(c.n_heads)[None, :, None, None] *
+                    jnp.arange(S, dtype=jnp.float32)[None, None, None, :])
+        if "attn_window" in layer:
+            w = layer["attn_window"]
+            qpos = length - T + jnp.arange(T, dtype=jnp.int32)[:, None]
+            delta = qpos - jnp.arange(S, dtype=jnp.int32)[None, :]
+            allowed = (delta < w) | (w <= 0)
+            wbias = jnp.where(allowed, 0.0, -1e30).astype(jnp.float32)
+            bias = wbias if bias is None else bias + wbias
+        return bias
+
+    # ------------------------------------------------------------------
+    # The block, stated once.  Every forward calls it and supplies a mixer.
+    # ------------------------------------------------------------------
+    @jax.named_scope("attn")
+    def _attn_delta(self, h, layer, positions, mix, cache):
+        """Attention sub-block on pre-normed input → (residual delta with
+        the wo projection applied and no residual add, cache)."""
+        c = self.config
+        B, T, _ = h.shape
+        q, k, v = self._qkv(h, layer, B, T, positions)
+        attn, cache = mix(q, k, v, layer, cache)
+        return self._proj(attn.reshape(B, T, c.n_heads * c.head_dim), layer,
+                          "wo"), cache
 
     @jax.named_scope("mlp")
     def _mlp_delta(self, h, layer, rng=None, train=True):
@@ -780,49 +826,117 @@ class CausalTransformerLM:
             inner = act(self._proj(h, layer, "w_up"))
         return self._proj(inner, layer, "w_down"), jnp.float32(0.0)
 
-    def _layer(self, x, layer, positions, rng=None, train=True):
+    def _sandwich(self, delta, layer, key):
+        """A sub-block's output on its way to the residual add."""
+        c = self.config
+        if key in layer:   # Gemma-2 sandwich / OLMo2: norm the
+            delta = _norm(delta, layer[key], c.norm_eps,
+                          c.use_rmsnorm)   # sub-block OUTPUT pre-residual
+        if c.residual_scale is not None:   # Granite residual_multiplier
+            delta = delta * c.residual_scale
+        return delta
+
+    def block(self, x, layer, positions, mix, cache=None, rng=None,
+              train=True):
+        """One transformer block → (x, cache, aux_loss): the residual
+        structure, pre-norms, q/k/v, ``wo``, sandwich norms, residual scale
+        and the MLP (dense or MoE).  ``mix`` (one of the mixers above) is
+        all a forward chooses; ``cache`` goes into it and comes out."""
         c = self.config
         if c.parallel_block:
             # GPT-J / parallel-residual NeoX: both sub-blocks read the
             # residual stream, one fused add (GPT-J shares one LN — the
             # policy duplicates it into attn_norm/mlp_norm; NeoX parallel
-            # keeps two distinct LNs)
+            # keeps two distinct LNs).  No sandwich norms here: no policy
+            # builds that pair
             ha = _pre_norm(x, layer, "attn_norm", c)
             hm = _pre_norm(x, layer, "mlp_norm", c)
             mlp, aux = self._mlp_delta(hm, layer, rng=rng, train=train)
-            attn = self._attn_delta(ha, layer, positions)
+            attn, cache = self._attn_delta(ha, layer, positions, mix, cache)
             if c.residual_scale is not None:   # Granite-style multiplier
                 attn = attn * c.residual_scale
                 mlp = mlp * c.residual_scale
-            return x + attn + mlp, aux
-        x = self._attn_block(x, layer, positions)
-        return self._mlp_block(x, layer, rng=rng, train=train)
+            return x + attn + mlp, cache, aux
+        h = _pre_norm(x, layer, "attn_norm", c)
+        delta, cache = self._attn_delta(h, layer, positions, mix, cache)
+        x = x + self._sandwich(delta, layer, "attn_post_norm")
+        h = _pre_norm(x, layer, "mlp_norm", c)
+        delta, aux = self._mlp_delta(h, layer, rng=rng, train=train)
+        return x + self._sandwich(delta, layer, "mlp_post_norm"), cache, aux
+
+    def _layer(self, x, layer, positions, rng=None, train=True):
+        """The block over a whole sequence → (x, aux): what ``apply``
+        scans and ``stream_layer`` / ``runtime/pipe`` call."""
+        x, _, aux = self.block(x, layer, positions, self.mix_full, rng=rng,
+                               train=train)
+        return x, aux
+
+    # ------------------------------------------------------------------
+    # The embedding and the head, stated once.
+    # ------------------------------------------------------------------
+    @jax.named_scope("embed")
+    def embed(self, params, input_ids, positions):
+        """Token ids [B, T] at ``positions`` [B, T] → the first hidden
+        state: table, scale, learned positions, embedding norm."""
+        c = self.config
+        x = params["tok_embed"][input_ids]
+        if c.embed_scale is not None:   # Gemma: sqrt(d) on the
+            x = x * jnp.asarray(c.embed_scale, x.dtype)  # input side only
+        if not c.use_rope and not c.use_alibi:
+            x = x + params["pos_embed"][positions].astype(x.dtype)
+        if c.embed_norm:
+            x = _norm(x, params["embed_norm"], c.norm_eps, c.use_rmsnorm,
+                      params.get("embed_norm_b"))
+        return x
+
+    def final_norm(self, params, x):
+        """The norm between the last block and the head."""
+        c = self.config
+        return _norm(x, params["final_norm"], c.norm_eps, c.use_rmsnorm,
+                     params.get("final_norm_b"))
+
+    def head_table(self, params):
+        """The LM head's [d, V] table, tied or not."""
+        return (params["tok_embed"].T if self.config.tie_embeddings
+                else params["lm_head"])
+
+    def logits(self, params, x):
+        """The last hidden state → float32 logits: final norm, table,
+        bias, scale, softcap."""
+        c = self.config
+        x = self.final_norm(params, x)
+        with jax.named_scope("loss_head"):
+            logits = (x @ self.head_table(params).astype(x.dtype)
+                      ).astype(jnp.float32)
+            if "lm_head_b" in params:
+                logits = logits + params["lm_head_b"].astype(jnp.float32)
+            if c.final_logit_scale is not None:   # Cohere logit_scale
+                logits = logits * c.final_logit_scale
+            return _softcap(logits, c.final_logit_softcap)
+
+    def _windows(self):
+        """Per-layer local-attention windows ride the layer loops as a side
+        input (NOT a param leaf: integer leaves would break jax.grad)."""
+        c = self.config
+        return (jnp.asarray(c.local_attn_pattern, jnp.int32)
+                if c.local_attn_pattern else None)
 
     def apply(self, params, input_ids, positions=None, rng=None, train=True,
               return_aux=False, return_hidden=False):
+        """Logits [B, S, V] of a whole sequence; ``return_hidden`` gives the
+        last hidden state BEFORE the final norm (what ``stream_head_loss``
+        takes) and the MoE aux loss."""
         c = self.config
         B, S = input_ids.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
 
-        with jax.named_scope("embed"):
-            x = params["tok_embed"][input_ids]
-            if c.embed_scale is not None:   # Gemma: sqrt(d) on the
-                x = x * jnp.asarray(c.embed_scale, x.dtype)  # input side
-
-            if not c.use_rope and not c.use_alibi:
-                x = x + params["pos_embed"][positions].astype(x.dtype)
-            if c.embed_norm:
-                x = _norm(x, params["embed_norm"], c.norm_eps,
-                          c.use_rmsnorm, params.get("embed_norm_b"))
-            # activation layout: batch over all data axes, sequence over sp
-            x = maybe_constrain(x, P(tuple(BATCH_AXES), SP_AXIS, None))
+        # activation layout: batch over all data axes, sequence over sp
+        x = maybe_constrain(self.embed(params, input_ids, positions),
+                            P(tuple(BATCH_AXES), SP_AXIS, None))
 
         aux = jnp.float32(0.0)
-        # per-layer local-attention windows ride the scan as a side input
-        # (NOT a param leaf: integer leaves would break jax.grad)
-        windows = (jnp.asarray(c.local_attn_pattern, jnp.int32)
-                   if c.local_attn_pattern else None)
+        windows = self._windows()
         if isinstance(params["layers"], (list, tuple)):
             # MoE / heterogeneous stack: unrolled layer loop
             layer_fn = self._layer
@@ -854,19 +968,9 @@ class CausalTransformerLM:
             x, l_auxs = layer_scan(body, x, xs)
             aux = jnp.sum(l_auxs)
 
-        x = _norm(x, params["final_norm"], c.norm_eps, c.use_rmsnorm,
-                  params.get("final_norm_b"))
         if return_hidden:
             return x, aux
-        with jax.named_scope("loss_head"):
-            head = (params["tok_embed"].T if c.tie_embeddings
-                    else params["lm_head"])
-            logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
-            if "lm_head_b" in params:
-                logits = logits + params["lm_head_b"].astype(jnp.float32)
-            if c.final_logit_scale is not None:   # Cohere logit_scale
-                logits = logits * c.final_logit_scale
-            logits = _softcap(logits, c.final_logit_softcap)
+        logits = self.logits(params, x)
         if return_aux:
             return logits, aux
         return logits
@@ -890,81 +994,26 @@ class CausalTransformerLM:
             v=jnp.broadcast_to(one.v[None], (c.n_layers,) + one.v.shape).copy(),
             length=one.length)
 
-    def _cached_attn_bias(self, layer, T, S, length):
-        """Decode-path analogue of ``_attn_bias`` over the full cache
-        buffer [S]; query positions are ``length - T + arange(T)``."""
-        c = self.config
-        bias = None
-        if c.use_alibi:
-            bias = (alibi_slopes(c.n_heads)[None, :, None, None] *
-                    jnp.arange(S, dtype=jnp.float32)[None, None, None, :])
-        if "attn_window" in layer:
-            w = layer["attn_window"]
-            qpos = length - T + jnp.arange(T, dtype=jnp.int32)[:, None]
-            delta = qpos - jnp.arange(S, dtype=jnp.int32)[None, :]
-            allowed = (delta < w) | (w <= 0)
-            wbias = jnp.where(allowed, 0.0, -1e30).astype(jnp.float32)
-            bias = wbias if bias is None else bias + wbias
-        return bias
-
-    def _layer_cached(self, x, layer, cache_k, cache_v, length, positions):
-        c = self.config
-        B, T, d = x.shape
-        H, Hkv, dh = c.n_heads, c.kv_heads, c.head_dim
-        h = _pre_norm(x, layer, "attn_norm", c)
-        q, k, v = self._qkv(h, layer, B, T, positions)
-        cache = update_cache(KVCache(k=cache_k, v=cache_v, length=length), k, v)
-        bias = self._cached_attn_bias(layer, T, cache.k.shape[2],
-                                      cache.length)
-        attn = decode_attention(q, cache, softmax_scale=c.attn_scale,
-                                bias=bias,
-                                logit_softcap=c.attn_logit_softcap)
-        attn_delta = self._proj(attn.reshape(B, T, H * dh), layer, "wo")
-        if "attn_post_norm" in layer:   # Gemma-2 sandwich (decode too)
-            attn_delta = _norm(attn_delta, layer["attn_post_norm"],
-                               c.norm_eps, c.use_rmsnorm)
-        if c.residual_scale is not None:   # Granite residual_multiplier
-            attn_delta = attn_delta * c.residual_scale
-        if c.parallel_block:
-            hm = _pre_norm(x, layer, "mlp_norm", c)
-            mlp_delta, _ = self._mlp_delta(hm, layer, train=False)
-            if c.residual_scale is not None:
-                mlp_delta = mlp_delta * c.residual_scale
-            return x + attn_delta + mlp_delta, cache
-        x = x + attn_delta
-        x, _ = self._mlp_block(x, layer, train=False)
-        return x, cache
-
     def apply_with_cache(self, params, input_ids, caches):
         """Forward for prefill (T=prompt) or decode (T=1), appending to
         ``caches``.  Returns (logits [B,T,V], new caches)."""
-        c = self.config
         B, T = input_ids.shape
         if isinstance(caches, list):
             start = caches[0].length
         else:
             start = caches.length
         positions = start + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-        x = params["tok_embed"][input_ids]
-        if c.embed_scale is not None:   # Gemma: sqrt(d) on the
-            x = x * jnp.asarray(c.embed_scale, x.dtype)  # input side only
+        x = self.embed(params, input_ids, positions)
 
-        if not c.use_rope and not c.use_alibi:
-            x = x + params["pos_embed"][positions].astype(x.dtype)
-        if c.embed_norm:
-            x = _norm(x, params["embed_norm"], c.norm_eps, c.use_rmsnorm,
-                      params.get("embed_norm_b"))
-
-        windows = (jnp.asarray(c.local_attn_pattern, jnp.int32)
-                   if c.local_attn_pattern else None)
+        windows = self._windows()
         if isinstance(caches, list):  # MoE / heterogeneous stack
             new_caches = []
             for i, (layer, cache) in enumerate(zip(params["layers"], caches)):
                 if windows is not None:
                     layer = dict(layer, attn_window=windows[i])
-                x, nc = self._layer_cached(x, layer, cache.k, cache.v,
-                                           start, positions)
-                new_caches.append(nc)
+                x, cache, _ = self.block(x, layer, positions,
+                                         self.mix_cached, cache, train=False)
+                new_caches.append(cache)
             out_caches = new_caches
         else:
             def body(x, inp):
@@ -972,8 +1021,9 @@ class CausalTransformerLM:
                 if windows is not None:
                     layer, w = layer
                     layer = dict(layer, attn_window=w)
-                x, cache = self._layer_cached(x, layer, ck, cv, start,
-                                              positions)
+                x, cache, _ = self.block(
+                    x, layer, positions, self.mix_cached,
+                    KVCache(k=ck, v=cv, length=start), train=False)
                 return x, (cache.k, cache.v)
 
             lxs = (params["layers"] if windows is None
@@ -982,17 +1032,7 @@ class CausalTransformerLM:
                 body, x, (lxs, caches.k, caches.v))
             out_caches = KVCache(k=new_k, v=new_v, length=start + T)
 
-        x = _norm(x, params["final_norm"], c.norm_eps, c.use_rmsnorm,
-                  params.get("final_norm_b"))
-        head = (params["tok_embed"].T if c.tie_embeddings
-                else params["lm_head"])
-        logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
-        if "lm_head_b" in params:
-            logits = logits + params["lm_head_b"].astype(jnp.float32)
-        if c.final_logit_scale is not None:   # Cohere logit_scale
-            logits = logits * c.final_logit_scale
-        logits = _softcap(logits, c.final_logit_softcap)
-        return logits, out_caches
+        return self.logits(params, x), out_caches
 
     # ------------------------------------------------------------------
     # paged KV-cache path (continuous-batching serving engine)
@@ -1026,67 +1066,31 @@ class CausalTransformerLM:
         fused ragged kernel; interpret runs the kernel on CPU) — static
         kwargs, so the serving engine binds them before jit.
         """
-        from deepspeed_tpu.ops.paged_attention import (paged_decode_attention,
-                                                       resolve_paged_impl,
-                                                       write_paged)
+        from deepspeed_tpu.ops.paged_attention import resolve_paged_impl
         c = self.config
         B, T = input_ids.shape
         positions = lengths[:, None] + jnp.broadcast_to(
             jnp.arange(T)[None, :], (B, T))
-        x = params["tok_embed"][input_ids]
-        if c.embed_scale is not None:   # Gemma: sqrt(d) on the
-            x = x * jnp.asarray(c.embed_scale, x.dtype)  # input side only
-
-        if not c.use_rope and not c.use_alibi:
-            x = x + params["pos_embed"][positions].astype(x.dtype)
-        if c.embed_norm:
-            x = _norm(x, params["embed_norm"], c.norm_eps, c.use_rmsnorm,
-                      params.get("embed_norm_b"))
-
-        H, dh = c.n_heads, c.head_dim
+        x = self.embed(params, input_ids, positions)
         # one backend for the write and the read of the pools
         impl = resolve_paged_impl(attn_backend, c.attn_logit_softcap)
 
         def body(carry, inp):
             # the stacked pools stay ONE buffer through the layers: carried,
             # written in place, read in place by layer index
-            x, cache = carry
+            x, pools = carry
             layer, i = inp
-            h = _pre_norm(x, layer, "attn_norm", c)
-            q, k, v = self._qkv(h, layer, B, T, positions)
-            cache = write_paged(cache, i, block_tables, lengths, k, v,
-                                impl=impl, interpret=attn_interpret)
-            # NOTE: ALiBi / local-window models are not yet served paged
-            # (their additive bias needs per-batch ragged positions the
-            # paged kernels don't take); init_paged_caches guards this
-            attn = paged_decode_attention(q, cache, block_tables,
-                                          lengths + T,
-                                          softmax_scale=c.attn_scale,
-                                          impl=impl,
-                                          interpret=attn_interpret,
-                                          logit_softcap=c.attn_logit_softcap,
-                                          layer=i)
-            attn_delta = self._proj(attn.reshape(B, T, H * dh), layer, "wo")
-            if "attn_post_norm" in layer:   # Gemma-2 sandwich
-                attn_delta = _norm(attn_delta, layer["attn_post_norm"],
-                                   c.norm_eps, c.use_rmsnorm)
-            if c.residual_scale is not None:   # Granite
-                attn_delta = attn_delta * c.residual_scale
-            if c.parallel_block:
-                hm = _pre_norm(x, layer, "mlp_norm", c)
-                mlp_delta, _ = self._mlp_delta(hm, layer, train=False)
-                if c.residual_scale is not None:
-                    mlp_delta = mlp_delta * c.residual_scale
-                x = x + attn_delta + mlp_delta
-            else:
-                x = x + attn_delta
-                x, _ = self._mlp_block(x, layer, train=False)
-            return (x, cache), None
+            mix = functools.partial(
+                self.mix_paged, index=i, block_tables=block_tables,
+                lengths=lengths, impl=impl, interpret=attn_interpret)
+            x, pools, _ = self.block(x, layer, positions, mix, pools,
+                                     train=False)
+            return (x, pools), None
 
         if isinstance(params["layers"], (list, tuple)):
             # MoE / heterogeneous stack: static per-layer loop (expert
             # leaves carry an [E, ...] dim sharded over ep at serve time —
-            # the MoE dispatch inside _mlp_block lowers to the same
+            # the MoE dispatch inside the block lowers to the same
             # all-to-alls as training, reference megatron_gpt_moe serving)
             carry = (x, caches)
             for i, layer in enumerate(params["layers"]):
@@ -1097,17 +1101,7 @@ class CausalTransformerLM:
                 body, (x, caches),
                 (params["layers"], jnp.arange(c.n_layers)))
 
-        x = _norm(x, params["final_norm"], c.norm_eps, c.use_rmsnorm,
-                  params.get("final_norm_b"))
-        head = (params["tok_embed"].T if c.tie_embeddings
-                else params["lm_head"])
-        logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
-        if "lm_head_b" in params:
-            logits = logits + params["lm_head_b"].astype(jnp.float32)
-        if c.final_logit_scale is not None:   # Cohere logit_scale
-            logits = logits * c.final_logit_scale
-        logits = _softcap(logits, c.final_logit_softcap)
-        return logits, caches, lengths + T
+        return self.logits(params, x), caches, lengths + T
 
     # ------------------------------------------------------------------
     # layer-stream contract (training-time parameter offload —
@@ -1131,19 +1125,11 @@ class CausalTransformerLM:
     def stream_embed(self, resident, batch, rng=None):
         """Embedding front of ``apply`` → (x, positions)."""
         del rng
-        c = self.config
         input_ids = batch["input_ids"] if isinstance(batch, dict) else batch
         B, S = input_ids.shape
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-        x = resident["tok_embed"][input_ids]
-        if c.embed_scale is not None:
-            x = x * jnp.asarray(c.embed_scale, x.dtype)
-        if not c.use_rope and not c.use_alibi:
-            x = x + resident["pos_embed"][positions].astype(x.dtype)
-        if c.embed_norm:
-            x = _norm(x, resident["embed_norm"], c.norm_eps, c.use_rmsnorm,
-                      resident.get("embed_norm_b"))
-        x = maybe_constrain(x, P(tuple(BATCH_AXES), SP_AXIS, None))
+        x = maybe_constrain(self.embed(resident, input_ids, positions),
+                            P(tuple(BATCH_AXES), SP_AXIS, None))
         return x, positions
 
     def stream_layer(self, layer, x, positions, window=None, rng=None,
@@ -1156,44 +1142,23 @@ class CausalTransformerLM:
         return self._layer(x, layer, positions, rng, train)
 
     def stream_head_loss(self, resident, x, batch):
-        """Final norm + LM head + next-token cross-entropy on the streamed
+        """Final norm + LM head + next-token cross-entropy on the last
         hidden state — the tail of ``loss`` (chunked logits included)."""
         c = self.config
-        x = _norm(x, resident["final_norm"], c.norm_eps, c.use_rmsnorm,
-                  resident.get("final_norm_b"))
-        head = (resident["tok_embed"].T if c.tie_embeddings
-                else resident["lm_head"])
         if c.loss_chunk_size and c.loss_chunk_size > 0:
             return chunked_next_token_xent(
-                x, head, resident.get("lm_head_b"), batch, c.loss_chunk_size,
+                self.final_norm(resident, x), self.head_table(resident),
+                resident.get("lm_head_b"), batch, c.loss_chunk_size,
                 logit_softcap=c.final_logit_softcap,
                 logit_scale=c.final_logit_scale)
-        logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
-        if "lm_head_b" in resident:
-            logits = logits + resident["lm_head_b"].astype(jnp.float32)
-        if c.final_logit_scale is not None:
-            logits = logits * c.final_logit_scale
-        logits = _softcap(logits, c.final_logit_softcap)
-        return next_token_xent(logits, batch)
+        return next_token_xent(self.logits(resident, x), batch)
 
     # ------------------------------------------------------------------
     def loss(self, params, batch, rng=None):
         """Next-token cross-entropy.  batch: dict with ``input_ids`` [B,S]
         (+ optional ``labels``, ``loss_mask``) or a raw [B,S] array."""
-        c = self.config
         input_ids = batch["input_ids"] if isinstance(batch, dict) else batch
-        if c.loss_chunk_size and c.loss_chunk_size > 0:
-            x, aux = self.apply(params, input_ids, rng=rng,
-                                return_hidden=True)
-            head = (params["tok_embed"].T if c.tie_embeddings
-                    else params["lm_head"])
-            ce = chunked_next_token_xent(x, head, params.get("lm_head_b"),
-                                         batch, c.loss_chunk_size,
-                                         logit_softcap=c.final_logit_softcap,
-                                         logit_scale=c.final_logit_scale)
-        else:
-            logits, aux = self.apply(params, input_ids, rng=rng,
-                                     return_aux=True)
-            ce = next_token_xent(logits, batch)
+        x, aux = self.apply(params, input_ids, rng=rng, return_hidden=True)
+        ce = self.stream_head_loss(params, x, batch)
         # MoE load-balancing loss (reference engine adds l_aux scaled by coef)
-        return ce + c.moe_aux_loss_coef * aux
+        return ce + self.config.moe_aux_loss_coef * aux

@@ -52,9 +52,11 @@ def main(argv=None):
     interp = bool(args.interpret)
 
     from deepspeed_tpu.models.transformer import alibi_slopes
-    from deepspeed_tpu.ops.pallas.decode_attention import (
-        decode_attention_pallas, paged_attention_pallas)
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        decode_attention_pallas
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention, ragged_paged_attention_rect)
     from deepspeed_tpu.ops.pallas.fused_adam import fused_adam_pallas
     from deepspeed_tpu.ops.pallas.sparse_attention import \
         sparse_attention_pallas
@@ -108,15 +110,13 @@ def main(argv=None):
     kp = jax.random.normal(rng, (npages * B, 2, page, D), jnp.bfloat16)
     tables = jnp.arange(B * npages, dtype=jnp.int32).reshape(B, npages)
     rows.append(_gate("decode_paged",
-                      lambda q, kp, vp, t, ln: paged_attention_pallas(
+                      lambda q, kp, vp, t, ln: ragged_paged_attention_rect(
                           q, kp, vp, t, ln, interpret=interp),
                       qd, kp, kp, tables, lengths))
 
     # fused ragged paged attention (one kernel, mixed prefill+decode):
     # gate pure-decode, pure-prefill, and mixed ragged shapes over the
     # same page pool — q_lens is host metadata, so it closes over the fn
-    from deepspeed_tpu.ops.pallas.ragged_paged_attention import \
-        ragged_paged_attention
 
     def ragged(name, q_lens, ctx_lens):
         qr = jax.random.normal(rng, (sum(q_lens), H, D), jnp.bfloat16)

@@ -29,7 +29,7 @@ config strings onto the pair.  Page allocation is host-side
 
 import math
 from collections import OrderedDict
-from typing import List, NamedTuple, Optional, Tuple
+from typing import List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -41,7 +41,8 @@ class PagedKVCache(NamedTuple):
     v_pages: jnp.ndarray
 
 
-# public vocabulary for serving.attention_backend (docs/config-json.md)
+# THE vocabulary of serving.attention_backend (docs/config-json.md), read
+# by resolve_attention_backend alone
 ATTENTION_BACKENDS = ("auto", "jnp", "pallas", "pallas-interpret")
 
 
@@ -72,54 +73,12 @@ def resolve_paged_impl(impl, logit_softcap=None):
     return "pallas" if use_pallas(impl) and not logit_softcap else "jnp"
 
 
-def init_paged_cache(num_pages, page_size, n_kv_heads, head_dim,
-                     dtype=jnp.bfloat16) -> PagedKVCache:
-    shape = (num_pages, n_kv_heads, page_size, head_dim)
-    return PagedKVCache(k_pages=jnp.zeros(shape, dtype),
-                        v_pages=jnp.zeros(shape, dtype))
-
-
-def append_paged(cache: PagedKVCache, block_tables, lengths, k_new, v_new
-                 ) -> Tuple[PagedKVCache, jnp.ndarray]:
-    """Append ONE token per sequence (decode step).
-
-    k_new/v_new: [B, 1, Hkv, D].  Returns (cache, new lengths).  The pages
-    written must already be mapped in ``block_tables`` (allocator's job).
-    """
-    B = k_new.shape[0]
-    page_size = cache.k_pages.shape[2]
-    page_idx = jnp.take_along_axis(
-        block_tables, (lengths // page_size)[:, None], axis=1)[:, 0]
-    offset = lengths % page_size
-    k = cache.k_pages.at[page_idx, :, offset].set(
-        k_new[:, 0].astype(cache.k_pages.dtype))
-    v = cache.v_pages.at[page_idx, :, offset].set(
-        v_new[:, 0].astype(cache.v_pages.dtype))
-    return PagedKVCache(k_pages=k, v_pages=v), lengths + 1
-
-
 def _row_targets(block_tables, lengths, T, page_size):
     """(page ids, in-page rows), both [B, T], of the T rows a sequence
     writes from ``lengths`` on."""
     pos = lengths[:, None] + jnp.arange(T)[None, :]          # [B, T]
     return (jnp.take_along_axis(block_tables, pos // page_size, axis=1),
             pos % page_size)
-
-
-def prefill_paged(cache: PagedKVCache, block_tables, lengths, k_new, v_new
-                  ) -> Tuple[PagedKVCache, jnp.ndarray]:
-    """Write a whole prompt [B, T, Hkv, D] starting at ``lengths`` (which is
-    typically zero)."""
-    T = k_new.shape[1]
-    page_idx, offset = _row_targets(block_tables, lengths, T,
-                                    cache.k_pages.shape[2])
-    # advanced indices (page_idx, offset) around the ':' slice put their
-    # broadcast dims first: the set value is [B, T, Hkv, D] = k_new's layout
-    k = cache.k_pages.at[page_idx, :, offset].set(
-        k_new.astype(cache.k_pages.dtype))
-    v = cache.v_pages.at[page_idx, :, offset].set(
-        v_new.astype(cache.v_pages.dtype))
-    return PagedKVCache(k_pages=k, v_pages=v), lengths + T
 
 
 def write_paged(cache: PagedKVCache, layer, block_tables, lengths, k_new,
@@ -154,23 +113,18 @@ def paged_decode_attention(q, cache: PagedKVCache, block_tables, lengths,
                            impl: Optional[str] = None,
                            interpret: bool = False,
                            logit_softcap: Optional[float] = None,
-                           backend: Optional[str] = None,
                            layer=None):
     """q: [B, T, H, D] — the last T tokens of each sequence (T=1 decode).
     With ``layer`` (may be traced) ``cache`` holds the stacked pools
     [L, P, Hkv, page, D] and that layer is read in place.
 
-    ``impl``: None (auto: Pallas kernel on TPU, jnp elsewhere), "pallas",
-    or "jnp"; ``backend`` is the serving-config spelling ("auto" | "jnp" |
-    "pallas" | "pallas-interpret") and overrides ``impl``/``interpret``
-    when given.  The Pallas path is the fused ragged kernel
+    ``impl`` and ``interpret`` as :func:`resolve_attention_backend` gives
+    them: None (auto: Pallas kernel on TPU, jnp elsewhere), "pallas", or
+    "jnp".  The Pallas path is the fused ragged kernel
     (``ops/pallas/ragged_paged_attention.py``); the jnp path gathers each
     sequence's pages into its logical view and runs masked attention over
     the valid ragged prefix — it is the oracle the kernel is tested
     against.  ``logit_softcap`` is jnp-only and forces the fallback."""
-    if backend is not None:
-        impl, forced = resolve_attention_backend(backend)
-        interpret = interpret or forced
     if resolve_paged_impl(impl, logit_softcap) == "pallas":
         from deepspeed_tpu.ops.pallas.ragged_paged_attention import \
             ragged_paged_attention_rect

@@ -1,11 +1,11 @@
-"""Pallas decode attention with KV cache (contiguous and paged).
+"""Pallas decode attention over the contiguous KV cache.
 
 Parity role: the reference's fused inference attention
 ``softmax_context_fp16`` (``csrc/transformer/inference/csrc/pt_binding.cpp``
 ~:1720) — attention over a growing KV cache, GQA-aware, without
 materialising logits in HBM.
 
-TPU design (one kernel body, two front-ends):
+TPU design:
 
 * grid = (batch, kv_heads, key_blocks); the per-sequence valid length is a
   **scalar-prefetch** operand so both the BlockSpec index maps and the
@@ -16,14 +16,12 @@ TPU design (one kernel body, two front-ends):
 * online softmax (running max / sum / accumulator in VMEM scratch that
   persists across the key-block grid dimension), fp32 accumulation, one
   [group·T, D] output tile per (batch, kv head);
-* GQA comes free: the q tile for one kv head is its whole head group;
-* the paged front-end (``paged_attention_pallas``) is now a deprecated
-  shim over the fused ragged kernel in
-  ``ops/pallas/ragged_paged_attention.py`` — one paged-attention kernel
-  surface for decode, prefill, and mixed ragged batches.
+* GQA comes free: the q tile for one kv head is its whole head group.
 
-The jnp paths in ``ops/decode_attention.py`` / ``ops/paged_attention.py``
-remain the test oracles; ``interpret=True`` runs this kernel on CPU CI.
+The paged pools have their own kernel,
+``ops/pallas/ragged_paged_attention.py``.  The jnp path in
+``ops/decode_attention.py`` remains the test oracle; ``interpret=True``
+runs this kernel on CPU CI.
 """
 
 import functools
@@ -154,23 +152,3 @@ def decode_attention_pallas(q, k, v, lengths, softmax_scale=None,
     )(lengths, qg, k, v)
     return out.reshape(B, T, H, D)
 
-
-def paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
-                           softmax_scale=None, interpret=False):
-    """DEPRECATED: delegate to the fused ragged kernel.
-
-    The decode-only paged kernel that used to live here is subsumed by
-    ``ops/pallas/ragged_paged_attention.py`` (one kernel surface for
-    decode, prefill, and mixed ragged batches).  This shim keeps the old
-    signature — q: [B, T, H, D]; k_pages/v_pages: [P, Hkv, page_size, D];
-    block_tables: [B, max_pages] int32; lengths: [B] int32 — and routes
-    through the rectangular front-end, which for T=1 does identical work
-    (one q row per sequence, pages resolved through the block table).
-    New callers should use ``paged_decode_attention`` in
-    ``ops/paged_attention.py`` or the ragged entry points directly.
-    """
-    from deepspeed_tpu.ops.pallas.ragged_paged_attention import \
-        ragged_paged_attention_rect
-    return ragged_paged_attention_rect(q, k_pages, v_pages, block_tables,
-                                       lengths, softmax_scale=softmax_scale,
-                                       interpret=interpret)

@@ -45,8 +45,8 @@ through the block table and serves a whole mixed batch in one launch:
 ``ragged_paged_attention`` is the packed front-end (tests/bench/gate);
 ``ragged_paged_attention_rect`` adapts the rectangular ``[B, T, H, D]``
 calls the jitted serving path makes (every sequence q_len = T) onto the
-same kernel — it is what ``paged_decode_attention(backend="pallas")``
-and the deprecated ``paged_attention_pallas`` route through, so there is
+same kernel — it is what ``paged_decode_attention(impl="pallas")``
+(``ops/paged_attention.py``) routes through, so there is
 one paged-attention kernel surface.  The jnp gather path remains the
 oracle; ``interpret=True`` runs this kernel on CPU CI.
 
@@ -481,7 +481,7 @@ def paged_kv_write(k_pages, v_pages, layer, block_tables, lengths, k_new,
     jit that donates them, or in a loop's carry, the pools are never
     copied); k_new/v_new: [B, T, Hkv, D], written at positions
     ``lengths[b] + arange(T)`` through ``block_tables`` exactly as
-    ``prefill_paged`` resolves them (columns past the table clamp to its
+    ``write_paged`` resolves them (columns past the table clamp to its
     last one, the engine's overrun column on the scratch page).  ``layer``
     and ``lengths`` may be traced.
 

@@ -75,20 +75,15 @@ class DeepSpeedTransformerLayer:
         B, S, _ = hidden_states.shape
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
         layer = jax.tree_util.tree_map(lambda x: x[0], params)  # drop L dim
-        if self.causal:
-            x = self._lm._attn_block(hidden_states, layer, positions)
-        else:
+        mix = self._lm.mix_full
+        if not self.causal:
             # bidirectional: reference BERT-style full attention
             from deepspeed_tpu.ops.attention import reference_attention
-            c = self.model_config
-            from deepspeed_tpu.models.transformer import _norm
-            h = _norm(hidden_states, layer["attn_norm"], c.norm_eps,
-                      c.use_rmsnorm, layer.get("attn_norm_b"))
-            q, k, v = self._lm._qkv(h, layer, B, S, positions)
-            attn = reference_attention(q, k, v, causal=False)
-            x = hidden_states + self._lm._proj(
-                attn.reshape(B, S, -1), layer, "wo")
-        x, _ = self._lm._mlp_block(x, layer, rng=rng, train=self.config.training)
+
+            def mix(q, k, v, layer, cache):
+                return reference_attention(q, k, v, causal=False), cache
+        x, _, _ = self._lm.block(hidden_states, layer, positions, mix,
+                                 rng=rng, train=self.config.training)
         return x
 
     forward = __call__

@@ -136,6 +136,7 @@ class EmbeddingPipe:
 
     def __init__(self, config: TransformerConfig):
         self.config = config
+        self._model = CausalTransformerLM(config)
 
     def init(self, rng, dtype=jnp.float32):
         c = self.config
@@ -144,11 +145,13 @@ class EmbeddingPipe:
             # untied: the embedding matrix is a local param; tied models get
             # it from tied_init via the "embed" tied group instead
             params.update(self.tied_init(rng, dtype))
-        if not c.use_rope:
+        if not c.use_rope and not c.use_alibi:
             params["pos_embed"] = (
                 jax.random.normal(jax.random.fold_in(rng, 1),
                                   (c.max_seq_len, c.hidden_size), jnp.float32)
                 / math.sqrt(c.hidden_size)).astype(dtype)
+        if c.embed_norm:
+            params["embed_norm"] = jnp.ones((c.hidden_size,), dtype)
         return params
 
     def tied_init(self, rng, dtype=jnp.float32):
@@ -159,14 +162,8 @@ class EmbeddingPipe:
 
     def __call__(self, params, batch, tied=None):
         ids = batch["input_ids"] if isinstance(batch, dict) else batch
-        tok = tied["tok_embed"] if tied is not None else params["tok_embed"]
-        x = tok[ids]
-        if self.config.embed_scale is not None:   # Gemma: input side only
-            x = x * jnp.asarray(self.config.embed_scale, x.dtype)
-        if not self.config.use_rope:
-            S = ids.shape[-1]
-            x = x + params["pos_embed"][:S][None].astype(x.dtype)
-        return x
+        positions = jnp.broadcast_to(jnp.arange(ids.shape[-1]), ids.shape)
+        return self._model.embed({**params, **(tied or {})}, ids, positions)
 
 
 class TransformerBlockPipe:
@@ -247,6 +244,7 @@ class LMHeadPipe:
 
     def __init__(self, config: TransformerConfig):
         self.config = config
+        self._model = CausalTransformerLM(config)
 
     def init(self, rng, dtype=jnp.float32):
         c = self.config
@@ -259,12 +257,7 @@ class LMHeadPipe:
         return params
 
     def __call__(self, params, x, tied=None):
-        from deepspeed_tpu.models.transformer import _norm
-        c = self.config
-        x = _norm(x, params["final_norm"], c.norm_eps, c.use_rmsnorm)
-        head = (tied["tok_embed"].T if c.tie_embeddings
-                else params["lm_head"])
-        return (x @ head.astype(x.dtype)).astype(jnp.float32)
+        return self._model.logits({**params, **(tied or {})}, x)
 
 
 def lm_loss_fn(logits, batch):

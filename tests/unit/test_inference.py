@@ -64,7 +64,7 @@ def test_generate_greedy_matches_training_forward(tiny_model):
 def test_decode_matches_training_forward_new_archs(variant):
     """The KV-cache decode path must reproduce the full forward for the
     Bloom/GPT-J/GPT-Neo architecture features (alibi, parallel block,
-    local windows) — guards the _layer_cached rewrites of each."""
+    local windows) through the dense-cache mixer of the shared block."""
     cfg = TransformerConfig.tiny(hidden_size=64, n_heads=4, **variant)
     model = CausalTransformerLM(cfg)
     params = model.init(jax.random.key(1))

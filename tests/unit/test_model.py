@@ -212,3 +212,91 @@ def test_residual_scale_consistent_across_paths():
                                                   jnp.asarray(ids), caches)
         np.testing.assert_allclose(full, np.asarray(cached_logits),
                                    rtol=2e-4, atol=2e-5)
+
+
+# One case for each architecture feature that each forward used to spell
+# out for itself.  ``extra`` names layer leaves ``init`` does not make for
+# the flags alone (Gemma-2 keeps its pre-norms beside the sandwich norms).
+FORWARD_FEATURES = {
+    "parallel_block": dict(parallel_block=True, use_rmsnorm=False,
+                           norm_bias=True, activation="gelu"),
+    "post_norm_only": dict(post_norm_only=True),
+    "sandwich_norms": dict(extra=("attn_post_norm", "mlp_post_norm")),
+    "residual_scale": dict(residual_scale=0.5),
+    "embed_scale": dict(embed_scale=8.0),
+    "embed_norm": dict(embed_norm=True, use_rmsnorm=False, norm_bias=True),
+    "lm_head_bias": dict(lm_head_bias=True),
+    "final_logit_scale": dict(final_logit_scale=0.25),
+    "final_logit_softcap": dict(final_logit_softcap=5.0,
+                                attn_logit_softcap=10.0),
+    "qk_norm_rms": dict(qk_norm="rms"),
+    "qk_norm_rms_flat": dict(qk_norm="rms_flat"),
+    "tied_head": dict(tie_embeddings=True),
+    "partial_rotary": dict(rope_dim=8),
+    "learned_positions": dict(use_rope=False, use_bias=True),
+}
+
+
+@pytest.mark.parametrize("feature", sorted(FORWARD_FEATURES))
+def test_every_forward_is_the_same_model(feature):
+    """``apply``'s logits against the dense-cache forward, the paged one
+    (jnp pair) and ``InferenceEngine``'s layer-streamed one, each as a
+    prefill and four decode steps: the embedding, the block and the head
+    are stated once, so a feature cannot reach one forward and miss
+    another."""
+    from deepspeed_tpu.parallel import groups
+    settings = dict(FORWARD_FEATURES[feature])
+    extra = settings.pop("extra", ())
+    cfg = TransformerConfig.tiny(hidden_size=64, n_heads=4, n_kv_heads=2,
+                                 **settings)
+    model = CausalTransformerLM(cfg)
+    params = model.init(jax.random.key(0))
+    for name in extra:
+        params["layers"][name] = jnp.ones((cfg.n_layers, cfg.hidden_size))
+    # no leaf left at the ones and zeros a dropped norm or bias would hide
+    # behind
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.key(1), len(leaves))
+    params = jax.tree_util.tree_unflatten(treedef, [
+        x + 0.1 * jax.random.normal(k, x.shape, x.dtype)
+        for x, k in zip(leaves, keys)])
+
+    B, T0, steps, page = 2, 6, 4, 4
+    ids = jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (B, T0 + steps)), jnp.int32)
+    want = np.asarray(model.apply(params, ids, train=False))
+    pieces = [ids[:, :T0]] + [ids[:, T0 + i:T0 + i + 1]
+                              for i in range(steps)]
+
+    def check(got, name):
+        np.testing.assert_allclose(
+            np.concatenate([np.asarray(g) for g in got], axis=1), want,
+            rtol=2e-4, atol=2e-4, err_msg=f"{feature}: {name}")
+
+    caches, got = model.init_caches(B, 16, dtype=jnp.float32), []
+    for piece in pieces:
+        logits, caches = model.apply_with_cache(params, piece, caches)
+        got.append(logits)
+    check(got, "apply_with_cache")
+
+    pools = model.init_paged_caches(1 + B * 3, page, dtype=jnp.float32)
+    tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 6, 0]], jnp.int32)
+    lengths, got = jnp.zeros((B,), jnp.int32), []
+    for piece in pieces:
+        logits, pools, lengths = model.apply_with_paged_cache(
+            params, piece, pools, tables, lengths, attn_backend="jnp")
+        got.append(logits)
+    check(got, "apply_with_paged_cache")
+
+    engine = deepspeed_tpu.init_inference(
+        model=model, params=params, dtype="fp32",
+        zero={"offload_param": {"device": "cpu"}})
+    try:
+        assert engine._streaming
+        caches, got = None, []
+        for piece in pieces:
+            logits, caches = engine.forward(piece, caches)
+            got.append(logits)
+        check(got, "InferenceEngine, layer-streamed")
+    finally:
+        groups.reset_mesh()

@@ -8,12 +8,24 @@ import pytest
 
 from deepspeed_tpu.ops.decode_attention import (KVCache, decode_attention,
                                                 init_cache, update_cache)
-from deepspeed_tpu.ops.paged_attention import (PagedAllocator, append_paged,
-                                               init_paged_cache,
+from deepspeed_tpu.ops.paged_attention import (PagedAllocator, PagedKVCache,
                                                paged_decode_attention,
-                                               prefill_paged)
+                                               write_paged)
 
 H, HKV, D, PAGE = 4, 2, 8, 4
+
+
+def _pools(num_pages):
+    """A one-layer stack of empty pools, as a dispatch carries them."""
+    shape = (1, num_pages, HKV, PAGE, D)
+    return PagedKVCache(jnp.zeros(shape, jnp.float32),
+                        jnp.zeros(shape, jnp.float32))
+
+
+def _write(pools, tables, lengths, k, v):
+    """The pools' one writer (jnp pair) → (pools, new lengths)."""
+    return (write_paged(pools, 0, tables, lengths, k, v, impl="jnp"),
+            lengths + k.shape[1])
 
 
 def _rand(shape, seed=0):
@@ -45,13 +57,13 @@ def test_paged_matches_dense_single_seq():
     al.allocate(0, T0)
 
     dense = init_cache(B, 16, HKV, D, jnp.float32)
-    paged = init_paged_cache(8, PAGE, HKV, D, jnp.float32)
+    paged = _pools(8)
     lengths = jnp.zeros((B,), jnp.int32)
 
     k0, v0 = _rand((B, T0, HKV, D), 1), _rand((B, T0, HKV, D), 2)
     dense = update_cache(dense, k0, v0)
     tables = jnp.asarray(al.block_table([0]))
-    paged, lengths = prefill_paged(paged, tables, lengths, k0, v0)
+    paged, lengths = _write(paged, tables, lengths, k0, v0)
 
     for step in range(5):
         al.extend(0, T0 + step + 1)
@@ -60,9 +72,9 @@ def test_paged_matches_dense_single_seq():
         k1, v1 = _rand((B, 1, HKV, D), 20 + step), _rand((B, 1, HKV, D),
                                                          30 + step)
         dense = update_cache(dense, k1, v1)
-        paged, lengths = append_paged(paged, tables, lengths, k1, v1)
+        paged, lengths = _write(paged, tables, lengths, k1, v1)
         ref = decode_attention(q, dense)
-        got = paged_decode_attention(q, paged, tables, lengths)
+        got = paged_decode_attention(q, paged, tables, lengths, layer=0)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=1e-5, atol=1e-6)
 
@@ -73,7 +85,7 @@ def test_paged_ragged_batch():
     al = PagedAllocator(num_pages=16, page_size=PAGE, max_pages_per_seq=4)
     al.allocate("s0", 3)
     al.allocate("s1", 11)
-    paged = init_paged_cache(16, PAGE, HKV, D, jnp.float32)
+    paged = _pools(16)
     tables = jnp.asarray(al.block_table(["s0", "s1"]))
     lengths = jnp.zeros((2,), jnp.int32)
 
@@ -83,11 +95,11 @@ def test_paged_ragged_batch():
     v = _rand((2, 11, HKV, D), 2)
     al.extend("s0", 11)  # scratch pages so padded writes land somewhere
     tables = jnp.asarray(al.block_table(["s0", "s1"]))
-    paged, _ = prefill_paged(paged, tables, lengths, k, v)
+    paged, _ = _write(paged, tables, lengths, k, v)
     lengths = jnp.asarray([3, 11], jnp.int32)
 
     q = _rand((2, 1, H, D), 3)
-    got = paged_decode_attention(q, paged, tables, lengths)
+    got = paged_decode_attention(q, paged, tables, lengths, layer=0)
 
     # reference: each sequence independently with a dense cache
     for b, L in enumerate((3, 11)):
@@ -103,13 +115,13 @@ def test_gqa_paged():
     al = PagedAllocator(num_pages=8, page_size=PAGE, max_pages_per_seq=2)
     al.allocate(0, T0)
     al.allocate(1, T0)
-    paged = init_paged_cache(8, PAGE, HKV, D, jnp.float32)
+    paged = _pools(8)
     tables = jnp.asarray(al.block_table([0, 1]))
     lengths = jnp.zeros((B,), jnp.int32)
     k, v = _rand((B, T0, HKV, D), 1), _rand((B, T0, HKV, D), 2)
-    paged, lengths = prefill_paged(paged, tables, lengths, k, v)
+    paged, lengths = _write(paged, tables, lengths, k, v)
     q = _rand((B, 1, H, D), 3)   # H=4 query heads over HKV=2 (GQA)
-    out = paged_decode_attention(q, paged, tables, lengths)
+    out = paged_decode_attention(q, paged, tables, lengths, layer=0)
     assert out.shape == (B, 1, H, D)
     dense = init_cache(B, 8, HKV, D, jnp.float32)
     dense = update_cache(dense, k, v)

@@ -8,12 +8,21 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.ops.decode_attention import KVCache, decode_attention
-from deepspeed_tpu.ops.paged_attention import (PagedAllocator,
-                                               init_paged_cache,
+from deepspeed_tpu.ops.paged_attention import (PagedAllocator, PagedKVCache,
                                                paged_decode_attention,
-                                               prefill_paged)
-from deepspeed_tpu.ops.pallas.decode_attention import (
-    decode_attention_pallas, paged_attention_pallas)
+                                               write_paged)
+from deepspeed_tpu.ops.pallas.decode_attention import decode_attention_pallas
+
+
+def _filled_pools(npages, page, tables, k, v):
+    """A one-layer stack of pools holding rows ``k``/``v`` [B, T, Hkv, D]
+    from position 0, written by the pools' one writer (jnp pair)."""
+    B, _, Hkv, D = k.shape
+    shape = (1, npages, Hkv, page, D)
+    empty = PagedKVCache(jnp.zeros(shape, jnp.float32),
+                         jnp.zeros(shape, jnp.float32))
+    return write_paged(empty, 0, tables, jnp.zeros((B,), jnp.int32), k, v,
+                       impl="jnp")
 
 
 def _cache_inputs(B=3, S=64, H=4, Hkv=2, D=16, seed=0):
@@ -64,24 +73,19 @@ def test_paged_kernel_matches_oracle(T):
     B, S, H, Hkv, D = 3, 64, 4, 2, 16
     page, npages, maxp = 16, 32, 4
     k, v, lengths, rng = _cache_inputs(B, S, H, Hkv, D)
-    cache = init_paged_cache(npages, page, Hkv, D, dtype=jnp.float32)
     alloc = PagedAllocator(npages, page, maxp)
     for b in range(B):
         alloc.allocate(b, int(lengths[b]))
     tables = jnp.asarray(alloc.block_table(range(B)))
-    cache, _ = prefill_paged(cache, tables, jnp.zeros((B,), jnp.int32),
-                             jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
+    cache = _filled_pools(npages, page, tables, jnp.swapaxes(k, 1, 2),
+                          jnp.swapaxes(v, 1, 2))
     q = jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)
 
-    oracle = paged_decode_attention(q, cache, tables, lengths, impl="jnp")
-    got = paged_attention_pallas(q, cache.k_pages, cache.v_pages, tables,
-                                 lengths, interpret=True)
+    oracle = paged_decode_attention(q, cache, tables, lengths, impl="jnp",
+                                    layer=0)
+    got = paged_decode_attention(q, cache, tables, lengths, impl="pallas",
+                                 interpret=True, layer=0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(oracle),
-                               rtol=1e-5, atol=1e-5)
-
-    via_api = paged_decode_attention(q, cache, tables, lengths,
-                                     impl="pallas", interpret=True)
-    np.testing.assert_allclose(np.asarray(via_api), np.asarray(oracle),
                                rtol=1e-5, atol=1e-5)
 
 
@@ -91,17 +95,17 @@ def test_paged_kernel_shuffled_page_table():
     B, H, Hkv, D = 2, 2, 2, 16
     page, npages, maxp = 8, 16, 4
     rng = np.random.default_rng(2)
-    cache = init_paged_cache(npages, page, Hkv, D, dtype=jnp.float32)
     # hand-build shuffled tables: seq0 -> pages [7, 3], seq1 -> [11, 0, 5]
     tables = jnp.asarray([[7, 3, 0, 0], [11, 0, 5, 0]], jnp.int32)
     lengths = jnp.asarray([13, 22], jnp.int32)
     k = jnp.asarray(rng.normal(size=(B, maxp * page, Hkv, D)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, maxp * page, Hkv, D)), jnp.float32)
-    cache, _ = prefill_paged(cache, tables, jnp.zeros((B,), jnp.int32), k, v)
+    cache = _filled_pools(npages, page, tables, k, v)
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
-    oracle = paged_decode_attention(q, cache, tables, lengths, impl="jnp")
-    got = paged_attention_pallas(q, cache.k_pages, cache.v_pages, tables,
-                                 lengths, interpret=True)
+    oracle = paged_decode_attention(q, cache, tables, lengths, impl="jnp",
+                                    layer=0)
+    got = paged_decode_attention(q, cache, tables, lengths, impl="pallas",
+                                 interpret=True, layer=0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(oracle),
                                rtol=1e-5, atol=1e-5)
 

@@ -221,8 +221,8 @@ def test_serving_shapes_match_oracle(T, group, page, d, dtype):
         for _ in range(2)))
     q = jnp.asarray(rng.standard_normal((4, T, hkv * group, d)), dtype)
     tables, lengths = jnp.asarray(tables), jnp.asarray(ctx, jnp.int32)
-    got = paged_decode_attention(q, pool, tables, lengths,
-                                 backend="pallas-interpret")
+    got = paged_decode_attention(q, pool, tables, lengths, impl="pallas",
+                                 interpret=True)
     assert got.dtype == q.dtype
     f32 = lambda t: jax.tree_util.tree_map(      # noqa: E731
         lambda x: x.astype(jnp.float32), t)
@@ -289,17 +289,19 @@ def test_pick_tiles_explicit_q_tile_and_mixed_lengths():
 
 
 def test_backend_selected_entry_point():
-    """``paged_decode_attention(backend=...)`` routes "pallas-interpret"
-    through the ragged kernel and agrees with the jnp backend."""
+    """What ``resolve_attention_backend`` makes of "pallas-interpret"
+    routes ``paged_decode_attention`` through the ragged kernel, and
+    agrees with what it makes of "jnp"."""
     ctx = [7, 12]
     _, tables, kp, vp = _build_state(ctx)
     rng = np.random.default_rng(4)
     q = jnp.asarray(rng.standard_normal((2, 1, H, D)), jnp.float32)
     cache = PagedKVCache(kp, vp)
     lengths = jnp.asarray(ctx, jnp.int32)
-    a = paged_decode_attention(q, cache, tables, lengths, backend="jnp")
-    b = paged_decode_attention(q, cache, tables, lengths,
-                               backend="pallas-interpret")
+    a, b = (paged_decode_attention(q, cache, tables, lengths, impl=impl,
+                                   interpret=interpret)
+            for impl, interpret in map(resolve_attention_backend,
+                                       ("jnp", "pallas-interpret")))
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), **TOL)
 
 
@@ -311,22 +313,6 @@ def test_resolve_attention_backend():
     assert resolve_attention_backend("pallas-interpret") == ("pallas", True)
     with pytest.raises(ValueError):
         resolve_attention_backend("cuda")
-
-
-def test_deprecated_shim_still_serves():
-    """``paged_attention_pallas`` (old decode-only surface) delegates to
-    the ragged kernel with unchanged semantics."""
-    from deepspeed_tpu.ops.pallas.decode_attention import \
-        paged_attention_pallas
-    ctx = [9, 14]
-    _, tables, kp, vp = _build_state(ctx)
-    rng = np.random.default_rng(5)
-    q = jnp.asarray(rng.standard_normal((2, 1, H, D)), jnp.float32)
-    lengths = jnp.asarray(ctx, jnp.int32)
-    got = paged_attention_pallas(q, kp, vp, tables, lengths, interpret=True)
-    want = paged_decode_attention(q, PagedKVCache(kp, vp), tables, lengths,
-                                  impl="jnp")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
 
 # -- serving end-to-end ----------------------------------------------------

@@ -93,7 +93,12 @@ def test_config_validation():
         SchedulerConfig({"slo_classes": {"platinum": {}}})
     with pytest.raises(ValueError):
         SchedulerConfig({"speculative": {"enabled": True,
-                                         "num_draft_tokens": 0}})
+                                         "num_draft_tokens": -1}})
+    # 0 draft tokens is "speculation off" (the autotuner's draft-length
+    # knob sweeps it), not an error
+    off = SchedulerConfig({"speculative": {"enabled": True,
+                                           "num_draft_tokens": 0}})
+    assert not off.speculative.enabled
     cfg = SchedulerConfig({"slo_classes":
                            {"latency": {"default_deadline_s": 2.0}}})
     assert cfg.class_deadline_s("latency") == 2.0
