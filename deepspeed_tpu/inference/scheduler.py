@@ -213,8 +213,7 @@ class SchedulerBase:
                     last[slot, 0] = req.last_token
                     tables[slot] = eng.tables[slot]
                     lengths[slot] = eng.lengths[slot]
-                args = (jnp.asarray(last), jnp.asarray(tables),
-                        jnp.asarray(lengths))
+                args = (jnp.asarray(last), jnp.asarray(tables), lengths)
             logits, eng.caches, _ = eng._run_step(*args)
             self._decode_sizes(lengths, ready)
             with tel.span("serve/decode/fetch"):
@@ -373,7 +372,8 @@ class SchedulerBase:
                         jnp.asarray(gen_counts), jnp.asarray(top_ks),
                         jnp.asarray(top_ps))
             toks, eng.caches = eng._dispatch(chunk_fn, args, "decode_chunk",
-                                             eng.max_batch, K)
+                                             eng.max_batch, K,
+                                             starts=lengths)
             self._decode_sizes(lengths, ready)
             with tel.span("serve/decode/fetch"):
                 toks = np.asarray(toks)
@@ -544,7 +544,7 @@ class ChunkedScheduler(SchedulerBase):
         out, self.draft_caches, _ = self.engine._dispatch(
             self._draft_step_fn,
             (self.draft_params, ids, self.draft_caches, tables, lengths),
-            phase, *ids.shape, backend="draft",
+            phase, *ids.shape, starts=np.asarray(lengths), backend="draft",
             config=self.draft_model.config)
         return out
 
@@ -624,7 +624,7 @@ class ChunkedScheduler(SchedulerBase):
                     ids[0, :n] = toks
                     args = (jnp.asarray(ids),
                             jnp.asarray(eng.tables[slot:slot + 1]),
-                            jnp.full((1,), start, jnp.int32))
+                            np.full((1,), start, np.int32))
                 t0 = eng._clock()
                 logits, eng.caches, _ = eng._run_step(*args,
                                                       phase="prefill")
@@ -659,7 +659,7 @@ class ChunkedScheduler(SchedulerBase):
         ids[0, :n] = toks
         self._run_draft(jnp.asarray(ids),
                         jnp.asarray(self.draft_tables[slot:slot + 1]),
-                        jnp.full((1,), start, jnp.int32),
+                        np.full((1,), start, np.int32),
                         phase="spec_prefill")
         req.draft_filled = start + n
         self.draft_lengths[slot] = req.draft_filled
@@ -804,7 +804,7 @@ class ChunkedScheduler(SchedulerBase):
             for s in specs:
                 ids[s, 1:1 + win[s]] = props[s, :win[s]]
             logits, eng.caches, _ = eng._run_step(
-                jnp.asarray(ids), jnp.asarray(tables), jnp.asarray(lengths),
+                jnp.asarray(ids), jnp.asarray(tables), lengths,
                 phase="spec_verify")
             self._decode_sizes(lengths, ready)
             with tel.span("serve/decode/fetch"):
@@ -830,8 +830,8 @@ class ChunkedScheduler(SchedulerBase):
             self._propose_fn,
             (self.draft_params, self.draft_caches, jnp.asarray(dtables),
              jnp.asarray(dlengths), jnp.asarray(dlast)),
-            "spec_draft", eng.max_batch, G + 1, backend="draft",
-            config=self.draft_model.config)
+            "spec_draft", eng.max_batch, G + 1, starts=dlengths,
+            backend="draft", config=self.draft_model.config)
         with eng.telemetry.span("serve/decode/fetch"):
             props[:, :] = np.asarray(toks)[:, :G]
         eng._serve_event("serve/spec_draft", slots=len(specs), window=G)

@@ -55,7 +55,8 @@ from deepspeed_tpu.ops.paged_attention import (PageAllocationError,
                                                PagedAllocator,
                                                resolve_attention_backend,
                                                resolve_paged_impl)
-from deepspeed_tpu.ops.pallas.ragged_paged_attention import pick_tiles
+from deepspeed_tpu.ops.pallas.ragged_paged_attention import (
+    pick_tiles, rect_grid_steps)
 from deepspeed_tpu.runtime.resilience import FaultInjector
 from deepspeed_tpu.utils.logging import logger
 
@@ -374,7 +375,7 @@ class ServingEngine:
         # so a prefill that add_request ran inline is in the next one
         self._report = self._new_report()
         self.last_step = None
-        self._kernel_grids = {}
+        self._kernel_tiles = {}
         self._reports = collections.deque(maxlen=STEP_REPORTS_KEPT)
         self._admission = AdmissionController(self.serving)
         # per-request lifecycle traces on the SAME injectable clock as the
@@ -1120,41 +1121,51 @@ class ServingEngine:
 
     def _run_step(self, ids, tables, lengths, phase="decode"):
         """One dispatch of the paged step: the launch only, nothing here
-        waits for the device."""
+        waits for the device.  ``lengths`` comes as the host's numpy array
+        (the call places it): ``kernel_grid`` is reckoned from it."""
         step_fn = self._prefill_fn if phase == "prefill" else self._step_fn
         return self._dispatch(
             step_fn, (self.params, ids, self.caches, tables, lengths),
-            phase, *ids.shape)
+            phase, *ids.shape, starts=np.asarray(lengths))
 
-    def kernel_grid(self, phase, batch, tokens, config=None):
-        """Grid steps of the ragged paged-attention kernel in one dispatch
-        (every layer of ``config``, default the target model): what the
-        tile picker chose for this compiled shape, 0 on the jnp path.
-        ``decode_chunk`` and ``spec_draft`` run ``tokens`` T=1 forwards."""
+    def kernel_grid(self, phase, batch, tokens, starts, config=None):
+        """(run, full) grid steps of the ragged paged-attention kernel in
+        one dispatch, every layer of ``config`` (default the target
+        model): the (q tile, kv step) pairs that hold keys, reckoned from
+        the tokens each sequence starts from as the kernel's item map
+        reckons them, and the rectangle of the tile picker's choice for
+        this compiled shape they are drawn from.  Both 0 on the jnp path.
+        ``decode_chunk`` and ``spec_draft`` run ``tokens`` T=1 forwards,
+        each one token further."""
+        if self.attention_impl != "pallas":
+            return 0, 0
         config = config or self.config
-        key = (phase, int(batch), int(tokens), id(config))
-        if key not in self._kernel_grids:
-            steps = 0
-            if self.attention_impl == "pallas":
-                calls, T = ((tokens, 1) if phase in ("decode_chunk",
-                                                     "spec_draft")
-                            else (1, tokens))
-                steps = calls * config.n_layers * pick_tiles(
-                    [int(T)] * int(batch), config.n_heads // config.kv_heads,
-                    config.kv_heads, self.page_size, config.head_dim,
-                    self.tables.shape[1],
-                    jnp.dtype(self.cache_dtype).itemsize).grid_steps
-            self._kernel_grids[key] = int(steps)
-        return self._kernel_grids[key]
+        calls, T = ((int(tokens), 1) if phase in ("decode_chunk",
+                                                  "spec_draft")
+                    else (1, int(tokens)))
+        key = (int(batch), T, id(config))
+        if key not in self._kernel_tiles:
+            self._kernel_tiles[key] = pick_tiles(
+                [T] * int(batch), config.n_heads // config.kv_heads,
+                config.kv_heads, self.page_size, config.head_dim,
+                self.tables.shape[1], jnp.dtype(self.cache_dtype).itemsize)
+        tiles = self._kernel_tiles[key]
+        ctx = np.asarray(starts)[None, :] \
+            + T * np.arange(1, calls + 1)[:, None]
+        run = rect_grid_steps(tiles, int(batch), T, ctx, self.page_size)
+        return (config.n_layers * run,
+                config.n_layers * calls * tiles.grid_steps)
 
-    def _dispatch(self, fn, args, phase, batch, tokens, backend=None,
-                  config=None):
+    def _dispatch(self, fn, args, phase, batch, tokens, *, starts,
+                  backend=None, config=None):
         """Launch jitted ``fn(*args)`` as one ``serve/step`` span and one
         entry of the open report's ``dispatches`` (the target model's
         steps, the chunked decode scan, the draft model's — ``config``
-        is the model whose layers the dispatch runs)."""
+        is the model whose layers the dispatch runs; ``starts`` the
+        tokens each of its sequences holds before it, on the host)."""
         t0_ns = time.perf_counter_ns()
-        kernel_grid = self.kernel_grid(phase, batch, tokens, config)
+        kernel_grid, kernel_grid_full = self.kernel_grid(
+            phase, batch, tokens, starts, config)
         # what the dispatch compiled: the draft model's call binds no
         # backend, so it resolves its own
         kv_write = self.attention_impl if config is None else \
@@ -1164,7 +1175,8 @@ class ServingEngine:
                 attrs={"backend": backend or self.attention_backend,
                        "phase": phase, "batch": int(batch),
                        "tokens": int(tokens),
-                       "kernel_grid": kernel_grid}), \
+                       "kernel_grid": kernel_grid,
+                       "kernel_grid_full": kernel_grid_full}), \
                 self._prof_track("prefill" if phase == "prefill"
                                  else "serve_step"), \
                 (self.mesh if self.mesh is not None
@@ -1172,7 +1184,8 @@ class ServingEngine:
             out = fn(*args)
         self._report["dispatches"].append(
             {"phase": phase, "batch": int(batch), "tokens": int(tokens),
-             "kernel_grid": kernel_grid, "kv_write": kv_write,
+             "kernel_grid": kernel_grid,
+             "kernel_grid_full": kernel_grid_full, "kv_write": kv_write,
              "t0_ns": t0_ns, "t1_ns": time.perf_counter_ns()})
         return out
 
@@ -1221,7 +1234,7 @@ class ServingEngine:
                 ids[0, :len(suffix)] = suffix
                 args = (jnp.asarray(ids),
                         jnp.asarray(self.tables[slot:slot + 1]),
-                        jnp.full((1,), cached, jnp.int32))
+                        np.full((1,), cached, np.int32))
             t0 = self._clock()
             logits, self.caches, _ = self._run_step(*args, phase="prefill")
             self._prefill_done(len(suffix), len(req.prompt))

@@ -727,11 +727,11 @@ class CausalTransformerLM:
         return attn, cache
 
     def mix_paged(self, q, k, v, layer, pools, *, index, block_tables,
-                  lengths, impl, interpret):
+                  lengths, impl, interpret, items):
         """Write this layer's rows into the STACKED page pools at
         ``lengths`` and attend over each sequence's ragged prefix, both in
         place by (traced) layer ``index`` (``apply_with_paged_cache``
-        binds the keywords)."""
+        binds the keywords; ``items`` is what every layer's read shares)."""
         from deepspeed_tpu.ops.paged_attention import (paged_decode_attention,
                                                        write_paged)
         c = self.config
@@ -745,7 +745,7 @@ class CausalTransformerLM:
                                       softmax_scale=c.attn_scale,
                                       impl=impl, interpret=interpret,
                                       logit_softcap=c.attn_logit_softcap,
-                                      layer=index)
+                                      layer=index, items=items)
         return attn, pools
 
     def _cached_attn_bias(self, layer, T, S, length):
@@ -1066,7 +1066,8 @@ class CausalTransformerLM:
         fused ragged kernel; interpret runs the kernel on CPU) — static
         kwargs, so the serving engine binds them before jit.
         """
-        from deepspeed_tpu.ops.paged_attention import resolve_paged_impl
+        from deepspeed_tpu.ops.paged_attention import (paged_read_items,
+                                                       resolve_paged_impl)
         c = self.config
         B, T = input_ids.shape
         positions = lengths[:, None] + jnp.broadcast_to(
@@ -1074,6 +1075,9 @@ class CausalTransformerLM:
         x = self.embed(params, input_ids, positions)
         # one backend for the write and the read of the pools
         impl = resolve_paged_impl(attn_backend, c.attn_logit_softcap)
+        # the steps of the read that hold keys: once a dispatch, not a layer
+        items = paged_read_items((B, T, c.n_heads, c.head_dim), caches,
+                                 block_tables, lengths + T, impl)
 
         def body(carry, inp):
             # the stacked pools stay ONE buffer through the layers: carried,
@@ -1082,7 +1086,8 @@ class CausalTransformerLM:
             layer, i = inp
             mix = functools.partial(
                 self.mix_paged, index=i, block_tables=block_tables,
-                lengths=lengths, impl=impl, interpret=attn_interpret)
+                lengths=lengths, impl=impl, interpret=attn_interpret,
+                items=items)
             x, pools, _ = self.block(x, layer, positions, mix, pools,
                                      train=False)
             return (x, pools), None

@@ -108,15 +108,30 @@ def write_paged(cache: PagedKVCache, layer, block_tables, lengths, k_new,
     return PagedKVCache(k_pages=k, v_pages=v)
 
 
+def paged_read_items(q_shape, cache: PagedKVCache, block_tables, lengths,
+                     impl: Optional[str] = None):
+    """What every layer's read of one dispatch shares: the ragged kernel's
+    item map (the (q tile, kv step) pairs that hold keys, from ``lengths``
+    — the new tokens included), or None where the jnp pair serves.  The
+    layer loop's caller builds it once and hands it to each layer's
+    :func:`paged_decode_attention` as ``items``."""
+    if resolve_paged_impl(impl) != "pallas":
+        return None
+    from deepspeed_tpu.ops.pallas.ragged_paged_attention import rect_item_map
+    return rect_item_map(q_shape, cache.k_pages, block_tables, lengths)
+
+
 def paged_decode_attention(q, cache: PagedKVCache, block_tables, lengths,
                            softmax_scale: Optional[float] = None,
                            impl: Optional[str] = None,
                            interpret: bool = False,
                            logit_softcap: Optional[float] = None,
-                           layer=None):
+                           layer=None, items=None):
     """q: [B, T, H, D] — the last T tokens of each sequence (T=1 decode).
     With ``layer`` (may be traced) ``cache`` holds the stacked pools
-    [L, P, Hkv, page, D] and that layer is read in place.
+    [L, P, Hkv, page, D] and that layer is read in place; ``items`` is
+    :func:`paged_read_items` of the same arguments, where the caller built
+    it for all its layers (the kernel builds its own otherwise).
 
     ``impl`` and ``interpret`` as :func:`resolve_attention_backend` gives
     them: None (auto: Pallas kernel on TPU, jnp elsewhere), "pallas", or
@@ -131,7 +146,8 @@ def paged_decode_attention(q, cache: PagedKVCache, block_tables, lengths,
         return ragged_paged_attention_rect(q, cache.k_pages, cache.v_pages,
                                            block_tables, lengths,
                                            softmax_scale=softmax_scale,
-                                           interpret=interpret, layer=layer)
+                                           interpret=interpret, layer=layer,
+                                           items=items)
     B, T, H, D = q.shape
     Hkv, page_size = cache.k_pages.shape[-3:-1]
     max_pages = block_tables.shape[1]

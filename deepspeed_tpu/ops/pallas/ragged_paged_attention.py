@@ -17,11 +17,10 @@ through the block table and serves a whole mixed batch in one launch:
 * **A grid step is MXU-sized, and its shape is picked from the call's
   shapes** (:func:`pick_tiles`, one pure function of the query lengths,
   ``group``, ``Hkv``, ``page_size``, ``D``, the table width and the
-  cache's itemsize, under ``VMEM_BUDGET``).  grid = (q tiles, kv-head
-  blocks, kv steps); one step multiplies ``heads`` kv heads'
-  ``[q_tile·group, D]`` query rows by ``pages`` pages of keys at once
-  (a batched dot over the heads).  A long prefill takes up to 1,024 rows
-  of one head by up to 1,024 keys a step; a T=1 decode takes ALL kv
+  cache's itemsize, under ``VMEM_BUDGET``).  One step multiplies ``heads``
+  kv heads' ``[q_tile·group, D]`` query rows by ``pages`` pages of keys at
+  once (a batched dot over the heads).  A long prefill takes up to 1,024
+  rows of one head by up to 1,024 keys a step; a T=1 decode takes ALL kv
   heads of its pages (one page's ``[Hkv, page, D]`` block is contiguous
   in the pool), two pages of 128 a step; a few rows (speculative verify,
   small chunks) sit in between; GQA folds each kv head's whole query
@@ -29,15 +28,32 @@ through the block table and serves a whole mixed batch in one launch:
   wrapper lays q out as ``[tiles, Hkv, q_tile·group, D]`` (XLA's
   transpose, outside the kernel) so a tile's rows are the second-minor
   dimension of its block.
+* **The grid follows the contexts.**  grid = (kv-head blocks, items): an
+  item is one (q tile, kv step) pair under that tile's causal frontier,
+  and the NUMBER of items is a traced value (a dynamic grid bound), so a
+  call runs as many steps as its contexts hold keys — not the rectangle
+  of tiles x block-table columns — and the compiled program stays one
+  per shape.  The item map (:func:`build_item_map`) is built inside the
+  jit from the context lengths: the running sum of each tile's steps
+  (:func:`live_steps`, the arithmetic the serving engine's
+  ``kernel_grid`` repeats on the host) and, from it, the tile of every
+  item.  A tile with no key keeps one item, so every output block is
+  written.  A dispatch builds the map once, ahead of its layer loop
+  (:func:`rect_item_map`).  Where tiles x columns is past
+  ``ITEM_TABLE_MAX`` (an engine of hundreds of slots by hundreds of
+  columns) the running sum alone rides in scalar memory and the index
+  maps bisect it; ``TileChoice.item_table`` says which.
 * **Scalar-prefetched metadata** (context lengths, query lengths,
-  tile→sequence / tile→q-tile maps, block tables) steers the BlockSpec
-  index maps: each of the step's ``pages`` K/V operands resolves its own
-  page through the block table, so exactly the owning sequence's pages
-  are fetched — shared prefix-cache pages and partial last pages read in
-  place; steps past the tile's causal frontier repeat the previous block
-  indices (DMA skipped) and their compute is ``pl.when``-predicated off.
+  tile→sequence / tile→q-tile maps, block tables, the layer, the item
+  map) steers the BlockSpec index maps: each of the step's ``pages`` K/V
+  operands resolves its own page through the block table, so exactly the
+  owning sequence's pages are fetched — shared prefix-cache pages and
+  partial last pages read in place; the last step's operands past the
+  frontier clamp to its last page.  Head blocks are the outer grid axis,
+  so one tile's steps stay consecutive and its accumulators live in
+  scratch between them.
 * **Online softmax** (running max / sum / accumulator in float32 VMEM
-  scratch persisting across the sequential kv grid dim).  ``QK^T`` and
+  scratch persisting across a tile's consecutive items).  ``QK^T`` and
   ``PV`` take operands in the cache's dtype (bf16 cache: bf16 operands,
   float32 accumulation; float32 cache: float32 operands), ``p`` is cast
   to the value dtype for ``PV`` — what the jnp oracle does.
@@ -83,16 +99,24 @@ TARGET_KEYS = 1024
 STEP_KV_BYTES = 2 * 2 ** 20
 MAX_PAGES_PER_STEP = 8
 VMEM_BUDGET = 16 * 2 ** 20
+# (q tile, kv step) pairs whose tile may be looked up in scalar memory,
+# one int32 each beside the block tables
+ITEM_TABLE_MAX = 16 * 1024
 
 
 class TileChoice(NamedTuple):
     """Block shapes of one call: ``q_tile`` query tokens of one sequence
-    x ``heads`` kv heads x ``pages`` K/V pages a grid step."""
+    x ``heads`` kv heads x ``pages`` K/V pages a grid step.  ``grid`` is
+    the rectangle the block table spans; a call runs the part of it that
+    holds keys (:func:`live_steps`)."""
     q_tile: int
     heads: int
     pages: int
     grid: Tuple[int, int, int]     # (q tiles, kv-head blocks, kv steps)
     vmem_bytes: int
+    # the item map's tile_of_item rides in scalar memory (else the index
+    # maps search the running sum of the tiles' steps)
+    item_table: bool
 
     @property
     def grid_steps(self) -> int:
@@ -159,23 +183,103 @@ def pick_tiles(q_lens, group, n_kv_heads, page_size, head_dim, table_width,
         heads = next(h for h in divisors if h < heads)
     n_tiles = sum(-(-int(ql) // q_tile) for ql in q_lens)
     grid = (n_tiles, n_kv_heads // heads, -(-table_width // pages))
-    return TileChoice(q_tile, heads, pages, grid, vmem(heads, pages))
+    return TileChoice(q_tile, heads, pages, grid, vmem(heads, pages),
+                      item_table=grid[0] * grid[2] <= ITEM_TABLE_MAX)
+
+
+def live_steps(ctx_lens, q_lens, seq_of_tile, qtile_of_tile,
+               tiles: TileChoice, page_size, xp=jnp):
+    """kv steps each q tile runs, [..., n_tiles]: the steps of
+    ``tiles.pages`` pages under the tile's causal frontier, and one for a
+    tile with no key (its output block is still written).  ``xp`` is
+    ``jnp`` inside the jit (the item map) or ``numpy`` on the host
+    (:func:`rect_grid_steps`): the same arithmetic for both."""
+    ctx, qlen = ctx_lens[..., seq_of_tile], q_lens[seq_of_tile]
+    # keys this q tile may attend (causal): positions < kv_hi
+    kv_hi = ctx - qlen + xp.minimum(qlen, (qtile_of_tile + 1) * tiles.q_tile)
+    return xp.clip(-(-kv_hi // (tiles.pages * page_size)), 1, tiles.grid[2])
+
+
+def rect_metadata(B, T, q_tile):
+    """(seq_of_tile, qtile_of_tile) of B sequences of T query rows each."""
+    n_qt = -(-T // q_tile)
+    return (np.repeat(np.arange(B, dtype=np.int32), n_qt),
+            np.tile(np.arange(n_qt, dtype=np.int32), B))
+
+
+def rect_grid_steps(tiles: TileChoice, B, T, ctx_lens, page_size) -> int:
+    """Grid steps a [B, T] call runs over host ``ctx_lens`` ([..., B], the
+    new tokens included; leading axes are further calls of the shape):
+    what the item map of each call will hold, times the kv-head blocks.
+    ``tiles.grid_steps`` is the rectangle it is drawn from."""
+    steps = live_steps(np.asarray(ctx_lens), np.full(B, T),
+                       *rect_metadata(B, T, tiles.q_tile), tiles, page_size,
+                       xp=np)
+    return tiles.grid[1] * int(steps.sum())
+
+
+class ItemMap(NamedTuple):
+    """The (q tile, kv step) pairs a call runs, in tile order with steps
+    ascending.  ``first``: [n_tiles + 1] int32, the running sum of the
+    tiles' steps (tile ``t`` owns items ``first[t] .. first[t + 1] - 1``;
+    ``first[-1]`` is their number, the grid's traced bound);
+    ``tile_of_item``: [n_tiles x kv steps] int32 where
+    ``TileChoice.item_table``, else one unused zero."""
+    first: jnp.ndarray
+    tile_of_item: jnp.ndarray
+
+
+def build_item_map(ctx_lens, q_lens, seq_of_tile, qtile_of_tile,
+                   tiles: TileChoice, page_size) -> ItemMap:
+    """The item map of one call, inside the jit; the same for every layer
+    of a dispatch, so a layer loop builds it once, outside."""
+    steps = live_steps(jnp.asarray(ctx_lens, jnp.int32),
+                       jnp.asarray(q_lens, jnp.int32), seq_of_tile,
+                       qtile_of_tile, tiles, page_size)
+    first = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                             jnp.cumsum(steps, dtype=jnp.int32)])
+    if not tiles.item_table:
+        return ItemMap(first, jnp.zeros(1, jnp.int32))
+    n_tiles, _, kv_steps = tiles.grid
+    tile_of_item = jnp.searchsorted(
+        first[1:], jnp.arange(n_tiles * kv_steps, dtype=jnp.int32),
+        side="right", method="compare_all")
+    return ItemMap(first, jnp.minimum(tile_of_item, n_tiles - 1)
+                   .astype(jnp.int32))
+
+
+def _locate(item, first_ref, toi_ref, n_tiles, item_table):
+    """(q tile, kv step) of grid item ``item``: looked up, or the last
+    tile whose first item is not past it (a bisection of ``first``)."""
+    if item_table:
+        t = toi_ref[item]
+    else:
+        def halve(_, span):
+            lo, hi = span
+            mid = (lo + hi + 1) // 2
+            under = first_ref[mid] <= item
+            return jnp.where(under, mid, lo), jnp.where(under, hi, mid - 1)
+        t, _ = jax.lax.fori_loop(0, (n_tiles - 1).bit_length(), halve,
+                                 (jnp.int32(0), jnp.int32(n_tiles - 1)))
+    return t, item - first_ref[t]
 
 
 def _ragged_kernel(ctx_ref, qlens_ref, sot_ref, qot_ref, tables_ref,
-                   layer_ref, q_ref, *refs, scale, page_size, q_tile, group,
-                   pages):
-    """One (q-tile, kv-head block, kv step) of online-softmax attention.
+                   layer_ref, first_ref, toi_ref, q_ref, *refs, scale,
+                   page_size, q_tile, group, pages, locate):
+    """One (kv-head block, item) of online-softmax attention; an item is
+    one (q tile, kv step) pair of the item map.
 
     q_ref: [1, heads, q_tile*group, D] — ``q_tile`` padded query rows of
     ONE sequence for ``heads`` kv heads' whole groups; then ``pages`` K
     refs and ``pages`` V refs of [1, heads, page, D] (the pages the index
     maps resolved through the block table); o_ref like q_ref; scratch
-    acc/m/l persist across the kv grid dim (TPU grids are sequential)."""
+    acc/m/l persist across a tile's items, which are consecutive (TPU
+    grids are sequential)."""
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
     o_ref, acc_ref, m_ref, l_ref = refs[2 * pages:]
-    t = pl.program_id(0)
-    i = pl.program_id(2)
+    item = pl.program_id(1)
+    t, i = locate(item, first_ref, toi_ref)
     s = sot_ref[t]
     qt = qot_ref[t]
     ctx = ctx_ref[s]          # tokens in the cache INCLUDING the queries
@@ -192,6 +296,8 @@ def _ragged_kernel(ctx_ref, qlens_ref, sot_ref, qot_ref, tables_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
+    # the map holds no step past the frontier: only the one item of a tile
+    # with no key at all is turned off here
     @pl.when(i * keys < kv_hi)
     def _compute():
         if pages == 1:
@@ -225,7 +331,7 @@ def _ragged_kernel(ctx_ref, qlens_ref, sot_ref, qot_ref, tables_ref,
             p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    @pl.when(i == pl.num_programs(2) - 1)
+    @pl.when(item + 1 == first_ref[t + 1])     # the tile's last item
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
@@ -241,17 +347,18 @@ KERNEL_DECODE = "ragged_paged_attention_decode"
 
 def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
                  seq_of_tile, qtile_of_tile, tiles: TileChoice, scale,
-                 interpret, name, layer=None):
+                 interpret, name, layer=None, items: ItemMap = None):
     """Launch the kernel over a tiled query stack.
 
     qt: [n_tiles, Hkv, q_tile*group, D] — tile ``t`` holds ``q_tile``
     rows of sequence ``seq_of_tile[t]`` (its ``qtile_of_tile[t]``-th
     tile), each row with its kv head's whole group.  ctx_lens/q_lens may
     be traced; seq_of_tile / qtile_of_tile are host metadata (they size
-    the grid).  k_pages/v_pages: one layer's pool [P, Hkv, page, D], or
-    the stacked pools [L, P, Hkv, page, D] with ``layer`` (may be traced)
-    the one to read — the index maps pick it, so no layer's pool is ever
-    sliced out of the stack."""
+    the item map).  k_pages/v_pages: one layer's pool [P, Hkv, page, D],
+    or the stacked pools [L, P, Hkv, page, D] with ``layer`` (may be
+    traced) the one to read — the index maps pick it, so no layer's pool
+    is ever sliced out of the stack.  ``items``: the call's
+    :func:`build_item_map`, built here when the caller brings none."""
     n_tiles, Hkv, rows, D = qt.shape
     if k_pages.ndim == 4:
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
@@ -265,18 +372,23 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
     qot = jnp.asarray(qtile_of_tile, jnp.int32)
     tables = jnp.asarray(block_tables, jnp.int32)
     lay = jnp.asarray(layer, jnp.int32).reshape(1)
+    if items is None:
+        items = build_item_map(ctx_lens, q_lens, seq_of_tile, qtile_of_tile,
+                               tiles, page_size)
+    locate = functools.partial(_locate, n_tiles=n_tiles,
+                               item_table=tiles.item_table)
 
-    def q_map(t, h, i, ctx, qls, sot, qot, tbl, lay):
-        return (t, h, 0, 0)
+    def q_map(h, item, ctx, qls, sot, qot, tbl, lay, first, toi):
+        return (locate(item, first, toi)[0], h, 0, 0)
 
-    def kv_map(j, t, h, i, ctx, qls, sot, qot, tbl, lay):
-        # fetch only pages under this tile's causal frontier: the step's
-        # j-th operand clamps to the last needed page, and steps past the
-        # frontier repeat the last needed step's indices (DMA skipped)
+    def kv_map(j, h, item, ctx, qls, sot, qot, tbl, lay, first, toi):
+        # the step's j-th operand is page i*pages + j of the tile's
+        # sequence, clamped to the last page under its causal frontier
+        t, i = locate(item, first, toi)
         s = sot[t]
         kv_hi = ctx[s] - qls[s] + jnp.minimum(qls[s], (qot[t] + 1) * q_tile)
         last = jnp.maximum(pl.cdiv(kv_hi, page_size) - 1, 0)
-        col = jnp.minimum(jnp.minimum(i, last // pages) * pages + j, last)
+        col = jnp.minimum(i * pages + j, last)
         return (lay[0], tbl[s, jnp.minimum(col, width - 1)], h, 0, 0)
 
     kv_specs = [pl.BlockSpec((None, 1, heads, page_size, D),
@@ -284,12 +396,15 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
                 for j in range(pages)]
     kernel = functools.partial(_ragged_kernel, scale=scale,
                                page_size=page_size, q_tile=q_tile,
-                               group=rows // q_tile, pages=pages)
+                               group=rows // q_tile, pages=pages,
+                               locate=locate)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
-            grid=tiles.grid,
+            num_scalar_prefetch=8,
+            # head blocks outermost, so a tile's items stay consecutive;
+            # the item bound is traced: one program whatever the contexts
+            grid=(Hkv // heads, items.first[n_tiles]),
             in_specs=[pl.BlockSpec((1, heads, rows, D), q_map)]
             + kv_specs + kv_specs,
             out_specs=pl.BlockSpec((1, heads, rows, D), q_map),
@@ -301,16 +416,16 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
         ),
         out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=2 * VMEM_BUDGET),
         interpret=interpret,
         name=name,
-    )(ctx_lens, q_lens, sot, qot, tables, lay, qt,
+    )(ctx_lens, q_lens, sot, qot, tables, lay, *items, qt,
       *([k_pages] * pages), *([v_pages] * pages))
 
 
-def _pick_for(q_lens, q, k_pages, block_tables, q_tile):
-    H, D = q.shape[-2:]
+def _pick_for(q_lens, q_shape, k_pages, block_tables, q_tile):
+    H, D = q_shape[-2:]
     Hkv, page_size = k_pages.shape[-3:-1]
     return pick_tiles(q_lens, H // Hkv, Hkv, page_size, D,
                       block_tables.shape[1], k_pages.dtype.itemsize, q_tile)
@@ -369,7 +484,7 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     assert sum(q_lens) == total_q, \
         f"q has {total_q} rows but q_lens sums to {sum(q_lens)}"
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-    tiles = _pick_for(q_lens, q, k_pages, block_tables, q_tile)
+    tiles = _pick_for(q_lens, q.shape, k_pages, block_tables, q_tile)
     starts, sot, qot, total_padded = _pack_metadata(q_lens, tiles.q_tile)
 
     # scatter each sequence's rows to its q_tile-aligned start (static
@@ -390,9 +505,28 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
          for s, ql in enumerate(q_lens)], axis=0)
 
 
+def _rect_layout(q_shape, k_pages, block_tables, q_tile):
+    """(tiles, seq_of_tile, qtile_of_tile) of a [B, T, H, D] call."""
+    B, T = q_shape[:2]
+    tiles = _pick_for([T] * B, q_shape, k_pages, block_tables, q_tile)
+    return (tiles,) + rect_metadata(B, T, tiles.q_tile)
+
+
+def rect_item_map(q_shape, k_pages, block_tables, lengths,
+                  q_tile=None) -> ItemMap:
+    """The item map :func:`ragged_paged_attention_rect` runs for these
+    arguments (``q_shape``: [B, T, H, D]).  It is the same for every layer
+    of a dispatch: the layer loop's caller builds it once and hands it to
+    each layer's call as ``items``."""
+    B, T = q_shape[:2]
+    tiles, sot, qot = _rect_layout(q_shape, k_pages, block_tables, q_tile)
+    return build_item_map(lengths, jnp.full((B,), T, jnp.int32), sot, qot,
+                          tiles, k_pages.shape[-2])
+
+
 def ragged_paged_attention_rect(q, k_pages, v_pages, block_tables, lengths,
                                 softmax_scale=None, q_tile=None,
-                                interpret=False, layer=None):
+                                interpret=False, layer=None, items=None):
     """Rectangular front-end for the jitted serving path.
 
     q: [B, T, H, D] — the last T tokens of each sequence (T=1 decode,
@@ -402,23 +536,23 @@ def ragged_paged_attention_rect(q, k_pages, v_pages, block_tables, lengths,
     :func:`ragged_paged_attention`; rows past a multiple-of-q_tile pad
     are masked inside the kernel.  With ``layer`` (may be traced) the
     pools are the stacked [L, P, Hkv, page, D] and are read in place.
+    ``items``: :func:`rect_item_map` of the same arguments, where the
+    caller built it once for all its layers.
     """
     B, T, H, D = q.shape
     Hkv = k_pages.shape[-3]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-    tiles = _pick_for([T] * B, q, k_pages, block_tables, q_tile)
-    n_qt = -(-T // tiles.q_tile)
-    Tp = n_qt * tiles.q_tile
+    tiles, sot, qot = _rect_layout(q.shape, k_pages, block_tables, q_tile)
+    Tp = len(sot) // B * tiles.q_tile
     if Tp != T:
         q = jnp.pad(q, ((0, 0), (0, Tp - T), (0, 0), (0, 0)))
-    sot = np.repeat(np.arange(B, dtype=np.int32), n_qt)
-    qot = np.tile(np.arange(n_qt, dtype=np.int32), B)
     q_lens = jnp.full((B,), T, jnp.int32)
-    out = _ragged_call(_to_tiles(q.reshape(B * Tp, H, D), B * n_qt,
+    out = _ragged_call(_to_tiles(q.reshape(B * Tp, H, D), len(sot),
                                  tiles.q_tile, Hkv),
                        k_pages, v_pages, block_tables, lengths, q_lens,
                        sot, qot, tiles, scale, interpret,
-                       KERNEL_DECODE if T == 1 else KERNEL_PREFILL, layer)
+                       KERNEL_DECODE if T == 1 else KERNEL_PREFILL, layer,
+                       items)
     return _from_tiles(out, tiles.q_tile).reshape(B, Tp, H, D)[:, :T]
 
 

@@ -137,6 +137,17 @@ def test_ragged_paged_attention(topo, name, batch, q_len, heads, kv_heads,
             else "ragged_paged_attention_prefill") in text
 
 
+def test_ragged_item_search_compiles(topo, monkeypatch):
+    """An engine whose (q tile, kv step) rectangle is past
+    ``ITEM_TABLE_MAX`` searches the running sum of the tiles' steps inside
+    the index maps: that loop goes through Mosaic too (the chat cell's
+    decode shape, the threshold lowered to reach the path)."""
+    from deepspeed_tpu.ops.pallas import ragged_paged_attention as rpa
+    monkeypatch.setattr(rpa, "ITEM_TABLE_MAX", 0)
+    test_ragged_paged_attention(topo, *next(
+        c for c in RAGGED_SHAPES if c[0] == "chat_decode_b32"))
+
+
 def test_paged_decode_step_llama_1b(topo):
     """One whole ``apply_with_paged_cache`` decode step at the widths
     ``chip_smoke.py`` serves."""
@@ -265,3 +276,25 @@ def test_serving_dispatch_never_copies_the_page_pools(
     if tokens == 1 or jit_name == "chunk":
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < ONE_LAYER_POOL_BYTES, temp
+
+
+def test_decode_is_one_program_whatever_the_lengths():
+    """The ragged kernel's item bound is a traced value, not a bucketed
+    shape: a decode dispatch of idle slots and one of slots at their full
+    context run the SAME compiled ``jit_serve_decode`` (interpret mode on
+    the CPU; on the chip ``compiles.*`` counts any other)."""
+    from deepspeed_tpu.inference.serving import ServingEngine
+    model = CausalTransformerLM(TransformerConfig.tiny(
+        hidden_size=64, n_heads=4, n_kv_heads=2))
+    eng = ServingEngine(model, model.init(jax.random.key(0)), max_batch=4,
+                        page_size=8, max_seq=128, dtype=jnp.float32,
+                        serving={"attention_backend": "pallas-interpret"})
+    ids = jnp.zeros((4, 1), jnp.int32)
+    grids = []
+    for lengths in (np.zeros(4, np.int32), np.full(4, 127, np.int32)):
+        _, eng.caches, _ = eng._run_step(ids, jnp.asarray(eng.tables),
+                                         lengths)
+        grids.append(eng._report["dispatches"][-1]["kernel_grid"])
+    assert eng._step_fn._cache_size() == 1
+    assert 0 < grids[0] < grids[1] <= \
+        eng._report["dispatches"][-1]["kernel_grid_full"]

@@ -18,9 +18,10 @@ import pytest
 from deepspeed_tpu.ops.paged_attention import (PagedAllocator, PagedKVCache,
                                                paged_decode_attention,
                                                resolve_attention_backend)
+from deepspeed_tpu.ops.pallas import ragged_paged_attention as rpa
 from deepspeed_tpu.ops.pallas.ragged_paged_attention import (
-    VMEM_BUDGET, pick_tiles, ragged_paged_attention,
-    ragged_paged_attention_rect)
+    VMEM_BUDGET, build_item_map, pick_tiles, ragged_paged_attention,
+    ragged_paged_attention_rect, rect_grid_steps, rect_metadata)
 
 H, HKV, D, PAGE = 4, 2, 8, 4
 NPAGES = 64
@@ -288,6 +289,127 @@ def test_pick_tiles_explicit_q_tile_and_mixed_lengths():
     assert tiles.q_tile == 256 and tiles.grid[0] == 2
 
 
+# -- the item map: the grid follows the contexts ------------------------------
+
+# name, T, group, Hkv, page, table width, contexts (the T new tokens
+# included; a slot whose context is T is idle: its table row is all scratch
+# page), q_tile override.  Pages of 8 and 32 float32 rows ride 8 a step.
+ITEM_CASES = [
+    ("every_slot_idle", 1, 1, 2, 8, 33, [1] * 32, None),
+    ("one_live_slot_of_32", 1, 1, 2, 8, 33, [1] * 5 + [150] + [1] * 26, None),
+    # exactly one step of 64 keys, one step plus one token, the whole
+    # table, an idle slot between them
+    ("step_edges", 1, 1, 2, 8, 33, [64, 1, 65, 264], None),
+    # a 192-token chunk over a 100-token cached prefix: its three q tiles'
+    # frontiers are 164, 228 and 292 keys
+    ("prefill_cached_prefix", 192, 1, 2, 8, 40, [292], 64),
+    ("gqa_decode", 1, 4, 2, 8, 33, [1, 70, 130, 64], None),
+    ("gqa_verify_window", 5, 2, 2, 8, 33, [5, 69, 200], None),
+    # 600 rows of one head a tile: two kv-head blocks sweep the items
+    ("two_head_blocks", 600, 1, 2, 32, 33, [700], None),
+]
+ITEM_IDS = [c[0] for c in ITEM_CASES]
+
+
+def _item_case(T, group, hkv, page, width, ctx, q_tile, d=16):
+    B = len(ctx)
+    tiles = pick_tiles([T] * B, group, hkv, page, d, width, 4, q_tile)
+    sot, qot = rect_metadata(B, T, tiles.q_tile)
+    return B, tiles, sot, qot
+
+
+def _want_items(ctx, T, sot, qot, tiles, page):
+    """The (tile, step) pairs with ``step * keys < kv_hi``, and step 0 of
+    a tile that has none, in tile order with steps ascending."""
+    keys = tiles.pages * page
+    want = []
+    for t, (s, qt) in enumerate(zip(sot, qot)):
+        kv_hi = ctx[s] - T + min(T, (qt + 1) * tiles.q_tile)
+        live = [(t, i) for i in range(tiles.grid[2]) if i * keys < kv_hi]
+        want += live or [(t, 0)]
+    return want
+
+
+@pytest.mark.parametrize("name,T,group,hkv,page,width,ctx,q_tile",
+                         ITEM_CASES, ids=ITEM_IDS)
+def test_item_map(name, T, group, hkv, page, width, ctx, q_tile):
+    """The map is a pure function of the contexts: exactly the pairs that
+    hold keys, one item for a tile that holds none, tiles in order and
+    steps ascending; the host's count and the search of the running sum
+    (what an engine too large for the table runs) agree with it."""
+    B, tiles, sot, qot = _item_case(T, group, hkv, page, width, ctx, q_tile)
+    want = _want_items(ctx, T, sot, qot, tiles, page)
+    items = build_item_map(jnp.asarray(ctx, jnp.int32),
+                           jnp.full((B,), T, jnp.int32), sot, qot, tiles,
+                           page)
+    first, toi = np.asarray(items.first), np.asarray(items.tile_of_item)
+    n = int(first[-1])
+    assert first[0] == 0 and n == len(want)
+    assert toi.shape == (tiles.grid[0] * tiles.grid[2],) and tiles.item_table
+    assert [(int(t), int(i - first[t])) for i, t in enumerate(toi[:n])] \
+        == want
+    assert np.all(toi[n:] == tiles.grid[0] - 1)      # in range past the end
+    assert rect_grid_steps(tiles, B, T, np.asarray(ctx), page) \
+        == n * tiles.grid[1]
+    searched = items._replace(tile_of_item=jnp.zeros(1, jnp.int32))
+    assert [tuple(map(int, rpa._locate(jnp.int32(i), *searched,
+                                       tiles.grid[0], False)))
+            for i in range(n)] == want
+
+
+@pytest.mark.parametrize("name,T,group,hkv,page,width,ctx,q_tile",
+                         ITEM_CASES, ids=ITEM_IDS)
+def test_item_map_kernel_matches_oracle(name, T, group, hkv, page, width, ctx,
+                                        q_tile):
+    """The kernel over each map against the jnp gather, idle slots beside
+    live ones: every row equal (an idle slot attends its own scratch-page
+    row in both) and finite — no output block is left unwritten."""
+    B, d = len(ctx), 16
+    tables = np.zeros((B, width), np.int32)
+    next_page = 1
+    for s, c in enumerate(ctx):
+        if c > T:
+            n = -(-c // page)
+            tables[s, :n] = np.arange(next_page, next_page + n)
+            next_page += n
+    rng = np.random.default_rng(11)
+    kp, vp = (jnp.asarray(rng.standard_normal((next_page, hkv, page, d)),
+                          jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((B, T, hkv * group, d)), jnp.float32)
+    tables, lengths = jnp.asarray(tables), jnp.asarray(ctx, jnp.int32)
+    got = np.asarray(jax.jit(lambda *a: ragged_paged_attention_rect(
+        *a, q_tile=q_tile, interpret=True))(q, kp, vp, tables, lengths))
+    want = paged_decode_attention(q, PagedKVCache(kp, vp), tables, lengths,
+                                  impl="jnp")
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, np.asarray(want), **TOL)
+
+
+def test_item_search_kernel_matches_table(monkeypatch):
+    """An engine whose (tile, step) rectangle would not fit scalar memory
+    keeps the running sum alone and searches it in the index maps: the
+    same items, so the same output to the bit."""
+    _, T, group, hkv, page, width, ctx, _ = ITEM_CASES[ITEM_IDS.index(
+        "step_edges")]
+    rng = np.random.default_rng(12)
+    tables = jnp.asarray(rng.integers(1, 40, (len(ctx), width)), jnp.int32)
+    kp, vp = (jnp.asarray(rng.standard_normal((40, hkv, page, 16)),
+                          jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((len(ctx), T, hkv * group, 16)),
+                    jnp.float32)
+    lengths = jnp.asarray(ctx, jnp.int32)
+
+    def run():
+        return np.asarray(ragged_paged_attention_rect(
+            q, kp, vp, tables, lengths, interpret=True))
+
+    table = run()
+    monkeypatch.setattr(rpa, "ITEM_TABLE_MAX", 0)
+    assert not pick_tiles([T] * len(ctx), group, hkv, page, 16, width,
+                          4).item_table
+    np.testing.assert_array_equal(run(), table)
+
+
 def test_backend_selected_entry_point():
     """What ``resolve_attention_backend`` makes of "pallas-interpret"
     routes ``paged_decode_attention`` through the ragged kernel, and
@@ -350,17 +472,21 @@ def test_serving_bit_identical_across_backends(tiny):
 
 
 def test_kernel_grid_in_step_report(tiny):
-    """Every dispatch of ``engine.last_step`` carries ``kernel_grid``: the
-    picked grid's steps over all layers for the compiled shape, 0 where
-    the jnp path serves."""
+    """Every dispatch of ``engine.last_step`` carries ``kernel_grid``, the
+    grid steps its ragged kernel RUNS over all layers (the steps that hold
+    a key of a decoding slot, and one for each idle slot), beside
+    ``kernel_grid_full``, the rectangle the tile picker chose for the
+    compiled shape; both 0 where the jnp path serves."""
     from deepspeed_tpu.inference.serving import ServingEngine
     cfg, model, params = tiny
+    page = 8
 
     def dispatches(backend):
-        eng = ServingEngine(model, params, max_batch=4, page_size=8,
-                            max_seq=64, dtype=jnp.float32,
+        eng = ServingEngine(model, params, max_batch=8, page_size=page,
+                            max_seq=256, dtype=jnp.float32,
                             serving={"attention_backend": backend})
-        eng.add_request("r0", list(range(1, 10)), max_new_tokens=3)
+        eng.add_request("r0", list(range(1, 100)), max_new_tokens=4)
+        eng.add_request("r1", list(range(1, 10)), max_new_tokens=4)
         while eng.queue or eng.n_active:
             eng.step()
         return eng, [d for rep in eng.step_reports()
@@ -370,13 +496,25 @@ def test_kernel_grid_in_step_report(tiny):
     assert {d["phase"] for d in got} == {"prefill", "decode"}
     width = eng.tables.shape[1]
     for d in got:
-        want = pick_tiles([d["tokens"]] * d["batch"],
-                          cfg.n_heads // cfg.kv_heads, cfg.kv_heads, 8,
-                          cfg.head_dim, width, 4).grid_steps * cfg.n_layers
-        assert d["kernel_grid"] == want > 0
-    assert eng.last_step["dispatches"][-1]["kernel_grid"] > 0
+        tiles = pick_tiles([d["tokens"]] * d["batch"],
+                           cfg.n_heads // cfg.kv_heads, cfg.kv_heads, page,
+                           cfg.head_dim, width, 4)
+        assert d["kernel_grid_full"] == tiles.grid_steps * cfg.n_layers > 0
+        keys = tiles.pages * page
+        if d["phase"] == "decode":
+            # 2 of 8 slots decode: their contexts' steps, one an idle slot
+            assert len(d["contexts"]) == 2
+            run = sum(-(-c // keys) for c in d["contexts"]) \
+                + d["batch"] - len(d["contexts"])
+            assert d["kernel_grid"] == run * tiles.grid[1] * cfg.n_layers
+            assert 0 < d["kernel_grid"] < d["kernel_grid_full"]
+        else:
+            assert 0 < d["kernel_grid"] <= d["kernel_grid_full"]
+    assert any(max(d["contexts"]) > keys for d in got
+               if d["phase"] == "decode")       # a slot of several steps
     _, got = dispatches("jnp")
-    assert got and all(d["kernel_grid"] == 0 for d in got)
+    assert got and all(d["kernel_grid"] == d["kernel_grid_full"] == 0
+                       for d in got)
 
 
 def test_bad_backend_rejected_at_construction(tiny):
