@@ -149,6 +149,17 @@ class RequestRejected(Exception):
             + (f": {detail}" if detail else ""))
 
 
+class ServingUnsupported(ValueError):
+    """The engine was asked to serve a model with a feature nobody has
+    built for it yet (``feature`` names it): raised when the engine is
+    made, before any request, in place of an assert deep in a dispatch."""
+
+    def __init__(self, feature: str, detail: str = ""):
+        self.feature = feature
+        super().__init__(f"serving: {feature} is not supported"
+                         + (f": {detail}" if detail else ""))
+
+
 class ServingStalled(RuntimeError):
     """``generate()`` (or ``drain``) could not make progress within its
     step budget.  Unlike the assert it replaces, every already-completed

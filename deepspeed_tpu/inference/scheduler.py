@@ -217,7 +217,7 @@ class SchedulerBase:
             logits, eng.caches, _ = eng._run_step(*args)
             self._decode_sizes(lengths, ready)
             with tel.span("serve/decode/fetch"):
-                logits_np = np.asarray(logits[:, 0])
+                logits_np = eng._fetch(logits[:, 0])
             self.sched_stats["decode_steps"] += 1
             with tel.span("serve/decode/sample"):
                 return self._sample_and_finish(ready, logits_np)

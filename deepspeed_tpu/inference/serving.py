@@ -44,10 +44,12 @@ from deepspeed_tpu.inference.robustness import (
     REJECT_DUPLICATE, REJECT_INFEASIBLE, REJECT_OVERLOADED,
     REJECT_OVERSIZED, REJECT_QUEUE_FULL, SHED_DEADLINE, SHED_DRAIN,
     SHED_OLDEST, AdmissionController, RequestRejected, RequestResult,
-    RequestTracer, ServingRobustnessConfig, ServingStalled)
+    RequestTracer, ServingRobustnessConfig, ServingStalled,
+    ServingUnsupported)
 from deepspeed_tpu.comm.quantize import CommQuantizer
 from deepspeed_tpu.inference.prefix_cache import PrefixCache, PrefixMatch
 from deepspeed_tpu.inference.scheduler import SLO_CLASSES, create_scheduler
+from deepspeed_tpu.models.transformer import SERVE_COUNTERS
 from deepspeed_tpu.monitor.attribution import RequestAttributor
 from deepspeed_tpu.monitor.telemetry import (get_telemetry,
                                              register_compiled)
@@ -221,6 +223,11 @@ class ServingEngine:
         if num_pages is None:
             num_pages = max_batch * self.max_pages_per_seq + 1
         self.mesh = None
+        if isinstance(serving, ServingRobustnessConfig):
+            self.serving = serving
+        else:
+            self.serving = ServingRobustnessConfig(serving or {})
+        self._refuse_unsupported(tp_size, ep_size)
         caches = model.init_paged_caches(num_pages, page_size, dtype=dtype)
         if ep_size > 1:
             assert getattr(self.config, "is_moe", False), \
@@ -252,10 +259,6 @@ class ServingEngine:
         self.params = params
         self.caches = caches
         self.cache_dtype = dtype
-        if isinstance(serving, ServingRobustnessConfig):
-            self.serving = serving
-        else:
-            self.serving = ServingRobustnessConfig(serving or {})
         if injector is None:
             injector = FaultInjector.from_config(
                 self.serving.fault_injection)
@@ -335,20 +338,34 @@ class ServingEngine:
         # (ops/paged_attention.py)
         self.attention_impl = resolve_paged_impl(
             attn_impl, getattr(self.config, "attn_logit_softcap", None))
+        latent = bool(getattr(self.config, "is_latent", False))
+        if latent:
+            # the latent pools are written and read in XLA whatever the
+            # backend asked for (models/transformer.py mix_latent)
+            self.attention_impl = "jnp"
         self._paged_call = functools.partial(
             self.model.apply_with_paged_cache,
             attn_backend=self.attention_impl, attn_interpret=attn_interpret)
+        # a model that counts on the device what a dispatch did (keys
+        # selected, expert pairs: transformer.SERVE_COUNTERS) is told
+        # which rows are tokens and hands the counts back beside the
+        # logits; they come to the host in the fetch the step makes
+        # anyway (_fetch)
+        self._counted = bool(getattr(self.config, "counts_serving", False))
+        self._counters_pending = None
 
         # two named jits over the one call, so a device trace's
         # ``XLA Modules`` line tells prefill (B=1, bucketed T:
         # ``jit_serve_prefill``) from decode (B=max_batch, T=1, and the
         # speculative verify window: ``jit_serve_decode``); each caches a
         # compilation per input shape
-        def serve_prefill(params, ids, caches, tables, lengths):
-            return self._paged_call(params, ids, caches, tables, lengths)
+        def serve_prefill(params, ids, caches, tables, lengths, *real):
+            return self._paged_call(params, ids, caches, tables, lengths,
+                                    **dict(zip(("real_lengths",), real)))
 
-        def serve_decode(params, ids, caches, tables, lengths):
-            return self._paged_call(params, ids, caches, tables, lengths)
+        def serve_decode(params, ids, caches, tables, lengths, *real):
+            return self._paged_call(params, ids, caches, tables, lengths,
+                                    **dict(zip(("real_lengths",), real)))
 
         self._prefill_fn = jax.jit(serve_prefill, donate_argnums=(2,))
         self._step_fn = jax.jit(serve_decode, donate_argnums=(2,))
@@ -420,6 +437,29 @@ class ServingEngine:
             incidents.add_context("serving_health", self.health)
             incidents.add_context("inflight_traces",
                                   self.tracer.snapshot_open)
+
+    def _refuse_unsupported(self, tp_size, ep_size):
+        """What a latent-attention model cannot be served with until
+        someone builds it, refused by name when the engine is made."""
+        if not getattr(self.config, "is_latent", False):
+            return
+        if getattr(self.serving.prefix_cache, "enabled", False):
+            raise ServingUnsupported(
+                "prefix_cache with latent attention",
+                "a shared prefix would need a prefill that starts from "
+                "cached latent pages")
+        sched = self.serving.scheduler
+        if getattr(sched, "policy", "monolithic") != "monolithic":
+            raise ServingUnsupported(
+                f"scheduler.policy {sched.policy!r} with latent attention",
+                "a prefill chunk or a speculative verify window attends "
+                "from a context already in the pool; only whole-prompt "
+                "prefills (monolithic) and T=1 decode steps are built")
+        if tp_size > 1 or ep_size > 1:
+            raise ServingUnsupported(
+                "tp_size / ep_size > 1 with latent attention",
+                "the latent pools have no head axis to shard, and the "
+                "expert layer holds a fixed share (moe_experts_held)")
 
     # -- telemetry -------------------------------------------------------
     @property
@@ -1119,14 +1159,22 @@ class ServingEngine:
         self.tables[slot, :] = 0
         self.tables[slot, :len(pages)] = pages
 
-    def _run_step(self, ids, tables, lengths, phase="decode"):
+    def _run_step(self, ids, tables, lengths, phase="decode", real=None):
         """One dispatch of the paged step: the launch only, nothing here
         waits for the device.  ``lengths`` comes as the host's numpy array
-        (the call places it): ``kernel_grid`` is reckoned from it."""
+        (the call places it): ``kernel_grid`` is reckoned from it.  A
+        model that counts its dispatches is also told how many of each
+        sequence's rows are tokens (``real``: a prefill's prompt under its
+        bucket; without it every row of a slot that holds a context, so
+        none of a decode batch's idle slots)."""
         step_fn = self._prefill_fn if phase == "prefill" else self._step_fn
-        return self._dispatch(
-            step_fn, (self.params, ids, self.caches, tables, lengths),
-            phase, *ids.shape, starts=np.asarray(lengths))
+        args = (self.params, ids, self.caches, tables, lengths)
+        if self._counted:
+            if real is None:
+                real = np.where(np.asarray(lengths) > 0, ids.shape[1], 0)
+            args += (np.asarray(real, np.int32),)
+        return self._dispatch(step_fn, args, phase, *ids.shape,
+                              starts=np.asarray(lengths))
 
     def kernel_grid(self, phase, batch, tokens, starts, config=None):
         """(run, full) grid steps of the ragged paged-attention kernel in
@@ -1170,24 +1218,43 @@ class ServingEngine:
         # backend, so it resolves its own
         kv_write = self.attention_impl if config is None else \
             resolve_paged_impl(None, config.attn_logit_softcap)
-        with self.telemetry.span(
-                "serve/step",
-                attrs={"backend": backend or self.attention_backend,
-                       "phase": phase, "batch": int(batch),
-                       "tokens": int(tokens),
-                       "kernel_grid": kernel_grid,
-                       "kernel_grid_full": kernel_grid_full}), \
+        attrs = {"backend": backend or self.attention_backend,
+                 "phase": phase, "batch": int(batch), "tokens": int(tokens),
+                 "kernel_grid": kernel_grid,
+                 "kernel_grid_full": kernel_grid_full}
+        with self.telemetry.span("serve/step", attrs=attrs), \
                 self._prof_track("prefill" if phase == "prefill"
                                  else "serve_step"), \
                 (self.mesh if self.mesh is not None
                  else contextlib.nullcontext()):
             out = fn(*args)
-        self._report["dispatches"].append(
-            {"phase": phase, "batch": int(batch), "tokens": int(tokens),
-             "kernel_grid": kernel_grid,
-             "kernel_grid_full": kernel_grid_full, "kv_write": kv_write,
-             "t0_ns": t0_ns, "t1_ns": time.perf_counter_ns()})
+        record = {"phase": phase, "batch": int(batch), "tokens": int(tokens),
+                  "kernel_grid": kernel_grid,
+                  "kernel_grid_full": kernel_grid_full, "kv_write": kv_write,
+                  "t0_ns": t0_ns, "t1_ns": time.perf_counter_ns()}
+        self._report["dispatches"].append(record)
+        if self._counted and fn in (self._prefill_fn, self._step_fn):
+            # the model's own counters, still on the device: they land in
+            # this record and on the span when the step fetches its logits
+            self._counters_pending = (out[3], record, attrs)
+            out = out[:3]
         return out
+
+    def _fetch(self, logits):
+        """The logits a step samples from, to the host.  A counted
+        model's ``SERVE_COUNTERS`` of that dispatch ride in the same
+        transfer (one ``device_get`` of both, no second wait) into the
+        dispatch's record in ``last_step`` and the attributes of its
+        ``serve/step`` span."""
+        pending, self._counters_pending = self._counters_pending, None
+        if pending is None:
+            return np.asarray(logits)
+        counters, record, attrs = pending
+        logits, counters = jax.device_get((logits, counters))
+        values = dict(zip(SERVE_COUNTERS, (int(v) for v in counters)))
+        record.update(values)
+        attrs.update(values)
+        return logits
 
     # -- prefix-cache plumbing ------------------------------------------
     def _on_prefix_evict(self, page: int):
@@ -1236,7 +1303,10 @@ class ServingEngine:
                         jnp.asarray(self.tables[slot:slot + 1]),
                         np.full((1,), cached, np.int32))
             t0 = self._clock()
-            logits, self.caches, _ = self._run_step(*args, phase="prefill")
+            # a model that counts its dispatches is told the prompt's rows
+            real = {"real": [len(suffix)]} if self._counted else {}
+            logits, self.caches, _ = self._run_step(*args, phase="prefill",
+                                                    **real)
             self._prefill_done(len(suffix), len(req.prompt))
             # monolithic prefill is one dispatch: fold its active wall
             # time into the critical path's prefill stage (chunked
@@ -1245,7 +1315,7 @@ class ServingEngine:
             self.lengths[slot] = len(req.prompt)
             req.prefilled = len(req.prompt)
             with tel.span("serve/prefill/fetch"):
-                row = np.asarray(logits[0, len(suffix) - 1])
+                row = self._fetch(logits[0, len(suffix) - 1])
             with tel.span("serve/prefill/sample"):
                 req.last_token = self._sample(req, row)
                 # the first output token exists as of the sample above —
@@ -1567,6 +1637,14 @@ class ServingEngine:
         if stray_rng:
             leaks["stray_rng"] = stray_rng
         leaks.update(self.alloc.audit())
+        # one allocator addresses every pool (K and V; a latent model's
+        # entries and its indexer keys, of unlike widths): each leaf must
+        # have the allocator's pages on its page axis
+        pools = {i: tuple(leaf.shape) for i, leaf in enumerate(
+            jax.tree_util.tree_leaves(self.caches))
+            if leaf.shape[1] != self.alloc.num_pages}
+        if pools:
+            leaks["pool_page_mismatch"] = pools
         if self.prefix_cache is not None:
             leaks.update(self.prefix_cache.audit())
         dirty = [s for s in range(self.max_batch)

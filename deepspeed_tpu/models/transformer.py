@@ -23,7 +23,7 @@ decoders).  TPU-first design:
 import functools
 import math
 from dataclasses import dataclass, field, replace
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -115,10 +115,69 @@ class TransformerConfig:
     moe_norm_topk_prob: bool = True  # renormalize the k gate values
     #   (Mixtral / Qwen2-MoE norm_topk_prob); False keeps softmax mass
     moe_eval_capacity_factor: Optional[float] = None  # None → capacity_factor
+    # The serving-time expert layer (``moe_dropless``): every token gets
+    # its ``moe_top_k`` experts, no capacity and no dropped token (the
+    # capacity path above stays the trainer's), and the chip computes the
+    # terms of the ``moe_experts_held`` experts (None: all) from
+    # ``moe_experts_first`` on; the other experts' terms are other chips'
+    # (docs/serving.md).  ``moe_scoring`` is its router's: "softmax" over
+    # all ``moe_num_experts``, or "sigmoid" with a selection bias
+    # (DeepSeek-V3 / GLM-5 ``noaux_tc``)
+    moe_dropless: bool = False
+    moe_scoring: str = "softmax"
+    moe_experts_held: Optional[int] = None
+    moe_experts_first: int = 0
+    moe_routed_scale: float = 1.0       # routed_scaling_factor
+    moe_ffn_hidden_size: Optional[int] = None   # expert width; None → ffn
+    moe_shared_experts: int = 0         # ungated always-on experts
+    first_dense_layers: int = 0         # leading layers with a dense FFN
+    # Latent attention (MLA; ``kv_lora_rank`` > 0): the cache holds
+    # [c_kv | k_rope] a token, heads are nope | rope wide on the query
+    # and key side and ``v_head_dim`` on the value side
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the learned key selection inside it (``index_topk`` > 0): each query
+    # attends to its index_topk best causal keys by the indexer's score
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_norm_eps: float = 1e-6        # the indexer key's LayerNorm
+    # ``init``'s seeded token embeddings: None keeps
+    # 1 / sqrt(hidden), a row of norm 1, which one layer's attention
+    # output (norm 2-3 under the variance-keeping projections) outweighs,
+    # so a token's state after the first layer is mostly an average of
+    # its context's values.  A model that SELECTS its keys is then
+    # ill-conditioned: two forwards that differ by a rounding swap keys at
+    # the top-k boundary, and the swap moves everything downstream by a
+    # tenth (docs/serving.md).  1.0 is unit elements (torch.nn.Embedding's
+    # default): the token keeps its identity and attention is a few
+    # percent of the stream, as in a trained network
+    init_embed_std: Optional[float] = None
 
     @property
     def is_moe(self):
         return self.moe_num_experts > 1
+
+    @property
+    def is_latent(self):
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_held(self):
+        return self.moe_experts_held or self.moe_num_experts
+
+    @property
+    def counts_serving(self):
+        """A serving dispatch of this model returns ``SERVE_COUNTERS``."""
+        return self.is_latent or (self.is_moe and self.moe_dropless)
+
+    @property
+    def layers_listed(self):
+        """Layers differ in structure: params["layers"] is a list."""
+        return self.is_moe or self.is_latent
 
     @property
     def kv_heads(self):
@@ -208,6 +267,58 @@ class TransformerConfig:
         if self.embed_norm:
             total += d
         return total
+
+
+class LatentQuery(NamedTuple):
+    """A latent-attention layer's query side, per head."""
+    nope: Any       # [B, T, H, qk_nope_head_dim]
+    rope: Any       # [B, T, H, qk_rope_head_dim], turned
+
+
+class LatentKey(NamedTuple):
+    """Its key side, shared by all heads: what the cache holds."""
+    c_kv: Any       # [B, T, kv_lora_rank], normed
+    rope: Any       # [B, T, qk_rope_head_dim], turned
+
+
+class Indexer(NamedTuple):
+    """The selection's own query heads, head weights and key."""
+    q: Any          # [B, T, Hi, Di]
+    w: Any          # [B, T, Hi]
+    k: Any          # [B, T, Di]
+
+
+# what a serving dispatch of a latent / dropless model counts on the device
+# (``apply_with_paged_cache`` returns them, in this order, as one int32
+# vector beside the logits): keys attended and causal keys in context,
+# summed over the REAL queries and the layers; (real token, expert) pairs
+# computed here, summed over expert layers; the fullest held expert of any
+# layer
+SERVE_COUNTERS = ("selected", "context_keys", "expert_pairs",
+                  "expert_load_max")
+
+
+class ServeCounts:
+    """One serving dispatch's account of itself, filled while it is
+    traced: ``real`` [B, T] says which rows are tokens (not bucket padding,
+    not an idle slot of a decode batch), the layers add their traced
+    counts (``expert_load_max`` keeps the largest)."""
+
+    def __init__(self, real):
+        self.real = real
+        self.counts = {}
+
+    def add(self, **counts):
+        for name, value in counts.items():
+            if name == "expert_load_max":
+                self.counts[name] = jnp.maximum(self.counts.get(name, 0),
+                                                value)
+            else:
+                self.counts[name] = self.counts.get(name, 0) + value
+
+    def vector(self):
+        return jnp.stack([jnp.asarray(self.counts.get(name, 0), jnp.int32)
+                          for name in SERVE_COUNTERS])
 
 
 # "gelu" is the tanh approximation (GPT-2 gelu_new / Gemma
@@ -407,7 +518,7 @@ class CausalTransformerLM:
     def __init__(self, config: TransformerConfig):
         self.config = config
         self.gate = None
-        if config.is_moe:
+        if config.is_moe and not config.moe_dropless:
             from deepspeed_tpu.moe.sharded_moe import TopKGate
             self.gate = TopKGate(
                 config.hidden_size, config.moe_num_experts,
@@ -425,6 +536,8 @@ class CausalTransformerLM:
         # reference convention: every Nth layer hosts experts (freq=2 →
         # alternating dense/MoE, MoE on odd layers)
         c = self.config
+        if i < c.first_dense_layers:
+            return False
         return c.is_moe and (i % c.moe_layer_freq == c.moe_layer_freq - 1)
 
     # ------------------------------------------------------------------
@@ -438,8 +551,11 @@ class CausalTransformerLM:
             return (jax.random.normal(key, shape, jnp.float32) /
                     math.sqrt(fan_in)).astype(dtype)
 
-        if c.is_moe:
-            return self._init_moe(rng, dtype, dense)
+        # dense() divides by sqrt(fan_in): the fan-in that gives the
+        # embeddings' seeded spread
+        embed_fan = d if c.init_embed_std is None else c.init_embed_std ** -2
+        if c.layers_listed:
+            return self._init_moe(rng, dtype, dense, embed_fan)
 
         layers = {
             "attn_norm": jnp.ones((L, d), dtype),
@@ -479,7 +595,7 @@ class CausalTransformerLM:
             layers["attn_norm_b"] = jnp.zeros((L, d), dtype)
             layers["mlp_norm_b"] = jnp.zeros((L, d), dtype)
         params = {
-            "tok_embed": dense(keys[7], (v, d), d),
+            "tok_embed": dense(keys[7], (v, d), embed_fan),
             "final_norm": jnp.ones((d,), dtype),
             "layers": layers,
         }
@@ -497,7 +613,7 @@ class CausalTransformerLM:
                 params["lm_head_b"] = jnp.zeros((v,), dtype)
         return params
 
-    def _init_moe(self, rng, dtype, dense):
+    def _init_moe(self, rng, dtype, dense, embed_fan):
         """MoE variant: ``layers`` is a LIST of per-layer dicts (layers
         differ in structure, so the forward unrolls instead of scanning —
         reference MoE models interleave dense/expert layers the same way)."""
@@ -510,14 +626,18 @@ class CausalTransformerLM:
             ks = jax.random.split(key, 8)
             norm_keys = (("attn_post_norm", "mlp_post_norm")
                          if c.post_norm_only else ("attn_norm", "mlp_norm"))
-            layer = {
-                norm_keys[0]: jnp.ones((d,), dtype),
-                "wq": dense(ks[0], (d, H * dh), d),
-                "wk": dense(ks[1], (d, Hkv * dh), d),
-                "wv": dense(ks[2], (d, Hkv * dh), d),
-                "wo": dense(ks[3], (H * dh, d), H * dh),
-                norm_keys[1]: jnp.ones((d,), dtype),
-            }
+            if c.is_latent:
+                layer = self._init_latent_attn(ks[0], dtype, dense)
+                layer.update({k: jnp.ones((d,), dtype) for k in norm_keys})
+            else:
+                layer = {
+                    norm_keys[0]: jnp.ones((d,), dtype),
+                    "wq": dense(ks[0], (d, H * dh), d),
+                    "wk": dense(ks[1], (d, Hkv * dh), d),
+                    "wv": dense(ks[2], (d, Hkv * dh), d),
+                    "wo": dense(ks[3], (H * dh, d), H * dh),
+                    norm_keys[1]: jnp.ones((d,), dtype),
+                }
             if c.qk_norm:
                 qd, kd = ((H * dh, Hkv * dh) if c.qk_norm == "rms_flat"
                           else (dh, dh))
@@ -526,7 +646,28 @@ class CausalTransformerLM:
                 if c.qk_norm == "layernorm" and c.norm_bias:
                     layer["q_norm_b"] = jnp.zeros((qd,), dtype)
                     layer["k_norm_b"] = jnp.zeros((kd,), dtype)
-            if moe:
+            if moe and c.moe_dropless:
+                # the router keeps its published width; the expert leaves
+                # hold this chip's share (experts 0..held-1)
+                fe, held = c.moe_ffn_hidden_size or f, c.experts_held
+                kg, kb, ksh = jax.random.split(ks[4], 3)
+                layer["moe"] = {
+                    "wg": dense(kg, (d, E), d).astype(jnp.float32),
+                    "w_up": dense(ks[5], (held, d, fe), d),
+                    "w_down": dense(ks[6], (held, fe, d), fe),
+                    "w_gate": dense(ks[7], (held, d, fe), d),
+                }
+                if c.moe_scoring == "sigmoid":      # the selection bias
+                    layer["moe"]["router_bias"] = 0.02 * jax.random.normal(
+                        kb, (E,), jnp.float32)
+                if c.moe_shared_experts:
+                    fs = fe * c.moe_shared_experts
+                    k0, k1, k2 = jax.random.split(ksh, 3)
+                    layer["moe"]["shared"] = {
+                        "w_gate": dense(k0, (d, fs), d),
+                        "w_up": dense(k1, (d, fs), d),
+                        "w_down": dense(k2, (fs, d), fs)}
+            elif moe:
                 layer["moe"] = {
                     "wg": dense(ks[4], (d, E), d).astype(jnp.float32),
                     "w_up": dense(ks[5], (E, d, f), d),
@@ -542,7 +683,7 @@ class CausalTransformerLM:
             return layer
 
         params = {
-            "tok_embed": dense(keys[-1], (v, d), d),
+            "tok_embed": dense(keys[-1], (v, d), embed_fan),
             "final_norm": jnp.ones((d,), dtype),
             "layers": [one_layer(keys[i], self._is_moe_layer(i))
                        for i in range(c.n_layers)],
@@ -552,6 +693,36 @@ class CausalTransformerLM:
         if not c.tie_embeddings:
             params["lm_head"] = dense(keys[-3], (d, v), d)
         return params
+
+    def _init_latent_attn(self, key, dtype, dense):
+        """One layer's latent-attention weights: the query's low-rank pair
+        with its norm, the joint [c_kv | k_rope] projection with the
+        latent's norm, the per-head decompression ``wkv_b`` ([k_nope | v]
+        a head: ``W_uk`` and ``W_uv`` of the absorbed form), ``wo``, and
+        the indexer (query heads off the query latent, one LayerNormed key
+        a token, a weight a head)."""
+        c = self.config
+        d, H, R, Rq = c.hidden_size, c.n_heads, c.kv_lora_rank, c.q_lora_rank
+        dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+        ks = jax.random.split(key, 8)
+        layer = {
+            "wq_a": dense(ks[0], (d, Rq), d),
+            "q_a_norm": jnp.ones((Rq,), dtype),
+            "wq_b": dense(ks[1], (Rq, H * (dn + dr)), Rq),
+            "wkv_a": dense(ks[2], (d, R + dr), d),
+            "kv_a_norm": jnp.ones((R,), dtype),
+            "wkv_b": dense(ks[3], (R, H * (dn + dv)), R),
+            "wo": dense(ks[4], (H * dv, d), H * dv),
+        }
+        if c.index_topk:
+            Hi, Di = c.index_n_heads, c.index_head_dim
+            layer.update({
+                "idx_wq": dense(ks[5], (Rq, Hi * Di), Rq),
+                "idx_wk": dense(ks[6], (d, Di), d),
+                "idx_k_norm": jnp.ones((Di,), dtype),
+                "idx_k_norm_b": jnp.zeros((Di,), dtype),
+                "idx_w": dense(ks[7], (d, Hi), d)})
+        return layer
 
     # ------------------------------------------------------------------
     def tp_rules(self):
@@ -598,8 +769,46 @@ class CausalTransformerLM:
             out = out + layer[f"{name}_b"].astype(out.dtype)
         return out
 
+    def _latent_qkv(self, h, layer, positions):
+        """A latent-attention layer's query side, key side and indexer
+        from the pre-normed input (what ``_qkv`` hands the latent mixers
+        as its q, k, v): ``LatentQuery(q_nope [B,T,H,dn], q_rope
+        [B,T,H,dr])``, ``LatentKey(c_kv [B,T,R], k_rope [B,T,dr])`` and
+        ``Indexer(q [B,T,Hi,Di], w [B,T,Hi], k [B,T,Di])`` (None without a
+        selection).  Rotary pairs are (2i, 2i+1); the indexer turns the
+        FIRST ``qk_rope_head_dim`` values of its heads."""
+        from deepspeed_tpu.ops.latent_attention import rope_interleaved
+        c = self.config
+        B, T, _ = h.shape
+        H, R = c.n_heads, c.kv_lora_rank
+        dn, dr = c.qk_nope_head_dim, c.qk_rope_head_dim
+        rope = functools.partial(rope_interleaved, positions=positions,
+                                 theta=c.rope_theta)
+        with jax.named_scope("latent_attn"):
+            c_q = _norm(h @ layer["wq_a"], layer["q_a_norm"], c.norm_eps,
+                        True)
+            q = (c_q @ layer["wq_b"]).reshape(B, T, H, dn + dr)
+            kv = h @ layer["wkv_a"]
+            query = LatentQuery(q[..., :dn], rope(q[..., dn:]))
+            key = LatentKey(
+                _norm(kv[..., :R], layer["kv_a_norm"], c.norm_eps, True),
+                rope(kv[..., R:]))
+        if not c.index_topk:
+            return query, key, None
+        with jax.named_scope("select"):
+            q_i = (c_q @ layer["idx_wq"]).reshape(
+                B, T, c.index_n_heads, c.index_head_dim)
+            k_i = _norm(h @ layer["idx_wk"], layer["idx_k_norm"],
+                        c.index_norm_eps, False, layer["idx_k_norm_b"])
+            turn = lambda x: jnp.concatenate(   # noqa: E731
+                [rope(x[..., :dr]), x[..., dr:]], axis=-1)
+            return query, key, Indexer(turn(q_i), h @ layer["idx_w"],
+                                       turn(k_i))
+
     def _qkv(self, h, layer, B, S, positions):
         c = self.config
+        if c.is_latent:
+            return self._latent_qkv(h, layer, positions)
         H, Hkv, dh = c.n_heads, c.kv_heads, c.head_dim
         qf = self._proj(h, layer, "wq")
         kf = self._proj(h, layer, "wk")
@@ -748,6 +957,85 @@ class CausalTransformerLM:
                                       layer=index, items=items)
         return attn, pools
 
+    def _latent_fresh(self, q, k, idx, layer, positions=None, counts=None):
+        """Latent attention of T tokens over themselves (a whole sequence,
+        or a prefill from an empty context): keys and values DECOMPRESSED
+        for the tokens at hand, each query over its selected causal keys.
+        -> [B, T, H, dv]."""
+        from deepspeed_tpu.ops import latent_attention as la
+        c = self.config
+        B, T, H, dn = q.nope.shape
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+        with jax.named_scope("latent_attn"):
+            kv = (k.c_kv @ layer["wkv_b"]).reshape(B, T, H, -1)
+            keys = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(
+                    k.rope[:, :, None], (B, T, H, k.rope.shape[-1]))], -1)
+            queries = jnp.concatenate([q.nope, q.rope], axis=-1)
+        if idx is None:     # no selection: every causal key
+            idx = Indexer(jnp.zeros((B, T, 1, 1), queries.dtype),
+                          jnp.zeros((B, T, 1), queries.dtype),
+                          jnp.zeros((B, T, 1), queries.dtype))
+        out, attended, context = la.prefill_attention(
+            queries, keys, kv[..., dn:], idx.q, idx.w, idx.k, positions,
+            c.index_topk or T, self._latent_scale(),
+            real=None if counts is None else counts.real)
+        if counts is not None:
+            counts.add(selected=attended, context_keys=context)
+        return out
+
+    def mix_latent_whole(self, q, k, idx, layer, cache):
+        """``mix_full``'s place for a latent model: the whole sequence,
+        no cache."""
+        return self._latent_fresh(q, k, idx, layer), cache
+
+    def _latent_scale(self):
+        c = self.config
+        return c.attn_scale if c.attn_scale is not None else \
+            1.0 / math.sqrt(c.qk_nope_head_dim + c.qk_rope_head_dim)
+
+    def mix_latent(self, q, k, idx, layer, pools, *, index, block_tables,
+                   lengths, counts=None):
+        """Write this layer's entries ``[c_kv | k_rope]`` and indexer keys
+        into the two STACKED latent pools at ``lengths``, then attend: a
+        decode step (T = 1) gathers its selected entries out of the pool
+        and uses the absorbed weights; T > 1 is a prefill FROM AN EMPTY
+        CONTEXT (``lengths`` 0: ``ServingEngine`` refuses what would break
+        that) and decompresses the tokens it brings."""
+        from deepspeed_tpu.ops import latent_attention as la
+        c = self.config
+        B, T, H, dn = q.nope.shape
+        entry = jnp.concatenate([k.c_kv, k.rope], axis=-1)
+        index_key = idx.k if idx is not None else \
+            jnp.zeros((B, T, pools.index_pages.shape[-1]), entry.dtype)
+        with jax.named_scope("latent_attn"):
+            pools = la.write_latent(pools, index, block_tables, lengths,
+                                    entry, index_key)
+        if T > 1:
+            positions = lengths[:, None] + jnp.arange(T)[None, :]
+            return self._latent_fresh(q, k, idx, layer, positions,
+                                      counts), pools
+        R = c.kv_lora_rank
+        w_kvb = layer["wkv_b"].reshape(R, H, -1)
+        with jax.named_scope("latent_attn"):
+            q_abs = jnp.einsum("bhd,rhd->bhr", q.nope[:, 0], w_kvb[..., :dn])
+        if idx is None:
+            idx = Indexer(jnp.zeros((B, 1, 1, pools.index_pages.shape[-1]),
+                                    entry.dtype),
+                          jnp.zeros((B, 1, 1), entry.dtype), None)
+        o_lat, attended, context = la.decode_attention(
+            q_abs, q.rope[:, 0], idx.q[:, 0], idx.w[:, 0], pools, index,
+            block_tables, lengths + 1,
+            c.index_topk or block_tables.shape[1] * pools.latent_pages.shape[2],
+            self._latent_scale(),
+            real=None if counts is None else counts.real[:, 0])
+        with jax.named_scope("latent_attn"):
+            out = jnp.einsum("bhr,rhd->bhd", o_lat, w_kvb[..., dn:])
+        if counts is not None:
+            counts.add(selected=attended, context_keys=context)
+        return out[:, None], pools
+
     def _cached_attn_bias(self, layer, T, S, length):
         """Decode-path analogue of ``_attn_bias`` over the full cache
         buffer [S]; query positions are ``length - T + arange(T)``."""
@@ -776,13 +1064,49 @@ class CausalTransformerLM:
         B, T, _ = h.shape
         q, k, v = self._qkv(h, layer, B, T, positions)
         attn, cache = mix(q, k, v, layer, cache)
-        return self._proj(attn.reshape(B, T, c.n_heads * c.head_dim), layer,
-                          "wo"), cache
+        return self._proj(attn.reshape(B, T, -1), layer, "wo"), cache
+
+    def _dropless_delta(self, h, layer, counts=None):
+        """The dropless expert layer, this chip's share of it: routing
+        over all experts, the held experts' terms by a grouped product
+        with no capacity, plus the ungated shared expert."""
+        from deepspeed_tpu.moe.sharded_moe import (dropless_held_experts,
+                                                   dropless_route)
+        c = self.config
+        moe = layer["moe"]
+        B, T, d = h.shape
+        flat = h.reshape(B * T, d)
+        with jax.named_scope("router"):
+            chosen, weights = dropless_route(
+                flat, moe["wg"], moe.get("router_bias"), c.moe_top_k,
+                scoring=c.moe_scoring, scale=c.moe_routed_scale,
+                norm=c.moe_norm_topk_prob)
+        if counts is not None:
+            # bucket padding and idle slots are nobody's tokens: their
+            # pairs are neither computed nor counted
+            chosen = jnp.where(counts.real.reshape(B * T, 1), chosen, -1)
+        with jax.named_scope("experts"):
+            out, load = dropless_held_experts(
+                flat, chosen, weights, moe, _ACTIVATIONS[c.activation],
+                first=c.moe_experts_first)
+        out = out.astype(h.dtype)
+        if "shared" in moe:
+            with jax.named_scope("shared_expert"):
+                sh = moe["shared"]
+                act = _ACTIVATIONS[c.activation]
+                out = out + (act(flat @ sh["w_gate"]) * (flat @ sh["w_up"])
+                             ) @ sh["w_down"]
+        if counts is not None:
+            counts.add(expert_pairs=jnp.sum(load),
+                       expert_load_max=jnp.max(load))
+        return out.reshape(B, T, d), jnp.float32(0.0)
 
     @jax.named_scope("mlp")
-    def _mlp_delta(self, h, layer, rng=None, train=True):
+    def _mlp_delta(self, h, layer, rng=None, train=True, counts=None):
         """FFN sub-block on pre-normed input; returns (delta, aux_loss)."""
         c = self.config
+        if "moe" in layer and c.moe_dropless:
+            return self._dropless_delta(h, layer, counts)
         if "moe" in layer:
             from deepspeed_tpu.moe.sharded_moe import moe_layer_forward
             act = _ACTIVATIONS[c.activation]
@@ -837,11 +1161,13 @@ class CausalTransformerLM:
         return delta
 
     def block(self, x, layer, positions, mix, cache=None, rng=None,
-              train=True):
+              train=True, counts=None):
         """One transformer block → (x, cache, aux_loss): the residual
         structure, pre-norms, q/k/v, ``wo``, sandwich norms, residual scale
         and the MLP (dense or MoE).  ``mix`` (one of the mixers above) is
-        all a forward chooses; ``cache`` goes into it and comes out."""
+        all a forward chooses; ``cache`` goes into it and comes out.
+        ``counts``: the serving dispatch's :class:`ServeCounts`, which the
+        expert layer adds to."""
         c = self.config
         if c.parallel_block:
             # GPT-J / parallel-residual NeoX: both sub-blocks read the
@@ -861,13 +1187,16 @@ class CausalTransformerLM:
         delta, cache = self._attn_delta(h, layer, positions, mix, cache)
         x = x + self._sandwich(delta, layer, "attn_post_norm")
         h = _pre_norm(x, layer, "mlp_norm", c)
-        delta, aux = self._mlp_delta(h, layer, rng=rng, train=train)
+        delta, aux = self._mlp_delta(h, layer, rng=rng, train=train,
+                                     counts=counts)
         return x + self._sandwich(delta, layer, "mlp_post_norm"), cache, aux
 
     def _layer(self, x, layer, positions, rng=None, train=True):
         """The block over a whole sequence → (x, aux): what ``apply``
         scans and ``stream_layer`` / ``runtime/pipe`` call."""
-        x, _, aux = self.block(x, layer, positions, self.mix_full, rng=rng,
+        mix = self.mix_latent_whole if self.config.is_latent \
+            else self.mix_full
+        x, _, aux = self.block(x, layer, positions, mix, rng=rng,
                                train=train)
         return x, aux
 
@@ -985,6 +1314,10 @@ class CausalTransformerLM:
         the decode forward stays a single scan.  (MoE models use a list of
         caches matching their per-layer params list.)"""
         c = self.config
+        if c.is_latent:
+            raise NotImplementedError(
+                "latent attention has no dense KVCache path: serve it "
+                "through the paged pools (init_paged_caches)")
         if c.is_moe:
             return [init_cache(batch, max_seq, c.kv_heads, c.head_dim, dtype)
                     for _ in range(c.n_layers)]
@@ -1045,6 +1378,14 @@ class CausalTransformerLM:
         c = self.config
         assert not c.use_alibi and not c.local_attn_pattern, \
             "paged serving does not support alibi/local-window models yet"
+        if c.is_latent:
+            # one entry a token, [c_kv | k_rope], and the indexer's key:
+            # two pools of unlike widths over the same pages
+            from deepspeed_tpu.ops.latent_attention import init_latent_pools
+            return init_latent_pools(
+                c.n_layers, num_pages, page_size,
+                c.kv_lora_rank + c.qk_rope_head_dim,
+                max(c.index_head_dim, 1), dtype)
         # each stack made in place: a broadcast of one layer's pool and a
         # copy of it held four stacks at once, the process's HBM peak
         shape = (c.n_layers, num_pages, c.kv_heads, page_size, c.head_dim)
@@ -1053,7 +1394,7 @@ class CausalTransformerLM:
 
     def apply_with_paged_cache(self, params, input_ids, caches, block_tables,
                                lengths, *, attn_backend=None,
-                               attn_interpret=False):
+                               attn_interpret=False, real_lengths=None):
         """Forward over paged KV caches: appends the T new tokens of every
         sequence at ``lengths`` (tables must already map the pages) and
         attends over each sequence's ragged prefix.  Returns
@@ -1064,7 +1405,14 @@ class CausalTransformerLM:
         ``attn_interpret`` select the paged-attention implementation
         (``ops/paged_attention.py``: None = auto, "jnp" oracle, "pallas"
         fused ragged kernel; interpret runs the kernel on CPU) — static
-        kwargs, so the serving engine binds them before jit.
+        kwargs, so the serving engine binds them before jit.  A latent
+        model's pools are read and written in XLA whatever the backend
+        (``mix_latent``).  A model that counts its serving dispatches
+        (``config.counts_serving``) returns a fourth result, the
+        dispatch's ``SERVE_COUNTERS`` as one int32 vector, over the real
+        rows: the first ``real_lengths`` [B] of each sequence's T (all
+        without it; a bucket's padding and a decode batch's idle slots
+        are the engine's to name).
         """
         from deepspeed_tpu.ops.paged_attention import (paged_read_items,
                                                        resolve_paged_impl)
@@ -1073,23 +1421,34 @@ class CausalTransformerLM:
         positions = lengths[:, None] + jnp.broadcast_to(
             jnp.arange(T)[None, :], (B, T))
         x = self.embed(params, input_ids, positions)
-        # one backend for the write and the read of the pools
-        impl = resolve_paged_impl(attn_backend, c.attn_logit_softcap)
-        # the steps of the read that hold keys: once a dispatch, not a layer
-        items = paged_read_items((B, T, c.n_heads, c.head_dim), caches,
-                                 block_tables, lengths + T, impl)
+        counts = None
+        if c.counts_serving:
+            counts = ServeCounts(
+                jnp.ones((B, T), bool) if real_lengths is None
+                else jnp.arange(T)[None, :] < real_lengths[:, None])
+        if c.is_latent:
+            paged = dict(block_tables=block_tables, lengths=lengths,
+                         counts=counts)
+            mixer = self.mix_latent
+        else:
+            # one backend for the write and the read of the pools
+            impl = resolve_paged_impl(attn_backend, c.attn_logit_softcap)
+            # the steps of the read that hold keys: once a dispatch, not a
+            # layer
+            items = paged_read_items((B, T, c.n_heads, c.head_dim), caches,
+                                     block_tables, lengths + T, impl)
+            paged = dict(block_tables=block_tables, lengths=lengths,
+                         impl=impl, interpret=attn_interpret, items=items)
+            mixer = self.mix_paged
 
         def body(carry, inp):
             # the stacked pools stay ONE buffer through the layers: carried,
             # written in place, read in place by layer index
             x, pools = carry
             layer, i = inp
-            mix = functools.partial(
-                self.mix_paged, index=i, block_tables=block_tables,
-                lengths=lengths, impl=impl, interpret=attn_interpret,
-                items=items)
+            mix = functools.partial(mixer, index=i, **paged)
             x, pools, _ = self.block(x, layer, positions, mix, pools,
-                                     train=False)
+                                     train=False, counts=counts)
             return (x, pools), None
 
         if isinstance(params["layers"], (list, tuple)):
@@ -1106,6 +1465,9 @@ class CausalTransformerLM:
                 body, (x, caches),
                 (params["layers"], jnp.arange(c.n_layers)))
 
+        if counts is not None:
+            return (self.logits(params, x), caches, lengths + T,
+                    counts.vector())
         return self.logits(params, x), caches, lengths + T
 
     # ------------------------------------------------------------------

@@ -379,3 +379,79 @@ def moe_layer_forward(gate: TopKGate, gate_params, expert_params, expert_fn,
             axis=1)                                        # all-to-all #2
     combined = maybe_constrain(combined, TOKENS_SPEC)
     return combined.reshape(B, S, D), out.l_aux, out.exp_counts
+
+
+# ----------------------------------------------------------------------
+# dropless routing and a chip's share of the experts
+# ----------------------------------------------------------------------
+DROPLESS_TILE = 512     # rows of one grouped-product step
+
+
+def dropless_route(h, wg, bias, k, scoring="sigmoid", scale=1.0, norm=True):
+    """Routing with no capacity: every token gets its ``k`` experts.
+    Scores over ALL experts in float32 — ``sigmoid(h wg)`` (``noaux_tc``
+    with one group) or ``softmax(h wg)`` — the ``k`` largest of ``scores +
+    bias`` chosen (``bias``: the selection bias, or None; ties to the
+    lower index), weighted by their UNBIASED scores, normalised over the
+    chosen ones if ``norm``, times ``scale``.  h: [N, d] ->
+    (chosen [N, k] int32, weights [N, k] float32)."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"moe_scoring {scoring!r}: 'sigmoid' or 'softmax'")
+    logits = jnp.dot(h.astype(jnp.float32), wg.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    biased = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, chosen = jax.lax.top_k(biased, k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if norm:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), weights * scale
+
+
+def dropless_held_experts(h, chosen, weights, experts, act, first=0,
+                          tile=DROPLESS_TILE):
+    """The held experts' part of a dropless expert layer: ``sum_e w_e
+    GLU_e(h)`` over the (token, expert) pairs whose expert this chip
+    holds — experts ``first .. first + E_held - 1`` of the ids in
+    ``chosen``, along the leading axis of ``experts["w_gate" | "w_up" |
+    "w_down"]``; pairs of the other experts are other chips' terms and
+    are left out.
+
+    A grouped product with no capacity: the pairs are sorted by expert,
+    and each held expert runs over ITS rows in steps of ``tile`` (a loop
+    whose trip count is the expert's load, so an expert nobody chose
+    reads no weights and no token is ever dropped).  h: [N, d]; returns
+    (out [N, d] float32, load [E_held] int32 pairs per held expert)."""
+    N, d = h.shape
+    k = chosen.shape[1]
+    held = experts["w_up"].shape[0]
+    tile = min(tile, N)
+    flat = chosen.reshape(-1) - first
+    mine = (flat >= 0) & (flat < held)
+    order = jnp.argsort(jnp.where(mine, flat, held), stable=True)
+    token = jnp.pad((jnp.arange(N * k, dtype=jnp.int32) // k)[order],
+                    (0, tile))
+    weight = jnp.pad(jnp.where(mine, weights.reshape(-1), 0.0)[order],
+                     (0, tile))
+    load = jnp.sum(flat[:, None] == jnp.arange(held)[None, :], axis=0,
+                   dtype=jnp.int32)
+    begin = jnp.cumsum(load) - load     # an expert's first sorted row
+
+    out = jnp.zeros((N, d), jnp.float32)
+    for e in range(held):
+        def rows(i, out, e=e):
+            at = begin[e] + i * tile
+            idx = jax.lax.dynamic_slice(token, (at,), (tile,))
+            live = i * tile + jnp.arange(tile) < load[e]
+            w = jnp.where(live, jax.lax.dynamic_slice(weight, (at,), (tile,)),
+                          0.0)
+            x = h[idx]
+            # the expert's weights are cut out of the stack INSIDE the
+            # loop: cut outside it they are copied whether it runs or not
+            y = (act(x @ experts["w_gate"][e]) * (x @ experts["w_up"][e])
+                 ) @ experts["w_down"][e]
+            return out.at[idx].add(y.astype(jnp.float32) * w[:, None])
+
+        out = jax.lax.fori_loop(0, -(-load[e] // tile), rows, out)
+    return out, load

@@ -176,6 +176,12 @@ class _OpenSpan:
 OP_PHASES = ("fwd", "bwd", "remat", "loss_head", "optimizer", "grad_reduce",
              "other")
 
+# what a latent-attention / dropless-expert model names inside ``attn`` and
+# ``mlp`` (models/transformer.py): ``phase_of`` gives the innermost of
+# these where an instruction has one, in any program
+SERVE_SCOPES = ("select", "latent_attn", "router", "experts",
+                "shared_expert")
+
 _MODEL_SCOPES = frozenset(("embed", "norm", "attn", "mlp"))
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -190,6 +196,9 @@ def phase_of(op_name):
     name-stack path JAX records: ``jit(step)/fwd/transpose(jvp(..))/
     checkpoint/rematted_computation/mlp/dot_general``)."""
     parts = op_name.split("/")
+    for part in reversed(parts):
+        if part in SERVE_SCOPES:
+            return part
     if "optimizer" in parts:
         return "optimizer"
     if "grad_reduce" in parts:
@@ -273,68 +282,91 @@ def _abstract(x):
 
 class CompiledSite:
     """A jitted entry point under its site name (``engine/train_step:2``,
-    ``serve/step_fn``).  Calls pass straight through; the first one also
-    keeps the shapes it was called with, so that :meth:`op_scopes` can
-    compile the same program again later (under ``mesh``, the context the
-    owner calls it in), outside any timed window, and read its text: with
-    the persistent compile cache on that second ``compile()`` is a cache
-    read."""
+    ``serve/step_fn``).  Calls pass straight through; the first one of
+    each shape (the shapes of its positional arguments that are arrays: a
+    serving program is compiled once a prefill bucket) also keeps what it
+    was called with, so that :meth:`op_scopes` can compile the same
+    program again later (under ``mesh``, the context the owner calls it
+    in), outside any timed window, and read its text: with the persistent
+    compile cache on that second ``compile()`` is a cache read."""
 
     def __init__(self, fn, site, mesh=None):
         self._fn, self.site, self._mesh = fn, site, mesh
-        self._first_call = self._scopes = None
+        self._calls = {}        # shapes of the array arguments -> abstract
+        self._scopes = {}       # the same key -> parsed table
 
     def __call__(self, *args, **kwargs):
-        if self._first_call is None:
-            self._first_call = jax.tree_util.tree_map(_abstract,
-                                                      (args, kwargs))
+        shapes = tuple(getattr(a, "shape", None) for a in args)
+        if shapes not in self._calls:
+            self._calls[shapes] = jax.tree_util.tree_map(_abstract,
+                                                         (args, kwargs))
         return self._fn(*args, **kwargs)
 
     def __getattr__(self, name):      # .lower, .__wrapped__, ...
         return getattr(self._fn, name)
 
-    def compiled_text(self):
-        """Text of the program compiled for the first call's shapes, or
-        None before any call."""
-        if self._first_call is None:
-            return None
-        args, kwargs = self._first_call
+    def _find(self, arg_shapes):
+        """The key of the first call seen (``arg_shapes`` None), or of the
+        call whose positional argument ``i`` had shape ``arg_shapes[i]``
+        for every ``i`` given; None where there is none."""
+        for shapes in self._calls:
+            if arg_shapes is None or all(
+                    i < len(shapes) and shapes[i] == tuple(shape)
+                    for i, shape in arg_shapes.items()):
+                return shapes
+        return None
+
+    def compiled_text(self, arg_shapes=None):
+        """Text of the program compiled for a call's shapes (the first
+        call's without ``arg_shapes``), or None before any such call."""
+        key = self._find(arg_shapes)
+        return None if key is None else self._text(key)
+
+    def _text(self, key):
+        args, kwargs = self._calls[key]
         with (self._mesh if self._mesh is not None
               else contextlib.nullcontext()):
             return self._fn.lower(*args, **kwargs).compile().as_text()
 
-    def op_scopes(self):
-        if self._scopes is None:
-            text = self.compiled_text()
-            if text is None:
-                return {}
-            self._scopes = parse_op_scopes(text)
-        return self._scopes
+    def op_scopes(self, arg_shapes=None):
+        key = self._find(arg_shapes)
+        if key is None:
+            return {}
+        if key not in self._scopes:
+            self._scopes[key] = parse_op_scopes(self._text(key))
+        return self._scopes[key]
 
 
 # site -> CompiledSite, newest wins; weak, so a dropped engine is freed
 _sites = weakref.WeakValueDictionary()
+_registered = itertools.count()
 
 
 def register_compiled(fn, site, mesh=None):
     """Wrap jitted ``fn`` as the program of ``site``; ``mesh`` is the
     context its owner calls it in, if any."""
     wrapped = _sites[site] = CompiledSite(fn, site, mesh)
+    wrapped.order = next(_registered)
     return wrapped
 
 
-def op_scopes(site):
-    """``{instruction name: phase}`` (phase one of :data:`OP_PHASES`) of
-    the program compiled at ``site`` — an exact site name, or a prefix up
-    to a ``:`` (``"engine/train_step"`` finds ``engine/train_step:2``);
-    empty for a site that has not run.  Programs share instruction names
-    (``fusion.3``), so tables are per site.  Parsed from the compiled text
-    once, lazily: call it outside any timed window."""
-    table = {}
-    for name, entry in sorted(_sites.items()):
-        if name == site or name.startswith(site + ":"):
-            table.update(entry.op_scopes())
-    return table
+def op_scopes(site, arg_shapes=None):
+    """``{instruction name: phase}`` (phase one of :data:`OP_PHASES`, or
+    the innermost of :data:`SERVE_SCOPES`) of the program compiled at
+    ``site`` — an exact site name, or a prefix up to a ``:``
+    (``"engine/train_step"`` finds ``engine/train_step:2``; of several
+    live ones, say two trainers' ``:1`` and ``:2``, the one registered
+    last, never a mixture); empty for a site that has not run.  Programs share instruction names
+    (``fusion.3``), so tables are per site, and per shape where a site
+    compiles several: ``arg_shapes`` (``{1: (1, 8192)}``: positional
+    argument 1 had that shape) picks the program of that call, the first
+    call's without it.  Parsed from the compiled text once, lazily: call
+    it outside any timed window."""
+    found = [entry for name, entry in list(_sites.items())
+             if name == site or name.startswith(site + ":")]
+    if not found:
+        return {}
+    return max(found, key=lambda entry: entry.order).op_scopes(arg_shapes)
 
 
 # ----------------------------------------------------------------------

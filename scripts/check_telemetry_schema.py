@@ -193,6 +193,18 @@ SPAN_NAMES = (
     "serve/decode/sample",
 )
 
+# FROZEN: what a serving dispatch of a latent-attention / dropless-expert
+# model counts on the device, in this order — keys of each such dispatch in
+# ``engine.last_step["dispatches"]`` and attrs of its ``serve/step`` span
+# (byte-identical to ``deepspeed_tpu.models.transformer.SERVE_COUNTERS``);
+# and the ``jax.named_scope``s ``telemetry.op_scopes`` tells apart in that
+# model's serving programs (``deepspeed_tpu.monitor.telemetry.
+# SERVE_SCOPES``).  A tier-1 test diffs both.
+SERVE_COUNTERS = ("selected", "context_keys", "expert_pairs",
+                  "expert_load_max")
+SERVE_SCOPES = ("select", "latent_attn", "router", "experts",
+                "shared_expert")
+
 # FROZEN vocabulary of serve-kind event names — must stay byte-identical
 # to ``deepspeed_tpu.inference.robustness.SERVE_EVENTS`` (the tier-1 test
 # diffs the two).  The prefix_* names belong to the prefix-cache subsystem

@@ -430,6 +430,33 @@ def test_train_batch_spans_and_op_scopes(toy_trainer):
     assert op_scopes("engine/no_such_site") == {}
 
 
+def test_a_site_prefix_finds_the_newest_program_never_a_mixture():
+    """Two live owners, one site prefix (two trainers' ``:1`` and ``:2``):
+    the table is the later one's alone."""
+    from deepspeed_tpu.monitor.telemetry import register_compiled
+
+    def older(x):
+        with jax.named_scope("optimizer"):
+            return x * 2.0
+
+    def newer(x):
+        with jax.named_scope("loss_head"):
+            return jnp.sin(x) @ x
+
+    # registered in the other order than the names sort
+    second = register_compiled(jax.jit(older), "toy/site:2")
+    first = register_compiled(jax.jit(newer), "toy/site:1")
+    second(jnp.ones((4, 4)))
+    first(jnp.ones((4, 4)))
+    table = op_scopes("toy/site")
+    assert table and table == op_scopes("toy/site:1")
+    assert "loss_head" in table.values()
+    assert "optimizer" not in table.values()
+    assert "optimizer" in op_scopes("toy/site:2").values()
+    del first
+    assert "optimizer" in op_scopes("toy/site").values()
+
+
 def test_train_step_text_names_the_flash_kernels():
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     q = jnp.ones((1, 128, 2, 64), jnp.float32)
