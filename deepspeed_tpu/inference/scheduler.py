@@ -509,8 +509,16 @@ class ChunkedScheduler(SchedulerBase):
         import functools
         self._draft_call = functools.partial(
             draft_model.apply_with_paged_cache)
+
+        def draft_prefill(params, ids, caches, tables, lengths):
+            # the draft's prompt chunks fill its cache and are never
+            # sampled from: no head
+            return self._draft_call(
+                params, ids, caches, tables, lengths,
+                head_rows=jnp.zeros((ids.shape[0], 0), jnp.int32))
+
         self._draft_step_fn = eng._wrap_compiled(
-            jax.jit(self._draft_call, donate_argnums=(2,)),
+            jax.jit(draft_prefill, donate_argnums=(2,)),
             "serve/spec_draft_fn")
         self._propose_fn = eng._wrap_compiled(
             self._build_propose_fn(), "serve/spec_propose")
@@ -541,12 +549,11 @@ class ChunkedScheduler(SchedulerBase):
         return jax.jit(propose, donate_argnums=(1,))
 
     def _run_draft(self, ids, tables, lengths, phase):
-        out, self.draft_caches, _ = self.engine._dispatch(
+        _, self.draft_caches, _ = self.engine._dispatch(
             self._draft_step_fn,
             (self.draft_params, ids, self.draft_caches, tables, lengths),
             phase, *ids.shape, starts=np.asarray(lengths), backend="draft",
-            config=self.draft_model.config)
-        return out
+            config=self.draft_model.config, head_rows=0)
 
     # -- admission hooks -------------------------------------------------
     def order_queue(self):
@@ -626,9 +633,12 @@ class ChunkedScheduler(SchedulerBase):
                             jnp.asarray(eng.tables[slot:slot + 1]),
                             np.full((1,), start, np.int32))
                 t0 = eng._clock()
+                # only the prompt's last chunk is sampled from: the ones
+                # before it take no head at all (a second program a chunk
+                # shape, for the table's bytes a chunk: docs/serving.md)
+                eng._prefill_next(n, start + n, sample=start + n >= P)
                 logits, eng.caches, _ = eng._run_step(*args,
                                                       phase="prefill")
-                eng._prefill_done(n, start + n)
                 # chunk-active wall time feeds the critical path's
                 # prefill stage; the wait BETWEEN chunks lands in the gap
                 # stage — the split that separates scheduler wins from
@@ -645,7 +655,7 @@ class ChunkedScheduler(SchedulerBase):
                     # the last prompt token's logits seed sampling — same
                     # contract as the monolithic prefill
                     with tel.span("serve/prefill/fetch"):
-                        row = np.asarray(logits[0, n - 1])
+                        row = eng._fetch(logits)[0, 0]
                     with tel.span("serve/prefill/sample"):
                         req.last_token = eng._sample(req, row)
                         eng._note_first_token(slot, req)

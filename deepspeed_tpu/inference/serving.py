@@ -75,6 +75,20 @@ _TERMINAL_BY_STATUS = {"shed": "shed", "drained": "shed",
 STEP_REPORTS_KEPT = 4096
 
 
+def greedy_token(logits: np.ndarray, width: int = 1024) -> int:
+    """``int(np.argmax(logits))`` of one vocabulary row, by the maximum of
+    each block of ``width`` and two short argmaxes: the same index, ties
+    and NaNs included (the first block that holds the maximum, the first
+    place in it).  ``np.argmax`` over a 100k-entry float32 row runs at
+    one of two speeds on the serving host, 40 or 160 us a row, set for a
+    whole run by where the process's buffers happen to lie; a ``maximum``
+    reduction does not, and a decode step of 16 slots waits for 16 picks
+    with the device idle (PERF.md §6, PR 33)."""
+    tops = np.maximum.reduceat(logits, np.arange(0, len(logits), width))
+    start = int(np.argmax(tops)) * width
+    return start + int(np.argmax(logits[start:start + width]))
+
+
 def _round_ms(v):
     return None if v is None else round(v, 3)
 
@@ -353,14 +367,17 @@ class ServingEngine:
         # anyway (_fetch)
         self._counted = bool(getattr(self.config, "counts_serving", False))
         self._counters_pending = None
+        self._prefill_sizes = None
 
         # two named jits over the one call, so a device trace's
         # ``XLA Modules`` line tells prefill (B=1, bucketed T:
         # ``jit_serve_prefill``) from decode (B=max_batch, T=1, and the
         # speculative verify window: ``jit_serve_decode``); each caches a
         # compilation per input shape
-        def serve_prefill(params, ids, caches, tables, lengths, *real):
+        def serve_prefill(params, ids, caches, tables, lengths, rows, *real):
+            # the head on ``rows`` alone: the row its caller samples from
             return self._paged_call(params, ids, caches, tables, lengths,
+                                    head_rows=rows,
                                     **dict(zip(("real_lengths",), real)))
 
         def serve_decode(params, ids, caches, tables, lengths, *real):
@@ -1159,22 +1176,43 @@ class ServingEngine:
         self.tables[slot, :] = 0
         self.tables[slot, :len(pages)] = pages
 
-    def _run_step(self, ids, tables, lengths, phase="decode", real=None):
+    def _prefill_next(self, real: int, context: int, sample: bool = True):
+        """Sizes of the prefill dispatch about to be launched (``_run_step``
+        keeps the signature its wrappers replace, so they come ahead of
+        it): ``real`` prompt tokens under its padded ``tokens``, the
+        ``context`` its keys and values then reach, and whether its caller
+        will ``sample`` from its last real row (a chunk that is not the
+        prompt's last reads nothing, and its program takes no head)."""
+        self._prefill_sizes = {"real": int(real), "context": int(context),
+                               "head_rows": int(bool(sample))}
+
+    def _run_step(self, ids, tables, lengths, phase="decode"):
         """One dispatch of the paged step: the launch only, nothing here
         waits for the device.  ``lengths`` comes as the host's numpy array
         (the call places it): ``kernel_grid`` is reckoned from it.  A
-        model that counts its dispatches is also told how many of each
-        sequence's rows are tokens (``real``: a prefill's prompt under its
-        bucket; without it every row of a slot that holds a context, so
-        none of a decode batch's idle slots)."""
-        step_fn = self._prefill_fn if phase == "prefill" else self._step_fn
+        prefill (sizes from ``_prefill_next``) takes the head on the one
+        row it samples from, or on none, and returns logits [1, 1 | 0, V];
+        every other phase on all its rows.  A model that counts its
+        dispatches is also told how many of each sequence's rows are
+        tokens (a prefill's prompt under its bucket; else every row of a
+        slot that holds a context, so none of a decode batch's idle
+        slots)."""
+        step_fn, sizes = self._step_fn, {}
         args = (self.params, ids, self.caches, tables, lengths)
+        if phase == "prefill":
+            step_fn = self._prefill_fn
+            sizes, self._prefill_sizes = self._prefill_sizes, None
+            # [B, 1 | 0]: the prompt's last row, or no row
+            args += (np.full((ids.shape[0], sizes["head_rows"]),
+                             sizes["real"] - 1, np.int32),)
         if self._counted:
-            if real is None:
-                real = np.where(np.asarray(lengths) > 0, ids.shape[1], 0)
+            real = (np.full(ids.shape[0], sizes["real"]) if sizes else
+                    np.where(np.asarray(lengths) > 0, ids.shape[1], 0))
             args += (np.asarray(real, np.int32),)
-        return self._dispatch(step_fn, args, phase, *ids.shape,
-                              starts=np.asarray(lengths))
+        out = self._dispatch(step_fn, args, phase, *ids.shape,
+                             starts=np.asarray(lengths), **sizes)
+        self._report["prompt_tokens"] += sizes.get("real", 0)
+        return out
 
     def kernel_grid(self, phase, batch, tokens, starts, config=None):
         """(run, full) grid steps of the ragged paged-attention kernel in
@@ -1205,12 +1243,16 @@ class ServingEngine:
                 config.n_layers * calls * tiles.grid_steps)
 
     def _dispatch(self, fn, args, phase, batch, tokens, *, starts,
-                  backend=None, config=None):
+                  backend=None, config=None, head_rows=None, **sizes):
         """Launch jitted ``fn(*args)`` as one ``serve/step`` span and one
         entry of the open report's ``dispatches`` (the target model's
         steps, the chunked decode scan, the draft model's — ``config``
         is the model whose layers the dispatch runs; ``starts`` the
-        tokens each of its sequences holds before it, on the host)."""
+        tokens each of its sequences holds before it, on the host;
+        ``head_rows`` the rows of a sequence the head ran on, all
+        ``tokens`` unless given; ``sizes`` a prefill's ``real`` and
+        ``context``, for the record)."""
+        head_rows = int(tokens if head_rows is None else head_rows)
         t0_ns = time.perf_counter_ns()
         kernel_grid, kernel_grid_full = self.kernel_grid(
             phase, batch, tokens, starts, config)
@@ -1221,7 +1263,8 @@ class ServingEngine:
         attrs = {"backend": backend or self.attention_backend,
                  "phase": phase, "batch": int(batch), "tokens": int(tokens),
                  "kernel_grid": kernel_grid,
-                 "kernel_grid_full": kernel_grid_full}
+                 "kernel_grid_full": kernel_grid_full,
+                 "head_rows": head_rows}
         with self.telemetry.span("serve/step", attrs=attrs), \
                 self._prof_track("prefill" if phase == "prefill"
                                  else "serve_step"), \
@@ -1231,6 +1274,7 @@ class ServingEngine:
         record = {"phase": phase, "batch": int(batch), "tokens": int(tokens),
                   "kernel_grid": kernel_grid,
                   "kernel_grid_full": kernel_grid_full, "kv_write": kv_write,
+                  "head_rows": head_rows, **sizes,
                   "t0_ns": t0_ns, "t1_ns": time.perf_counter_ns()}
         self._report["dispatches"].append(record)
         if self._counted and fn in (self._prefill_fn, self._step_fn):
@@ -1303,11 +1347,8 @@ class ServingEngine:
                         jnp.asarray(self.tables[slot:slot + 1]),
                         np.full((1,), cached, np.int32))
             t0 = self._clock()
-            # a model that counts its dispatches is told the prompt's rows
-            real = {"real": [len(suffix)]} if self._counted else {}
-            logits, self.caches, _ = self._run_step(*args, phase="prefill",
-                                                    **real)
-            self._prefill_done(len(suffix), len(req.prompt))
+            self._prefill_next(len(suffix), len(req.prompt))
+            logits, self.caches, _ = self._run_step(*args, phase="prefill")
             # monolithic prefill is one dispatch: fold its active wall
             # time into the critical path's prefill stage (chunked
             # prefills land here per chunk via the scheduler)
@@ -1315,20 +1356,14 @@ class ServingEngine:
             self.lengths[slot] = len(req.prompt)
             req.prefilled = len(req.prompt)
             with tel.span("serve/prefill/fetch"):
-                row = self._fetch(logits[0, len(suffix) - 1])
+                # [1, 1, V]: the program took the head on this row alone
+                row = self._fetch(logits)[0, 0]
             with tel.span("serve/prefill/sample"):
                 req.last_token = self._sample(req, row)
                 # the first output token exists as of the sample above —
                 # a sampler fault raises before this line, so an
                 # evicted-at-prefill request correctly reports no TTFT
                 self._note_first_token(slot, req)
-
-    def _prefill_done(self, real: int, context: int):
-        """Sizes of the prefill dispatch just launched, into the report:
-        ``real`` prompt tokens of its padded ``tokens``, and the
-        ``context`` its keys and values now reach."""
-        self._report["dispatches"][-1].update(real=real, context=context)
-        self._report["prompt_tokens"] += real
 
     def _note_first_token(self, slot: int, req: _Request):
         """TTFT bookkeeping shared by the monolithic prefill and the
@@ -1354,7 +1389,7 @@ class ServingEngine:
         if self.injector is not None:
             self.injector.check("serve_sample")
         if req.temperature <= 0.0:
-            return int(np.argmax(logits))
+            return greedy_token(logits)
         rng = self._rng.setdefault(req.req_id,
                                    np.random.default_rng(req.seed))
         l = logits.astype(np.float64) / req.temperature

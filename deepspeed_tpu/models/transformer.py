@@ -1394,11 +1394,16 @@ class CausalTransformerLM:
 
     def apply_with_paged_cache(self, params, input_ids, caches, block_tables,
                                lengths, *, attn_backend=None,
-                               attn_interpret=False, real_lengths=None):
+                               attn_interpret=False, real_lengths=None,
+                               head_rows=None):
         """Forward over paged KV caches: appends the T new tokens of every
         sequence at ``lengths`` (tables must already map the pages) and
         attends over each sequence's ragged prefix.  Returns
-        (logits [B, T, V], new caches, lengths + T).
+        (logits [B, T, V], new caches, lengths + T); with ``head_rows``
+        (int32 [B, R]: of each sequence the rows its caller will read, a
+        prefill's one last token, none of a chunk that samples nothing)
+        the final norm and the head run on those rows alone and the logits
+        are [B, R, V].
 
         ``caches``: pytree from ``init_paged_caches``; ``block_tables``:
         [B, max_pages] int32; ``lengths``: [B] int32.  ``attn_backend`` /
@@ -1465,6 +1470,8 @@ class CausalTransformerLM:
                 body, (x, caches),
                 (params["layers"], jnp.arange(c.n_layers)))
 
+        if head_rows is not None:
+            x = jnp.take_along_axis(x, head_rows[:, :, None], axis=1)
         if counts is not None:
             return (self.logits(params, x), caches, lengths + T,
                     counts.vector())

@@ -437,6 +437,24 @@ def diagnose(cell, seed, devices, noise=0.0, dtype=None, mantissa_bits=0):
     return out
 
 
+def _in_a_process_of_one_device(argv):
+    """``--margins --cpu`` from a process that was given several CPU
+    devices (the tests' eight): the same command in a child with one, as
+    the cell's own command runs.  A float32 toy's rows move in the
+    seventh digit with the number of devices the host's threads are
+    shared among, and this reading is held to the harness's own."""
+    import subprocess
+    done = subprocess.run(
+        [sys.executable, os.path.abspath(__file__)] + list(argv),
+        env=dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=""),
+        capture_output=True, text=True, check=True)
+    readings = [json.loads(line) for line in done.stdout.splitlines()
+                if line.startswith("{")]
+    for reading in readings:
+        print(json.dumps(reading), flush=True)
+    return readings
+
+
 def main(argv=None, require_tpu=True):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", default=os.path.join(
@@ -475,6 +493,9 @@ def main(argv=None, require_tpu=True):
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     devices = device.require_devices(1, require_tpu and not args.cpu)
     readings = []
+    if args.margins and args.cpu and len(devices[0].client.devices()) > 1:
+        return _in_a_process_of_one_device(
+            sys.argv[1:] if argv is None else argv)
     if args.reference_only or args.margins:
         for seed in args.seeds:
             readings.append(

@@ -217,6 +217,43 @@ def _pool_shaped(text, layers):
     return found
 
 
+def _olmo2(topo, settings=()):
+    """OLMo-2 1B abstract on the described chip: (the paged call the
+    engine jits, with a prefill's ``head_rows`` as an optional sixth
+    argument; params; caches; ``ints(*shape)``)."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = CausalTransformerLM(TransformerConfig(**{**OLMO2_1B,
+                                                     **dict(settings)}))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _on(chip, x.shape, x.dtype), tree)
+
+    def ints(*shape):
+        return _on(chip, shape, jnp.int32)
+
+    def paged_call(params, ids, caches, tables, lengths, *rows):
+        return model.apply_with_paged_cache(
+            params, ids, caches, tables, lengths, attn_backend="pallas",
+            **dict(zip(("head_rows",), rows)))
+
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.bfloat16)))
+    caches = on_chip(jax.eval_shape(
+        lambda: model.init_paged_caches(POOL[0], POOL[2])))
+    return paged_call, params, caches, ints
+
+
+def _lower_dispatch(olmo2, jit_name, batch, tokens, *head):
+    """One dispatch as the engine jits it (the caches donated); ``head``
+    the shape of a prefill's ``head_rows``, nothing for every row."""
+    paged_call, params, caches, ints = olmo2
+    paged_call.__name__ = jit_name
+    return jax.jit(paged_call, donate_argnums=(2,)).lower(
+        params, ints(batch, tokens), caches, ints(batch, 33), ints(batch),
+        *[ints(*shape) for shape in head])
+
+
 @pytest.mark.parametrize("name,jit_name,batch,tokens,settings", DISPATCHES,
                          ids=[c[0] for c in DISPATCHES])
 def test_serving_dispatch_never_copies_the_page_pools(
@@ -231,37 +268,21 @@ def test_serving_dispatch_never_copies_the_page_pools(
     import types
 
     from deepspeed_tpu.inference.scheduler import SchedulerBase
-    chip = SingleDeviceSharding(topo.devices[0])
-    model = CausalTransformerLM(TransformerConfig(**{**OLMO2_1B,
-                                                     **settings}))
-    layers = model.config.n_layers
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda x: _on(chip, x.shape, x.dtype), tree)
-
-    def ints(*shape):
-        return _on(chip, shape, jnp.int32)
-
-    params = on_chip(jax.eval_shape(
-        lambda: model.init(jax.random.key(0), jnp.bfloat16)))
-    caches = on_chip(jax.eval_shape(
-        lambda: model.init_paged_caches(POOL[0], POOL[2])))
-    paged_call = lambda *a: model.apply_with_paged_cache(   # noqa: E731
-        *a, attn_backend="pallas")
+    olmo2 = paged_call, params, caches, ints = _olmo2(topo, settings)
+    layers = caches.k_pages.shape[0]
     if jit_name == "chunk":
         # the scheduler's own K-token scan, the pools in ITS carry
         sched = types.SimpleNamespace(engine=types.SimpleNamespace(
             decode_chunk=tokens, _paged_call=paged_call))
+        floats = _on(ints(batch).sharding, (batch,), jnp.float32)
         lowered = SchedulerBase._build_chunk_fn(sched, False).lower(
             params, caches, ints(batch, 33), ints(batch), ints(batch),
-            _on(chip, (batch,), jnp.float32), ints(batch), ints(batch),
-            ints(batch), _on(chip, (batch,), jnp.float32))
+            floats, ints(batch), ints(batch), ints(batch), floats)
+    elif jit_name == "serve_prefill":
+        # as the engine dispatches it: the head on the one row it samples
+        lowered = _lower_dispatch(olmo2, jit_name, batch, tokens, (batch, 1))
     else:
-        paged_call.__name__ = jit_name
-        lowered = jax.jit(paged_call, donate_argnums=(2,)).lower(
-            params, ints(batch, tokens), caches, ints(batch, 33),
-            ints(batch))
+        lowered = _lower_dispatch(olmo2, jit_name, batch, tokens)
     compiled = lowered.compile()
     text = compiled.as_text()
     allowed = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
@@ -276,6 +297,72 @@ def test_serving_dispatch_never_copies_the_page_pools(
     if tokens == 1 or jit_name == "chunk":
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < ONE_LAYER_POOL_BYTES, temp
+
+
+BUCKET_LOGITS = "f32[1,4096,100352]"      # 1.64 GB: every row of a bucket
+
+
+def _text_and_bytes(compiled):
+    """The compiled text, and the bytes the dispatch allocates beside its
+    arguments: temporaries and results, less the donated pools (the
+    compiler lays temporaries into the logits' block where there is one,
+    so neither number alone says what the head's rows cost)."""
+    memory = compiled.memory_analysis()
+    return compiled.as_text(), (memory.temp_size_in_bytes
+                                + memory.output_size_in_bytes
+                                - memory.alias_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def all_rows_prefill(topo):
+    """The 4,096 bucket asked for every row, as every prefill was until
+    PR 33."""
+    return _text_and_bytes(_lower_dispatch(_olmo2(topo), "serve_prefill", 1,
+                                           4096).compile())
+
+
+@pytest.mark.parametrize("rows", [1, 0], ids=["sampled_row", "no_head"])
+def test_prefill_holds_no_logits_of_its_bucket(topo, all_rows_prefill, rows):
+    """A prefill takes the head on the row it samples from (an
+    intermediate chunk on none): the 4,096 bucket's program has no float32
+    logits of the whole bucket anywhere, and allocates about that block
+    less than the all-rows call (1.58 GB: the block, less the 67 MB of
+    temporaries that lay in it)."""
+    text, held = _text_and_bytes(_lower_dispatch(
+        _olmo2(topo), "serve_prefill", 1, 4096, (1, rows)).compile())
+    full_text, full_held = all_rows_prefill
+    assert BUCKET_LOGITS in full_text
+    assert BUCKET_LOGITS not in text
+    assert f"f32[1,{rows},100352]" in text
+    assert full_held - held > 1.5e9, (full_held, held)
+
+
+def test_decode_still_returns_every_row(topo):
+    """The decode program (B = 32, T = 1) is not asked for rows: its
+    result is the logits of all 32 slots, as the sampler reads them."""
+    text = _lower_dispatch(_olmo2(topo), "serve_decode", 32,
+                           1).compile().as_text()
+    result = next(line for line in text.splitlines()
+                  if line.startswith("ENTRY"))
+    assert "f32[32,1,100352]" in result, result
+
+
+def test_one_program_a_bucket_whatever_row_is_sampled():
+    """The row a prefill samples from is an argument of its program, not
+    a shape: prompts of 9 to 16 tokens run ONE compiled
+    ``jit_serve_prefill`` (a bucket of 16), and the decode program, which
+    is asked for no rows, stays one beside them."""
+    from deepspeed_tpu.inference.serving import ServingEngine
+    model = CausalTransformerLM(TransformerConfig.tiny(
+        hidden_size=64, n_heads=4, n_kv_heads=2))
+    eng = ServingEngine(model, model.init(jax.random.key(0)), max_batch=4,
+                        page_size=8, max_seq=128, dtype=jnp.float32)
+    for i, n in enumerate((9, 12, 16)):
+        eng.add_request(i, list(range(1, n + 1)), max_new_tokens=2)
+    while eng.queue or eng.n_active:
+        eng.step()
+    assert eng._prefill_fn._cache_size() == 1
+    assert eng._step_fn._cache_size() == 1
 
 
 def test_decode_is_one_program_whatever_the_lengths():

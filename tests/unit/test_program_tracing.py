@@ -479,7 +479,8 @@ def test_serve_programs_name_their_jits_and_kernels(tiny):
     ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)   # noqa
     width = eng.tables.shape[1]
     prefill = eng._prefill_fn.lower(
-        eng.params, ints(1, 16), eng.caches, ints(1, width), ints(1))
+        eng.params, ints(1, 16), eng.caches, ints(1, width), ints(1),
+        ints(1, 1))
     decode = eng._step_fn.lower(
         eng.params, ints(2, 1), eng.caches, ints(2, width), ints(2))
     assert "jit_serve_prefill" in prefill.as_text()
