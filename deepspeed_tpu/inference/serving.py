@@ -56,7 +56,8 @@ from deepspeed_tpu.monitor.telemetry import (get_telemetry,
 from deepspeed_tpu.ops.paged_attention import (PageAllocationError,
                                                PagedAllocator,
                                                resolve_attention_backend,
-                                               resolve_paged_impl)
+                                               resolve_paged_impl,
+                                               ring_pages)
 from deepspeed_tpu.ops.pallas.ragged_paged_attention import (
     pick_tiles, rect_grid_steps)
 from deepspeed_tpu.runtime.resilience import FaultInjector
@@ -73,6 +74,14 @@ _TERMINAL_BY_STATUS = {"shed": "shed", "drained": "shed",
 # per-step reports ``ServingEngine.step_reports()`` keeps: ten minutes of
 # 140 ms steps
 STEP_REPORTS_KEPT = 4096
+
+
+# what a model with sliding-window layers adds to each prefill and decode
+# dispatch of ``last_step`` and to its ``serve/step`` span
+# (``ServingEngine._window_counts``; frozen in
+# scripts/check_telemetry_schema.py)
+WINDOW_COUNTS = ("context_keys", "attended_keys", "pages_full",
+                 "pages_ring")
 
 
 def greedy_token(logits: np.ndarray, width: int = 1024) -> int:
@@ -242,7 +251,15 @@ class ServingEngine:
         else:
             self.serving = ServingRobustnessConfig(serving or {})
         self._refuse_unsupported(tp_size, ep_size)
-        caches = model.init_paged_caches(num_pages, page_size, dtype=dtype)
+        # a model with sliding-window layers keeps their keys and values
+        # in a ring of pages a slot, beside the growing tables of its
+        # full-attention layers (ops/paged_attention.py): each row of
+        # ``tables`` ends in the slot's ring
+        window = int(getattr(self.config, "attn_window", 0) or 0)
+        self.ring_pages = ring_pages(window, page_size) if window else 0
+        caches = model.init_paged_caches(
+            num_pages, page_size, dtype=dtype,
+            **({"ring_slots": max_batch} if window else {}))
         if ep_size > 1:
             assert getattr(self.config, "is_moe", False), \
                 "ep_size > 1 needs an MoE model"
@@ -280,7 +297,9 @@ class ServingEngine:
         self.alloc = PagedAllocator(num_pages, page_size,
                                     self.max_pages_per_seq,
                                     reserve_scratch=True,
-                                    injector=injector)
+                                    injector=injector,
+                                    ring_pages=self.ring_pages,
+                                    ring_slots=max_batch)
         # content-hashed KV-page reuse (inference/prefix_cache.py): the
         # namespace pins cached pages to this model shape / cache dtype /
         # page size, so a differently-configured engine can never attach
@@ -337,8 +356,9 @@ class ServingEngine:
         # reservation — this column catches it ON SCRATCH by construction
         # instead of relying on OOB-gather clamping (which would overwrite
         # the request's own last real page)
-        self.tables = np.zeros((max_batch, self.max_pages_per_seq + 1),
-                               np.int32)
+        self.tables = np.zeros(
+            (max_batch, self.max_pages_per_seq + 1 + self.ring_pages),
+            np.int32)
         # attention backend: "auto" (Pallas kernel on TPU, jnp elsewhere),
         # "jnp" (gather oracle), "pallas", or "pallas-interpret" (the exact
         # kernel path through the interpreter — CPU CI).  Bound as static
@@ -456,8 +476,11 @@ class ServingEngine:
                                   self.tracer.snapshot_open)
 
     def _refuse_unsupported(self, tp_size, ep_size):
-        """What a latent-attention model cannot be served with until
-        someone builds it, refused by name when the engine is made."""
+        """What a latent-attention model, or one with sliding-window
+        layers, cannot be served with until someone builds it, refused by
+        name when the engine is made."""
+        if getattr(self.config, "attn_window", 0):
+            self._refuse_for_ring(tp_size, ep_size)
         if not getattr(self.config, "is_latent", False):
             return
         if getattr(self.serving.prefix_cache, "enabled", False):
@@ -477,6 +500,53 @@ class ServingEngine:
                 "tp_size / ep_size > 1 with latent attention",
                 "the latent pools have no head axis to shard, and the "
                 "expert layer holds a fixed share (moe_experts_held)")
+
+    def _refuse_for_ring(self, tp_size, ep_size):
+        """A window layer's ring holds the last ``window`` keys of ONE
+        sequence and a prefill fills it from an empty context: whatever
+        shares pages between sequences, or brings more than a few rows to
+        a context already there, is not built."""
+        if getattr(self.serving.prefix_cache, "enabled", False):
+            raise ServingUnsupported(
+                "prefix_cache with sliding-window layers",
+                "a ring page holds one sequence's newest keys and is "
+                "overwritten as it decodes: it cannot be shared, and a "
+                "prefill onto cached full-attention pages would find the "
+                "ring empty")
+        sched = self.serving.scheduler
+        if getattr(sched, "policy", "monolithic") != "monolithic":
+            raise ServingUnsupported(
+                f"scheduler.policy {sched.policy!r} with sliding-window "
+                "layers",
+                "a prefill chunk or a speculative verify window onto a "
+                "context already in the ring may bring no more rows than "
+                "the ring's slack (ring x page - window + 1); "
+                "only whole-prompt prefills (monolithic) and T=1 decode "
+                "steps are built")
+        if tp_size > 1 or ep_size > 1:
+            raise ServingUnsupported(
+                "tp_size / ep_size > 1 with sliding-window layers",
+                "the scanned periods' stacked weights have no sharding "
+                "rules yet")
+
+    def _refuse_migration(self, what):
+        """KV-page migration moves the pages of the growing tables; a
+        window model's rings would stay behind."""
+        if self.ring_pages:
+            raise ServingUnsupported(
+                f"{what} with sliding-window layers",
+                "a handed-off or imported request would need its ring's "
+                "pages moved and re-seated in the receiving slot's ring")
+
+    def _seat(self, slot: int, req_id):
+        """Point ``slot``'s row of the tables at the request's pages (and
+        its ring, in the row's last columns)."""
+        pages = self.alloc.seq_pages[req_id]
+        self.tables[slot, :] = 0
+        self.tables[slot, :len(pages)] = pages
+        if self.ring_pages:
+            self.tables[slot, -self.ring_pages:] = \
+                self.alloc.seq_rings[req_id]
 
     # -- telemetry -------------------------------------------------------
     @property
@@ -622,6 +692,8 @@ class ServingEngine:
         so its trace's queue wait and TTFT count from arrival, not from
         this call."""
         cfg = self.serving
+        if prefill_only:
+            self._refuse_migration("prefill_only requests")
         if self.draining:
             self._reject(req_id, REJECT_DRAINING,
                          "engine is draining; admission stopped")
@@ -836,7 +908,8 @@ class ServingEngine:
             pinned = set(shared) | set(protect)
             evictable = sum(1 for p in self.alloc.reclaimable
                             if p not in pinned)
-            if need_fresh > self.alloc.free_page_count + evictable:
+            if need_fresh > self.alloc.free_page_count + evictable \
+                    or not self.alloc.ring_available():
                 return          # head-of-line: keep FIFO order
             # full reservation (prompt + budget) at admission: an admitted
             # request can NEVER deadlock on pages mid-flight (no vLLM-style
@@ -860,8 +933,7 @@ class ServingEngine:
                                   tokens_reused=cached,
                                   cow=int(match.cow_src is not None))
             self.queue.pop(0)
-            self.tables[slot, :] = 0
-            self.tables[slot, :len(pages)] = pages
+            self._seat(slot, req.req_id)
             self.lengths[slot] = 0
             self.slots[slot] = req
             tr = self.tracer.prefill_start(req.req_id, slot)
@@ -998,6 +1070,7 @@ class ServingEngine:
         every cache leaf) into a standalone payload pytree shaped like
         the cache with P = pow2-padded ``len(page_ids)`` — the migration
         wire format.  Pure read, no donation."""
+        self._refuse_migration("export_pages")
         padded = self._pad_pow2(page_ids)
         if self._gather_pages_fn is None:
             def gather(caches, ids):
@@ -1019,6 +1092,7 @@ class ServingEngine:
         (the source replica's ``comm_quant`` wire codec) are
         self-describing and dequantize here — the destination needs no
         matching config."""
+        self._refuse_migration("import_pages")
         payload = CommQuantizer.decode_payload(payload)
         leaves = jax.tree_util.tree_leaves(payload)
         padded = np.zeros(leaves[0].shape[1], np.int32)
@@ -1053,6 +1127,7 @@ class ServingEngine:
         fault site is all-or-nothing.  Returns True when installed, False
         when this engine cannot take it right now (draining, no free
         slot, page pressure, id collision)."""
+        self._refuse_migration("import_request")
         if self.draining:
             return False
         slot = next((s for s in range(self.max_batch)
@@ -1091,8 +1166,7 @@ class ServingEngine:
             rng = np.random.default_rng(handoff.seed)
             rng.bit_generator.state = handoff.rng_state
             self._rng[rid] = rng
-        self.tables[slot, :] = 0
-        self.tables[slot, :len(pages)] = pages
+        self._seat(slot, rid)
         self.lengths[slot] = len(handoff.prompt)
         self.slots[slot] = req
         self._pending_imports[rid] = (slot, handoff, len(shared))
@@ -1173,8 +1247,7 @@ class ServingEngine:
             f"request {req.req_id!r}: {len(pages)} pages held after trim, "
             f"expected {expected} for {total} tokens "
             f"(page_size {self.page_size})")
-        self.tables[slot, :] = 0
-        self.tables[slot, :len(pages)] = pages
+        self._seat(slot, req.req_id)
 
     def _prefill_next(self, real: int, context: int, sample: bool = True):
         """Sizes of the prefill dispatch about to be launched (``_run_step``
@@ -1222,25 +1295,44 @@ class ServingEngine:
         reckons them, and the rectangle of the tile picker's choice for
         this compiled shape they are drawn from.  Both 0 on the jnp path.
         ``decode_chunk`` and ``spec_draft`` run ``tokens`` T=1 forwards,
-        each one token further."""
+        each one token further.  A window layer's call reads its ring (a
+        decode step) or the prefill's own rows as pages, from the first
+        key inside the window."""
         if self.attention_impl != "pallas":
             return 0, 0
         config = config or self.config
         calls, T = ((int(tokens), 1) if phase in ("decode_chunk",
                                                   "spec_draft")
                     else (1, int(tokens)))
-        key = (int(batch), T, id(config))
-        if key not in self._kernel_tiles:
-            self._kernel_tiles[key] = pick_tiles(
-                [T] * int(batch), config.n_heads // config.kv_heads,
-                config.kv_heads, self.page_size, config.head_dim,
-                self.tables.shape[1], jnp.dtype(self.cache_dtype).itemsize)
-        tiles = self._kernel_tiles[key]
+        batch = int(batch)
+        windows = [w for w in getattr(config, "local_attn_pattern", None)
+                   or () if w]
+        ring = self.ring_pages if windows else 0
         ctx = np.asarray(starts)[None, :] \
             + T * np.arange(1, calls + 1)[:, None]
-        run = rect_grid_steps(tiles, int(batch), T, ctx, self.page_size)
-        return (config.n_layers * run,
-                config.n_layers * calls * tiles.grid_steps)
+
+        def steps(n_layers, ctx, width, window=None, ring=None):
+            key = (batch, T, id(config), width, window)
+            if key not in self._kernel_tiles:
+                self._kernel_tiles[key] = pick_tiles(
+                    [T] * batch, config.n_heads // config.kv_heads,
+                    config.kv_heads, self.page_size, config.head_dim, width,
+                    jnp.dtype(self.cache_dtype).itemsize, window=window,
+                    ring=ring)
+            tiles = self._kernel_tiles[key]
+            return np.array([
+                n_layers * rect_grid_steps(tiles, batch, T, ctx,
+                                           self.page_size, window),
+                n_layers * calls * tiles.grid_steps])
+
+        run = steps(config.n_layers - len(windows), ctx,
+                    self.tables.shape[1] - ring)
+        for window in sorted(set(windows)):
+            n = windows.count(window)
+            run = run + (steps(n, ctx, ring, window, ring) if T == 1 else
+                         steps(n, np.full_like(ctx, T),
+                               -(-T // self.page_size), window))
+        return int(run[0]), int(run[1])
 
     def _dispatch(self, fn, args, phase, batch, tokens, *, starts,
                   backend=None, config=None, head_rows=None, **sizes):
@@ -1265,6 +1357,10 @@ class ServingEngine:
                  "kernel_grid": kernel_grid,
                  "kernel_grid_full": kernel_grid_full,
                  "head_rows": head_rows}
+        counted = {}
+        if self.ring_pages and config is None:
+            counted = self._window_counts(phase, tokens, starts, sizes)
+            attrs.update(counted)
         with self.telemetry.span("serve/step", attrs=attrs), \
                 self._prof_track("prefill" if phase == "prefill"
                                  else "serve_step"), \
@@ -1274,7 +1370,7 @@ class ServingEngine:
         record = {"phase": phase, "batch": int(batch), "tokens": int(tokens),
                   "kernel_grid": kernel_grid,
                   "kernel_grid_full": kernel_grid_full, "kv_write": kv_write,
-                  "head_rows": head_rows, **sizes,
+                  "head_rows": head_rows, **sizes, **counted,
                   "t0_ns": t0_ns, "t1_ns": time.perf_counter_ns()}
         self._report["dispatches"].append(record)
         if self._counted and fn in (self._prefill_fn, self._step_fn):
@@ -1283,6 +1379,38 @@ class ServingEngine:
             self._counters_pending = (out[3], record, attrs)
             out = out[:3]
         return out
+
+    def _window_counts(self, phase, tokens, starts, sizes):
+        """A window model's account of one dispatch, on the host (all of
+        it follows from the lengths): ``context_keys``, the causal keys of
+        every real query summed over the layers, and ``attended_keys``,
+        those inside the layer's window; the pages in use of both kinds
+        (``pages_full`` of the growing tables, ``pages_ring``)."""
+        starts = np.asarray(starts, np.int64)
+        if phase == "prefill":      # positions starts .. starts + real - 1
+            rows = np.full_like(starts, sizes["real"])
+        else:       # one row a step a live slot, ``tokens`` steps
+            steps = int(tokens) if phase in ("decode_chunk",
+                                             "spec_draft") else 1
+            rows = np.where(starts > 0, steps, 0)
+        ends = starts + rows
+
+        def keys(window):
+            """Keys the queries at contexts starts + 1 .. ends meet under
+            ``window``: sum of min(context, window)."""
+            def upto(n):
+                m = np.minimum(n, window)
+                return m * (m + 1) // 2 + (n - m) * window
+            return int((upto(ends) - upto(starts)).sum())
+
+        pattern = self.config.local_attn_pattern
+        causal = keys(int(ends.max(initial=0)) + 1)     # no window at all
+        return dict(zip(WINDOW_COUNTS, (
+            len(pattern) * causal,
+            sum(pattern.count(w) * (keys(w) if w else causal)
+                for w in set(pattern)),
+            self.alloc.num_pages - 1 - self.alloc.available_page_count,
+            self.alloc.ring_pages_in_use)))
 
     def _fetch(self, logits):
         """The logits a step samples from, to the host.  A counted
@@ -1296,6 +1424,10 @@ class ServingEngine:
         counters, record, attrs = pending
         logits, counters = jax.device_get((logits, counters))
         values = dict(zip(SERVE_COUNTERS, (int(v) for v in counters)))
+        if self.ring_pages:
+            # a window model's keys are counted on the host (_dispatch)
+            values = {k: v for k, v in values.items()
+                      if k not in record and k != "selected"}
         record.update(values)
         attrs.update(values)
         return logits
@@ -1675,11 +1807,23 @@ class ServingEngine:
         # one allocator addresses every pool (K and V; a latent model's
         # entries and its indexer keys, of unlike widths): each leaf must
         # have the allocator's pages on its page axis
+        # (a window model's ring stack: the allocator's rings and their
+        # scratch page)
+        ring_leaves = [id(leaf) for leaf in jax.tree_util.tree_leaves(
+            getattr(self.caches, "ring", ()))]
         pools = {i: tuple(leaf.shape) for i, leaf in enumerate(
             jax.tree_util.tree_leaves(self.caches))
-            if leaf.shape[1] != self.alloc.num_pages}
+            if leaf.shape[1] != (self.alloc.ring_pool + 1
+                                 if id(leaf) in ring_leaves
+                                 else self.alloc.num_pages)}
         if pools:
             leaks["pool_page_mismatch"] = pools
+        unseated = [s for s, req in enumerate(self.slots)
+                    if req is not None and self.ring_pages and list(
+                        self.tables[s, -self.ring_pages:])
+                    != self.alloc.seq_rings.get(req.req_id)]
+        if unseated:
+            leaks["ring_not_seated"] = unseated
         if self.prefix_cache is not None:
             leaks.update(self.prefix_cache.audit())
         dirty = [s for s in range(self.max_batch)

@@ -20,6 +20,7 @@ decoders).  TPU-first design:
 * logits/loss in fp32 (matching the reference's fused softmax numerics).
 """
 
+import contextlib
 import functools
 import math
 from dataclasses import dataclass, field, replace
@@ -72,6 +73,21 @@ class TransformerConfig:
     #                                         uses 1.0 instead of 1/sqrt(dh))
     local_attn_pattern: Optional[Tuple[int, ...]] = None  # per-layer sliding
     #                window (0 = global); GPT-Neo alternates (0, 256, 0, ...)
+    # The rest of the layer pattern, as data.  ``rope_pattern``: per layer,
+    # whether q and k turn (None: every layer, under ``use_rope``; a
+    # full-attention layer of a window model may carry no positions).
+    # ``layer_period`` > 0: the patterns above repeat with that period
+    # after the leading layers (the whole periods that hold the
+    # ``first_dense_layers``); params keep those leading layers as a list
+    # and the others STACKED by position in the period
+    # (``params["periods"][j]``: leaves [n_periods, ...]), and the serving
+    # forward scans over the periods instead of unrolling every layer
+    rope_pattern: Optional[Tuple[bool, ...]] = None
+    layer_period: int = 0
+    attn_gate: bool = False                 # attention output x sigmoid(h
+    #   wg_attn) ahead of ``wo`` (afmoe's gated attention)
+    sandwich_norm: bool = False             # pre-norms AND post-norms on
+    #   both sub-blocks (Gemma-2 / afmoe); ``init`` makes all four
     residual_scale: Optional[float] = None  # x + scale*delta on every
     #   sub-block residual add (Granite residual_multiplier)
     post_norm_only: bool = False            # OLMo2: no pre-norms; blocks
@@ -128,6 +144,7 @@ class TransformerConfig:
     moe_experts_held: Optional[int] = None
     moe_experts_first: int = 0
     moe_routed_scale: float = 1.0       # routed_scaling_factor
+    moe_route_norm_eps: float = 0.0     # added to the chosen scores' sum
     moe_ffn_hidden_size: Optional[int] = None   # expert width; None → ffn
     moe_shared_experts: int = 0         # ungated always-on experts
     first_dense_layers: int = 0         # leading layers with a dense FFN
@@ -171,8 +188,32 @@ class TransformerConfig:
 
     @property
     def counts_serving(self):
-        """A serving dispatch of this model returns ``SERVE_COUNTERS``."""
-        return self.is_latent or (self.is_moe and self.moe_dropless)
+        """A serving dispatch of this model returns ``SERVE_COUNTERS``
+        (and is told which of its rows are tokens)."""
+        return self.is_latent or (self.is_moe and self.moe_dropless) \
+            or self.attn_window > 0
+
+    @property
+    def attn_window(self):
+        """The sliding window of the model's window layers (the widest, if
+        they differ); 0 without one."""
+        return max(self.local_attn_pattern or (0,))
+
+    @property
+    def leading_layers(self):
+        """Layers ahead of the scanned periods: the whole periods that
+        hold the leading dense layers; all of them without a period."""
+        if not self.layer_period:
+            return self.n_layers
+        return -(-self.first_dense_layers // self.layer_period) \
+            * self.layer_period
+
+    def layer_window(self, i):
+        return self.local_attn_pattern[i] if self.local_attn_pattern else 0
+
+    def layer_rotary(self, i):
+        return self.use_rope and (self.rope_pattern is None
+                                  or bool(self.rope_pattern[i]))
 
     @property
     def layers_listed(self):
@@ -319,6 +360,26 @@ class ServeCounts:
     def vector(self):
         return jnp.stack([jnp.asarray(self.counts.get(name, 0), jnp.int32)
                           for name in SERVE_COUNTERS])
+
+    def absorb(self, vectors):
+        """Add what the iterations of a scan counted: ``vectors``
+        [n, len(SERVE_COUNTERS)], each one iteration's :meth:`vector`."""
+        for name, column in zip(SERVE_COUNTERS, vectors.T):
+            self.add(**{name: jnp.max(column) if name == "expert_load_max"
+                        else jnp.sum(column)})
+
+
+def _hold_expert_stack(stacked_layer):
+    """A stacked layer [n, ...] as (what a scan over it may cut a layer
+    out of, its dropless experts' leaves, kept whole): the held experts'
+    weights are most of a layer, and the expert loops index a stack in
+    place (``dropless_held_experts(layer=)``)."""
+    moe = stacked_layer.get("moe")
+    if not moe:
+        return stacked_layer, {}
+    held = {k: moe[k] for k in ("w_gate", "w_up", "w_down")}
+    rest = {k: v for k, v in moe.items() if k not in held}
+    return dict(stacked_layer, moe=rest), held
 
 
 # "gelu" is the tanh approximation (GPT-2 gelu_new / Gemma
@@ -518,6 +579,25 @@ class CausalTransformerLM:
     def __init__(self, config: TransformerConfig):
         self.config = config
         self.gate = None
+        c = config
+        for name in ("local_attn_pattern", "rope_pattern"):
+            pattern = getattr(c, name)
+            assert pattern is None or len(pattern) == c.n_layers, \
+                f"{name} has {len(pattern)} entries for {c.n_layers} layers"
+        if c.rope_pattern is not None or c.layer_period:
+            assert c.layers_listed, \
+                "rope_pattern / layer_period need a listed (MoE) layer stack"
+        if c.layer_period:
+            lead, period = c.leading_layers, c.layer_period
+            assert (c.n_layers - lead) % period == 0 and \
+                c.moe_layer_freq == 1, (
+                    f"{c.n_layers} layers do not make whole periods of "
+                    f"{period} after the {lead} leading ones")
+            for i in range(lead, c.n_layers):
+                at = lead + (i - lead) % period
+                assert (c.layer_window(i), c.layer_rotary(i)) == \
+                    (c.layer_window(at), c.layer_rotary(at)), \
+                    f"layer {i} does not repeat layer {at}'s pattern"
         if config.is_moe and not config.moe_dropless:
             from deepspeed_tpu.moe.sharded_moe import TopKGate
             self.gate = TopKGate(
@@ -626,18 +706,22 @@ class CausalTransformerLM:
             ks = jax.random.split(key, 8)
             norm_keys = (("attn_post_norm", "mlp_post_norm")
                          if c.post_norm_only else ("attn_norm", "mlp_norm"))
+            if c.sandwich_norm:
+                norm_keys += ("attn_post_norm", "mlp_post_norm")
             if c.is_latent:
                 layer = self._init_latent_attn(ks[0], dtype, dense)
                 layer.update({k: jnp.ones((d,), dtype) for k in norm_keys})
             else:
                 layer = {
-                    norm_keys[0]: jnp.ones((d,), dtype),
                     "wq": dense(ks[0], (d, H * dh), d),
                     "wk": dense(ks[1], (d, Hkv * dh), d),
                     "wv": dense(ks[2], (d, Hkv * dh), d),
                     "wo": dense(ks[3], (H * dh, d), H * dh),
-                    norm_keys[1]: jnp.ones((d,), dtype),
                 }
+                layer.update({k: jnp.ones((d,), dtype) for k in norm_keys})
+                if c.attn_gate:
+                    layer["wg_attn"] = dense(jax.random.fold_in(ks[0], 1),
+                                             (d, H * dh), d)
             if c.qk_norm:
                 qd, kd = ((H * dh, Hkv * dh) if c.qk_norm == "rms_flat"
                           else (dh, dh))
@@ -682,12 +766,22 @@ class CausalTransformerLM:
                     layer["w_gate"] = dense(ks[7], (d, f), d)
             return layer
 
+        lead, period = c.leading_layers, c.layer_period
         params = {
             "tok_embed": dense(keys[-1], (v, d), embed_fan),
             "final_norm": jnp.ones((d,), dtype),
             "layers": [one_layer(keys[i], self._is_moe_layer(i))
-                       for i in range(c.n_layers)],
+                       for i in range(lead)],
         }
+        if period:
+            # the layers of one position in the period, made stacked (each
+            # from the key the list layout would give it): a stack of
+            # finished layers would hold the weights twice
+            params["periods"] = [
+                jax.vmap(functools.partial(
+                    one_layer, moe=self._is_moe_layer(lead + j)))(
+                        keys[lead + j:c.n_layers:period])
+                for j in range(period)]
         if not c.use_rope:
             params["pos_embed"] = dense(keys[-2], (c.max_seq_len, d), d)
         if not c.tie_embeddings:
@@ -805,7 +899,7 @@ class CausalTransformerLM:
             return query, key, Indexer(turn(q_i), h @ layer["idx_w"],
                                        turn(k_i))
 
-    def _qkv(self, h, layer, B, S, positions):
+    def _qkv(self, h, layer, B, S, positions, rotary=True):
         c = self.config
         if c.is_latent:
             return self._latent_qkv(h, layer, positions)
@@ -834,7 +928,7 @@ class CausalTransformerLM:
                       layer.get("q_norm_b"))
             k = _norm(k, layer["k_norm"], c.norm_eps, rms,
                       layer.get("k_norm_b"))
-        if c.use_rope:
+        if c.use_rope and rotary:
             q = _rope(q, positions, c.rope_theta, c.rope_dim,
                       inv_freq=c.rope_inv_freq)
             k = _rope(k, positions, c.rope_theta, c.rope_dim,
@@ -940,22 +1034,78 @@ class CausalTransformerLM:
         """Write this layer's rows into the STACKED page pools at
         ``lengths`` and attend over each sequence's ragged prefix, both in
         place by (traced) layer ``index`` (``apply_with_paged_cache``
-        binds the keywords; ``items`` is what every layer's read shares)."""
+        binds the keywords; ``items`` is what every layer's read shares).
+        A full-attention layer of a window model reads and writes the
+        same way, on that model's ``full`` stack."""
         from deepspeed_tpu.ops.paged_attention import (paged_decode_attention,
                                                        write_paged)
         c = self.config
-        pools = write_paged(pools, index, block_tables, lengths, k, v,
-                            impl=impl, interpret=interpret)
-        # NOTE: ALiBi / local-window models are not yet served paged
-        # (their additive bias needs per-batch ragged positions the
-        # paged kernels don't take); init_paged_caches guards this
-        attn = paged_decode_attention(q, pools, block_tables,
-                                      lengths + q.shape[1],
-                                      softmax_scale=c.attn_scale,
-                                      impl=impl, interpret=interpret,
-                                      logit_softcap=c.attn_logit_softcap,
-                                      layer=index, items=items)
+        with (jax.named_scope("attn_full") if c.attn_window
+              else contextlib.nullcontext()):
+            pools = write_paged(pools, index, block_tables, lengths, k, v,
+                                impl=impl, interpret=interpret)
+            attn = paged_decode_attention(
+                q, pools, block_tables, lengths + q.shape[1],
+                softmax_scale=c.attn_scale, impl=impl, interpret=interpret,
+                logit_softcap=c.attn_logit_softcap, layer=index, items=items)
         return attn, pools
+
+    @jax.named_scope("attn_window")
+    def mix_ring(self, q, k, v, layer, pool, *, index, window, ring_tables,
+                 lengths, real_lengths, impl, interpret, items):
+        """A sliding-window layer on the serving path: its keys and values
+        live in a RING of pages a sequence (``ring_tables`` [B, ring]),
+        layer ``index`` of the window layers' stack ``pool``.
+
+        A decode step (T = 1) writes its row into the ring, over the
+        oldest page's row of a ring ago, and attends through the ring, the
+        mask by logical position.  T > 1 is a prefill FROM AN EMPTY CONTEXT
+        (``lengths`` 0: ``ServingEngine`` refuses what would break that),
+        which may be many times the ring: it attends over the rows it
+        brings, laid out as pages of their own and read by the same kernel
+        under the window, and leaves in the ring only what a later query
+        can still see, the last ``ring x page`` of its ``real_lengths``
+        rows, each in the row its position wraps to."""
+        from deepspeed_tpu.ops.paged_attention import (PagedKVCache,
+                                                       paged_decode_attention,
+                                                       write_paged)
+        c = self.config
+        B, T, Hkv, dh = k.shape
+        page, ring = pool.k_pages.shape[3], ring_tables.shape[1]
+        kwargs = dict(softmax_scale=c.attn_scale, impl=impl,
+                      interpret=interpret,
+                      logit_softcap=c.attn_logit_softcap, window=window)
+        write = functools.partial(write_paged, pool, index, ring_tables,
+                                  impl=impl, interpret=interpret, ring=ring)
+        if T == 1:
+            pool = write(lengths, k, v)
+            return paged_decode_attention(
+                q, pool, ring_tables, lengths + 1, layer=index, ring=ring,
+                items=items, **kwargs), pool
+        n_pages = -(-T // page)
+
+        def as_pages(rows):     # [B, T, Hkv, dh] -> [1, B x n, Hkv, page, dh]
+            rows = jnp.pad(rows, ((0, 0), (0, n_pages * page - T),
+                                  (0, 0), (0, 0)))
+            return jnp.swapaxes(rows.reshape(B * n_pages, page, Hkv, dh),
+                                1, 2)[None].astype(pool.k_pages.dtype)
+
+        fresh = PagedKVCache(as_pages(k), as_pages(v))
+        attn = paged_decode_attention(
+            q, fresh, jnp.arange(B * n_pages, dtype=jnp.int32
+                                 ).reshape(B, n_pages),
+            jnp.full((B,), T, jnp.int32), layer=0, items=items, **kwargs)
+        held = ring * page
+        if T > held:
+            # ring row s takes the newest real position congruent to it
+            s_ = jnp.arange(held)[None, :]
+            at = s_ + held * ((real_lengths[:, None] - 1 - s_) // held)
+            at = jnp.clip(at, 0, T - 1)
+            # whole [Hkv, dh] rows by position (an index an element, as
+            # ``take_along_axis`` would broadcast it, is a scalar gather)
+            seq = jnp.arange(B)[:, None]
+            k, v = k[seq, at], v[seq, at]
+        return attn, write(jnp.zeros((B,), jnp.int32), k, v)
 
     def _latent_fresh(self, q, k, idx, layer, positions=None, counts=None):
         """Latent attention of T tokens over themselves (a whole sequence,
@@ -1057,14 +1207,21 @@ class CausalTransformerLM:
     # The block, stated once.  Every forward calls it and supplies a mixer.
     # ------------------------------------------------------------------
     @jax.named_scope("attn")
-    def _attn_delta(self, h, layer, positions, mix, cache):
+    def _attn_delta(self, h, layer, positions, mix, cache, rotary=True):
         """Attention sub-block on pre-normed input → (residual delta with
-        the wo projection applied and no residual add, cache)."""
-        c = self.config
+        the wo projection applied and no residual add, cache).  A layer
+        with ``wg_attn`` gates what its mixer returns, whichever mixer:
+        ``attn * sigmoid(h wg_attn)`` ahead of ``wo``."""
         B, T, _ = h.shape
-        q, k, v = self._qkv(h, layer, B, T, positions)
+        q, k, v = self._qkv(h, layer, B, T, positions, rotary)
         attn, cache = mix(q, k, v, layer, cache)
-        return self._proj(attn.reshape(B, T, -1), layer, "wo"), cache
+        attn = attn.reshape(B, T, -1)
+        if "wg_attn" in layer:
+            with jax.named_scope("attn_gate"):
+                attn = attn * jax.nn.sigmoid(
+                    (h @ layer["wg_attn"]).astype(jnp.float32)
+                ).astype(attn.dtype)
+        return self._proj(attn, layer, "wo"), cache
 
     def _dropless_delta(self, h, layer, counts=None):
         """The dropless expert layer, this chip's share of it: routing
@@ -1080,7 +1237,7 @@ class CausalTransformerLM:
             chosen, weights = dropless_route(
                 flat, moe["wg"], moe.get("router_bias"), c.moe_top_k,
                 scoring=c.moe_scoring, scale=c.moe_routed_scale,
-                norm=c.moe_norm_topk_prob)
+                norm=c.moe_norm_topk_prob, norm_eps=c.moe_route_norm_eps)
         if counts is not None:
             # bucket padding and idle slots are nobody's tokens: their
             # pairs are neither computed nor counted
@@ -1088,7 +1245,7 @@ class CausalTransformerLM:
         with jax.named_scope("experts"):
             out, load = dropless_held_experts(
                 flat, chosen, weights, moe, _ACTIVATIONS[c.activation],
-                first=c.moe_experts_first)
+                first=c.moe_experts_first, layer=moe.get("stack_layer"))
         out = out.astype(h.dtype)
         if "shared" in moe:
             with jax.named_scope("shared_expert"):
@@ -1161,13 +1318,14 @@ class CausalTransformerLM:
         return delta
 
     def block(self, x, layer, positions, mix, cache=None, rng=None,
-              train=True, counts=None):
+              train=True, counts=None, rotary=True):
         """One transformer block → (x, cache, aux_loss): the residual
         structure, pre-norms, q/k/v, ``wo``, sandwich norms, residual scale
         and the MLP (dense or MoE).  ``mix`` (one of the mixers above) is
         all a forward chooses; ``cache`` goes into it and comes out.
         ``counts``: the serving dispatch's :class:`ServeCounts`, which the
-        expert layer adds to."""
+        expert layer adds to.  ``rotary`` (static): whether this layer's q
+        and k turn (``config.layer_rotary``)."""
         c = self.config
         if c.parallel_block:
             # GPT-J / parallel-residual NeoX: both sub-blocks read the
@@ -1178,27 +1336,39 @@ class CausalTransformerLM:
             ha = _pre_norm(x, layer, "attn_norm", c)
             hm = _pre_norm(x, layer, "mlp_norm", c)
             mlp, aux = self._mlp_delta(hm, layer, rng=rng, train=train)
-            attn, cache = self._attn_delta(ha, layer, positions, mix, cache)
+            attn, cache = self._attn_delta(ha, layer, positions, mix, cache,
+                                           rotary)
             if c.residual_scale is not None:   # Granite-style multiplier
                 attn = attn * c.residual_scale
                 mlp = mlp * c.residual_scale
             return x + attn + mlp, cache, aux
         h = _pre_norm(x, layer, "attn_norm", c)
-        delta, cache = self._attn_delta(h, layer, positions, mix, cache)
+        delta, cache = self._attn_delta(h, layer, positions, mix, cache,
+                                        rotary)
         x = x + self._sandwich(delta, layer, "attn_post_norm")
         h = _pre_norm(x, layer, "mlp_norm", c)
         delta, aux = self._mlp_delta(h, layer, rng=rng, train=train,
                                      counts=counts)
         return x + self._sandwich(delta, layer, "mlp_post_norm"), cache, aux
 
-    def _layer(self, x, layer, positions, rng=None, train=True):
+    def _layer(self, x, layer, positions, rng=None, train=True, rotary=True):
         """The block over a whole sequence → (x, aux): what ``apply``
         scans and ``stream_layer`` / ``runtime/pipe`` call."""
         mix = self.mix_latent_whole if self.config.is_latent \
             else self.mix_full
         x, _, aux = self.block(x, layer, positions, mix, rng=rng,
-                               train=train)
+                               train=train, rotary=rotary)
         return x, aux
+
+    def layer_list(self, params):
+        """Every layer's weights, in order: the listed layers, then each
+        scanned period's cut out of ``params["periods"]``."""
+        layers = list(params["layers"])
+        for p in range((self.config.n_layers - len(layers))
+                       // max(self.config.layer_period, 1)):
+            layers += [jax.tree_util.tree_map(lambda w: w[p], at)
+                       for at in params["periods"]]
+        return layers
 
     # ------------------------------------------------------------------
     # The embedding and the head, stated once.
@@ -1272,12 +1442,13 @@ class CausalTransformerLM:
             if c.remat:
                 policy = getattr(jax.checkpoint_policies, c.remat_policy, None)
                 layer_fn = jax.checkpoint(layer_fn, policy=policy,
-                                          static_argnums=(4,))
-            for i, layer in enumerate(params["layers"]):
+                                          static_argnums=(4, 5))
+            for i, layer in enumerate(self.layer_list(params)):
                 if windows is not None:
                     layer = dict(layer, attn_window=windows[i])
                 lrng = jax.random.fold_in(rng, i) if rng is not None else None
-                x, l_aux = layer_fn(x, layer, positions, lrng, train)
+                x, l_aux = layer_fn(x, layer, positions, lrng, train,
+                                    c.layer_rotary(i))
                 aux = aux + l_aux
         else:
             def body(x, inp):
@@ -1341,11 +1512,13 @@ class CausalTransformerLM:
         windows = self._windows()
         if isinstance(caches, list):  # MoE / heterogeneous stack
             new_caches = []
-            for i, (layer, cache) in enumerate(zip(params["layers"], caches)):
+            for i, (layer, cache) in enumerate(zip(self.layer_list(params),
+                                                   caches)):
                 if windows is not None:
                     layer = dict(layer, attn_window=windows[i])
-                x, cache, _ = self.block(x, layer, positions,
-                                         self.mix_cached, cache, train=False)
+                x, cache, _ = self.block(
+                    x, layer, positions, self.mix_cached, cache, train=False,
+                    rotary=self.config.layer_rotary(i))
                 new_caches.append(cache)
             out_caches = new_caches
         else:
@@ -1370,14 +1543,37 @@ class CausalTransformerLM:
     # ------------------------------------------------------------------
     # paged KV-cache path (continuous-batching serving engine)
     # ------------------------------------------------------------------
-    def init_paged_caches(self, num_pages, page_size, dtype=jnp.bfloat16):
+    def init_paged_caches(self, num_pages, page_size, dtype=jnp.bfloat16,
+                          ring_slots=0):
         """Stacked per-layer page pools: leaves [L, P, Hkv, page, D] — one
         scan for homogeneous stacks; MoE / heterogeneous models index the
-        same pools per layer in a static loop."""
-        from deepspeed_tpu.ops.paged_attention import PagedKVCache
+        same pools per layer in a static loop.  A model with
+        sliding-window layers gets a ``WindowedKVCache``: that stack for
+        its full-attention layers alone, and for its window layers one of
+        ``ring_slots`` rings of ``ring_pages(window, page_size)`` pages
+        (and a scratch page), whatever ``num_pages``."""
+        from deepspeed_tpu.ops.paged_attention import (PagedKVCache,
+                                                       WindowedKVCache,
+                                                       ring_pages)
         c = self.config
-        assert not c.use_alibi and not c.local_attn_pattern, \
-            "paged serving does not support alibi/local-window models yet"
+        assert not c.use_alibi, \
+            "paged serving has no ALiBi: the paged kernels take no bias"
+        if c.attn_window:
+            assert c.layers_listed, (
+                "window layers are served paged out of a listed layer "
+                "stack: a scan over stacked layers has one kind of layer")
+            assert ring_slots > 0, "a window model's pools need ring_slots"
+            n_window = sum(1 for w in c.local_attn_pattern if w)
+            ring = ring_slots * ring_pages(c.attn_window, page_size) + 1
+
+            def stack(layers, pages):
+                shape = (layers, pages, c.kv_heads, page_size, c.head_dim)
+                return PagedKVCache(jnp.zeros(shape, dtype),
+                                    jnp.zeros(shape, dtype))
+
+            return WindowedKVCache(
+                full=stack(c.n_layers - n_window, num_pages),
+                ring=stack(n_window, ring))
         if c.is_latent:
             # one entry a token, [c_kv | k_rope], and the indexer's key:
             # two pools of unlike widths over the same pages
@@ -1420,7 +1616,8 @@ class CausalTransformerLM:
         are the engine's to name).
         """
         from deepspeed_tpu.ops.paged_attention import (paged_read_items,
-                                                       resolve_paged_impl)
+                                                       resolve_paged_impl,
+                                                       ring_pages)
         c = self.config
         B, T = input_ids.shape
         positions = lengths[:, None] + jnp.broadcast_to(
@@ -1431,6 +1628,7 @@ class CausalTransformerLM:
             counts = ServeCounts(
                 jnp.ones((B, T), bool) if real_lengths is None
                 else jnp.arange(T)[None, :] < real_lengths[:, None])
+        ring_mix = {}       # window -> what its layers' mixer is bound to
         if c.is_latent:
             paged = dict(block_tables=block_tables, lengths=lengths,
                          counts=counts)
@@ -1438,23 +1636,71 @@ class CausalTransformerLM:
         else:
             # one backend for the write and the read of the pools
             impl = resolve_paged_impl(attn_backend, c.attn_logit_softcap)
+            full = caches.full if c.attn_window else caches
+            if c.attn_window:
+                # the table's last columns are each sequence's ring
+                page = full.k_pages.shape[3]
+                ring = ring_pages(c.attn_window, page)
+                block_tables, ring_tables = (block_tables[:, :-ring],
+                                             block_tables[:, -ring:])
+                q_shape = (B, T, c.n_heads, c.head_dim)
+                for window in sorted({w for w in c.local_attn_pattern if w}):
+                    if T == 1:
+                        items = paged_read_items(
+                            q_shape, caches.ring, ring_tables, lengths + 1,
+                            impl, window, ring)
+                    else:   # over the rows the prefill brings (mix_ring)
+                        n = -(-T // page)
+                        items = paged_read_items(
+                            q_shape, jax.tree_util.tree_map(
+                                lambda pool: jax.ShapeDtypeStruct(
+                                    (1, B * n) + pool.shape[2:], pool.dtype),
+                                caches.ring),
+                            jax.ShapeDtypeStruct((B, n), jnp.int32),
+                            jnp.full((B,), T, jnp.int32), impl, window)
+                    ring_mix[window] = dict(
+                        window=window, ring_tables=ring_tables,
+                        lengths=lengths, impl=impl, interpret=attn_interpret,
+                        items=items, real_lengths=(
+                            real_lengths if real_lengths is not None
+                            else jnp.full((B,), T, jnp.int32)))
             # the steps of the read that hold keys: once a dispatch, not a
             # layer
-            items = paged_read_items((B, T, c.n_heads, c.head_dim), caches,
+            items = paged_read_items((B, T, c.n_heads, c.head_dim), full,
                                      block_tables, lengths + T, impl)
             paged = dict(block_tables=block_tables, lengths=lengths,
                          impl=impl, interpret=attn_interpret, items=items)
             mixer = self.mix_paged
 
-        def body(carry, inp):
+        def body(carry, inp, at=None, counts=counts):
             # the stacked pools stay ONE buffer through the layers: carried,
-            # written in place, read in place by layer index
+            # written in place, read in place by layer index.  ``at``: the
+            # (static) layer whose pattern this one repeats, where ``i`` is
+            # its traced place in its kind's stack
             x, pools = carry
             layer, i = inp
-            mix = functools.partial(mixer, index=i, **paged)
-            x, pools, _ = self.block(x, layer, positions, mix, pools,
-                                     train=False, counts=counts)
-            return (x, pools), None
+            if not c.attn_window:
+                mix = functools.partial(mixer, index=i, **paged)
+                x, pools, _ = self.block(x, layer, positions, mix, pools,
+                                         train=False, counts=counts)
+                return (x, pools), None
+            window = c.layer_window(at)
+            mix = functools.partial(self.mix_ring, index=i,
+                                    **ring_mix[window]) if window else \
+                functools.partial(mixer, index=i, **paged)
+            kind = "ring" if window else "full"
+            x, pool, _ = self.block(x, layer, positions, mix,
+                                    getattr(pools, kind), train=False,
+                                    counts=counts,
+                                    rotary=c.layer_rotary(at))
+            return (x, pools._replace(**{kind: pool})), None
+
+        def place(i):
+            """Layer ``i``'s place in the stack of its kind."""
+            if not c.attn_window:
+                return i
+            return sum(1 for j in range(i)
+                       if bool(c.layer_window(j)) == bool(c.layer_window(i)))
 
         if isinstance(params["layers"], (list, tuple)):
             # MoE / heterogeneous stack: static per-layer loop (expert
@@ -1463,7 +1709,41 @@ class CausalTransformerLM:
             # all-to-alls as training, reference megatron_gpt_moe serving)
             carry = (x, caches)
             for i, layer in enumerate(params["layers"]):
-                carry, _ = body(carry, (layer, i))
+                carry, _ = body(carry, (layer, place(i)), i)
+            if c.layer_period:
+                # the periods that follow repeat one pattern: scanned, each
+                # layer's place in its stack a traced step on from the
+                # first period's
+                lead, period = c.leading_layers, c.layer_period
+                kinds = [bool(c.layer_window(lead + j))
+                         for j in range(period)]
+                stride = [kinds.count(kind) for kind in kinds]
+
+                # the experts' weights stay stacked, out of the scanned
+                # operands: the expert loops read period ``p``'s in place
+                # (``stack_layer``); cut out by the scan, all of a layer's
+                # experts would be copied every iteration
+                scanned, stacks = zip(*map(_hold_expert_stack,
+                                           params["periods"]))
+
+                def one_period(carry, inp):
+                    layers, p = inp
+                    inner = None if counts is None else \
+                        ServeCounts(counts.real)
+                    for j, layer in enumerate(layers):
+                        if stacks[j]:
+                            layer = dict(layer, moe=dict(
+                                layer["moe"], **stacks[j], stack_layer=p))
+                        carry, _ = body(
+                            carry, (layer, place(lead + j) + p * stride[j]),
+                            lead + j, inner)
+                    return carry, None if inner is None else inner.vector()
+
+                carry, counted = jax.lax.scan(
+                    one_period, carry,
+                    (scanned, jnp.arange((c.n_layers - lead) // period)))
+                if counts is not None:
+                    counts.absorb(counted)
             x, caches = carry
         else:
             (x, caches), _ = jax.lax.scan(

@@ -387,13 +387,27 @@ def moe_layer_forward(gate: TopKGate, gate_params, expert_params, expert_fn,
 DROPLESS_TILE = 512     # rows of one grouped-product step
 
 
-def dropless_route(h, wg, bias, k, scoring="sigmoid", scale=1.0, norm=True):
+def vmem_bytes():
+    """A core's VMEM on the chip this process runs on: XLA may keep there
+    an array no larger (``dropless_held_experts`` asks).  With no TPU to
+    ask (the CPU tests, a compile for a described chip) a v5e's, the chip
+    this repo compiles for where none is attached."""
+    from jax.experimental.pallas import tpu as pltpu
+    try:
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:      # no TPU, or a kind this JAX does not know
+        return 128 * 2 ** 20
+
+
+def dropless_route(h, wg, bias, k, scoring="sigmoid", scale=1.0, norm=True,
+                   norm_eps=0.0):
     """Routing with no capacity: every token gets its ``k`` experts.
     Scores over ALL experts in float32 — ``sigmoid(h wg)`` (``noaux_tc``
     with one group) or ``softmax(h wg)`` — the ``k`` largest of ``scores +
     bias`` chosen (``bias``: the selection bias, or None; ties to the
     lower index), weighted by their UNBIASED scores, normalised over the
-    chosen ones if ``norm``, times ``scale``.  h: [N, d] ->
+    chosen ones if ``norm`` (their sum plus ``norm_eps``, where a model
+    states one), times ``scale``.  h: [N, d] ->
     (chosen [N, k] int32, weights [N, k] float32)."""
     if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"moe_scoring {scoring!r}: 'sigmoid' or 'softmax'")
@@ -405,12 +419,13 @@ def dropless_route(h, wg, bias, k, scoring="sigmoid", scale=1.0, norm=True):
     _, chosen = jax.lax.top_k(biased, k)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if norm:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + norm_eps if norm_eps else total)
     return chosen.astype(jnp.int32), weights * scale
 
 
 def dropless_held_experts(h, chosen, weights, experts, act, first=0,
-                          tile=DROPLESS_TILE):
+                          tile=DROPLESS_TILE, layer=None):
     """The held experts' part of a dropless expert layer: ``sum_e w_e
     GLU_e(h)`` over the (token, expert) pairs whose expert this chip
     holds — experts ``first .. first + E_held - 1`` of the ids in
@@ -422,10 +437,15 @@ def dropless_held_experts(h, chosen, weights, experts, act, first=0,
     and each held expert runs over ITS rows in steps of ``tile`` (a loop
     whose trip count is the expert's load, so an expert nobody chose
     reads no weights and no token is ever dropped).  h: [N, d]; returns
-    (out [N, d] float32, load [E_held] int32 pairs per held expert)."""
+    (out [N, d] float32, load [E_held] int32 pairs per held expert).
+
+    With ``layer`` (may be traced) the expert leaves are a STACK of
+    layers' experts, [L, E_held, ...], and that layer's are read in place
+    (a scan over stacked layers that cut a layer's experts out ahead of
+    the loops would copy all of them every iteration)."""
     N, d = h.shape
     k = chosen.shape[1]
-    held = experts["w_up"].shape[0]
+    held = experts["w_up"].shape[-3]
     tile = min(tile, N)
     flat = chosen.reshape(-1) - first
     mine = (flat >= 0) & (flat < held)
@@ -438,7 +458,18 @@ def dropless_held_experts(h, chosen, weights, experts, act, first=0,
                    dtype=jnp.int32)
     begin = jnp.cumsum(load) - load     # an expert's first sorted row
 
-    out = jnp.zeros((N, d), jnp.float32)
+    # An ``out`` that fits VMEM, XLA may keep there, and its scatter-add
+    # into such an ``out``, which sorts every step's indices first (they
+    # repeat: a step's rows past its expert's load are the next experts'
+    # pairs, weight 0, often of the same tokens), halted the v5e
+    # (docs/serving.md, "The dropless expert layer's scatter").  There the
+    # rows past the load go to ``tile`` spare rows past the tokens', each
+    # to its own: a step meets every row at most once, ascending (a stable
+    # sort keeps an expert's tokens ascending), says so, and XLA scatters
+    # without the sort.  A larger ``out`` stays in HBM, where the sorted
+    # scatter is sound and the faster of the two.
+    spare = (N + tile) * d * 4 <= vmem_bytes()
+    out = jnp.zeros((N + tile if spare else N, d), jnp.float32)
     for e in range(held):
         def rows(i, out, e=e):
             at = begin[e] + i * tile
@@ -449,9 +480,14 @@ def dropless_held_experts(h, chosen, weights, experts, act, first=0,
             x = h[idx]
             # the expert's weights are cut out of the stack INSIDE the
             # loop: cut outside it they are copied whether it runs or not
-            y = (act(x @ experts["w_gate"][e]) * (x @ experts["w_up"][e])
-                 ) @ experts["w_down"][e]
-            return out.at[idx].add(y.astype(jnp.float32) * w[:, None])
+            this = e if layer is None else (layer, e)
+            y = (act(x @ experts["w_gate"][this])
+                 * (x @ experts["w_up"][this])) @ experts["w_down"][this]
+            y = y.astype(jnp.float32) * w[:, None]
+            if not spare:
+                return out.at[idx].add(y)
+            return out.at[jnp.where(live, idx, N + jnp.arange(tile))].add(
+                y, indices_are_sorted=True, unique_indices=True)
 
         out = jax.lax.fori_loop(0, -(-load[e] // tile), rows, out)
-    return out, load
+    return (out[:N] if spare else out), load

@@ -176,11 +176,12 @@ class _OpenSpan:
 OP_PHASES = ("fwd", "bwd", "remat", "loss_head", "optimizer", "grad_reduce",
              "other")
 
-# what a latent-attention / dropless-expert model names inside ``attn`` and
+# what a latent-attention / dropless-expert model, and one whose layers are
+# sliding-window or full attention by a pattern, name inside ``attn`` and
 # ``mlp`` (models/transformer.py): ``phase_of`` gives the innermost of
 # these where an instruction has one, in any program
 SERVE_SCOPES = ("select", "latent_attn", "router", "experts",
-                "shared_expert")
+                "shared_expert", "attn_window", "attn_full", "attn_gate")
 
 _MODEL_SCOPES = frozenset(("embed", "norm", "attn", "mlp"))
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")

@@ -17,6 +17,16 @@ A serving dispatch holds every layer's pool stacked,
 [L, num_pages, Hkv, page_size, D], and passes a layer index
 (``write_paged``, ``paged_decode_attention(layer=)``).
 
+A model with sliding-window layers holds TWO such stacks
+(``WindowedKVCache``): the full-attention layers' pool under the growing
+block tables above, and the window layers' pool under a RING a sequence,
+``ring_pages(window, page_size)`` pages whatever the context: logical page
+``p`` of a sequence lives in column ``p % ring`` of its ring table, so a
+write past the ring's end lands on the oldest page, whose keys have left
+every later query's window.  ``write_paged`` and
+``paged_decode_attention`` take ``window`` and ``ring`` as static
+arguments of the call; masks are by logical position.
+
 Two compute paths behind one API: the fused ragged Pallas kernel
 (``ops/pallas/ragged_paged_attention.py`` — the K/V index maps read the
 block table so only each sequence's own pages are DMA'd, and one launch
@@ -39,6 +49,21 @@ import numpy as np
 class PagedKVCache(NamedTuple):
     k_pages: jnp.ndarray   # [P, Hkv, page, D]
     v_pages: jnp.ndarray
+
+
+class WindowedKVCache(NamedTuple):
+    """The pools of a model whose layers are of two kinds: ``full`` (the
+    full-attention layers, [L_full, P, ...] under the growing tables) and
+    ``ring`` (the sliding-window layers, [L_window, slots x ring + 1, ...]
+    under each slot's ring table)."""
+    full: PagedKVCache
+    ring: PagedKVCache
+
+
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages of a sequence's ring: the window's pages and one more, since
+    a window that does not start on a page boundary touches that many."""
+    return -(-window // page_size) + 1
 
 
 # THE vocabulary of serving.attention_backend (docs/config-json.md), read
@@ -73,17 +98,20 @@ def resolve_paged_impl(impl, logit_softcap=None):
     return "pallas" if use_pallas(impl) and not logit_softcap else "jnp"
 
 
-def _row_targets(block_tables, lengths, T, page_size):
+def _row_targets(block_tables, lengths, T, page_size, ring=None):
     """(page ids, in-page rows), both [B, T], of the T rows a sequence
-    writes from ``lengths`` on."""
+    writes from ``lengths`` on (through a ring of ``ring`` columns: the
+    logical page modulo it)."""
     pos = lengths[:, None] + jnp.arange(T)[None, :]          # [B, T]
-    return (jnp.take_along_axis(block_tables, pos // page_size, axis=1),
-            pos % page_size)
+    col = pos // page_size
+    if ring:
+        col = col % ring
+    return (jnp.take_along_axis(block_tables, col, axis=1), pos % page_size)
 
 
 def write_paged(cache: PagedKVCache, layer, block_tables, lengths, k_new,
                 v_new, impl: Optional[str] = None,
-                interpret: bool = False) -> PagedKVCache:
+                interpret: bool = False, ring=None) -> PagedKVCache:
     """Write rows [B, T, Hkv, D] from ``lengths`` on into layer ``layer``
     (may be traced) of the STACKED pools [L, P, Hkv, page, D], in place.
 
@@ -92,15 +120,16 @@ def write_paged(cache: PagedKVCache, layer, block_tables, lengths, k_new,
     aliased ``paged_kv_write`` kernel, or the jnp scatter on the stack.
     An XLA scatter next to the ragged kernel makes the compiler re-lay
     the whole pool between the two (docs/serving.md), so the write follows
-    the read's backend."""
+    the read's backend.  ``ring`` (static): ``block_tables`` is a ring of
+    that many columns and the rows wrap around it."""
     if resolve_paged_impl(impl) == "pallas":
         from deepspeed_tpu.ops.pallas.ragged_paged_attention import \
             paged_kv_write
         return PagedKVCache(*paged_kv_write(
             cache.k_pages, cache.v_pages, layer, block_tables, lengths,
-            k_new, v_new, interpret=interpret))
+            k_new, v_new, interpret=interpret, ring=ring))
     page_idx, offset = _row_targets(block_tables, lengths, k_new.shape[1],
-                                    cache.k_pages.shape[3])
+                                    cache.k_pages.shape[3], ring)
     k = cache.k_pages.at[layer, page_idx, :, offset].set(
         k_new.astype(cache.k_pages.dtype))
     v = cache.v_pages.at[layer, page_idx, :, offset].set(
@@ -109,16 +138,19 @@ def write_paged(cache: PagedKVCache, layer, block_tables, lengths, k_new,
 
 
 def paged_read_items(q_shape, cache: PagedKVCache, block_tables, lengths,
-                     impl: Optional[str] = None):
+                     impl: Optional[str] = None, window=None, ring=None):
     """What every layer's read of one dispatch shares: the ragged kernel's
     item map (the (q tile, kv step) pairs that hold keys, from ``lengths``
     — the new tokens included), or None where the jnp pair serves.  The
     layer loop's caller builds it once and hands it to each layer's
-    :func:`paged_decode_attention` as ``items``."""
+    :func:`paged_decode_attention` as ``items``.  Layers of another
+    ``window`` or ``ring`` read through another map; ``cache`` and
+    ``block_tables`` may be shapes (``jax.ShapeDtypeStruct``)."""
     if resolve_paged_impl(impl) != "pallas":
         return None
     from deepspeed_tpu.ops.pallas.ragged_paged_attention import rect_item_map
-    return rect_item_map(q_shape, cache.k_pages, block_tables, lengths)
+    return rect_item_map(q_shape, cache.k_pages, block_tables, lengths,
+                         window=window, ring=ring)
 
 
 def paged_decode_attention(q, cache: PagedKVCache, block_tables, lengths,
@@ -126,7 +158,7 @@ def paged_decode_attention(q, cache: PagedKVCache, block_tables, lengths,
                            impl: Optional[str] = None,
                            interpret: bool = False,
                            logit_softcap: Optional[float] = None,
-                           layer=None, items=None):
+                           layer=None, items=None, window=None, ring=None):
     """q: [B, T, H, D] — the last T tokens of each sequence (T=1 decode).
     With ``layer`` (may be traced) ``cache`` holds the stacked pools
     [L, P, Hkv, page, D] and that layer is read in place; ``items`` is
@@ -139,7 +171,20 @@ def paged_decode_attention(q, cache: PagedKVCache, block_tables, lengths,
     (``ops/pallas/ragged_paged_attention.py``); the jnp path gathers each
     sequence's pages into its logical view and runs masked attention over
     the valid ragged prefix — it is the oracle the kernel is tested
-    against.  ``logit_softcap`` is jnp-only and forces the fallback."""
+    against.  ``logit_softcap`` is jnp-only and forces the fallback.
+
+    ``window`` (static): query ``i`` attends keys ``j`` with ``i - window
+    < j <= i``.  ``ring`` (static, with a window): ``block_tables`` is a
+    ring of that many columns, logical page ``p`` in column ``p % ring``;
+    the call may bring at most ``ring x page - window + 1`` rows (a row
+    written ``ring x page`` positions after another takes its place, and
+    the call's first query still attends ``window - 1`` keys back)."""
+    if ring:
+        page_size = cache.k_pages.shape[-2]
+        assert window and ring == block_tables.shape[1] and \
+            q.shape[1] + window - 1 <= ring * page_size, (
+                f"{q.shape[1]} rows through a ring of {ring} pages of "
+                f"{page_size} under a window of {window}")
     if resolve_paged_impl(impl, logit_softcap) == "pallas":
         from deepspeed_tpu.ops.pallas.ragged_paged_attention import \
             ragged_paged_attention_rect
@@ -147,7 +192,8 @@ def paged_decode_attention(q, cache: PagedKVCache, block_tables, lengths,
                                            block_tables, lengths,
                                            softmax_scale=softmax_scale,
                                            interpret=interpret, layer=layer,
-                                           items=items)
+                                           items=items, window=window,
+                                           ring=ring)
     B, T, H, D = q.shape
     Hkv, page_size = cache.k_pages.shape[-3:-1]
     max_pages = block_tables.shape[1]
@@ -168,7 +214,19 @@ def paged_decode_attention(q, cache: PagedKVCache, block_tables, lengths,
         logits = logit_softcap * jnp.tanh(logits / logit_softcap)
     kpos = jnp.arange(S)[None, None, :]                       # [1, 1, S]
     qpos = (lengths[:, None] - T + jnp.arange(T)[None, :])[..., None]
+    if ring:
+        # what the ring holds, by logical position: column c the newest
+        # page congruent to it, and past the context's last row of that
+        # page still the rows of a ring earlier
+        last = (lengths[:, None, None] - 1) // page_size      # [B, 1, 1]
+        kpos = ((last - (last - kpos // page_size) % ring) * page_size
+                + kpos % page_size)
+        kpos = jnp.where(kpos < lengths[:, None, None], kpos, kpos - S)
     mask = kpos <= qpos                                       # [B, T, S]
+    if ring:
+        mask = mask & (kpos >= 0)
+    if window:
+        mask = mask & (kpos > qpos - window)
     logits = jnp.where(mask[:, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bqhd", probs.astype(v.dtype), v)
@@ -197,11 +255,17 @@ class PagedAllocator:
     (oldest first, ``evict_hook`` notified so the cache can drop its index
     entries) only when an allocation outgrows the free list.  With no
     cache layered on top every refcount is 1 and the reclaimable tier
-    stays empty — the original allocator semantics."""
+    stays empty — the original allocator semantics.
+
+    The allocator knows the layer kinds: with ``ring_pages`` a sequence
+    also holds one RING of that many pages of the window layers' pool
+    (``seq_rings``), taken with its first pages and returned with its
+    last, out of ``ring_slots`` rings (pages 1 .. of that pool; page 0 is
+    its scratch page).  A ring is never shared, grown or shrunk."""
 
     def __init__(self, num_pages: int, page_size: int,
                  max_pages_per_seq: int, reserve_scratch: bool = False,
-                 injector=None):
+                 injector=None, ring_pages: int = 0, ring_slots: int = 0):
         """``reserve_scratch``: keep page 0 out of the pool — serving
         engines point INACTIVE batch slots' tables at page 0 so their
         dummy-token writes land in a sacrificial page.  ``injector``: a
@@ -222,6 +286,10 @@ class PagedAllocator:
         self.evict_hook = None              # called with each evicted page
         self.pages_taken = 0                # fresh pages handed out (stats)
         self.reclaim_evictions = 0          # reclaimable pages surrendered
+        self.ring_pages = int(ring_pages)
+        self.ring_pool = self.ring_pages * int(ring_slots)
+        self.ring_free: List[int] = list(range(1, 1 + self.ring_pool))
+        self.seq_rings = {}
 
     def can_allocate(self, n_pages: int) -> bool:
         return self.available_page_count >= n_pages
@@ -235,6 +303,14 @@ class PagedAllocator:
         """Pages an allocation can actually obtain: the free list plus the
         reclaimable tier (cached pages evictable on demand)."""
         return len(self.free) + len(self.reclaimable)
+
+    @property
+    def ring_pages_in_use(self) -> int:
+        return self.ring_pages * len(self.seq_rings)
+
+    def ring_available(self) -> bool:
+        """A sequence admitted now would find its ring."""
+        return len(self.ring_free) >= self.ring_pages
 
     # -- refcount plumbing ----------------------------------------------
     def _ref_page(self, page: int):
@@ -340,6 +416,10 @@ class PagedAllocator:
             raise PageAllocationError(
                 f"out of KV pages: need {fresh_needed}, free "
                 f"{len(self.free)} (+{evictable} reclaimable)")
+        if not self.ring_available():
+            raise PageAllocationError(
+                f"out of ring pages: need {self.ring_pages}, free "
+                f"{len(self.ring_free)}")
         self._check_injector()
         for p in protect:
             self._ref_page(p)
@@ -351,6 +431,9 @@ class PagedAllocator:
             for p in protect:
                 self._release_page(p)
         self.seq_pages[seq_id] = pages
+        if self.ring_pages:
+            self.seq_rings[seq_id] = [self.ring_free.pop()
+                                      for _ in range(self.ring_pages)]
         return pages
 
     def extend(self, seq_id, total_tokens: int) -> List[int]:
@@ -383,6 +466,7 @@ class PagedAllocator:
     def free_sequence(self, seq_id):
         for page in self.seq_pages.pop(seq_id, []):
             self._release_page(page)
+        self.ring_free.extend(self.seq_rings.pop(seq_id, []))
 
     def audit(self) -> dict:
         """Refcount/accounting invariants; {} when clean.  Every page is
@@ -415,6 +499,16 @@ class PagedAllocator:
         if not self.cached >= set(self.reclaimable):
             problems["uncached_reclaimable"] = sorted(
                 set(self.reclaimable) - self.cached)
+        ring_held = [p for ring in self.seq_rings.values() for p in ring]
+        if set(self.seq_rings) != (set(self.seq_pages) if self.ring_pages
+                                   else set()) or \
+                sorted(ring_held + self.ring_free) != \
+                list(range(1, 1 + self.ring_pool)) or \
+                any(len(r) != self.ring_pages
+                    for r in self.seq_rings.values()):
+            problems["ring_accounting"] = {
+                "held": len(ring_held), "free": len(self.ring_free),
+                "sequences": sorted(self.seq_rings, key=str)}
         return problems
 
     def block_table(self, seq_ids) -> np.ndarray:

@@ -143,7 +143,7 @@ def _step_vmem_bytes(q_tile, heads, pages, group, page_size, D, itemsize):
 
 
 def pick_tiles(q_lens, group, n_kv_heads, page_size, head_dim, table_width,
-               itemsize, q_tile=None) -> TileChoice:
+               itemsize, q_tile=None, window=None, ring=None) -> TileChoice:
     """Block shapes for one call, from what the call can see.
 
     ``q_lens``: host ints, one per sequence (``[T] * B`` for the
@@ -154,7 +154,9 @@ def pick_tiles(q_lens, group, n_kv_heads, page_size, head_dim, table_width,
     ``STEP_KV_BYTES`` of K and V a step (a page must fill whole sublane
     tiles of its dtype to be concatenated with others); pages, then
     heads, shrink to fit ``VMEM_BUDGET``.  ``q_tile`` overrides the first
-    choice (tests)."""
+    choice (tests).  Under a ``window`` a tile runs no more kv steps than
+    hold its rows' windows, whatever the table's width (a ``ring``'s width
+    is no bound at all: its columns come round again)."""
     T = max(q_lens)
     if q_tile is None:
         cap = max(1, MAX_ROWS // group)
@@ -182,22 +184,38 @@ def pick_tiles(q_lens, group, n_kv_heads, page_size, head_dim, table_width,
     while heads > 1 and vmem(heads, pages) > VMEM_BUDGET:
         heads = next(h for h in divisors if h < heads)
     n_tiles = sum(-(-int(ql) // q_tile) for ql in q_lens)
-    grid = (n_tiles, n_kv_heads // heads, -(-table_width // pages))
+    kv_steps = -(-table_width // pages)
+    if window is not None:
+        most = (window + q_tile - 2) // (pages * page_size) + 2
+        kv_steps = most if ring else min(kv_steps, most)
+    grid = (n_tiles, n_kv_heads // heads, kv_steps)
     return TileChoice(q_tile, heads, pages, grid, vmem(heads, pages),
                       item_table=grid[0] * grid[2] <= ITEM_TABLE_MAX)
 
 
+def first_step(ctx, qlen, qtile, q_tile, keys, window, xp=jnp):
+    """The kv step (of ``keys`` keys) that holds the oldest key inside the
+    ``window`` of a q tile's first row."""
+    return xp.maximum(ctx - qlen + qtile * q_tile - window + 1, 0) // keys
+
+
 def live_steps(ctx_lens, q_lens, seq_of_tile, qtile_of_tile,
-               tiles: TileChoice, page_size, xp=jnp):
+               tiles: TileChoice, page_size, xp=jnp, window=None):
     """kv steps each q tile runs, [..., n_tiles]: the steps of
-    ``tiles.pages`` pages under the tile's causal frontier, and one for a
-    tile with no key (its output block is still written).  ``xp`` is
-    ``jnp`` inside the jit (the item map) or ``numpy`` on the host
-    (:func:`rect_grid_steps`): the same arithmetic for both."""
+    ``tiles.pages`` pages under the tile's causal frontier (from the one
+    that holds the first key inside the ``window`` of its first row), and
+    one for a tile with no key (its output block is still written).
+    ``xp`` is ``jnp`` inside the jit (the item map) or ``numpy`` on the
+    host (:func:`rect_grid_steps`): the same arithmetic for both."""
     ctx, qlen = ctx_lens[..., seq_of_tile], q_lens[seq_of_tile]
+    keys = tiles.pages * page_size
     # keys this q tile may attend (causal): positions < kv_hi
     kv_hi = ctx - qlen + xp.minimum(qlen, (qtile_of_tile + 1) * tiles.q_tile)
-    return xp.clip(-(-kv_hi // (tiles.pages * page_size)), 1, tiles.grid[2])
+    steps = -(-kv_hi // keys)
+    if window is not None:
+        steps = steps - first_step(ctx, qlen, qtile_of_tile, tiles.q_tile,
+                                   keys, window, xp)
+    return xp.clip(steps, 1, tiles.grid[2])
 
 
 def rect_metadata(B, T, q_tile):
@@ -207,14 +225,15 @@ def rect_metadata(B, T, q_tile):
             np.tile(np.arange(n_qt, dtype=np.int32), B))
 
 
-def rect_grid_steps(tiles: TileChoice, B, T, ctx_lens, page_size) -> int:
+def rect_grid_steps(tiles: TileChoice, B, T, ctx_lens, page_size,
+                    window=None) -> int:
     """Grid steps a [B, T] call runs over host ``ctx_lens`` ([..., B], the
     new tokens included; leading axes are further calls of the shape):
     what the item map of each call will hold, times the kv-head blocks.
     ``tiles.grid_steps`` is the rectangle it is drawn from."""
     steps = live_steps(np.asarray(ctx_lens), np.full(B, T),
                        *rect_metadata(B, T, tiles.q_tile), tiles, page_size,
-                       xp=np)
+                       xp=np, window=window)
     return tiles.grid[1] * int(steps.sum())
 
 
@@ -230,12 +249,13 @@ class ItemMap(NamedTuple):
 
 
 def build_item_map(ctx_lens, q_lens, seq_of_tile, qtile_of_tile,
-                   tiles: TileChoice, page_size) -> ItemMap:
+                   tiles: TileChoice, page_size, window=None) -> ItemMap:
     """The item map of one call, inside the jit; the same for every layer
-    of a dispatch, so a layer loop builds it once, outside."""
+    of a dispatch (of one ``window``), so a layer loop builds it once,
+    outside."""
     steps = live_steps(jnp.asarray(ctx_lens, jnp.int32),
                        jnp.asarray(q_lens, jnp.int32), seq_of_tile,
-                       qtile_of_tile, tiles, page_size)
+                       qtile_of_tile, tiles, page_size, window=window)
     first = jnp.concatenate([jnp.zeros(1, jnp.int32),
                              jnp.cumsum(steps, dtype=jnp.int32)])
     if not tiles.item_table:
@@ -266,7 +286,7 @@ def _locate(item, first_ref, toi_ref, n_tiles, item_table):
 
 def _ragged_kernel(ctx_ref, qlens_ref, sot_ref, qot_ref, tables_ref,
                    layer_ref, first_ref, toi_ref, q_ref, *refs, scale,
-                   page_size, q_tile, group, pages, locate):
+                   page_size, q_tile, group, pages, locate, window=None):
     """One (kv-head block, item) of online-softmax attention; an item is
     one (q tile, kv step) pair of the item map.
 
@@ -275,7 +295,8 @@ def _ragged_kernel(ctx_ref, qlens_ref, sot_ref, qot_ref, tables_ref,
     refs and ``pages`` V refs of [1, heads, page, D] (the pages the index
     maps resolved through the block table); o_ref like q_ref; scratch
     acc/m/l persist across a tile's items, which are consecutive (TPU
-    grids are sequential)."""
+    grids are sequential).  Under a ``window`` a tile's items start at
+    :func:`first_step` and a row's keys end ``window`` behind it."""
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
     o_ref, acc_ref, m_ref, l_ref = refs[2 * pages:]
     item = pl.program_id(1)
@@ -289,6 +310,8 @@ def _ragged_kernel(ctx_ref, qlens_ref, sot_ref, qot_ref, tables_ref,
 
     rows = q_tile * group
     keys = pages * page_size
+    step = i if window is None else \
+        i + first_step(ctx, qlen, qt, q_tile, keys, window)
 
     @pl.when(i == 0)
     def _init():
@@ -298,7 +321,7 @@ def _ragged_kernel(ctx_ref, qlens_ref, sot_ref, qot_ref, tables_ref,
 
     # the map holds no step past the frontier: only the one item of a tile
     # with no key at all is turned off here
-    @pl.when(i * keys < kv_hi)
+    @pl.when(step * keys < kv_hi)
     def _compute():
         if pages == 1:
             k, v = k_refs[0][0], v_refs[0][0]              # [heads, page, D]
@@ -317,7 +340,10 @@ def _ragged_kernel(ctx_ref, qlens_ref, sot_ref, qot_ref, tables_ref,
             jnp.int32, (rows, 1), 0) // group
         last_key = jnp.where(local_t < qlen, ctx - qlen + local_t, -1)
         kpos = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
-        sc = jnp.where((kpos <= last_key - i * keys)[None], sc, _NEG)
+        seen = kpos <= last_key - step * keys
+        if window is not None:
+            seen = seen & (kpos > last_key - window - step * keys)
+        sc = jnp.where(seen[None], sc, _NEG)
 
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
@@ -347,7 +373,8 @@ KERNEL_DECODE = "ragged_paged_attention_decode"
 
 def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
                  seq_of_tile, qtile_of_tile, tiles: TileChoice, scale,
-                 interpret, name, layer=None, items: ItemMap = None):
+                 interpret, name, layer=None, items: ItemMap = None,
+                 window=None, ring=None):
     """Launch the kernel over a tiled query stack.
 
     qt: [n_tiles, Hkv, q_tile*group, D] — tile ``t`` holds ``q_tile``
@@ -358,7 +385,10 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
     or the stacked pools [L, P, Hkv, page, D] with ``layer`` (may be
     traced) the one to read — the index maps pick it, so no layer's pool
     is ever sliced out of the stack.  ``items``: the call's
-    :func:`build_item_map`, built here when the caller brings none."""
+    :func:`build_item_map`, built here when the caller brings none.
+    ``window`` / ``ring`` (static): a sliding window over the keys, and a
+    block table that is a ring of ``ring`` columns (logical page ``p`` in
+    column ``p % ring``)."""
     n_tiles, Hkv, rows, D = qt.shape
     if k_pages.ndim == 4:
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
@@ -374,7 +404,7 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
     lay = jnp.asarray(layer, jnp.int32).reshape(1)
     if items is None:
         items = build_item_map(ctx_lens, q_lens, seq_of_tile, qtile_of_tile,
-                               tiles, page_size)
+                               tiles, page_size, window)
     locate = functools.partial(_locate, n_tiles=n_tiles,
                                item_table=tiles.item_table)
 
@@ -388,8 +418,14 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
         s = sot[t]
         kv_hi = ctx[s] - qls[s] + jnp.minimum(qls[s], (qot[t] + 1) * q_tile)
         last = jnp.maximum(pl.cdiv(kv_hi, page_size) - 1, 0)
+        if window is not None:
+            i = i + first_step(ctx[s], qls[s], qot[t], q_tile,
+                               pages * page_size, window)
         col = jnp.minimum(i * pages + j, last)
-        return (lay[0], tbl[s, jnp.minimum(col, width - 1)], h, 0, 0)
+        # a ring's columns come round again; a table's last one catches
+        # whatever lies past it
+        return (lay[0], tbl[s, col % ring if ring
+                            else jnp.minimum(col, width - 1)], h, 0, 0)
 
     kv_specs = [pl.BlockSpec((None, 1, heads, page_size, D),
                              functools.partial(kv_map, j))
@@ -397,7 +433,7 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
     kernel = functools.partial(_ragged_kernel, scale=scale,
                                page_size=page_size, q_tile=q_tile,
                                group=rows // q_tile, pages=pages,
-                               locate=locate)
+                               locate=locate, window=window)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -424,11 +460,13 @@ def _ragged_call(qt, k_pages, v_pages, block_tables, ctx_lens, q_lens,
       *([k_pages] * pages), *([v_pages] * pages))
 
 
-def _pick_for(q_lens, q_shape, k_pages, block_tables, q_tile):
+def _pick_for(q_lens, q_shape, k_pages, block_tables, q_tile, window=None,
+              ring=None):
     H, D = q_shape[-2:]
     Hkv, page_size = k_pages.shape[-3:-1]
     return pick_tiles(q_lens, H // Hkv, Hkv, page_size, D,
-                      block_tables.shape[1], k_pages.dtype.itemsize, q_tile)
+                      block_tables.shape[1], k_pages.dtype.itemsize, q_tile,
+                      window, ring)
 
 
 def _to_tiles(q, n_tiles, q_tile, Hkv):
@@ -464,7 +502,7 @@ def _pack_metadata(q_lens, q_tile):
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
                            q_lens, softmax_scale=None, q_tile=None,
-                           interpret=False):
+                           interpret=False, window=None, ring=None):
     """Mixed prefill+decode attention over a packed ragged batch.
 
     q: [total_q, H, D] — sequence b's rows are
@@ -475,6 +513,7 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     host ints — the packed layout is host metadata, like the block
     tables' shape.  ``q_tile`` None lets :func:`pick_tiles` choose (every
     sequence pads to a multiple of the tile the longest one picks).
+    ``window`` / ``ring`` as :func:`_ragged_call` reads them.
     Returns [total_q, H, D].
     """
     total_q, H, D = q.shape
@@ -484,7 +523,8 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     assert sum(q_lens) == total_q, \
         f"q has {total_q} rows but q_lens sums to {sum(q_lens)}"
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-    tiles = _pick_for(q_lens, q.shape, k_pages, block_tables, q_tile)
+    tiles = _pick_for(q_lens, q.shape, k_pages, block_tables, q_tile,
+                      window, ring)
     starts, sot, qot, total_padded = _pack_metadata(q_lens, tiles.q_tile)
 
     # scatter each sequence's rows to its q_tile-aligned start (static
@@ -498,35 +538,40 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     out = _ragged_call(_to_tiles(qp, len(sot), tiles.q_tile, Hkv),
                        k_pages, v_pages, block_tables, ctx_lens, q_lens,
                        sot, qot, tiles, scale, interpret,
-                       KERNEL_DECODE if max(q_lens) == 1 else KERNEL_PREFILL)
+                       KERNEL_DECODE if max(q_lens) == 1 else KERNEL_PREFILL,
+                       window=window, ring=ring)
     out = _from_tiles(out, tiles.q_tile)
     return jnp.concatenate(
         [out[int(starts[s]):int(starts[s]) + ql]
          for s, ql in enumerate(q_lens)], axis=0)
 
 
-def _rect_layout(q_shape, k_pages, block_tables, q_tile):
+def _rect_layout(q_shape, k_pages, block_tables, q_tile, window=None,
+                 ring=None):
     """(tiles, seq_of_tile, qtile_of_tile) of a [B, T, H, D] call."""
     B, T = q_shape[:2]
-    tiles = _pick_for([T] * B, q_shape, k_pages, block_tables, q_tile)
+    tiles = _pick_for([T] * B, q_shape, k_pages, block_tables, q_tile,
+                      window, ring)
     return (tiles,) + rect_metadata(B, T, tiles.q_tile)
 
 
 def rect_item_map(q_shape, k_pages, block_tables, lengths,
-                  q_tile=None) -> ItemMap:
+                  q_tile=None, window=None, ring=None) -> ItemMap:
     """The item map :func:`ragged_paged_attention_rect` runs for these
     arguments (``q_shape``: [B, T, H, D]).  It is the same for every layer
     of a dispatch: the layer loop's caller builds it once and hands it to
     each layer's call as ``items``."""
     B, T = q_shape[:2]
-    tiles, sot, qot = _rect_layout(q_shape, k_pages, block_tables, q_tile)
+    tiles, sot, qot = _rect_layout(q_shape, k_pages, block_tables, q_tile,
+                                   window, ring)
     return build_item_map(lengths, jnp.full((B,), T, jnp.int32), sot, qot,
-                          tiles, k_pages.shape[-2])
+                          tiles, k_pages.shape[-2], window)
 
 
 def ragged_paged_attention_rect(q, k_pages, v_pages, block_tables, lengths,
                                 softmax_scale=None, q_tile=None,
-                                interpret=False, layer=None, items=None):
+                                interpret=False, layer=None, items=None,
+                                window=None, ring=None):
     """Rectangular front-end for the jitted serving path.
 
     q: [B, T, H, D] — the last T tokens of each sequence (T=1 decode,
@@ -537,12 +582,14 @@ def ragged_paged_attention_rect(q, k_pages, v_pages, block_tables, lengths,
     are masked inside the kernel.  With ``layer`` (may be traced) the
     pools are the stacked [L, P, Hkv, page, D] and are read in place.
     ``items``: :func:`rect_item_map` of the same arguments, where the
-    caller built it once for all its layers.
+    caller built it once for all its layers.  ``window`` / ``ring`` as
+    :func:`_ragged_call` reads them.
     """
     B, T, H, D = q.shape
     Hkv = k_pages.shape[-3]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-    tiles, sot, qot = _rect_layout(q.shape, k_pages, block_tables, q_tile)
+    tiles, sot, qot = _rect_layout(q.shape, k_pages, block_tables, q_tile,
+                                   window, ring)
     Tp = len(sot) // B * tiles.q_tile
     if Tp != T:
         q = jnp.pad(q, ((0, 0), (0, Tp - T), (0, 0), (0, 0)))
@@ -552,7 +599,7 @@ def ragged_paged_attention_rect(q, k_pages, v_pages, block_tables, lengths,
                        k_pages, v_pages, block_tables, lengths, q_lens,
                        sot, qot, tiles, scale, interpret,
                        KERNEL_DECODE if T == 1 else KERNEL_PREFILL, layer,
-                       items)
+                       items, window, ring)
     return _from_tiles(out, tiles.q_tile).reshape(B, Tp, H, D)[:, :T]
 
 
@@ -607,7 +654,7 @@ def _kv_write_kernel(layer_ref, starts_ref, tables_ref, kn_ref, vn_ref,
 
 
 def paged_kv_write(k_pages, v_pages, layer, block_tables, lengths, k_new,
-                   v_new, interpret=False):
+                   v_new, interpret=False, ring=None):
     """Write each sequence's new rows into layer ``layer`` of the stacked
     pools, touching no other byte of them.
 
@@ -616,8 +663,9 @@ def paged_kv_write(k_pages, v_pages, layer, block_tables, lengths, k_new,
     copied); k_new/v_new: [B, T, Hkv, D], written at positions
     ``lengths[b] + arange(T)`` through ``block_tables`` exactly as
     ``write_paged`` resolves them (columns past the table clamp to its
-    last one, the engine's overrun column on the scratch page).  ``layer``
-    and ``lengths`` may be traced.
+    last one, the engine's overrun column on the scratch page; with
+    ``ring``, static, the table is a ring of that many columns and the
+    rows wrap around it).  ``layer`` and ``lengths`` may be traced.
 
     XLA lines the new rows up with the pool's row blocks (they are small:
     ``[B, blocks, Hkv, rows, D]``), the kernel merges whole blocks chosen
@@ -655,7 +703,8 @@ def paged_kv_write(k_pages, v_pages, layer, block_tables, lengths, k_new,
 
     def pool_map(b, j, h, lay, st, tbl):
         blk = st[b] // rows + block_of(b, j, st)
-        col = jnp.minimum(blk // per_page, width - 1)
+        col = blk // per_page
+        col = col % ring if ring else jnp.minimum(col, width - 1)
         return (lay[0], tbl[b, col], h, blk % per_page, 0)
 
     block = (None, None, heads, rows, D)

@@ -203,7 +203,13 @@ SPAN_NAMES = (
 SERVE_COUNTERS = ("selected", "context_keys", "expert_pairs",
                   "expert_load_max")
 SERVE_SCOPES = ("select", "latent_attn", "router", "experts",
-                "shared_expert")
+                "shared_expert", "attn_window", "attn_full", "attn_gate")
+# FROZEN: what a model with sliding-window layers adds to the same
+# dispatches and spans, reckoned on the host from the lengths
+# (byte-identical to ``deepspeed_tpu.inference.serving.WINDOW_COUNTS``;
+# it brings no ``selected``)
+WINDOW_COUNTS = ("context_keys", "attended_keys", "pages_full",
+                 "pages_ring")
 
 # FROZEN vocabulary of serve-kind event names — must stay byte-identical
 # to ``deepspeed_tpu.inference.robustness.SERVE_EVENTS`` (the tier-1 test

@@ -137,6 +137,51 @@ def test_ragged_paged_attention(topo, name, batch, q_len, heads, kv_heads,
             else "ragged_paged_attention_prefill") in text
 
 
+# name, batch, q_len, table width, window, ring: Trinity-Mini's shapes as
+# the engine dispatches them (32 query heads on 4 kv heads of 128: group 8;
+# page 128; ``max_seq`` 16,384 -> 128 pages + the overrun column; a window
+# of 2,048 in a ring of 17 pages).  A window layer's decode step reads
+# through the ring; its prefill reads the bucket's own rows as pages under
+# the window; a full layer reads the growing table
+WINDOW_SHAPES = [
+    ("ring_decode_b16", 16, 1, 17, 2048, 17),
+    ("full_decode_b16", 16, 1, 129, None, None),
+    ("window_prefill_512", 1, 512, 4, 2048, None),
+    ("window_prefill_16384", 1, 16384, 128, 2048, None),
+    ("full_prefill_16384", 1, 16384, 129, None, None),
+]
+
+
+@pytest.mark.parametrize("name,batch,q_len,width,window,ring", WINDOW_SHAPES,
+                         ids=[c[0] for c in WINDOW_SHAPES])
+def test_ragged_window_and_ring(topo, name, batch, q_len, width, window,
+                                ring):
+    """Group 8 under a window and through a ring goes through Mosaic at
+    the tiles the picker chooses, and so does the ring's wrapping write."""
+    from deepspeed_tpu.ops.paged_attention import write_paged
+    chip = SingleDeviceSharding(topo.devices[0])
+    heads, kv_heads, page_size, head_dim = 32, 4, 128, 128
+    pool = _on(chip, (3, batch * width + 1, kv_heads, page_size, head_dim))
+    rows = _on(chip, (batch, q_len, kv_heads, head_dim))
+
+    def step(q, k_new, v_new, k, v, tables, lengths):
+        cache = PagedKVCache(k, v)
+        if ring:
+            cache = write_paged(cache, 1, tables, lengths, k_new, v_new,
+                                impl="pallas", ring=ring)
+        return paged_decode_attention(
+            q, cache, tables, lengths + q_len, impl="pallas", layer=1,
+            window=window, ring=ring), cache
+
+    text = _compiled_text(
+        step, _on(chip, (batch, q_len, heads, head_dim)), rows, rows, pool,
+        pool, _on(chip, (batch, width), jnp.int32),
+        _on(chip, (batch,), jnp.int32))
+    assert ("ragged_paged_attention_decode" if q_len == 1
+            else "ragged_paged_attention_prefill") in text
+    assert ("paged_kv_write" in text) == bool(ring)
+
+
 def test_ragged_item_search_compiles(topo, monkeypatch):
     """An engine whose (q tile, kv step) rectangle is past
     ``ITEM_TABLE_MAX`` searches the running sum of the tiles' steps inside
@@ -196,12 +241,13 @@ DISPATCHES = [
 ]
 
 
-def _pool_shaped(text, layers):
+def _pool_shaped(text, layers, shapes=None):
     """(name, opcode) of every instruction of the compiled text whose
-    result holds a pool-shaped or stack-shaped array."""
+    result holds a pool-shaped or stack-shaped array (or one of
+    ``shapes``)."""
     import re
     dims = ",".join(map(str, POOL))
-    shapes = (f"bf16[{dims}]", f"bf16[{layers},{dims}]")
+    shapes = shapes or (f"bf16[{dims}]", f"bf16[{layers},{dims}]")
     found = []
     for line in text.splitlines():
         name, eq, rest = line.partition(" = ")
@@ -297,6 +343,65 @@ def test_serving_dispatch_never_copies_the_page_pools(
     if tokens == 1 or jit_name == "chunk":
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < ONE_LAYER_POOL_BYTES, temp
+
+
+@pytest.mark.parametrize("batch,tokens", [(16, 1), (1, 4096)],
+                         ids=["decode_b16", "prefill_4096"])
+def test_window_model_dispatch_never_copies_either_stack(topo, batch,
+                                                         tokens):
+    """The same guard for a model with window layers (Trinity-Mini's
+    widths, 8 of its layers: 4 leading and one scanned period, 4 of 128
+    experts held): neither the full-attention stack nor the ring stack is
+    sliced, re-laid or copied by a dispatch; a prefill longer than the
+    ring (4,096 rows into 17 pages of 128) gathers its tail out of the new
+    rows, not out of a pool."""
+    import json
+    import os
+
+    from chipbench.families import afmoe
+    with open(os.path.join(os.path.dirname(afmoe.__file__), "..",
+                           "configs", "trinity-mini-ep8.json")) as f:
+        cfg = json.load(f)
+    cfg.update(num_hidden_layers=8, layer_types=cfg["layer_types"][:8],
+               num_experts=4, vocab_size=1024)
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = CausalTransformerLM(TransformerConfig(
+        **afmoe.transformer_kwargs(cfg)))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _on(chip, x.shape, x.dtype), tree)
+
+    def ints(*shape):
+        return _on(chip, shape, jnp.int32)
+
+    pages, slots, ring = 257, 16, 17
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.bfloat16)))
+    caches = on_chip(jax.eval_shape(
+        lambda: model.init_paged_caches(pages, 128, ring_slots=slots)))
+
+    def serve(params, ids, caches, tables, lengths, real, *rows):
+        return model.apply_with_paged_cache(
+            params, ids, caches, tables, lengths, attn_backend="pallas",
+            real_lengths=real, **dict(zip(("head_rows",), rows)))
+
+    compiled = jax.jit(serve, donate_argnums=(2,)).lower(
+        params, ints(batch, tokens), caches, ints(batch, 129 + ring),
+        ints(batch), ints(batch),
+        *([ints(batch, 1)] if tokens > 1 else [])).compile()
+    text = compiled.as_text()
+    found = {op for _, op in _pool_shaped(text, None, [
+        f"bf16[{layers},{n},4,128,128]"
+        for layers, n in ((2, pages), (6, slots * ring + 1))])}
+    assert found and found <= {"parameter", "get-tuple-element", "tuple",
+                               "while", "bitcast", "tpu_custom_call"}, found
+    assert "paged_kv_write" in text and " while(" in text
+    assert ("ragged_paged_attention_decode" if tokens == 1
+            else "ragged_paged_attention_prefill") in text
+    smaller = 2 * 2 * slots * ring * 4 * 128 * 128      # one ring layer
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        smaller if tokens == 1 else 1 << 30)
 
 
 BUCKET_LOGITS = "f32[1,4096,100352]"      # 1.64 GB: every row of a bucket

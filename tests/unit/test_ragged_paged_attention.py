@@ -550,3 +550,135 @@ def test_reservation_trimmed_and_audited(tiny):
     assert leaks["over_reserved_slots"]["r0"]["held"] == 4
     eng.alloc.shrink("r0", 11)
     assert eng.leak_report() == {}
+
+
+# ---------------------------------------------------------------------------
+# a sliding window over the keys, and a block table that is a ring
+# ---------------------------------------------------------------------------
+def _windowed_case(window, ring_on, T, ctx, group, page=8, hkv=2, d=16):
+    """A pool (layer 1 of a stack of 2) that holds each sequence's keys
+    where its table says, a row of a ring holding the newest position
+    congruent to it; the T newest rows of each sequence as queries:
+    (q, keys, values, pool, tables, ring)."""
+    from deepspeed_tpu.ops.paged_attention import ring_pages
+    rng = np.random.default_rng(0)
+    ctx = np.asarray(ctx)
+    B, longest = len(ctx), int(ctx.max())
+    ring = ring_pages(window, page) if ring_on else None
+    width = ring or -(-longest // page) + 1
+    tables = 1 + np.arange(B * width, dtype=np.int32).reshape(B, width)
+    keys = rng.standard_normal((B, longest, hkv, d)).astype(np.float32)
+    values = rng.standard_normal((B, longest, hkv, d)).astype(np.float32)
+    # what nobody wrote is noise, not zeros: a key read from a row that is
+    # not inside the window must show
+    k_pool = rng.standard_normal((2, 1 + B * width, hkv, page, d))
+    v_pool = rng.standard_normal((2, 1 + B * width, hkv, page, d))
+    for b in range(B):
+        for t in range(ctx[b]):
+            col = t // page % ring if ring else t // page
+            k_pool[1, tables[b, col], :, t % page] = keys[b, t]
+            v_pool[1, tables[b, col], :, t % page] = values[b, t]
+    pool = PagedKVCache(jnp.asarray(k_pool, jnp.float32),
+                        jnp.asarray(v_pool, jnp.float32))
+    q = rng.standard_normal((B, T, hkv * group, d)).astype(np.float32)
+    return q, keys, values, pool, jnp.asarray(tables), ring
+
+
+def _brute_force(q, keys, values, ctx, window, group):
+    """Dense attention of each query over the keys its window holds."""
+    want = np.zeros_like(q)
+    B, T = q.shape[:2]
+    for b in range(B):
+        for t in range(T):
+            i = ctx[b] - T + t
+            lo = max(0, i - window + 1) if window else 0
+            kk = np.repeat(keys[b, lo:i + 1], group, axis=1)
+            vv = np.repeat(values[b, lo:i + 1], group, axis=1)
+            s = np.einsum("hd,khd->hk", q[b, t], kk) / np.sqrt(q.shape[-1])
+            p = np.exp(s - s.max(-1, keepdims=True))
+            want[b, t] = np.einsum("hk,khd->hd",
+                                   p / p.sum(-1, keepdims=True), vv)
+    return want
+
+
+WINDOW_CASES = [
+    # name, window, ring, T, contexts, group
+    ("no_window_group8", None, False, 1, [5, 17, 40], 8),
+    ("window_beyond_context", 64, False, 1, [5, 24, 40], 1),
+    ("window_inside_decode", 16, False, 1, [5, 17, 40], 8),
+    ("window_inside_prefill", 16, False, 12, [12, 24, 70], 2),
+    ("window_inside_prefill_group8", 16, False, 40, [40, 64], 8),
+    ("ring_decode_group8", 16, True, 1, [5, 17, 70], 8),
+    ("ring_decode_wrapped_twice", 16, True, 1, [49, 24, 73], 1),
+    ("ring_beyond_context", 64, True, 1, [5, 24, 70], 1),
+    ("ring_chunk_of_four", 16, True, 4, [5, 24, 70], 2),
+    ("ring_chunk_at_its_limit", 16, True, 9, [9, 33, 70], 1),
+]
+
+
+@pytest.mark.parametrize("name,window,ring_on,T,ctx,group", WINDOW_CASES,
+                         ids=[c[0] for c in WINDOW_CASES])
+def test_window_and_ring_match_oracle_and_brute_force(name, window, ring_on,
+                                                      T, ctx, group):
+    q, keys, values, pool, tables, ring = _windowed_case(
+        window, ring_on, T, ctx, group)
+    want = _brute_force(q, keys, values, ctx, window, group)
+    for impl in ("jnp", "pallas"):
+        got = paged_decode_attention(
+            jnp.asarray(q), pool, tables, jnp.asarray(ctx),
+            impl=impl, interpret=True, layer=1, window=window, ring=ring)
+        np.testing.assert_allclose(got, want, **TOL, err_msg=impl)
+
+
+def test_window_runs_only_the_steps_its_rows_can_see():
+    """The item map of a windowed call starts at the first page that
+    holds a key inside the window: a decode step over a context of 40
+    pages of 8 under a window of 16 runs the one step of 8 pages that
+    holds its 16 keys, not five."""
+    ctx = np.array([320, 9, 100])
+    tiles = pick_tiles([1] * 3, 8, 1, 8, 16, 41, 4, window=16)
+    whole = pick_tiles([1] * 3, 8, 1, 8, 16, 41, 4)
+    assert tiles.pages == whole.pages == 8 and whole.grid[2] == 6
+    assert tiles.grid[2] == 2           # (16 + 1 - 2) // 64 + 2
+    assert rect_grid_steps(whole, 3, 1, ctx, 8) == 5 + 1 + 2
+    assert rect_grid_steps(tiles, 3, 1, ctx, 8, window=16) == 1 + 1 + 1
+    # a window that straddles two steps runs both
+    assert rect_grid_steps(tiles, 3, 1, np.array([70, 64, 65]), 8,
+                           window=16) == 2 + 1 + 2
+    ring = pick_tiles([1] * 3, 8, 1, 8, 16, 3, 4, window=16, ring=3)
+    assert ring.pages == 3 and ring.grid[2] == 2
+    # the widths the benchmark's window model runs (PERF.md section 4):
+    # group 8 on 4 kv heads of 128, pages of 128, bf16
+    decode = pick_tiles([1] * 16, 8, 4, 128, 128, 17, 2, window=2048, ring=17)
+    assert (decode.q_tile, decode.heads, decode.pages) == (1, 4, 8)
+    assert decode.grid == (16, 1, 3)
+    prefill = pick_tiles([16384], 8, 4, 128, 128, 128, 2, window=2048)
+    assert (prefill.q_tile, prefill.heads, prefill.pages) == (128, 1, 8)
+    assert prefill.grid == (128, 4, 4)
+
+
+@pytest.mark.parametrize("T,start", [(1, 23), (5, 21), (9, 20), (24, 0)])
+def test_paged_kv_write_wraps_around_the_ring(T, start):
+    """Rows written through a ring of 3 pages of 8 land at their position
+    modulo 24 and touch no other byte; the jnp scatter leaves the same."""
+    from deepspeed_tpu.ops.paged_attention import write_paged
+    rng = np.random.default_rng(T)
+    pool = jnp.asarray(rng.standard_normal((2, 7, 2, 8, 16)), jnp.float32)
+    tables = jnp.asarray([[4, 2, 6], [1, 5, 3]], jnp.int32)
+    new_k = jnp.asarray(rng.standard_normal((2, T, 2, 16)), jnp.float32)
+    new_v = jnp.asarray(rng.standard_normal((2, T, 2, 16)), jnp.float32)
+    starts = jnp.asarray([start, start + 48], jnp.int32)
+    k, v = rpa.paged_kv_write(pool, pool + 1, 1, tables, starts, new_k,
+                              new_v, interpret=True, ring=3)
+    want_k, want_v = np.array(pool), np.array(pool + 1)
+    for b in range(2):
+        for t in range(T):
+            at = (int(starts[b]) + t) % 24
+            want_k[1, tables[b, at // 8], :, at % 8] = new_k[b, t]
+            want_v[1, tables[b, at // 8], :, at % 8] = new_v[b, t]
+    np.testing.assert_array_equal(k, want_k)
+    np.testing.assert_array_equal(v, want_v)
+    scattered = write_paged(PagedKVCache(pool, pool + 1), 1, tables, starts,
+                            new_k, new_v, impl="jnp", ring=3)
+    np.testing.assert_array_equal(scattered.k_pages, want_k)
+    np.testing.assert_array_equal(scattered.v_pages, want_v)
