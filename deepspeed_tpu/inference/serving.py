@@ -372,6 +372,11 @@ class ServingEngine:
         # (ops/paged_attention.py)
         self.attention_impl = resolve_paged_impl(
             attn_impl, getattr(self.config, "attn_logit_softcap", None))
+        # a dropless expert layer's grouped product takes the same
+        # choice (the kernel on TPU or where the backend says so), and no
+        # softcap stands in its way; None for a model without one
+        self.experts_impl = resolve_paged_impl(attn_impl) if getattr(
+            self.config, "moe_dropless", False) else None
         latent = bool(getattr(self.config, "is_latent", False))
         if latent:
             # the latent pools are written and read in XLA whatever the
@@ -379,7 +384,8 @@ class ServingEngine:
             self.attention_impl = "jnp"
         self._paged_call = functools.partial(
             self.model.apply_with_paged_cache,
-            attn_backend=self.attention_impl, attn_interpret=attn_interpret)
+            attn_backend=self.attention_impl, attn_interpret=attn_interpret,
+            expert_backend=self.experts_impl)
         # a model that counts on the device what a dispatch did (keys
         # selected, expert pairs: transformer.SERVE_COUNTERS) is told
         # which rows are tokens and hands the counts back beside the
@@ -1372,6 +1378,9 @@ class ServingEngine:
                   "kernel_grid_full": kernel_grid_full, "kv_write": kv_write,
                   "head_rows": head_rows, **sizes, **counted,
                   "t0_ns": t0_ns, "t1_ns": time.perf_counter_ns()}
+        if self.experts_impl and config is None:
+            # what the target model's expert layers compiled to
+            record["experts"] = self.experts_impl
         self._report["dispatches"].append(record)
         if self._counted and fn in (self._prefill_fn, self._step_fn):
             # the model's own counters, still on the device: they land in

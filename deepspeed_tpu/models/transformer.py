@@ -334,20 +334,29 @@ class Indexer(NamedTuple):
 # vector beside the logits): keys attended and causal keys in context,
 # summed over the REAL queries and the layers; (real token, expert) pairs
 # computed here, summed over expert layers; the fullest held expert of any
-# layer
+# layer; the rows the grouped expert product computed for those pairs, tile
+# padding included, summed over expert layers
 SERVE_COUNTERS = ("selected", "context_keys", "expert_pairs",
-                  "expert_load_max")
+                  "expert_load_max", "expert_rows")
 
 
 class ServeCounts:
     """One serving dispatch's account of itself, filled while it is
     traced: ``real`` [B, T] says which rows are tokens (not bucket padding,
     not an idle slot of a decode batch), the layers add their traced
-    counts (``expert_load_max`` keeps the largest)."""
+    counts (``expert_load_max`` keeps the largest).  ``expert_impl`` /
+    ``interpret``: what the dispatch's expert layers run their grouped
+    product on (``dropless_held_experts``)."""
 
-    def __init__(self, real):
+    def __init__(self, real, expert_impl=None, interpret=False):
         self.real = real
+        self.expert_impl = expert_impl
+        self.interpret = interpret
         self.counts = {}
+
+    def fresh(self):
+        """The same dispatch, nothing counted yet (a scan's iteration)."""
+        return ServeCounts(self.real, self.expert_impl, self.interpret)
 
     def add(self, **counts):
         for name, value in counts.items():
@@ -372,7 +381,7 @@ class ServeCounts:
 def _hold_expert_stack(stacked_layer):
     """A stacked layer [n, ...] as (what a scan over it may cut a layer
     out of, its dropless experts' leaves, kept whole): the held experts'
-    weights are most of a layer, and the expert loops index a stack in
+    weights are most of a layer, and the grouped product reads a stack in
     place (``dropless_held_experts(layer=)``)."""
     moe = stacked_layer.get("moe")
     if not moe:
@@ -1243,9 +1252,11 @@ class CausalTransformerLM:
             # pairs are neither computed nor counted
             chosen = jnp.where(counts.real.reshape(B * T, 1), chosen, -1)
         with jax.named_scope("experts"):
-            out, load = dropless_held_experts(
+            out, load, rows = dropless_held_experts(
                 flat, chosen, weights, moe, _ACTIVATIONS[c.activation],
-                first=c.moe_experts_first, layer=moe.get("stack_layer"))
+                first=c.moe_experts_first, layer=moe.get("stack_layer"),
+                impl=None if counts is None else counts.expert_impl,
+                interpret=counts is not None and counts.interpret)
         out = out.astype(h.dtype)
         if "shared" in moe:
             with jax.named_scope("shared_expert"):
@@ -1255,7 +1266,7 @@ class CausalTransformerLM:
                              ) @ sh["w_down"]
         if counts is not None:
             counts.add(expert_pairs=jnp.sum(load),
-                       expert_load_max=jnp.max(load))
+                       expert_load_max=jnp.max(load), expert_rows=rows)
         return out.reshape(B, T, d), jnp.float32(0.0)
 
     @jax.named_scope("mlp")
@@ -1591,7 +1602,7 @@ class CausalTransformerLM:
     def apply_with_paged_cache(self, params, input_ids, caches, block_tables,
                                lengths, *, attn_backend=None,
                                attn_interpret=False, real_lengths=None,
-                               head_rows=None):
+                               head_rows=None, expert_backend=None):
         """Forward over paged KV caches: appends the T new tokens of every
         sequence at ``lengths`` (tables must already map the pages) and
         attends over each sequence's ragged prefix.  Returns
@@ -1608,8 +1619,11 @@ class CausalTransformerLM:
         fused ragged kernel; interpret runs the kernel on CPU) — static
         kwargs, so the serving engine binds them before jit.  A latent
         model's pools are read and written in XLA whatever the backend
-        (``mix_latent``).  A model that counts its serving dispatches
-        (``config.counts_serving``) returns a fourth result, the
+        (``mix_latent``).  ``expert_backend`` ("pallas" | "jnp" | None =
+        auto) is what a dropless expert layer's grouped product runs on
+        (``moe/sharded_moe.py:dropless_held_experts``; the kernel's
+        interpreter with ``attn_interpret``).  A model that counts its
+        serving dispatches (``config.counts_serving``) returns a fourth result, the
         dispatch's ``SERVE_COUNTERS`` as one int32 vector, over the real
         rows: the first ``real_lengths`` [B] of each sequence's T (all
         without it; a bucket's padding and a decode batch's idle slots
@@ -1627,7 +1641,8 @@ class CausalTransformerLM:
         if c.counts_serving:
             counts = ServeCounts(
                 jnp.ones((B, T), bool) if real_lengths is None
-                else jnp.arange(T)[None, :] < real_lengths[:, None])
+                else jnp.arange(T)[None, :] < real_lengths[:, None],
+                expert_backend, attn_interpret)
         ring_mix = {}       # window -> what its layers' mixer is bound to
         if c.is_latent:
             paged = dict(block_tables=block_tables, lengths=lengths,
@@ -1720,16 +1735,15 @@ class CausalTransformerLM:
                 stride = [kinds.count(kind) for kind in kinds]
 
                 # the experts' weights stay stacked, out of the scanned
-                # operands: the expert loops read period ``p``'s in place
-                # (``stack_layer``); cut out by the scan, all of a layer's
+                # operands: the grouped product reads period ``p``'s in
+                # place (``stack_layer``); cut out by the scan, all of a layer's
                 # experts would be copied every iteration
                 scanned, stacks = zip(*map(_hold_expert_stack,
                                            params["periods"]))
 
                 def one_period(carry, inp):
                     layers, p = inp
-                    inner = None if counts is None else \
-                        ServeCounts(counts.real)
+                    inner = None if counts is None else counts.fresh()
                     for j, layer in enumerate(layers):
                         if stacks[j]:
                             layer = dict(layer, moe=dict(

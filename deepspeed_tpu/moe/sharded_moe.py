@@ -384,19 +384,12 @@ def moe_layer_forward(gate: TopKGate, gate_params, expert_params, expert_fn,
 # ----------------------------------------------------------------------
 # dropless routing and a chip's share of the experts
 # ----------------------------------------------------------------------
-DROPLESS_TILE = 512     # rows of one grouped-product step
-
-
-def vmem_bytes():
-    """A core's VMEM on the chip this process runs on: XLA may keep there
-    an array no larger (``dropless_held_experts`` asks).  With no TPU to
-    ask (the CPU tests, a compile for a described chip) a v5e's, the chip
-    this repo compiles for where none is attached."""
-    from jax.experimental.pallas import tpu as pltpu
-    try:
-        return pltpu.get_tpu_info().vmem_capacity_bytes
-    except ValueError:      # no TPU, or a kind this JAX does not know
-        return 128 * 2 ** 20
+# Columns of the terms that the tokens gather back at a time
+# (``dropless_held_experts``): a chunk's rows of so many columns fit the
+# v5e's VMEM, where XLA then keeps them for the ``k`` gathers (out of HBM a
+# gather of rows runs at a seventh of the chip's bandwidth: GLM-5's 6,144
+# columns at once took 0.77 ms a gather of 8,192 rows, 0.05 in three blocks)
+COMBINE_COLS = 2048
 
 
 def dropless_route(h, wg, bias, k, scoring="sigmoid", scale=1.0, norm=True,
@@ -425,69 +418,110 @@ def dropless_route(h, wg, bias, k, scoring="sigmoid", scale=1.0, norm=True,
 
 
 def dropless_held_experts(h, chosen, weights, experts, act, first=0,
-                          tile=DROPLESS_TILE, layer=None):
+                          tile=None, layer=None, impl=None, interpret=False):
     """The held experts' part of a dropless expert layer: ``sum_e w_e
     GLU_e(h)`` over the (token, expert) pairs whose expert this chip
     holds — experts ``first .. first + E_held - 1`` of the ids in
     ``chosen``, along the leading axis of ``experts["w_gate" | "w_up" |
     "w_down"]``; pairs of the other experts are other chips' terms and
-    are left out.
+    are left out.  h: [N, d]; returns (out [N, d] float32, load [E_held]
+    int32 pairs per held expert, the rows computed, tile padding
+    included).
 
-    A grouped product with no capacity: the pairs are sorted by expert,
-    and each held expert runs over ITS rows in steps of ``tile`` (a loop
-    whose trip count is the expert's load, so an expert nobody chose
-    reads no weights and no token is ever dropped).  h: [N, d]; returns
-    (out [N, d] float32, load [E_held] int32 pairs per held expert).
+    ONE grouped product with no capacity.  The pairs are sorted by expert
+    and each expert's group is padded to whole row tiles, so a tile has
+    one expert; the kernel (``ops/pallas/grouped_expert_glu.py``) runs the
+    tiles that hold pairs and finds each tile's weights in the leaves by
+    its expert's index: an expert nobody chose reads no weights, nothing
+    is sliced out of a leaf, no token is ever dropped.  The sorted list is
+    at worst ``N * k`` rows and is never laid out: it runs in chunks of
+    the rows one pair a token would fill (with every expert's padding), as
+    many as hold pairs, one in the common case.  A chunk gathers its rows
+    of ``h``, the kernel writes each pair's term at its sorted place, and
+    each token gathers its ``k`` terms back and sums them with its weights
+    in float32 (a pair outside the chunk, or of an expert held elsewhere,
+    adds nothing; ``COMBINE_COLS`` columns at a time): no scatter anywhere (docs/serving.md, "The dropless
+    expert layer").
 
-    With ``layer`` (may be traced) the expert leaves are a STACK of
-    layers' experts, [L, E_held, ...], and that layer's are read in place
-    (a scan over stacked layers that cut a layer's experts out ahead of
-    the loops would copy all of them every iteration)."""
+    ``tile``: the row tile (default: from the shapes,
+    ``pick_expert_tiles``).  With ``layer`` (may be traced) the expert
+    leaves are a STACK of layers' experts, [L, E_held, ...], and that
+    layer's are read in place.  ``impl`` / ``interpret``: as
+    ``ops/decode_attention.py:use_pallas`` reads them (None: the kernel on
+    a TPU, the same product in jnp elsewhere)."""
+    from deepspeed_tpu.ops.decode_attention import use_pallas
+    from deepspeed_tpu.ops.pallas import grouped_expert_glu as glu
     N, d = h.shape
     k = chosen.shape[1]
-    held = experts["w_up"].shape[-3]
-    tile = min(tile, N)
+    held, _, f = experts["w_up"].shape[-3:]
+    tiles = glu.pick_expert_tiles(N, held, d, f, h.dtype.itemsize, rows=tile)
+    tile = tiles.rows
+    product = glu.grouped_expert_glu if use_pallas(impl) \
+        else glu.grouped_glu_jnp
+
     flat = chosen.reshape(-1) - first
     mine = (flat >= 0) & (flat < held)
-    order = jnp.argsort(jnp.where(mine, flat, held), stable=True)
-    token = jnp.pad((jnp.arange(N * k, dtype=jnp.int32) // k)[order],
-                    (0, tile))
-    weight = jnp.pad(jnp.where(mine, weights.reshape(-1), 0.0)[order],
-                     (0, tile))
-    load = jnp.sum(flat[:, None] == jnp.arange(held)[None, :], axis=0,
+    expert = jnp.where(mine, flat, held)
+    load = jnp.sum(expert[:, None] == jnp.arange(held)[None, :], axis=0,
                    dtype=jnp.int32)
-    begin = jnp.cumsum(load) - load     # an expert's first sorted row
+    begin = jnp.cumsum(load) - load         # an expert's first sorted pair
+    group = -(-load // tile)                # its row tiles
+    end = jnp.cumsum(group)                 # ... where they end
+    start = end - group                     # ... and begin
+    n_tiles = end[-1]
+    # the expert of every tile the padded list can have
+    tile_expert = jnp.minimum(held - 1, jnp.sum(
+        jnp.arange(-(-N * k // tile) + held)[:, None] >= end[None, :],
+        axis=1, dtype=jnp.int32))
+    # sorted place -> pair, and each pair's row in the padded list
+    pairs = jnp.arange(N * k, dtype=jnp.int32)
+    expert_sorted, order = jax.lax.sort((expert, pairs), num_keys=1)
+    at = jnp.minimum(expert_sorted, held - 1)
+    row_sorted = start[at] * tile + pairs - begin[at]
+    _, row = jax.lax.sort((order, row_sorted), num_keys=1)
+    row = row.reshape(N, k)
+    mine = mine.reshape(N, k)
+    weights = weights.astype(jnp.float32)
 
-    # An ``out`` that fits VMEM, XLA may keep there, and its scatter-add
-    # into such an ``out``, which sorts every step's indices first (they
-    # repeat: a step's rows past its expert's load are the next experts'
-    # pairs, weight 0, often of the same tokens), halted the v5e
-    # (docs/serving.md, "The dropless expert layer's scatter").  There the
-    # rows past the load go to ``tile`` spare rows past the tokens', each
-    # to its own: a step meets every row at most once, ascending (a stable
-    # sort keeps an expert's tokens ascending), says so, and XLA scatters
-    # without the sort.  A larger ``out`` stays in HBM, where the sorted
-    # scatter is sound and the faster of the two.
-    spare = (N + tile) * d * 4 <= vmem_bytes()
-    out = jnp.zeros((N + tile if spare else N, d), jnp.float32)
-    for e in range(held):
-        def rows(i, out, e=e):
-            at = begin[e] + i * tile
-            idx = jax.lax.dynamic_slice(token, (at,), (tile,))
-            live = i * tile + jnp.arange(tile) < load[e]
-            w = jnp.where(live, jax.lax.dynamic_slice(weight, (at,), (tile,)),
-                          0.0)
-            x = h[idx]
-            # the expert's weights are cut out of the stack INSIDE the
-            # loop: cut outside it they are copied whether it runs or not
-            this = e if layer is None else (layer, e)
-            y = (act(x @ experts["w_gate"][this])
-                 * (x @ experts["w_up"][this])) @ experts["w_down"][this]
-            y = y.astype(jnp.float32) * w[:, None]
-            if not spare:
-                return out.at[idx].add(y)
-            return out.at[jnp.where(live, idx, N + jnp.arange(tile))].add(
-                y, indices_are_sorted=True, unique_indices=True)
+    # a chunk: the rows that one pair a token fills, every expert padded
+    chunk_tiles = -(-N // tile) + held
+    token_sorted = order // k
+    in_tile = jnp.arange(tile)[None, :]
 
-        out = jax.lax.fori_loop(0, -(-load[e] // tile), rows, out)
-    return (out[:N] if spare else out), load
+    def chunk(c, out):
+        base = c * chunk_tiles
+        # each of the chunk's tiles: its expert, its first sorted pair and
+        # how many of its rows hold one
+        t = jnp.minimum(base + jnp.arange(chunk_tiles),
+                        tile_expert.shape[0] - 1)
+        e = tile_expert[t]
+        before = (t - start[e]) * tile
+        at = jnp.minimum((begin[e] + before)[:, None] + in_tile, N * k - 1)
+        token = jnp.where(in_tile < (load[e] - before)[:, None],
+                          token_sorted[at], 0)
+        x = h[token.reshape(-1)]
+        y = product(x, experts["w_gate"], experts["w_up"],
+                    experts["w_down"], tile_expert,
+                    jnp.minimum(n_tiles - base, chunk_tiles), act, tiles,
+                    layer=layer, base=base, interpret=interpret)
+        # each token's terms of this chunk, back at the token, a block of
+        # columns at a time (``COMBINE_COLS``)
+        local = row - base * tile
+        here = mine & (local >= 0) & (local < chunk_tiles * tile)
+        local = jnp.clip(local, 0, chunk_tiles * tile - 1)
+        blocks = []
+        for b, block in enumerate(out):
+            part = y[:, b * cols:(b + 1) * cols]
+            for j in range(k):
+                block = block + jnp.where(
+                    here[:, j, None],
+                    part[local[:, j]].astype(jnp.float32)
+                    * weights[:, j, None], 0.0)
+            blocks.append(block)
+        return tuple(blocks)
+
+    cols = COMBINE_COLS if d % COMBINE_COLS == 0 else d
+    out = jax.lax.fori_loop(
+        0, -(-n_tiles // chunk_tiles), chunk,
+        tuple(jnp.zeros((N, cols), jnp.float32) for _ in range(d // cols)))
+    return jnp.concatenate(out, axis=1), load, n_tiles * tile

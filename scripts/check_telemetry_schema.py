@@ -201,7 +201,7 @@ SPAN_NAMES = (
 # model's serving programs (``deepspeed_tpu.monitor.telemetry.
 # SERVE_SCOPES``).  A tier-1 test diffs both.
 SERVE_COUNTERS = ("selected", "context_keys", "expert_pairs",
-                  "expert_load_max")
+                  "expert_load_max", "expert_rows")
 SERVE_SCOPES = ("select", "latent_attn", "router", "experts",
                 "shared_expert", "attn_window", "attn_full", "attn_gate")
 # FROZEN: what a model with sliding-window layers adds to the same

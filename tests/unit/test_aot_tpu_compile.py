@@ -241,6 +241,12 @@ DISPATCHES = [
 ]
 
 
+# what may hold a pool or a weight stack without copying it: plumbing, the
+# layer loop, and the kernels that read and write in place
+IN_PLACE_OPS = {"parameter", "get-tuple-element", "tuple", "while",
+                "bitcast", "tpu_custom_call"}
+
+
 def _pool_shaped(text, layers, shapes=None):
     """(name, opcode) of every instruction of the compiled text whose
     result holds a pool-shaped or stack-shaped array (or one of
@@ -331,11 +337,9 @@ def test_serving_dispatch_never_copies_the_page_pools(
         lowered = _lower_dispatch(olmo2, jit_name, batch, tokens)
     compiled = lowered.compile()
     text = compiled.as_text()
-    allowed = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
-               "tpu_custom_call"}
     found = _pool_shaped(text, layers)
     assert found, "the pools are not in the compiled text"
-    assert not [f for f in found if f[1] not in allowed]
+    assert not [f for f in found if f[1] not in IN_PLACE_OPS]
     assert "paged_kv_write" in text
     assert ("ragged_paged_attention_decode"
             if tokens == 1 or jit_name == "chunk"
@@ -345,16 +349,17 @@ def test_serving_dispatch_never_copies_the_page_pools(
         assert temp < ONE_LAYER_POOL_BYTES, temp
 
 
-@pytest.mark.parametrize("batch,tokens", [(16, 1), (1, 4096)],
-                         ids=["decode_b16", "prefill_4096"])
-def test_window_model_dispatch_never_copies_either_stack(topo, batch,
-                                                         tokens):
-    """The same guard for a model with window layers (Trinity-Mini's
-    widths, 8 of its layers: 4 leading and one scanned period, 4 of 128
-    experts held): neither the full-attention stack nor the ring stack is
-    sliced, re-laid or copied by a dispatch; a prefill longer than the
-    ring (4,096 rows into 17 pages of 128) gathers its tail out of the new
-    rows, not out of a pool."""
+WINDOW_DISPATCHES = {"decode_b16": (16, 1), "prefill_4096": (1, 4096)}
+WINDOW_SIZES = dict(pages=257, slots=16, ring=17, held=16)
+
+
+@pytest.fixture(scope="module")
+def window_dispatch(topo):
+    """Each of ``WINDOW_DISPATCHES`` of a model with window layers and a
+    share of dropless experts (Trinity-Mini's widths, 8 of its layers: 4
+    leading and one scanned period, 16 of 128 experts held), compiled once
+    for the tests that read its text."""
+    import functools
     import json
     import os
 
@@ -363,7 +368,7 @@ def test_window_model_dispatch_never_copies_either_stack(topo, batch,
                            "configs", "trinity-mini-ep8.json")) as f:
         cfg = json.load(f)
     cfg.update(num_hidden_layers=8, layer_types=cfg["layer_types"][:8],
-               num_experts=4, vocab_size=1024)
+               num_experts=WINDOW_SIZES["held"], vocab_size=1024)
     chip = SingleDeviceSharding(topo.devices[0])
     model = CausalTransformerLM(TransformerConfig(
         **afmoe.transformer_kwargs(cfg)))
@@ -375,7 +380,8 @@ def test_window_model_dispatch_never_copies_either_stack(topo, batch,
     def ints(*shape):
         return _on(chip, shape, jnp.int32)
 
-    pages, slots, ring = 257, 16, 17
+    pages, slots, ring = (WINDOW_SIZES[k] for k in ("pages", "slots",
+                                                    "ring"))
     params = on_chip(jax.eval_shape(
         lambda: model.init(jax.random.key(0), jnp.bfloat16)))
     caches = on_chip(jax.eval_shape(
@@ -384,24 +390,74 @@ def test_window_model_dispatch_never_copies_either_stack(topo, batch,
     def serve(params, ids, caches, tables, lengths, real, *rows):
         return model.apply_with_paged_cache(
             params, ids, caches, tables, lengths, attn_backend="pallas",
-            real_lengths=real, **dict(zip(("head_rows",), rows)))
+            expert_backend="pallas", real_lengths=real,
+            **dict(zip(("head_rows",), rows)))
 
-    compiled = jax.jit(serve, donate_argnums=(2,)).lower(
-        params, ints(batch, tokens), caches, ints(batch, 129 + ring),
-        ints(batch), ints(batch),
-        *([ints(batch, 1)] if tokens > 1 else [])).compile()
+    @functools.lru_cache(maxsize=None)
+    def compiled(name):
+        batch, tokens = WINDOW_DISPATCHES[name]
+        return jax.jit(serve, donate_argnums=(2,)).lower(
+            params, ints(batch, tokens), caches, ints(batch, 129 + ring),
+            ints(batch), ints(batch),
+            *([ints(batch, 1)] if tokens > 1 else [])).compile()
+
+    return compiled
+
+
+@pytest.mark.parametrize("name", WINDOW_DISPATCHES)
+def test_window_model_dispatch_never_copies_either_stack(window_dispatch,
+                                                         name):
+    """The same guard for a model with window layers: neither the
+    full-attention stack nor the ring stack is sliced, re-laid or copied
+    by a dispatch; a prefill longer than the ring (4,096 rows into 17
+    pages of 128) gathers its tail out of the new rows, not out of a
+    pool."""
+    compiled = window_dispatch(name)
+    tokens = WINDOW_DISPATCHES[name][1]
+    pages, slots, ring = (WINDOW_SIZES[k] for k in ("pages", "slots",
+                                                    "ring"))
     text = compiled.as_text()
     found = {op for _, op in _pool_shaped(text, None, [
         f"bf16[{layers},{n},4,128,128]"
         for layers, n in ((2, pages), (6, slots * ring + 1))])}
-    assert found and found <= {"parameter", "get-tuple-element", "tuple",
-                               "while", "bitcast", "tpu_custom_call"}, found
+    assert found and found <= IN_PLACE_OPS, found
     assert "paged_kv_write" in text and " while(" in text
     assert ("ragged_paged_attention_decode" if tokens == 1
             else "ragged_paged_attention_prefill") in text
     smaller = 2 * 2 * slots * ring * 4 * 128 * 128      # one ring layer
     assert compiled.memory_analysis().temp_size_in_bytes < (
         smaller if tokens == 1 else 1 << 30)
+
+
+@pytest.mark.parametrize("name", WINDOW_DISPATCHES)
+def test_expert_layer_never_copies_an_expert_or_loops_over_them(
+        window_dispatch, name):
+    """The guard that keeps the copies of the held experts from coming
+    back unseen on a CPU-only check (PERF.md §6, PR 38: ``w_gate[layer,
+    e]`` and ``w_up[layer, e]`` of every held expert, cut out ahead of a
+    ``while`` an expert and copied whether it ran or not, were a sixth of
+    a cell's busy time).  The expert layer is ONE kernel call that finds
+    its weights in the stacked leaves: nothing but parameters, tuple
+    plumbing, the loops, bitcasts and the kernel has a result of a leaf's
+    shape; nothing under the ``experts`` scope has one expert's (the
+    shared expert, of the same widths, is not under it); and the loops
+    are the scan over the periods and one of chunks an expert layer, not
+    one an expert."""
+    import re
+    text = window_dispatch(name).as_text()
+    held = WINDOW_SIZES["held"]
+    leaves = [f"bf16[{lead}{held},{shape}]" for lead in ("", "1,")
+              for shape in ("2048,1024", "1024,2048")]
+    found = {op for _, op in _pool_shaped(text, None, leaves)}
+    assert found and found <= IN_PLACE_OPS, found
+    one = re.compile(r" = \(?bf16\[(1,)*(2048,1024|1024,2048)\]")
+    assert not [line[:120] for line in text.splitlines()
+                if one.search(line) and "/experts/" in line]
+    assert "grouped_expert_glu" in text
+    # 2 listed expert layers and the period's 4: a loop of chunks each,
+    # and the scan, where a single period keeps one (the parent: 16 more
+    # a layer, one an expert)
+    assert 6 <= text.count(" while(") <= 1 + 2 + 4
 
 
 BUCKET_LOGITS = "f32[1,4096,100352]"      # 1.64 GB: every row of a bucket

@@ -175,7 +175,7 @@ def test_no_token_is_dropped_when_every_token_goes_to_one_expert(params):
         h, moe["wg"], bias, 4, scale=2.5)
     assert np.all(np.asarray(chosen) == [3, 0, 9, 1])
     np.testing.assert_allclose(weights.sum(-1), 2.5, rtol=1e-6)
-    out, load = sharded_moe.dropless_held_experts(
+    out, load, rows = sharded_moe.dropless_held_experts(
         h, chosen, weights, moe, jax.nn.silu, tile=16)
     assert load.tolist() == [50, 50, 0, 50] + [0] * 5 + [50] + [0] * 6
     want = sum(weights[:, j:j + 1] * _glu(h, moe, e)
@@ -183,36 +183,30 @@ def test_no_token_is_dropped_when_every_token_goes_to_one_expert(params):
     np.testing.assert_allclose(out, want, atol=1e-5)
 
 
-def test_a_step_past_its_experts_load_adds_nothing_to_any_token(params,
-                                                                  monkeypatch):
+def test_a_step_past_its_experts_load_adds_nothing_to_any_token(params):
     """Tokens 0 and 1 choose experts 0 and 1, tokens 2 and 3 experts 1 and
-    2: expert 0's step of 4 rows holds its own two pairs and then expert
-    1's pairs of the SAME two tokens.  Those rows go to spare rows past the
-    tokens' (the scatter is told it meets each row once, ascending), so
-    each token still gets exactly its own terms."""
+    2: expert 0's tile of 4 rows holds its own two pairs and two rows of
+    padding, which no token reads back, so each token still gets exactly
+    its own terms; and nothing is scattered: every pair's term is written
+    at its sorted place and gathered back by its token."""
     moe = params["layers"][1]["moe"]
     h = jax.random.normal(jax.random.key(3), (4, 64))
     chosen = jnp.asarray([[0, 1], [0, 1], [1, 2], [1, 2]], jnp.int32)
     weights = jax.random.uniform(jax.random.key(4), (4, 2), minval=0.5)
-    out, load = sharded_moe.dropless_held_experts(
+    out, load, rows = sharded_moe.dropless_held_experts(
         h, chosen, weights, moe, jax.nn.silu, tile=4)
     assert out.shape == h.shape and load.tolist()[:4] == [2, 4, 2, 0]
+    assert int(rows) == 12      # a tile each, rows of padding included
     want = jnp.stack([sum(weights[t, j] * _glu(h[t], moe, int(chosen[t, j]))
                           for j in range(2)) for t in range(4)])
     np.testing.assert_allclose(out, want, atol=1e-5)
-    def text():
-        return str(jax.make_jaxpr(lambda: sharded_moe.dropless_held_experts(
-            h, chosen, weights, moe, jax.nn.silu, tile=4))())
-
-    assert "unique_indices=True" in text() and \
-        "indices_are_sorted=True" in text()
-    # an ``out`` too large for VMEM keeps the plain scatter-add: the same
-    # terms, the repeated rows added as they come
-    monkeypatch.setattr(sharded_moe, "vmem_bytes", lambda: 0)
-    assert "unique_indices=True" not in text()
-    plain, _ = sharded_moe.dropless_held_experts(
-        h, chosen, weights, moe, jax.nn.silu, tile=4)
-    np.testing.assert_allclose(plain, want, atol=1e-5)
+    for impl in ("jnp", "pallas"):
+        text = str(jax.make_jaxpr(
+            lambda: sharded_moe.dropless_held_experts(
+                h, chosen, weights, moe, jax.nn.silu, tile=4, impl=impl,
+                interpret=True))())
+        assert "scatter" not in text and "gather" in text
+        assert ("pallas_call" in text) == (impl == "pallas")
 
 
 def test_dropless_is_not_the_scoring_a_softmax_router_serves_it_too():
@@ -320,6 +314,9 @@ def test_engine_serves_counts_and_leaks_nothing_over_both_pools(model,
         assert d["selected"] == layers * sum(min(k, c) for c in contexts)
         assert d["expert_pairs"] <= expert_layers * len(contexts) * \
             CFG["num_experts_per_tok"]
+        # the grouped product's rows: the pairs in whole tiles of 16
+        assert d["expert_rows"] >= d["expert_pairs"]
+        assert d["expert_rows"] % 16 == 0 and d["experts"] == "jnp"
     decode = [d for d in dispatches if d["phase"] == "decode"][0]
     assert decode["expert_load_max"] <= 4 < decode["expert_pairs"] + 5
     spans = [s for s in engine.telemetry.spans(mark)

@@ -205,8 +205,14 @@ def test_every_dispatch_carries_the_window_models_counts(model, params):
     while engine.n_active:
         engine.step()
         dispatches += engine.last_step["dispatches"]
-    want = set(serving.WINDOW_COUNTS) | {"expert_pairs", "expert_load_max"}
+    want = set(serving.WINDOW_COUNTS) | {"expert_pairs", "expert_load_max",
+                                         "expert_rows"}
     assert all(want <= set(d) and "selected" not in d for d in dispatches)
+    # the grouped product's rows: every pair, in whole tiles (16 rows at
+    # these sizes); the CPU's implementation by name
+    assert all(d["expert_rows"] >= d["expert_pairs"]
+               and d["expert_rows"] % 16 == 0 and d["experts"] == "jnp"
+               for d in dispatches)
     prefill = next(d for d in dispatches if d["phase"] == "prefill")
     # 40 real queries of a 64 bucket: 8 layers see 1 + .. + 40 causal keys,
     # the 6 window layers at most 16 of them
@@ -323,7 +329,8 @@ def test_three_periods_scan_and_count_like_the_unrolled_loop():
     other = _engine(listed, dict(params, layers=scanned.layer_list(params)))
     other.add_request(0, list(range(37)), max_new_tokens=30)
     engine.add_request(1, list(range(37)), max_new_tokens=30)
-    for name in ("expert_pairs", "expert_load_max", "attended_keys"):
+    for name in ("expert_pairs", "expert_load_max", "expert_rows",
+                 "attended_keys"):
         assert engine._report["dispatches"][0][name] == \
             other._report["dispatches"][0][name]
 
