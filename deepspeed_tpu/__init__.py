@@ -10,25 +10,34 @@ __version__ = "0.1.0"
 __git_hash__ = None
 __git_branch__ = None
 
-from deepspeed_tpu.accelerator import get_accelerator, set_accelerator  # noqa: F401
-from deepspeed_tpu import comm  # noqa: F401
-from deepspeed_tpu.comm.comm import init_distributed  # noqa: F401
-from deepspeed_tpu.runtime.config import DeepSpeedConfig, DeepSpeedConfigError  # noqa: F401
-from deepspeed_tpu.runtime import zero  # noqa: F401
-from deepspeed_tpu.utils.init_on_device import OnDevice  # noqa: F401
-from deepspeed_tpu.utils.logging import logger, log_dist  # noqa: F401
-from deepspeed_tpu import module_inject, ops  # noqa: F401
-from deepspeed_tpu.runtime import DeepSpeedOptimizer, ZeROOptimizer  # noqa: F401
-from deepspeed_tpu.runtime.engine import DeepSpeedEngine  # noqa: F401
-from deepspeed_tpu.runtime.pipe.engine import PipelineEngine  # noqa: F401
-from deepspeed_tpu.inference.engine import InferenceEngine  # noqa: F401
-from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig  # noqa: F401
-from deepspeed_tpu.runtime.lr_schedules import add_tuning_arguments  # noqa: F401
-from deepspeed_tpu.runtime.activation_checkpointing import checkpointing  # noqa: F401
-from deepspeed_tpu.ops.transformer import (DeepSpeedTransformerLayer,  # noqa: F401
-                                           DeepSpeedTransformerConfig)
-from deepspeed_tpu.module_inject import (replace_transformer_layer,  # noqa: F401
-                                         revert_transformer_layer)
+import time as _time
+
+_import_began_ns = _time.perf_counter_ns()     # setup/import starts here
+
+from deepspeed_tpu.monitor.telemetry import setup_span as _setup_span  # noqa: E402
+
+# everything the package pulls in, JAX included when this is the first
+# import of it, is one ``setup/import`` span of the compile account
+with _setup_span("setup/import", since_ns=_import_began_ns):
+    from deepspeed_tpu.accelerator import get_accelerator, set_accelerator  # noqa: F401
+    from deepspeed_tpu import comm  # noqa: F401
+    from deepspeed_tpu.comm.comm import init_distributed  # noqa: F401
+    from deepspeed_tpu.runtime.config import DeepSpeedConfig, DeepSpeedConfigError  # noqa: F401
+    from deepspeed_tpu.runtime import zero  # noqa: F401
+    from deepspeed_tpu.utils.init_on_device import OnDevice  # noqa: F401
+    from deepspeed_tpu.utils.logging import logger, log_dist  # noqa: F401
+    from deepspeed_tpu import module_inject, ops  # noqa: F401
+    from deepspeed_tpu.runtime import DeepSpeedOptimizer, ZeROOptimizer  # noqa: F401
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine  # noqa: F401
+    from deepspeed_tpu.runtime.pipe.engine import PipelineEngine  # noqa: F401
+    from deepspeed_tpu.inference.engine import InferenceEngine  # noqa: F401
+    from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig  # noqa: F401
+    from deepspeed_tpu.runtime.lr_schedules import add_tuning_arguments  # noqa: F401
+    from deepspeed_tpu.runtime.activation_checkpointing import checkpointing  # noqa: F401
+    from deepspeed_tpu.ops.transformer import (DeepSpeedTransformerLayer,  # noqa: F401
+                                               DeepSpeedTransformerConfig)
+    from deepspeed_tpu.module_inject import (replace_transformer_layer,  # noqa: F401
+                                             revert_transformer_layer)
 
 
 def initialize(args=None,

@@ -22,6 +22,7 @@ import numpy as np
 
 from deepspeed_tpu import comm as dist
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+from deepspeed_tpu.monitor.telemetry import in_setup_span
 from deepspeed_tpu.ops.decode_attention import init_cache
 from deepspeed_tpu.parallel import groups
 from deepspeed_tpu.parallel.topology import TP_AXIS, TopologyConfig
@@ -38,6 +39,7 @@ class InferenceEngine:
     """Wraps a model (our ``CausalTransformerLM`` or any object exposing
     ``apply_with_cache``/``init_caches``) for sharded generation."""
 
+    @in_setup_span("setup/engine", kind="inference")
     def __init__(self, model, config: DeepSpeedInferenceConfig, params=None,
                  mesh=None):
         self.module = model
@@ -68,6 +70,7 @@ class InferenceEngine:
                  f"tp={config.tp_size} mesh={dict(self.mesh.shape)}", ranks=[0])
 
     # ------------------------------------------------------------------
+    @in_setup_span("setup/engine/weights")
     def set_params(self, params):
         """Cast + shard weights (reference dtype convert + weight slicing in
         module_inject; here: device_put with TP/fsdp shardings).

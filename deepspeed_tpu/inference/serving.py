@@ -51,8 +51,8 @@ from deepspeed_tpu.inference.prefix_cache import PrefixCache, PrefixMatch
 from deepspeed_tpu.inference.scheduler import SLO_CLASSES, create_scheduler
 from deepspeed_tpu.models.transformer import SERVE_COUNTERS
 from deepspeed_tpu.monitor.attribution import RequestAttributor
-from deepspeed_tpu.monitor.telemetry import (get_telemetry,
-                                             register_compiled)
+from deepspeed_tpu.monitor.telemetry import (get_telemetry, in_setup_span,
+                                             register_compiled, setup_span)
 from deepspeed_tpu.ops.paged_attention import (PageAllocationError,
                                                PagedAllocator,
                                                resolve_attention_backend,
@@ -214,6 +214,7 @@ class ServingEngine:
     their outputs are ignored.
     """
 
+    @in_setup_span("setup/engine", kind="serving")
     def __init__(self, model, params, max_batch: int = 8,
                  page_size: int = 128, num_pages: Optional[int] = None,
                  max_seq: int = 2048, dtype=jnp.bfloat16,
@@ -257,9 +258,10 @@ class ServingEngine:
         # ``tables`` ends in the slot's ring
         window = int(getattr(self.config, "attn_window", 0) or 0)
         self.ring_pages = ring_pages(window, page_size) if window else 0
-        caches = model.init_paged_caches(
-            num_pages, page_size, dtype=dtype,
-            **({"ring_slots": max_batch} if window else {}))
+        with setup_span("setup/engine/pools"):
+            caches = model.init_paged_caches(
+                num_pages, page_size, dtype=dtype,
+                **({"ring_slots": max_batch} if window else {}))
         if ep_size > 1:
             assert getattr(self.config, "is_moe", False), \
                 "ep_size > 1 needs an MoE model"

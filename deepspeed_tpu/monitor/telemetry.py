@@ -21,6 +21,11 @@ its own sink.  Here every subsystem writes into ONE process-local
   names (the names a device trace shows: ``fusion.728``) to the step
   phase (``fwd``, ``bwd``, ``remat``, ``loss_head``, ``optimizer``,
   ``grad_reduce``, ``other``) their ``jax.named_scope`` path says.
+* the compile account — one record for every program JAX traces, lowers
+  and compiles or reads from the persistent cache (``jax.monitoring``),
+  with the site and the span it happened in, and the ``setup/*`` spans of
+  import and engine construction, kept apart from the ring:
+  :meth:`Telemetry.compile_log`, :meth:`Telemetry.startup_report`.
 * :class:`JsonlEventSink` — rank-0-gated JSONL stream with size-based
   rotation.  ``MonitorMaster`` gains it as a fourth writer, so scalar
   monitor events, comm census, HBM gauges, heartbeats and stalls all land
@@ -38,10 +43,13 @@ seconds), ``kind`` and ``name``.  The frozen per-kind schema lives in
 """
 
 import contextlib
+import functools
 import itertools
 import json
+import math
 import os
 import re
+import sys
 import threading
 import time
 import weakref
@@ -73,6 +81,8 @@ SPAN_NAMES = (
     "serve/prefill/sample",
     "serve/decode", "serve/decode/build", "serve/decode/fetch",
     "serve/decode/sample",
+    "setup/import", "setup/engine", "setup/engine/state",
+    "setup/engine/weights", "setup/engine/pools", "compile",
 )
 
 
@@ -124,7 +134,7 @@ class SpanRing:
 # ids are unique in the process and the open span is per thread, whichever
 # Telemetry object a span belongs to: a parent link may cross objects
 _span_ids = itertools.count(1)
-_open = threading.local()     # .top: id of the innermost open span
+_open = threading.local()     # .top: the innermost open span (_OpenSpan)
 
 
 class _OpenSpan:
@@ -133,7 +143,7 @@ class _OpenSpan:
     2 us a span on the CPU, profiler annotation included."""
 
     __slots__ = ("tel", "name", "step", "attrs", "req_id", "id", "parent",
-                 "t0", "_ann")
+                 "t0", "_ann")       # parent: the _OpenSpan around it
 
     def __init__(self, tel, name, step, attrs, req_id):
         self.tel, self.name, self.step = tel, name, step
@@ -141,7 +151,8 @@ class _OpenSpan:
 
     def __enter__(self):
         self.parent = getattr(_open, "top", None)
-        self.id = _open.top = next(_span_ids)
+        _open.top = self
+        self.id = next(_span_ids)
         self._ann = jax.profiler.TraceAnnotation(self.name)
         self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
@@ -150,11 +161,11 @@ class _OpenSpan:
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
         self._ann.__exit__(*exc)
-        _open.top = self.parent
+        parent = _open.top = self.parent
         step = self.step
         tel = self.tel
         tel.ring.record(
-            (self.id, self.parent, self.name, self.t0, t1,
+            (self.id, parent and parent.id, self.name, self.t0, t1,
              step if step is not None else self.req_id, self.attrs))
         if tel.enabled:
             dur_ms = (t1 - self.t0) / 1e6
@@ -295,6 +306,7 @@ class CompiledSite:
         self._fn, self.site, self._mesh = fn, site, mesh
         self._calls = {}        # shapes of the array arguments -> abstract
         self._scopes = {}       # the same key -> parsed table
+        self._compiled = {}     # the same key -> programs the account saw
 
     def __call__(self, *args, **kwargs):
         shapes = tuple(getattr(a, "shape", None) for a in args)
@@ -323,8 +335,8 @@ class CompiledSite:
         key = self._find(arg_shapes)
         return None if key is None else self._text(key)
 
-    def _text(self, key):
-        args, kwargs = self._calls[key]
+    def _text(self, shapes):
+        args, kwargs = self._calls[shapes]
         with (self._mesh if self._mesh is not None
               else contextlib.nullcontext()):
             return self._fn.lower(*args, **kwargs).compile().as_text()
@@ -368,6 +380,233 @@ def op_scopes(site, arg_shapes=None):
     if not found:
         return {}
     return max(found, key=lambda entry: entry.order).op_scopes(arg_shapes)
+
+
+# ----------------------------------------------------------------------
+# the compile account, and the set-up spans
+# ----------------------------------------------------------------------
+_TRACED = "/jax/core/compile/jaxpr_trace_duration"
+_LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+# the frames a compilation is attributed to a site by: found on the stack
+# when JAX reports a backend compile, so a call that compiles nothing pays
+# nothing for it (both have ``self`` and ``shapes`` among their locals)
+_SITE_FRAMES = (CompiledSite.__call__.__code__, CompiledSite._text.__code__)
+
+
+def _ids_upward(span):
+    """Ids of an open span and of those around it, innermost first."""
+    ids = []
+    while span is not None:
+        ids.append(span.id)
+        span = span.parent
+    return tuple(ids)
+
+
+class _Pending(threading.local):
+    """What one thread has traced and lowered since its last record."""
+
+    def __init__(self):
+        self.reset()
+
+    def reset(self):
+        # (t0_ns, seconds) of the traces no later trace has swallowed yet;
+        # bounded for a thread that only ever traces
+        self.traces = deque(maxlen=4096)
+        self.lower_s = self.retrieval_s = 0.0
+        self.cache = "off"
+
+
+class CompileAccount:
+    """Every program JAX compiled or read from the persistent cache in
+    this process, and the ``setup/*`` spans, in a store of their own: a
+    serving run wraps the span ring long before anyone asks how the
+    process started.  Process-wide, as ``_sites`` is, and fed by the one
+    pair of ``jax.monitoring`` listeners registered below.
+
+    A record closes at JAX's backend-compile event (a compilation, or the
+    read when the cache had the program) and takes with it what its
+    thread traced and lowered since the thread's last record: a trace
+    that led to no program (``jax.eval_shape``) is booked to the next
+    one.  ``t0_ns = t1_ns - (trace_s + lower_s + backend_s)``."""
+
+    KEPT = 4096     # records, and set-up spans: the newest of each
+
+    def __init__(self):
+        self.records = deque(maxlen=self.KEPT)
+        self.spans = deque(maxlen=self.KEPT)    # (Span, ids of ancestors)
+        self._pending = _Pending()
+
+    # -- the listeners -------------------------------------------------
+    def on_duration(self, event, secs, fun_name=None, **_kw):
+        pending = self._pending
+        if event == _TRACED:
+            # an inner jit (every jnp function is one) is traced inside
+            # the outer one's trace and reported first, thousands of them
+            # in a model's: the outer interval swallows them all
+            t0 = time.perf_counter_ns() - int(secs * 1e9)
+            traces = pending.traces
+            while traces and traces[-1][0] >= t0:
+                traces.pop()
+            traces.append((t0, secs))
+        elif event == _LOWERED:
+            pending.lower_s += secs
+        elif event == _CACHE_READ:
+            pending.retrieval_s = secs
+        elif event == _BACKEND:
+            self._close(pending, secs, fun_name)
+
+    def on_event(self, event, **_kw):
+        if event == _CACHE_ASKED:
+            # JAX asks its cache whether or not it was given a directory;
+            # with one, a miss until the hit is reported
+            self._pending.cache = \
+                "miss" if jax.config.jax_compilation_cache_dir else "off"
+        elif event == _CACHE_HIT:
+            self._pending.cache = "hit"
+
+    def _close(self, pending, backend_s, name):
+        t1 = time.perf_counter_ns()
+        trace_s = math.fsum(t[1] for t in pending.traces)
+        record = {"t0_ns": t1 - int(
+                      (trace_s + pending.lower_s + backend_s) * 1e9),
+                  "t1_ns": t1, "name": name, "trace_s": trace_s,
+                  "lower_s": pending.lower_s, "backend_s": backend_s,
+                  "cache": pending.cache, "site": None, "shapes": None,
+                  "repeat": False}
+        if pending.cache == "hit":
+            record["retrieval_s"] = pending.retrieval_s
+        pending.reset()
+        frame = sys._getframe()
+        while frame is not None and frame.f_code not in _SITE_FRAMES:
+            frame = frame.f_back
+        if frame is not None:
+            site, shapes = frame.f_locals["self"], frame.f_locals["shapes"]
+            seen = site._compiled.get(shapes, 0)
+            site._compiled[shapes] = seen + 1
+            record.update(site=site.site, shapes=shapes, repeat=seen > 0)
+        span = getattr(_open, "top", None)
+        ids = _ids_upward(span)
+        record.update(span=span.name if span else None,
+                      span_attrs=span.attrs if span else None,
+                      span_ids=ids)
+        self.records.append(record)
+        # ... and a ``compile`` span of the ring, timed by JAX
+        tel, t0 = _telemetry, record["t0_ns"]
+        attrs = {"site": record["site"], "cache": record["cache"]}
+        tel.ring.record((next(_span_ids), ids[0] if ids else None, "compile",
+                         t0, t1, None, attrs))
+        if tel.enabled:
+            tel.emit("span", "compile", dur_ms=round((t1 - t0) / 1e6, 3),
+                     attrs=attrs)
+
+    # -- the readers ---------------------------------------------------
+    def log(self, since_ns=None, until_ns=None):
+        return [dict(r) for r in tuple(self.records)
+                if (since_ns is None or r["t1_ns"] >= since_ns)
+                and (until_ns is None or r["t1_ns"] <= until_ns)]
+
+    def report(self, until_ns=None):
+        records = self.log(until_ns=until_ns)
+        spans = [(s, up) for s, up in tuple(self.spans)
+                 if until_ns is None or s.t1_ns <= until_ns]
+        names = {s.id: s.name for s, _ in spans}
+        seconds = {"import": 0.0, "engine": 0.0}
+        for span, ancestors in spans:
+            if any(names.get(i) == span.name for i in ancestors):
+                continue        # nested in its like: the outer one counts
+            # its thread's records, one after another: none takes more of
+            # the span than passed since the one before it closed (a
+            # record may carry a trace from before the span)
+            inside, since = 0.0, span.t0_ns
+            for r in records:
+                if span.id in r["span_ids"]:
+                    inside += min(_seconds(r), (r["t1_ns"] - since) / 1e9)
+                    since = r["t1_ns"]
+            key = span.name.partition("/")[2]
+            seconds[key] = seconds.get(key, 0.0) + \
+                (span.t1_ns - span.t0_ns) / 1e9 - inside
+        by_site = {}
+        for r in records:
+            by_site.setdefault(r["site"], []).append(r)
+        out = _totals(records)
+        out["seconds"] = {**seconds, **out["seconds"]}
+        out.update(
+            by_site={site: _totals(rs) for site, rs in by_site.items()},
+            slowest=sorted(records, key=_seconds, reverse=True)[:10],
+            spans=[s for s, _ in spans])
+        return out
+
+
+def _seconds(record):
+    return record["trace_s"] + record["lower_s"] + record["backend_s"]
+
+
+def _totals(records):
+    """Counts and seconds of some of the account's records."""
+    hits = [r for r in records if r["cache"] == "hit"]
+    return {"programs": len(records), "cache_hits": len(hits),
+            "cache_misses": sum(r["cache"] == "miss" for r in records),
+            "repeat_compiles": sum(r["repeat"] for r in records),
+            "seconds": {
+                "trace": math.fsum(r["trace_s"] for r in records),
+                "lower": math.fsum(r["lower_s"] for r in records),
+                "compile": math.fsum(r["backend_s"] for r in records
+                                     if r["cache"] != "hit"),
+                "cache_read": math.fsum(r["backend_s"] for r in hits)}}
+
+
+_account = CompileAccount()
+jax.monitoring.register_event_duration_secs_listener(_account.on_duration)
+jax.monitoring.register_event_listener(_account.on_event)
+
+
+class _SetupSpan(_OpenSpan):
+    """A span of the process's start (``setup/import``, ``setup/engine``
+    and its parts): a handful a process, never on a step's path, kept in
+    the account as well as in the ring."""
+
+    __slots__ = ("since",)
+
+    def __init__(self, name, attrs, since_ns):
+        super().__init__(_telemetry, name, None, attrs or None, None)
+        self.since = since_ns
+
+    def __enter__(self):
+        super().__enter__()
+        if self.since is not None:
+            self.t0 = self.since
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        ancestors = _ids_upward(self.parent)
+        _account.spans.append(
+            (Span(self.id, ancestors[0] if ancestors else None, self.name,
+                  self.t0, t1, None, self.attrs), ancestors))
+        return super().__exit__(*exc)
+
+
+def setup_span(name, since_ns=None, **attrs):
+    """A set-up span of the process-wide telemetry, as a context manager.
+    ``since_ns`` back-dates its start: the package's ``__init__`` reads
+    the clock before it can import this module."""
+    return _SetupSpan(name, attrs, since_ns)
+
+
+def in_setup_span(name, **attrs):
+    """Decorator: the call runs inside ``setup_span(name, **attrs)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inside(*args, **kwargs):
+            with setup_span(name, **attrs):
+                return fn(*args, **kwargs)
+        return inside
+    return wrap
 
 
 # ----------------------------------------------------------------------
@@ -830,6 +1069,34 @@ class Telemetry:
         """Finished spans of this object's ring inside the given
         ``perf_counter_ns`` bounds, ordered by start."""
         return self.ring.spans(since_ns, until_ns)
+
+    @staticmethod
+    def compile_log(since_ns=None, until_ns=None):
+        """The compile account's records that closed inside the bounds,
+        oldest first: one dict for every program JAX compiled or read from
+        the persistent cache in this process (whichever ``Telemetry``
+        object is asked), with ``t0_ns`` / ``t1_ns``, ``name``,
+        ``trace_s``, ``lower_s``, ``backend_s``, ``cache`` (``hit`` with
+        ``retrieval_s``, ``miss``, ``off``), ``site`` and ``shapes`` (a
+        call through :func:`register_compiled`, else None), ``repeat``
+        (the site had compiled those shapes before), ``span`` /
+        ``span_attrs`` / ``span_ids`` (the spans open on its thread,
+        innermost first).  docs/telemetry.md, "Set-up and the compile
+        account"."""
+        return _account.log(since_ns, until_ns)
+
+    @staticmethod
+    def startup_report(until_ns=None):
+        """How the process started, up to ``until_ns``: ``seconds`` in
+        ``import``, ``engine`` (with its parts ``engine/state``,
+        ``engine/weights``, ``engine/pools``; each less the account's
+        seconds inside it), ``trace``, ``lower``, ``compile`` (misses, and
+        programs compiled with no cache), ``cache_read`` (hits); counts
+        ``programs``, ``cache_hits``, ``cache_misses``,
+        ``repeat_compiles``; the same ``by_site``; the ten ``slowest``
+        records; the ``setup/*`` ``spans``.  Kept apart from the ring, so
+        it still holds the start after the ring has wrapped."""
+        return _account.report(until_ns)
 
     def gauge(self, name, value, step=None):
         """Set gauge ``name`` (peak-tracked) and emit a ``gauge`` event."""

@@ -46,7 +46,7 @@ from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.comm.quantize import CommQuantizer
 from deepspeed_tpu.monitor.monitor import MonitorMaster
 from deepspeed_tpu.monitor.telemetry import (MetricsDrain, StepStallWatchdog,
-                                             get_telemetry,
+                                             get_telemetry, in_setup_span,
                                              register_compiled)
 from deepspeed_tpu.parallel import groups
 from deepspeed_tpu.parallel.topology import FSDP_AXIS, build_mesh
@@ -138,6 +138,7 @@ def _batch_token_count(batch):
 
 class DeepSpeedEngine:
 
+    @in_setup_span("setup/engine", kind="train")
     def __init__(self,
                  model: Callable,
                  config: DeepSpeedConfig,
@@ -492,6 +493,7 @@ class DeepSpeedEngine:
             schedule_fn = lambda step: jnp.asarray(base_lr, jnp.float32)  # noqa: E731
         return tx, base_lr, schedule_fn
 
+    @in_setup_span("setup/engine/state")
     def _init_state(self, params) -> TrainState:
         cfg = self._config
         zc = cfg.zero_config

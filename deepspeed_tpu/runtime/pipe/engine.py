@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.monitor.telemetry import in_setup_span
 from deepspeed_tpu.runtime.engine import (DeepSpeedEngine, TrainState,
                                           moq_anneal_step)
 from deepspeed_tpu.runtime.pipe.module import PipelineModule
@@ -30,6 +31,7 @@ from deepspeed_tpu.utils.logging import log_dist
 
 class PipelineEngine(DeepSpeedEngine):
 
+    @in_setup_span("setup/engine", kind="train")
     def __init__(self, model, config, **kwargs):
         assert isinstance(model, PipelineModule), \
             "PipelineEngine requires a PipelineModule model"

@@ -180,7 +180,10 @@ SCHEMA = {
 # ``serve/admit``, ``serve/prefill`` (> build, ``serve/step``, fetch,
 # sample) and ``serve/decode`` (> build, ``serve/step``, fetch, sample);
 # ``engine/train_batch`` holds ``engine/input``, ``engine/dispatch`` and,
-# with the prefetch iterator, ``engine/input_wait``.
+# with the prefetch iterator, ``engine/input_wait``.  ``setup/*`` are the
+# process's start (the package's import, each engine's construction and
+# its parts) and ``compile`` one program JAX compiled or read from the
+# persistent cache, as the compile account closes it (docs/telemetry.md).
 SPAN_NAMES = (
     "checkpoint/load", "checkpoint/save",
     "engine/forward", "engine/backward", "engine/step",
@@ -191,6 +194,8 @@ SPAN_NAMES = (
     "serve/prefill/sample",
     "serve/decode", "serve/decode/build", "serve/decode/fetch",
     "serve/decode/sample",
+    "setup/import", "setup/engine", "setup/engine/state",
+    "setup/engine/weights", "setup/engine/pools", "compile",
 )
 
 # FROZEN: what a serving dispatch of a latent-attention / dropless-expert
