@@ -217,10 +217,11 @@ class SchedulerBase:
             logits, eng.caches, _ = eng._run_step(*args)
             self._decode_sizes(lengths, ready)
             with tel.span("serve/decode/fetch"):
-                logits_np = eng._fetch(logits[:, 0])
+                # the wait for the device and one int32 a slot
+                logits = eng._fetch(logits)
             self.sched_stats["decode_steps"] += 1
             with tel.span("serve/decode/sample"):
-                return self._sample_and_finish(ready, logits_np)
+                return self._sample_and_finish(ready, logits)
 
     def _decode_sizes(self, lengths, ready):
         """Into the report's newest dispatch: the context each ready slot
@@ -228,10 +229,11 @@ class SchedulerBase:
         self.engine._report["dispatches"][-1]["contexts"] = \
             [int(lengths[s]) + 1 for s in ready]
 
-    def _sample_and_finish(self, ready, logits_np):
+    def _sample_and_finish(self, ready, logits):
         """The host half of a one-token decode step: append, sample the
-        next token of every ready slot, evict faulted slots, finish the
-        done ones (which may admit, and prefill, queued requests)."""
+        next token of every ready slot (from its row of the step's
+        fetched ``StepLogits``), evict faulted slots, finish the done
+        ones (which may admit, and prefill, queued requests)."""
         from deepspeed_tpu.inference.robustness import EVICT_FAULT
         eng = self.engine
         # finishing frees slots, which admits (and may prefill) queued
@@ -250,7 +252,7 @@ class SchedulerBase:
                 done_slots.append(slot)
             else:
                 try:
-                    req.last_token = eng._sample(req, logits_np[slot])
+                    req.last_token = eng._sample(req, logits[slot, 0])
                 except Exception as e:   # per-slot fault isolation
                     fault_slots.append((slot, str(e)))
         for slot, err in fault_slots:
@@ -818,11 +820,11 @@ class ChunkedScheduler(SchedulerBase):
                 phase="spec_verify")
             self._decode_sizes(lengths, ready)
             with tel.span("serve/decode/fetch"):
-                logits_np = np.asarray(logits)
+                logits = eng._fetch(logits)
             self.sched_stats["decode_steps"] += 1
             with tel.span("serve/decode/sample"):
                 return self._accept_and_finish(ready, specs, win, props,
-                                               logits_np)
+                                               logits)
 
     def _propose(self, specs, props):
         """The draft model's ``gamma`` greedy proposals for the ``specs``
@@ -846,10 +848,12 @@ class ChunkedScheduler(SchedulerBase):
             props[:, :] = np.asarray(toks)[:, :G]
         eng._serve_event("serve/spec_draft", slots=len(specs), window=G)
 
-    def _accept_and_finish(self, ready, specs, win, props, logits_np):
+    def _accept_and_finish(self, ready, specs, win, props, logits):
         """The host half of a speculative step: per-token semantics for
-        the slots that rode at window 0, longest-matching-prefix accept
-        for the speculating ones, then evictions and finishes."""
+        the slots that rode at window 0 (their row of the verify
+        dispatch's ``StepLogits``), longest-matching-prefix accept for
+        the speculating ones, which read their window's rows of the
+        whole block on the host, then evictions and finishes."""
         from deepspeed_tpu.inference.robustness import EVICT_FAULT
         eng = self.engine
         G = self.gamma
@@ -868,12 +872,13 @@ class ChunkedScheduler(SchedulerBase):
                     done_slots.append(s)
                 else:
                     try:
-                        req.last_token = eng._sample(req, logits_np[s, 0])
+                        req.last_token = eng._sample(req, logits[s, 0])
                     except Exception as e:
                         fault_slots.append((s, str(e)))
                 continue
             w = int(win[s])
-            g = np.argmax(logits_np[s, :w + 1], axis=-1).astype(np.int32)
+            g = np.argmax(logits.host()[s, :w + 1],
+                          axis=-1).astype(np.int32)
             req.out.append(req.last_token)
             eng.lengths[s] += 1
             self.sched_stats["decode_tokens"] += 1
@@ -895,8 +900,10 @@ class ChunkedScheduler(SchedulerBase):
             self.sched_stats["spec_rejected"] += w - m
             # on the host as of the verify fetch: the m accepted tokens
             # and, when the request goes on, the token after them
-            if m + (not finished):
-                eng._emit(req.req_id, m + (not finished))
+            n = m + (not finished)
+            if n:
+                eng._emit(req.req_id, n)
+                logits.count(n, n)
             if finished:
                 done_slots.append(s)
             else:

@@ -98,6 +98,62 @@ def greedy_token(logits: np.ndarray, width: int = 1024) -> int:
     return start + int(np.argmax(logits[start:start + width]))
 
 
+class StepLogits:
+    """The logits ``[B, R, V]`` of one dispatch of a serving program, left
+    where the program wrote them: on the device.  What a step needs of
+    them is ``picks`` ``[B, R]``, the greedy token of every row, which the
+    program takes itself and ``ServingEngine._fetch`` brings to the host
+    (None until then).  The float32 block crosses only if something reads
+    it: :meth:`host` is ``np.asarray`` of the array the program returned
+    (a copy, no program), made once and shared by the dispatch's rows
+    (the speculative verify window reads its rows of it).  ``block[b,
+    r]`` is that token's :class:`LogitsRow`, once the picks are here.
+    ``record`` / ``attrs`` are the dispatch's entry in ``last_step`` and
+    the attributes of its ``serve/step`` span, where :meth:`count` keeps
+    ``picked`` and ``host_rows``."""
+
+    __slots__ = ("device", "device_picks", "picks", "counters", "record",
+                 "attrs", "_host")
+
+    def __init__(self, device, device_picks, counters, record, attrs):
+        self.device, self.device_picks = device, device_picks
+        self.counters, self.record, self.attrs = counters, record, attrs
+        self.picks = self._host = None
+
+    def host(self) -> np.ndarray:
+        if self._host is None:
+            self._host = np.asarray(self.device)
+        return self._host
+
+    def __getitem__(self, at):
+        return LogitsRow(self, at)
+
+    def count(self, picked: int, host_rows: int):
+        """``picked`` tokens were chosen from this dispatch, ``host_rows``
+        of them with their logits row read on the host."""
+        for kept in (self.record, self.attrs):
+            kept["picked"] += picked
+            kept["host_rows"] += host_rows
+
+
+class LogitsRow:
+    """One token's logits row, wherever it lies: what ``_sample(req,
+    row)`` is handed.  ``np.asarray(row)`` / ``np.array(row, np.float32)``
+    is the float32 row (the first read of a dispatch fetches its block);
+    ``pick`` is the program's own ``argmax`` of it; ``read`` says whether
+    anything looked."""
+
+    __slots__ = ("block", "at", "pick", "read")
+
+    def __init__(self, block: StepLogits, at):
+        self.block, self.at, self.read = block, at, False
+        self.pick = int(block.picks[at])
+
+    def __array__(self, dtype=None, copy=None):
+        self.read = True
+        return np.array(self.block.host()[self.at], dtype=dtype, copy=copy)
+
+
 def _round_ms(v):
     return None if v is None else round(v, 3)
 
@@ -394,7 +450,6 @@ class ServingEngine:
         # logits; they come to the host in the fetch the step makes
         # anyway (_fetch)
         self._counted = bool(getattr(self.config, "counts_serving", False))
-        self._counters_pending = None
         self._prefill_sizes = None
 
         # two named jits over the one call, so a device trace's
@@ -402,15 +457,24 @@ class ServingEngine:
         # ``jit_serve_prefill``) from decode (B=max_batch, T=1, and the
         # speculative verify window: ``jit_serve_decode``); each caches a
         # compilation per input shape
+        # Both end with the greedy pick of every row they took the head
+        # on, ``np.argmax``'s (the first index of the maximum, a NaN
+        # counts as the maximum) over the float32 logits they return: a
+        # step fetches those ids, and the block only if a row of it is
+        # read (StepLogits)
+        def with_picks(out):
+            return out + (jnp.argmax(out[0], axis=-1).astype(jnp.int32),)
+
         def serve_prefill(params, ids, caches, tables, lengths, rows, *real):
             # the head on ``rows`` alone: the row its caller samples from
-            return self._paged_call(params, ids, caches, tables, lengths,
-                                    head_rows=rows,
-                                    **dict(zip(("real_lengths",), real)))
+            return with_picks(self._paged_call(
+                params, ids, caches, tables, lengths, head_rows=rows,
+                **dict(zip(("real_lengths",), real))))
 
         def serve_decode(params, ids, caches, tables, lengths, *real):
-            return self._paged_call(params, ids, caches, tables, lengths,
-                                    **dict(zip(("real_lengths",), real)))
+            return with_picks(self._paged_call(
+                params, ids, caches, tables, lengths,
+                **dict(zip(("real_lengths",), real))))
 
         self._prefill_fn = jax.jit(serve_prefill, donate_argnums=(2,))
         self._step_fn = jax.jit(serve_decode, donate_argnums=(2,))
@@ -1272,8 +1336,10 @@ class ServingEngine:
         waits for the device.  ``lengths`` comes as the host's numpy array
         (the call places it): ``kernel_grid`` is reckoned from it.  A
         prefill (sizes from ``_prefill_next``) takes the head on the one
-        row it samples from, or on none, and returns logits [1, 1 | 0, V];
-        every other phase on all its rows.  A model that counts its
+        row it samples from, or on none (logits [1, 1 | 0, V]); every
+        other phase on all its rows.  Returns ``(logits, caches, lengths
+        + T)``, the logits as the :class:`StepLogits` of the dispatch,
+        still on the device.  A model that counts its
         dispatches is also told how many of each sequence's rows are
         tokens (a prefill's prompt under its bucket; else every row of a
         slot that holds a context, so none of a decode batch's idle
@@ -1384,11 +1450,15 @@ class ServingEngine:
             # what the target model's expert layers compiled to
             record["experts"] = self.experts_impl
         self._report["dispatches"].append(record)
-        if self._counted and fn in (self._prefill_fn, self._step_fn):
-            # the model's own counters, still on the device: they land in
-            # this record and on the span when the step fetches its logits
-            self._counters_pending = (out[3], record, attrs)
-            out = out[:3]
+        if fn in (self._prefill_fn, self._step_fn):
+            # the two serving programs: their logits stay on the device
+            # with the picks and a counted model's own counters, which
+            # land in this record and on the span when the step fetches
+            logits, caches, lengths, *counters, picks = out
+            for kept in (record, attrs):
+                kept.update(picked=0, host_rows=0)
+            out = (StepLogits(logits, picks, counters[0] if counters
+                              else None, record, attrs), caches, lengths)
         return out
 
     def _window_counts(self, phase, tokens, starts, sizes):
@@ -1423,24 +1493,24 @@ class ServingEngine:
             self.alloc.num_pages - 1 - self.alloc.available_page_count,
             self.alloc.ring_pages_in_use)))
 
-    def _fetch(self, logits):
-        """The logits a step samples from, to the host.  A counted
-        model's ``SERVE_COUNTERS`` of that dispatch ride in the same
-        transfer (one ``device_get`` of both, no second wait) into the
-        dispatch's record in ``last_step`` and the attributes of its
-        ``serve/step`` span."""
-        pending, self._counters_pending = self._counters_pending, None
-        if pending is None:
-            return np.asarray(logits)
-        counters, record, attrs = pending
-        logits, counters = jax.device_get((logits, counters))
-        values = dict(zip(SERVE_COUNTERS, (int(v) for v in counters)))
-        if self.ring_pages:
-            # a window model's keys are counted on the host (_dispatch)
-            values = {k: v for k, v in values.items()
-                      if k not in record and k != "selected"}
-        record.update(values)
-        attrs.update(values)
+    def _fetch(self, logits: StepLogits) -> StepLogits:
+        """Wait for a dispatch of a serving program and bring what a step
+        needs of it to the host: its ``picks``, one int32 a row.  A
+        counted model's ``SERVE_COUNTERS`` of that dispatch ride in the
+        same transfer (one ``device_get`` of both, no second wait) into
+        the dispatch's record in ``last_step`` and the attributes of its
+        ``serve/step`` span.  The logits block stays where it is until a
+        row of it is read."""
+        logits.picks, counters = jax.device_get(
+            (logits.device_picks, logits.counters))
+        if counters is not None:
+            values = dict(zip(SERVE_COUNTERS, (int(v) for v in counters)))
+            if self.ring_pages:
+                # a window model's keys are counted on the host (_dispatch)
+                values = {k: v for k, v in values.items()
+                          if k not in logits.record and k != "selected"}
+            logits.record.update(values)
+            logits.attrs.update(values)
         return logits
 
     # -- prefix-cache plumbing ------------------------------------------
@@ -1499,7 +1569,7 @@ class ServingEngine:
             self.lengths[slot] = len(req.prompt)
             req.prefilled = len(req.prompt)
             with tel.span("serve/prefill/fetch"):
-                # [1, 1, V]: the program took the head on this row alone
+                # [1, 1]: the program took the head on this row alone
                 row = self._fetch(logits)[0, 0]
             with tel.span("serve/prefill/sample"):
                 req.last_token = self._sample(req, row)
@@ -1519,20 +1589,31 @@ class ServingEngine:
                               req_id=req.req_id, slot=slot,
                               ttft_ms=_round_ms(tr.ttft_ms()))
 
-    def _sample(self, req: _Request, logits: np.ndarray) -> int:
-        """Sample one token on the host from a fetched logits row.  The
-        token is emitted (``_emit``) once it exists, stamped with the time
-        its row was here: a sampler fault emits nothing."""
+    def _sample(self, req: _Request, logits) -> int:
+        """The one place a token is chosen, once a token: from its logits
+        row, a :class:`LogitsRow` (still on the device unless something
+        read it) or a float32 ``np.ndarray`` already here.  The token is
+        emitted (``_emit``) once it exists, stamped with the time its
+        pick was here: a sampler fault emits nothing.  Its dispatch
+        counts it ``picked``, and among its ``host_rows`` if the row had
+        been read by then."""
         t_ns = time.perf_counter_ns()
         token = self._sample_host(req, logits)
         self._emit(req.req_id, 1, t_ns)
+        if isinstance(logits, LogitsRow):
+            logits.block.count(1, logits.read)
         return token
 
-    def _sample_host(self, req: _Request, logits: np.ndarray) -> int:
+    def _sample_host(self, req: _Request, logits) -> int:
         if self.injector is not None:
             self.injector.check("serve_sample")
         if req.temperature <= 0.0:
-            return greedy_token(logits)
+            # the program's own argmax of the same float32 row, where the
+            # row brings one
+            pick = getattr(logits, "pick", None)
+            return greedy_token(np.asarray(logits)) if pick is None \
+                else pick
+        logits = np.asarray(logits)
         rng = self._rng.setdefault(req.req_id,
                                    np.random.default_rng(req.seed))
         l = logits.astype(np.float64) / req.temperature
