@@ -625,9 +625,11 @@ class ChunkedScheduler(SchedulerBase):
             start = req.prefilled
             toks = req.prompt[start:start + self.chunk]
             n = len(toks)
+            chunk = start // self.chunk     # its index in the prompt
             with tel.span("serve/prefill", req_id=req.req_id,
                           attrs={"bucket": self.chunk, "real": n,
-                                 "cached": start}):
+                                 "cached": start, "chunk": chunk,
+                                 "context": start + n}):
                 with tel.span("serve/prefill/build"):
                     ids = np.zeros((1, self.chunk), np.int32)
                     ids[0, :n] = toks
@@ -638,7 +640,8 @@ class ChunkedScheduler(SchedulerBase):
                 # only the prompt's last chunk is sampled from: the ones
                 # before it take no head at all (a second program a chunk
                 # shape, for the table's bytes a chunk: docs/serving.md)
-                eng._prefill_next(n, start + n, sample=start + n >= P)
+                eng._prefill_next(n, start + n, sample=start + n >= P,
+                                  chunk=chunk)
                 logits, eng.caches, _ = eng._run_step(*args,
                                                       phase="prefill")
                 # chunk-active wall time feeds the critical path's

@@ -53,6 +53,7 @@ from deepspeed_tpu.models.transformer import SERVE_COUNTERS
 from deepspeed_tpu.monitor.attribution import RequestAttributor
 from deepspeed_tpu.monitor.telemetry import (get_telemetry, in_setup_span,
                                              register_compiled, setup_span)
+from deepspeed_tpu.ops.latent_attention import context_entries
 from deepspeed_tpu.ops.paged_attention import (PageAllocationError,
                                                PagedAllocator,
                                                resolve_attention_backend,
@@ -82,6 +83,15 @@ STEP_REPORTS_KEPT = 4096
 # scripts/check_telemetry_schema.py)
 WINDOW_COUNTS = ("context_keys", "attended_keys", "pages_full",
                  "pages_ring")
+
+# what a PREFILL dispatch of ``last_step`` and its ``serve/step`` span say
+# of a prompt served in chunks (frozen in
+# scripts/check_telemetry_schema.py): ``ctx_entries``, of a
+# latent-attention model without a selection, the pool entries one layer's
+# attention walks for the chunk from what was cached before it (reckoned
+# in ``ServingEngine._dispatch``); ``chunk``, of the chunked policy
+# whatever the model, the chunk's index in its prompt (the scheduler's)
+CHUNK_COUNTS = ("ctx_entries", "chunk")
 
 
 def greedy_token(logits: np.ndarray, width: int = 1024) -> int:
@@ -440,6 +450,11 @@ class ServingEngine:
             # the latent pools are written and read in XLA whatever the
             # backend asked for (models/transformer.py mix_latent)
             self.attention_impl = "jnp"
+        # no selection: every query attends over its whole context, a
+        # prefill may start from entries already in the pool, and nothing
+        # is ``selected`` (mix_latent_dense)
+        self._latent_dense = latent and not getattr(
+            self.config, "index_topk", 0)
         self._paged_call = functools.partial(
             self.model.apply_with_paged_cache,
             attn_backend=self.attention_impl, attn_interpret=attn_interpret,
@@ -451,6 +466,10 @@ class ServingEngine:
         # anyway (_fetch)
         self._counted = bool(getattr(self.config, "counts_serving", False))
         self._prefill_sizes = None
+        # dispatches whose counters no fetch has brought yet (a prefill
+        # chunk that is not sampled from is never waited for): the next
+        # fetch brings them along
+        self._uncounted = []
 
         # two named jits over the one call, so a device trace's
         # ``XLA Modules`` line tells prefill (B=1, bucketed T:
@@ -555,18 +574,29 @@ class ServingEngine:
             self._refuse_for_ring(tp_size, ep_size)
         if not getattr(self.config, "is_latent", False):
             return
+        selects = bool(getattr(self.config, "index_topk", 0))
         if getattr(self.serving.prefix_cache, "enabled", False):
             raise ServingUnsupported(
                 "prefix_cache with latent attention",
-                "a shared prefix would need a prefill that starts from "
-                "cached latent pages")
+                "a prefill can start from cached latent pages only "
+                "without a selection, and sharing and copying such pages "
+                "between requests has not been served against a "
+                "reference")
         sched = self.serving.scheduler
-        if getattr(sched, "policy", "monolithic") != "monolithic":
+        policy = getattr(sched, "policy", "monolithic")
+        if policy != "monolithic" and selects:
             raise ServingUnsupported(
-                f"scheduler.policy {sched.policy!r} with latent attention",
-                "a prefill chunk or a speculative verify window attends "
-                "from a context already in the pool; only whole-prompt "
-                "prefills (monolithic) and T=1 decode steps are built")
+                f"scheduler.policy {policy!r} with latent attention and a "
+                "key selection (index_topk)",
+                "a prefill chunk attends from a context already in the "
+                "pool, and the selection over cached index keys for T > 1 "
+                "queries is not built; only whole-prompt prefills "
+                "(monolithic) and T=1 decode steps are")
+        if getattr(getattr(sched, "speculative", None), "enabled", False):
+            raise ServingUnsupported(
+                "scheduler.speculative with latent attention",
+                "a verify window over latent pages has not been served "
+                "against a reference")
         if tp_size > 1 or ep_size > 1:
             raise ServingUnsupported(
                 "tp_size / ep_size > 1 with latent attention",
@@ -1321,15 +1351,20 @@ class ServingEngine:
             f"(page_size {self.page_size})")
         self._seat(slot, req.req_id)
 
-    def _prefill_next(self, real: int, context: int, sample: bool = True):
+    def _prefill_next(self, real: int, context: int, sample: bool = True,
+                      chunk: Optional[int] = None):
         """Sizes of the prefill dispatch about to be launched (``_run_step``
         keeps the signature its wrappers replace, so they come ahead of
         it): ``real`` prompt tokens under its padded ``tokens``, the
-        ``context`` its keys and values then reach, and whether its caller
+        ``context`` its keys and values then reach, whether its caller
         will ``sample`` from its last real row (a chunk that is not the
-        prompt's last reads nothing, and its program takes no head)."""
+        prompt's last reads nothing, and its program takes no head), and
+        the ``chunk``'s index in its prompt where the prompt comes in
+        chunks (the chunked policy; reported with the dispatch)."""
         self._prefill_sizes = {"real": int(real), "context": int(context),
                                "head_rows": int(bool(sample))}
+        if chunk is not None:
+            self._prefill_sizes["chunk"] = int(chunk)
 
     def _run_step(self, ids, tables, lengths, phase="decode"):
         """One dispatch of the paged step: the launch only, nothing here
@@ -1434,7 +1469,13 @@ class ServingEngine:
         counted = {}
         if self.ring_pages and config is None:
             counted = self._window_counts(phase, tokens, starts, sizes)
-            attrs.update(counted)
+        elif self._latent_dense and phase == "prefill":
+            # what its attention walks of the pool: whole blocks of keys
+            # over what was cached before the chunk
+            counted = {"ctx_entries": max(
+                context_entries(n, self.page_size) for n in starts)}
+        attrs.update(counted, **{k: sizes[k] for k in ("chunk",)
+                                 if k in sizes})
         with self.telemetry.span("serve/step", attrs=attrs), \
                 self._prof_track("prefill" if phase == "prefill"
                                  else "serve_step"), \
@@ -1459,6 +1500,8 @@ class ServingEngine:
                 kept.update(picked=0, host_rows=0)
             out = (StepLogits(logits, picks, counters[0] if counters
                               else None, record, attrs), caches, lengths)
+            if counters:
+                self._uncounted.append(out[0])
         return out
 
     def _window_counts(self, phase, tokens, starts, sizes):
@@ -1501,16 +1544,25 @@ class ServingEngine:
         the dispatch's record in ``last_step`` and the attributes of its
         ``serve/step`` span.  The logits block stays where it is until a
         row of it is read."""
-        logits.picks, counters = jax.device_get(
-            (logits.device_picks, logits.counters))
-        if counters is not None:
+        earlier = [d for d in self._uncounted if d is not logits]
+        self._uncounted = []
+        # an earlier dispatch that nothing fetched (an unsampled prefill
+        # chunk) has run by the time this one has: no second wait
+        logits.picks, *counted = jax.device_get(
+            (logits.device_picks, logits.counters,
+             *(d.counters for d in earlier)))
+        for dispatch, counters in zip([logits] + earlier, counted):
+            if counters is None:
+                continue
             values = dict(zip(SERVE_COUNTERS, (int(v) for v in counters)))
-            if self.ring_pages:
-                # a window model's keys are counted on the host (_dispatch)
+            if self.ring_pages or self._latent_dense:
+                # a window model's keys are counted on the host
+                # (_dispatch); neither it nor a latent model without a
+                # selection has a ``selected`` to publish
                 values = {k: v for k, v in values.items()
-                          if k not in logits.record and k != "selected"}
-            logits.record.update(values)
-            logits.attrs.update(values)
+                          if k not in dispatch.record and k != "selected"}
+            dispatch.record.update(values)
+            dispatch.attrs.update(values)
         return logits
 
     # -- prefix-cache plumbing ------------------------------------------
@@ -1925,12 +1977,22 @@ class ServingEngine:
             leaks["dirty_inactive_slots"] = dirty
         # every active slot's reservation must equal its TRUE page need
         # (prompt + budget) — _trim_reservation's invariant, the lengths
-        # the ragged attention kernel and the allocator both work from
+        # the ragged attention kernel and the allocator both work from.
+        # It holds from the prompt's LAST chunk on (_complete_prefill
+        # trims): under the chunked policy a request between chunks is
+        # held to what admission reserved, its prompt padded to whole
+        # chunks from where it stands (chunks are whole, so that is the
+        # admission's ``cached + padded``) or prompt + budget, the larger
         over = {}
         for s, req in enumerate(self.slots):
             if req is None:
                 continue
             total = len(req.prompt) + req.max_new_tokens
+            left = len(req.prompt) - req.prefilled
+            if left > 0:
+                total = min(max(total, req.prefilled
+                                + self.scheduler.prefill_padded_len(left)),
+                            self.max_pages_per_seq * self.page_size)
             expected = max(1, -(-total // self.page_size))
             held = len(self.alloc.seq_pages.get(req.req_id, ()))
             if held != expected:

@@ -162,6 +162,12 @@ class TransformerConfig:
     index_head_dim: int = 0
     index_topk: int = 0
     index_norm_eps: float = 1e-6        # the indexer key's LayerNorm
+    # ``rope_scaling`` of type ``yarn`` for the latent mixer's rotary
+    # slice, as ``(factor, original positions, beta_fast, beta_slow,
+    # mscale, mscale_all_dim)`` (ops/latent_attention.py RopeYarn):
+    # frequencies blended by dimension, and the softmax scale times
+    # ``mscale_all_dim``'s magnitude squared
+    rope_yarn: Optional[Tuple[float, ...]] = None
     # ``init``'s seeded token embeddings: None keeps
     # 1 / sqrt(hidden), a row of norm 1, which one layer's attention
     # output (norm 2-3 under the variance-keeping projections) outweighs,
@@ -880,13 +886,19 @@ class CausalTransformerLM:
         ``Indexer(q [B,T,Hi,Di], w [B,T,Hi], k [B,T,Di])`` (None without a
         selection).  Rotary pairs are (2i, 2i+1); the indexer turns the
         FIRST ``qk_rope_head_dim`` values of its heads."""
-        from deepspeed_tpu.ops.latent_attention import rope_interleaved
+        from deepspeed_tpu.ops.latent_attention import (RopeYarn,
+                                                        rope_interleaved)
         c = self.config
         B, T, _ = h.shape
         H, R = c.n_heads, c.kv_lora_rank
         dn, dr = c.qk_nope_head_dim, c.qk_rope_head_dim
         rope = functools.partial(rope_interleaved, positions=positions,
                                  theta=c.rope_theta)
+        if c.rope_yarn is not None:
+            yarn = RopeYarn(*c.rope_yarn)
+            rope = functools.partial(
+                rope, inv_freq=yarn.inv_freq(dr, c.rope_theta),
+                magnitude=yarn.rotary_magnitude)
         with jax.named_scope("latent_attn"):
             c_q = _norm(h @ layer["wq_a"], layer["q_a_norm"], c.norm_eps,
                         True)
@@ -1116,32 +1128,49 @@ class CausalTransformerLM:
             k, v = k[seq, at], v[seq, at]
         return attn, write(jnp.zeros((B,), jnp.int32), k, v)
 
-    def _latent_fresh(self, q, k, idx, layer, positions=None, counts=None):
+    def _latent_fresh(self, q, k, idx, layer, positions=None, counts=None,
+                      context=None):
         """Latent attention of T tokens over themselves (a whole sequence,
         or a prefill from an empty context): keys and values DECOMPRESSED
-        for the tokens at hand, each query over its selected causal keys.
+        for the tokens at hand, each query over its selected causal keys
+        (all of them without an indexer).  ``context``: ``(pools, layer
+        index, block_tables, lengths)`` of a model without a selection
+        whose tokens follow ``lengths`` entries already in the pool (a
+        chunk of a longer prompt): the queries attend over those first
+        (``la.context_attention``, scope ``latent_ctx``).
         -> [B, T, H, dv]."""
         from deepspeed_tpu.ops import latent_attention as la
         c = self.config
         B, T, H, dn = q.nope.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+        real = None if counts is None else counts.real
         with jax.named_scope("latent_attn"):
             kv = (k.c_kv @ layer["wkv_b"]).reshape(B, T, H, -1)
             keys = jnp.concatenate(
                 [kv[..., :dn], jnp.broadcast_to(
                     k.rope[:, :, None], (B, T, H, k.rope.shape[-1]))], -1)
             queries = jnp.concatenate([q.nope, q.rope], axis=-1)
+            state = None
+            if context is not None:
+                pools, index, block_tables, lengths = context
+                with jax.named_scope("latent_ctx"):
+                    state = la.context_attention(
+                        queries, pools, index, block_tables, lengths,
+                        layer["wkv_b"].reshape(-1, H, dn + c.v_head_dim),
+                        k.rope.shape[-1], self._latent_scale(), real=real)
         if idx is None:     # no selection: every causal key
-            idx = Indexer(jnp.zeros((B, T, 1, 1), queries.dtype),
-                          jnp.zeros((B, T, 1), queries.dtype),
-                          jnp.zeros((B, T, 1), queries.dtype))
-        out, attended, context = la.prefill_attention(
+            idx = Indexer(None, None, None)
+        out, attended, causal = la.prefill_attention(
             queries, keys, kv[..., dn:], idx.q, idx.w, idx.k, positions,
-            c.index_topk or T, self._latent_scale(),
-            real=None if counts is None else counts.real)
+            c.index_topk, self._latent_scale(), real=real, state=state)
+        if counts is not None and context is not None:
+            # every real query also met the ``lengths`` cached entries
+            cached = jnp.sum(jnp.sum(real, axis=1) * lengths).astype(
+                jnp.int32)
+            attended, causal = attended + cached, causal + cached
         if counts is not None:
-            counts.add(selected=attended, context_keys=context)
+            counts.add(selected=attended, context_keys=causal)
         return out
 
     def mix_latent_whole(self, q, k, idx, layer, cache):
@@ -1151,8 +1180,13 @@ class CausalTransformerLM:
 
     def _latent_scale(self):
         c = self.config
-        return c.attn_scale if c.attn_scale is not None else \
-            1.0 / math.sqrt(c.qk_nope_head_dim + c.qk_rope_head_dim)
+        if c.attn_scale is not None:
+            return c.attn_scale
+        scale = 1.0 / math.sqrt(c.qk_nope_head_dim + c.qk_rope_head_dim)
+        if c.rope_yarn is not None:
+            from deepspeed_tpu.ops.latent_attention import RopeYarn
+            scale *= RopeYarn(*c.rope_yarn).softmax_factor
+        return scale
 
     def mix_latent(self, q, k, idx, layer, pools, *, index, block_tables,
                    lengths, counts=None):
@@ -1161,16 +1195,19 @@ class CausalTransformerLM:
         decode step (T = 1) gathers its selected entries out of the pool
         and uses the absorbed weights; T > 1 is a prefill FROM AN EMPTY
         CONTEXT (``lengths`` 0: ``ServingEngine`` refuses what would break
-        that) and decompresses the tokens it brings."""
+        that) and decompresses the tokens it brings.  A model without a
+        selection takes :meth:`mix_latent_dense`."""
         from deepspeed_tpu.ops import latent_attention as la
         c = self.config
+        if idx is None:
+            return self.mix_latent_dense(
+                q, k, layer, pools, index=index, block_tables=block_tables,
+                lengths=lengths, counts=counts)
         B, T, H, dn = q.nope.shape
         entry = jnp.concatenate([k.c_kv, k.rope], axis=-1)
-        index_key = idx.k if idx is not None else \
-            jnp.zeros((B, T, pools.index_pages.shape[-1]), entry.dtype)
         with jax.named_scope("latent_attn"):
             pools = la.write_latent(pools, index, block_tables, lengths,
-                                    entry, index_key)
+                                    entry, idx.k)
         if T > 1:
             positions = lengths[:, None] + jnp.arange(T)[None, :]
             return self._latent_fresh(q, k, idx, layer, positions,
@@ -1179,20 +1216,47 @@ class CausalTransformerLM:
         w_kvb = layer["wkv_b"].reshape(R, H, -1)
         with jax.named_scope("latent_attn"):
             q_abs = jnp.einsum("bhd,rhd->bhr", q.nope[:, 0], w_kvb[..., :dn])
-        if idx is None:
-            idx = Indexer(jnp.zeros((B, 1, 1, pools.index_pages.shape[-1]),
-                                    entry.dtype),
-                          jnp.zeros((B, 1, 1), entry.dtype), None)
         o_lat, attended, context = la.decode_attention(
             q_abs, q.rope[:, 0], idx.q[:, 0], idx.w[:, 0], pools, index,
-            block_tables, lengths + 1,
-            c.index_topk or block_tables.shape[1] * pools.latent_pages.shape[2],
-            self._latent_scale(),
+            block_tables, lengths + 1, c.index_topk, self._latent_scale(),
             real=None if counts is None else counts.real[:, 0])
         with jax.named_scope("latent_attn"):
             out = jnp.einsum("bhr,rhd->bhd", o_lat, w_kvb[..., dn:])
         if counts is not None:
             counts.add(selected=attended, context_keys=context)
+        return out[:, None], pools
+
+    def mix_latent_dense(self, q, k, layer, pools, *, index, block_tables,
+                         lengths, counts=None):
+        """:meth:`mix_latent` of a model WITHOUT a selection: no index
+        pool, no scores, no top-k.  The entries are written at
+        ``lengths``; T > 1 is a prefill from whatever the pool holds of
+        the sequence (``lengths`` >= 0: a chunk of a longer prompt attends
+        over the cached entries, then causally over itself; at 0 it is
+        the fresh prefill), a decode step reads the context's entries in
+        page order with the absorbed weights."""
+        from deepspeed_tpu.ops import latent_attention as la
+        c = self.config
+        B, T, H, dn = q.nope.shape
+        with jax.named_scope("latent_attn"):
+            pools = la.write_latent(
+                pools, index, block_tables, lengths,
+                jnp.concatenate([k.c_kv, k.rope], axis=-1), None)
+        if T > 1:
+            positions = lengths[:, None] + jnp.arange(T)[None, :]
+            return self._latent_fresh(
+                q, k, None, layer, positions, counts,
+                context=(pools, index, block_tables, lengths)), pools
+        w_kvb = layer["wkv_b"].reshape(c.kv_lora_rank, H, -1)
+        with jax.named_scope("latent_attn"):
+            q_abs = jnp.einsum("bhd,rhd->bhr", q.nope[:, 0], w_kvb[..., :dn])
+            o_lat, context = la.dense_decode_attention(
+                q_abs, q.rope[:, 0], pools, index, block_tables, lengths + 1,
+                self._latent_scale(),
+                real=None if counts is None else counts.real[:, 0])
+            out = jnp.einsum("bhr,rhd->bhd", o_lat, w_kvb[..., dn:])
+        if counts is not None:
+            counts.add(selected=context, context_keys=context)
         return out[:, None], pools
 
     def _cached_attn_bias(self, layer, T, S, length):
@@ -1592,7 +1656,7 @@ class CausalTransformerLM:
             return init_latent_pools(
                 c.n_layers, num_pages, page_size,
                 c.kv_lora_rank + c.qk_rope_head_dim,
-                max(c.index_head_dim, 1), dtype)
+                c.index_head_dim if c.index_topk else 0, dtype)
         # each stack made in place: a broadcast of one layer's pool and a
         # copy of it held four stacks at once, the process's HBM peak
         shape = (c.n_layers, num_pages, c.kv_heads, page_size, c.head_dim)

@@ -192,7 +192,8 @@ OP_PHASES = ("fwd", "bwd", "remat", "loss_head", "optimizer", "grad_reduce",
 # ``mlp`` (models/transformer.py): ``phase_of`` gives the innermost of
 # these where an instruction has one, in any program
 SERVE_SCOPES = ("select", "latent_attn", "router", "experts",
-                "shared_expert", "attn_window", "attn_full", "attn_gate")
+                "shared_expert", "attn_window", "attn_full", "attn_gate",
+                "latent_ctx")
 
 _MODEL_SCOPES = frozenset(("embed", "norm", "attn", "mlp"))
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")

@@ -208,13 +208,23 @@ SPAN_NAMES = (
 SERVE_COUNTERS = ("selected", "context_keys", "expert_pairs",
                   "expert_load_max", "expert_rows")
 SERVE_SCOPES = ("select", "latent_attn", "router", "experts",
-                "shared_expert", "attn_window", "attn_full", "attn_gate")
+                "shared_expert", "attn_window", "attn_full", "attn_gate",
+                "latent_ctx")
 # FROZEN: what a model with sliding-window layers adds to the same
 # dispatches and spans, reckoned on the host from the lengths
 # (byte-identical to ``deepspeed_tpu.inference.serving.WINDOW_COUNTS``;
 # it brings no ``selected``)
 WINDOW_COUNTS = ("context_keys", "attended_keys", "pages_full",
                  "pages_ring")
+# FROZEN: what a prefill dispatch and its span say of a prompt served in
+# chunks (byte-identical to
+# ``deepspeed_tpu.inference.serving.CHUNK_COUNTS``): ``ctx_entries``, of a
+# latent-attention model WITHOUT a selection (which publishes no
+# ``selected``), the pool entries one layer's attention walks for the
+# chunk from what was cached before it, reckoned on the host; ``chunk``,
+# of the chunked policy whatever the model, the chunk's index in its
+# prompt
+CHUNK_COUNTS = ("ctx_entries", "chunk")
 
 # FROZEN vocabulary of serve-kind event names — must stay byte-identical
 # to ``deepspeed_tpu.inference.robustness.SERVE_EVENTS`` (the tier-1 test

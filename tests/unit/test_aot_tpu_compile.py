@@ -460,6 +460,87 @@ def test_expert_layer_never_copies_an_expert_or_loops_over_them(
     assert 6 <= text.count(" while(") <= 1 + 2 + 4
 
 
+DENSE_LATENT_DISPATCHES = {"decode_b16": (16, 1, None),
+                           "chunk_2048": (1, 2048, 0),
+                           "chunk_2048_sampled": (1, 2048, 1)}
+DENSE_LATENT_PAGES = 2 * 136 + 1        # two slots of max_seq 17,408
+
+
+@pytest.fixture(scope="module")
+def dense_latent_dispatch(topo):
+    """Each of ``DENSE_LATENT_DISPATCHES`` of a latent-attention model
+    WITHOUT a selection (Kimi-K2's widths, 2 of its layers: the dense one
+    and an expert layer of 12 held of 384), compiled once."""
+    import functools
+    import json
+    import os
+
+    from chipbench.families import kimi_k2
+    with open(os.path.join(os.path.dirname(kimi_k2.__file__), "..",
+                           "configs", "kimi-k2-ep32.json")) as f:
+        cfg = json.load(f)
+    cfg.update(num_hidden_layers=2, vocab_size=1024)
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = CausalTransformerLM(TransformerConfig(
+        **kimi_k2.transformer_kwargs(cfg)))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _on(chip, x.shape, x.dtype), tree)
+
+    def ints(*shape):
+        return _on(chip, shape, jnp.int32)
+
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.bfloat16)))
+    caches = on_chip(jax.eval_shape(
+        lambda: model.init_paged_caches(DENSE_LATENT_PAGES, 128)))
+
+    def serve(params, ids, caches, tables, lengths, real, *rows):
+        return model.apply_with_paged_cache(
+            params, ids, caches, tables, lengths, expert_backend="pallas",
+            real_lengths=real, **dict(zip(("head_rows",), rows)))
+
+    @functools.lru_cache(maxsize=None)
+    def compiled(name):
+        batch, tokens, rows = DENSE_LATENT_DISPATCHES[name]
+        return jax.jit(serve, donate_argnums=(2,)).lower(
+            params, ints(batch, tokens), caches, ints(batch, 137),
+            ints(batch), ints(batch),
+            *([] if rows is None else [ints(batch, rows)])).compile()
+
+    return compiled
+
+
+@pytest.mark.parametrize("name", DENSE_LATENT_DISPATCHES)
+def test_dense_latent_dispatch_walks_the_pool_in_place(dense_latent_dispatch,
+                                                       name):
+    """A decode step and a prefill chunk of a latent model without a
+    selection (PR 41): the stacked pool of 640-value rows is written by a
+    scatter in place and read a block of pages at a time inside a loop;
+    nothing else has a result of its shape (no copy, no re-layout), the
+    index pool has no bytes, and the 12 held experts of 384 go through
+    the one grouped kernel."""
+    compiled = dense_latent_dispatch(name)
+    text = compiled.as_text()
+    pool = f"bf16[2,{DENSE_LATENT_PAGES},128,640]"
+    found = {op for _, op in _pool_shaped(text, None, [pool])}
+    assert found and found <= IN_PLACE_OPS | {"fusion", "scatter"}, found
+    assert " while(" in text
+    # a chunk that is not sampled from needs nothing of its LAST layer
+    # but the entries it writes: the compiler drops that layer's expert
+    # product (here the only one) with the head
+    batch, tokens, rows = DENSE_LATENT_DISPATCHES[name]
+    assert ("grouped_expert_glu" in text) == (rows != 0)
+    # no index keys anywhere: the second pool is an array of no elements
+    assert f"bf16[2,{DENSE_LATENT_PAGES},128,0]" in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * DENSE_LATENT_PAGES * 128 * 1280
+    # a decode step's largest temporary is a block of gathered entries; a
+    # chunk's the dense layer's gate and up (2,048 x 18,432)
+    assert memory.temp_size_in_bytes < (1 << 26 if tokens == 1 else 1 << 30)
+
+
 BUCKET_LOGITS = "f32[1,4096,100352]"      # 1.64 GB: every row of a bucket
 
 
