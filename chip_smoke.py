@@ -352,7 +352,8 @@ def phase_serve(counter, model_kw=LLAMA_1B, max_batch=8, page_size=16,
         "decode step (ragged paged attention)", engine._step_fn,
         engine.params, shape((max_batch, 1), jnp.int32), engine.caches,
         shape(engine.tables.shape, jnp.int32),
-        shape((max_batch,), jnp.int32), on_chip=on_chip)
+        shape((max_batch,), jnp.int32), shape((max_batch, 1), jnp.int32),
+        on_chip=on_chip)
     memory = compiled.memory_analysis() if on_chip else None
     del compiled
 

@@ -16,8 +16,7 @@ primitives (``_run_step`` / ``_sample`` / ``_prefill``); the scheduler
 owns WHAT each step dispatches:
 
 - ``monolithic`` (default): the whole prompt prefills in one bucketed
-  dispatch at admission, decode advances every slot per step — today's
-  behaviour bit-for-bit.
+  dispatch at admission, decode advances every slot per step.
 - ``chunked``: prefill runs ``prefill_chunk_tokens`` at a time,
   interleaved with decode; per-request SLO classes (``latency`` vs
   ``throughput``) order both queue admission and chunk scheduling, and
@@ -125,6 +124,26 @@ class SchedulerConfig(DeepSpeedConfigModel):
         return float(ttl) if ttl else None
 
 
+class _InFlight:
+    """A decode dispatch whose picks the host has not taken: ``logits``
+    its :class:`~deepspeed_tpu.inference.serving.StepLogits`, ``owed``
+    the request of each slot that still gets a token from it (slot order),
+    ``overlaps`` whether the dispatch before it was still to be fetched
+    when this one was launched (its picks then count as ``ahead``)."""
+
+    __slots__ = ("logits", "owed", "overlaps")
+
+    def __init__(self, logits, owed, overlaps):
+        self.logits, self.owed, self.overlaps = logits, owed, overlaps
+
+    def drop(self, slot, req):
+        """The row of ``slot`` was launched for nothing, if ``req`` is
+        still owed a token from it: its pick is thrown away."""
+        if self.owed.get(slot) is req:
+            del self.owed[slot]
+            self.logits.bump("redone")
+
+
 class SchedulerBase:
     """Decode machinery shared by every policy.
 
@@ -133,7 +152,10 @@ class SchedulerBase:
     table row and length 0: their writes land on the reserved scratch
     page and the host loop skips their outputs.  Under the monolithic
     policy every active slot is ready, so the masked arrays equal the
-    engine's own tables/lengths — bit-for-bit the pre-scheduler step.
+    engine's own tables/lengths.  The one-token step (``_decode_once``,
+    both policies) runs one dispatch ahead of the host while every
+    request it serves is greedy; the speculative window and
+    ``decode_chunk > 1`` wait for their ids.
     """
 
     policy = "base"
@@ -142,6 +164,9 @@ class SchedulerBase:
         self.engine = engine
         self.cfg = cfg
         self._chunk_fns = {}   # use_filters(bool) -> compiled chunk fn
+        # one-token decode dispatches whose picks the host has not taken,
+        # oldest first: at most one between steps (_decode_once)
+        self._ahead: List[_InFlight] = []
         self.sched_stats = {"prefill_chunks": 0, "prefills_split": 0,
                             "decode_steps": 0, "decode_tokens": 0}
 
@@ -163,7 +188,12 @@ class SchedulerBase:
 
     def release_slot(self, slot: int, req):
         """The request in ``slot`` is leaving the engine (finish, evict,
-        deadline, drain) — drop any scheduler-held state for it."""
+        deadline, drain) — drop any scheduler-held state for it: a row
+        in flight for it is thrown away when it arrives, and a dispatch
+        that owes nobody a token is in flight no more."""
+        for dispatch in self._ahead:
+            dispatch.drop(slot, req)
+        self._ahead = [d for d in self._ahead if d.owed]
 
     # -- step hooks ------------------------------------------------------
     def run_step(self) -> Dict[Any, List[int]]:
@@ -197,31 +227,97 @@ class SchedulerBase:
         return True
 
     def _decode_once(self, ready: List[int]) -> Dict[Any, List[int]]:
-        """One token for every ready slot (the pre-scheduler per-token
-        step body, masked to ``ready``)."""
+        """One token for every ready slot, the host one decode step
+        behind the device where it can be.
+
+        A step LAUNCHES the next dispatch, then takes the picks of the one
+        before it: a slot whose token that older dispatch still owes is
+        fed it on the device (``serve_decode``'s ``fed``), so the build,
+        the launch, the fetch's round trip and the sampling below all run
+        while the device computes.  The dispatch just launched stays in
+        flight (``_ahead``) when every request it owes a token is greedy:
+        the program's pick is then the token.  Otherwise (a sampled
+        request in the batch) its picks are taken here as well, and that
+        is the whole of a step with nothing in flight: launch, nothing
+        older to take, fetch, sample.
+
+        The host's books follow the LAUNCHES: a token joins ``req.out``
+        and the slot's length when it is both known and fed (``_fed``),
+        so ``eng.lengths``, the tables and the budget are those of the
+        next dispatch, and a request ends, and is handed back, as soon as
+        its last token is known and launched (EOS is tested on the token
+        fed, so neither it nor the budget costs a row).  What can waste
+        a row is a pick the host does not take: a sampler that returns
+        another token, a sampler fault, a deadline or an eviction while
+        the row is in flight.  The row is dropped (``redone``), the length
+        stays, and the slot is fed from the host again at the same
+        position, which overwrites what the dropped row wrote."""
         eng = self.engine
         tel = eng.telemetry
+        done_slots: List[int] = []
         with tel.span("serve/decode",
                       attrs={"batch": eng.max_batch, "ready": len(ready),
                              "tokens": 1}):
-            with tel.span("serve/decode/build"):
-                last = np.zeros((eng.max_batch, 1), np.int32)
-                tables = np.zeros_like(eng.tables)
-                lengths = np.zeros_like(eng.lengths)
-                for slot in ready:
-                    req = eng.slots[slot]
-                    last[slot, 0] = req.last_token
-                    tables[slot] = eng.tables[slot]
-                    lengths[slot] = eng.lengths[slot]
-                args = (jnp.asarray(last), jnp.asarray(tables), lengths)
-            logits, eng.caches, _ = eng._run_step(*args)
-            self._decode_sizes(lengths, ready)
-            with tel.span("serve/decode/fetch"):
-                # the wait for the device and one int32 a slot
-                logits = eng._fetch(logits)
-            self.sched_stats["decode_steps"] += 1
+            if ready:
+                self._launch(ready, done_slots)
+            while self._ahead:
+                dispatch = self._ahead[0]
+                if not dispatch.owed:       # every request it fed ended
+                    self._ahead.remove(dispatch)
+                    continue
+                if len(self._ahead) == 1 and all(
+                        req.temperature <= 0.0
+                        for req in dispatch.owed.values()):
+                    break       # its picks are its tokens: run ahead
+                with tel.span("serve/decode/fetch"):
+                    # the wait for the device and one int32 a slot
+                    eng._fetch(dispatch.logits)
+                with tel.span("serve/decode/sample"):
+                    self._take_picks(dispatch, done_slots)
             with tel.span("serve/decode/sample"):
-                return self._sample_and_finish(ready, logits)
+                return self._finish_slots(done_slots)
+
+    def _launch(self, ready, done_slots):
+        """Launch one decode dispatch for the ``ready`` slots behind what
+        is in flight, and book the rows fed from the host."""
+        eng = self.engine
+        older = self._ahead[-1] if self._ahead else None
+        with eng.telemetry.span("serve/decode/build"):
+            last = np.zeros((eng.max_batch, 1), np.int32)
+            tables = np.zeros_like(eng.tables)
+            lengths = np.zeros_like(eng.lengths)
+            for slot in ready:
+                req = eng.slots[slot]
+                # the token the older dispatch owes is fed where it lies
+                owed = older is not None and older.owed.get(slot) is req
+                last[slot, 0] = -1 if owed else req.last_token
+                tables[slot] = eng.tables[slot]
+                lengths[slot] = eng.lengths[slot]
+            args = (jnp.asarray(last), jnp.asarray(tables), lengths)
+        logits, eng.caches, _ = eng._run_step(*args)
+        self._decode_sizes(lengths, ready)
+        self.sched_stats["decode_steps"] += 1
+        newer = _InFlight(
+            logits, {slot: eng.slots[slot] for slot in ready},
+            overlaps=older is not None and older.logits.picks is None)
+        self._ahead.append(newer)
+        for slot in ready:
+            if last[slot, 0] >= 0:
+                self._fed(slot, newer, done_slots)
+
+    def _fed(self, slot, dispatch, done_slots):
+        """``req.last_token`` is known and ``dispatch`` feeds it at the
+        slot's length: it is part of the sequence.  A request that ends
+        with it is owed no further token."""
+        eng = self.engine
+        req = eng.slots[slot]
+        req.out.append(req.last_token)
+        eng.lengths[slot] += 1
+        self.sched_stats["decode_tokens"] += 1
+        if (eng.eos is not None and req.last_token == eng.eos) or \
+                len(req.out) >= req.max_new_tokens:
+            del dispatch.owed[slot]
+            done_slots.append(slot)
 
     def _decode_sizes(self, lengths, ready):
         """Into the report's newest dispatch: the context each ready slot
@@ -229,32 +325,34 @@ class SchedulerBase:
         self.engine._report["dispatches"][-1]["contexts"] = \
             [int(lengths[s]) + 1 for s in ready]
 
-    def _sample_and_finish(self, ready, logits):
-        """The host half of a one-token decode step: append, sample the
-        next token of every ready slot (from its row of the step's
-        fetched ``StepLogits``), evict faulted slots, finish the done
-        ones (which may admit, and prefill, queued requests)."""
+    def _take_picks(self, dispatch, done_slots):
+        """The host half of a one-token decode step, for the oldest
+        dispatch in flight, fetched: sample the next token of every slot
+        it owes one (from its row of the ``StepLogits``) and evict the
+        slots whose sampler failed.  Where the dispatch after it was
+        launched on the pick, the token is booked there if it is the
+        pick, and that row dropped if it is not."""
         from deepspeed_tpu.inference.robustness import EVICT_FAULT
         eng = self.engine
-        # finishing frees slots, which admits (and may prefill) queued
-        # requests — defer that until after the loop so a mid-loop
-        # admission is never mistaken for a slot this decode step served
-        done_slots, fault_slots = [], []
-        done_now: Dict[Any, List[int]] = {}
-        for slot in ready:
-            req = eng.slots[slot]
-            # the token we just fed is now part of the sequence
-            req.out.append(req.last_token)
-            eng.lengths[slot] += 1
-            self.sched_stats["decode_tokens"] += 1
-            ended = (eng.eos is not None and req.last_token == eng.eos)
-            if ended or len(req.out) >= req.max_new_tokens:
-                done_slots.append(slot)
+        self._ahead.remove(dispatch)
+        following = self._ahead[0] if self._ahead else None
+        logits = dispatch.logits
+        fault_slots = []
+        for slot, req in dispatch.owed.items():
+            if eng.slots[slot] is not req:
+                continue
+            try:
+                req.last_token = eng._sample(req, logits[slot, 0])
+            except Exception as e:   # per-slot fault isolation
+                fault_slots.append((slot, str(e)))
+                continue
+            if following is None or following.owed.get(slot) is not req:
+                continue        # fed from the host by the next launch
+            if req.last_token == int(logits.picks[slot, 0]):
+                logits.bump("ahead", int(following.overlaps))
+                self._fed(slot, following, done_slots)
             else:
-                try:
-                    req.last_token = eng._sample(req, logits[slot, 0])
-                except Exception as e:   # per-slot fault isolation
-                    fault_slots.append((slot, str(e)))
+                following.drop(slot, req)
         for slot, err in fault_slots:
             rid = eng.slots[slot].req_id
             logger.warning(f"evicting request {rid!r} after sampler "
@@ -265,6 +363,13 @@ class SchedulerBase:
                              reason=EVICT_FAULT, error=err)
         if fault_slots:
             eng._admit()
+
+    def _finish_slots(self, done_slots):
+        """Finish the requests that ended in this step (which may admit,
+        and prefill, queued requests: after the step's own sampling, so a
+        mid-step admission is never mistaken for a slot it served)."""
+        eng = self.engine
+        done_now: Dict[Any, List[int]] = {}
         for slot in done_slots:
             rid = eng.slots[slot].req_id
             eng._finish(slot)
@@ -422,9 +527,9 @@ class SchedulerBase:
 
 
 class MonolithicScheduler(SchedulerBase):
-    """Today's behaviour, bit-for-bit: the whole (uncached) prompt
-    prefills in one bucketed dispatch at slot-fill time; every active
-    slot decodes every step."""
+    """The whole (uncached) prompt prefills in one bucketed dispatch at
+    slot-fill time, fetched and sampled there; every active slot decodes
+    every step, by ``_decode_once`` or the ``decode_chunk`` scan."""
 
     policy = "monolithic"
 
@@ -588,6 +693,7 @@ class ChunkedScheduler(SchedulerBase):
         return False
 
     def release_slot(self, slot: int, req):
+        super().release_slot(slot, req)
         if self.spec and slot in self._spec_slots:
             self._spec_slots.discard(slot)
             self.draft_alloc.free_sequence(req.req_id)

@@ -112,15 +112,20 @@ class StepLogits:
     """The logits ``[B, R, V]`` of one dispatch of a serving program, left
     where the program wrote them: on the device.  What a step needs of
     them is ``picks`` ``[B, R]``, the greedy token of every row, which the
-    program takes itself and ``ServingEngine._fetch`` brings to the host
-    (None until then).  The float32 block crosses only if something reads
+    program takes itself: ``device_picks`` is what the next decode
+    dispatch is fed where the host has not seen them yet, and
+    ``ServingEngine._fetch`` brings them to the host as ``picks`` (None
+    until a fetch of this dispatch, or of a later one, has: a decode
+    dispatch may still be running when the ``step()`` that launched it
+    returns).  The float32 block crosses only if something reads
     it: :meth:`host` is ``np.asarray`` of the array the program returned
     (a copy, no program), made once and shared by the dispatch's rows
     (the speculative verify window reads its rows of it).  ``block[b,
     r]`` is that token's :class:`LogitsRow`, once the picks are here.
     ``record`` / ``attrs`` are the dispatch's entry in ``last_step`` and
     the attributes of its ``serve/step`` span, where :meth:`count` keeps
-    ``picked`` and ``host_rows``."""
+    ``picked`` and ``host_rows`` and :meth:`bump` a decode dispatch's
+    ``ahead`` and ``redone``."""
 
     __slots__ = ("device", "device_picks", "picks", "counters", "record",
                  "attrs", "_host")
@@ -141,9 +146,12 @@ class StepLogits:
     def count(self, picked: int, host_rows: int):
         """``picked`` tokens were chosen from this dispatch, ``host_rows``
         of them with their logits row read on the host."""
+        self.bump("picked", picked)
+        self.bump("host_rows", host_rows)
+
+    def bump(self, counter: str, n: int = 1):
         for kept in (self.record, self.attrs):
-            kept["picked"] += picked
-            kept["host_rows"] += host_rows
+            kept[counter] += n
 
 
 class LogitsRow:
@@ -466,10 +474,12 @@ class ServingEngine:
         # anyway (_fetch)
         self._counted = bool(getattr(self.config, "counts_serving", False))
         self._prefill_sizes = None
-        # dispatches whose counters no fetch has brought yet (a prefill
-        # chunk that is not sampled from is never waited for): the next
-        # fetch brings them along
-        self._uncounted = []
+        # dispatches of the two serving programs that no fetch has
+        # brought yet, in launch order (a prefill chunk that is not
+        # sampled from is never waited for, a decode step is left running
+        # behind the next one's launch): a fetch of a later dispatch
+        # brings their picks and counters along
+        self._unfetched = []
 
         # two named jits over the one call, so a device trace's
         # ``XLA Modules`` line tells prefill (B=1, bucketed T:
@@ -490,13 +500,29 @@ class ServingEngine:
                 params, ids, caches, tables, lengths, head_rows=rows,
                 **dict(zip(("real_lengths",), real))))
 
-        def serve_decode(params, ids, caches, tables, lengths, *real):
+        def serve_decode(params, ids, caches, tables, lengths, fed, *real):
+            # a row whose id is negative is fed ``fed``'s: the pick of the
+            # decode dispatch before this one, which has not left the
+            # device (the scheduler launches a step before the host has
+            # the ids of the one before: scheduler._decode_once)
             return with_picks(self._paged_call(
-                params, ids, caches, tables, lengths,
-                **dict(zip(("real_lengths",), real))))
+                params, jnp.where(ids < 0, fed, ids), caches, tables,
+                lengths, **dict(zip(("real_lengths",), real))))
 
         self._prefill_fn = jax.jit(serve_prefill, donate_argnums=(2,))
         self._step_fn = jax.jit(serve_decode, donate_argnums=(2,))
+        # what a decode dispatch is handed as ``fed``: the ``picks`` of
+        # the decode dispatch before it, where they lie; before any,
+        # zeros that no row reads, placed as a dispatch's results are
+        # (their type carries the mesh of the weights' placement), or the
+        # second decode step would trace and compile the program again
+        placed = getattr(jax.tree_util.tree_leaves(params)[0], "sharding",
+                         None)
+        self._picks = jax.device_put(
+            np.zeros((max_batch, 1), np.int32),
+            jax.sharding.NamedSharding(placed.mesh,
+                                       jax.sharding.PartitionSpec())
+            if isinstance(placed, jax.sharding.NamedSharding) else None)
         self._rng = {}
         # multi-token decode: one device program advances every slot
         # ``decode_chunk`` tokens (sampling included) per host round-trip,
@@ -1324,6 +1350,7 @@ class ServingEngine:
         if entry is None:
             return False
         slot, _, _ = entry
+        self.scheduler.release_slot(slot, self.slots[slot])
         self.attrib.discard(req_id)
         self.alloc.free_sequence(req_id)
         self._rng.pop(req_id, None)
@@ -1372,7 +1399,10 @@ class ServingEngine:
         (the call places it): ``kernel_grid`` is reckoned from it.  A
         prefill (sizes from ``_prefill_next``) takes the head on the one
         row it samples from, or on none (logits [1, 1 | 0, V]); every
-        other phase on all its rows.  Returns ``(logits, caches, lengths
+        other phase on all its rows, and a row whose id is negative
+        starts from the pick the decode dispatch before this one made for
+        its slot (``serve_decode``'s ``fed``: that dispatch need not have
+        ended).  Returns ``(logits, caches, lengths
         + T)``, the logits as the :class:`StepLogits` of the dispatch,
         still on the device.  A model that counts its
         dispatches is also told how many of each sequence's rows are
@@ -1387,6 +1417,8 @@ class ServingEngine:
             # [B, 1 | 0]: the prompt's last row, or no row
             args += (np.full((ids.shape[0], sizes["head_rows"]),
                              sizes["real"] - 1, np.int32),)
+        else:
+            args += (self._picks,)
         if self._counted:
             real = (np.full(ids.shape[0], sizes["real"]) if sizes else
                     np.where(np.asarray(lengths) > 0, ids.shape[1], 0))
@@ -1500,8 +1532,14 @@ class ServingEngine:
                 kept.update(picked=0, host_rows=0)
             out = (StepLogits(logits, picks, counters[0] if counters
                               else None, record, attrs), caches, lengths)
-            if counters:
-                self._uncounted.append(out[0])
+            self._unfetched.append(out[0])
+            if phase == "decode":
+                # of the tokens picked from it, those the next dispatch
+                # had been fed before the host held them; rows of it
+                # launched for nothing (scheduler._decode_once)
+                for kept in (record, attrs):
+                    kept.update(ahead=0, redone=0)
+                self._picks = picks
         return out
 
     def _window_counts(self, phase, tokens, starts, sizes):
@@ -1542,16 +1580,20 @@ class ServingEngine:
         counted model's ``SERVE_COUNTERS`` of that dispatch ride in the
         same transfer (one ``device_get`` of both, no second wait) into
         the dispatch's record in ``last_step`` and the attributes of its
-        ``serve/step`` span.  The logits block stays where it is until a
-        row of it is read."""
-        earlier = [d for d in self._uncounted if d is not logits]
-        self._uncounted = []
-        # an earlier dispatch that nothing fetched (an unsampled prefill
-        # chunk) has run by the time this one has: no second wait
-        logits.picks, *counted = jax.device_get(
-            (logits.device_picks, logits.counters,
-             *(d.counters for d in earlier)))
-        for dispatch, counters in zip([logits] + earlier, counted):
+        ``serve/step`` span.  So does every dispatch launched before it
+        that nothing has fetched (an unsampled prefill chunk; the decode
+        step a prefill was launched behind): it has run by the time this
+        one has.  One launched after it is left running.  The logits
+        block stays where it is until a row of it is read."""
+        if logits.picks is not None:    # a later dispatch's fetch brought it
+            return logits
+        upto = self._unfetched.index(logits) + 1
+        fetched, self._unfetched = (self._unfetched[:upto],
+                                    self._unfetched[upto:])
+        got = jax.device_get([(d.device_picks, d.counters)
+                              for d in fetched])
+        for dispatch, (picks, counters) in zip(fetched, got):
+            dispatch.picks = picks
             if counters is None:
                 continue
             values = dict(zip(SERVE_COUNTERS, (int(v) for v in counters)))
@@ -1747,8 +1789,25 @@ class ServingEngine:
         finished during this step (req_id → full tokens).  Expired
         deadlines are cancelled first; an injected ``serve_step`` fault
         returns {} WITHOUT mutating any request (the retry serves
-        identically), and raises only after ``serving.step_fault_limit``
-        consecutive faults."""
+        identically; a dispatch in flight is left where it is), and
+        raises only after ``serving.step_fault_limit`` consecutive
+        faults.
+
+        A one-token decode step may return with its dispatch still
+        running (``scheduler._decode_once``: while every request it
+        serves is greedy, the next step is launched on the device's own
+        picks before this one's reach the host).  What the engine shows
+        between steps follows the LAUNCHES: ``lengths``, ``tables``,
+        ``req.out``, ``n_active`` and ``queue`` are those of the next
+        dispatch, and a request is handed back once its last token is
+        known and fed, which its final dispatch need not have ended for.
+        Only the newest token of each decoding request is owed: it is
+        emitted, and ``req.last_token`` set, by the step after the one
+        that launched its dispatch (a request that is owed a token is
+        active).  Nothing has to be settled for ``drain()``, ``health()``,
+        ``leak_report()``, page export or import: whatever is launched
+        later is ordered behind the dispatch in flight on the device, and
+        an eviction drops the row it has there."""
         report = self._report
         report["t0_ns"] = time.perf_counter_ns()
         try:
@@ -1787,7 +1846,13 @@ class ServingEngine:
                 # SLO burn-rate sweep on the engine's (injectable) clock —
                 # a sustained multi-window miss fraction opens one incident
                 incidents.observe_slo(now=self._clock())
-        return self.scheduler.run_step()
+        done = self.scheduler.run_step()
+        if self._counted and self._unfetched and not self.n_active:
+            # the engine empties, so no later fetch would bring along the
+            # counters of a dispatch that nothing waited for (the one that
+            # fed every request its last token)
+            self._fetch(self._unfetched[-1])
+        return done
 
     # -- lifecycle / introspection --------------------------------------
     def pop_terminated(self) -> Dict[Any, RequestResult]:

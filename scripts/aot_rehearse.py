@@ -142,13 +142,13 @@ def serve():
                                   "prefill 512": (1, 512)}.items():
         t0 = time.time()
         # prefill and decode are two named jits over the one paged call
-        # (a prefill takes the head on the one row it samples from)
-        step_fn, rows = ((engine._step_fn, ()) if tokens == 1
-                         else (engine._prefill_fn, (ints(batch, 1),)))
+        # (a prefill takes the head on the one row it samples from, a
+        # decode step the picks of the step before it)
+        step_fn = engine._step_fn if tokens == 1 else engine._prefill_fn
         compiled = step_fn.lower(
             params, ints(batch, tokens), caches,
             ints(batch, engine.tables.shape[1]), ints(batch),
-            *rows).compile()
+            ints(batch, 1)).compile()
         report(f"serve llama-1B {name}", compiled, t0)
 
 

@@ -156,7 +156,10 @@ def test_a_greedy_run_reads_no_row_and_yields_the_parents_ids(served):
     assert done == parents
     (picked, host_rows, emitted), per = _counts(reports)
     assert picked == emitted == len(PROMPTS) * NEW and host_rows == 0
-    assert all(p == e and h == 0 for p, h, e in per)
+    # a decode dispatch's tokens reach the host in the step after the one
+    # that launched it (the engine runs ahead): the sums agree, not each
+    # report's
+    assert all(h == 0 for _, h, _ in per)
     for report in reports:
         for d in report["dispatches"]:
             assert ("picked" in d) == (d["phase"] in ("prefill", "decode"))
@@ -204,7 +207,7 @@ def test_the_harness_check_still_gets_every_row(served):
             done[rid][len(prompt):]
     (picked, host_rows, emitted), per = _counts(reports)
     assert picked == host_rows == emitted == len(PROMPTS) * NEW
-    assert all(p == h == e for p, h, e in per)
+    assert all(p == h for p, h, _ in per)
 
 
 SAMPLING = {1: dict(temperature=0.8, seed=11),

@@ -486,7 +486,8 @@ def test_serve_programs_name_their_jits_and_kernels(tiny):
         eng.params, ints(1, 16), eng.caches, ints(1, width), ints(1),
         ints(1, 1))
     decode = eng._step_fn.lower(
-        eng.params, ints(2, 1), eng.caches, ints(2, width), ints(2))
+        eng.params, ints(2, 1), eng.caches, ints(2, width), ints(2),
+        ints(2, 1))
     assert "jit_serve_prefill" in prefill.as_text()
     assert "jit_serve_decode" in decode.as_text()
     assert "ragged_paged_attention_prefill" in \

@@ -107,9 +107,11 @@ def test_trace_lifecycle_exact_latencies(tiny, tmp_path):
         a = e["attrs"]
         by.setdefault(a["req_id"], []).append(
             (e["name"].rsplit("/", 1)[1], a))
-    # request a: admitted/prefilled/first token all at t=0; its three
-    # tokens reach the host at t=0 (prefill), 1 and 2, the step at t=3
-    # appends the last and finishes; the critical-path attribution event
+    # request a: admitted/prefilled/first token all at t=0; the step at
+    # t=1 launches its first decode dispatch and leaves it running (the
+    # engine runs one dispatch ahead of the host), so its three tokens
+    # reach the host at t=0 (prefill), 2 and 3, and the step at t=3, which
+    # has fed the last, finishes it; the critical-path attribution event
     # (PR 16) follows the terminal
     stages_a = [s for s, _ in by["a"]]
     assert stages_a == ["admitted", "prefill_start", "first_token",
@@ -118,18 +120,18 @@ def test_trace_lifecycle_exact_latencies(tiny, tmp_path):
     assert fin_a["queue_wait_ms"] == 0.0 and fin_a["ttft_ms"] == 0.0
     assert fin_a["e2e_ms"] == 3000.0
     # tpot is the mean of the gaps between tokens as they reached the
-    # host (1000, 1000), not (terminal - first token) / (n - 1)
-    assert fin_a["tpot_ms"] == 1000.0
+    # host (2000, 1000), not (terminal - first token) / (n - 1)
+    assert fin_a["tpot_ms"] == 1500.0
     assert fin_a["n_generated"] == 3 and fin_a["slot"] == 0
     # request b: waited t=0..3 in queue, prefilled when a's slot freed
     fin_b = dict(by["b"])["finish"]
     assert fin_b["queue_wait_ms"] == 3000.0 and fin_b["ttft_ms"] == 3000.0
-    assert fin_b["e2e_ms"] == 6000.0 and fin_b["tpot_ms"] == 1000.0
+    assert fin_b["e2e_ms"] == 6000.0 and fin_b["tpot_ms"] == 1500.0
     # the tracer keeps every gap, on the engine's clock
     done = {tr.req_id: tr for tr in eng.tracer.completed}
-    assert done["a"].token_times == [0.0, 1.0, 2.0]
-    assert done["a"].tpot_gaps_ms() == [1000.0, 1000.0]
-    assert done["b"].tpot_gaps_ms() == [1000.0, 1000.0]
+    assert done["a"].token_times == [0.0, 2.0, 3.0]
+    assert done["a"].tpot_gaps_ms() == [2000.0, 1000.0]
+    assert done["b"].tpot_gaps_ms() == [2000.0, 1000.0]
     # registry histograms carry exactly the JSONL-derived samples
     assert sorted(tel.registry.histograms["serve/ttft_ms"].values()) == \
         [0.0, 3000.0]
@@ -139,7 +141,7 @@ def test_trace_lifecycle_exact_latencies(tiny, tmp_path):
         tel.registry.histograms["serve/queue_wait_ms"].values()) == \
         [0.0, 3000.0]
     assert tel.registry.histograms["serve/tpot_ms"].values() == \
-        [1000.0, 1000.0]
+        [1500.0, 1500.0]
 
 
 def test_tracer_unit_invariants():
