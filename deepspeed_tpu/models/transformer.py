@@ -166,7 +166,12 @@ class TransformerConfig:
     # slice, as ``(factor, original positions, beta_fast, beta_slow,
     # mscale, mscale_all_dim)`` (ops/latent_attention.py RopeYarn):
     # frequencies blended by dimension, and the softmax scale times
-    # ``mscale_all_dim``'s magnitude squared
+    # ``mscale_all_dim``'s magnitude squared.  On a model WITHOUT latent
+    # attention it is the rotary specification of the FULL-attention
+    # layers (window 0 in ``local_attn_pattern``; every layer without a
+    # pattern): rotate-half heads turned by the blended frequencies, cos
+    # and sin times the magnitude; window layers keep ``rope_theta`` /
+    # ``rope_inv_freq`` unscaled (``layer_rotary`` says which a layer is)
     rope_yarn: Optional[Tuple[float, ...]] = None
     # ``init``'s seeded token embeddings: None keeps
     # 1 / sqrt(hidden), a row of norm 1, which one layer's attention
@@ -218,8 +223,17 @@ class TransformerConfig:
         return self.local_attn_pattern[i] if self.local_attn_pattern else 0
 
     def layer_rotary(self, i):
-        return self.use_rope and (self.rope_pattern is None
-                                  or bool(self.rope_pattern[i]))
+        """Layer ``i``'s rotary kind: False (no positions), True (the
+        model's ``rope_theta`` / ``rope_inv_freq``), or ``"yarn"`` (a
+        full-attention layer under ``rope_yarn``, without latent
+        attention)."""
+        if not (self.use_rope and (self.rope_pattern is None
+                                   or bool(self.rope_pattern[i]))):
+            return False
+        if self.rope_yarn is not None and not self.is_latent \
+                and not self.layer_window(i):
+            return "yarn"
+        return True
 
     @property
     def layers_listed(self):
@@ -302,9 +316,31 @@ class TransformerConfig:
         dh = self.head_dim
         per_layer = (d * self.n_heads * dh + 2 * d * self.kv_heads * dh +
                      self.n_heads * dh * d)
-        per_layer += (3 if self.gated else 2) * d * f
         per_layer += 2 * d  # norms
+        if self.qk_norm and not self.is_latent:
+            per_layer += (self.n_heads * dh + self.kv_heads * dh
+                          if self.qk_norm == "rms_flat" else 2 * dh)
+        dense = (3 if self.gated else 2) * d * f
         total = self.n_layers * per_layer + v * d + d
+        if self.is_moe:
+            # what this process HOLDS: the router's published width, the
+            # held experts (all of them on the capacity path), the shared
+            # one; the leading and the in-between layers keep a dense FFN
+            fe = (self.moe_ffn_hidden_size or f) if self.moe_dropless else f
+            held = self.experts_held if self.moe_dropless \
+                else self.moe_num_experts
+            expert = (3 if self.gated or self.moe_dropless else 2) * d * fe
+            moe = d * self.moe_num_experts + held * expert
+            if self.moe_dropless:
+                moe += self.moe_shared_experts * expert
+                if self.moe_scoring == "sigmoid":
+                    moe += self.moe_num_experts      # the selection bias
+            n_moe = sum(
+                1 for i in range(self.first_dense_layers, self.n_layers)
+                if i % self.moe_layer_freq == self.moe_layer_freq - 1)
+            total += n_moe * moe + (self.n_layers - n_moe) * dense
+        else:
+            total += self.n_layers * dense
         if not self.tie_embeddings:
             total += v * d
             if self.lm_head_bias:
@@ -352,7 +388,9 @@ class ServeCounts:
     not an idle slot of a decode batch), the layers add their traced
     counts (``expert_load_max`` keeps the largest).  ``expert_impl`` /
     ``interpret``: what the dispatch's expert layers run their grouped
-    product on (``dropless_held_experts``)."""
+    product on (``dropless_held_experts``).  The trainer's forward keeps
+    the same account of a step (``real`` None: every row is a token;
+    ``apply(counts=)``), of which the engine hands out ``TRAIN_COUNTERS``."""
 
     def __init__(self, real, expert_impl=None, interpret=False):
         self.real = real
@@ -382,6 +420,19 @@ class ServeCounts:
         for name, column in zip(SERVE_COUNTERS, vectors.T):
             self.add(**{name: jnp.max(column) if name == "expert_load_max"
                         else jnp.sum(column)})
+
+
+# what a training step of a dropless expert model counts on the device
+# (``loss(counted=True)`` returns them as one int32 vector beside the loss,
+# summed over the expert layers; the engine sums them over a step's
+# micro-batches, the load's maximum kept: ``train/moe/<name>`` gauges)
+TRAIN_COUNTERS = ("expert_pairs", "expert_load_max", "expert_rows")
+
+
+def merge_train_counters(a, b):
+    """Two micro-batches' ``TRAIN_COUNTERS`` vectors as one."""
+    largest = jnp.asarray([n.endswith("_max") for n in TRAIN_COUNTERS])
+    return jnp.where(largest, jnp.maximum(a, b), a + b)
 
 
 def _hold_expert_stack(stacked_layer):
@@ -544,15 +595,18 @@ def chunked_next_token_xent(x, head, head_b, batch, chunk_size: int,
     return nll_sum / jnp.maximum(m_sum, 1.0)
 
 
-def _rope(x, positions, theta, rope_dim=None, inv_freq=None):
+def _rope(x, positions, theta, rope_dim=None, inv_freq=None, magnitude=1.0):
     """Rotary embedding; x: [B, S, H, D].  ``rope_dim`` < D rotates only the
     leading dims (GPT-NeoX partial rotary).  ``inv_freq``: per-dim inverse
     frequencies overriding the theta power law — how Llama-3 / linear
-    rope scaling ships (the policy precomputes the scaled table)."""
+    rope scaling ships (the policy precomputes the scaled table).
+    ``magnitude``: what cos and sin are multiplied by (YaRN's
+    ``attention_factor``)."""
     if rope_dim is not None and rope_dim < x.shape[-1]:
         rot, rest = x[..., :rope_dim], x[..., rope_dim:]
         return jnp.concatenate(
-            [_rope(rot, positions, theta, inv_freq=inv_freq), rest], axis=-1)
+            [_rope(rot, positions, theta, inv_freq=inv_freq,
+                   magnitude=magnitude), rest], axis=-1)
     B, S, H, D = x.shape
     half = D // 2
     if inv_freq is not None:
@@ -566,6 +620,8 @@ def _rope(x, positions, theta, rope_dim=None, inv_freq=None):
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if magnitude != 1.0:
+        cos, sin = cos * magnitude, sin * magnitude
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -950,10 +1006,16 @@ class CausalTransformerLM:
             k = _norm(k, layer["k_norm"], c.norm_eps, rms,
                       layer.get("k_norm_b"))
         if c.use_rope and rotary:
-            q = _rope(q, positions, c.rope_theta, c.rope_dim,
-                      inv_freq=c.rope_inv_freq)
-            k = _rope(k, positions, c.rope_theta, c.rope_dim,
-                      inv_freq=c.rope_inv_freq)
+            turn = functools.partial(_rope, positions=positions,
+                                     theta=c.rope_theta, rope_dim=c.rope_dim,
+                                     inv_freq=c.rope_inv_freq)
+            if rotary == "yarn":    # a full-attention layer's own kind
+                from deepspeed_tpu.ops.latent_attention import RopeYarn
+                yarn = RopeYarn(*c.rope_yarn)
+                turn = functools.partial(
+                    turn, inv_freq=yarn.inv_freq(c.rotary_dim, c.rope_theta),
+                    magnitude=yarn.rotary_magnitude)
+            q, k = turn(q), turn(k)
         return q, k, v
 
     def _attn_bias(self, layer, Sq, Sk):
@@ -978,6 +1040,18 @@ class CausalTransformerLM:
         """Causal attention over the whole sequence, no cache (the trainer,
         ``apply``)."""
         c = self.config
+        window = layer.get("attn_window")
+        if c.attn_window and isinstance(window, int):
+            # the layer's kind is static (a scanned period's place): its
+            # work carries the kind's name, and a full layer takes the
+            # plain causal path
+            layer = dict(layer)
+            if window:
+                layer["attn_window"] = jnp.int32(window)
+            else:
+                del layer["attn_window"]
+            with jax.named_scope("attn_window" if window else "attn_full"):
+                return self.mix_full(q, k, v, layer, cache)
         H, Hkv = c.n_heads, c.kv_heads
         has_alibi = c.use_alibi
         has_window = "attn_window" in layer
@@ -1296,7 +1370,7 @@ class CausalTransformerLM:
                 ).astype(attn.dtype)
         return self._proj(attn, layer, "wo"), cache
 
-    def _dropless_delta(self, h, layer, counts=None):
+    def _dropless_delta(self, h, layer, counts=None, train=False):
         """The dropless expert layer, this chip's share of it: routing
         over all experts, the held experts' terms by a grouped product
         with no capacity, plus the ungated shared expert."""
@@ -1306,12 +1380,20 @@ class CausalTransformerLM:
         moe = layer["moe"]
         B, T, d = h.shape
         flat = h.reshape(B * T, d)
+        # the balance statistic is the trainer's (``loss`` weighs it);
+        # a serving dispatch (``train`` False) routes and nothing more
+        balance = train and c.moe_aux_loss_coef > 0
+        aux = jnp.float32(0.0)
         with jax.named_scope("router"):
-            chosen, weights = dropless_route(
+            chosen, weights, *scores = dropless_route(
                 flat, moe["wg"], moe.get("router_bias"), c.moe_top_k,
                 scoring=c.moe_scoring, scale=c.moe_routed_scale,
-                norm=c.moe_norm_topk_prob, norm_eps=c.moe_route_norm_eps)
-        if counts is not None:
+                norm=c.moe_norm_topk_prob, norm_eps=c.moe_route_norm_eps,
+                with_scores=balance)
+            if balance:
+                from deepspeed_tpu.moe.sharded_moe import balance_statistic
+                aux = balance_statistic(chosen, scores[0])
+        if counts is not None and counts.real is not None:
             # bucket padding and idle slots are nobody's tokens: their
             # pairs are neither computed nor counted
             chosen = jnp.where(counts.real.reshape(B * T, 1), chosen, -1)
@@ -1331,14 +1413,14 @@ class CausalTransformerLM:
         if counts is not None:
             counts.add(expert_pairs=jnp.sum(load),
                        expert_load_max=jnp.max(load), expert_rows=rows)
-        return out.reshape(B, T, d), jnp.float32(0.0)
+        return out.reshape(B, T, d), aux
 
     @jax.named_scope("mlp")
     def _mlp_delta(self, h, layer, rng=None, train=True, counts=None):
         """FFN sub-block on pre-normed input; returns (delta, aux_loss)."""
         c = self.config
         if "moe" in layer and c.moe_dropless:
-            return self._dropless_delta(h, layer, counts)
+            return self._dropless_delta(h, layer, counts, train)
         if "moe" in layer:
             from deepspeed_tpu.moe.sharded_moe import moe_layer_forward
             act = _ACTIVATIONS[c.activation]
@@ -1426,13 +1508,18 @@ class CausalTransformerLM:
                                      counts=counts)
         return x + self._sandwich(delta, layer, "mlp_post_norm"), cache, aux
 
-    def _layer(self, x, layer, positions, rng=None, train=True, rotary=True):
+    def _layer(self, x, layer, positions, rng=None, train=True, rotary=True,
+               window=None, counts=None):
         """The block over a whole sequence → (x, aux): what ``apply``
-        scans and ``stream_layer`` / ``runtime/pipe`` call."""
+        scans and ``stream_layer`` / ``runtime/pipe`` call.  ``window``
+        (static; None: whatever ``layer["attn_window"]`` holds): the
+        layer's sliding window, 0 for a full-attention layer."""
         mix = self.mix_latent_whole if self.config.is_latent \
             else self.mix_full
+        if window is not None:
+            layer = dict(layer, attn_window=int(window))
         x, _, aux = self.block(x, layer, positions, mix, rng=rng,
-                               train=train, rotary=rotary)
+                               train=train, rotary=rotary, counts=counts)
         return x, aux
 
     def layer_list(self, params):
@@ -1496,10 +1583,15 @@ class CausalTransformerLM:
                 if c.local_attn_pattern else None)
 
     def apply(self, params, input_ids, positions=None, rng=None, train=True,
-              return_aux=False, return_hidden=False):
+              return_aux=False, return_hidden=False, counts=None):
         """Logits [B, S, V] of a whole sequence; ``return_hidden`` gives the
         last hidden state BEFORE the final norm (what ``stream_head_loss``
-        takes) and the MoE aux loss."""
+        takes) and the MoE aux loss.  ``counts`` (a :class:`ServeCounts`
+        with ``real`` None): the expert layers add their pairs, load and
+        rows to it.  A model with ``layer_period`` runs its leading layers
+        one by one and SCANS the periods after them, a period's places
+        unrolled in the body, each with its own static window, rotary
+        kind and scope."""
         c = self.config
         B, S = input_ids.shape
         if positions is None:
@@ -1512,19 +1604,57 @@ class CausalTransformerLM:
         aux = jnp.float32(0.0)
         windows = self._windows()
         if isinstance(params["layers"], (list, tuple)):
-            # MoE / heterogeneous stack: unrolled layer loop
-            layer_fn = self._layer
-            if c.remat:
-                policy = getattr(jax.checkpoint_policies, c.remat_policy, None)
-                layer_fn = jax.checkpoint(layer_fn, policy=policy,
-                                          static_argnums=(4, 5))
-            for i, layer in enumerate(self.layer_list(params)):
-                if windows is not None:
+            # MoE / heterogeneous stack: unrolled layer loop, then a scan
+            # over the periods that follow it
+            scanned = c.layer_period and "periods" in params
+            policy = getattr(jax.checkpoint_policies, c.remat_policy, None)
+
+            def run(indices, static_window, x, layers, positions, rngs):
+                """The blocks of layers ``indices`` (their static pattern)
+                one after another → (x, aux, what they counted)."""
+                inner = None if counts is None else counts.fresh()
+                total = jnp.float32(0.0)
+                for i, layer, lrng in zip(indices, layers, rngs):
+                    x, l_aux = self._layer(
+                        x, layer, positions, lrng, train, c.layer_rotary(i),
+                        c.layer_window(i) if static_window else None, inner)
+                    total = total + l_aux
+                return x, total, None if inner is None else inner.vector()
+
+            def checkpointed(fn):
+                return jax.checkpoint(fn, policy=policy) if c.remat else fn
+
+            listed = params["layers"] if scanned else self.layer_list(params)
+            for i, layer in enumerate(listed):
+                if windows is not None and not scanned:
                     layer = dict(layer, attn_window=windows[i])
                 lrng = jax.random.fold_in(rng, i) if rng is not None else None
-                x, l_aux = layer_fn(x, layer, positions, lrng, train,
-                                    c.layer_rotary(i))
+                x, l_aux, counted = checkpointed(functools.partial(
+                    run, (i,), scanned))(x, (layer,), positions, (lrng,))
                 aux = aux + l_aux
+                if counts is not None:
+                    counts.absorb(counted[None])
+            if scanned:
+                lead, period = c.leading_layers, c.layer_period
+                at = tuple(range(lead, lead + period))
+
+                def one_period(x, inp):
+                    layers, p = inp
+                    rngs = tuple(
+                        None if rng is None else jax.random.fold_in(
+                            rng, lead + p * period + j)
+                        for j in range(period))
+                    x, l_aux, counted = run(at, True, x, layers, positions,
+                                            rngs)
+                    return x, (l_aux, counted)
+
+                x, (l_auxs, counted) = layer_scan(
+                    checkpointed(one_period), x,
+                    (tuple(params["periods"]),
+                     jnp.arange((c.n_layers - lead) // period)))
+                aux = aux + jnp.sum(l_auxs)
+                if counts is not None:
+                    counts.absorb(counted)
         else:
             def body(x, inp):
                 if windows is not None:
@@ -1886,11 +2016,28 @@ class CausalTransformerLM:
         return next_token_xent(self.logits(resident, x), batch)
 
     # ------------------------------------------------------------------
-    def loss(self, params, batch, rng=None):
+    merge_train_counters = staticmethod(merge_train_counters)
+
+    @property
+    def train_counters(self):
+        """Names of what ``loss(counted=True)`` counts beside the loss
+        (``TRAIN_COUNTERS`` for a dropless expert model, else none)."""
+        c = self.config
+        return TRAIN_COUNTERS if c.is_moe and c.moe_dropless else ()
+
+    def loss(self, params, batch, rng=None, counted=False):
         """Next-token cross-entropy.  batch: dict with ``input_ids`` [B,S]
-        (+ optional ``labels``, ``loss_mask``) or a raw [B,S] array."""
+        (+ optional ``labels``, ``loss_mask``) or a raw [B,S] array.
+        ``counted``: (loss, int32 vector of ``train_counters``)."""
         input_ids = batch["input_ids"] if isinstance(batch, dict) else batch
-        x, aux = self.apply(params, input_ids, rng=rng, return_hidden=True)
+        counts = ServeCounts(None) if counted else None
+        x, aux = self.apply(params, input_ids, rng=rng, return_hidden=True,
+                            counts=counts)
         ce = self.stream_head_loss(params, x, batch)
         # MoE load-balancing loss (reference engine adds l_aux scaled by coef)
-        return ce + self.config.moe_aux_loss_coef * aux
+        loss = ce + self.config.moe_aux_loss_coef * aux
+        if not counted:
+            return loss
+        return loss, jnp.stack([
+            jnp.asarray(counts.counts.get(name, 0), jnp.int32)
+            for name in self.train_counters])

@@ -13,8 +13,9 @@ so the program never retraces.  Everything is fp32 at the gate (reference
 casts gate logits to fp32 too).
 """
 
+import functools
 import math
-from typing import NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -393,7 +394,7 @@ COMBINE_COLS = 2048
 
 
 def dropless_route(h, wg, bias, k, scoring="sigmoid", scale=1.0, norm=True,
-                   norm_eps=0.0):
+                   norm_eps=0.0, with_scores=False):
     """Routing with no capacity: every token gets its ``k`` experts.
     Scores over ALL experts in float32 — ``sigmoid(h wg)`` (``noaux_tc``
     with one group) or ``softmax(h wg)`` — the ``k`` largest of ``scores +
@@ -401,7 +402,9 @@ def dropless_route(h, wg, bias, k, scoring="sigmoid", scale=1.0, norm=True,
     lower index), weighted by their UNBIASED scores, normalised over the
     chosen ones if ``norm`` (their sum plus ``norm_eps``, where a model
     states one), times ``scale``.  h: [N, d] ->
-    (chosen [N, k] int32, weights [N, k] float32)."""
+    (chosen [N, k] int32, weights [N, k] float32), and the scores [N, E]
+    with ``with_scores``.  Differentiable through the chosen scores (the
+    choice itself has no gradient)."""
     if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"moe_scoring {scoring!r}: 'sigmoid' or 'softmax'")
     logits = jnp.dot(h.astype(jnp.float32), wg.astype(jnp.float32),
@@ -414,7 +417,181 @@ def dropless_route(h, wg, bias, k, scoring="sigmoid", scale=1.0, norm=True,
     if norm:
         total = jnp.sum(weights, axis=-1, keepdims=True)
         weights = weights / (total + norm_eps if norm_eps else total)
-    return chosen.astype(jnp.int32), weights * scale
+    chosen = chosen.astype(jnp.int32)
+    if with_scores:
+        return chosen, weights * scale, scores
+    return chosen, weights * scale
+
+
+def balance_statistic(chosen, scores):
+    """``E * sum_e f_e P_e`` of one expert layer's routing (the Switch /
+    GShard load-balancing term): ``f_e`` the share of the (token, choice)
+    pairs on expert ``e``, ``P_e`` the mean router score of ``e``, both
+    over ALL ``E`` experts of the router; 1.0 at a uniform routing.  The
+    gradient flows through ``P`` alone."""
+    E = scores.shape[-1]
+    share = jnp.mean(
+        (chosen[..., None] == jnp.arange(E)).astype(jnp.float32),
+        axis=(0, 1))
+    return E * jnp.sum(share * jnp.mean(scores, axis=0))
+
+
+class _HeldPlan(NamedTuple):
+    """Where one call's (token, expert) pairs lie in the sorted, tiled
+    list (all int32 / bool: nothing here has a gradient)."""
+    load: Any           # [held] pairs an expert
+    begin: Any          # [held] an expert's first sorted pair
+    start: Any          # [held] its first row tile
+    n_tiles: Any        # scalar: tiles that hold pairs
+    tile_expert: Any    # the expert of every tile the list can have
+    order: Any          # [N k] sorted place -> pair
+    row: Any            # [N, k] each pair's row in the padded list
+    mine: Any           # [N, k] the pair's expert is held here
+    layer: Any          # the layer to read in stacked leaves (or 0)
+
+
+class _HeldStatic(NamedTuple):
+    act: Any
+    tiles: Any          # glu.ExpertTiles
+    kernel: bool        # the Pallas kernels, else their jnp cousins
+    interpret: bool
+    stacked: bool       # the leaves are [L, held, ...]
+
+
+def _chunk_rows(plan, tile, chunk_tiles, n_pairs, c):
+    """Chunk ``c`` of the sorted list: (first tile, each row's sorted place
+    [tiles, tile], which rows hold a pair, each pair's row in the chunk
+    [N, k], which pairs are held here and lie in it)."""
+    base = c * chunk_tiles
+    t = jnp.minimum(base + jnp.arange(chunk_tiles),
+                    plan.tile_expert.shape[0] - 1)
+    e = plan.tile_expert[t]
+    before = (t - plan.start[e]) * tile
+    in_tile = jnp.arange(tile)[None, :]
+    at = jnp.minimum((plan.begin[e] + before)[:, None] + in_tile, n_pairs - 1)
+    local = plan.row - base * tile
+    here = plan.mine & (local >= 0) & (local < chunk_tiles * tile)
+    return (base, at, in_tile < (plan.load[e] - before)[:, None],
+            jnp.clip(local, 0, chunk_tiles * tile - 1), here)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_sum(static, h, weights, w_gate, w_up, w_down, plan):
+    """``sum_j w[n, j] GLU_{chosen[n, j]}(h[n])`` over the held pairs, by
+    the plan: [N, d] float32."""
+    from deepspeed_tpu.ops.pallas import grouped_expert_glu as glu
+    N, d = h.shape
+    k = plan.row.shape[1]
+    held = plan.load.shape[0]
+    tiles, tile = static.tiles, static.tiles.rows
+    product = glu.grouped_expert_glu if static.kernel else glu.grouped_glu_jnp
+    layer = plan.layer if static.stacked else None
+    # a chunk: the rows that one pair a token fills, every expert padded
+    chunk_tiles = -(-N // tile) + held
+    token_sorted = plan.order // k
+
+    def chunk(c, out):
+        base, at, valid, local, here = _chunk_rows(plan, tile, chunk_tiles,
+                                                   N * k, c)
+        token = jnp.where(valid, token_sorted[at], 0)
+        x = h[token.reshape(-1)]
+        y = product(x, w_gate, w_up, w_down, plan.tile_expert,
+                    jnp.minimum(plan.n_tiles - base, chunk_tiles),
+                    static.act, tiles, layer=layer, base=base,
+                    interpret=static.interpret)
+        # each token's terms of this chunk, back at the token, a block of
+        # columns at a time (``COMBINE_COLS``)
+        blocks = []
+        for b, block in enumerate(out):
+            part = y[:, b * cols:(b + 1) * cols]
+            for j in range(k):
+                block = block + jnp.where(
+                    here[:, j, None],
+                    part[local[:, j]].astype(jnp.float32)
+                    * weights[:, j, None], 0.0)
+            blocks.append(block)
+        return tuple(blocks)
+
+    cols = COMBINE_COLS if d % COMBINE_COLS == 0 else d
+    out = jax.lax.fori_loop(
+        0, -(-plan.n_tiles // chunk_tiles), chunk,
+        tuple(jnp.zeros((N, cols), jnp.float32) for _ in range(d // cols)))
+    return jnp.concatenate(out, axis=1)
+
+
+def _held_sum_fwd(static, h, weights, w_gate, w_up, w_down, plan):
+    # nothing of the forward is kept but its operands: the backward runs
+    # the gate and up products again, a chunk at a time
+    return (_held_sum(static, h, weights, w_gate, w_up, w_down, plan),
+            (h, weights, w_gate, w_up, w_down, plan))
+
+
+def _held_sum_bwd(static, kept, d_out):
+    """The same chunks again.  A chunk gathers its rows of ``h`` and of the
+    cotangent (the weighted combine's transpose is a gather by the sorted
+    order; a tile's padding rows get zeros and so add nothing to any
+    gradient), the two kernels give the rows' gradient and add the tiles'
+    weight gradients into their experts' float32 accumulators, and each
+    token gathers its ``k`` rows' gradients back and sums them (the row
+    gather's transpose; no scatter)."""
+    from deepspeed_tpu.ops.pallas import grouped_expert_glu as glu
+    h, weights, w_gate, w_up, w_down, plan = kept
+    N, d = h.shape
+    k = plan.row.shape[1]
+    held, _, f = w_up.shape[-3:]
+    tile = static.tiles.rows
+    cols_dx, cols_dw = glu.backward_cols(tile, d, f, h.dtype.itemsize)
+    dx_of, dw_of = (
+        (glu.grouped_expert_glu_dx, glu.grouped_expert_glu_dw)
+        if static.kernel else (glu.grouped_glu_dx_jnp, glu.grouped_glu_dw_jnp))
+    layer = plan.layer if static.stacked else None
+    chunk_tiles = -(-N // tile) + held
+    g_out = d_out.astype(h.dtype)
+    pair_weight = weights.astype(jnp.float32).reshape(-1)
+
+    def chunk(c, carry):
+        d_h, d_weights, acc = carry
+        base, at, valid, local, here = _chunk_rows(plan, tile, chunk_tiles,
+                                                   N * k, c)
+        live = jnp.minimum(plan.n_tiles - base, chunk_tiles)
+        pair = plan.order[at]
+        token = jnp.where(valid, pair // k, 0).reshape(-1)
+        valid = valid.reshape(-1)
+        x = h[token]
+        g = jnp.where(valid[:, None], g_out[token], 0)
+        w = jnp.where(valid, pair_weight[pair.reshape(-1)], 0.0)
+        dx, d_gate, d_up, inner, dw = dx_of(
+            x, g, w, w_gate, w_up, w_down, plan.tile_expert, live,
+            static.act, tile, cols_dx, layer=layer, base=base,
+            interpret=static.interpret)
+        acc = dw_of(x, g, d_gate, d_up, inner, acc, plan.tile_expert, live,
+                    tile, cols_dw, base=base, interpret=static.interpret)
+        for j in range(k):
+            d_h = d_h + jnp.where(here[:, j, None],
+                                  dx[local[:, j]].astype(jnp.float32), 0.0)
+        return d_h, d_weights + jnp.where(here, dw[local], 0.0), acc
+
+    d_h, d_weights, acc = jax.lax.fori_loop(
+        0, -(-plan.n_tiles // chunk_tiles), chunk,
+        (jnp.zeros((N, d), jnp.float32), jnp.zeros((N, k), jnp.float32),
+         (jnp.zeros((held, d, f), jnp.float32),
+          jnp.zeros((held, d, f), jnp.float32),
+          jnp.zeros((held, f, d), jnp.float32))))
+
+    def of_leaf(grad, leaf):
+        grad = grad.astype(leaf.dtype)
+        if not static.stacked:
+            return grad
+        # the one layer read in the stack; the others' gradients are zero
+        return jax.lax.dynamic_update_index_in_dim(
+            jnp.zeros_like(leaf), grad, plan.layer, 0)
+
+    return (d_h.astype(h.dtype), d_weights.astype(weights.dtype),
+            of_leaf(acc[0], w_gate), of_leaf(acc[1], w_up),
+            of_leaf(acc[2], w_down), None)
+
+
+_held_sum.defvjp(_held_sum_fwd, _held_sum_bwd)
 
 
 def dropless_held_experts(h, chosen, weights, experts, act, first=0,
@@ -443,6 +620,11 @@ def dropless_held_experts(h, chosen, weights, experts, act, first=0,
     adds nothing; ``COMBINE_COLS`` columns at a time): no scatter anywhere (docs/serving.md, "The dropless
     expert layer").
 
+    Differentiable (a ``custom_vjp``, :func:`_held_sum_bwd`) with respect
+    to ``h``, ``weights`` (and so the router) and the three expert leaves:
+    the backward is grouped products over the same tiles, in the same
+    chunks, and follows the pairs as the forward does.
+
     ``tile``: the row tile (default: from the shapes,
     ``pick_expert_tiles``).  With ``layer`` (may be traced) the expert
     leaves are a STACK of layers' experts, [L, E_held, ...], and that
@@ -456,8 +638,6 @@ def dropless_held_experts(h, chosen, weights, experts, act, first=0,
     held, _, f = experts["w_up"].shape[-3:]
     tiles = glu.pick_expert_tiles(N, held, d, f, h.dtype.itemsize, rows=tile)
     tile = tiles.rows
-    product = glu.grouped_expert_glu if use_pallas(impl) \
-        else glu.grouped_glu_jnp
 
     flat = chosen.reshape(-1) - first
     mine = (flat >= 0) & (flat < held)
@@ -479,49 +659,13 @@ def dropless_held_experts(h, chosen, weights, experts, act, first=0,
     at = jnp.minimum(expert_sorted, held - 1)
     row_sorted = start[at] * tile + pairs - begin[at]
     _, row = jax.lax.sort((order, row_sorted), num_keys=1)
-    row = row.reshape(N, k)
-    mine = mine.reshape(N, k)
-    weights = weights.astype(jnp.float32)
-
-    # a chunk: the rows that one pair a token fills, every expert padded
-    chunk_tiles = -(-N // tile) + held
-    token_sorted = order // k
-    in_tile = jnp.arange(tile)[None, :]
-
-    def chunk(c, out):
-        base = c * chunk_tiles
-        # each of the chunk's tiles: its expert, its first sorted pair and
-        # how many of its rows hold one
-        t = jnp.minimum(base + jnp.arange(chunk_tiles),
-                        tile_expert.shape[0] - 1)
-        e = tile_expert[t]
-        before = (t - start[e]) * tile
-        at = jnp.minimum((begin[e] + before)[:, None] + in_tile, N * k - 1)
-        token = jnp.where(in_tile < (load[e] - before)[:, None],
-                          token_sorted[at], 0)
-        x = h[token.reshape(-1)]
-        y = product(x, experts["w_gate"], experts["w_up"],
-                    experts["w_down"], tile_expert,
-                    jnp.minimum(n_tiles - base, chunk_tiles), act, tiles,
-                    layer=layer, base=base, interpret=interpret)
-        # each token's terms of this chunk, back at the token, a block of
-        # columns at a time (``COMBINE_COLS``)
-        local = row - base * tile
-        here = mine & (local >= 0) & (local < chunk_tiles * tile)
-        local = jnp.clip(local, 0, chunk_tiles * tile - 1)
-        blocks = []
-        for b, block in enumerate(out):
-            part = y[:, b * cols:(b + 1) * cols]
-            for j in range(k):
-                block = block + jnp.where(
-                    here[:, j, None],
-                    part[local[:, j]].astype(jnp.float32)
-                    * weights[:, j, None], 0.0)
-            blocks.append(block)
-        return tuple(blocks)
-
-    cols = COMBINE_COLS if d % COMBINE_COLS == 0 else d
-    out = jax.lax.fori_loop(
-        0, -(-n_tiles // chunk_tiles), chunk,
-        tuple(jnp.zeros((N, cols), jnp.float32) for _ in range(d // cols)))
-    return jnp.concatenate(out, axis=1), load, n_tiles * tile
+    stacked = experts["w_up"].ndim == 4
+    plan = _HeldPlan(load, begin, start, n_tiles, tile_expert, order,
+                     row.reshape(N, k), mine.reshape(N, k),
+                     jnp.asarray(layer if stacked else 0, jnp.int32))
+    out = _held_sum(
+        _HeldStatic(act, tiles, bool(use_pallas(impl)), bool(interpret),
+                    stacked),
+        h, weights.astype(jnp.float32), experts["w_gate"], experts["w_up"],
+        experts["w_down"], plan)
+    return out, load, n_tiles * tile

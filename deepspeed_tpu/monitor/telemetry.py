@@ -75,7 +75,7 @@ SPAN_NAMES = (
     "checkpoint/load", "checkpoint/save",
     "engine/forward", "engine/backward", "engine/step",
     "engine/train_batch", "engine/input", "engine/dispatch",
-    "engine/input_wait", "param_stream/train_step",
+    "engine/input_wait", "engine/monitor", "param_stream/train_step",
     "serve/loop", "serve/admit", "serve/step",
     "serve/prefill", "serve/prefill/build", "serve/prefill/fetch",
     "serve/prefill/sample",

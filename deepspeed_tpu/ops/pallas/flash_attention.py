@@ -157,6 +157,23 @@ def _bias_inputs(alibi_slopes, window, B, H):
     return slopes_bh, w_bh
 
 
+# Mosaic's default scoped VMEM, which the kernels' double-buffered blocks
+# fit up to about 4k positions; a call whose blocks take more asks for it
+DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+
+
+def _vmem_params(block_bytes):
+    """``pallas_call`` keywords for a call whose blocks (one buffer of
+    each) take ``block_bytes``: nothing while two buffers of each fit the
+    default with room for the kernel's own temporaries (every program of
+    a shorter sequence stays as it was), else a limit of its own."""
+    need = 2 * block_bytes + 4 * 2 ** 20
+    if need <= DEFAULT_SCOPED_VMEM:
+        return {}
+    return dict(compiler_params=pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(need + need // 4, 100 * 2 ** 20))))
+
+
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret=False,
                alibi_slopes=None, window=None):
     B, S, H, D = q.shape
@@ -204,6 +221,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret=False,
         ],
         interpret=interpret,
         name="flash_attention_fwd",     # the instruction's name in a trace
+        **_vmem_params(2 * S * D * k.dtype.itemsize
+                       + 2 * block_q * D * q.dtype.itemsize),
     )(*args)
 
     out = jnp.swapaxes(out.reshape(B, H, S, D), 1, 2)
@@ -385,6 +404,8 @@ def _flash_bwd_pallas(scale, causal, res, g, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         interpret=interpret,
         name="flash_attention_dq",
+        **_vmem_params(2 * S * D * k.dtype.itemsize
+                       + 3 * block_q * D * q.dtype.itemsize),
     )(qr, kr, vr, gr, lser, delta, *scalar_args)
 
     full_spec = pl.BlockSpec((1, S, D), lambda bh, ki: (bh, 0, 0))
@@ -417,6 +438,10 @@ def _flash_bwd_pallas(scale, causal, res, g, block_q, block_k,
         ],
         interpret=interpret,
         name="flash_attention_dkv",
+        # whole-sequence q and dO, and lse and delta as [S, 1] float32
+        # columns, which VMEM pads to 128 lanes
+        **_vmem_params(2 * S * D * q.dtype.itemsize + 2 * S * 128 * 4
+                       + 2 * block_k * D * (k.dtype.itemsize + 4)),
     )(qr, kr, vr, gr, lser, delta, *scalar_args)
 
     dq = jnp.swapaxes(dq.reshape(B, H, S, D), 1, 2)

@@ -107,6 +107,10 @@ class StepMetrics:
     lr: jnp.ndarray
     loss_scale: jnp.ndarray
     overflow: jnp.ndarray
+    # what the model counted on the device this step (int32 vector, one
+    # entry a name of ``model.train_counters``; None for a model that
+    # counts nothing): the ``train/moe/*`` gauges
+    counters: Any = None
 
 
 def _global_norm_f32(grads) -> jnp.ndarray:
@@ -152,6 +156,10 @@ class DeepSpeedEngine:
                  training_data=None):
         self.module = model
         self.loss_fn = self._resolve_loss_fn(model)
+        # what the model counts on the device beside its loss
+        # (``model.loss(counted=True)``; models/transformer.py
+        # TRAIN_COUNTERS): outputs of the fused train step
+        self._train_counters = tuple(getattr(model, "train_counters", ()))
         self._config = config
         self.accelerator = get_accelerator()
 
@@ -699,7 +707,10 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------
     def _loss_and_grads(self, params, loss_scale, batch, rng, step=None,
                         qstep=None):
-        """value_and_grad of the (possibly loss-scaled) compute-dtype loss.
+        """value_and_grad of the (possibly loss-scaled) compute-dtype loss:
+        ``(loss, grads, counters)``, the last the model's own counters of
+        the micro-batch (``model.loss(counted=True)``), None for a model
+        that counts nothing.
 
         ``qstep`` is the MoQ anneal clock — the *successful*-step counter
         (global_step - skipped_steps), because the reference Quantizer skips
@@ -716,16 +727,24 @@ class DeepSpeedEngine:
         def scaled_loss(p):
             with jax.named_scope("fwd"):
                 p_c = self._transformed_compute_params(p, rng, step, qstep)
-                return self._model_scaled_loss(p_c, batch, rng, loss_scale)
+                if self._train_counters:
+                    loss, counters = self.loss_fn(p_c, batch, rng,
+                                                  counted=True)
+                    return (loss * loss_scale).astype(jnp.float32), \
+                        (loss, counters)
+                scaled, loss = self._model_scaled_loss(p_c, batch, rng,
+                                                       loss_scale)
+                return scaled, (loss, None)
 
-        (_, loss), grads = jax.value_and_grad(scaled_loss, has_aux=True)(params)
+        (_, (loss, counters)), grads = jax.value_and_grad(
+            scaled_loss, has_aux=True)(params)
         # unscale in fp32, then store at grad_accum_dtype (XLA fuses the
         # round-trip; bf16 storage halves the grad tree / GAS carry)
         with jax.named_scope("bwd"):
             grads = jax.tree_util.tree_map(
                 lambda g: (g.astype(jnp.float32) / loss_scale).astype(
                     self.grad_accum_dtype), grads)
-        return loss, grads
+        return loss, grads, counters
 
     def _transformed_compute_params(self, p, rng, step, qstep):
         """Compute-dtype view of the params with the cast-site transforms
@@ -921,7 +940,8 @@ class DeepSpeedEngine:
             prev = tuple(sub)
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    def _finish_step(self, state: TrainState, loss, grads, rng):
+    def _finish_step(self, state: TrainState, loss, grads, rng,
+                     counters=None):
         """Shared train-step tail: grad placement constraint, overflow
         check, optimizer update, metrics.  Used by both the dense and the
         pipeline engines so their semantics cannot diverge."""
@@ -939,33 +959,39 @@ class DeepSpeedEngine:
                 lr=jnp.asarray(self._schedule_fn(state.global_step),
                                jnp.float32),
                 loss_scale=new_state.loss_scale.cur_scale,
-                overflow=overflow)
+                overflow=overflow, counters=counters)
         return new_state, metrics
 
     def _forward_grads(self, params, scale, step_rng, batch, gas: int,
                        step=None, qstep=None):
         """GAS microbatch accumulation (``lax.scan``) shared by the fused and
         the offload step builders (reference: one grad-accumulation semantic,
-        ``backward:1931`` scaling by 1/GAS)."""
+        ``backward:1931`` scaling by 1/GAS): ``(loss, grads, counters)``,
+        the model's counters merged over the micro-batches, or None."""
         if gas > 1:
             def micro(carry, inp):
                 idx, mb = inp
-                acc, rloss = carry
+                acc, rloss, counters = carry
                 mb_rng = jax.random.fold_in(step_rng, idx)
-                loss, grads = self._loss_and_grads(params, scale, mb, mb_rng,
-                                                   step=step, qstep=qstep)
+                loss, grads, counted = self._loss_and_grads(
+                    params, scale, mb, mb_rng, step=step, qstep=qstep)
                 with jax.named_scope("bwd"):
                     acc = jax.tree_util.tree_map(jnp.add, acc, grads)
-                return (acc, rloss + loss), None
+                if counted is not None:
+                    counters = self.module.merge_train_counters(counters,
+                                                                counted)
+                return (acc, rloss + loss, counters), None
 
             zeros = jax.tree_util.tree_map(
                 lambda x: jnp.zeros(x.shape, self.grad_accum_dtype), params)
-            (gsum, lsum), _ = jax.lax.scan(
-                micro, (zeros, jnp.float32(0.0)),
+            uncounted = jnp.zeros((len(self._train_counters),), jnp.int32) \
+                if self._train_counters else None
+            (gsum, lsum, counters), _ = jax.lax.scan(
+                micro, (zeros, jnp.float32(0.0), uncounted),
                 (jnp.arange(gas), batch))
             with jax.named_scope("bwd"):
                 grads = jax.tree_util.tree_map(lambda g: g / gas, gsum)
-            return lsum / gas, grads
+            return lsum / gas, grads, counters
         return self._loss_and_grads(params, scale, batch, step_rng, step=step,
                                     qstep=qstep)
 
@@ -980,14 +1006,17 @@ class DeepSpeedEngine:
                 scale = (state.loss_scale.cur_scale if fp16
                          else jnp.float32(1.0))
                 rng, step_rng = jax.random.split(state.rng)
-                loss, grads = self._forward_grads(
+                # a model that counts its own work (``train_counters``)
+                # hands the counts out beside the loss: outputs of this
+                # program, read with the step's other metrics
+                loss, grads, counters = self._forward_grads(
                     state.params, scale, step_rng, batch, gas,
                     step=state.global_step,
                     qstep=moq_anneal_step(state))
                 # ZeRO grad placement: stage>=2 spec is fsdp-sharded → XLA
                 # lowers the DP reduction as reduce-scatter (reference
                 # average_tensor / __reduce_and_partition_ipg_grads)
-                return self._finish_step(state, loss, grads, rng)
+                return self._finish_step(state, loss, grads, rng, counters)
 
         return train_step
 
@@ -1029,7 +1058,7 @@ class DeepSpeedEngine:
                     scale = (state.loss_scale.cur_scale if fp16
                              else jnp.float32(1.0))
                     rng, step_rng = jax.random.split(state.rng)
-                    loss, grads = self._forward_grads(
+                    loss, grads, _ = self._forward_grads(
                         state.params, scale, step_rng, batch, gas,
                         step=state.global_step,
                         qstep=moq_anneal_step(state))
@@ -1117,7 +1146,7 @@ class DeepSpeedEngine:
                              if self._config.fp16_enabled
                              else jnp.float32(1.0))
                     rng, step_rng = jax.random.split(state.rng)
-                    loss, grads = self._loss_and_grads(
+                    loss, grads, _ = self._loss_and_grads(
                         state.params, scale, batch, step_rng,
                         step=state.global_step,
                         qstep=moq_anneal_step(state))
@@ -1588,6 +1617,11 @@ class DeepSpeedEngine:
                     "engine/grad_norm": metrics.grad_norm}
             if self._config.fp16_enabled:
                 vals["engine/loss_scale"] = metrics.loss_scale
+            if metrics.counters is not None:
+                # the expert layers' own account of the step, summed over
+                # layers and micro-batches (docs/telemetry.md)
+                for i, name in enumerate(self._train_counters):
+                    vals[f"train/moe/{name}"] = metrics.counters[i]
             self._metrics_drain.push(step, vals)
         elif self._global_grad_norm is not None:
             self._metrics_drain.push(
@@ -1649,6 +1683,14 @@ class DeepSpeedEngine:
     def _write_monitor(self, metrics=None):
         if not self.monitor.enabled:
             return
+        # a monitor takes host floats, so this WAITS for the step's device
+        # work: with a monitor on (``telemetry.enabled`` attaches the JSONL
+        # one) ``engine/train_batch`` is the whole step, and the host's own
+        # part of it is that span less this one
+        with self.telemetry.span("engine/monitor", step=self.global_steps):
+            self._write_monitor_events(metrics)
+
+    def _write_monitor_events(self, metrics=None):
         events = []
         if metrics is not None:
             events = [

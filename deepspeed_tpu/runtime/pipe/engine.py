@@ -102,7 +102,7 @@ class PipelineEngine(DeepSpeedEngine):
                 batch = jax.tree_util.tree_map(lambda x: x[None], batch)
             scale = state.loss_scale.cur_scale if fp16 else jnp.float32(1.0)
             rng, step_rng = jax.random.split(state.rng)
-            loss, grads = self._loss_and_grads(
+            loss, grads, _ = self._loss_and_grads(
                 state.params, scale, batch, step_rng,
                 step=state.global_step,
                 qstep=moq_anneal_step(state))
@@ -127,7 +127,7 @@ class PipelineEngine(DeepSpeedEngine):
                 scale = (state.loss_scale.cur_scale if fp16
                          else jnp.float32(1.0))
                 rng, step_rng = jax.random.split(state.rng)
-                loss, grads = self._loss_and_grads(
+                loss, grads, _ = self._loss_and_grads(
                     state.params, scale, batch, step_rng,
                     step=state.global_step, qstep=moq_anneal_step(state))
                 grads = constrain(grads, self.plan.grad_specs(state.params),

@@ -179,8 +179,9 @@ SCHEMA = {
 # ``serve/loop`` is one ``ServingEngine.step()``; beneath it
 # ``serve/admit``, ``serve/prefill`` (> build, ``serve/step``, fetch,
 # sample) and ``serve/decode`` (> build, ``serve/step``, fetch, sample);
-# ``engine/train_batch`` holds ``engine/input``, ``engine/dispatch`` and,
-# with the prefetch iterator, ``engine/input_wait``.  ``setup/*`` are the
+# ``engine/train_batch`` holds ``engine/input``, ``engine/dispatch``, with
+# the prefetch iterator ``engine/input_wait`` and, with a monitor on,
+# ``engine/monitor`` (the host floats it takes: a wait for the device).  ``setup/*`` are the
 # process's start (the package's import, each engine's construction and
 # its parts) and ``compile`` one program JAX compiled or read from the
 # persistent cache, as the compile account closes it (docs/telemetry.md).
@@ -188,7 +189,7 @@ SPAN_NAMES = (
     "checkpoint/load", "checkpoint/save",
     "engine/forward", "engine/backward", "engine/step",
     "engine/train_batch", "engine/input", "engine/dispatch",
-    "engine/input_wait", "param_stream/train_step",
+    "engine/input_wait", "engine/monitor", "param_stream/train_step",
     "serve/loop", "serve/admit", "serve/step",
     "serve/prefill", "serve/prefill/build", "serve/prefill/fetch",
     "serve/prefill/sample",

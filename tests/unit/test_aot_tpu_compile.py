@@ -69,6 +69,10 @@ def _on(sharding, shape, dtype=jnp.bfloat16):
     ("gqa_16_4", (2, 1024, 16, 128), 4, None),
     ("window", (2, 1024, 16, 128), 16, 256),
     ("d64", (2, 1024, 12, 64), 12, None),
+    # PR 43: 8,192 positions, where the dk/dv kernel's whole-sequence
+    # blocks pass Mosaic's default 16 MiB of scoped VMEM (25.5 MB: the
+    # call then asks for its own limit, ``_vmem_params``)
+    ("gqa_32_4_s8192_window", (1, 8192, 32, 128), 4, 1024),
 ])
 def test_flash_forward_backward(topo, name, shape, kv_heads, window):
     chip = SingleDeviceSharding(topo.devices[0])
@@ -627,3 +631,27 @@ def test_decode_is_one_program_whatever_the_lengths():
     assert eng._step_fn._cache_size() == 1
     assert 0 < grids[0] < grids[1] <= \
         eng._report["dispatches"][-1]["kernel_grid_full"]
+
+
+def test_dropless_expert_layer_backward(topo):
+    """PR 43: the held-expert layer's ``custom_vjp`` at Mellum2's widths
+    (d 2304, f 896, 16 held of 64, top-8) through Mosaic: the backward's
+    two kernels are in the compiled gradient, the dw kernel's traced grid
+    bound in SECOND place and its aliased float32 accumulators included."""
+    from deepspeed_tpu.moe.sharded_moe import dropless_held_experts
+    chip = SingleDeviceSharding(topo.devices[0])
+    N, d, f, held, k = 2048, 2304, 896, 16, 8
+
+    def loss(h, weights, experts, chosen):
+        out, _, _ = dropless_held_experts(h, chosen, weights, experts,
+                                          jax.nn.silu, impl="pallas")
+        return jnp.sum(out)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        _on(chip, (N, d)), _on(chip, (N, k), jnp.float32),
+        {"w_gate": _on(chip, (held, d, f)), "w_up": _on(chip, (held, d, f)),
+         "w_down": _on(chip, (held, f, d))},
+        _on(chip, (N, k), jnp.int32)).compile().as_text()
+    assert "grouped_expert_glu_dx" in text and "grouped_expert_glu_dw" in text
+    # the combine's and the row gather's transposes are gathers too
+    assert "scatter(" not in text

@@ -297,8 +297,11 @@ def test_stacked_periods_are_the_listed_layers(model, params):
         jax.tree_util.tree_map(np.testing.assert_array_equal, a, b)
     ids = jax.random.randint(jax.random.key(2), (2, 45), 0, 128)
     whole = model.apply(params, ids, train=False)
+    # since PR 43 ``apply`` SCANS the periods (one compiled body) where the
+    # listed model unrolls its layers: float32 sums in another order, 2e-6
+    # of logits of about 1 on ten of 11,520 values
     np.testing.assert_allclose(listed_model.apply(listed, ids, train=False),
-                               whole, atol=1e-6)
+                               whole, atol=3e-6)
     want, _ = reference.logits(params, ids, CFG)
     assert float(jnp.abs(whole - want).max() / jnp.abs(want).max()) < TOL
     prompts = {0: list(range(30)), 1: list(range(7))}
