@@ -15,8 +15,9 @@ admission, page reservation, deadlines, tracing, and the device
 primitives (``_run_step`` / ``_sample`` / ``_prefill``); the scheduler
 owns WHAT each step dispatches:
 
-- ``monolithic`` (default): the whole prompt prefills in one bucketed
-  dispatch at admission, decode advances every slot per step.
+- ``monolithic`` (default): the whole prompt prefills at admission, in
+  one bucketed dispatch or, a long one, in a few pieces launched back to
+  back; decode advances every slot per step.
 - ``chunked``: prefill runs ``prefill_chunk_tokens`` at a time,
   interleaved with decode; per-request SLO classes (``latency`` vs
   ``throughput``) order both queue admission and chunk scheduling, and
@@ -167,7 +168,8 @@ class SchedulerBase:
         # one-token decode dispatches whose picks the host has not taken,
         # oldest first: at most one between steps (_decode_once)
         self._ahead: List[_InFlight] = []
-        self.sched_stats = {"prefill_chunks": 0, "prefills_split": 0,
+        self.sched_stats = {"prefill_chunks": 0, "prefill_pieces": 0,
+                            "prefills_split": 0,
                             "decode_steps": 0, "decode_tokens": 0}
 
     # -- admission hooks (called by ServingEngine._admit) ----------------
@@ -527,21 +529,48 @@ class SchedulerBase:
 
 
 class MonolithicScheduler(SchedulerBase):
-    """The whole (uncached) prompt prefills in one bucketed dispatch at
-    slot-fill time, fetched and sampled there; every active slot decodes
-    every step, by ``_decode_once`` or the ``decode_chunk`` scan."""
+    """The whole (uncached) prompt prefills at slot-fill time, fetched and
+    sampled there; every active slot decodes every step, by
+    ``_decode_once`` or the ``decode_chunk`` scan.
+
+    A prompt is one dispatch of a power-of-two bucket.  Where the engine
+    builds a prefill onto a context already in the pool
+    (``engine.prefill_piece_rows``), a suffix longer than that many rows
+    pads to the next multiple of them instead and is sent as that
+    length's binary expansion, largest piece first (3,584 = 2,048 + 1,024
+    + 512): every piece a power of two, so a bucket's compiled program,
+    and no new shape for the rows that the next power of two would have
+    padded."""
 
     policy = "monolithic"
 
-    def prefill_padded_len(self, suffix_tokens: int) -> int:
+    def prefill_pieces(self, suffix_tokens: int) -> List[int]:
+        """Shapes of the dispatches that prefill ``suffix_tokens``, in
+        launch order.  A ``max_seq`` that is no multiple of a piece's rows
+        is a shape of its own, as it is a bucket's cap."""
         eng = self.engine
-        return min(eng._bucket(suffix_tokens), eng.max_seq)
+        rows = eng.prefill_piece_rows
+        if rows and suffix_tokens > rows:
+            padded = -(-suffix_tokens // rows) * rows
+            if padded <= eng.max_seq:
+                return [1 << bit
+                        for bit in reversed(range(padded.bit_length()))
+                        if padded >> bit & 1]
+        return [min(eng._bucket(suffix_tokens), eng.max_seq)]
+
+    def prefill_padded_len(self, suffix_tokens: int) -> int:
+        return sum(self.prefill_pieces(suffix_tokens))
 
     def fill_slot(self, slot: int, req, cached: int) -> bool:
-        eng = self.engine
-        bucket = self.prefill_padded_len(len(req.prompt) - cached)
-        eng._prefill(slot, req, bucket, cached)
+        pieces = self.prefill_pieces(len(req.prompt) - cached)
+        self.sched_stats["prefill_pieces"] += len(pieces)
+        self.sched_stats["prefills_split"] += len(pieces) > 1
+        self.engine._prefill(slot, req, pieces, cached)
         return True
+
+    def meta(self) -> Dict[str, Any]:
+        return {**super().meta(),
+                "prefill_piece_rows": self.engine.prefill_piece_rows}
 
     def run_step(self) -> Dict[Any, List[int]]:
         eng = self.engine
@@ -729,34 +758,18 @@ class ChunkedScheduler(SchedulerBase):
         P = len(req.prompt)
         if req.prefilled < P:
             start = req.prefilled
-            toks = req.prompt[start:start + self.chunk]
-            n = len(toks)
+            n = min(self.chunk, P - start)
             chunk = start // self.chunk     # its index in the prompt
             with tel.span("serve/prefill", req_id=req.req_id,
                           attrs={"bucket": self.chunk, "real": n,
                                  "cached": start, "chunk": chunk,
                                  "context": start + n}):
-                with tel.span("serve/prefill/build"):
-                    ids = np.zeros((1, self.chunk), np.int32)
-                    ids[0, :n] = toks
-                    args = (jnp.asarray(ids),
-                            jnp.asarray(eng.tables[slot:slot + 1]),
-                            np.full((1,), start, np.int32))
-                t0 = eng._clock()
                 # only the prompt's last chunk is sampled from: the ones
                 # before it take no head at all (a second program a chunk
                 # shape, for the table's bytes a chunk: docs/serving.md)
-                eng._prefill_next(n, start + n, sample=start + n >= P,
-                                  chunk=chunk)
-                logits, eng.caches, _ = eng._run_step(*args,
-                                                      phase="prefill")
-                # chunk-active wall time feeds the critical path's
-                # prefill stage; the wait BETWEEN chunks lands in the gap
-                # stage — the split that separates scheduler wins from
-                # kernel wins
-                eng.attrib.chunk(req.req_id, (eng._clock() - t0) * 1000.0)
-                req.prefilled = start + n
-                eng.lengths[slot] = req.prefilled
+                logits = eng._prefill_rows(slot, req, start, self.chunk,
+                                           sample=start + n >= P,
+                                           chunk=chunk)
                 self.sched_stats["prefill_chunks"] += 1
                 eng._serve_event("serve/prefill_chunk", req_id=req.req_id,
                                  slot=slot, start=start, tokens=n,

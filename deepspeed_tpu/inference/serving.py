@@ -93,6 +93,15 @@ WINDOW_COUNTS = ("context_keys", "attended_keys", "pages_full",
 # whatever the model, the chunk's index in its prompt (the scheduler's)
 CHUNK_COUNTS = ("ctx_entries", "chunk")
 
+# A whole-prompt prefill longer than this pads to the next multiple of it,
+# not to the next power of two, and goes as pieces that are powers of two
+# no smaller (``MonolithicScheduler.prefill_pieces``).  The v5e's ridge is
+# 197 TFLOP/s / 819 GB/s = 240 rows of bf16 weights: at twice that a
+# piece's matmuls still hide the read of the weights.  Not a config key:
+# a smaller piece is a shape more to compile for a dispatch the weights'
+# read bounds.  A multiple of the page size, so a piece starts on a page.
+PREFILL_PIECE_ROWS = 512
+
 
 def greedy_token(logits: np.ndarray, width: int = 1024) -> int:
     """``int(np.argmax(logits))`` of one vocabulary row, by the maximum of
@@ -463,6 +472,15 @@ class ServingEngine:
         # is ``selected`` (mix_latent_dense)
         self._latent_dense = latent and not getattr(
             self.config, "index_topk", 0)
+        # the least rows of a piece of a long prompt's prefill, each piece
+        # started on what the one before it wrote; 0, and every prompt one
+        # bucket, where no prefill onto a context already in the pool is
+        # built: a window layer's ring is filled from an empty context
+        # (_refuse_for_ring), a selection over cached index keys takes one
+        # query (_refuse_unsupported)
+        self.prefill_piece_rows = PREFILL_PIECE_ROWS if (
+            not self.ring_pages and (not latent or self._latent_dense)
+            and PREFILL_PIECE_ROWS % page_size == 0) else 0
         self._paged_call = functools.partial(
             self.model.apply_with_paged_cache,
             attn_backend=self.attention_impl, attn_interpret=attn_interpret,
@@ -475,10 +493,10 @@ class ServingEngine:
         self._counted = bool(getattr(self.config, "counts_serving", False))
         self._prefill_sizes = None
         # dispatches of the two serving programs that no fetch has
-        # brought yet, in launch order (a prefill chunk that is not
-        # sampled from is never waited for, a decode step is left running
-        # behind the next one's launch): a fetch of a later dispatch
-        # brings their picks and counters along
+        # brought yet, in launch order (a prefill chunk, or a piece of a
+        # long prompt, that is not sampled from is never waited for, a
+        # decode step is left running behind the next one's launch): a
+        # fetch of a later dispatch brings their picks and counters along
         self._unfetched = []
 
         # two named jits over the one call, so a device trace's
@@ -1020,8 +1038,9 @@ class ServingEngine:
                      if self.prefix_cache is not None else PrefixMatch())
             cached = match.cached_tokens(self.page_size)
             # the scheduler owns the prefill shape: the monolithic policy
-            # pads the suffix to a power-of-two bucket, the chunked one
-            # to a whole number of prefill chunks
+            # pads the suffix to a power-of-two bucket, or a long one to
+            # whole pieces (prefill_piece_rows), the chunked one to a
+            # whole number of prefill chunks
             padded = self.scheduler.prefill_padded_len(
                 len(req.prompt) - cached)
             # reservation covers the budget AND the padded suffix prefill;
@@ -1633,37 +1652,62 @@ class ServingEngine:
             self.caches = self._copy_page_fn(
                 self.caches, jnp.int32(src), jnp.int32(dst))
 
-    def _prefill(self, slot: int, req: _Request, bucket: int,
+    def _prefill_rows(self, slot: int, req: _Request, start: int,
+                      shape: int, sample: bool = True,
+                      chunk: Optional[int] = None) -> StepLogits:
+        """Launch one prefill dispatch of ``shape`` rows for the prompt's
+        tokens ``[start, start + shape)`` (fewer at the prompt's end, the
+        rest padding) at position ``start``: a whole suffix, a piece of
+        one (``_prefill``) or a chunk of the chunked policy.  Causal
+        attention reads what lies before ``start`` through the block
+        table, so the rows are those of a prefill of the whole prompt.
+        ``sample`` and ``chunk`` as ``_prefill_next`` has them.  The slot
+        then holds the prompt as far as these rows reach."""
+        tokens = req.prompt[start:start + shape]
+        with self.telemetry.span("serve/prefill/build"):
+            ids = np.zeros((1, shape), np.int32)
+            ids[0, :len(tokens)] = tokens
+            args = (jnp.asarray(ids),
+                    jnp.asarray(self.tables[slot:slot + 1]),
+                    np.full((1,), start, np.int32))
+        t0 = self._clock()
+        self._prefill_next(len(tokens), start + len(tokens), sample=sample,
+                           chunk=chunk)
+        logits, self.caches, _ = self._run_step(*args, phase="prefill")
+        # the dispatch's active wall time feeds the critical path's
+        # prefill stage; the wait BETWEEN the chunked policy's chunks lands
+        # in the gap stage — the split that separates scheduler wins from
+        # kernel wins
+        self.attrib.chunk(req.req_id, (self._clock() - t0) * 1000.0)
+        req.prefilled = start + len(tokens)
+        self.lengths[slot] = req.prefilled
+        return logits
+
+    def _prefill(self, slot: int, req: _Request, pieces: List[int],
                  cached: int = 0):
         """Prefill the UNCACHED suffix: the first ``cached`` prompt tokens
-        already sit in attached (or COW-copied) pages, so the device step
-        runs only the remaining tokens at start position ``cached`` —
-        causal attention reads the cached pages through the block table,
-        so the result is bit-identical to a full prefill.  ``cached`` is
-        capped at ``len(prompt) - 1`` upstream: the last prompt token
-        always prefills, because its logits seed sampling."""
-        suffix = req.prompt[cached:]
+        already sit in attached (or COW-copied) pages, so the device runs
+        only the remaining tokens from position ``cached``, as dispatches
+        of the shapes ``pieces`` launched back to back, each where the one
+        before it ended (one bucket, or the scheduler's pieces of a long
+        prompt).  Only the last, which holds the prompt's last row and the
+        padding, is fetched and sampled from; the others run the same
+        program their shape always has and their one head row is never
+        read.  ``cached`` is capped at ``len(prompt) - 1`` upstream: the
+        last prompt token always prefills, because its logits seed
+        sampling."""
         tel = self.telemetry
         with tel.span("serve/prefill", req_id=req.req_id,
-                      attrs={"bucket": bucket, "real": len(suffix),
-                             "cached": cached}):
-            with tel.span("serve/prefill/build"):
-                ids = np.zeros((1, bucket), np.int32)
-                ids[0, :len(suffix)] = suffix
-                args = (jnp.asarray(ids),
-                        jnp.asarray(self.tables[slot:slot + 1]),
-                        np.full((1,), cached, np.int32))
-            t0 = self._clock()
-            self._prefill_next(len(suffix), len(req.prompt))
-            logits, self.caches, _ = self._run_step(*args, phase="prefill")
-            # monolithic prefill is one dispatch: fold its active wall
-            # time into the critical path's prefill stage (chunked
-            # prefills land here per chunk via the scheduler)
-            self.attrib.chunk(req.req_id, (self._clock() - t0) * 1000.0)
-            self.lengths[slot] = len(req.prompt)
-            req.prefilled = len(req.prompt)
+                      attrs={"bucket": sum(pieces),
+                             "real": len(req.prompt) - cached,
+                             "cached": cached, "pieces": len(pieces)}):
+            start = cached
+            for shape in pieces:
+                logits = self._prefill_rows(slot, req, start, shape)
+                start += shape
             with tel.span("serve/prefill/fetch"):
-                # [1, 1]: the program took the head on this row alone
+                # [1, 1]: the program took the head on this row alone;
+                # the pieces before it have run by the time it has
                 row = self._fetch(logits)[0, 0]
             with tel.span("serve/prefill/sample"):
                 req.last_token = self._sample(req, row)

@@ -247,7 +247,8 @@ SERVE_EVENTS = (
     "serve/backend",
     # scheduler plane (inference/scheduler.py): the once-per-engine
     # policy meta record ("serve/sched": policy / prefill_chunk_tokens /
-    # speculative / num_draft_tokens), one chunked-prefill dispatch
+    # speculative / num_draft_tokens / the monolithic policy's
+    # prefill_piece_rows), one chunked-prefill dispatch
     # ("serve/prefill_chunk": req_id / slot / start / tokens / remaining /
     # slo_class), one draft-model proposal ("serve/spec_draft": slots /
     # window) and its target verification ("serve/spec_verify": slots /
