@@ -53,12 +53,14 @@ from deepspeed_tpu.models.transformer import SERVE_COUNTERS
 from deepspeed_tpu.monitor.attribution import RequestAttributor
 from deepspeed_tpu.monitor.telemetry import (get_telemetry, in_setup_span,
                                              register_compiled, setup_span)
-from deepspeed_tpu.ops.latent_attention import context_entries
+from deepspeed_tpu.ops.latent_attention import (PREFILL_BLOCK_K,
+                                                context_entries)
 from deepspeed_tpu.ops.paged_attention import (PageAllocationError,
                                                PagedAllocator,
                                                resolve_attention_backend,
                                                resolve_paged_impl,
                                                ring_pages)
+from deepspeed_tpu.ops.pallas.latent_attention import pick_latent_tiles
 from deepspeed_tpu.ops.pallas.ragged_paged_attention import (
     pick_tiles, rect_grid_steps)
 from deepspeed_tpu.runtime.resilience import FaultInjector
@@ -92,6 +94,12 @@ WINDOW_COUNTS = ("context_keys", "attended_keys", "pages_full",
 # in ``ServingEngine._dispatch``); ``chunk``, of the chunked policy
 # whatever the model, the chunk's index in its prompt (the scheduler's)
 CHUNK_COUNTS = ("ctx_entries", "chunk")
+# what a dispatch's record says of what it compiled to, each "pallas" or
+# "jnp": the write of the page pools (every dispatch), a dropless expert
+# layer's grouped product (every dispatch of such a model), a latent
+# model's prefill over the pool (its prefill dispatches)
+# (scripts/check_telemetry_schema.py DISPATCH_IMPLS, frozen)
+DISPATCH_IMPLS = ("kv_write", "experts", "latent")
 
 # A whole-prompt prefill longer than this pads to the next multiple of it,
 # not to the next power of two, and goes as pieces that are powers of two
@@ -464,14 +472,21 @@ class ServingEngine:
             self.config, "moe_dropless", False) else None
         latent = bool(getattr(self.config, "is_latent", False))
         if latent:
-            # the latent pools are written and read in XLA whatever the
-            # backend asked for (models/transformer.py mix_latent)
+            # the latent pools are written, and read by a decode step and
+            # by a prefill under a selection, in XLA whatever the backend
+            # asked for (models/transformer.py mix_latent)
             self.attention_impl = "jnp"
         # no selection: every query attends over its whole context, a
         # prefill may start from entries already in the pool, and nothing
         # is ``selected`` (mix_latent_dense)
         self._latent_dense = latent and not getattr(
             self.config, "index_topk", 0)
+        # such a prefill's read of the pool takes the backend's choice as
+        # the expert layers do (ops/pallas/latent_attention.py: one kernel
+        # over the cached entries and the chunk itself); a selection's
+        # prefill stays in XLA; None for a model without latent attention
+        self.latent_impl = None if not latent else \
+            resolve_paged_impl(attn_impl) if self._latent_dense else "jnp"
         # the least rows of a piece of a long prompt's prefill, each piece
         # started on what the one before it wrote; 0, and every prompt one
         # bucket, where no prefill onto a context already in the pool is
@@ -484,7 +499,8 @@ class ServingEngine:
         self._paged_call = functools.partial(
             self.model.apply_with_paged_cache,
             attn_backend=self.attention_impl, attn_interpret=attn_interpret,
-            expert_backend=self.experts_impl)
+            expert_backend=self.experts_impl,
+            latent_backend=self.latent_impl)
         # a model that counts on the device what a dispatch did (keys
         # selected, expert pairs: transformer.SERVE_COUNTERS) is told
         # which rows are tokens and hands the counts back beside the
@@ -1494,6 +1510,18 @@ class ServingEngine:
                                -(-T // self.page_size), window))
         return int(run[0]), int(run[1])
 
+    def _latent_walk_keys(self, tokens):
+        """Keys a step of a dense latent prefill's walk of the pool: the
+        kernel's own choice for ``tokens`` rows a sequence, or the XLA
+        walk's block."""
+        if self.latent_impl != "pallas":
+            return PREFILL_BLOCK_K
+        c, pool = self.config, self.caches.latent_pages
+        return self.page_size * pick_latent_tiles(
+            int(tokens), c.n_heads, c.qk_nope_head_dim, c.v_head_dim,
+            c.kv_lora_rank, pool.shape[-1], self.page_size,
+            self.tables.shape[1], pool.dtype.itemsize).pages
+
     def _dispatch(self, fn, args, phase, batch, tokens, *, starts,
                   backend=None, config=None, head_rows=None, **sizes):
         """Launch jitted ``fn(*args)`` as one ``serve/step`` span and one
@@ -1523,8 +1551,9 @@ class ServingEngine:
         elif self._latent_dense and phase == "prefill":
             # what its attention walks of the pool: whole blocks of keys
             # over what was cached before the chunk
+            block = self._latent_walk_keys(tokens)
             counted = {"ctx_entries": max(
-                context_entries(n, self.page_size) for n in starts)}
+                context_entries(n, self.page_size, block) for n in starts)}
         attrs.update(counted, **{k: sizes[k] for k in ("chunk",)
                                  if k in sizes})
         with self.telemetry.span("serve/step", attrs=attrs), \
@@ -1541,6 +1570,9 @@ class ServingEngine:
         if self.experts_impl and config is None:
             # what the target model's expert layers compiled to
             record["experts"] = self.experts_impl
+        if self.latent_impl and phase == "prefill" and config is None:
+            # what the prefill's read of the latent pool compiled to
+            record["latent"] = self.latent_impl
         self._report["dispatches"].append(record)
         if fn in (self._prefill_fn, self._step_fn):
             # the two serving programs: their logits stay on the device

@@ -1203,15 +1203,19 @@ class CausalTransformerLM:
         return attn, write(jnp.zeros((B,), jnp.int32), k, v)
 
     def _latent_fresh(self, q, k, idx, layer, positions=None, counts=None,
-                      context=None):
+                      context=None, impl=None, interpret=False):
         """Latent attention of T tokens over themselves (a whole sequence,
         or a prefill from an empty context): keys and values DECOMPRESSED
         for the tokens at hand, each query over its selected causal keys
         (all of them without an indexer).  ``context``: ``(pools, layer
         index, block_tables, lengths)`` of a model without a selection
         whose tokens follow ``lengths`` entries already in the pool (a
-        chunk of a longer prompt): the queries attend over those first
-        (``la.context_attention``, scope ``latent_ctx``).
+        chunk of a longer prompt) and are themselves written there: with
+        ``impl`` "pallas" ONE kernel walks the pool over both
+        (``ops/pallas/latent_attention.py``; its interpreter with
+        ``interpret``); otherwise the queries attend over the cached
+        entries first (``la.context_attention``, scope ``latent_ctx``)
+        and over their own decompressed keys after, in XLA.
         -> [B, T, H, dv]."""
         from deepspeed_tpu.ops import latent_attention as la
         c = self.config
@@ -1219,6 +1223,24 @@ class CausalTransformerLM:
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
         real = None if counts is None else counts.real
+        if idx is None and context is not None and impl == "pallas":
+            from deepspeed_tpu.ops.pallas.latent_attention import \
+                latent_prefill_attention
+            pools, index, block_tables, lengths = context
+            rows = None if real is None \
+                else jnp.sum(real, axis=1, dtype=jnp.int32)
+            with jax.named_scope("latent_attn"):
+                out = latent_prefill_attention(
+                    q.nope, q.rope, pools.latent_pages, index, block_tables,
+                    lengths, layer["wkv_b"], self._latent_scale(),
+                    real_lengths=rows, interpret=interpret)
+            if counts is not None:
+                # real query t of a sequence met its ``lengths`` cached
+                # entries and the t + 1 causal keys of the chunk
+                met = jnp.sum(rows * lengths + rows * (rows + 1) // 2
+                              ).astype(jnp.int32)
+                counts.add(selected=met, context_keys=met)
+            return out
         with jax.named_scope("latent_attn"):
             kv = (k.c_kv @ layer["wkv_b"]).reshape(B, T, H, -1)
             keys = jnp.concatenate(
@@ -1263,20 +1285,22 @@ class CausalTransformerLM:
         return scale
 
     def mix_latent(self, q, k, idx, layer, pools, *, index, block_tables,
-                   lengths, counts=None):
+                   lengths, counts=None, impl=None, interpret=False):
         """Write this layer's entries ``[c_kv | k_rope]`` and indexer keys
         into the two STACKED latent pools at ``lengths``, then attend: a
         decode step (T = 1) gathers its selected entries out of the pool
         and uses the absorbed weights; T > 1 is a prefill FROM AN EMPTY
         CONTEXT (``lengths`` 0: ``ServingEngine`` refuses what would break
         that) and decompresses the tokens it brings.  A model without a
-        selection takes :meth:`mix_latent_dense`."""
+        selection takes :meth:`mix_latent_dense`, the only reader of
+        ``impl`` / ``interpret``."""
         from deepspeed_tpu.ops import latent_attention as la
         c = self.config
         if idx is None:
             return self.mix_latent_dense(
                 q, k, layer, pools, index=index, block_tables=block_tables,
-                lengths=lengths, counts=counts)
+                lengths=lengths, counts=counts, impl=impl,
+                interpret=interpret)
         B, T, H, dn = q.nope.shape
         entry = jnp.concatenate([k.c_kv, k.rope], axis=-1)
         with jax.named_scope("latent_attn"):
@@ -1301,14 +1325,15 @@ class CausalTransformerLM:
         return out[:, None], pools
 
     def mix_latent_dense(self, q, k, layer, pools, *, index, block_tables,
-                         lengths, counts=None):
+                         lengths, counts=None, impl=None, interpret=False):
         """:meth:`mix_latent` of a model WITHOUT a selection: no index
         pool, no scores, no top-k.  The entries are written at
         ``lengths``; T > 1 is a prefill from whatever the pool holds of
         the sequence (``lengths`` >= 0: a chunk of a longer prompt attends
         over the cached entries, then causally over itself; at 0 it is
-        the fresh prefill), a decode step reads the context's entries in
-        page order with the absorbed weights."""
+        the fresh prefill; ``impl`` "pallas": both in one kernel over the
+        pool, else in XLA), a decode step reads the context's entries in
+        page order with the absorbed weights, in XLA."""
         from deepspeed_tpu.ops import latent_attention as la
         c = self.config
         B, T, H, dn = q.nope.shape
@@ -1320,7 +1345,8 @@ class CausalTransformerLM:
             positions = lengths[:, None] + jnp.arange(T)[None, :]
             return self._latent_fresh(
                 q, k, None, layer, positions, counts,
-                context=(pools, index, block_tables, lengths)), pools
+                context=(pools, index, block_tables, lengths), impl=impl,
+                interpret=interpret), pools
         w_kvb = layer["wkv_b"].reshape(c.kv_lora_rank, H, -1)
         with jax.named_scope("latent_attn"):
             q_abs = jnp.einsum("bhd,rhd->bhr", q.nope[:, 0], w_kvb[..., :dn])
@@ -1796,7 +1822,8 @@ class CausalTransformerLM:
     def apply_with_paged_cache(self, params, input_ids, caches, block_tables,
                                lengths, *, attn_backend=None,
                                attn_interpret=False, real_lengths=None,
-                               head_rows=None, expert_backend=None):
+                               head_rows=None, expert_backend=None,
+                               latent_backend=None):
         """Forward over paged KV caches: appends the T new tokens of every
         sequence at ``lengths`` (tables must already map the pages) and
         attends over each sequence's ragged prefix.  Returns
@@ -1812,8 +1839,12 @@ class CausalTransformerLM:
         (``ops/paged_attention.py``: None = auto, "jnp" oracle, "pallas"
         fused ragged kernel; interpret runs the kernel on CPU) — static
         kwargs, so the serving engine binds them before jit.  A latent
-        model's pools are read and written in XLA whatever the backend
-        (``mix_latent``).  ``expert_backend`` ("pallas" | "jnp" | None =
+        model's pools are written, and read by a decode step and by a
+        prefill under a selection, in XLA whatever the backend;
+        ``latent_backend`` ("pallas" | "jnp" / None) is what the prefill
+        of a latent model WITHOUT a selection reads them with
+        (``mix_latent_dense``; the kernel's interpreter with
+        ``attn_interpret``).  ``expert_backend`` ("pallas" | "jnp" | None =
         auto) is what a dropless expert layer's grouped product runs on
         (``moe/sharded_moe.py:dropless_held_experts``; the kernel's
         interpreter with ``attn_interpret``).  A model that counts its
@@ -1840,7 +1871,8 @@ class CausalTransformerLM:
         ring_mix = {}       # window -> what its layers' mixer is bound to
         if c.is_latent:
             paged = dict(block_tables=block_tables, lengths=lengths,
-                         counts=counts)
+                         counts=counts, impl=latent_backend,
+                         interpret=attn_interpret)
             mixer = self.mix_latent
         else:
             # one backend for the write and the read of the pools

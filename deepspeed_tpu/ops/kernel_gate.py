@@ -131,6 +131,36 @@ def main(argv=None):
     ragged("prefill", [256] * B, [256] * B)
     ragged("mixed", [256, 1], [256, S // 2])
 
+    # dense latent prefill (one kernel over the pool: the cached entries
+    # and the chunk itself), at Kimi-K2's widths: a chunk from an empty
+    # pool (the causal blocks alone), a chunk over cached context, and
+    # several sequences of unlike lengths with padded rows
+    from deepspeed_tpu.ops.latent_attention import init_latent_pools
+    from deepspeed_tpu.ops.pallas.latent_attention import \
+        latent_prefill_attention
+    Hl, dn, dr, dv, R = 8, 128, 64, 128, 512
+    lat_pool = init_latent_pools(2, 3 * npages + 1, page, R + dr,
+                                 0).latent_pages
+    w_kvb = jax.random.normal(rng, (R, Hl * (dn + dv)), jnp.bfloat16)
+
+    def latent(name, Bl, T, cached, real):
+        qn = jax.random.normal(rng, (Bl, T, Hl, dn), jnp.bfloat16)
+        qr = jax.random.normal(rng, (Bl, T, Hl, dr), jnp.bfloat16)
+        tbl = 1 + jnp.arange(Bl * npages, dtype=jnp.int32).reshape(
+            Bl, npages)
+        rows.append(_gate(
+            f"latent_prefill_{name}",
+            lambda qn, qr, pool, t, ln, w, rl: latent_prefill_attention(
+                qn, qr, pool, 1, t, ln, w, 0.07, real_lengths=rl,
+                interpret=interp),
+            qn, qr, lat_pool, tbl, jnp.asarray(cached, jnp.int32), w_kvb,
+            jnp.asarray(real, jnp.int32)))
+
+    latent("causal", 1, S // 2, [0], [S // 2])
+    latent("context", 1, S // 2, [S // 2], [S // 2])
+    latent("batch", 3, S // 4, [S // 2, 0, 3 * page + 5],
+           [S // 4, S // 8 + 3, 0])
+
     # sparse attention (fixed local+global layout)
     block, nb = 128, S // 128
     layout = np.zeros((H, nb, nb), np.int64)

@@ -40,13 +40,29 @@ keys.  Its decode step (:func:`dense_decode_attention`) reads the
 context's entries in page order, masked by the lengths, as far as the
 longest context of the batch reaches.
 
+Which function serves which model (``models/transformer.py``):
+
+* a model WITH a selection: :func:`prefill_attention` (its prefill, from
+  an empty context) and :func:`decode_attention`, whatever the backend;
+* the whole-sequence path of any latent model (``mix_latent_whole``: the
+  trainer's forward, the references' comparisons):
+  :func:`prefill_attention`;
+* a model WITHOUT a selection, served: :func:`dense_decode_attention` for
+  a decode step whatever the backend; for a prefill, where the engine's
+  backend resolves to "pallas" (a TPU), the kernel
+  ``ops/pallas/latent_attention.py`` ``latent_prefill_attention``, which
+  walks cached entries and chunk alike out of the pool with its scores in
+  VMEM (PR 45), and elsewhere :func:`context_attention` +
+  :func:`prefill_attention`, which are also that kernel's oracle
+  (``tests/unit/test_latent_prefill_kernel.py``).
+
 The selection (:func:`index_scores`, :func:`topk_mask`): query ``t`` keeps
 the ``min(k, t + 1)`` causal keys of largest ``I[t, s] = sum_h w[t, h] *
 relu(q_i[t, h] . k_i[s])``, by value, ties to the lower index
 (``jax.lax.top_k``'s rule, which the decode step uses directly).
 
-Everything here is plain XLA under ``jax.named_scope``s the caller opens
-(``latent_attn``, ``select``): no Pallas kernel yet (ROADMAP B1).
+Everything in this file is plain XLA under ``jax.named_scope``s the
+caller opens (``latent_attn``, ``select``, ``latent_ctx``).
 """
 
 import math

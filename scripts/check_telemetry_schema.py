@@ -226,6 +226,15 @@ WINDOW_COUNTS = ("context_keys", "attended_keys", "pages_full",
 # of the chunked policy whatever the model, the chunk's index in its
 # prompt
 CHUNK_COUNTS = ("ctx_entries", "chunk")
+# FROZEN: the fields of a dispatch's record in ``last_step`` that say what
+# it compiled to, each ``"pallas"`` or ``"jnp"`` (byte-identical to
+# ``deepspeed_tpu.inference.serving.DISPATCH_IMPLS``): ``kv_write`` (the
+# write of the page pools; every dispatch), ``experts`` (a dropless expert
+# layer's grouped product; every dispatch of the target model that has
+# one), ``latent`` (a latent-attention model's prefill over its pool: the
+# ``latent_attention_prefill`` kernel of a model WITHOUT a selection, or
+# the XLA walk; its prefill dispatches alone)
+DISPATCH_IMPLS = ("kv_write", "experts", "latent")
 
 # FROZEN vocabulary of serve-kind event names — must stay byte-identical
 # to ``deepspeed_tpu.inference.robustness.SERVE_EVENTS`` (the tier-1 test

@@ -464,9 +464,15 @@ def test_expert_layer_never_copies_an_expert_or_loops_over_them(
     assert 6 <= text.count(" while(") <= 1 + 2 + 4
 
 
-DENSE_LATENT_DISPATCHES = {"decode_b16": (16, 1, None),
-                           "chunk_2048": (1, 2048, 0),
-                           "chunk_2048_sampled": (1, 2048, 1)}
+# name: (sequences, tokens, rows the head runs on, what the prefill reads
+# the pool with: the XLA walk, or the kernel of PR 45)
+DENSE_LATENT_DISPATCHES = {"decode_b16": (16, 1, None, "jnp"),
+                           "chunk_2048": (1, 2048, 0, "jnp"),
+                           "chunk_2048_sampled": (1, 2048, 1, "jnp"),
+                           "chunk_2048_kernel": (1, 2048, 0, "pallas"),
+                           "chunk_2048_sampled_kernel": (1, 2048, 1,
+                                                         "pallas"),
+                           "pieces_b3_512_kernel": (3, 512, 1, "pallas")}
 DENSE_LATENT_PAGES = 2 * 136 + 1        # two slots of max_seq 17,408
 
 
@@ -500,15 +506,17 @@ def dense_latent_dispatch(topo):
     caches = on_chip(jax.eval_shape(
         lambda: model.init_paged_caches(DENSE_LATENT_PAGES, 128)))
 
-    def serve(params, ids, caches, tables, lengths, real, *rows):
+    def serve(impl, params, ids, caches, tables, lengths, real, *rows):
         return model.apply_with_paged_cache(
             params, ids, caches, tables, lengths, expert_backend="pallas",
-            real_lengths=real, **dict(zip(("head_rows",), rows)))
+            latent_backend=impl, real_lengths=real,
+            **dict(zip(("head_rows",), rows)))
 
     @functools.lru_cache(maxsize=None)
     def compiled(name):
-        batch, tokens, rows = DENSE_LATENT_DISPATCHES[name]
-        return jax.jit(serve, donate_argnums=(2,)).lower(
+        batch, tokens, rows, impl = DENSE_LATENT_DISPATCHES[name]
+        return jax.jit(functools.partial(serve, impl),
+                       donate_argnums=(2,)).lower(
             params, ints(batch, tokens), caches, ints(batch, 137),
             ints(batch), ints(batch),
             *([] if rows is None else [ints(batch, rows)])).compile()
@@ -521,20 +529,23 @@ def test_dense_latent_dispatch_walks_the_pool_in_place(dense_latent_dispatch,
                                                        name):
     """A decode step and a prefill chunk of a latent model without a
     selection (PR 41): the stacked pool of 640-value rows is written by a
-    scatter in place and read a block of pages at a time inside a loop;
-    nothing else has a result of its shape (no copy, no re-layout), the
-    index pool has no bytes, and the 12 held experts of 384 go through
-    the one grouped kernel."""
+    scatter in place and read a block of pages at a time inside a loop,
+    or (PR 45) by the ``latent_attention_prefill`` kernel through its
+    index maps, which Mosaic compiles at these widths for one chunk of
+    2,048 rows and for three pieces of 512; nothing else has a result of
+    its shape (no copy, no re-layout), the index pool has no bytes, and
+    the 12 held experts of 384 go through the one grouped kernel."""
     compiled = dense_latent_dispatch(name)
     text = compiled.as_text()
     pool = f"bf16[2,{DENSE_LATENT_PAGES},128,640]"
     found = {op for _, op in _pool_shaped(text, None, [pool])}
     assert found and found <= IN_PLACE_OPS | {"fusion", "scatter"}, found
-    assert " while(" in text
+    batch, tokens, rows, impl = DENSE_LATENT_DISPATCHES[name]
+    assert ("latent_attention_prefill" in text) == (impl == "pallas")
+    assert " while(" in text or impl == "pallas"   # the XLA walk's loop
     # a chunk that is not sampled from needs nothing of its LAST layer
     # but the entries it writes: the compiler drops that layer's expert
     # product (here the only one) with the head
-    batch, tokens, rows = DENSE_LATENT_DISPATCHES[name]
     assert ("grouped_expert_glu" in text) == (rows != 0)
     # no index keys anywhere: the second pool is an array of no elements
     assert f"bf16[2,{DENSE_LATENT_PAGES},128,0]" in text
