@@ -47,8 +47,8 @@ from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
 SEED = 0
 
-# the configuration bench.py names gpt_1b: 1.01B parameters, the largest
-# GPT whose whole bf16-moment train state fits one 16 GB chip
+# the configuration ``ds_bench train`` names gpt_1b: 1.01B parameters, the
+# largest GPT whose whole bf16-moment train state fits one 16 GB chip
 GPT_1B = dict(vocab_size=50304, max_seq_len=1024, activation="gelu",
               use_rmsnorm=False, use_rope=False, tie_embeddings=True,
               **MODELS["gpt_1b"])

@@ -14,8 +14,8 @@ fractions, attainment counters — through a weighted
 Trials execute through the SAME journaled trial runner as the legacy
 tuner (``ResourceManager.run_one``), so crash/resume and skip-finished
 semantics are shared.  Every trial appends ``{run: "tune-<id>", bench,
-metric, value}`` rows to the perf ledger so ``scripts/ds_perf_diff.py``
-can gate the tuned config against the untuned baseline, and the winner
+metric, value}`` rows to its ``ledger_path`` (row schema:
+``scripts/check_telemetry_schema.py --ledger``), and the winner
 persists as a provenance-stamped config overlay
 (:mod:`~deepspeed_tpu.autotuning.overlay`) consumed at
 ``deepspeed.initialize()`` / ``create_serving_engine()`` time.

@@ -48,17 +48,6 @@ MODELS = {
                      n_kv_heads=8, ffn_hidden_size=11008),
 }
 
-_PEAK_BF16 = (("v6", 918.0), ("v5p", 459.0), ("v5 lite", 197.0),
-              ("v5e", 197.0), ("v5", 459.0), ("v4", 275.0), ("v3", 61.5))
-
-
-def _peak_tflops(kind: str):
-    k = (kind or "").lower()
-    for sub, val in _PEAK_BF16:
-        if sub in k:
-            return val
-    return None
-
 
 def run_benchmark(model="gpt_350m", batch=8, gas=1, seq=1024, steps=10,
                   zero_stage=3, offload=None, remat=True,
@@ -71,6 +60,7 @@ def run_benchmark(model="gpt_350m", batch=8, gas=1, seq=1024, steps=10,
     import numpy as np
 
     import deepspeed_tpu
+    from deepspeed_tpu.comm.topology_model import device_peak_flops
     from deepspeed_tpu.models.transformer import (CausalTransformerLM,
                                                   TransformerConfig)
     from deepspeed_tpu.parallel import groups
@@ -161,16 +151,16 @@ def run_benchmark(model="gpt_350m", batch=8, gas=1, seq=1024, steps=10,
     n_chips = max(1, engine.mesh.size)
     tokens = gas * batch * seq * steps
     tps = tokens / dt
-    tflops = 6.0 * cfg.num_params() * tps / 1e12 / n_chips
+    flops = 6.0 * cfg.num_params() * tps / n_chips
     kind = getattr(jax.devices()[0], "device_kind", "")
-    peak = _peak_tflops(kind)
+    peak = device_peak_flops(kind)
     out = {
         "model": model if isinstance(model, str) else "custom",
         "n_params": cfg.num_params(),
         "batch": batch, "gas": gas, "seq": seq, "zero_stage": zero_stage,
         "steps": steps,
         "tokens_per_sec_per_chip": round(tps / n_chips, 1),
-        "model_tflops_per_chip": round(tflops, 2),
+        "model_tflops_per_chip": round(flops / 1e12, 2),
         "loss": float(loss),
         "device_kind": kind, "n_chips": n_chips,
     }
@@ -185,7 +175,7 @@ def run_benchmark(model="gpt_350m", batch=8, gas=1, seq=1024, steps=10,
     if arch != "gpt":
         out["arch"] = arch
     if peak:
-        out["mfu"] = round(tflops / peak, 4)
+        out["mfu"] = round(flops / peak, 4)
     return out
 
 

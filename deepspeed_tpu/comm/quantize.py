@@ -26,7 +26,7 @@ the wire codec as a blockwise quantize-dequantize (QDQ) of the gradient —
 exactly the phase-2 re-quantization of the two-phase collective (phase-1
 per-rank error averages down by 1/world).  The REAL shard_map collectives
 here are what a multi-chip deployment lowers to, and are what the unit
-tests and the ``cpu_comm_quant`` bench exercise directly.
+tests (``tests/unit/test_comm_quant.py``) exercise directly.
 """
 
 from dataclasses import dataclass

@@ -36,8 +36,12 @@ LINK_PEAK_GBPS = (
 # cross-host data-center network fallback (per-host NIC, GB/s)
 DCN_PEAK_GBPS = 12.5
 
-# per-chip bf16 dense peak (TFLOP/s), same table bench.py uses for its
-# roofline rows; MFU = achieved model flops/s / (peak * device count)
+# bf16 dense peak (TFLOP/s) of one JAX device; MFU = achieved model
+# flops/s / (peak * device count).  Source, for this table and
+# HBM_PEAK_GBPS: Google Cloud documentation, the "TPU v2" ... "TPU v6e"
+# pages under cloud.google.com/tpu/docs (per chip; v2 and v3 halved,
+# their chips being two devices).  The package's only copy: the train
+# suite of ds_bench and the engine's train/mfu gauge both read it here.
 PEAK_TFLOPS = (
     ("v6e", 918.0), ("v6 lite", 918.0), ("v6", 918.0),
     ("v5p", 459.0), ("v5e", 197.0), ("v5 lite", 197.0), ("v5", 459.0),

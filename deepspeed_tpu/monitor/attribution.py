@@ -35,8 +35,8 @@ the time went*:
 Both halves are host-side accounting over events/timestamps that already
 exist: no device syncs, no extra compiles.  Collective durations inside
 ``jit`` are trace-time (the census convention), so live training
-decompositions are simulation/bench-grade off-hardware; the analytic
-``cpu_step_attr`` micro-bench pins the algebra to a known workload.
+decompositions are simulation-grade off-hardware;
+``tests/unit/test_attribution.py`` pins the algebra to a known workload.
 
 Frozen vocabularies below are mirrored byte-identical in
 ``scripts/check_telemetry_schema.py`` (tier-1 lockstep tests diff them).
@@ -64,7 +64,7 @@ STEP_ATTR_GAUGES = (
 # FROZEN ordered stage vocabulary of the per-request critical path (the
 # ``serve/request/attr`` event carries one ``<stage>_ms`` attr per entry;
 # their sum equals ``e2e_ms`` by construction).  Mirrored in
-# scripts/check_telemetry_schema.py and ds_perf_diff's direction table.
+# scripts/check_telemetry_schema.py.
 ATTR_STAGES = ("queue", "prefill", "migrate", "gap", "decode")
 
 # span names folded into the training decomposition.  engine/train_batch

@@ -569,8 +569,8 @@ def simulate_forward_schedule(n_layers: int, compute_ms: float,
       ``exposed_comm_frac = g / (g + L*c)``
 
     tests/unit/test_zero_overlap.py holds the layer_scan docstring to
-    this model; ``bench.py cpu_overlap`` holds the measured multi-rank
-    step to it."""
+    this model, and an explicit 4-rank ``shard_map`` run of both
+    schedules to the same bits."""
     g = float(gather_ms) / 1000.0
     c = float(compute_ms) / 1000.0
     comm, compute = [], []

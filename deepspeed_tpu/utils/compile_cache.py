@@ -1,5 +1,5 @@
 """Where JAX's persistent compilation cache lives — one rule for every
-entry point (``chip_smoke.py``, ``bench.py --worker``, ``bin/ds_bench``,
+entry point (``chip_smoke.py``, ``bin/ds_bench``,
 ``autotuning/trial_worker.py``, ``inference/fleet_worker.py``).
 
 The directory is part of the cache key, so processes that should share

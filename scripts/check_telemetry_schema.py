@@ -12,7 +12,7 @@ Usage:
     python scripts/check_telemetry_schema.py --prom <metrics.txt> [...]
     python scripts/check_telemetry_schema.py --shards <shard_dir> [...]
     python scripts/check_telemetry_schema.py --cluster <payload.json> [...]
-    python scripts/check_telemetry_schema.py --ledger <BENCH_LEDGER.jsonl>
+    python scripts/check_telemetry_schema.py --ledger <ledger.jsonl>
     python scripts/check_telemetry_schema.py --incidents <bundle_or_dir> [...]
     python scripts/check_telemetry_schema.py --tune <overlay_or_dir> [...]
 
@@ -24,9 +24,9 @@ section) plus ``ring.jsonl`` whose every line validates against the
 event schema.  A path may be one bundle or a parent ``incidents/``
 directory of bundles.
 
-The ``--ledger`` mode validates a perf-regression ledger
-(``bench.py`` appends one row per micro-bench metric; ``scripts/
-ds_perf_diff.py`` compares runs against it): every row must carry
+The ``--ledger`` mode validates a metric ledger (the autotuner's control
+plane appends one row per scored metric of a trial to its
+``ledger_path``): every row must carry
 ``ts``/``run``/``bench``/``metric``/``value`` with an optional
 ``unit``.
 
@@ -724,11 +724,10 @@ def validate_cluster_file(path):
 
 
 # ----------------------------------------------------------------------
-# perf-regression ledger (bench.py appends; scripts/ds_perf_diff.py reads)
+# metric ledger (autotuning/controlplane.py appends)
 # ----------------------------------------------------------------------
-# One row per (run, bench, metric): ``run`` groups every metric a single
-# bench.py invocation recorded, so ds_perf_diff.py can baseline on prior
-# runs and diff the latest against them.
+# One row per (run, bench, metric): ``run`` groups every metric one
+# trial recorded.
 LEDGER_REQUIRED = {"ts": _NUM, "run": str, "bench": str, "metric": str,
                    "value": _NUM}
 LEDGER_OPTIONAL = {"unit": str}

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Chaos campaign runner for the cross-process fleet (gate 10).
+"""Chaos campaign runner for the cross-process fleet (run_gates.sh's "chaos smoke").
 
 Sweeps gray-failure scenarios over a REAL 2-worker subprocess fleet on
 the deterministic ``tiny_engine_factory`` spec, with every fault driven

@@ -433,7 +433,7 @@ def _autotuning_summary(agg):
     """Closed-loop autotuner digest from the frozen ``tune/*`` stream:
     trials run/pruned with their knob points, the snapshot-scored
     objective per trial, the winning overlay's knobs and provenance,
-    and the BENCH_LEDGER rows the trial runner appended (one per scored
+    and the ledger rows the trial runner appended (one per scored
     metric plus the objective row).  None when the stream carries no
     tune events."""
     tunes = agg.get("tunes") or {}

@@ -4,15 +4,14 @@
 #   scripts/run_gates.sh [TELEMETRY_DIR] [INCIDENTS_DIR] [TUNE_DIR]
 #
 #   1. check_telemetry_schema.py <events.jsonl...>   frozen event vocab
-#   2. check_telemetry_schema.py --ledger            BENCH_LEDGER.jsonl rows
-#   3. check_telemetry_schema.py --incidents         incident bundles
-#   4. ds_perf_diff.py --check                       perf regression gate
-#   5. check_telemetry_schema.py --tune              tune journals/overlay
-#   6. comm-quant smoke                              int8 codec roundtrip
-#   7. ds_trace_export.py --check                    Perfetto trace export
-#   8. overlap smoke                                 ZeRO-3 comm overlap
-#   9. fleet xproc smoke                             kill -9 a worker proc
-#  10. chaos smoke                                   seeded wire faults
+#   2. check_telemetry_schema.py --incidents         incident bundles
+#   3. check_telemetry_schema.py --tune              tune journals/overlay
+#   4. comm-quant smoke                              int8 codec roundtrip
+#   5. ds_trace_export.py --check                    Perfetto trace export
+#   6. overlap smoke                                 ZeRO-3 comm overlap
+#   7. fleet xproc smoke                             kill -9 a worker proc
+#   8. chaos smoke                                   seeded wire faults
+#   9. tiered smoke                                  memory block -> store
 #
 # TELEMETRY_DIR (optional) is searched recursively for events*.jsonl
 # streams; INCIDENTS_DIR (optional) holds incident bundles; TUNE_DIR
@@ -28,7 +27,6 @@ PY="${PYTHON:-python}"
 TELEMETRY_DIR="${1:-}"
 INCIDENTS_DIR="${2:-}"
 TUNE_DIR="${3:-}"
-LEDGER="${LEDGER:-$REPO/BENCH_LEDGER.jsonl}"
 fail=0
 
 run_gate() {
@@ -57,15 +55,7 @@ else
     echo "== gate: event schema == SKIP (no telemetry dir given)"
 fi
 
-# 2. bench ledger rows
-if [ -f "$LEDGER" ]; then
-    run_gate "bench ledger" \
-        "$PY" "$REPO/scripts/check_telemetry_schema.py" --ledger "$LEDGER"
-else
-    echo "== gate: bench ledger == SKIP ($LEDGER missing)"
-fi
-
-# 3. incident bundles
+# 2. incident bundles
 if [ -n "$INCIDENTS_DIR" ] && [ -d "$INCIDENTS_DIR" ]; then
     run_gate "incident bundles" \
         "$PY" "$REPO/scripts/check_telemetry_schema.py" --incidents \
@@ -74,11 +64,7 @@ else
     echo "== gate: incident bundles == SKIP (no incidents dir given)"
 fi
 
-# 4. perf regression (exits 0 quietly on a missing/single-run ledger)
-run_gate "perf diff" "$PY" "$REPO/scripts/ds_perf_diff.py" --check \
-    "$LEDGER"
-
-# 5. autotuner artifacts: trial journals, tune/* stream, overlay
+# 3. autotuner artifacts: trial journals, tune/* stream, overlay
 # provenance (defaults to the control plane's results_dir when present)
 if [ -z "$TUNE_DIR" ] && [ -d "$REPO/autotuning_results" ]; then
     TUNE_DIR="$REPO/autotuning_results"
@@ -90,7 +76,7 @@ else
     echo "== gate: tune artifacts == SKIP (no tune dir given)"
 fi
 
-# 6. quantized-collective smoke: the comm.quantization config block must
+# 4. quantized-collective smoke: the comm.quantization config block must
 # parse, activate the int8 codec, shrink the wire, and produce a
 # schema-valid annotated census event + frozen quant gauge
 run_gate "comm quant smoke" env JAX_PLATFORMS=cpu REPO="$REPO" "$PY" - <<'EOF'
@@ -140,7 +126,7 @@ print(f"quant smoke: saved {int(saved)} bytes, rel err {err:.4f}, "
       f"{len(events)} schema-valid events")
 EOF
 
-# 7. trace export: every telemetry stream found under TELEMETRY_DIR must
+# 5. trace export: every telemetry stream found under TELEMETRY_DIR must
 # convert to a valid Chrome trace-event file (attribution flow arrows
 # included) — the exporter is the debugging path of last resort, so a
 # stream it chokes on is a gate failure, not a rendering nit
@@ -166,7 +152,7 @@ else
     echo "== gate: trace export == SKIP (no telemetry dir given)"
 fi
 
-# 8. overlap smoke: a ZeRO-3 config with zero_optimization.overlap on
+# 6. overlap smoke: a ZeRO-3 config with zero_optimization.overlap on
 # must run the double-buffered step on the simulated 8-device mesh with
 # a bit-identical forward vs the serial oracle (the gather pipeline may
 # reorder communication, never math), the trajectory inside ulp
@@ -262,7 +248,7 @@ print(f"overlap smoke: 3 overlapped steps vs serial — step-0 loss "
       f"exposed_comm_frac on a {len(events)}-event schema-valid stream")
 EOF
 
-# 9. cross-process fleet smoke: a 2-worker subprocess fleet must serve
+# 7. cross-process fleet smoke: a 2-worker subprocess fleet must serve
 # the same tokens as the in-process fleet bit-for-bit, then survive a
 # real kill -9 of one worker mid-decode with zero lost requests — every
 # id reaches exactly one typed terminal, survivors stay bit-identical,
@@ -352,7 +338,7 @@ print(f"fleet xproc smoke: {len(ref)} requests bit-identical across the "
       f"schema-valid worker_lost event + incident bundle")
 EOF
 
-# 10. chaos smoke: deterministic wire-fault campaign over the 2-worker
+# 8. chaos smoke: deterministic wire-fault campaign over the 2-worker
 # subprocess fleet — lost add_request ack (channel retry + ikey dedup),
 # slow worker (circuit breaker opens, probes, closes; no respawn), and a
 # torn commit_import ack (gray migrate recovers exactly-once). Each
@@ -362,7 +348,7 @@ EOF
 run_gate "chaos smoke" env JAX_PLATFORMS=cpu "$PY" \
     "$REPO/scripts/ds_chaos.py" --scenarios ack_loss,slow_worker,torn_commit
 
-# 11. tiered-store smoke: a memory config block must build a TieredStore
+# 9. tiered-store smoke: a memory config block must build a TieredStore
 # whose quantized NVMe entries carry their scale sidecars, whose sealed
 # directory fscks COMMITTED (and flags a torn payload file as partial),
 # and whose frozen tier/* gauges ride a schema-valid stream

@@ -2,8 +2,8 @@
 
 The script's real run is on the chip (through the chip tool); here its
 phases run at tiny size on the CPU with the kernels in interpret mode, and
-the no-accelerator exits of ``chip_smoke.py`` and ``bench.py``, the one
-compile-cache rule and the flash-attention dispatch policy are pinned.
+its no-accelerator exit, the one compile-cache rule and the
+flash-attention dispatch policy are pinned.
 """
 
 import importlib.util
@@ -32,17 +32,13 @@ TINY_LLAMA = dict(vocab_size=256, hidden_size=64, n_layers=2, n_heads=4,
                   n_kv_heads=2, ffn_hidden_size=128, max_seq_len=64)
 
 
-def _load(name):
+@pytest.fixture(scope="module")
+def smoke():
     spec = importlib.util.spec_from_file_location(
-        f"{name}_under_test", os.path.join(REPO, f"{name}.py"))
+        "chip_smoke_under_test", os.path.join(REPO, "chip_smoke.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
-
-
-@pytest.fixture(scope="module")
-def smoke():
-    return _load("chip_smoke")
 
 
 @pytest.fixture(scope="module")
@@ -70,34 +66,6 @@ def test_smoke_alone_in_a_directory_fails(tmp_path):
     shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
     out = _run("chip_smoke.py", tmp_path)
     assert out.returncode != 0 and '"ok"' not in out.stdout
-
-
-@pytest.mark.parametrize("probe,train,reason", [
-    ({"platform": "tpu", "kind": "TPU v9 mystery", "n_devices": 1,
-      "hbm": 16e9}, None, "unknown device_kind"),
-    ({"platform": "tpu", "kind": "TPU v5 lite", "n_devices": 1,
-      "hbm": 16e9}, None, "no training attempt succeeded"),
-    ({"platform": "cpu", "kind": "cpu", "n_devices": 8, "hbm": 0}, None,
-     "no TPU: jax found platform 'cpu'"),
-    (None, None, "backend probe failed"),
-])
-def test_bench_main_has_no_fallback(monkeypatch, capsys, probe, train,
-                                    reason):
-    bench = _load("bench")
-    calls = []
-
-    def fake_worker(name, spec=None, **kw):
-        calls.append((name, kw.get("cpu", False)))
-        result = probe if name == "probe" else train
-        return result, (None if result else "boom")
-
-    monkeypatch.setattr(bench, "_run_worker", fake_worker)
-    with pytest.raises(SystemExit) as exit_info:
-        bench.main()
-    assert reason in str(exit_info.value.code)
-    assert "\n" not in str(exit_info.value.code)
-    assert capsys.readouterr().out == ""
-    assert not any(cpu for _, cpu in calls)     # nothing retried on the CPU
 
 
 # -- one compile-cache rule -------------------------------------------------

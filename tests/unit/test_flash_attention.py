@@ -1,6 +1,6 @@
 """Flash-attention kernel vs jnp oracle — run via the Pallas interpreter on
 CPU (exact fp32 math, so tolerances are tight).  On real TPU the compiled
-kernel is exercised by bench.py / the model's auto dispatch."""
+kernel is exercised by chip_smoke.py / the model's auto dispatch."""
 
 import jax
 import jax.numpy as jnp

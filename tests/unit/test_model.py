@@ -157,20 +157,6 @@ def test_chunked_xent_explicit_labels():
     assert abs(want - got) < 1e-5
 
 
-def test_bench_loss_chunk_matches_config():
-    """bench.py sizes the batch ladder with a mirrored constant (its parent
-    process must not import jax); keep it pinned to the model default."""
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..", "..",
-                              "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert bench.LOSS_CHUNK_TOKENS == \
-        TransformerConfig.__dataclass_fields__["loss_chunk_size"].default
-
-
 def test_qk_norm_scratch_init_trains():
     """qk_norm must work from scratch init (not just HF conversion):
     init materializes q_norm/k_norm at the right shapes (per-head [dh]

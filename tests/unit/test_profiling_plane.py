@@ -1,8 +1,7 @@
 """Performance observability plane (monitor/profiling.py): compile
 tracing with the recompile-storm verdict and watchdog exemption, per-span
 HBM attribution with the monotonic-growth leak detector, the live
-roofline gauges, the exporter surfaces, and the perf-regression gate
-(scripts/ds_perf_diff.py) over the bench ledger."""
+roofline gauges, and the exporter surfaces."""
 
 import importlib.util
 import json
@@ -34,11 +33,6 @@ def _load_script(name):
 @pytest.fixture(scope="module")
 def checker():
     return _load_script("check_telemetry_schema")
-
-
-@pytest.fixture(scope="module")
-def perf_diff():
-    return _load_script("ds_perf_diff")
 
 
 class FakeClock:
@@ -286,172 +280,9 @@ def test_exporter_surfaces_profiling_gauges_with_rank_labels(tmp_path):
     tel.close()
 
 
-# ----------------------------------------------------------------------
-# perf-regression gate (scripts/ds_perf_diff.py)
-# ----------------------------------------------------------------------
-def _ledger(path, runs):
-    """runs: {run_name: {(bench, metric): value}} appended in order."""
-    with open(path, "w") as f:
-        for run, metrics in runs.items():
-            for (bench, metric), value in metrics.items():
-                f.write(json.dumps(
-                    {"ts": 1.0, "run": run, "bench": bench,
-                     "metric": metric, "value": value}) + "\n")
-
-
-def test_perf_diff_metric_direction(perf_diff):
-    assert perf_diff.metric_direction("steps_per_sec") == "up"
-    assert perf_diff.metric_direction("tokens_per_sec_decode") == "up"
-    assert perf_diff.metric_direction("busbw_gbps") == "up"
-    assert perf_diff.metric_direction("step_time_ms") == "down"
-    assert perf_diff.metric_direction("churn_wall_s") == "down"
-    assert perf_diff.metric_direction("peak_bytes") == "down"
-    assert perf_diff.metric_direction("recompiles") is None
-
-
-def test_perf_diff_catches_regression(perf_diff, tmp_path, capsys):
-    led = tmp_path / "ledger.jsonl"
-    _ledger(led, {
-        "run-1": {("b", "step_time_ms"): 100.0,
-                  ("b", "tokens_per_sec"): 50.0},
-        "run-2": {("b", "step_time_ms"): 104.0,
-                  ("b", "tokens_per_sec"): 51.0},
-        "run-3": {("b", "step_time_ms"): 200.0,     # 2x: regression
-                  ("b", "tokens_per_sec"): 49.0},   # -4%: within 25%
-    })
-    assert perf_diff.main([str(led)]) == 1
-    out = capsys.readouterr().out
-    assert "regression" in out and "FAIL" in out
-    # baseline is the median of run-1/run-2, not the last run alone
-    res = perf_diff.diff(*perf_diff.split_runs(
-        perf_diff.load_ledger(str(led))[0])[:2], 0.25)
-    by_metric = {r["metric"]: r for r in res}
-    assert by_metric["step_time_ms"]["baseline"] == pytest.approx(102.0)
-    assert by_metric["step_time_ms"]["verdict"] == "regression"
-    assert by_metric["tokens_per_sec"]["verdict"] == "ok"
-
-
-def test_perf_diff_passes_within_tolerance(perf_diff, tmp_path, capsys):
-    led = tmp_path / "ledger.jsonl"
-    _ledger(led, {
-        "run-1": {("b", "step_time_ms"): 100.0},
-        "run-2": {("b", "step_time_ms"): 110.0},    # +10% < 25%
-    })
-    assert perf_diff.main([str(led)]) == 0
-    assert "OK: no regressions" in capsys.readouterr().out
-    # tighten the tolerance and the same delta gates
-    assert perf_diff.main([str(led), "--tolerance", "0.05"]) == 1
-    capsys.readouterr()
-
-
-def test_perf_diff_check_mode_skips_cleanly(perf_diff, tmp_path, capsys):
-    missing = tmp_path / "nope.jsonl"
-    assert perf_diff.main(["--check", str(missing)]) == 0
-    assert perf_diff.main([str(missing)]) == 2     # strict mode: error
-    single = tmp_path / "single.jsonl"
-    _ledger(single, {"run-1": {("b", "step_time_ms"): 100.0}})
-    assert perf_diff.main(["--check", str(single)]) == 0
-    assert "skipping" in capsys.readouterr().out
-    assert perf_diff.main([str(single)]) == 2
-
-
-def test_perf_diff_rejects_malformed_ledger(perf_diff, tmp_path, capsys):
-    bad = tmp_path / "bad.jsonl"
-    bad.write_text('{"ts": 1.0, "run": "r1", "bench": "b"}\n')
-    assert perf_diff.main([str(bad)]) == 2
-    capsys.readouterr()
-
-
-def test_perf_diff_ungated_and_new_metrics(perf_diff, tmp_path, capsys):
-    """Direction-less metrics and metrics with no baseline report but
-    never gate — a new bench must not fail CI on its first appearance."""
-    led = tmp_path / "ledger.jsonl"
-    _ledger(led, {
-        "run-1": {("b", "recompiles"): 6.0},
-        "run-2": {("b", "recompiles"): 60.0,        # no direction
-                  ("b", "new_thing_ms"): 5.0},      # no baseline
-    })
-    assert perf_diff.main([str(led)]) == 0
-    out = capsys.readouterr().out
-    assert "ungated" in out and "no_baseline" in out
-
-
 def test_profile_spans_cover_engine_and_serving():
     """The frozen span vocabulary must keep covering both planes' track
     sites (engine fwd/bwd/step/train_batch, serving serve_step/prefill)."""
     for span in ("fwd", "bwd", "step", "train_batch", "serve_step",
                  "prefill"):
         assert span in PROFILE_SPANS
-
-
-# ----------------------------------------------------------------------
-# perf-diff: stale-baseline freshness check (--check)
-# ----------------------------------------------------------------------
-def _rows(specs):
-    """specs: (ts, run, bench, metric, value) tuples, in ledger order."""
-    return [{"ts": ts, "run": run, "bench": bench, "metric": metric,
-             "value": value} for ts, run, bench, metric, value in specs]
-
-
-def _write_rows(path, specs):
-    with open(path, "w") as f:
-        for row in _rows(specs):
-            f.write(json.dumps(row) + "\n")
-
-
-def test_stale_baseline_train_evidence_predates_cpu_runs(perf_diff):
-    rows = _rows([(100.0, "gpu-1", "train", "step_time_ms", 9.0)] +
-                 [(100.0 + 10 * i, f"cpu-{i}", "b", "m", 1.0)
-                  for i in range(1, 4)])
-    warn = perf_diff.check_stale_baseline(rows, None, 3)
-    assert warn and "STALE-BASELINE" in warn
-
-
-def test_stale_baseline_fresh_train_evidence(perf_diff):
-    # a train row newer than the oldest of the last-3 cpu runs: fresh
-    rows = _rows([(100.0, "cpu-1", "b", "m", 1.0),
-                  (110.0, "cpu-2", "b", "m", 1.0),
-                  (115.0, "gpu-1", "train", "step_time_ms", 9.0),
-                  (120.0, "cpu-3", "b", "m", 1.0)])
-    assert perf_diff.check_stale_baseline(rows, None, 3) is None
-
-
-def test_stale_baseline_no_evidence_at_all(perf_diff):
-    rows = _rows([(100.0 + i, f"cpu-{i}", "b", "m", 1.0)
-                  for i in range(3)])
-    warn = perf_diff.check_stale_baseline(rows, "/nonexistent", 3)
-    assert warn and "no on-chip train evidence" in warn
-    # not enough cpu runs yet: nothing to judge
-    assert perf_diff.check_stale_baseline(rows[:2], "/nonexistent", 3) \
-        is None
-
-
-def test_stale_baseline_onchip_capture_rescues(perf_diff, tmp_path):
-    rows = _rows([(100.0, "gpu-1", "train", "step_time_ms", 9.0)] +
-                 [(100.0 + 10 * i, f"cpu-{i}", "b", "m", 1.0)
-                  for i in range(1, 4)])
-    cap = tmp_path / "BENCH_onchip_latest.json"
-    cap.write_text(json.dumps({"captured_unix": 500.0}))
-    assert perf_diff.check_stale_baseline(rows, str(cap), 3) is None
-    cap.write_text(json.dumps({"captured_unix": 90.0}))   # older: stale
-    warn = perf_diff.check_stale_baseline(rows, str(cap), 3)
-    assert warn and "predates" in warn
-    cap.write_text("not json")                            # ignored
-    assert "STALE-BASELINE" in perf_diff.check_stale_baseline(
-        rows, str(cap), 3)
-
-
-def test_stale_baseline_in_check_mode_output(perf_diff, tmp_path, capsys):
-    led = tmp_path / "ledger.jsonl"
-    _write_rows(str(led), [(100.0, "gpu-1", "train", "step_time_ms", 9.0)] +
-                [(100.0 + 10 * i, f"cpu-{i}", "b", "m", 1.0)
-                 for i in range(1, 4)])
-    assert perf_diff.main(["--check", str(led)]) == 0   # warns, no gate
-    assert "STALE-BASELINE" in capsys.readouterr().out
-    # strict mode stays quiet about freshness (the gate is the signal)
-    _write_rows(str(led), [(100.0, "cpu-1", "b", "m", 1.0),
-                           (110.0, "cpu-2", "b", "m", 1.0),
-                           (115.0, "gpu-1", "train", "step", 9.0),
-                           (120.0, "cpu-3", "b", "m", 1.0)])
-    assert perf_diff.main(["--check", str(led)]) == 0
-    assert "STALE-BASELINE" not in capsys.readouterr().out

@@ -492,24 +492,6 @@ def test_report_request_latency_table(tiny, tmp_path, capsys):
     assert "request latency" in out and "slowest requests" in out
 
 
-def test_bench_serving_slo_smoke():
-    """The ``serving_slo`` bench worker runs in-process on CPU: latency
-    percentiles, SLO attainment, a clean trace audit, and a validated
-    exporter scrape."""
-    path = os.path.join(REPO, "bench.py")
-    spec = importlib.util.spec_from_file_location("bench", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    r = bench._serving_slo_bench({"requests": 8, "max_new_tokens": 3})
-    assert r["leaks"] == {}
-    assert r["exporter_scrape_ok"]
-    assert r["traces"]["open"] == 0
-    assert r["traces"]["admitted"] == r["traces"]["closed"]
-    assert r["ttft"]["count"] == r["served"]
-    assert r["slo_attained"] + r["slo_missed"] == r["traces"]["closed"]
-    assert r["goodput_tokens"] == r["served"] * 3
-
-
 def test_prom_text_renders_engine_snapshot(tiny, tmp_path):
     """prom_text over a real engine run stays exporter-servable without
     an HTTP round-trip (MetricsExporter import works standalone too)."""

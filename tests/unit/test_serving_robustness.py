@@ -582,23 +582,3 @@ def test_inference_config_carries_serving_block():
     assert c.serving.max_queue == 9 and c.serving.overload_policy == "block"
     with pytest.raises(ValueError):
         DeepSpeedInferenceConfig({"serving": {"overload_policy": "nah"}})
-
-
-def test_bench_serving_overload_smoke():
-    """The ``serving`` bench worker runs in-process on CPU and reports the
-    overload digest (shed rate + step latency tail) leak-free."""
-    repo = os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test_serving", os.path.join(repo, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    res = bench._serving_bench({"requests": 6, "arrivals_per_step": 2,
-                                "max_new_tokens": 4, "warmup_steps": 1,
-                                "max_queue": 3})
-    assert res["offered_requests"] == 6
-    assert res["served"] + res["shed"] + res["rejected"] == 6
-    assert res["policy"] == "shed-oldest"
-    assert res["leaks"] == {}
-    assert res["step_p50_ms"] >= 0 and res["step_p99_ms"] >= res["step_p50_ms"]
-    assert 0.0 <= res["shed_rate"] <= 1.0

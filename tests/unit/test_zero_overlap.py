@@ -76,7 +76,7 @@ def test_overlapped_schedule_gathers_run_under_compute(depth):
 
 def test_schedule_agrees_with_attribution_decomposition():
     """The schedule model and decompose_step (the gauge's producer) must
-    attribute the same exposure — the bench leans on this agreement."""
+    attribute the same exposure."""
     for depth in (0, 1):
         s = simulate_forward_schedule(6, compute_ms=2.0, gather_ms=1.0,
                                       prefetch_depth=depth)
@@ -86,6 +86,81 @@ def test_schedule_agrees_with_attribution_decomposition():
         assert rec["exposed_comm_ms"] == pytest.approx(
             s["exposed_comm_ms"], abs=1e-6)
         assert rec["comm_ms"] == pytest.approx(s["comm_ms"], abs=1e-6)
+
+
+# ----------------------------------------------------------------------
+# explicit schedule: same collectives, reordered issue, same bits
+# ----------------------------------------------------------------------
+def test_explicit_overlapped_schedule_is_bit_identical_to_serial():
+    """A 4-rank ``shard_map`` ZeRO-3 run of a stacked MLP under two
+    schedules built from the SAME explicit collectives: serial (gather
+    layer k, compute layer k) and overlapped (layer k+1's tiled
+    all_gather issued before layer k's compute).  The backward rides
+    the transposed program, where each gather becomes a per-layer
+    psum_scatter.  Every collective is placed by hand, so overlap
+    reorders communication and never math: every step's loss is equal
+    bit for bit, and the run trains."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    world, steps, batch, lr = 4, 50, 32, 0.5
+    mesh = Mesh(np.array(jax.devices()[:world]), ("fsdp",))
+
+    def gather(leaf):
+        return jax.lax.all_gather(leaf, "fsdp", axis=0, tiled=True)
+
+    def mse(h, yb):
+        err = h - yb
+        return jax.lax.psum(jnp.sum(err * err), "fsdp") / (batch * HIDDEN)
+
+    def fwd_serial(wl, bl, xb, yb):
+        h = xb
+        for k in range(LAYERS):
+            h = jnp.tanh(h @ gather(wl[k]) + gather(bl[k]))
+        return mse(h, yb)
+
+    def fwd_overlap(wl, bl, xb, yb):
+        h = xb
+        nxt = (gather(wl[0]), gather(bl[0]))
+        for k in range(LAYERS):
+            wk, bk = nxt
+            if k + 1 < LAYERS:
+                nxt = (gather(wl[k + 1]), gather(bl[k + 1]))
+            h = jnp.tanh(h @ wk + bk)
+        return mse(h, yb)
+
+    w_spec, b_spec, x_spec = (P(None, "fsdp", None), P(None, "fsdp"),
+                              P("fsdp", None))
+    rng = np.random.default_rng(0)
+    w0 = (rng.standard_normal((LAYERS, HIDDEN, HIDDEN))
+          / np.sqrt(HIDDEN)).astype(np.float32)
+    proj = (rng.standard_normal((HIDDEN, HIDDEN)) * 0.5).astype(np.float32)
+    X = rng.standard_normal((steps, batch, HIDDEN)).astype(np.float32)
+    Y = np.tanh(X @ proj)
+
+    def run(fwd):
+        loss_fn = jax.shard_map(
+            fwd, mesh=mesh, in_specs=(w_spec, b_spec, x_spec, x_spec),
+            out_specs=P(), check_vma=False)
+
+        @jax.jit
+        def step(wl, bl, xb, yb):
+            loss, (gw, gb) = jax.value_and_grad(
+                loss_fn, argnums=(0, 1))(wl, bl, xb, yb)
+            return wl - lr * gw, bl - lr * gb, loss
+
+        wl = jax.device_put(w0, NamedSharding(mesh, w_spec))
+        bl = jax.device_put(np.zeros((LAYERS, HIDDEN), np.float32),
+                            NamedSharding(mesh, b_spec))
+        x_sh = NamedSharding(mesh, x_spec)
+        losses = []
+        for i in range(steps):
+            wl, bl, loss = step(wl, bl, jax.device_put(X[i], x_sh),
+                                jax.device_put(Y[i], x_sh))
+            losses.append(np.asarray(loss, np.float32))
+        return np.asarray(losses)
+
+    serial, overlapped = run(fwd_serial), run(fwd_overlap)
+    np.testing.assert_array_equal(serial, overlapped)
+    assert serial[-1] < 0.7 * serial[0]   # actually trains
 
 
 # ----------------------------------------------------------------------
@@ -292,7 +367,7 @@ def test_engine_overlapped_trajectory_matches_serial(depth):
     partitioner's own communication reordering, not a math change; the
     construction-level bit-identity bar — same collectives, reordered
     issue — is enforced where the schedule is explicit, in
-    ``bench.py cpu_overlap``'s shard_map run."""
+    test_explicit_overlapped_schedule_is_bit_identical_to_serial."""
     zero_on = {k: (dict(v, gather_prefetch_depth=depth)
                    if k == "overlap" else v)
                for k, v in _OVERLAP_ZERO.items()}
