@@ -107,6 +107,11 @@ SERVE_EVENTS = (
     # serve/spec_rejected_tokens registry counters)
     "serve/sched", "serve/prefill_chunk",
     "serve/spec_draft", "serve/spec_verify",
+    # the once-per-engine record of a model with state-space layers
+    # ("serve/state": layers / slot_bytes / dtype / conv_dtype, the
+    # recurrent state it keeps a slot beside the pages, and redo, what a
+    # dropped decode row costs: "prefill_from_zero")
+    "serve/state",
     # per-request lifecycle trace (RequestTracer): one event per state
     # transition, each carrying req_id plus the derived latencies so a
     # request's full history is reconstructible from the JSONL stream

@@ -181,6 +181,13 @@ class SchedulerBase:
         write — the engine sizes the page reservation from it."""
         raise NotImplementedError
 
+    def prefill_pieces(self, suffix_tokens: int) -> List[int]:
+        """Shapes of the dispatches that prefill ``suffix_tokens`` in one
+        go, in launch order: one power-of-two bucket (the monolithic
+        policy may send a long one as pieces)."""
+        eng = self.engine
+        return [min(eng._bucket(suffix_tokens), eng.max_seq)]
+
     def fill_slot(self, slot: int, req, cached: int) -> bool:
         """A queued request just landed in ``slot`` (pages reserved,
         COW done).  Returns True when the prefill ran to completion
@@ -253,7 +260,10 @@ class SchedulerBase:
         another token, a sampler fault, a deadline or an eviction while
         the row is in flight.  The row is dropped (``redone``), the length
         stays, and the slot is fed from the host again at the same
-        position, which overwrites what the dropped row wrote."""
+        position, which overwrites what the dropped row wrote.  (A K/V
+        row written twice is the same row; a recurrent state advanced
+        twice is wrong, so a model with state-space layers builds the
+        slot's state again first: ``ServingEngine._redo_state``.)"""
         eng = self.engine
         tel = eng.telemetry
         done_slots: List[int] = []
@@ -355,6 +365,9 @@ class SchedulerBase:
                 self._fed(slot, following, done_slots)
             else:
                 following.drop(slot, req)
+                if eng._stateful:
+                    # the dropped row advanced the slot's recurrent state
+                    eng._redo_state(slot, req)
         for slot, err in fault_slots:
             rid = eng.slots[slot].req_id
             logger.warning(f"evicting request {rid!r} after sampler "
@@ -556,7 +569,7 @@ class MonolithicScheduler(SchedulerBase):
                 return [1 << bit
                         for bit in reversed(range(padded.bit_length()))
                         if padded >> bit & 1]
-        return [min(eng._bucket(suffix_tokens), eng.max_seq)]
+        return super().prefill_pieces(suffix_tokens)
 
     def prefill_padded_len(self, suffix_tokens: int) -> int:
         return sum(self.prefill_pieces(suffix_tokens))

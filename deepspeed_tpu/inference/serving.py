@@ -57,6 +57,7 @@ from deepspeed_tpu.ops.latent_attention import (PREFILL_BLOCK_K,
                                                 context_entries)
 from deepspeed_tpu.ops.paged_attention import (PageAllocationError,
                                                PagedAllocator,
+                                               kv_lane_pack,
                                                resolve_attention_backend,
                                                resolve_paged_impl,
                                                ring_pages)
@@ -100,6 +101,14 @@ CHUNK_COUNTS = ("ctx_entries", "chunk")
 # model's prefill over the pool (its prefill dispatches)
 # (scripts/check_telemetry_schema.py DISPATCH_IMPLS, frozen)
 DISPATCH_IMPLS = ("kv_write", "experts", "latent")
+# what a model with state-space layers adds to each prefill and decode
+# dispatch of ``last_step`` and to its ``serve/step`` span, from the host
+# (frozen in scripts/check_telemetry_schema.py): ``state_slots``, the rows
+# whose recurrent state the dispatch advanced (a prefill's one slot, a
+# decode step's served slots), and ``state_bytes``, the bytes of state and
+# of the convolution's last inputs it had to read and write for them, all
+# such layers (from shapes and dtypes)
+STATE_COUNTS = ("state_slots", "state_bytes")
 
 # A whole-prompt prefill longer than this pads to the next multiple of it,
 # not to the next power of two, and goes as pieces that are powers of two
@@ -342,17 +351,22 @@ class ServingEngine:
             self.serving = serving
         else:
             self.serving = ServingRobustnessConfig(serving or {})
-        self._refuse_unsupported(tp_size, ep_size)
+        self._refuse_unsupported(tp_size, ep_size, decode_chunk)
         # a model with sliding-window layers keeps their keys and values
         # in a ring of pages a slot, beside the growing tables of its
         # full-attention layers (ops/paged_attention.py): each row of
         # ``tables`` ends in the slot's ring
         window = int(getattr(self.config, "attn_window", 0) or 0)
         self.ring_pages = ring_pages(window, page_size) if window else 0
+        # a model with state-space layers keeps their recurrent state
+        # beside the page pools, a row a SLOT (ops/ssm.py HybridKVCache):
+        # no allocator, nothing to leak
+        self._stateful = bool(getattr(self.config, "has_ssm", False))
         with setup_span("setup/engine/pools"):
             caches = model.init_paged_caches(
                 num_pages, page_size, dtype=dtype,
-                **({"ring_slots": max_batch} if window else {}))
+                **({"ring_slots": max_batch} if window else {}),
+                **({"state_slots": max_batch} if self._stateful else {}))
         if ep_size > 1:
             assert getattr(self.config, "is_moe", False), \
                 "ep_size > 1 needs an MoE model"
@@ -383,6 +397,11 @@ class ServingEngine:
         self.params = params
         self.caches = caches
         self.cache_dtype = dtype
+        # bytes of recurrent state (and of the convolution's last inputs)
+        # a slot holds, all state-space layers; 0 for a model without
+        self.state_slot_bytes = sum(
+            leaf.size * jnp.dtype(leaf.dtype).itemsize // max_batch
+            for leaf in caches.ssm) if self._stateful else 0
         if injector is None:
             injector = FaultInjector.from_config(
                 self.serving.fault_injection)
@@ -508,6 +527,7 @@ class ServingEngine:
         # anyway (_fetch)
         self._counted = bool(getattr(self.config, "counts_serving", False))
         self._prefill_sizes = None
+        self._prefill_slot = None
         # dispatches of the two serving programs that no fetch has
         # brought yet, in launch order (a prefill chunk, or a piece of a
         # long prompt, that is not sampled from is never waited for, a
@@ -528,20 +548,31 @@ class ServingEngine:
         def with_picks(out):
             return out + (jnp.argmax(out[0], axis=-1).astype(jnp.int32),)
 
+        # what ``_run_step`` hands a program after its fixed arguments,
+        # in this order: how many of each sequence's rows are tokens (a
+        # counted model's dispatches; a state model's prefill), and the
+        # slot whose state a prefill starts from and leaves advanced (a
+        # state model's; its decode step takes both from ``lengths``)
+        told = ("real_lengths", "state_slots")
+        # the two programs close over the model's call and not over this
+        # engine: their scope tables are read after the engine is dropped
+        # (telemetry.register_compiled(keep=True))
+        paged_call = self._paged_call
+
         def serve_prefill(params, ids, caches, tables, lengths, rows, *real):
             # the head on ``rows`` alone: the row its caller samples from
-            return with_picks(self._paged_call(
+            return with_picks(paged_call(
                 params, ids, caches, tables, lengths, head_rows=rows,
-                **dict(zip(("real_lengths",), real))))
+                **dict(zip(told, real))))
 
         def serve_decode(params, ids, caches, tables, lengths, fed, *real):
             # a row whose id is negative is fed ``fed``'s: the pick of the
             # decode dispatch before this one, which has not left the
             # device (the scheduler launches a step before the host has
             # the ids of the one before: scheduler._decode_once)
-            return with_picks(self._paged_call(
+            return with_picks(paged_call(
                 params, jnp.where(ids < 0, fed, ids), caches, tables,
-                lengths, **dict(zip(("real_lengths",), real))))
+                lengths, **dict(zip(told, real))))
 
         self._prefill_fn = jax.jit(serve_prefill, donate_argnums=(2,))
         self._step_fn = jax.jit(serve_decode, donate_argnums=(2,))
@@ -571,9 +602,10 @@ class ServingEngine:
         # shows up as compile/* events, and a recompile storm flips
         # health()["recompile_storm"].  Telemetry must be bound first.
         self._storm_flagged = False
-        self._step_fn = self._wrap_compiled(self._step_fn, "serve/step_fn")
+        self._step_fn = self._wrap_compiled(self._step_fn, "serve/step_fn",
+                                            keep=True)
         self._prefill_fn = self._wrap_compiled(self._prefill_fn,
-                                               "serve/prefill_fn")
+                                               "serve/prefill_fn", keep=True)
         # the engine's own account of each step(): what it dispatched and
         # which tokens reached the host when (docs/telemetry.md).  The
         # open report collects from the moment the last step() returned,
@@ -602,7 +634,8 @@ class ServingEngine:
                       "prefix_cow_copies": 0, "prefix_evictions": 0,
                       "slo_attained": 0, "slo_missed": 0,
                       "goodput_tokens": 0,
-                      "prefill_handoffs": 0, "imports": 0}
+                      "prefill_handoffs": 0, "imports": 0,
+                      "state_redone": 0}
         # one frozen event per engine records which attention path every
         # serve/step span of this stream ran (ds_telemetry_report keys
         # its serving-attention table off it)
@@ -618,6 +651,15 @@ class ServingEngine:
                                           draft_model=draft_model,
                                           draft_params=draft_params)
         self._serve_event("serve/sched", **self.scheduler.meta())
+        if self._stateful:
+            # one frozen event an engine: the state beside the pages, and
+            # what a dropped decode row costs (``_redo_state``)
+            self._serve_event(
+                "serve/state", layers=int(caches.ssm.state.shape[0]),
+                slot_bytes=int(self.state_slot_bytes),
+                dtype=jnp.dtype(caches.ssm.state.dtype).name,
+                conv_dtype=jnp.dtype(caches.ssm.conv.dtype).name,
+                redo="prefill_from_zero")
         # incident plane: bundles snapshot this engine's health() and its
         # in-flight request traces alongside the flight-recorder dump
         incidents = getattr(self.telemetry, "incidents", None)
@@ -626,12 +668,15 @@ class ServingEngine:
             incidents.add_context("inflight_traces",
                                   self.tracer.snapshot_open)
 
-    def _refuse_unsupported(self, tp_size, ep_size):
-        """What a latent-attention model, or one with sliding-window
-        layers, cannot be served with until someone builds it, refused by
-        name when the engine is made."""
+    def _refuse_unsupported(self, tp_size, ep_size, decode_chunk=1):
+        """What a latent-attention model, one with sliding-window layers,
+        or one with state-space layers (a recurrent state a slot beside
+        the pages) cannot be served with until someone builds it, refused
+        by name when the engine is made."""
         if getattr(self.config, "attn_window", 0):
             self._refuse_for_ring(tp_size, ep_size)
+        if getattr(self.config, "has_ssm", False):
+            self._refuse_for_state(tp_size, ep_size, decode_chunk)
         if not getattr(self.config, "is_latent", False):
             return
         selects = bool(getattr(self.config, "index_topk", 0))
@@ -691,14 +736,47 @@ class ServingEngine:
                 "the scanned periods' stacked weights have no sharding "
                 "rules yet")
 
+    def _refuse_for_state(self, tp_size, ep_size, decode_chunk):
+        """A state-space layer's state is the whole of one sequence's past
+        in one row a slot: it can be started from zero and advanced, not
+        shared, rolled back or cut at a page boundary."""
+        if getattr(self.serving.prefix_cache, "enabled", False):
+            raise ServingUnsupported(
+                "prefix_cache with state-space layers",
+                "a prefill onto shared pages would need the recurrent "
+                "state as it stood at the page boundary, and no snapshot "
+                "of it is kept")
+        sched = self.serving.scheduler
+        if getattr(getattr(sched, "speculative", None), "enabled", False):
+            raise ServingUnsupported(
+                "scheduler.speculative with state-space layers",
+                "a verify window advances the state by every drafted "
+                "token, and a rejected draft would have to roll it back")
+        if int(decode_chunk) > 1:
+            raise ServingUnsupported(
+                "decode_chunk > 1 with state-space layers",
+                "the device scan runs on past a request's last token, "
+                "and the state it advanced there cannot be taken back")
+        if tp_size > 1 or ep_size > 1:
+            raise ServingUnsupported(
+                "tp_size / ep_size > 1 with state-space layers",
+                "the state pools and the scanned periods' stacked weights "
+                "have no sharding rules yet")
+
     def _refuse_migration(self, what):
         """KV-page migration moves the pages of the growing tables; a
-        window model's rings would stay behind."""
+        window model's rings, and a state-space model's recurrent state,
+        would stay behind."""
         if self.ring_pages:
             raise ServingUnsupported(
                 f"{what} with sliding-window layers",
                 "a handed-off or imported request would need its ring's "
                 "pages moved and re-seated in the receiving slot's ring")
+        if self._stateful:
+            raise ServingUnsupported(
+                f"{what} with state-space layers",
+                "a handed-off or imported request would need its slot's "
+                "recurrent state moved with its pages")
 
     def _seat(self, slot: int, req_id):
         """Point ``slot``'s row of the tables at the request's pages (and
@@ -721,11 +799,11 @@ class ServingEngine:
         tel = self.telemetry
         return getattr(tel, "profiling", None) if tel is not None else None
 
-    def _wrap_compiled(self, fn, site):
+    def _wrap_compiled(self, fn, site, keep=False):
         """Register the jitted entry point under its site name
-        (``telemetry.op_scopes``), and compile-trace it with the
-        profiling plane on."""
-        fn = register_compiled(fn, site, mesh=self.mesh)
+        (``telemetry.op_scopes``; ``keep``: readable after this engine is
+        dropped), and compile-trace it with the profiling plane on."""
+        fn = register_compiled(fn, site, mesh=self.mesh, keep=keep)
         prof = self._profiling
         return prof.wrap(fn, site) if prof is not None else fn
 
@@ -733,7 +811,8 @@ class ServingEngine:
     @staticmethod
     def _new_report():
         return {"t0_ns": None, "t1_ns": None, "dispatches": [],
-                "emitted": [], "prompt_tokens": 0, "active": 0, "queued": 0}
+                "emitted": [], "prompt_tokens": 0, "active": 0, "queued": 0,
+                "state_redone": 0}
 
     def _emit(self, req_id, n=1, t_ns=None):
         """``n`` output tokens of ``req_id`` are on the host (as of
@@ -752,7 +831,10 @@ class ServingEngine:
         decode ``contexts``) and ``emitted`` (``(req_id, n_tokens,
         t_host_ns)``, stamped when the tokens reached the host) since the
         previous ``step()`` returned; ``prompt_tokens`` whose keys and
-        values became available; ``active`` and ``queued`` at the end."""
+        values became available; ``active`` and ``queued`` at the end;
+        ``state_redone``, of a model with state-space layers, the slots
+        whose recurrent state was built again after a dropped decode row
+        (``_redo_state``)."""
         return list(self._reports)
 
     def _prof_track(self, span):
@@ -1182,6 +1264,12 @@ class ServingEngine:
         self.stats["prefill_handoffs"] += 1
         self._close_trace(req, "finish", reason="prefill_handoff")
 
+    def _page_pools(self):
+        """The leaves of the caches that hold PAGES (a state-space
+        model's recurrent state, a row a slot, is none of them)."""
+        return jax.tree_util.tree_leaves(
+            self.caches.full if self._stateful else self.caches)
+
     # -- KV-page migration (disaggregated fleets) ------------------------
     @property
     def kv_page_bytes(self) -> int:
@@ -1192,7 +1280,7 @@ class ServingEngine:
             self._kv_page_bytes = sum(
                 int(np.prod(leaf.shape[:1] + leaf.shape[2:])) *
                 jnp.dtype(leaf.dtype).itemsize
-                for leaf in jax.tree_util.tree_leaves(self.caches))
+                for leaf in self._page_pools())
         return self._kv_page_bytes
 
     @staticmethod
@@ -1414,7 +1502,8 @@ class ServingEngine:
         self._seat(slot, req.req_id)
 
     def _prefill_next(self, real: int, context: int, sample: bool = True,
-                      chunk: Optional[int] = None):
+                      chunk: Optional[int] = None,
+                      slot: Optional[int] = None):
         """Sizes of the prefill dispatch about to be launched (``_run_step``
         keeps the signature its wrappers replace, so they come ahead of
         it): ``real`` prompt tokens under its padded ``tokens``, the
@@ -1422,11 +1511,14 @@ class ServingEngine:
         will ``sample`` from its last real row (a chunk that is not the
         prompt's last reads nothing, and its program takes no head), and
         the ``chunk``'s index in its prompt where the prompt comes in
-        chunks (the chunked policy; reported with the dispatch)."""
+        chunks (the chunked policy; reported with the dispatch).  ``slot``:
+        the slot the rows belong to, which a model with state-space layers
+        is told (its recurrent state is a row a slot)."""
         self._prefill_sizes = {"real": int(real), "context": int(context),
                                "head_rows": int(bool(sample))}
         if chunk is not None:
             self._prefill_sizes["chunk"] = int(chunk)
+        self._prefill_slot = slot
 
     def _run_step(self, ids, tables, lengths, phase="decode"):
         """One dispatch of the paged step: the launch only, nothing here
@@ -1443,7 +1535,9 @@ class ServingEngine:
         dispatches is also told how many of each sequence's rows are
         tokens (a prefill's prompt under its bucket; else every row of a
         slot that holds a context, so none of a decode batch's idle
-        slots)."""
+        slots).  A model with state-space layers is told a PREFILL's real
+        rows and its slot (``_prefill_next``); its decode step takes the
+        slots it serves from ``lengths``."""
         step_fn, sizes = self._step_fn, {}
         args = (self.params, ids, self.caches, tables, lengths)
         if phase == "prefill":
@@ -1454,10 +1548,12 @@ class ServingEngine:
                              sizes["real"] - 1, np.int32),)
         else:
             args += (self._picks,)
-        if self._counted:
+        if self._counted or (self._stateful and sizes):
             real = (np.full(ids.shape[0], sizes["real"]) if sizes else
                     np.where(np.asarray(lengths) > 0, ids.shape[1], 0))
             args += (np.asarray(real, np.int32),)
+        if self._stateful and sizes:
+            args += (np.full(ids.shape[0], self._prefill_slot, np.int32),)
         out = self._dispatch(step_fn, args, phase, *ids.shape,
                              starts=np.asarray(lengths), **sizes)
         self._report["prompt_tokens"] += sizes.get("real", 0)
@@ -1490,9 +1586,12 @@ class ServingEngine:
         def steps(n_layers, ctx, width, window=None, ring=None):
             key = (batch, T, id(config), width, window)
             if key not in self._kernel_tiles:
+                # the heads as the pools hold them (kv_lane_pack)
+                pack = kv_lane_pack(config.kv_heads, config.head_dim)
                 self._kernel_tiles[key] = pick_tiles(
-                    [T] * batch, config.n_heads // config.kv_heads,
-                    config.kv_heads, self.page_size, config.head_dim, width,
+                    [T] * batch, config.n_heads // config.kv_heads * pack,
+                    config.kv_heads // pack, self.page_size,
+                    config.head_dim * pack, width,
                     jnp.dtype(self.cache_dtype).itemsize, window=window,
                     ring=ring)
             tiles = self._kernel_tiles[key]
@@ -1501,7 +1600,8 @@ class ServingEngine:
                                            self.page_size, window),
                 n_layers * calls * tiles.grid_steps])
 
-        run = steps(config.n_layers - len(windows), ctx,
+        state_layers = sum(getattr(config, "ssm_pattern", None) or ())
+        run = steps(config.n_layers - len(windows) - state_layers, ctx,
                     self.tables.shape[1] - ring)
         for window in sorted(set(windows)):
             n = windows.count(window)
@@ -1554,6 +1654,14 @@ class ServingEngine:
             block = self._latent_walk_keys(tokens)
             counted = {"ctx_entries": max(
                 context_entries(n, self.page_size, block) for n in starts)}
+        elif self._stateful and config is None:
+            # the rows whose state it advances: a prefill's one slot, a
+            # decode step's served slots; each reads and writes its state
+            # and its convolution's last inputs in every such layer
+            rows = int(batch) if phase == "prefill" else \
+                int(np.count_nonzero(np.asarray(starts)))
+            counted = dict(zip(STATE_COUNTS, (
+                rows, 2 * rows * self.state_slot_bytes)))
         attrs.update(counted, **{k: sizes[k] for k in ("chunk",)
                                  if k in sizes})
         with self.telemetry.span("serve/step", attrs=attrs), \
@@ -1686,7 +1794,8 @@ class ServingEngine:
 
     def _prefill_rows(self, slot: int, req: _Request, start: int,
                       shape: int, sample: bool = True,
-                      chunk: Optional[int] = None) -> StepLogits:
+                      chunk: Optional[int] = None,
+                      seq: Optional[List[int]] = None) -> StepLogits:
         """Launch one prefill dispatch of ``shape`` rows for the prompt's
         tokens ``[start, start + shape)`` (fewer at the prompt's end, the
         rest padding) at position ``start``: a whole suffix, a piece of
@@ -1694,8 +1803,10 @@ class ServingEngine:
         attention reads what lies before ``start`` through the block
         table, so the rows are those of a prefill of the whole prompt.
         ``sample`` and ``chunk`` as ``_prefill_next`` has them.  The slot
-        then holds the prompt as far as these rows reach."""
-        tokens = req.prompt[start:start + shape]
+        then holds the prompt as far as these rows reach.  ``seq``: the
+        tokens to run in the prompt's place (``_redo_state``: the prompt
+        and the output so far, which the slot holds already)."""
+        tokens = (req.prompt if seq is None else seq)[start:start + shape]
         with self.telemetry.span("serve/prefill/build"):
             ids = np.zeros((1, shape), np.int32)
             ids[0, :len(tokens)] = tokens
@@ -1704,16 +1815,46 @@ class ServingEngine:
                     np.full((1,), start, np.int32))
         t0 = self._clock()
         self._prefill_next(len(tokens), start + len(tokens), sample=sample,
-                           chunk=chunk)
+                           chunk=chunk, slot=slot)
         logits, self.caches, _ = self._run_step(*args, phase="prefill")
         # the dispatch's active wall time feeds the critical path's
         # prefill stage; the wait BETWEEN the chunked policy's chunks lands
         # in the gap stage — the split that separates scheduler wins from
         # kernel wins
-        self.attrib.chunk(req.req_id, (self._clock() - t0) * 1000.0)
-        req.prefilled = start + len(tokens)
-        self.lengths[slot] = req.prefilled
+        if seq is None:
+            self.attrib.chunk(req.req_id, (self._clock() - t0) * 1000.0)
+            req.prefilled = start + len(tokens)
+        self.lengths[slot] = start + len(tokens)
         return logits
+
+    def _redo_state(self, slot: int, req: _Request):
+        """A decode row of ``slot`` ran and was dropped (the host's token
+        was not the device's pick: ``scheduler._take_picks``), and the
+        request goes on.  The K/V row it wrote is overwritten by the row
+        that feeds the slot again at the same length; the recurrent state
+        it advanced cannot be taken back.  So the slot's state is built
+        again from zero, by a prefill of the prompt and the output so far
+        from position 0 (the pages take the same rows again), launched
+        behind the dropped row and ahead of the one that feeds the slot
+        again; nothing of it is fetched.  It costs no memory and no
+        operand a step; the event is rare (a row is only in flight for a
+        greedy request, whose token IS the device's pick unless a sampler
+        says otherwise).  Counted in ``stats`` and the step's report."""
+        seq = req.prompt + req.out
+        assert len(seq) == int(self.lengths[slot])
+        pieces = self.scheduler.prefill_pieces(len(seq))
+        with self.telemetry.span("serve/prefill", req_id=req.req_id,
+                                 attrs={"bucket": sum(pieces),
+                                        "real": len(seq), "cached": 0,
+                                        "pieces": len(pieces), "redo": 1}):
+            start = 0
+            for shape in pieces:
+                self._prefill_rows(slot, req, start, shape, seq=seq)
+                start += shape
+        # the rows are no new prompt tokens: the step's report counts none
+        self._report["prompt_tokens"] -= len(seq)
+        self.stats["state_redone"] += 1
+        self._report["state_redone"] += 1
 
     def _prefill(self, slot: int, req: _Request, pieces: List[int],
                  cached: int = 0):
@@ -2030,6 +2171,13 @@ class ServingEngine:
                        "terminals": dict(self.tracer.terminals)},
         }
         snap["scheduler"] = self.scheduler.snapshot()
+        if self._stateful:
+            # the recurrent state beside the pages: constant, whatever the
+            # contexts (a row a slot)
+            snap["state"] = {
+                "slot_bytes": self.state_slot_bytes,
+                "bytes": self.state_slot_bytes * self.max_batch,
+                "redone": self.stats["state_redone"]}
         if self.prefix_cache is not None:
             snap["prefix_cache"] = self.prefix_cache.snapshot()
         prof = self._profiling
@@ -2097,12 +2245,23 @@ class ServingEngine:
         ring_leaves = [id(leaf) for leaf in jax.tree_util.tree_leaves(
             getattr(self.caches, "ring", ()))]
         pools = {i: tuple(leaf.shape) for i, leaf in enumerate(
-            jax.tree_util.tree_leaves(self.caches))
+            self._page_pools())
             if leaf.shape[1] != (self.alloc.ring_pool + 1
                                  if id(leaf) in ring_leaves
                                  else self.alloc.num_pages)}
         if pools:
             leaks["pool_page_mismatch"] = pools
+        if self._stateful:
+            # a state-space model's state is a row a slot: nothing to
+            # allocate or free, so all there is to audit is that the
+            # pools hold ``max_batch`` rows (``health()`` says the bytes)
+            ssm = self.caches.ssm
+            if (ssm.state.shape[1], ssm.conv.shape[1]) != \
+                    (self.max_batch,) * 2:
+                leaks["state_slot_mismatch"] = {
+                    "slot_bytes": self.state_slot_bytes,
+                    "state": tuple(ssm.state.shape),
+                    "conv": tuple(ssm.conv.shape)}
         unseated = [s for s, req in enumerate(self.slots)
                     if req is not None and self.ring_pages and list(
                         self.tables[s, -self.ring_pages:])

@@ -84,6 +84,20 @@ class TransformerConfig:
     # forward scans over the periods instead of unrolling every layer
     rope_pattern: Optional[Tuple[bool, ...]] = None
     layer_period: int = 0
+    # ``ssm_pattern``: per layer, whether its mixer is a state-space
+    # (Mamba-2) layer in place of attention (ops/ssm.py): ``ssm_heads``
+    # heads of ``ssm_head_dim`` (their product the mixer's inner width),
+    # a state of ``ssm_state`` a head element, ``ssm_groups`` groups
+    # sharing B and C, a causal depthwise convolution of ``ssm_conv`` rows
+    # ahead of the recurrence, which a sequence of rows computes in
+    # chunks of ``ssm_chunk``
+    ssm_pattern: Optional[Tuple[bool, ...]] = None
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
     attn_gate: bool = False                 # attention output x sigmoid(h
     #   wg_attn) ahead of ``wo`` (afmoe's gated attention)
     sandwich_norm: bool = False             # pre-norms AND post-norms on
@@ -198,6 +212,25 @@ class TransformerConfig:
         return self.moe_experts_held or self.moe_num_experts
 
     @property
+    def has_ssm(self):
+        """Some layers' mixer is a state-space layer (``ssm_pattern``):
+        the serving path keeps a recurrent state a slot beside the pages."""
+        return bool(self.ssm_pattern) and any(self.ssm_pattern)
+
+    def layer_ssm(self, i):
+        return bool(self.ssm_pattern) and bool(self.ssm_pattern[i])
+
+    @property
+    def ssm_inner(self):
+        """The state-space mixer's inner width, heads x head width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self):
+        """Channels of its convolution: x | B | C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
     def counts_serving(self):
         """A serving dispatch of this model returns ``SERVE_COUNTERS``
         (and is told which of its rows are tokens)."""
@@ -237,8 +270,12 @@ class TransformerConfig:
 
     @property
     def layers_listed(self):
-        """Layers differ in structure: params["layers"] is a list."""
-        return self.is_moe or self.is_latent
+        """Layers differ in structure, so params["layers"] is a list (and
+        ``layer_period`` may stack the periods after it), for one of three
+        reasons: some layers hold experts (MoE), the attention is latent,
+        or the kinds of mixer differ (state-space layers among attention
+        layers)."""
+        return self.is_moe or self.is_latent or self.has_ssm
 
     @property
     def kv_heads(self):
@@ -322,6 +359,14 @@ class TransformerConfig:
                           if self.qk_norm == "rms_flat" else 2 * dh)
         dense = (3 if self.gated else 2) * d * f
         total = self.n_layers * per_layer + v * d + d
+        if self.has_ssm:
+            # a state-space layer holds its mixer in place of q, k, v, o:
+            # in and out projections, the convolution and its bias, A_log,
+            # D and dt_bias a head, the gated norm
+            inner, conv = self.ssm_inner, self.ssm_conv_dim
+            mixer = d * (inner + conv + self.ssm_heads) + inner * d \
+                + (self.ssm_conv + 1) * conv + 3 * self.ssm_heads + inner
+            total += sum(self.ssm_pattern) * (mixer + 2 * d - per_layer)
         if self.is_moe:
             # what this process HOLDS: the router's published width, the
             # held experts (all of them on the capacity path), the shared
@@ -651,13 +696,22 @@ class CausalTransformerLM:
         self.config = config
         self.gate = None
         c = config
-        for name in ("local_attn_pattern", "rope_pattern"):
+        for name in ("local_attn_pattern", "rope_pattern", "ssm_pattern"):
             pattern = getattr(c, name)
             assert pattern is None or len(pattern) == c.n_layers, \
                 f"{name} has {len(pattern)} entries for {c.n_layers} layers"
         if c.rope_pattern is not None or c.layer_period:
-            assert c.layers_listed, \
-                "rope_pattern / layer_period need a listed (MoE) layer stack"
+            assert c.layers_listed, (
+                "rope_pattern / layer_period need a listed layer stack: a "
+                "model with expert layers, latent attention or "
+                "state-space layers (TransformerConfig.layers_listed)")
+        if c.has_ssm:
+            assert c.ssm_heads % c.ssm_groups == 0 and c.ssm_state > 0 \
+                and c.ssm_head_dim > 0 and c.ssm_conv > 1, \
+                "a state-space layer needs its sizes (ssm_heads, " \
+                "ssm_head_dim, ssm_state, ssm_groups, ssm_conv)"
+            assert not (c.is_latent or c.attn_window or c.parallel_block), \
+                "state-space layers stand among plain attention layers"
         if c.layer_period:
             lead, period = c.leading_layers, c.layer_period
             assert (c.n_layers - lead) % period == 0 and \
@@ -666,8 +720,10 @@ class CausalTransformerLM:
                     f"{period} after the {lead} leading ones")
             for i in range(lead, c.n_layers):
                 at = lead + (i - lead) % period
-                assert (c.layer_window(i), c.layer_rotary(i)) == \
-                    (c.layer_window(at), c.layer_rotary(at)), \
+                assert (c.layer_window(i), c.layer_rotary(i),
+                        c.layer_ssm(i)) == \
+                    (c.layer_window(at), c.layer_rotary(at),
+                     c.layer_ssm(at)), \
                     f"layer {i} does not repeat layer {at}'s pattern"
         if config.is_moe and not config.moe_dropless:
             from deepspeed_tpu.moe.sharded_moe import TopKGate
@@ -773,13 +829,16 @@ class CausalTransformerLM:
         dh, H, Hkv, E = c.head_dim, c.n_heads, c.kv_heads, c.moe_num_experts
         keys = jax.random.split(rng, c.n_layers + 4)
 
-        def one_layer(key, moe: bool):
+        def one_layer(key, moe: bool, ssm: bool = False):
             ks = jax.random.split(key, 8)
             norm_keys = (("attn_post_norm", "mlp_post_norm")
                          if c.post_norm_only else ("attn_norm", "mlp_norm"))
             if c.sandwich_norm:
                 norm_keys += ("attn_post_norm", "mlp_post_norm")
-            if c.is_latent:
+            if ssm:
+                layer = {"ssm": self._init_ssm(ks[0], dtype, dense)}
+                layer.update({k: jnp.ones((d,), dtype) for k in norm_keys})
+            elif c.is_latent:
                 layer = self._init_latent_attn(ks[0], dtype, dense)
                 layer.update({k: jnp.ones((d,), dtype) for k in norm_keys})
             else:
@@ -793,7 +852,7 @@ class CausalTransformerLM:
                 if c.attn_gate:
                     layer["wg_attn"] = dense(jax.random.fold_in(ks[0], 1),
                                              (d, H * dh), d)
-            if c.qk_norm:
+            if c.qk_norm and not ssm:
                 qd, kd = ((H * dh, Hkv * dh) if c.qk_norm == "rms_flat"
                           else (dh, dh))
                 layer["q_norm"] = jnp.ones((qd,), dtype)
@@ -841,7 +900,8 @@ class CausalTransformerLM:
         params = {
             "tok_embed": dense(keys[-1], (v, d), embed_fan),
             "final_norm": jnp.ones((d,), dtype),
-            "layers": [one_layer(keys[i], self._is_moe_layer(i))
+            "layers": [one_layer(keys[i], self._is_moe_layer(i),
+                                 c.layer_ssm(i))
                        for i in range(lead)],
         }
         if period:
@@ -850,7 +910,8 @@ class CausalTransformerLM:
             # finished layers would hold the weights twice
             params["periods"] = [
                 jax.vmap(functools.partial(
-                    one_layer, moe=self._is_moe_layer(lead + j)))(
+                    one_layer, moe=self._is_moe_layer(lead + j),
+                    ssm=c.layer_ssm(lead + j)))(
                         keys[lead + j:c.n_layers:period])
                 for j in range(period)]
         if not c.use_rope:
@@ -858,6 +919,41 @@ class CausalTransformerLM:
         if not c.tie_embeddings:
             params["lm_head"] = dense(keys[-3], (d, v), d)
         return params
+
+    def _init_ssm(self, key, dtype, dense):
+        """One state-space layer's mixer: the joint projection to
+        [z | x B C | dt], the depthwise convolution over x B C (``conv_w``
+        [K, C], row K - 1 on the current input) with its bias, a decay
+        rate ``A_log``, a skip ``D`` and a ``dt_bias`` a head, the gated
+        norm and the output projection.
+
+        The three per-head vectors are seeded as Mamba-2's own
+        initialiser seeds them: ``dt`` log-uniform in [0.001, 0.1] with
+        ``dt_bias`` its inverse softplus, ``A`` uniform in [1, 16], ``D``
+        ones: time constants from one token to a thousand.  (``log(1..H)``
+        and ones, the values a converted checkpoint would overwrite, decay
+        by ``exp(-1.3 h)`` a step: a seeded model that forgets within two
+        tokens, on which a lost state would read as nothing.)"""
+        c = self.config
+        d, H, K = c.hidden_size, c.ssm_heads, c.ssm_conv
+        inner, conv = c.ssm_inner, c.ssm_conv_dim
+        ks = jax.random.split(key, 6)
+
+        def uniform(key, shape, lo, hi):
+            return jax.random.uniform(key, shape, jnp.float32, lo, hi)
+
+        dt = jnp.exp(uniform(ks[2], (H,), math.log(1e-3), math.log(1e-1)))
+        bound = 1.0 / math.sqrt(K)
+        return {
+            "w_in": dense(ks[0], (d, inner + conv + H), d),
+            "conv_w": uniform(ks[1], (K, conv), -bound, bound).astype(dtype),
+            "conv_b": uniform(ks[5], (conv,), -bound, bound).astype(dtype),
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            "A_log": jnp.log(uniform(ks[3], (H,), 1.0, 16.0)).astype(dtype),
+            "D": jnp.ones((H,), dtype),
+            "norm": jnp.ones((inner,), dtype),
+            "w_out": dense(ks[4], (inner, d), inner),
+        }
 
     def _init_latent_attn(self, key, dtype, dense):
         """One layer's latent-attention weights: the query's low-rank pair
@@ -1135,7 +1231,7 @@ class CausalTransformerLM:
         from deepspeed_tpu.ops.paged_attention import (paged_decode_attention,
                                                        write_paged)
         c = self.config
-        with (jax.named_scope("attn_full") if c.attn_window
+        with (jax.named_scope("attn_full") if c.attn_window or c.has_ssm
               else contextlib.nullcontext()):
             pools = write_paged(pools, index, block_tables, lengths, k, v,
                                 impl=impl, interpret=interpret)
@@ -1179,9 +1275,14 @@ class CausalTransformerLM:
                 items=items, **kwargs), pool
         n_pages = -(-T // page)
 
+        # as the pool holds them: heads narrower than the lanes share a
+        # row (ops/paged_attention.py kv_lane_pack)
+        Hkv, dh = pool.k_pages.shape[2], pool.k_pages.shape[4]
+
         def as_pages(rows):     # [B, T, Hkv, dh] -> [1, B x n, Hkv, page, dh]
-            rows = jnp.pad(rows, ((0, 0), (0, n_pages * page - T),
-                                  (0, 0), (0, 0)))
+            rows = jnp.pad(rows.reshape(B, T, Hkv, dh),
+                           ((0, 0), (0, n_pages * page - T),
+                            (0, 0), (0, 0)))
             return jnp.swapaxes(rows.reshape(B * n_pages, page, Hkv, dh),
                                 1, 2)[None].astype(pool.k_pages.dtype)
 
@@ -1201,6 +1302,131 @@ class CausalTransformerLM:
             seq = jnp.arange(B)[:, None]
             k, v = k[seq, at], v[seq, at]
         return attn, write(jnp.zeros((B,), jnp.int32), k, v)
+
+    # ------------------------------------------------------------------
+    # The state-space mixer: between the pre-norm and the residual add in
+    # place of q, k, v ... ``wo`` altogether.  ``block`` hands a layer
+    # with ``ssm`` weights to ``mix(h, weights, cache) -> (delta, cache)``.
+    # ------------------------------------------------------------------
+    def _ssm_mixer(self, h, w, state, tail, real=None):
+        """A Mamba-2 mixer over T rows a sequence.  h: [B, T, d], normed;
+        ``state`` [B, H, P, N] float32 and ``tail`` [B, K - 1, C]: what
+        the rows before row 0 left (zeros ahead of position 0); ``real``
+        [B]: how many of the T rows are tokens (None: all), the others
+        advance nothing.  Returns (delta [B, T, d], state, tail)."""
+        from deepspeed_tpu.ops.ssm import causal_conv, ssd_scan, ssm_step
+        c = self.config
+        B, T, _ = h.shape
+        H, P, N, G = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_groups
+        inner, conv = c.ssm_inner, c.ssm_conv_dim
+        with jax.named_scope("ssm_proj"):
+            # the product's float32 sums: z and x B C leave in the
+            # activations' dtype, dt (a head's 64 columns, from which the
+            # decays of hundreds of steps are made) as summed
+            z, xbc, dt = jnp.split(
+                jnp.dot(h, w["w_in"], preferred_element_type=jnp.float32),
+                (inner, inner + conv), axis=-1)
+            z, xbc = z.astype(h.dtype), xbc.astype(h.dtype)
+        with jax.named_scope("ssm_conv"):
+            xbc, tail = causal_conv(xbc, tail, w["conv_w"], w["conv_b"],
+                                    real)
+        with jax.named_scope("ssm_scan"):
+            x, Bm, Cm = jnp.split(xbc, (inner, inner + G * N), axis=-1)
+            x = x.reshape(B, T, H, P)
+            Bm, Cm = Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N)
+            dt = jax.nn.softplus(dt + w["dt_bias"].astype(jnp.float32))
+            if real is not None:    # padding advances nothing
+                dt = jnp.where(jnp.arange(T)[None, :, None]
+                               < real[:, None, None], dt, 0.0)
+            A = -jnp.exp(w["A_log"].astype(jnp.float32))
+            if T == 1:
+                y, state = ssm_step(x[:, 0], dt[:, 0], A, Bm[:, 0],
+                                    Cm[:, 0], w["D"], state)
+                y = y[:, None]
+            else:
+                y, state = ssd_scan(x, dt, A, Bm, Cm, w["D"], state,
+                                    c.ssm_chunk)
+        with jax.named_scope("ssm_proj"):
+            # the gate first, then the norm over the whole inner width
+            y = y.reshape(B, T, inner).astype(jnp.float32) \
+                * jax.nn.silu(z.astype(jnp.float32))
+            y = _norm(y, w["norm"], c.norm_eps, True).astype(h.dtype)
+            return y @ w["w_out"], state, tail
+
+    def mix_ssm_whole(self, h, w, cache):
+        """The state-space mixer over whole sequences from an empty state
+        (the trainer, ``apply``)."""
+        c = self.config
+        B = h.shape[0]
+        delta, _, _ = self._ssm_mixer(
+            h, w, jnp.zeros((B, c.ssm_heads, c.ssm_head_dim, c.ssm_state),
+                            jnp.float32),
+            jnp.zeros((B, c.ssm_conv - 1, c.ssm_conv_dim), h.dtype))
+        return delta, cache
+
+    def mix_ssm_paged(self, h, w, pool, *, index, lengths, real_lengths,
+                      slots):
+        """A state-space layer on the serving path: its state lives in
+        ``pool`` (``ops/ssm.py StateCache``) at layer ``index`` of the
+        state-space layers' stack, a row a SLOT, read and written in place.
+
+        ``slots`` [B] (a prefill: the slot of each sequence) starts from
+        zeros at ``lengths`` 0 whatever the slot held, from the slot's
+        state otherwise (a later piece of a long prompt), advances it by
+        the first ``real_lengths`` rows and leaves there the last K - 1
+        REAL inputs of the convolution.  ``slots`` None is a decode
+        dispatch: row b is slot b, and a row the dispatch does not serve
+        (``lengths`` 0: idle, or part-way through its prompt) keeps its
+        state bit for bit."""
+        state_pool, conv_pool = pool
+        B, c = h.shape[0], self.config
+        rows = (B, c.ssm_conv - 1, c.ssm_conv_dim)
+        scan, conv = (functools.partial(jax.named_scope, name)
+                      for name in ("ssm_scan", "ssm_conv"))
+        if slots is None:
+            with scan():
+                state = jax.lax.dynamic_index_in_dim(state_pool, index, 0,
+                                                     False)
+            with conv():
+                tail = jax.lax.dynamic_index_in_dim(conv_pool, index, 0,
+                                                    False)
+            delta, new_state, new_tail = self._ssm_mixer(
+                h, w, state, tail.reshape(rows))
+            live = lengths > 0
+            with scan():
+                state_pool = jax.lax.dynamic_update_index_in_dim(
+                    state_pool, jnp.where(live[:, None, None, None],
+                                          new_state, state), index, 0)
+            with conv():
+                conv_pool = jax.lax.dynamic_update_index_in_dim(
+                    conv_pool, jnp.where(
+                        live[:, None], new_tail.reshape(B, -1),
+                        tail).astype(conv_pool.dtype), index, 0)
+            return delta, type(pool)(state_pool, conv_pool)
+        fresh = lengths == 0
+        with scan():    # one sequence a prefill dispatch
+            state = jnp.where(fresh[:, None, None, None], 0.0, jnp.stack([
+                jax.lax.dynamic_slice(
+                    state_pool, (index, slots[b], 0, 0, 0),
+                    (1, 1) + state_pool.shape[2:])[0, 0]
+                for b in range(B)]))
+        with conv():
+            tail = jnp.where(fresh[:, None], 0, jnp.stack([
+                jax.lax.dynamic_slice(
+                    conv_pool, (index, slots[b], 0),
+                    (1, 1, conv_pool.shape[2]))[0, 0] for b in range(B)]))
+        delta, state, tail = self._ssm_mixer(h, w, state, tail.reshape(rows),
+                                             real_lengths)
+        tail = tail.reshape(B, -1).astype(conv_pool.dtype)
+        for b in range(B):
+            with scan():
+                state_pool = jax.lax.dynamic_update_slice(
+                    state_pool, state[b][None, None],
+                    (index, slots[b], 0, 0, 0))
+            with conv():
+                conv_pool = jax.lax.dynamic_update_slice(
+                    conv_pool, tail[b][None, None], (index, slots[b], 0))
+        return delta, type(pool)(state_pool, conv_pool)
 
     def _latent_fresh(self, q, k, idx, layer, positions=None, counts=None,
                       context=None, impl=None, interpret=False):
@@ -1508,7 +1734,9 @@ class CausalTransformerLM:
         all a forward chooses; ``cache`` goes into it and comes out.
         ``counts``: the serving dispatch's :class:`ServeCounts`, which the
         expert layer adds to.  ``rotary`` (static): whether this layer's q
-        and k turn (``config.layer_rotary``)."""
+        and k turn (``config.layer_rotary``).  A layer with ``ssm``
+        weights has a state-space mixer in attention's place, and its
+        ``mix`` is ``mix(h, weights, cache) -> (delta, cache)``."""
         c = self.config
         if c.parallel_block:
             # GPT-J / parallel-residual NeoX: both sub-blocks read the
@@ -1526,8 +1754,11 @@ class CausalTransformerLM:
                 mlp = mlp * c.residual_scale
             return x + attn + mlp, cache, aux
         h = _pre_norm(x, layer, "attn_norm", c)
-        delta, cache = self._attn_delta(h, layer, positions, mix, cache,
-                                        rotary)
+        if "ssm" in layer:      # a state-space mixer in attention's place
+            delta, cache = mix(h, layer["ssm"], cache)
+        else:
+            delta, cache = self._attn_delta(h, layer, positions, mix, cache,
+                                            rotary)
         x = x + self._sandwich(delta, layer, "attn_post_norm")
         h = _pre_norm(x, layer, "mlp_norm", c)
         delta, aux = self._mlp_delta(h, layer, rng=rng, train=train,
@@ -1540,7 +1771,8 @@ class CausalTransformerLM:
         scans and ``stream_layer`` / ``runtime/pipe`` call.  ``window``
         (static; None: whatever ``layer["attn_window"]`` holds): the
         layer's sliding window, 0 for a full-attention layer."""
-        mix = self.mix_latent_whole if self.config.is_latent \
+        mix = self.mix_ssm_whole if "ssm" in layer \
+            else self.mix_latent_whole if self.config.is_latent \
             else self.mix_full
         if window is not None:
             layer = dict(layer, attn_window=int(window))
@@ -1716,10 +1948,11 @@ class CausalTransformerLM:
         the decode forward stays a single scan.  (MoE models use a list of
         caches matching their per-layer params list.)"""
         c = self.config
-        if c.is_latent:
+        if c.is_latent or c.has_ssm:
             raise NotImplementedError(
-                "latent attention has no dense KVCache path: serve it "
-                "through the paged pools (init_paged_caches)")
+                "latent attention and state-space layers have no dense "
+                "KVCache path: serve them through the paged pools "
+                "(init_paged_caches)")
         if c.is_moe:
             return [init_cache(batch, max_seq, c.kv_heads, c.head_dim, dtype)
                     for _ in range(c.n_layers)]
@@ -1775,16 +2008,21 @@ class CausalTransformerLM:
     # paged KV-cache path (continuous-batching serving engine)
     # ------------------------------------------------------------------
     def init_paged_caches(self, num_pages, page_size, dtype=jnp.bfloat16,
-                          ring_slots=0):
+                          ring_slots=0, state_slots=0):
         """Stacked per-layer page pools: leaves [L, P, Hkv, page, D] — one
         scan for homogeneous stacks; MoE / heterogeneous models index the
         same pools per layer in a static loop.  A model with
         sliding-window layers gets a ``WindowedKVCache``: that stack for
         its full-attention layers alone, and for its window layers one of
         ``ring_slots`` rings of ``ring_pages(window, page_size)`` pages
-        (and a scratch page), whatever ``num_pages``."""
+        (and a scratch page), whatever ``num_pages``.  A model with
+        state-space layers gets a ``HybridKVCache``: the stack for its
+        attention layers alone, and for the others ``state_slots`` rows of
+        recurrent state (float32) and of the convolution's last inputs
+        (``dtype``), a row a slot."""
         from deepspeed_tpu.ops.paged_attention import (PagedKVCache,
                                                        WindowedKVCache,
+                                                       paged_pool_shape,
                                                        ring_pages)
         c = self.config
         assert not c.use_alibi, \
@@ -1798,13 +2036,27 @@ class CausalTransformerLM:
             ring = ring_slots * ring_pages(c.attn_window, page_size) + 1
 
             def stack(layers, pages):
-                shape = (layers, pages, c.kv_heads, page_size, c.head_dim)
+                shape = paged_pool_shape(layers, pages, c.kv_heads,
+                                         page_size, c.head_dim)
                 return PagedKVCache(jnp.zeros(shape, dtype),
                                     jnp.zeros(shape, dtype))
 
             return WindowedKVCache(
                 full=stack(c.n_layers - n_window, num_pages),
                 ring=stack(n_window, ring))
+        if c.has_ssm:
+            from deepspeed_tpu.ops.ssm import HybridKVCache, init_state_cache
+            assert state_slots > 0, \
+                "a state-space model's pools need state_slots"
+            n_ssm = sum(c.ssm_pattern)
+            shape = paged_pool_shape(c.n_layers - n_ssm, num_pages,
+                                     c.kv_heads, page_size, c.head_dim)
+            return HybridKVCache(
+                full=PagedKVCache(jnp.zeros(shape, dtype),
+                                  jnp.zeros(shape, dtype)),
+                ssm=init_state_cache(
+                    n_ssm, state_slots, c.ssm_heads, c.ssm_head_dim,
+                    c.ssm_state, c.ssm_conv - 1, c.ssm_conv_dim, dtype))
         if c.is_latent:
             # one entry a token, [c_kv | k_rope], and the indexer's key:
             # two pools of unlike widths over the same pages
@@ -1815,7 +2067,8 @@ class CausalTransformerLM:
                 c.index_head_dim if c.index_topk else 0, dtype)
         # each stack made in place: a broadcast of one layer's pool and a
         # copy of it held four stacks at once, the process's HBM peak
-        shape = (c.n_layers, num_pages, c.kv_heads, page_size, c.head_dim)
+        shape = paged_pool_shape(c.n_layers, num_pages, c.kv_heads,
+                                 page_size, c.head_dim)
         return PagedKVCache(k_pages=jnp.zeros(shape, dtype),
                             v_pages=jnp.zeros(shape, dtype))
 
@@ -1823,7 +2076,7 @@ class CausalTransformerLM:
                                lengths, *, attn_backend=None,
                                attn_interpret=False, real_lengths=None,
                                head_rows=None, expert_backend=None,
-                               latent_backend=None):
+                               latent_backend=None, state_slots=None):
         """Forward over paged KV caches: appends the T new tokens of every
         sequence at ``lengths`` (tables must already map the pages) and
         attends over each sequence's ragged prefix.  Returns
@@ -1852,7 +2105,12 @@ class CausalTransformerLM:
         dispatch's ``SERVE_COUNTERS`` as one int32 vector, over the real
         rows: the first ``real_lengths`` [B] of each sequence's T (all
         without it; a bucket's padding and a decode batch's idle slots
-        are the engine's to name).
+        are the engine's to name).  A model with state-space layers
+        (``config.has_ssm``) is told a PREFILL's ``state_slots`` [B], the
+        slot whose state each sequence starts from (zeros at ``lengths``
+        0) and leaves advanced by its ``real_lengths`` rows; without
+        ``state_slots`` the dispatch is a decode step, row b is slot b,
+        and a row at ``lengths`` 0 keeps its state (``mix_ssm_paged``).
         """
         from deepspeed_tpu.ops.paged_attention import (paged_read_items,
                                                        resolve_paged_impl,
@@ -1877,7 +2135,7 @@ class CausalTransformerLM:
         else:
             # one backend for the write and the read of the pools
             impl = resolve_paged_impl(attn_backend, c.attn_logit_softcap)
-            full = caches.full if c.attn_window else caches
+            full = caches.full if c.attn_window or c.has_ssm else caches
             if c.attn_window:
                 # the table's last columns are each sequence's ring
                 page = full.k_pages.shape[3]
@@ -1920,28 +2178,36 @@ class CausalTransformerLM:
             # its traced place in its kind's stack
             x, pools = carry
             layer, i = inp
-            if not c.attn_window:
+            if not (c.attn_window or c.has_ssm):
                 mix = functools.partial(mixer, index=i, **paged)
                 x, pools, _ = self.block(x, layer, positions, mix, pools,
                                          train=False, counts=counts)
                 return (x, pools), None
-            window = c.layer_window(at)
-            mix = functools.partial(self.mix_ring, index=i,
-                                    **ring_mix[window]) if window else \
-                functools.partial(mixer, index=i, **paged)
-            kind = "ring" if window else "full"
+            kind, window = kind_of(at), c.layer_window(at)
+            if kind == "ssm":
+                mix = functools.partial(
+                    self.mix_ssm_paged, index=i, lengths=lengths,
+                    real_lengths=real_lengths, slots=state_slots)
+            elif kind == "ring":
+                mix = functools.partial(self.mix_ring, index=i,
+                                        **ring_mix[window])
+            else:
+                mix = functools.partial(mixer, index=i, **paged)
             x, pool, _ = self.block(x, layer, positions, mix,
                                     getattr(pools, kind), train=False,
                                     counts=counts,
                                     rotary=c.layer_rotary(at))
             return (x, pools._replace(**{kind: pool})), None
 
+        def kind_of(i):
+            """Which of the dispatch's pools layer ``i`` keeps its cache
+            in: its kind of mixer."""
+            return "ssm" if c.layer_ssm(i) else \
+                "ring" if c.layer_window(i) else "full"
+
         def place(i):
             """Layer ``i``'s place in the stack of its kind."""
-            if not c.attn_window:
-                return i
-            return sum(1 for j in range(i)
-                       if bool(c.layer_window(j)) == bool(c.layer_window(i)))
+            return sum(1 for j in range(i) if kind_of(j) == kind_of(i))
 
         if isinstance(params["layers"], (list, tuple)):
             # MoE / heterogeneous stack: static per-layer loop (expert
@@ -1956,8 +2222,7 @@ class CausalTransformerLM:
                 # layer's place in its stack a traced step on from the
                 # first period's
                 lead, period = c.leading_layers, c.layer_period
-                kinds = [bool(c.layer_window(lead + j))
-                         for j in range(period)]
+                kinds = [kind_of(lead + j) for j in range(period)]
                 stride = [kinds.count(kind) for kind in kinds]
 
                 # the experts' weights stay stacked, out of the scanned

@@ -187,13 +187,14 @@ class _OpenSpan:
 OP_PHASES = ("fwd", "bwd", "remat", "loss_head", "optimizer", "grad_reduce",
              "other")
 
-# what a latent-attention / dropless-expert model, and one whose layers are
-# sliding-window or full attention by a pattern, name inside ``attn`` and
+# what a latent-attention / dropless-expert model, one whose layers are
+# sliding-window or full attention by a pattern, and one with state-space
+# layers (``ssm_*``: in attention's place) name inside ``attn`` and
 # ``mlp`` (models/transformer.py): ``phase_of`` gives the innermost of
 # these where an instruction has one, in any program
 SERVE_SCOPES = ("select", "latent_attn", "router", "experts",
                 "shared_expert", "attn_window", "attn_full", "attn_gate",
-                "latent_ctx")
+                "latent_ctx", "ssm_proj", "ssm_conv", "ssm_scan")
 
 _MODEL_SCOPES = frozenset(("embed", "norm", "attn", "mlp"))
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
@@ -352,14 +353,25 @@ class CompiledSite:
 
 
 # site -> CompiledSite, newest wins; weak, so a dropped engine is freed
+# (a trainer's step closes over its engine and its state)
 _sites = weakref.WeakValueDictionary()
+# ... and the sites whose table has to OUTLIVE their owner (``keep``): a
+# reader asks for the serving programs' scopes after the run that made the
+# engine has handed it back, and the engine, a cycle of references, goes
+# whenever the collector next runs: the shares of device time by scope
+# came and went by the run.  Their closures hold the model, no weights
+_kept = {}
 _registered = itertools.count()
 
 
-def register_compiled(fn, site, mesh=None):
+def register_compiled(fn, site, mesh=None, keep=False):
     """Wrap jitted ``fn`` as the program of ``site``; ``mesh`` is the
-    context its owner calls it in, if any."""
+    context its owner calls it in, if any.  ``keep``: the site stays
+    readable after its owner is dropped, until a newer one of its name
+    (for an ``fn`` whose closure holds no engine and no arrays)."""
     wrapped = _sites[site] = CompiledSite(fn, site, mesh)
+    if keep:
+        _kept[site] = wrapped
     wrapped.order = next(_registered)
     return wrapped
 
@@ -376,7 +388,7 @@ def op_scopes(site, arg_shapes=None):
     argument 1 had that shape) picks the program of that call, the first
     call's without it.  Parsed from the compiled text once, lazily: call
     it outside any timed window."""
-    found = [entry for name, entry in list(_sites.items())
+    found = [entry for name, entry in [*_kept.items(), *_sites.items()]
              if name == site or name.startswith(site + ":")]
     if not found:
         return {}

@@ -17,6 +17,16 @@ A serving dispatch holds every layer's pool stacked,
 [L, num_pages, Hkv, page_size, D], and passes a layer index
 (``write_paged``, ``paged_decode_attention(layer=)``).
 
+Heads narrower than the chip's 128 lanes share a pool row
+(``kv_lane_pack``): a pool whose minor dimension is under 128 is re-laid
+by the TPU compiler on its way into and out of every kernel call, the
+whole pool a dispatch.  So the pools of a model with ``D`` = 64 are
+[L, P, Hkv / 2, page, 128], two adjacent key/value heads a row;
+``write_paged`` and ``paged_decode_attention`` see it from the pool's
+shape and pack the rows and spread the queries themselves (exact: a
+query meets the other head's lanes with zeros), callers hand them heads
+of ``D`` as ever.
+
 A model with sliding-window layers holds TWO such stacks
 (``WindowedKVCache``): the full-attention layers' pool under the growing
 block tables above, and the window layers' pool under a RING a sequence,
@@ -58,6 +68,53 @@ class WindowedKVCache(NamedTuple):
     under each slot's ring table)."""
     full: PagedKVCache
     ring: PagedKVCache
+
+
+LANES = 128     # the minor dimension the TPU tiles without padding
+
+
+def kv_lane_pack(kv_heads: int, head_dim: int) -> int:
+    """How many adjacent key/value heads share one row of the page pools:
+    as many as fill the chip's 128 lanes, where the heads divide into
+    that; 1 (no packing) for heads of 128 and wider, and for every model
+    whose heads do not fit."""
+    pack = LANES // head_dim if head_dim < LANES and LANES % head_dim == 0 \
+        else 1
+    return pack if kv_heads % pack == 0 else 1
+
+
+def paged_pool_shape(layers, num_pages, kv_heads, page_size, head_dim):
+    """The shape of one stacked pool (K or V) of ``layers`` layers."""
+    pack = kv_lane_pack(kv_heads, head_dim)
+    return (layers, num_pages, kv_heads // pack, page_size, head_dim * pack)
+
+
+def _pack_rows(pool, rows):
+    """Key or value rows [B, T, Hkv, D] as the pool holds them: ``pack``
+    adjacent heads a row (a reshape)."""
+    if pool.shape[-1] == rows.shape[-1]:
+        return rows
+    B, T = rows.shape[:2]
+    return rows.reshape(B, T, pool.shape[-3], pool.shape[-1])
+
+
+def _spread_queries(q, kv_heads, pack):
+    """Queries [B, T, H, D] against packed rows: each in the lanes of its
+    own key/value head, zeros in the others' -> [B, T, H, pack x D].  The
+    ``pack x group`` query heads of a packed row stay adjacent."""
+    B, T, H, D = q.shape
+    group = H // kv_heads
+    q = q.reshape(B, T, kv_heads // pack, pack, group, 1, D) \
+        * jnp.eye(pack, dtype=q.dtype)[:, None, :, None]
+    return q.reshape(B, T, H, pack * D)
+
+
+def _gather_outputs(out, kv_heads, pack):
+    """The inverse on the output side: each query head's own lanes."""
+    B, T, H, wide = out.shape
+    group = H // kv_heads
+    out = out.reshape(B, T, kv_heads // pack, pack, group, pack, wide // pack)
+    return jnp.einsum("btjsgsd->btjsgd", out).reshape(B, T, H, wide // pack)
 
 
 def ring_pages(window: int, page_size: int) -> int:
@@ -122,6 +179,8 @@ def write_paged(cache: PagedKVCache, layer, block_tables, lengths, k_new,
     the whole pool between the two (docs/serving.md), so the write follows
     the read's backend.  ``ring`` (static): ``block_tables`` is a ring of
     that many columns and the rows wrap around it."""
+    k_new, v_new = (_pack_rows(cache.k_pages, rows)
+                    for rows in (k_new, v_new))
     if resolve_paged_impl(impl) == "pallas":
         from deepspeed_tpu.ops.pallas.ragged_paged_attention import \
             paged_kv_write
@@ -149,6 +208,8 @@ def paged_read_items(q_shape, cache: PagedKVCache, block_tables, lengths,
     if resolve_paged_impl(impl) != "pallas":
         return None
     from deepspeed_tpu.ops.pallas.ragged_paged_attention import rect_item_map
+    # against packed rows the queries are as wide as a row
+    q_shape = tuple(q_shape[:3]) + (cache.k_pages.shape[-1],)
     return rect_item_map(q_shape, cache.k_pages, block_tables, lengths,
                          window=window, ring=ring)
 
@@ -179,6 +240,17 @@ def paged_decode_attention(q, cache: PagedKVCache, block_tables, lengths,
     the call may bring at most ``ring x page - window + 1`` rows (a row
     written ``ring x page`` positions after another takes its place, and
     the call's first query still attends ``window - 1`` keys back)."""
+    pack = cache.k_pages.shape[-1] // q.shape[-1]
+    if pack > 1:    # ``pack`` key/value heads a pool row (kv_lane_pack)
+        kv_heads = cache.k_pages.shape[-3] * pack
+        if softmax_scale is None:
+            softmax_scale = 1.0 / math.sqrt(q.shape[-1])
+        out = paged_decode_attention(
+            _spread_queries(q, kv_heads, pack), cache, block_tables, lengths,
+            softmax_scale=softmax_scale, impl=impl, interpret=interpret,
+            logit_softcap=logit_softcap, layer=layer, items=items,
+            window=window, ring=ring)
+        return _gather_outputs(out, kv_heads, pack)
     if ring:
         page_size = cache.k_pages.shape[-2]
         assert window and ring == block_tables.shape[1] and \

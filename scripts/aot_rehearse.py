@@ -3,7 +3,7 @@
 the engine's OWN jitted train step and serving step, at ``chip_smoke.py``'s
 sizes, for a TPU v5e that is described and not attached.
 
-    python scripts/aot_rehearse.py [serve] [train1] [train4]
+    python scripts/aot_rehearse.py [serve] [train1] [train4] [cell:<workload>]
 
 Run it before a chip call that a change to those steps puts at stake — it
 needs no chip, and raises what the chip's compiler would raise (a program
@@ -152,6 +152,57 @@ def serve():
         report(f"serve llama-1B {name}", compiled, t0)
 
 
+def cell(workload):
+    """A serving cell of the chip benchmark (``BENCHMARK.json``): its
+    decode program and its largest and smallest prefill bucket at the
+    cell's own sizes, as ``ServingEngine`` dispatches them (a model that
+    counts its dispatches or keeps per-slot state is told a prefill's real
+    rows and slot, as ``_run_step`` tells it)."""
+    from chipbench import cells, sut
+    loaded = cells.load_cell(workload)
+    cfg, mix = loaded.config, loaded.mix
+    chip = SingleDeviceSharding(TOPOLOGY.devices[0])
+    model = sut.build_model(loaded)
+    params = jax.tree_util.tree_map(
+        lambda x: shaped(x, chip), jax.eval_shape(
+            lambda: model.init(jax.random.key(0),
+                               sut.DTYPES[cfg["serve"]["dtype"]])))
+    # the engine makes its pools with jnp.zeros: hand it their shapes
+    pools = model.init_paged_caches
+    model.init_paged_caches = lambda *a, **k: jax.eval_shape(
+        lambda: pools(*a, **k))
+    batch = int(mix["max_batch"])
+    engine = ServingEngine(model, params, max_batch=batch,
+                           **cfg["serve"]["engine"])
+    caches = jax.tree_util.tree_map(lambda x: shaped(x, chip), engine.caches)
+    pool = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(engine.caches))
+    print(f"{workload}: attention {engine.attention_impl}, pools {pool} "
+          f"bytes, state a slot {engine.state_slot_bytes} bytes")
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    width = engine.tables.shape[1]
+    told = engine._counted + 2 * engine._stateful
+    lengths = mix["prompt_tokens"]
+    buckets = sorted({engine.scheduler.prefill_pieces(int(n))[0]
+                      for n in (lengths["min"], lengths["max"])})
+    for name, tokens in [("decode", 1)] + [(f"prefill {n}", n)
+                                           for n in buckets]:
+        t0 = time.time()
+        if tokens == 1:
+            compiled = engine._step_fn.lower(
+                params, ints(batch, 1), caches, ints(batch, width),
+                ints(batch), ints(batch, 1),
+                *[ints(batch)] * engine._counted).compile()
+        else:
+            compiled = engine._prefill_fn.lower(
+                params, ints(1, tokens), caches, ints(1, width), ints(1),
+                ints(1, 1), *[ints(1)] * told).compile()
+        report(f"{workload} {name}", compiled, t0)
+
+
 if __name__ == "__main__":
     # a compile for a described chip cannot be read back from the cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -163,3 +214,6 @@ if __name__ == "__main__":
         train(1, 2, 4)
     if "train4" in what:
         train(4, 2, 1)
+    for name in what:
+        if name.startswith("cell:"):
+            cell(name[len("cell:"):])

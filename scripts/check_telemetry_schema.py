@@ -210,7 +210,7 @@ SERVE_COUNTERS = ("selected", "context_keys", "expert_pairs",
                   "expert_load_max", "expert_rows")
 SERVE_SCOPES = ("select", "latent_attn", "router", "experts",
                 "shared_expert", "attn_window", "attn_full", "attn_gate",
-                "latent_ctx")
+                "latent_ctx", "ssm_proj", "ssm_conv", "ssm_scan")
 # FROZEN: what a model with sliding-window layers adds to the same
 # dispatches and spans, reckoned on the host from the lengths
 # (byte-identical to ``deepspeed_tpu.inference.serving.WINDOW_COUNTS``;
@@ -235,6 +235,13 @@ CHUNK_COUNTS = ("ctx_entries", "chunk")
 # ``latent_attention_prefill`` kernel of a model WITHOUT a selection, or
 # the XLA walk; its prefill dispatches alone)
 DISPATCH_IMPLS = ("kv_write", "experts", "latent")
+# FROZEN: what a model with state-space layers adds to each prefill and
+# decode dispatch and its ``serve/step`` span, from the host
+# (byte-identical to ``deepspeed_tpu.inference.serving.STATE_COUNTS``):
+# ``state_slots``, the rows whose recurrent state the dispatch advanced,
+# and ``state_bytes``, the bytes of state and of the convolution's last
+# inputs it had to read and write for them, all such layers
+STATE_COUNTS = ("state_slots", "state_bytes")
 
 # FROZEN vocabulary of serve-kind event names — must stay byte-identical
 # to ``deepspeed_tpu.inference.robustness.SERVE_EVENTS`` (the tier-1 test
@@ -264,6 +271,11 @@ SERVE_EVENTS = (
     # window / accepted / rejected)
     "serve/sched", "serve/prefill_chunk",
     "serve/spec_draft", "serve/spec_verify",
+    # the once-per-engine record of a model with state-space layers
+    # ("serve/state": layers / slot_bytes / dtype / conv_dtype, the
+    # recurrent state it keeps a slot beside the pages, and redo, what a
+    # dropped decode row costs: "prefill_from_zero")
+    "serve/state",
     # per-request lifecycle trace (RequestTracer): one event per state
     # transition, each carrying req_id plus the derived latencies so a
     # request's full history is reconstructible from the JSONL stream

@@ -666,3 +666,94 @@ def test_dropless_expert_layer_backward(topo):
     assert "grouped_expert_glu_dx" in text and "grouped_expert_glu_dw" in text
     # the combine's and the row gather's transposes are gathers too
     assert "scatter(" not in text
+
+
+HYBRID_DISPATCHES = {"decode_b64": (64, 1), "prefill_1024": (1, 1024)}
+HYBRID_SIZES = dict(pages=1025, slots=64, layers=20)
+
+
+@pytest.fixture(scope="module")
+def hybrid_dispatch(topo):
+    """Each of ``HYBRID_DISPATCHES`` of a model with state-space layers
+    (granite-4.0-h-micro's widths, two of its four periods of ten layers,
+    a vocabulary of 1,024), as the engine dispatches it: a prefill is told
+    its real rows and its slot, a decode step neither."""
+    import functools
+    import json
+    import os
+
+    from chipbench.families import granitemoehybrid
+    with open(os.path.join(os.path.dirname(granitemoehybrid.__file__), "..",
+                           "configs", "granite-4.0-h-micro.json")) as f:
+        cfg = json.load(f)
+    layers = HYBRID_SIZES["layers"]
+    cfg.update(num_hidden_layers=layers,
+               layer_types=cfg["layer_types"][:layers], vocab_size=1024)
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = CausalTransformerLM(TransformerConfig(
+        **granitemoehybrid.transformer_kwargs(cfg)))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _on(chip, x.shape, x.dtype), tree)
+
+    def ints(*shape):
+        return _on(chip, shape, jnp.int32)
+
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.bfloat16)))
+    caches = on_chip(jax.eval_shape(lambda: model.init_paged_caches(
+        HYBRID_SIZES["pages"], 128, state_slots=HYBRID_SIZES["slots"])))
+
+    def serve(params, ids, caches, tables, lengths, *told):
+        return model.apply_with_paged_cache(
+            params, ids, caches, tables, lengths, attn_backend="pallas",
+            **dict(zip(("head_rows", "real_lengths", "state_slots"), told)))
+
+    @functools.lru_cache(maxsize=None)
+    def compiled(name):
+        batch, tokens = HYBRID_DISPATCHES[name]
+        return jax.jit(serve, donate_argnums=(2,)).lower(
+            params, ints(batch, tokens), caches, ints(batch, 17),
+            ints(batch), *([ints(batch, 1), ints(batch), ints(batch)]
+                           if tokens > 1 else [])).compile()
+
+    return compiled
+
+
+@pytest.mark.parametrize("name", HYBRID_DISPATCHES)
+def test_hybrid_dispatch_copies_neither_the_pages_nor_the_state(
+        hybrid_dispatch, name):
+    """The guard of PR 29's rule for a model with state-space layers: no
+    dispatch slices, re-lays or copies the attention layers' page stack
+    (heads of 64 packed two a row of 128 lanes: at [.., 8, 128, 64] the
+    compiler re-laid both stacks on the way into and out of every dispatch,
+    four copies of a pool), the recurrent state (float32, a row a slot) or
+    the convolution's last inputs (flat: as [layers, 3, slots, 4352] the
+    prefill re-laid it nine times); the state is read and written in place
+    by the layer loop's fusions."""
+    compiled = hybrid_dispatch(name)
+    tokens = HYBRID_DISPATCHES[name][1]
+    pages, slots = HYBRID_SIZES["pages"], HYBRID_SIZES["slots"]
+    text = compiled.as_text()
+    found = {op for _, op in _pool_shaped(text, None, [
+        f"bf16[2,{pages},4,128,128]"])}
+    assert found and found <= IN_PLACE_OPS, found
+    assert f"bf16[2,{pages},8,128,64]" not in text
+    # the state is read and written in place by the loop's fusions (a
+    # dynamic-update-slice inside each); the conv's tails, a pool of 30 MB
+    # here, may be prefetched whole (slice-start, copy-start: no re-layout)
+    state = {op for _, op in _pool_shaped(text, None, [
+        f"f32[18,{slots},64,64,128]"])}
+    assert state and state <= IN_PLACE_OPS | {
+        "fusion", "dynamic-update-slice"}, state
+    tails = {op for _, op in _pool_shaped(text, None, [
+        f"bf16[18,{slots},13056]"])}
+    assert tails and "copy" not in tails, tails
+    assert f"bf16[18,3,{slots},4352]" not in text
+    assert "paged_kv_write" in text and " while(" in text
+    assert ("ragged_paged_attention_decode" if tokens == 1
+            else "ragged_paged_attention_prefill") in text
+    one_layer_state = slots * 64 * 64 * 128 * 4         # 33.5 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        one_layer_state if tokens == 1 else 1 << 28)
