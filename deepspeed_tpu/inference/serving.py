@@ -98,9 +98,11 @@ CHUNK_COUNTS = ("ctx_entries", "chunk")
 # what a dispatch's record says of what it compiled to, each "pallas" or
 # "jnp": the write of the page pools (every dispatch), a dropless expert
 # layer's grouped product (every dispatch of such a model), a latent
-# model's prefill over the pool (its prefill dispatches)
+# model's prefill over the pool (its prefill dispatches), a state-space
+# layer's one-row recurrence on the state pool (the decode dispatches of a
+# model with such layers)
 # (scripts/check_telemetry_schema.py DISPATCH_IMPLS, frozen)
-DISPATCH_IMPLS = ("kv_write", "experts", "latent")
+DISPATCH_IMPLS = ("kv_write", "experts", "latent", "state")
 # what a model with state-space layers adds to each prefill and decode
 # dispatch of ``last_step`` and to its ``serve/step`` span, from the host
 # (frozen in scripts/check_telemetry_schema.py): ``state_slots``, the rows
@@ -489,6 +491,11 @@ class ServingEngine:
         # softcap stands in its way; None for a model without one
         self.experts_impl = resolve_paged_impl(attn_impl) if getattr(
             self.config, "moe_dropless", False) else None
+        # a state-space layer's recurrence in a decode dispatch runs on the
+        # dispatch's one backend (models/transformer.py mix_ssm_paged: the
+        # ``ssm_decode_update`` kernel or the jnp slice, step and masked
+        # write); None for a model without such layers
+        self.state_impl = self.attention_impl if self._stateful else None
         latent = bool(getattr(self.config, "is_latent", False))
         if latent:
             # the latent pools are written, and read by a decode step and
@@ -1681,6 +1688,9 @@ class ServingEngine:
         if self.latent_impl and phase == "prefill" and config is None:
             # what the prefill's read of the latent pool compiled to
             record["latent"] = self.latent_impl
+        if self.state_impl and phase == "decode" and config is None:
+            # what the state-space layers' recurrence compiled to
+            record["state"] = self.state_impl
         self._report["dispatches"].append(record)
         if fn in (self._prefill_fn, self._step_fn):
             # the two serving programs: their logits stay on the device

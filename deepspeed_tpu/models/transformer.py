@@ -1313,7 +1313,10 @@ class CausalTransformerLM:
         ``state`` [B, H, P, N] float32 and ``tail`` [B, K - 1, C]: what
         the rows before row 0 left (zeros ahead of position 0); ``real``
         [B]: how many of the T rows are tokens (None: all), the others
-        advance nothing.  Returns (delta [B, T, d], state, tail)."""
+        advance nothing.  Returns (delta [B, T, d], state, tail).  A
+        decode dispatch (T = 1) hands as ``state`` the recurrence on the
+        state where it lies, ``state(x, dt, A, B, C, D) -> (y, pool)``,
+        and gets the pool back in the state's place."""
         from deepspeed_tpu.ops.ssm import causal_conv, ssd_scan, ssm_step
         c = self.config
         B, T, _ = h.shape
@@ -1340,8 +1343,9 @@ class CausalTransformerLM:
                                < real[:, None, None], dt, 0.0)
             A = -jnp.exp(w["A_log"].astype(jnp.float32))
             if T == 1:
-                y, state = ssm_step(x[:, 0], dt[:, 0], A, Bm[:, 0],
-                                    Cm[:, 0], w["D"], state)
+                row = (x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], w["D"])
+                y, state = state(*row) if callable(state) \
+                    else ssm_step(*row, state)
                 y = y[:, None]
             else:
                 y, state = ssd_scan(x, dt, A, Bm, Cm, w["D"], state,
@@ -1365,7 +1369,7 @@ class CausalTransformerLM:
         return delta, cache
 
     def mix_ssm_paged(self, h, w, pool, *, index, lengths, real_lengths,
-                      slots):
+                      slots, impl, interpret):
         """A state-space layer on the serving path: its state lives in
         ``pool`` (``ops/ssm.py StateCache``) at layer ``index`` of the
         state-space layers' stack, a row a SLOT, read and written in place.
@@ -1377,26 +1381,28 @@ class CausalTransformerLM:
         REAL inputs of the convolution.  ``slots`` None is a decode
         dispatch: row b is slot b, and a row the dispatch does not serve
         (``lengths`` 0: idle, or part-way through its prompt) keeps its
-        state bit for bit."""
+        state bit for bit.  Its recurrence runs on the stacked pool
+        (``ops/ssm.py state_decode_update``): with ``impl`` "pallas" one
+        kernel a layer, ``ssm_decode_update``, that reads a slot's state
+        once and writes it once (its interpreter with ``interpret``), the
+        dispatch's one backend as ``mix_paged``'s; with "jnp" the slice,
+        ``ssm_step`` and the masked write.  The prefill and the conv
+        tails' masked write are XLA's whatever the backend."""
+        from deepspeed_tpu.ops.ssm import state_decode_update
         state_pool, conv_pool = pool
         B, c = h.shape[0], self.config
         rows = (B, c.ssm_conv - 1, c.ssm_conv_dim)
         scan, conv = (functools.partial(jax.named_scope, name)
                       for name in ("ssm_scan", "ssm_conv"))
         if slots is None:
-            with scan():
-                state = jax.lax.dynamic_index_in_dim(state_pool, index, 0,
-                                                     False)
             with conv():
                 tail = jax.lax.dynamic_index_in_dim(conv_pool, index, 0,
                                                     False)
-            delta, new_state, new_tail = self._ssm_mixer(
-                h, w, state, tail.reshape(rows))
             live = lengths > 0
-            with scan():
-                state_pool = jax.lax.dynamic_update_index_in_dim(
-                    state_pool, jnp.where(live[:, None, None, None],
-                                          new_state, state), index, 0)
+            delta, state_pool, new_tail = self._ssm_mixer(
+                h, w, functools.partial(
+                    state_decode_update, state_pool, index, live=live,
+                    impl=impl, interpret=interpret), tail.reshape(rows))
             with conv():
                 conv_pool = jax.lax.dynamic_update_index_in_dim(
                     conv_pool, jnp.where(
@@ -2187,7 +2193,8 @@ class CausalTransformerLM:
             if kind == "ssm":
                 mix = functools.partial(
                     self.mix_ssm_paged, index=i, lengths=lengths,
-                    real_lengths=real_lengths, slots=state_slots)
+                    real_lengths=real_lengths, slots=state_slots,
+                    impl=impl, interpret=attn_interpret)
             elif kind == "ring":
                 mix = functools.partial(self.mix_ring, index=i,
                                         **ring_mix[window])

@@ -161,6 +161,30 @@ def main(argv=None):
     latent("batch", 3, S // 4, [S // 2, 0, 3 * page + 5],
            [S // 4, S // 8 + 3, 0])
 
+    # the decode step's one-row state-space update, in place in the stacked
+    # state pool: granite-4.0-h-micro's widths at the cell's 64 slots (two
+    # layers of its 36, the layer traced), the same with slots the
+    # dispatch does not serve, and the toy engine's widths in float32
+    from deepspeed_tpu.ops.ssm import state_decode_update
+
+    def state(name, slots, Hs, P, Ns, G, dtype, live=None):
+        live = jnp.ones((slots,), bool) if live is None else live
+        rows.append(_gate(
+            f"ssm_update_{name}",
+            lambda pool, lay, x, dt, a, b, c, d: state_decode_update(
+                pool, lay, x, dt, a, b, c, d, live, impl="pallas",
+                interpret=interp),
+            jnp.zeros((2, slots, Hs, P, Ns), jnp.float32),
+            jnp.asarray(1, jnp.int32), jnp.zeros((slots, Hs, P), dtype),
+            jnp.ones((slots, Hs), jnp.float32), -jnp.ones((Hs,)),
+            jnp.zeros((slots, G, Ns), dtype),
+            jnp.zeros((slots, G, Ns), dtype), jnp.ones((Hs,))))
+
+    state("cell", 64, 64, 64, 128, 1, jnp.bfloat16)
+    state("dead_rows", 64, 64, 64, 128, 1, jnp.bfloat16,
+          live=jnp.arange(64) % 3 > 0)
+    state("toy", 3, 16, 32, 32, 1, jnp.float32)
+
     # sparse attention (fixed local+global layout)
     block, nb = 128, S // 128
     layout = np.zeros((H, nb, nb), np.int64)

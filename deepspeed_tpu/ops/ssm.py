@@ -29,7 +29,12 @@ of the last chunk here).
 with its own small state: the last ``K - 1`` inputs.
 
 On the serving path both states live beside the page pools
-(``HybridKVCache``), a row a SLOT: no allocator, nothing to leak.
+(``HybridKVCache``), a row a SLOT: no allocator, nothing to leak.  A
+decode dispatch advances a layer's rows of the STACKED state pool through
+``state_decode_update``: on a TPU one Pallas kernel
+(``ops/pallas/ssm_update.py``, device line ``ssm_decode_update``) that
+reads each slot's state once and writes it once, in place; elsewhere the
+slice, ``ssm_step`` and the masked write, which stays the oracle.
 """
 
 from typing import Any, NamedTuple
@@ -106,6 +111,34 @@ def ssm_step(x, dt, A, B, C, D, state):
     y = jnp.sum(S * C.astype(f32)[:, :, None, None, :], axis=-1) \
         + D.astype(f32).reshape(G, H // G, 1) * xf
     return y.reshape(b, H, P).astype(x.dtype), S.reshape(b, H, P, N)
+
+
+def state_decode_update(pool, layer, x, dt, A, B, C, D, live, impl="jnp",
+                        interpret=False):
+    """One row a SLOT on the stacked pool: ``ssm_step`` on layer
+    ``layer`` (may be traced) of ``pool`` [L, slots, H, P, N] float32, the
+    other operands as ``ssm_step``'s with a row a slot.  A slot that is
+    not ``live`` [slots] keeps its state bit for bit (its ``y`` is nobody's
+    to read).  ``impl`` "pallas": the aliased kernel
+    (``ops/pallas/ssm_update.py``; its interpreter with ``interpret``),
+    which takes the decay and ``dt x`` as ``ssm_step`` forms them and
+    leaves ``D x`` and the cast here; "jnp": the slice, ``ssm_step`` and
+    the masked write, the oracle.  Returns (y [slots, H, P] in ``x``'s
+    dtype, the pool)."""
+    if impl == "pallas":
+        from deepspeed_tpu.ops.pallas.ssm_update import ssm_decode_update
+        f32 = jnp.float32
+        xf, dt = x.astype(f32), dt.astype(f32)
+        y, pool = ssm_decode_update(
+            pool, layer, live, jnp.exp(dt * A.astype(f32)),
+            dt[..., None] * xf, B.astype(f32), C.astype(f32),
+            interpret=interpret)
+        y = y + D.astype(f32)[:, None] * xf
+        return y.astype(x.dtype), pool
+    state = jax.lax.dynamic_index_in_dim(pool, layer, 0, False)
+    y, new = ssm_step(x, dt, A, B, C, D, state)
+    return y, jax.lax.dynamic_update_index_in_dim(
+        pool, jnp.where(live[:, None, None, None], new, state), layer, 0)
 
 
 def ssd_scan(x, dt, A, B, C, D, state, chunk):

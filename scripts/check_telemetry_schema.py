@@ -233,8 +233,11 @@ CHUNK_COUNTS = ("ctx_entries", "chunk")
 # layer's grouped product; every dispatch of the target model that has
 # one), ``latent`` (a latent-attention model's prefill over its pool: the
 # ``latent_attention_prefill`` kernel of a model WITHOUT a selection, or
-# the XLA walk; its prefill dispatches alone)
-DISPATCH_IMPLS = ("kv_write", "experts", "latent")
+# the XLA walk; its prefill dispatches alone), ``state`` (a state-space
+# layer's one-row recurrence on the state pool: the ``ssm_decode_update``
+# kernel, or the jnp slice, step and masked write; the decode dispatches
+# of a model with such layers)
+DISPATCH_IMPLS = ("kv_write", "experts", "latent", "state")
 # FROZEN: what a model with state-space layers adds to each prefill and
 # decode dispatch and its ``serve/step`` span, from the host
 # (byte-identical to ``deepspeed_tpu.inference.serving.STATE_COUNTS``):

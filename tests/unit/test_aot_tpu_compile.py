@@ -730,8 +730,10 @@ def test_hybrid_dispatch_copies_neither_the_pages_nor_the_state(
     compiler re-laid both stacks on the way into and out of every dispatch,
     four copies of a pool), the recurrent state (float32, a row a slot) or
     the convolution's last inputs (flat: as [layers, 3, slots, 4352] the
-    prefill re-laid it nine times); the state is read and written in place
-    by the layer loop's fusions."""
+    prefill re-laid it nine times).  A decode step advances the state
+    through the aliased ``ssm_decode_update`` kernel alone (PR 48: no
+    fusion holds the pool, so none reads it a second time for ``y``); a
+    prefill reads and writes it in place by the layer loop's fusions."""
     compiled = hybrid_dispatch(name)
     tokens = HYBRID_DISPATCHES[name][1]
     pages, slots = HYBRID_SIZES["pages"], HYBRID_SIZES["slots"]
@@ -740,13 +742,17 @@ def test_hybrid_dispatch_copies_neither_the_pages_nor_the_state(
         f"bf16[2,{pages},4,128,128]"])}
     assert found and found <= IN_PLACE_OPS, found
     assert f"bf16[2,{pages},8,128,64]" not in text
-    # the state is read and written in place by the loop's fusions (a
-    # dynamic-update-slice inside each); the conv's tails, a pool of 30 MB
-    # here, may be prefetched whole (slice-start, copy-start: no re-layout)
+    # a decode step's state goes through the kernel's custom call and
+    # nothing else; a prefill's is read and written in place by the loop's
+    # fusions (a dynamic-update-slice inside each); the conv's tails, a
+    # pool of 30 MB here, may be prefetched whole (slice-start, copy-start:
+    # no re-layout)
     state = {op for _, op in _pool_shaped(text, None, [
         f"f32[18,{slots},64,64,128]"])}
-    assert state and state <= IN_PLACE_OPS | {
-        "fusion", "dynamic-update-slice"}, state
+    assert state and state <= IN_PLACE_OPS | (
+        set() if tokens == 1 else {"fusion", "dynamic-update-slice"}), state
+    assert ("tpu_custom_call" in state) == (tokens == 1)
+    assert ("ssm_decode_update" in text) == (tokens == 1)
     tails = {op for _, op in _pool_shaped(text, None, [
         f"bf16[18,{slots},13056]"])}
     assert tails and "copy" not in tails, tails

@@ -205,8 +205,8 @@ def test_the_chunked_policy_is_rules_one_and_three_together(model, params):
 
 
 # ---- rule 4: a dropped row does not advance the state twice -------------
-def _dropped_row(model, params, redo=True):
-    engine = _engine(model, params)
+def _dropped_row(model, params, redo=True, backend="jnp"):
+    engine = _engine(model, params, serving={"attention_backend": backend})
     if not redo:
         engine._redo_state = lambda slot, req: None
     # the fifth sample of the request is not the device's pick: the row
@@ -233,6 +233,18 @@ def test_a_dropped_and_redone_row_leaves_the_state_right(model, params):
     # the rebuild's rows are no new prompt tokens
     assert sum(r["prompt_tokens"] for r in engine.step_reports()) == 17 + 26
     assert engine.leak_report() == {}
+
+
+def test_a_dropped_row_under_the_state_kernel(model, params):
+    """(e) again with the decode step's recurrence in the
+    ``ssm_decode_update`` kernel (its interpreter): the dropped row
+    advanced the state in place in the pool, and the rebuild from zero
+    puts it right all the same."""
+    engine, errors = _dropped_row(model, params,
+                                  backend="pallas-interpret")
+    assert engine.state_impl == "pallas"
+    assert errors["e"] < TOL and errors["other"] < TOL
+    assert engine.stats["state_redone"] == 1
 
 
 def test_without_the_redo_the_dropped_row_shows(model, params):
@@ -337,6 +349,8 @@ def test_counts_scopes_and_the_event_are_the_frozen_ones(model, params,
     checker = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(checker)
     assert tuple(checker.STATE_COUNTS) == tuple(serving.STATE_COUNTS)
+    assert tuple(checker.DISPATCH_IMPLS) == tuple(serving.DISPATCH_IMPLS)
+    assert checker.DISPATCH_IMPLS[-1] == "state"
     assert "serve/state" in checker.SERVE_EVENTS
     from deepspeed_tpu.monitor.telemetry import Telemetry
     from deepspeed_tpu.runtime.config import TelemetryConfig
@@ -398,6 +412,46 @@ def test_the_kernel_reads_packed_heads_and_agrees(model, params):
     assert max(rows.errors(params, done).values()) < TOL
     decode = engine.last_step["dispatches"][-1]
     assert decode["kernel_grid"] > 0 and decode["kv_write"] == "pallas"
+
+
+def _serve_three(model, params, backend):
+    """Three slots: two requests from the start, a third admitted while
+    they decode, one slot idle at the end; the rows sampled from, the
+    tokens and every decode dispatch's record."""
+    engine = _engine(model, params, serving={"attention_backend": backend})
+    rows = Rows(engine)
+    engine.add_request("m", _prompt(19, 27), max_new_tokens=DECODED)
+    engine.add_request("n", _prompt(20, 13), max_new_tokens=10)
+    for _ in range(4):
+        engine.step()
+    engine.add_request("o", _prompt(21, 41), max_new_tokens=DECODED)
+    done = _run(engine)
+    decodes = [d for r in engine.step_reports() for d in r["dispatches"]
+               if d["phase"] == "decode"]
+    return engine, rows, done, decodes
+
+
+def test_the_state_kernel_serves_what_the_jnp_backend_serves(model, params):
+    """The decode step's recurrence through ``ssm_decode_update`` (its
+    interpreter) against the jnp slice, ``ssm_step`` and masked write: the
+    same tokens, every sampled row's logits to the file's tolerance, and
+    each decode dispatch's record says which of the two it compiled to."""
+    engine, rows, done, decodes = _serve_three(model, params,
+                                               "pallas-interpret")
+    oracle, want, want_done, want_decodes = _serve_three(model, params, "jnp")
+    assert engine.state_impl == "pallas" and oracle.state_impl == "jnp"
+    assert done == want_done
+    for rid in done:
+        got, ref = np.stack(rows.rows[rid]), np.stack(want.rows[rid])
+        assert np.abs(got - ref).max() <= TOL * np.abs(ref).max(), rid
+    assert max(rows.errors(params, done).values()) < TOL
+    assert decodes and {d["state"] for d in decodes} == {"pallas"}
+    assert want_decodes and {d["state"] for d in want_decodes} == {"jnp"}
+    # a decode step served one, two and three of the three slots
+    assert {d["state_slots"] for d in decodes} == {1, 2, 3}
+    prefills = [d for r in engine.step_reports() for d in r["dispatches"]
+                if d["phase"] == "prefill"]
+    assert prefills and all("state" not in d for d in prefills)
 
 
 # ---- the controls: the comparison can see a lost state ------------------

@@ -326,8 +326,9 @@ def test_a_model_without_latent_attention_says_nothing():
         serving={"attention_backend": "pallas-interpret"})
     engine.generate([list(range(1, 20))], max_new_tokens=2)
     dispatches = [d for r in engine.step_reports() for d in r["dispatches"]]
-    assert engine.latent_impl is None and dispatches
-    assert all("latent" not in d and "experts" not in d
+    assert engine.latent_impl is None and engine.state_impl is None
+    assert dispatches
+    assert all("latent" not in d and "experts" not in d and "state" not in d
                and d["kv_write"] == "pallas" for d in dispatches)
 
 
