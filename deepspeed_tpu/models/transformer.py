@@ -124,9 +124,11 @@ class TransformerConfig:
     # ring attention token layout: "zigzag" balances the causal triangle
     # across sp devices (~2x step time at large sp); needs S % (2*sp) == 0
     ring_layout: str = "contiguous"
-    # Pallas flash-attention tile sizes (tunable per chip generation)
-    attn_block_q: int = 512
-    attn_block_k: int = 512
+    # Pallas flash-attention tile sizes: None = picked from each call's
+    # shapes (``ops/pallas/flash_attention.py pick_flash_tiles``); a value
+    # overrides the picker (the autotuner's ``attn_blocks``)
+    attn_block_q: Optional[int] = None
+    attn_block_k: Optional[int] = None
     # training loss: stream logits in chunks of this many tokens under a
     # remat'd scan so the full fp32 [B,S,V] tensor never hits HBM (the
     # logits buffer, not the model states, caps the trainable micro-batch
@@ -472,6 +474,15 @@ class ServeCounts:
 # summed over the expert layers; the engine sums them over a step's
 # micro-batches, the load's maximum kept: ``train/moe/<name>`` gauges)
 TRAIN_COUNTERS = ("expert_pairs", "expert_load_max", "expert_rows")
+
+
+# what the flash kernels of one training step do, reckoned from the shapes
+# when the step is built (``CausalTransformerLM.attention_plan``; static,
+# so the engine sets them once: ``train/attn/<name>`` gauges).  Masked over
+# visited is the share of tiles that pay for a mask, needed over visited
+# the most the kernels' share of their roofline can read
+ATTN_PLAN = ("tiles_visited", "tiles_masked", "pairs_visited",
+             "pairs_needed")
 
 
 def merge_train_counters(a, b):
@@ -2321,6 +2332,36 @@ class CausalTransformerLM:
 
     # ------------------------------------------------------------------
     merge_train_counters = staticmethod(merge_train_counters)
+
+    def attention_plan(self, batch, seq):
+        """``ATTN_PLAN`` of one micro-batch of ``batch`` sequences of
+        ``seq`` tokens: what its flash kernels (forward, dq, dk/dv: a tile
+        of one is a tile of each) visit and mask and how many pairs they
+        compute for how many attended to, summed over heads and layers,
+        from the bounds the kernels' loops run on
+        (``ops/pallas/flash_attention.py flash_plan``).  None where
+        ``mix_full`` does not take the kernel (another ``attn_impl``, the
+        CPU, a score cap, a shape it cannot tile, latent attention)."""
+        from deepspeed_tpu.ops.pallas.flash_attention import (
+            flash_plan, flash_tiles, resolve_tiles)
+        c = self.config
+        flash = c.attn_impl == "pallas" or (
+            c.attn_impl == "auto" and jax.default_backend() != "cpu")
+        blocks = (c.attn_block_q, c.attn_block_k)
+        if not flash or c.is_latent or c.attn_logit_softcap or \
+                not flash_tiles(seq, c.n_heads, c.kv_heads, *blocks):
+            return None
+        tiles = resolve_tiles(seq, c.head_dim, c.n_heads // c.kv_heads, 2,
+                              *blocks)
+        total = dict.fromkeys(ATTN_PLAN, 0)
+        for i in range(c.n_layers):
+            if c.layer_ssm(i):
+                continue
+            plan = flash_plan(seq, *tiles, causal=True,
+                              window=c.layer_window(i))
+            for name in ATTN_PLAN:
+                total[name] += 3 * batch * c.n_heads * plan[name]
+        return total
 
     @property
     def train_counters(self):

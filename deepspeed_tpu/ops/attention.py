@@ -99,17 +99,16 @@ def attention(q, k, v, causal=True, softmax_scale=None, impl="auto",
     reason through :func:`_warn_fallback`, never silently.  A kernel that
     fails to trace raises under every ``impl``.
 
-    ``block_q``/``block_k`` tune the Pallas flash tiles (None = kernel
-    defaults) and MUST be static (they pick the Pallas grid).
+    ``block_q``/``block_k`` override the Pallas flash tiles (None = the
+    kernel picks them from the call's shapes, ``pick_flash_tiles``) and
+    MUST be static (they pick the Pallas grid).
     ``alibi_slopes`` ([H]) and ``window`` (traced scalar, 0/None =
     unlimited) ride the flash kernel's in-kernel bias on the Pallas path
     and a materialized :func:`alibi_window_bias` on the reference path.
     ``interpret`` (static) runs the kernel in the Pallas interpreter (CPU
     CI)."""
-    from deepspeed_tpu.ops.pallas.flash_attention import (
-        DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention, flash_tiles)
-    block_q = block_q or DEFAULT_BLOCK_Q
-    block_k = block_k or DEFAULT_BLOCK_K
+    from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                          flash_tiles)
     mesh = _mesh_to_shard_over()
     batch_ways = head_ways = 1
     if mesh is not None:
@@ -123,7 +122,8 @@ def attention(q, k, v, causal=True, softmax_scale=None, impl="auto",
     elif not flash_tiles(q.shape[1], q.shape[2], k.shape[2],
                          block_q, block_k):
         why_not = (f"q{q.shape} k{k.shape} does not tile "
-                   f"block_q={block_q} block_k={block_k}")
+                   f"block_q={block_q or 'picked'} "
+                   f"block_k={block_k or 'picked'}")
     elif q.shape[0] % batch_ways or k.shape[2] % head_ways:
         why_not = (f"q{q.shape} k{k.shape} does not divide over the mesh "
                    f"({batch_ways} batch x {head_ways} head shards)")

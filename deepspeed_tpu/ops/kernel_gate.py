@@ -97,6 +97,21 @@ def main(argv=None):
     flash_bwd("alibi", causal=True, alibi_slopes=slopes)
     flash_bwd("window", causal=True, window=256)
     flash_bwd("gqa", kk=kg, vv=vg, causal=True)
+    # the shape the mellum training cell runs: 32 heads of 128 on 4 kv
+    # heads (group 8) at 8,192 positions under its window of 1,024 (a
+    # traced scalar there).  Compile only, so the gate can afford it; the
+    # interpreter's smoke keeps the gate's own sequence
+    S8 = S if interp else 8192
+    q8 = jax.ShapeDtypeStruct((1, S8, 32, 128), jnp.bfloat16)
+    k8 = jax.ShapeDtypeStruct((1, S8, 4, 128), jnp.bfloat16)
+    rows.append(_gate(
+        "flash_bwd_gqa8_window_s8192",
+        jax.value_and_grad(
+            lambda q, k, v, w: flash_attention(
+                q, k, v, causal=True, window=w,
+                interpret=interp).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)),
+        q8, k8, k8, jax.ShapeDtypeStruct((), jnp.int32)))
 
     # decode: contiguous + paged caches (serving path)
     qd = jax.random.normal(rng, (B, 1, H, D), jnp.bfloat16)

@@ -6,18 +6,30 @@ softmax/dropout/transform sub-kernels — rebuilt as a tiled online-softmax
 kernel that streams K/V blocks through VMEM into the MXU and never
 materialises the [S, S] score matrix.
 
-Forward: Pallas kernel, grid (batch·heads, q_blocks); K/V for the head stay
-in VMEM (fine to S≈8k at D=128); inner ``fori_loop`` over K blocks carries
-(acc, row-max, row-sum) registers.  Causal blocks beyond the diagonal are
-skipped via the loop bound, the diagonal block is masked with iota.
+Three kernels, one a ``pallas_call``: ``flash_attention_fwd`` and
+``flash_attention_dq`` (grid (batch·heads, q blocks); K/V of the head stay
+in VMEM, an inner loop over K blocks) and ``flash_attention_dkv`` (grid
+(batch·heads, k blocks); Q/dO of the head stay in VMEM, an inner loop over
+q blocks), the backward two from the saved log-sum-exp: O(S) memory, the
+probabilities recomputed a tile at a time.
 
-Backward: custom VJP using the saved log-sum-exp, as two Pallas kernels —
-``_bwd_dq_kernel`` (grid over q blocks; streams K/V) and
-``_bwd_dkv_kernel`` (grid over k blocks; streams Q/dO) — O(S) memory,
-recomputing the probabilities tile-by-tile instead of materialising the
-[B,H,S,S] score matrix.  ``_flash_bwd`` (jnp einsums) is the test oracle
-only: non-tiling shapes never reach the custom VJP, because
-``flash_attention()`` refuses them.
+**What a tile does beside its products** is what the chip (v5e) said
+pays (PERF.md §6, PR 49).  A call visits only the tiles that hold an
+attended pair (:func:`_k_bounds`, :func:`_q_bounds`; the window is a traced
+scalar, so the bounds are computed in the kernel) and masks each with one
+compare against the tile's own iota difference (:func:`_mask`).  Operands
+reach the MXU in the dtype they arrive in (float32 accumulation; ``p`` and
+``ds`` are cast to it for the second products), the softmax scale is folded
+into the ``[block, D]`` operand, never the score tile, and the running max
+starts ABOVE a masked score, so a row with no key yet needs no guard:
+``exp(masked - m)`` is 0.  The forward keeps its running sum a lane
+(``[BQ, 128]``) and folds the lanes once a q block, not once a tile.
+dk/dv work on transposed scores (``k qᵀ``), so both of their accumulating
+products are plain and ``lse`` / ``delta`` ride as lane-dense rows.  Tiles
+come from :func:`pick_flash_tiles` unless the caller names them.
+
+``_flash_bwd`` (jnp einsums) is the test oracle only: non-tiling shapes
+never reach the custom VJP, because ``flash_attention()`` refuses them.
 """
 
 import functools
@@ -25,110 +37,281 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK_Q = 512
-DEFAULT_BLOCK_K = 512
 _NEG = -1e30
+# where the running max starts: above a masked score (so a row whose
+# tiles so far were all masked gives exp(_NEG - _M0) = 0, no guard) and
+# below every real one (so its first real tile rescales by exp(-inf) = 0)
+_M0 = _NEG / 2
+_NT = (((1,), (1,)), ((), ()))      # a bᵀ: contract the minor dim of both
+
+# Mosaic's default scoped VMEM; a call whose blocks and temporaries take
+# more asks for its own limit (the v5e has 128 MiB)
+DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+VMEM_CEILING = 100 * 2 ** 20
+# tile sides the picker takes, the first that divides the sequence
+# (multiples of the 128 lanes; why none is larger: PERF.md §6, PR 49)
+TILE_SIDES = (512, 256, 128)
+# a sequence this short is one tile whatever its length
+WHOLE_TILE = 512
 
 
-def _tile_positions(q_base, k_base, block_q, block_k):
-    qpos = q_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    kpos = k_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return qpos, kpos
+# ----------------------------------------------------------------------
+# Which tiles a call visits.  One arithmetic for the kernels (``xp=jnp``:
+# traced scalars) and the plan (``xp=np``: every block at once).
+# ----------------------------------------------------------------------
+def _div(a, b, xp):
+    """a // b for a >= 0 (the kernels' scalar core divides truncating)."""
+    return jax.lax.div(a, b) if xp is jnp else a // b
 
 
-def _mask_bias(s, qpos, kpos, causal, slope, window):
-    """Shared score-tile transform: ALiBi bias (``slope * kpos`` — the
-    row-constant part cancels in softmax, matching the model's
-    ``_attn_bias``) then causal / sliding-window masking.  ``slope`` and
-    ``window`` are traced scalars (0 disables)."""
-    if slope is not None:
-        s = s + slope * kpos.astype(jnp.float32)
-    allowed = None
+def _k_bounds(qi, block_q, block_k, seq_len, causal, window, xp=jnp):
+    """K blocks ``[lo, hi)`` of q block ``qi``: every block with a key that
+    one of its rows attends to, and no other — with a window the far-past
+    blocks are skipped (true sliding-window FLOPs).  ``window`` <= 0:
+    unlimited."""
+    q0 = qi * block_q
+    hi = seq_len // block_k + 0 * qi
     if causal:
-        allowed = qpos >= kpos
+        hi = xp.minimum(hi, _div(q0 + block_q + block_k - 1, block_k, xp))
+    lo = 0 * qi
     if window is not None:
-        in_win = (qpos - kpos < window) | (window <= 0)
-        allowed = in_win if allowed is None else (allowed & in_win)
-    if allowed is not None:
-        s = jnp.where(allowed, s, _NEG)
-    return s
-
-
-def _k_range(qi, block_q, block_k, seq_len, causal, window):
-    """[lo, hi) K-block range visible to q-block ``qi``; with a window the
-    far-past blocks are skipped (true sliding-window FLOPs)."""
-    num_k_blocks = seq_len // block_k
-    if causal:
-        hi = jax.lax.div((qi + 1) * block_q + block_k - 1, block_k)
-        hi = jnp.minimum(hi, num_k_blocks)
-    else:
-        hi = num_k_blocks
-    lo = 0
-    if window is not None:
-        lo_w = jax.lax.div(qi * block_q - (window - 1), block_k)
-        lo = jnp.where(window > 0, jnp.maximum(0, lo_w), 0)
+        # the first block with a key inside the FIRST row's window
+        lo = xp.where(window > 0,
+                      _div(xp.maximum(q0 - window + 1, 0), block_k, xp), lo)
     return lo, hi
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                block_q, block_k, seq_len):
-    _fwd_impl(q_ref, k_ref, v_ref, None, None, o_ref, lse_ref, scale=scale,
-              causal=causal, block_q=block_q, block_k=block_k,
-              seq_len=seq_len)
+def _q_bounds(ki, block_q, block_k, seq_len, causal, window, xp=jnp):
+    """Q blocks ``[lo, hi)`` of k block ``ki``: :func:`_k_bounds` the other
+    way round."""
+    k0 = ki * block_k
+    hi = seq_len // block_q + 0 * ki
+    lo = _div(k0, block_q, xp) if causal else 0 * ki
+    if window is not None:
+        # the last q block with a row that still sees the block's last key
+        hi = xp.where(window > 0, xp.minimum(
+            hi, _div(k0 + block_k + window - 2, block_q, xp) + 1), hi)
+    return xp.minimum(lo, hi), hi
 
 
-def _fwd_kernel_biased(q_ref, k_ref, v_ref, slope_ref, window_ref, o_ref,
-                       lse_ref, *, scale, causal, block_q, block_k,
-                       seq_len, use_slope=True, use_window=True):
-    _fwd_impl(q_ref, k_ref, v_ref, slope_ref if use_slope else None,
-              window_ref if use_window else None, o_ref, lse_ref,
-              scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-              seq_len=seq_len)
+def attended_pairs(seq_len, causal=True, window=None):
+    """(query, key) pairs one head attends to."""
+    q = np.arange(seq_len, dtype=np.int64)
+    last = q if causal else np.full_like(q, seq_len - 1)
+    first = np.maximum(q - window + 1, 0) if window and window > 0 \
+        else np.zeros_like(q)
+    return int((last - first + 1).sum())
 
 
-def _fwd_impl(q_ref, k_ref, v_ref, slope_ref, window_ref, o_ref, lse_ref,
-              *, scale, causal, block_q, block_k, seq_len):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale          # [BLK_Q, D]
-    d = q.shape[-1]
+def flash_plan(seq_len, block_q, block_k, causal=True, window=None):
+    """What ONE head's kernel does under these tiles, from the bounds its
+    loop runs on (all three kernels visit the same tiles): ``tiles_visited``
+    and, of them, ``tiles_masked`` (those that build a mask: every tile of
+    a causal or windowed call, see the module's text); ``pairs_visited``
+    (scores computed) over ``pairs_needed`` (pairs attended to) is the tile
+    ceiling of the kernel's roofline share.  ``window``: a Python int or
+    None."""
+    block_q, block_k = min(block_q, seq_len), min(block_k, seq_len)
+    w = np.int64(window) if window else None
+    lo, hi = _k_bounds(np.arange(seq_len // block_q, dtype=np.int64),
+                       block_q, block_k, seq_len, causal, w, xp=np)
+    visited = int((hi - lo).sum())
+    return {"tiles_visited": visited,
+            "tiles_masked": visited if causal or w is not None else 0,
+            "pairs_visited": visited * block_q * block_k,
+            "pairs_needed": attended_pairs(seq_len, causal, window)}
 
+
+# ----------------------------------------------------------------------
+# Tiles from what the call can see
+# ----------------------------------------------------------------------
+def _vmem_bytes(seq_len, head_dim, block_q, block_k, itemsize, group=1):
+    """The most any of the three kernels holds in VMEM: two whole-sequence
+    operands and its blocks, two buffers each, and the float32 tiles and
+    accumulators of its loop body (dk/dv is the largest: four score-sized
+    temporaries and float32 results for a grouped call)."""
+    whole = 2 * seq_len * head_dim * itemsize
+    out = 4 if group > 1 else itemsize
+    blocks = max(3 * block_q * head_dim * itemsize,
+                 2 * block_k * head_dim * (itemsize + out))
+    # lse and delta, a row a q block, in whole tiles of 8 sublanes
+    rows = 2 * max(8, seq_len // block_q) * block_q * 4
+    body = 4 * block_q * block_k * 4 + 4 * max(block_q, block_k) \
+        * head_dim * 4
+    return 2 * (whole + blocks + rows) + body
+
+
+def pick_flash_tiles(seq_len, head_dim, group=1, itemsize=2):
+    """``(block_q, block_k)`` for a call, from its shapes: the largest of
+    ``TILE_SIDES`` that divides ``seq_len`` and whose VMEM
+    (:func:`_vmem_bytes`) the chip has.  On the v5e a 512 x 512 tile beat
+    every other at 2,048 and at 8,192 positions, causal and under a window
+    of 1,024 alike: a smaller q tile computes fewer pairs beyond the
+    diagonal or the window but loses more than that a tile, a longer key
+    step computes more of them and gains nothing a pair (PERF.md §6,
+    PR 49) — so a window has no say here.  Raises ``ValueError`` for a
+    sequence no tile divides."""
+    if seq_len <= WHOLE_TILE:
+        return seq_len, seq_len
+    sides = [b for b in TILE_SIDES if seq_len % b == 0]
+    if not sides:
+        raise ValueError(
+            f"flash attention cannot tile a sequence of {seq_len}: past "
+            f"{WHOLE_TILE} positions it must be a multiple of "
+            f"{TILE_SIDES[-1]}")
+    side = next((b for b in sides if _vmem_bytes(
+        seq_len, head_dim, b, b, itemsize, group) <= VMEM_CEILING),
+        sides[-1])
+    return side, side
+
+
+def flash_tiles(seq_len, n_heads, n_kv_heads, block_q=None, block_k=None):
+    """Whether the kernel's grid covers this shape exactly (``None``: the
+    tile :func:`pick_flash_tiles` would take, which divides the sequence
+    if any does)."""
+    if n_heads % n_kv_heads:
+        return False
+    if None in (block_q, block_k) and seq_len > WHOLE_TILE \
+            and seq_len % TILE_SIDES[-1]:
+        return False
+    return all(seq_len % min(b, seq_len) == 0
+               for b in (block_q, block_k) if b)
+
+
+def resolve_tiles(seq_len, head_dim, group, itemsize, block_q, block_k):
+    """The caller's tiles where it names them, the picker's elsewhere."""
+    if block_q is None or block_k is None:
+        picked = pick_flash_tiles(seq_len, head_dim, group, itemsize)
+        block_q = block_q or picked[0]
+        block_k = block_k or picked[1]
+    return min(block_q, seq_len), min(block_k, seq_len)
+
+
+def _vmem_params(seq_len, head_dim, block_q, block_k, itemsize, group):
+    """``pallas_call`` keywords: nothing while the call fits Mosaic's
+    default scoped VMEM (every program of a shorter sequence stays as it
+    was), else a limit of its own."""
+    need = _vmem_bytes(seq_len, head_dim, block_q, block_k, itemsize, group)
+    if need <= DEFAULT_SCOPED_VMEM:
+        return {}
+    return dict(compiler_params=pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(need + need // 4, VMEM_CEILING))))
+
+
+# ----------------------------------------------------------------------
+# The tile
+# ----------------------------------------------------------------------
+def _mask(s, rel, off, causal, window):
+    """Mask one tile: ``rel + off`` is ``qpos - kpos`` (``rel`` the tile's
+    own iota difference, made once a grid step; ``off`` a scalar)."""
+    allowed = None
+    if causal:
+        allowed = rel >= -off
+    if window is not None:
+        in_win = (rel < window - off) | (window <= 0)
+        allowed = in_win if allowed is None else (allowed & in_win)
+    return s if allowed is None else jnp.where(allowed, s, _NEG)
+
+
+def _alibi(slope, start, n, axis):
+    """``slope * kpos`` of ``n`` keys from ``start`` on, along ``axis`` of a
+    tile (a row for scores, a column for transposed ones); None without a
+    slope."""
+    if slope is None:
+        return None
+    shape = (1, n) if axis else (n, 1)
+    kpos = start + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    return slope * kpos.astype(jnp.float32)
+
+
+def _scores(a, b, bias, rel, off, causal, window):
+    """The masked float32 score tile ``a bᵀ`` (+ ``bias``)."""
+    s = jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+    if bias is not None:
+        s = s + bias
+    return _mask(s, rel, off, causal, window)
+
+
+def _scalars(refs, use_slope, use_window):
+    """(the operand refs, slope, window) of a kernel whose bias scalars,
+    where the call has any, follow its operands."""
+    if not (use_slope or use_window):
+        return refs, None, None
+    *refs, slope_ref, window_ref = refs
     bh = pl.program_id(0)
-    slope = slope_ref[bh, 0] if slope_ref is not None else None
-    window = window_ref[bh, 0] if window_ref is not None else None
-    lo, hi = _k_range(qi, block_q, block_k, seq_len, causal, window)
+    return (refs, slope_ref[bh, 0] if use_slope else None,
+            window_ref[bh, 0] if use_window else None)
 
-    def body(kb, carry):
+
+def _scaled(x, scale):
+    """x * scale in x's own dtype (rounded once, as the MXU's feed would)."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _col_to_row(x):
+    """[R, 1] -> [1, R].  Through a transpose where R fills whole lane
+    tiles: Mosaic's own reshape of a column costs about half a
+    microsecond a grid step on the v5e."""
+    rows = x.shape[0]
+    if rows % 128:
+        return x.reshape(1, rows)
+    return jnp.broadcast_to(x, (rows, 128)).T[:1]
+
+
+def _row_to_col(x):
+    """[1, R] -> [R, 1]."""
+    rows = x.shape[1]
+    if rows % 128:
+        return x.reshape(rows, 1)
+    return jnp.broadcast_to(x, (128, rows)).T[:, :1]
+
+
+def _lane_sums(p):
+    """[R, n * 128] -> [R, 128]: the 128-wide column groups added up (no
+    cross-lane work: that is left to once a q block)."""
+    lanes = min(128, p.shape[-1])
+    return sum(p[:, j:j + lanes] for j in range(0, p.shape[-1], lanes))
+
+
+def _fwd_kernel(*refs, n_in, scale, causal, block_q, block_k, seq_len,
+                use_slope, use_window):
+    (q_ref, k_ref, v_ref), slope, window = _scalars(
+        refs[:n_in], use_slope, use_window)
+    o_ref, lse_ref = refs[n_in:]
+    qi = pl.program_id(1)
+    q = _scaled(q_ref[0], scale)                       # [BQ, D]
+    d = q.shape[-1]
+    rel = (jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+           - jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1))
+
+    def tile(kb, carry):
         acc, m, l = carry
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = q @ k.T                                    # [BLK_Q, BLK_K]
-        if causal or slope is not None or window is not None:
-            qpos, kpos = _tile_positions(qi * block_q, kb * block_k,
-                                         block_q, block_k)
-            s = _mask_bias(s, qpos, kpos, causal, slope, window)
-        bm = jnp.max(s, axis=-1, keepdims=True)        # [BLK_Q, 1]
-        new_m = jnp.maximum(m, bm)
-        p = jnp.exp(s - new_m)
-        p = jnp.where(new_m <= _NEG / 2, 0.0, p)
+        k0 = pl.multiple_of(kb * block_k, block_k)
+        k = k_ref[0, pl.ds(k0, block_k), :]
+        v = v_ref[0, pl.ds(k0, block_k), :]
+        s = _scores(q, k, _alibi(slope, k0, block_k, 1), rel,
+                    qi * block_q - k0, causal, window)
+        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - new_m)                         # [BQ, BK]
         corr = jnp.exp(m - new_m)
-        corr = jnp.where(m <= _NEG / 2, 0.0, corr)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + p @ v
+        l = l * corr + _lane_sums(p)                   # [BQ, 128]
+        acc = acc * corr + jnp.dot(p.astype(v.dtype), v,
+                                   preferred_element_type=jnp.float32)
         return acc, new_m, l
 
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q, 1), _NEG, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
+    lo, hi = _k_bounds(qi, block_q, block_k, seq_len, causal, window)
+    acc, m, l = jax.lax.fori_loop(lo, hi, tile, (
+        jnp.zeros((block_q, d), jnp.float32),
+        jnp.full((block_q, 1), _M0, jnp.float32),
+        jnp.zeros((block_q, min(128, block_k)), jnp.float32)))
 
-    l_safe = jnp.maximum(l, 1e-30)
+    l_safe = jnp.maximum(jnp.sum(l, axis=-1, keepdims=True), 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l_safe)
+    lse_ref[0] = _col_to_row(m + jnp.log(l_safe))
 
 
 def _scalar_specs():
@@ -157,23 +340,6 @@ def _bias_inputs(alibi_slopes, window, B, H):
     return slopes_bh, w_bh
 
 
-# Mosaic's default scoped VMEM, which the kernels' double-buffered blocks
-# fit up to about 4k positions; a call whose blocks take more asks for it
-DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
-
-
-def _vmem_params(block_bytes):
-    """``pallas_call`` keywords for a call whose blocks (one buffer of
-    each) take ``block_bytes``: nothing while two buffers of each fit the
-    default with room for the kernel's own temporaries (every program of
-    a shorter sequence stays as it was), else a limit of its own."""
-    need = 2 * block_bytes + 4 * 2 ** 20
-    if need <= DEFAULT_SCOPED_VMEM:
-        return {}
-    return dict(compiler_params=pltpu.CompilerParams(
-        vmem_limit_bytes=int(min(need + need // 4, 100 * 2 ** 20))))
-
-
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret=False,
                alibi_slopes=None, window=None):
     B, S, H, D = q.shape
@@ -188,172 +354,115 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret=False,
     grid = (B * H, S // block_q)
 
     slopes_bh, w_bh = _bias_inputs(alibi_slopes, window, B, H)
-    in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, S, D), lambda bh, qi, g=group: (bh // g, 0, 0)),
-        pl.BlockSpec((1, S, D), lambda bh, qi, g=group: (bh // g, 0, 0)),
-    ]
+    q_spec = pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0))
+    kv_spec = pl.BlockSpec((1, S, D), lambda bh, qi, g=group: (bh // g, 0, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
     args = [qr, kr, vr]
-    if slopes_bh is None:
-        kernel = functools.partial(
-            _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, seq_len=S)
-    else:
-        kernel = functools.partial(
-            _fwd_kernel_biased, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, seq_len=S,
-            use_slope=alibi_slopes is not None,
-            use_window=window is not None)
+    if slopes_bh is not None:
         in_specs += _scalar_specs()
         args += [slopes_bh, w_bh]
 
     out, lse = pl.pallas_call(
-        kernel,
+        functools.partial(
+            _fwd_kernel, n_in=len(args), scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, seq_len=S,
+            use_slope=alibi_slopes is not None,
+            use_window=window is not None),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
+            q_spec,
+            # a lane-dense row: an [S, 1] column is padded 128 times
+            # over, in HBM as in VMEM
+            pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention_fwd",     # the instruction's name in a trace
-        **_vmem_params(2 * S * D * k.dtype.itemsize
-                       + 2 * block_q * D * q.dtype.itemsize),
+        **_vmem_params(S, D, block_q, block_k, k.dtype.itemsize, group),
     )(*args)
 
     out = jnp.swapaxes(out.reshape(B, H, S, D), 1, 2)
     return out, lse.reshape(B, H, S)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, scale, causal, block_q, block_k, seq_len):
-    _bwd_dq_impl(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
-                 None, dq_ref, scale=scale, causal=causal, block_q=block_q,
-                 block_k=block_k, seq_len=seq_len)
-
-
-def _bwd_dq_kernel_biased(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          slope_ref, window_ref, dq_ref, *, scale, causal,
-                          block_q, block_k, seq_len, use_slope=True,
-                          use_window=True):
-    _bwd_dq_impl(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 slope_ref if use_slope else None,
-                 window_ref if use_window else None, dq_ref, scale=scale,
-                 causal=causal, block_q=block_q, block_k=block_k,
-                 seq_len=seq_len)
-
-
-def _bwd_dq_impl(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slope_ref,
-                 window_ref, dq_ref, *, scale, causal, block_q, block_k,
-                 seq_len):
+def _bwd_dq_kernel(*refs, n_in, scale, causal, block_q, block_k, seq_len,
+                   use_slope, use_window):
     """dQ for one (batch·head, q-block): stream K/V blocks, recompute P
     from the saved LSE, accumulate dq = Σ_kb dS @ K."""
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), slope, window = \
+        _scalars(refs[:n_in], use_slope, use_window)
+    dq_ref, = refs[n_in:]
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)                   # [BQ, D]
-    do = do_ref[0].astype(jnp.float32)                 # [BQ, D]
-    lse = lse_ref[0].reshape(block_q, 1)               # [BQ, 1, 1]→[BQ, 1]
-    delta = delta_ref[0].reshape(block_q, 1)
+    q = _scaled(q_ref[0], scale)                       # [BQ, D]
+    do = do_ref[0]                                     # [BQ, D]
+    lse = _row_to_col(lse_ref[0])                      # [BQ, 1]
+    delta = _row_to_col(delta_ref[0])
     d = q.shape[-1]
+    rel = (jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+           - jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1))
 
-    bh = pl.program_id(0)
-    slope = slope_ref[bh, 0] if slope_ref is not None else None
-    window = window_ref[bh, 0] if window_ref is not None else None
-    lo, hi = _k_range(qi, block_q, block_k, seq_len, causal, window)
-
-    def body(kb, dq):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal or slope is not None or window is not None:
-            qpos, kpos = _tile_positions(qi * block_q, kb * block_k,
-                                         block_q, block_k)
-            s = _mask_bias(s, qpos, kpos, causal, slope, window)
+    def tile(kb, dq):
+        k0 = pl.multiple_of(kb * block_k, block_k)
+        k = k_ref[0, pl.ds(k0, block_k), :]
+        v = v_ref[0, pl.ds(k0, block_k), :]
+        s = _scores(q, k, _alibi(slope, k0, block_k, 1), rel,
+                    qi * block_q - k0, causal, window)
         p = jnp.exp(s - lse)
-        p = jnp.where(s <= _NEG / 2, 0.0, p)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(do, v, _NT,
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta)
+        return dq + jnp.dot(ds.astype(k.dtype), k,
+                            preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(lo, hi, body,
+    lo, hi = _k_bounds(qi, block_q, block_k, seq_len, causal, window)
+    dq = jax.lax.fori_loop(lo, hi, tile,
                            jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, scale, causal, block_q, block_k,
-                    seq_len):
-    _bwd_dkv_impl(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
-                  None, dk_ref, dv_ref, scale=scale, causal=causal,
-                  block_q=block_q, block_k=block_k, seq_len=seq_len)
-
-
-def _bwd_dkv_kernel_biased(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           slope_ref, window_ref, dk_ref, dv_ref, *, scale,
-                           causal, block_q, block_k, seq_len,
-                           use_slope=True, use_window=True):
-    _bwd_dkv_impl(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                  slope_ref if use_slope else None,
-                  window_ref if use_window else None, dk_ref, dv_ref,
-                  scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, seq_len=seq_len)
-
-
-def _bwd_dkv_impl(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                  slope_ref, window_ref, dk_ref, dv_ref, *, scale, causal,
-                  block_q, block_k, seq_len):
-    """dK/dV for one (batch·head, k-block): stream Q/dO blocks.
+def _bwd_dkv_kernel(*refs, n_in, scale, causal, block_q, block_k, seq_len,
+                    use_slope, use_window):
+    """dK/dV for one (batch·head, k-block): stream Q/dO blocks, the scores
+    transposed ([BK, BQ]) so that both sums are plain products:
     dv = Σ_qb Pᵀ @ dO;  dk = Σ_qb dSᵀ @ Q."""
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), slope, window = \
+        _scalars(refs[:n_in], use_slope, use_window)
+    dk_ref, dv_ref = refs[n_in:]
     ki = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)                   # [BK, D]
-    v = v_ref[0].astype(jnp.float32)
+    k = _scaled(k_ref[0], scale)                       # [BK, D]
+    v = v_ref[0]
     d = k.shape[-1]
+    # qpos - kpos of the transposed tile, less the tile's offset
+    rel = (jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
+           - jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0))
+    bias = _alibi(slope, ki * block_k, block_k, 0)     # [BK, 1]
 
-    bh = pl.program_id(0)
-    slope = slope_ref[bh, 0] if slope_ref is not None else None
-    window = window_ref[bh, 0] if window_ref is not None else None
-    num_q_blocks = seq_len // block_q
-    lo = (ki * block_k) // block_q if causal else 0
-    hi = num_q_blocks
-    if window is not None:
-        # last q block that can see this k block: qpos < kpos + window
-        hi_w = jax.lax.div((ki + 1) * block_k + window - 2, block_q) + 1
-        hi = jnp.where(window > 0,
-                       jnp.minimum(num_q_blocks, hi_w), num_q_blocks)
-
-    def body(qb, carry):
+    def tile(qb, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qb * block_q, block_q), :].reshape(block_q, 1)
-        delta = delta_ref[0, pl.ds(qb * block_q, block_q), :].reshape(
-            block_q, 1)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal or slope is not None or window is not None:
-            qpos, kpos = _tile_positions(qb * block_q, ki * block_k,
-                                         block_q, block_k)
-            s = _mask_bias(s, qpos, kpos, causal, slope, window)
-        p = jnp.exp(s - lse)
-        p = jnp.where(s <= _NEG / 2, 0.0, p)
-        dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        q0 = pl.multiple_of(qb * block_q, block_q)
+        q = q_ref[0, pl.ds(q0, block_q), :]
+        do = do_ref[0, pl.ds(q0, block_q), :]
+        lse = lse_ref[0, pl.ds(qb, 1), :]              # [1, BQ]
+        delta = delta_ref[0, pl.ds(qb, 1), :]
+        s = _scores(k, q, bias, rel, q0 - ki * block_k, causal, window)
+        p = jnp.exp(s - lse)                           # [BK, BQ]
+        dv = dv + jnp.dot(p.astype(do.dtype), do,
+                          preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, do, _NT,
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+        ds = p * (dp - delta)
+        dk = dk + jnp.dot(ds.astype(q.dtype), q,
+                          preferred_element_type=jnp.float32)
         return dk, dv
 
-    dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(lo, hi, body, (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    zeros = jnp.zeros((block_k, d), jnp.float32)
+    lo, hi = _q_bounds(ki, block_q, block_k, seq_len, causal, window)
+    dk, dv = jax.lax.fori_loop(lo, hi, tile, (zeros, zeros))
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
@@ -365,84 +474,69 @@ def _flash_bwd_pallas(scale, causal, res, g, block_q, block_k,
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     group = H // Hkv
+    block_q = min(block_q, S)
+    block_k = min(block_k, S)
 
     qr = jnp.swapaxes(q, 1, 2).reshape(B * H, S, D)
     kr = jnp.swapaxes(k, 1, 2).reshape(B * Hkv, S, D)
     vr = jnp.swapaxes(v, 1, 2).reshape(B * Hkv, S, D)
     gr = jnp.swapaxes(g, 1, 2).reshape(B * H, S, D)
-    of = jnp.swapaxes(out, 1, 2).reshape(B * H, S, D)
-    # trailing singleton dim: mosaic requires the last two block dims to
-    # tile (8, 128) or equal the array dims — (block, 1) blocks of an
-    # [..., 1] array are legal where (1, block) blocks of a 2-D one aren't
-    lser = lse.reshape(B * H, S, 1)
-    # delta_i = Σ_d dO_i · O_i  (the softmax-jacobian row term)
-    delta = jnp.sum(gr.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1, keepdims=True)
+    # delta_i = Σ_d dO_i · O_i  (the softmax-jacobian row term), [B, H, S]
+    delta = jnp.einsum("bshd,bshd->bhs", g.astype(jnp.float32),
+                       out.astype(jnp.float32))
+    # lane-dense rows: dq takes a [1, BQ] block of the [1, S] row (and
+    # turns it), dk/dv, whose scores are transposed, a q block's row by its
+    # index
+    lse_row, delta_row = (x.reshape(B * H, 1, S) for x in (lse, delta))
+    lse_blk, delta_blk = (x.reshape(B * H, S // block_q, block_q)
+                          for x in (lse, delta))
 
     slopes_bh, w_bh = _bias_inputs(alibi_slopes, window, B, H)
     scalar_specs = ([] if slopes_bh is None
                     else _scalar_specs())
     scalar_args = [] if slopes_bh is None else [slopes_bh, w_bh]
+    static = dict(n_in=6 + len(scalar_args), scale=scale, causal=causal,
+                  block_q=block_q, block_k=block_k, seq_len=S,
+                  use_slope=alibi_slopes is not None,
+                  use_window=window is not None)
+    vmem = _vmem_params(S, D, block_q, block_k, k.dtype.itemsize, group)
 
+    q_spec = pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0))
     kv_spec = pl.BlockSpec((1, S, D), lambda bh, i, g=group: (bh // g, 0, 0))
-    dq_kernel = _bwd_dq_kernel if slopes_bh is None else functools.partial(
-        _bwd_dq_kernel_biased, use_slope=alibi_slopes is not None,
-        use_window=window is not None)
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi))
     dq = pl.pallas_call(
-        functools.partial(dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=S),
+        functools.partial(_bwd_dq_kernel, **static),
         grid=(B * H, S // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-            kv_spec,
-            kv_spec,
-            pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
-        ] + scalar_specs,
-        out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+        + scalar_specs,
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         interpret=interpret,
         name="flash_attention_dq",
-        **_vmem_params(2 * S * D * k.dtype.itemsize
-                       + 3 * block_q * D * q.dtype.itemsize),
-    )(qr, kr, vr, gr, lser, delta, *scalar_args)
+        **vmem,
+    )(qr, kr, vr, gr, lse_row, delta_row, *scalar_args)
 
     full_spec = pl.BlockSpec((1, S, D), lambda bh, ki: (bh, 0, 0))
-    dkv_kernel = (_bwd_dkv_kernel if slopes_bh is None
-                  else functools.partial(
-                      _bwd_dkv_kernel_biased,
-                      use_slope=alibi_slopes is not None,
-                      use_window=window is not None))
+    k_spec = pl.BlockSpec((1, block_k, D),
+                          lambda bh, ki, g=group: (bh // g, ki, 0))
+    rows_spec = pl.BlockSpec((1, S // block_q, block_q),
+                             lambda bh, ki: (bh, 0, 0))
+    dkv_spec = pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0))
+    # a query head's dk/dv: the gradient itself where it has the kv head
+    # to itself, else a float32 term of the group's sum
+    dkv_shape = jax.ShapeDtypeStruct(
+        (B * H, S, D), k.dtype if group == 1 else jnp.float32)
     dk, dv = pl.pallas_call(
-        functools.partial(dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=S),
+        functools.partial(_bwd_dkv_kernel, **static),
         grid=(B * H, S // block_k),
-        in_specs=[
-            full_spec,                                     # q
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, ki, g=group: (bh // g, ki, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, ki, g=group: (bh // g, ki, 0)),
-            full_spec,                                     # dO
-            pl.BlockSpec((1, S, 1), lambda bh, ki: (bh, 0, 0)),  # lse
-            pl.BlockSpec((1, S, 1), lambda bh, ki: (bh, 0, 0)),  # delta
-        ] + scalar_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((B * H, S, D), jnp.float32),
-        ],
+        in_specs=[full_spec, k_spec, k_spec, full_spec, rows_spec,
+                  rows_spec] + scalar_specs,
+        out_specs=[dkv_spec, dkv_spec],
+        out_shape=[dkv_shape, dkv_shape],
         interpret=interpret,
         name="flash_attention_dkv",
-        # whole-sequence q and dO, and lse and delta as [S, 1] float32
-        # columns, which VMEM pads to 128 lanes
-        **_vmem_params(2 * S * D * q.dtype.itemsize + 2 * S * 128 * 4
-                       + 2 * block_k * D * (k.dtype.itemsize + 4)),
-    )(qr, kr, vr, gr, lser, delta, *scalar_args)
+        **vmem,
+    )(qr, kr, vr, gr, lse_blk, delta_blk, *scalar_args)
 
     dq = jnp.swapaxes(dq.reshape(B, H, S, D), 1, 2)
     dk = dk.reshape(B, Hkv, group, S, D).sum(axis=2)     # GQA group reduce
@@ -525,22 +619,17 @@ def _flash_attention_bwd(scale, causal, block_q, block_k, interpret,
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
-def flash_tiles(seq_len, n_heads, n_kv_heads, block_q=DEFAULT_BLOCK_Q,
-                block_k=DEFAULT_BLOCK_K):
-    """Whether the kernel's grid covers this shape exactly."""
-    return (seq_len % min(block_q, seq_len) == 0
-            and seq_len % min(block_k, seq_len) == 0
-            and n_heads % n_kv_heads == 0)
-
-
 def flash_attention(q, k, v, causal=True, softmax_scale=None,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=False, alibi_slopes=None, window=None):
+                    block_q=None, block_k=None, interpret=False,
+                    alibi_slopes=None, window=None):
     """q: [B, S, H, D]; k/v: [B, S, Hkv, D].  Raises ``ValueError`` when the
     shape doesn't tile (:func:`flash_tiles`) — choosing another
     implementation is ``ops.attention.attention``'s decision, not the
     kernel's.  ``interpret=True`` runs the kernel in the Pallas interpreter
     (CPU CI).
+
+    ``block_q`` / ``block_k``: None takes :func:`pick_flash_tiles`' tiles
+    for the call's shapes; a value overrides (tests, the autotuner).
 
     ``alibi_slopes`` ([H] fp32, treated as CONSTANT — stop_gradient; ALiBi
     slopes are a deterministic function of the head count, never learned)
@@ -550,14 +639,19 @@ def flash_attention(q, k, v, causal=True, softmax_scale=None,
     asymptotics (role of the reference's local-attention inference kernels,
     ``csrc/transformer/inference``)."""
     B, S, H, D = q.shape
+    Hkv = k.shape[2]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-    if not flash_tiles(S, H, k.shape[2], block_q, block_k):
+    if H % Hkv:
+        raise ValueError(
+            f"flash attention cannot tile q{q.shape} k{k.shape}: the kv "
+            "heads must divide the heads")
+    block_q, block_k = resolve_tiles(S, D, H // Hkv, q.dtype.itemsize,
+                                     block_q, block_k)
+    if S % block_q or S % block_k:
         raise ValueError(
             f"flash attention cannot tile q{q.shape} k{k.shape} with "
             f"block_q={block_q} block_k={block_k}: the sequence must be a "
-            "multiple of both blocks and the kv heads must divide the heads")
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
+            "multiple of both blocks")
     window_f = (None if window is None
                 else jnp.asarray(window, jnp.float32))
     if alibi_slopes is not None:

@@ -30,6 +30,7 @@ The model contract is functional: ``model`` is a callable
 """
 
 import contextlib
+import math
 import os
 import time
 from typing import Any, Callable, Dict, Optional
@@ -358,6 +359,10 @@ class DeepSpeedEngine:
                                  if self._profiling is not None else None),
             ).start()
         self._last_batch_tokens = None
+        # the flash kernels' plan of one step (``train/attn/*`` gauges):
+        # None until the first batch shows its shape, then a dict, empty
+        # for a model without one
+        self._attn_plan = None
         # live MFU: analytic per-step model flops (set once the flops
         # profiler has run) / measured step time / device-peak ceiling
         self._analytic_step_flops = None
@@ -1318,6 +1323,8 @@ class DeepSpeedEngine:
                 batch = self._shard_batch(batch, leading_gas_dim=gas > 1)
         if self._tel_enabled:
             self._last_batch_tokens = _batch_token_count(batch)
+            if self._attn_plan is None:
+                self._attn_plan = self._plan_attention(batch, gas)
         if self._injector is not None and \
                 self._injector.poison_grads(self.global_steps):
             # deterministic divergence trigger: NaN the float batch inputs
@@ -1573,6 +1580,21 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------
     # monitor / introspection parity accessors
     # ------------------------------------------------------------------
+    def _plan_attention(self, batch, gas):
+        """The model's ``attention_plan`` for a step of ``gas``
+        micro-batches like ``batch`` (static, from shapes): set as the
+        ``train/attn/*`` gauges, once."""
+        plan_of = getattr(self.module, "attention_plan", None)
+        ids = batch.get("input_ids") if isinstance(batch, dict) else batch
+        shape = getattr(ids, "shape", ())
+        if plan_of is None or len(shape) < 2:
+            return {}
+        plan = plan_of(math.prod(shape[:-1]) // gas, shape[-1]) or {}
+        for name, value in plan.items():
+            self.telemetry.gauge(f"train/attn/{name}", float(value * gas),
+                                 step=self.global_steps)
+        return plan
+
     def _emit_step_telemetry(self, step_secs=None, metrics=None):
         """Per-step telemetry tail (telemetry-enabled runs only): heartbeat
         for the stall watchdog, loss/grad-norm/loss-scale + throughput

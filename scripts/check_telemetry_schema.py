@@ -458,6 +458,19 @@ STEP_ATTR_GAUGES = (
 )
 ATTR_STAGES = ("queue", "prefill", "migrate", "gap", "decode")
 
+# FROZEN vocabulary of the trainer's flash-attention plan — must stay
+# byte-identical to ``deepspeed_tpu.models.transformer.ATTN_PLAN`` under
+# the ``train/attn/`` prefix (the tier-1 test diffs the two).  Set once,
+# when the first batch shows the step's shapes: what the three flash
+# kernels of one optimizer step visit, mask, compute and need
+# (docs/telemetry.md).
+TRAIN_ATTN_GAUGES = (
+    "train/attn/tiles_visited",
+    "train/attn/tiles_masked",
+    "train/attn/pairs_visited",
+    "train/attn/pairs_needed",
+)
+
 EVENT_KINDS = tuple(SCHEMA)
 
 
@@ -534,6 +547,11 @@ def validate_event(event):
             event["name"] not in STEP_ATTR_GAUGES:
         problems.append(
             f"gauge: unknown step/attr gauge {event['name']!r}")
+    if kind == "gauge" and isinstance(event.get("name"), str) and \
+            event["name"].startswith("train/attn/") and \
+            event["name"] not in TRAIN_ATTN_GAUGES:
+        problems.append(
+            f"gauge: unknown train/attn gauge {event['name']!r}")
     if kind == "gauge" and isinstance(event.get("name"), str) and \
             event["name"].startswith("fleet/") and \
             event["name"] not in FLEET_GAUGES:

@@ -10,6 +10,7 @@ text as a ``tpu_custom_call``.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or it logs under /tmp
 # compile-only: no chip to contend for, so two test processes may load libtpu
@@ -69,12 +70,25 @@ def _on(sharding, shape, dtype=jnp.bfloat16):
     ("gqa_16_4", (2, 1024, 16, 128), 4, None),
     ("window", (2, 1024, 16, 128), 16, 256),
     ("d64", (2, 1024, 12, 64), 12, None),
-    # PR 43: 8,192 positions, where the dk/dv kernel's whole-sequence
-    # blocks pass Mosaic's default 16 MiB of scoped VMEM (25.5 MB: the
-    # call then asks for its own limit, ``_vmem_params``)
+    # PR 43: 8,192 positions, where the dk/dv kernel holds a head's whole
+    # q and dO (its blocks and temporaries are 15.5 of Mosaic's default
+    # 16 MiB of scoped VMEM by ``_vmem_bytes``; past it the call asks for
+    # its own limit, ``_vmem_params``)
     ("gqa_32_4_s8192_window", (1, 8192, 32, 128), 4, 1024),
+    # PR 49: the three training cells' own calls, under the tiles the
+    # picker gives them
+    ("train-pythia-1.4b-s2048", (2, 2048, 16, 128), 16, None),
+    ("train-pythia-6.9b-fsdp4", (1, 2048, 32, 128), 32, None),
+    ("train-mellum2-12b-ep4-s8192_full", (1, 8192, 32, 128), 4, None),
+    # twice that: the first length whose calls ask for a VMEM limit of
+    # their own (22.75 MB by ``_vmem_bytes``)
+    ("gqa_4_1_s16384", (1, 16384, 4, 128), 1, None),
 ])
 def test_flash_forward_backward(topo, name, shape, kv_heads, window):
+    """Forward and backward are exactly three kernels, each once, by name:
+    what the benchmark's cost files count a layer and micro-batch
+    (``chipbench/costs/flash_attention_train.py`` divides EVERY Pallas call
+    of a step by layers and micro-batches)."""
     chip = SingleDeviceSharding(topo.devices[0])
     B, S, _, D = shape
 
@@ -85,7 +99,10 @@ def test_flash_forward_backward(topo, name, shape, kv_heads, window):
     text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
                           _on(chip, shape), _on(chip, (B, S, kv_heads, D)),
                           _on(chip, (B, S, kv_heads, D)))
-    assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("fwd", "dq", "dkv"):
+        assert len(re.findall(
+            rf"%flash_attention_{kernel}[.\d]* = ", text)) == 1, kernel
 
 
 def test_flash_on_a_four_chip_mesh(topo):

@@ -226,3 +226,172 @@ def test_flash_window_traced_per_layer():
                                    bias=_bias_for(S, window=w))
         np.testing.assert_allclose(np.asarray(f(jnp.int32(w))),
                                    np.asarray(want), rtol=2e-4, atol=2e-4)
+
+
+# ----------------------------------------------------------------------
+# The kinds of tile a call can hold (PR 49): wholly inside the causal
+# triangle and the window, across the diagonal, across the window's far
+# edge, with rows that have no key in it; and what the kernels no longer do
+# (guard such rows, scale the score tile, upcast their operands)
+# ----------------------------------------------------------------------
+# name, S, H, Hkv, D, block_q, block_k, window, alibi, causal, dtype
+EDGE_CASES = [
+    ("window_under_a_tile", 256, 2, 2, 32, 64, 64, 16, False, True, "f32"),
+    ("window_off_the_tiles", 256, 2, 2, 32, 64, 64, 100, False, True,
+     "f32"),
+    ("window_of_two_tiles", 256, 2, 2, 32, 64, 64, 128, False, True, "f32"),
+    ("window_past_the_sequence", 128, 2, 2, 32, 32, 32, 500, False, True,
+     "f32"),
+    # a q tile of 128 rows over key steps of 32 under a window of 8: the
+    # last rows' keys lie three steps past the first visited one
+    ("rows_wholly_masked_in_a_tile", 256, 2, 2, 32, 128, 32, 8, False,
+     True, "f32"),
+    ("group_8", 128, 8, 1, 32, 32, 64, 48, False, True, "f32"),
+    ("q_tile_over_k_step", 256, 2, 2, 32, 128, 32, None, False, True,
+     "f32"),
+    ("k_step_over_q_tile", 256, 2, 2, 32, 32, 128, None, False, True,
+     "f32"),
+    ("alibi_with_a_window", 256, 4, 2, 32, 64, 32, 80, True, True, "f32"),
+    ("alibi_alone", 128, 4, 4, 32, 32, 64, None, True, True, "f32"),
+    ("not_causal", 256, 2, 2, 32, 64, 128, None, False, False, "f32"),
+    ("not_causal_with_a_window", 256, 2, 2, 32, 64, 32, 70, False, False,
+     "f32"),
+    ("picked_tiles", 1024, 1, 1, 32, None, None, 300, False, True, "f32"),
+    ("picked_q_tile_alone", 1024, 1, 1, 32, None, 128, None, False, True,
+     "f32"),
+    ("bf16_inputs", 256, 4, 2, 64, 64, 128, 96, False, True, "bf16"),
+]
+
+
+@pytest.mark.parametrize(
+    "S,H,Hkv,D,bq,bk,window,alibi,causal,dtype",
+    [c[1:] for c in EDGE_CASES], ids=[c[0] for c in EDGE_CASES])
+def test_flash_edges_forward_and_gradients(S, H, Hkv, D, bq, bk, window,
+                                           alibi, causal, dtype):
+    """Forward and all three gradients against ``reference_attention`` over
+    the kinds of tile the split tells apart."""
+    from deepspeed_tpu.models.transformer import alibi_slopes
+    from deepspeed_tpu.ops.attention import alibi_window_bias
+    rng = np.random.default_rng(49)
+    dt = jnp.float32 if dtype == "f32" else jnp.bfloat16
+    B = 1
+    q = jnp.asarray(rng.normal(size=(B, S, H, D)), dt)
+    k = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), dt)
+    v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), dt)
+    w = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
+    slopes = alibi_slopes(H) if alibi else None
+    bias = alibi_window_bias(S, S, slopes=slopes, window=window) \
+        if alibi or window is not None else None
+
+    def loss_flash(q, k, v):
+        o = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk,
+                            interpret=True, alibi_slopes=slopes,
+                            window=window)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    def loss_ref(q, k, v):
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        o = reference_attention(*f32, causal=causal, bias=bias)
+        return jnp.sum(o * w), o
+
+    (_, out), grads = jax.value_and_grad(
+        loss_flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, want), wants = jax.value_and_grad(
+        loss_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    tol = dict(rtol=2e-4, atol=2e-4) if dtype == "f32" \
+        else dict(rtol=5e-2, atol=5e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), **tol)
+    gtol = dict(rtol=1e-3, atol=1e-3) if dtype == "f32" \
+        else dict(rtol=1e-1, atol=1e-1)
+    for name, a, b in zip("qkv", grads, wants):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   err_msg=f"d{name}", **gtol)
+
+
+@pytest.mark.parametrize("S,bq,bk,causal,window", [
+    (256, 64, 64, True, None), (256, 64, 64, True, 16),
+    (256, 64, 64, True, 100), (256, 128, 32, True, 8),
+    (256, 32, 128, True, 48), (256, 64, 32, False, 70),
+    (256, 64, 128, False, None), (2048, 512, 512, True, None),
+    (8192, 512, 512, True, 1024), (256, 64, 64, True, 0),
+])
+def test_the_plan_counts_the_tiles_that_hold_a_pair(S, bq, bk, causal,
+                                                    window):
+    """``flash_plan``'s bounds against the mask itself: every tile with an
+    attended pair is visited and no other, and the dk/dv kernel's bounds
+    (``_q_bounds``) name the same tiles."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (_k_bounds,
+                                                          _q_bounds,
+                                                          flash_plan)
+    qpos, kpos = np.arange(S)[:, None], np.arange(S)[None, :]
+    allowed = np.ones((S, S), bool)
+    if causal:
+        allowed &= qpos >= kpos
+    if window:
+        allowed &= qpos - kpos < window
+    some = allowed.reshape(S // bq, bq, S // bk, bk).any(axis=(1, 3))
+    w = np.int64(window) if window else None
+    kb = np.arange(S // bk)[None, :]
+    lo, hi = (x[:, None] for x in _k_bounds(
+        np.arange(S // bq), bq, bk, S, causal, w, xp=np))
+    np.testing.assert_array_equal((kb >= lo) & (kb < hi), some)
+    qb = np.arange(S // bq)[:, None]
+    lo, hi = (x[None, :] for x in _q_bounds(
+        np.arange(S // bk), bq, bk, S, causal, w, xp=np))
+    np.testing.assert_array_equal((qb >= lo) & (qb < hi), some)
+    assert flash_plan(S, bq, bk, causal, window) == {
+        "tiles_visited": int(some.sum()),
+        "tiles_masked": int(some.sum()) if causal or window else 0,
+        "pairs_visited": int(some.sum()) * bq * bk,
+        "pairs_needed": int(allowed.sum())}
+
+
+# the three training cells' calls: sequence, head_dim, group, window
+CELL_CALLS = [
+    ("train-pythia-1.4b-s2048", 2048, 128, 1, None),
+    ("train-pythia-6.9b-fsdp4", 2048, 128, 1, None),
+    ("train-mellum2-12b-ep4-s8192_full", 8192, 128, 8, None),
+    ("train-mellum2-12b-ep4-s8192_window", 8192, 128, 8, 1024),
+]
+
+
+@pytest.mark.parametrize("S,D,group,window", [c[1:] for c in CELL_CALLS],
+                         ids=[c[0] for c in CELL_CALLS])
+def test_picked_tiles_of_the_cells(S, D, group, window):
+    """The tiles divide the sequence, the picker's own VMEM count is under
+    the limit the call asks for, and the pairs a causal call of 2,048
+    positions needs are four fifths of those it computes.  (Under the
+    window of 1,024 they are two thirds: a 256-row tile would make it four
+    fifths and made each kernel 31-54 % slower on the chip, PERF.md §6,
+    PR 49.)"""
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        DEFAULT_SCOPED_VMEM, VMEM_CEILING, _vmem_bytes, _vmem_params,
+        flash_plan, pick_flash_tiles)
+    bq, bk = pick_flash_tiles(S, D, group, 2)
+    assert (bq, bk) == (512, 512)
+    need = _vmem_bytes(S, D, bq, bk, 2, group)
+    asked = _vmem_params(S, D, bq, bk, 2, group)
+    limit = asked["compiler_params"].vmem_limit_bytes if asked \
+        else DEFAULT_SCOPED_VMEM
+    assert need <= limit <= VMEM_CEILING
+    plan = flash_plan(S, bq, bk, True, window)
+    share = plan["pairs_needed"] / plan["pairs_visited"]
+    assert share >= (2 / 3 if window else 0.8 if S == 2048 else 0.94)
+
+
+@pytest.mark.parametrize("S,ok", [(100, True), (512, True), (640, True),
+                                  (1000, False), (2048, True),
+                                  (32768, True)])
+def test_the_picker_refuses_what_no_tile_divides(S, ok):
+    from deepspeed_tpu.ops.pallas.flash_attention import (flash_tiles,
+                                                          pick_flash_tiles)
+    assert flash_tiles(S, 4, 2) is ok
+    assert flash_tiles(S, 4, 3) is False
+    if ok:
+        bq, bk = pick_flash_tiles(S, 64)
+        assert S % bq == 0 and S % bk == 0 and bq == bk <= 512
+    else:
+        with pytest.raises(ValueError, match="cannot tile"):
+            pick_flash_tiles(S, 64)

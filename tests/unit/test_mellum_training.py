@@ -89,6 +89,55 @@ def test_a_static_window_reaches_the_flash_kernel_under_its_kinds_name(
     assert ("attn_full" if window else "attn_window") not in text
 
 
+def test_the_trainer_sets_the_flash_kernels_plan_as_gauges(tmp_path):
+    """``train/attn/*``: what the flash kernels of one optimizer step
+    visit, mask, compute and need, from ``flash_plan`` over the layers'
+    static windows (three of 16, one full), the heads and both
+    micro-batches; set once, and admitted by the schema check."""
+    import subprocess
+    import sys
+
+    import deepspeed_tpu
+    from deepspeed_tpu.models.transformer import ATTN_PLAN
+    from deepspeed_tpu.monitor.telemetry import get_telemetry
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_plan
+    from deepspeed_tpu.parallel import groups
+    _, model = _toy("pallas")
+    c = model.config
+    assert _toy("reference")[1].attention_plan(2, 64) is None
+    groups.reset_mesh()
+    engine, *_ = deepspeed_tpu.initialize(
+        model=model, model_parameters=model.init(jax.random.key(0)),
+        config={"train_micro_batch_size_per_gpu": 2,
+                "gradient_accumulation_steps": 2,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+                "telemetry": {"enabled": True, "output_path": str(tmp_path),
+                              "job_name": "plan", "hbm_gauges": False,
+                              "stall_watchdog": False}})
+    seqs = 2 * jax.device_count()           # of one micro-batch, all shards
+    ids = np.random.default_rng(0).integers(0, c.vocab_size, (2, seqs, 64),
+                                            dtype=np.int32)
+    for _ in range(2):
+        engine.train_batch(batch={"input_ids": ids})
+    gauges = get_telemetry().registry.snapshot()["gauges"]
+    get_telemetry().close()
+    groups.reset_mesh()
+    window, full = (flash_plan(64, 64, 64, True, w) for w in (16, 0))
+    calls = 3 * 2 * seqs * c.n_heads    # kernels, micro-batches, sequences
+    for name in ATTN_PLAN:
+        assert gauges["train/attn/" + name]["value"] == \
+            calls * (3 * window[name] + full[name]), name
+    assert window["pairs_needed"] < full["pairs_needed"] == 64 * 65 // 2
+    stream = tmp_path / "plan" / "events.jsonl"
+    events = [json.loads(line) for line in open(stream)]
+    assert sum(e["kind"] == "gauge" and e["name"].startswith("train/attn/")
+               for e in events) == len(ATTN_PLAN)           # once, not a step
+    checker = os.path.join(os.path.dirname(__file__), "..", "..", "scripts",
+                           "check_telemetry_schema.py")
+    assert subprocess.run([sys.executable, checker, str(stream)]
+                          ).returncode == 0
+
+
 def test_yarn_rotate_half_frequencies_and_magnitude():
     """The published full-attention rotary (theta 500,000, factor 16 over
     8,192, beta 32 / 1, head 128), from the formula by hand: dimension i

@@ -371,6 +371,23 @@ def test_rejects_unknown_step_attr_gauge(checker):
         dict(base, name="step/attr/bogus_ms"))
 
 
+def test_train_attn_gauges_in_lockstep(checker):
+    """The trainer's flash-attention plan: ``ATTN_PLAN`` of the model file
+    under ``train/attn/`` is the checker's vocabulary, name for name."""
+    import time
+    from deepspeed_tpu.models.transformer import ATTN_PLAN
+    assert checker.TRAIN_ATTN_GAUGES == tuple(
+        "train/attn/" + name for name in ATTN_PLAN)
+    base = {"ts": time.time(), "kind": "gauge", "value": 1.0,
+            "peak": 1.0}
+    for name in checker.TRAIN_ATTN_GAUGES:
+        assert checker.validate_event(dict(base, name=name)) == []
+    assert checker.validate_event(dict(base, name="train/attn/tiles"))
+    # its neighbours stay free-form
+    assert checker.validate_event(
+        dict(base, name="train/moe/expert_pairs")) == []
+
+
 def test_attr_event_requires_every_stage(checker):
     """serve/request/attr must carry one numeric <stage>_ms per frozen
     stage plus e2e_ms — a dropped or non-numeric stage fails."""
