@@ -1399,7 +1399,9 @@ class CausalTransformerLM:
         dispatch's one backend as ``mix_paged``'s; with "jnp" the slice,
         ``ssm_step`` and the masked write.  The prefill and the conv
         tails' masked write are XLA's whatever the backend."""
-        from deepspeed_tpu.ops.ssm import state_decode_update
+        from deepspeed_tpu.ops.ssm import (read_slot_state,
+                                           state_decode_update,
+                                           write_slot_state)
         state_pool, conv_pool = pool
         B, c = h.shape[0], self.config
         rows = (B, c.ssm_conv - 1, c.ssm_conv_dim)
@@ -1423,9 +1425,7 @@ class CausalTransformerLM:
         fresh = lengths == 0
         with scan():    # one sequence a prefill dispatch
             state = jnp.where(fresh[:, None, None, None], 0.0, jnp.stack([
-                jax.lax.dynamic_slice(
-                    state_pool, (index, slots[b], 0, 0, 0),
-                    (1, 1) + state_pool.shape[2:])[0, 0]
+                read_slot_state(state_pool, index, slots[b])
                 for b in range(B)]))
         with conv():
             tail = jnp.where(fresh[:, None], 0, jnp.stack([
@@ -1437,9 +1437,8 @@ class CausalTransformerLM:
         tail = tail.reshape(B, -1).astype(conv_pool.dtype)
         for b in range(B):
             with scan():
-                state_pool = jax.lax.dynamic_update_slice(
-                    state_pool, state[b][None, None],
-                    (index, slots[b], 0, 0, 0))
+                state_pool = write_slot_state(state_pool, index, slots[b],
+                                              state[b])
             with conv():
                 conv_pool = jax.lax.dynamic_update_slice(
                     conv_pool, tail[b][None, None], (index, slots[b], 0))

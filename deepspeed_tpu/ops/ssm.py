@@ -141,6 +141,36 @@ def state_decode_update(pool, layer, x, dt, A, B, C, D, live, impl="jnp",
         pool, jnp.where(live[:, None, None, None], new, state), layer, 0)
 
 
+def _by_rows(pool):
+    """The stacked state pool [L, slots, H, P, N] with a slot's heads and
+    their rows as ONE axis, [L, slots, H x P, N] (no data moves)."""
+    return pool.reshape(pool.shape[:2] + (-1, pool.shape[-1]))
+
+
+def read_slot_state(pool, layer, slot):
+    """Slot ``slot``'s state of layer ``layer`` (both may be traced) out
+    of the stacked pool [L, slots, H, P, N] -> [H, P, N]: what a prefill
+    starts a sequence from."""
+    flat = _by_rows(pool)
+    return jax.lax.dynamic_slice(
+        flat, (layer, slot, 0, 0), (1, 1) + flat.shape[2:]
+    ).reshape(pool.shape[2:])
+
+
+def write_slot_state(pool, layer, slot, state):
+    """The pool with ``state`` [H, P, N] as slot ``slot``'s of layer
+    ``layer``, written in place as [H x P, N].  The chunked scan's einsums
+    leave the new state in an order of their own choosing, and a write of
+    [H, P, N] handed that order on to the WHOLE pool: at 128 heads of 64
+    the 64-row prefill (one chunk) copied 2.4 GB on its way in and again
+    on its way out (docs/serving.md).  With heads and rows one axis there
+    is one order to have."""
+    flat = _by_rows(pool)
+    return jax.lax.dynamic_update_slice(
+        flat, state.reshape((1, 1) + flat.shape[2:]).astype(pool.dtype),
+        (layer, slot, 0, 0)).reshape(pool.shape)
+
+
 def ssd_scan(x, dt, A, B, C, D, state, chunk):
     """T rows a sequence, in chunks of ``chunk``.  x: [b, T, H, P]; dt:
     [b, T, H] float32 (0 on a row that is no token); A, D: [H]; B, C:

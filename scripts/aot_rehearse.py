@@ -184,7 +184,9 @@ def cell(workload):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
 
     width = engine.tables.shape[1]
-    told = engine._counted + 2 * engine._stateful
+    # a prefill is told its real rows once (a counted or a state model's)
+    # and a state model's its slot
+    told = (engine._counted or engine._stateful) + engine._stateful
     lengths = mix["prompt_tokens"]
     buckets = sorted({engine.scheduler.prefill_pieces(int(n))[0]
                       for n in (lengths["min"], lengths["max"])})

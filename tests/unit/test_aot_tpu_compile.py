@@ -780,3 +780,55 @@ def test_hybrid_dispatch_copies_neither_the_pages_nor_the_state(
     one_layer_state = slots * 64 * 64 * 128 * 4         # 33.5 MB
     assert compiled.memory_analysis().temp_size_in_bytes < (
         one_layer_state if tokens == 1 else 1 << 28)
+
+
+def test_a_short_prefill_of_128_state_heads_leaves_the_pool_where_it_lies(
+        topo):
+    """granite-4.0-h-small's widths (128 state heads of 64 x 128, a routed
+    expert layer in every layer; the cell's one period of ten layers and
+    its pools, a vocabulary of 1,024): a prefill of 64 rows, one chunk of
+    the scan.  Its einsums leave the new state in an order of their own,
+    and written back as [H, P, N] that order went on to the WHOLE pool:
+    2.4 GB copied on the way in and again on the way out, 2.46 GB of
+    temporaries (PR 51; the micro's 64 heads never showed it, nor did the
+    longer buckets here).  ``mix_ssm_paged`` cuts a slot's state out of
+    [L, slots, H x P, N]: no instruction but the loop's in-place ones holds
+    the pool."""
+    import json
+    import os
+
+    from chipbench.families import granitemoehybrid_routed as family
+    with open(os.path.join(os.path.dirname(family.__file__), "..",
+                           "configs", "granite-4.0-h-small-ep2.json")) as f:
+        cfg = dict(json.load(f), vocab_size=1024)
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = CausalTransformerLM(TransformerConfig(
+        **family.transformer_kwargs(cfg)))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _on(chip, x.shape, x.dtype), tree)
+
+    def ints(*shape):
+        return _on(chip, shape, jnp.int32)
+
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.bfloat16)))
+    caches = on_chip(jax.eval_shape(lambda: model.init_paged_caches(
+        1025, 128, state_slots=64)))
+
+    def serve(params, ids, caches, tables, lengths, rows, real, slots):
+        return model.apply_with_paged_cache(
+            params, ids, caches, tables, lengths, attn_backend="pallas",
+            expert_backend="pallas", head_rows=rows, real_lengths=real,
+            state_slots=slots)
+
+    compiled = jax.jit(serve, donate_argnums=(2,)).lower(
+        params, ints(1, 64), caches, ints(1, 17), ints(1), ints(1, 1),
+        ints(1), ints(1)).compile()
+    state = {op for _, op in _pool_shaped(compiled.as_text(), None, [
+        "f32[9,64,128,64,128]", "f32[9,64,8192,128]"])}
+    assert state and state <= IN_PLACE_OPS | {
+        "fusion", "dynamic-update-slice", "bitcast"}, state
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
+    assert "grouped_expert_glu" in compiled.as_text()
