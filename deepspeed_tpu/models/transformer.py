@@ -280,6 +280,31 @@ class TransformerConfig:
         return self.is_moe or self.is_latent or self.has_ssm
 
     @property
+    def keeps_flash_residuals(self):
+        """The layers run under ``jax.checkpoint`` with a policy that keeps
+        their matrix products, the flash call's among them."""
+        return self.remat and self.remat_policy in PRODUCT_SAVING_POLICIES
+
+    def checkpoint_policy(self):
+        """What ``jax.checkpoint`` of a layer takes as ``policy``:
+        ``remat_policy`` by its name in ``jax.checkpoint_policies`` (None,
+        which keeps nothing, for a name it does not have), and under a
+        policy that keeps matrix products the flash call's result and
+        ``lse`` too (``FLASH_RESIDUALS``: ``B S H D`` elements of the
+        compute dtype and ``B H S`` float32 a layer and micro-batch).
+        ``nothing_saveable``, whose user asked for the least memory, and
+        the offload policies stay as they are.  The names are identities
+        wherever the kernel does not run, so nothing here asks which
+        attention a layer takes."""
+        from deepspeed_tpu.ops.pallas.flash_attention import FLASH_RESIDUALS
+        policies = jax.checkpoint_policies
+        policy = getattr(policies, self.remat_policy, None)
+        if self.remat_policy in PRODUCT_SAVING_POLICIES:
+            policy = policies.save_from_both_policies(
+                policy, policies.save_only_these_names(*FLASH_RESIDUALS))
+        return policy
+
+    @property
     def kv_heads(self):
         return self.n_kv_heads or self.n_heads
 
@@ -483,6 +508,21 @@ TRAIN_COUNTERS = ("expert_pairs", "expert_load_max", "expert_rows")
 # the most the kernels' share of their roofline can read
 ATTN_PLAN = ("tiles_visited", "tiles_masked", "pairs_visited",
              "pairs_needed")
+# beside them, ``train/attn/saved_residual_bytes``: what ONE layer keeps of
+# ONE micro-batch's flash call for the backward pass (its result and
+# ``lse``), 0 where the forward kernel runs again instead
+# (``CausalTransformerLM.saved_attention_bytes``)
+ATTN_SAVED = "saved_residual_bytes"
+
+# the ``remat_policy`` names (of ``jax.checkpoint_policies``) that keep a
+# layer's matrix products for the backward pass.  The flash call is a
+# product like them, but a ``pallas_call`` and no ``dot_general``: under
+# these its result and ``lse`` are kept by name (``TransformerConfig
+# .checkpoint_policy``), or the whole forward kernel runs a second time in
+# every layer's backward pass
+PRODUCT_SAVING_POLICIES = (
+    "dots_saveable", "checkpoint_dots", "dots_with_no_batch_dims_saveable",
+    "checkpoint_dots_with_no_batch_dims", "everything_saveable")
 
 
 def merge_train_counters(a, b):
@@ -1881,7 +1921,7 @@ class CausalTransformerLM:
             # MoE / heterogeneous stack: unrolled layer loop, then a scan
             # over the periods that follow it
             scanned = c.layer_period and "periods" in params
-            policy = getattr(jax.checkpoint_policies, c.remat_policy, None)
+            policy = c.checkpoint_policy()
 
             def run(indices, static_window, x, layers, positions, rngs):
                 """The blocks of layers ``indices`` (their static pattern)
@@ -1940,8 +1980,7 @@ class CausalTransformerLM:
                 return x, l_aux
 
             if c.remat:
-                policy = getattr(jax.checkpoint_policies, c.remat_policy, None)
-                body = jax.checkpoint(body, policy=policy)
+                body = jax.checkpoint(body, policy=c.checkpoint_policy())
             xs = (params["layers"] if windows is None
                   else (params["layers"], windows))
             x, l_auxs = layer_scan(body, x, xs)
@@ -2332,6 +2371,34 @@ class CausalTransformerLM:
     # ------------------------------------------------------------------
     merge_train_counters = staticmethod(merge_train_counters)
 
+    def _trains_on_flash(self, seq):
+        """``mix_full`` takes the flash kernel for sequences of ``seq``
+        tokens (not under another ``attn_impl``, on the CPU, with a score
+        cap, for a shape it cannot tile or for latent attention)."""
+        from deepspeed_tpu.ops.pallas.flash_attention import flash_tiles
+        c = self.config
+        flash = c.attn_impl == "pallas" or (
+            c.attn_impl == "auto" and jax.default_backend() != "cpu")
+        return bool(flash and not c.is_latent and not c.attn_logit_softcap
+                    and flash_tiles(seq, c.n_heads, c.kv_heads,
+                                    c.attn_block_q, c.attn_block_k))
+
+    def saved_attention_bytes(self, batch, seq, itemsize=2):
+        """``ATTN_SAVED``: the bytes ONE attention layer keeps of one
+        micro-batch of ``batch`` sequences of ``seq`` tokens (all shards')
+        from its flash forward to its backward pass, the result's
+        ``B S H D`` elements of the compute dtype (``itemsize``) and the
+        ``B H S`` float32 of ``lse``: so much more is alive a layer, and
+        the forward kernel runs once.  0 where the layers' policy keeps
+        neither (``TransformerConfig.checkpoint_policy``) or they are not
+        checkpointed; None where the kernel does not run."""
+        c = self.config
+        if not self._trains_on_flash(seq):
+            return None
+        if not c.keeps_flash_residuals:
+            return 0
+        return batch * seq * c.n_heads * (c.head_dim * itemsize + 4)
+
     def attention_plan(self, batch, seq):
         """``ATTN_PLAN`` of one micro-batch of ``batch`` sequences of
         ``seq`` tokens: what its flash kernels (forward, dq, dk/dv: a tile
@@ -2342,16 +2409,12 @@ class CausalTransformerLM:
         ``mix_full`` does not take the kernel (another ``attn_impl``, the
         CPU, a score cap, a shape it cannot tile, latent attention)."""
         from deepspeed_tpu.ops.pallas.flash_attention import (
-            flash_plan, flash_tiles, resolve_tiles)
+            flash_plan, resolve_tiles)
         c = self.config
-        flash = c.attn_impl == "pallas" or (
-            c.attn_impl == "auto" and jax.default_backend() != "cpu")
-        blocks = (c.attn_block_q, c.attn_block_k)
-        if not flash or c.is_latent or c.attn_logit_softcap or \
-                not flash_tiles(seq, c.n_heads, c.kv_heads, *blocks):
+        if not self._trains_on_flash(seq):
             return None
         tiles = resolve_tiles(seq, c.head_dim, c.n_heads // c.kv_heads, 2,
-                              *blocks)
+                              c.attn_block_q, c.attn_block_k)
         total = dict.fromkeys(ATTN_PLAN, 0)
         for i in range(c.n_layers):
             if c.layer_ssm(i):

@@ -38,6 +38,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -57,6 +58,13 @@ VMEM_CEILING = 100 * 2 ** 20
 TILE_SIDES = (512, 256, 128)
 # a sequence this short is one tile whatever its length
 WHOLE_TILE = 512
+# what the forward kernel made and the backward pass reads, by the names a
+# ``jax.checkpoint`` policy can keep them under (``out`` [B·H, S, D] in q's
+# dtype, ``lse`` [B·H, 1, S] float32, as the kernel wrote them): a policy
+# that lists neither runs the forward again in the backward pass, and
+# outside ``jax.checkpoint`` a name is the identity
+# (``models/transformer.py TransformerConfig.checkpoint_policy``)
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
 # ----------------------------------------------------------------------
@@ -341,7 +349,7 @@ def _bias_inputs(alibi_slopes, window, B, H):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret=False,
-               alibi_slopes=None, window=None):
+               alibi_slopes=None, window=None, names=None):
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     group = H // Hkv
@@ -385,6 +393,14 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret=False,
         **_vmem_params(S, D, block_q, block_k, k.dtype.itemsize, group),
     )(*args)
 
+    if names:
+        # named as the kernel wrote them, AHEAD of the transpose: what is
+        # kept is then laid out as the call left it and the output
+        # projection reads the call's result in place; named behind the
+        # transpose, XLA kept a copy with the positions minor and the 1.4b
+        # step gave back 3 of the 10 ms a kept forward saves (PERF.md §6,
+        # PR 52)
+        out, lse = map(checkpoint_name, (out, lse), names)
     out = jnp.swapaxes(out.reshape(B, H, S, D), 1, 2)
     return out, lse.reshape(B, H, S)
 
@@ -597,7 +613,7 @@ def _flash_attention_fwd(q, k, v, alibi_slopes, window, scale, causal,
                          block_q, block_k, interpret=False):
     out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
                           interpret, alibi_slopes=alibi_slopes,
-                          window=window)
+                          window=window, names=FLASH_RESIDUALS)
     return out, (q, k, v, alibi_slopes, window, out, lse)
 
 

@@ -414,7 +414,14 @@ class ActivationCheckpointingConfig(DeepSpeedConfigModel):
     number_checkpoints = None
     synchronize_checkpoint_boundary = False
     profile = False
-    # TPU extension: remat policy name passed to jax.checkpoint
+    # TPU extension: remat policy name passed to jax.checkpoint as
+    # ``jax.checkpoint_policies`` has it (this Megatron-style surface lists
+    # no names: a flash call inside the checkpointed function is computed
+    # again in the backward pass whatever the policy keeps).  A
+    # ``CausalTransformerLM``'s own ``remat_policy`` differs: one that
+    # keeps matrix products also keeps the flash call's result and lse,
+    # B·S·H·D of the compute dtype + B·H·S·4 bytes a layer, and
+    # ``nothing_saveable`` keeps neither (docs/training.md)
     policy = "nothing_saveable"
 
 

@@ -1582,17 +1582,28 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------
     def _plan_attention(self, batch, gas):
         """The model's ``attention_plan`` for a step of ``gas``
-        micro-batches like ``batch`` (static, from shapes): set as the
-        ``train/attn/*`` gauges, once."""
+        micro-batches like ``batch`` (static, from shapes), and what its
+        remat policy keeps of a flash call: set as the ``train/attn/*``
+        gauges, once."""
         plan_of = getattr(self.module, "attention_plan", None)
         ids = batch.get("input_ids") if isinstance(batch, dict) else batch
         shape = getattr(ids, "shape", ())
         if plan_of is None or len(shape) < 2:
             return {}
-        plan = plan_of(math.prod(shape[:-1]) // gas, shape[-1]) or {}
+        sequences, positions = math.prod(shape[:-1]) // gas, shape[-1]
+        plan = plan_of(sequences, positions) or {}
         for name, value in plan.items():
             self.telemetry.gauge(f"train/attn/{name}", float(value * gas),
                                  step=self.global_steps)
+        if plan:
+            # what a layer keeps of one micro-batch's flash call under
+            # the model's remat policy (not a step's sum: no ``gas``)
+            self.telemetry.gauge(
+                "train/attn/saved_residual_bytes",
+                float(self.module.saved_attention_bytes(
+                    sequences, positions,
+                    jnp.dtype(self.compute_dtype).itemsize)),
+                step=self.global_steps)
         return plan
 
     def _emit_step_telemetry(self, step_secs=None, metrics=None):

@@ -39,6 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 
 import chip_smoke
 import deepspeed_tpu
+import train_step_account
 from deepspeed_tpu.inference.serving import ServingEngine
 from deepspeed_tpu.models.transformer import (CausalTransformerLM,
                                               TransformerConfig)
@@ -64,12 +65,17 @@ def report(name, compiled, t0):
           f"  {compiled.memory_analysis()}", flush=True)
 
 
-def train(n_devices, micro_batch, gas, seq=1024):
+def train(n_devices, micro_batch, gas, seq=1024, model=None, config=None,
+          name="GPT-1B"):
+    """The engine's train step of ``model`` under ``config`` (default: the
+    smoke's GPT-1B under its ZeRO-3 config) on an fsdp mesh of
+    ``n_devices`` described chips."""
     axes = tuple(n_devices if a == "fsdp" else 1 for a in MESH_AXES)
     mesh = Mesh(np.asarray(TOPOLOGY.devices[:n_devices]).reshape(axes),
                 MESH_AXES)
-    model = CausalTransformerLM(TransformerConfig(
+    model = model or CausalTransformerLM(TransformerConfig(
         **chip_smoke.GPT_1B, remat=True, remat_policy="dots_saveable"))
+    config = config or chip_smoke._train_config(micro_batch, gas)
     abstract_state = {}
 
     def init_state_of_shapes(self, params):
@@ -101,9 +107,7 @@ def train(n_devices, micro_batch, gas, seq=1024):
         model_parameters=jax.eval_shape(
             lambda: model.init(jax.random.key(0))),
         config=deepspeed_tpu.DeepSpeedConfig(
-            chip_smoke._train_config(micro_batch, gas,
-                                     mesh={"fsdp": n_devices}),
-            world_size=n_devices))
+            dict(config, mesh={"fsdp": n_devices}), world_size=n_devices))
     spec = list(engine.plan.batch_spec(2))
     rows = (micro_batch * n_devices, seq)
     batch = {"input_ids": jax.ShapeDtypeStruct(
@@ -114,8 +118,11 @@ def train(n_devices, micro_batch, gas, seq=1024):
     with mesh:
         compiled = engine._get_compiled_train_step(gas).lower(
             abstract_state["state"], batch).compile()
-    report(f"train GPT-1B fsdp={n_devices} micro_batch={micro_batch} "
+    report(f"train {name} fsdp={n_devices} micro_batch={micro_batch} "
            f"gas={gas}", compiled, t0)
+    clones = train_step_account.xla_clones(
+        train_step_account.instruction_names(compiled.as_text()))
+    print(f"  instructions named .remat: {len(clones)}", flush=True)
 
 
 def serve():
@@ -153,7 +160,9 @@ def serve():
 
 
 def cell(workload):
-    """A serving cell of the chip benchmark (``BENCHMARK.json``): its
+    """A cell of the chip benchmark (``BENCHMARK.json``).  A training
+    cell: the engine's train step, built as ``chipbench/train_cell.py``
+    builds it.  A serving cell: its
     decode program and its largest and smallest prefill bucket at the
     cell's own sizes, as ``ServingEngine`` dispatches them (a model that
     counts its dispatches or keeps per-slot state is told a prefill's real
@@ -161,6 +170,11 @@ def cell(workload):
     from chipbench import cells, sut
     loaded = cells.load_cell(workload)
     cfg, mix = loaded.config, loaded.mix
+    if mix["kind"] == "pretrain":
+        micro, gas, seq, config = train_step_account.step_of(loaded)
+        return train(
+            loaded.chips, micro, gas, seq, config=config, name=workload,
+            model=sut.build_model(loaded, **cfg["train"]["model"]))
     chip = SingleDeviceSharding(TOPOLOGY.devices[0])
     model = sut.build_model(loaded)
     params = jax.tree_util.tree_map(

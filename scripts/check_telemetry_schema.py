@@ -459,16 +459,18 @@ STEP_ATTR_GAUGES = (
 ATTR_STAGES = ("queue", "prefill", "migrate", "gap", "decode")
 
 # FROZEN vocabulary of the trainer's flash-attention plan — must stay
-# byte-identical to ``deepspeed_tpu.models.transformer.ATTN_PLAN`` under
-# the ``train/attn/`` prefix (the tier-1 test diffs the two).  Set once,
-# when the first batch shows the step's shapes: what the three flash
-# kernels of one optimizer step visit, mask, compute and need
-# (docs/telemetry.md).
+# byte-identical to ``deepspeed_tpu.models.transformer.ATTN_PLAN`` and
+# ``ATTN_SAVED`` under the ``train/attn/`` prefix (the tier-1 test diffs
+# the two).  Set once, when the first batch shows the step's shapes: what
+# the three flash kernels of one optimizer step visit, mask, compute and
+# need, and the bytes a layer keeps of one micro-batch's forward call for
+# its backward pass (docs/telemetry.md).
 TRAIN_ATTN_GAUGES = (
     "train/attn/tiles_visited",
     "train/attn/tiles_masked",
     "train/attn/pairs_visited",
     "train/attn/pairs_needed",
+    "train/attn/saved_residual_bytes",
 )
 
 EVENT_KINDS = tuple(SCHEMA)

@@ -98,7 +98,7 @@ def test_the_trainer_sets_the_flash_kernels_plan_as_gauges(tmp_path):
     import sys
 
     import deepspeed_tpu
-    from deepspeed_tpu.models.transformer import ATTN_PLAN
+    from deepspeed_tpu.models.transformer import ATTN_PLAN, ATTN_SAVED
     from deepspeed_tpu.monitor.telemetry import get_telemetry
     from deepspeed_tpu.ops.pallas.flash_attention import flash_plan
     from deepspeed_tpu.parallel import groups
@@ -130,8 +130,12 @@ def test_the_trainer_sets_the_flash_kernels_plan_as_gauges(tmp_path):
     assert window["pairs_needed"] < full["pairs_needed"] == 64 * 65 // 2
     stream = tmp_path / "plan" / "events.jsonl"
     events = [json.loads(line) for line in open(stream)]
+    # the toy's layers are checkpointed under ``dots_saveable``: a layer
+    # keeps a micro-batch's result (float32 here) and ``lse``
+    assert gauges["train/attn/" + ATTN_SAVED]["value"] == \
+        seqs * 64 * c.n_heads * (c.head_dim * 4 + 4)
     assert sum(e["kind"] == "gauge" and e["name"].startswith("train/attn/")
-               for e in events) == len(ATTN_PLAN)           # once, not a step
+               for e in events) == len(ATTN_PLAN) + 1       # once, not a step
     checker = os.path.join(os.path.dirname(__file__), "..", "..", "scripts",
                            "check_telemetry_schema.py")
     assert subprocess.run([sys.executable, checker, str(stream)]

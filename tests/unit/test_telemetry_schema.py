@@ -373,11 +373,12 @@ def test_rejects_unknown_step_attr_gauge(checker):
 
 def test_train_attn_gauges_in_lockstep(checker):
     """The trainer's flash-attention plan: ``ATTN_PLAN`` of the model file
-    under ``train/attn/`` is the checker's vocabulary, name for name."""
+    and ``ATTN_SAVED`` after it, under ``train/attn/``, are the checker's
+    vocabulary, name for name."""
     import time
-    from deepspeed_tpu.models.transformer import ATTN_PLAN
+    from deepspeed_tpu.models.transformer import ATTN_PLAN, ATTN_SAVED
     assert checker.TRAIN_ATTN_GAUGES == tuple(
-        "train/attn/" + name for name in ATTN_PLAN)
+        "train/attn/" + name for name in ATTN_PLAN + (ATTN_SAVED,))
     base = {"ts": time.time(), "kind": "gauge", "value": 1.0,
             "peak": 1.0}
     for name in checker.TRAIN_ATTN_GAUGES:
