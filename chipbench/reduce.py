@@ -7,9 +7,12 @@ What a TPU trace holds (looked at by hand, PERF.md §6): one plane per chip
 named ``/device:TPU:<n>`` whose line ``XLA Ops`` has one event per executed
 operation, named by its whole HLO text (``%fusion.3 = bf16[..] fusion(..)``);
 asynchronous operations (copies, collectives) span start to done on the
-line ``Async XLA Ops``.  Host threads are lines of the plane ``/host:CPU``;
-the benchmark's own ``jax.profiler.TraceAnnotation``s (``chipbench/...``)
-are events there, on the same clock to within about a millisecond.
+line ``Async XLA Ops``; the line ``XLA Modules`` has one event per executed
+PROGRAM, named by its jit (``jit_serve_prefill(<fingerprint>)``), that
+spans the program's operations.  Host threads are lines of the plane
+``/host:CPU``; the benchmark's own ``jax.profiler.TraceAnnotation``s
+(``chipbench/...``) are events there, on the same clock to within about a
+millisecond.
 """
 
 import glob
@@ -75,6 +78,11 @@ class Trace:
     ops: list = field(default_factory=list)        # DeviceLine per device
     async_ops: list = field(default_factory=list)  # DeviceLine per device
     annotations: list = field(default_factory=list)  # (name, start, end)
+    # the executed programs: a DeviceLine per device whose ``label`` is an
+    # index into ``module_names`` (the event's whole name, fingerprint and
+    # all); empty where the trace has no ``XLA Modules`` line
+    modules: list = field(default_factory=list)
+    module_names: list = field(default_factory=list)
 
     @property
     def window(self):
@@ -101,32 +109,40 @@ def load_xplane(path):
     from jax.profiler import ProfileData
     profile = ProfileData.from_file(path)
     trace = Trace()
-    index = {}
 
-    def line_arrays(line):
+    def new_op(text):
+        trace.labels.append(":".join(parse_op(text)))
+        trace.kinds.append(op_kind(text))
+
+    def line_arrays(line, index, new_label):
+        """A line's events; ``index`` maps an event's text to its label,
+        ``new_label(text)`` records a text met for the first time."""
         start, dur, label = [], [], []
         for event in line.events:
             text = event.name
-            i = index.get(text)
-            if i is None:
-                i = index[text] = len(trace.labels)
-                trace.labels.append(":".join(parse_op(text)))
-                trace.kinds.append(op_kind(text))
+            if text not in index:
+                index[text] = len(index)
+                new_label(text)
             start.append(event.start_ns)
             dur.append(event.duration_ns)
-            label.append(i)
+            label.append(index[text])
         return DeviceLine(np.asarray(start, np.float64),
                           np.asarray(dur, np.float64),
                           np.asarray(label, np.int64))
 
+    ops, modules = {}, {}       # text -> label: the two lists of labels
     for plane in profile.planes:
         if plane.name.startswith("/device:TPU:"):
             lines = {line.name: line for line in plane.lines}
             if "XLA Ops" in lines:
-                trace.ops.append(line_arrays(lines["XLA Ops"]))
+                trace.ops.append(line_arrays(lines["XLA Ops"], ops, new_op))
                 if "Async XLA Ops" in lines:
-                    trace.async_ops.append(
-                        line_arrays(lines["Async XLA Ops"]))
+                    trace.async_ops.append(line_arrays(
+                        lines["Async XLA Ops"], ops, new_op))
+                if "XLA Modules" in lines:
+                    trace.modules.append(line_arrays(
+                        lines["XLA Modules"], modules,
+                        trace.module_names.append))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for event in line.events:

@@ -4,26 +4,30 @@ serve loop that does not wait for every dispatch.
 ``scope_pct`` gives an operation to the dispatch launched last before it,
 which is right while each dispatch is fetched before the next is launched.
 The chunked policy launches a prefill chunk that is not sampled from
-(``head_rows`` 0) and never waits for it: the decode step goes out a
-millisecond later and the chunk's operations run on under the decode's
-launch time.  What always holds is the order: one device runs its
-programs one at a time in the order they were launched.  So the device's
-line is cut at the FIRST operation of each execution:
+(``head_rows`` 0) and never waits for it, and since the loop runs a decode
+step ahead a decode dispatch is left running too: a chunk is always
+launched behind the decode in flight and its operations run on under later
+launch times.  What always holds is the order: one device runs its
+programs one at a time in the order they were launched.  The device's
+``XLA Modules`` line (``reduce.Trace.modules``) has one event an executed
+program, named by its jit, that spans the program's operations, so:
 
-- a program's first operation is one instruction of its entry computation,
-  the same at every execution, and the trace keeps operations apart by
-  their whole text (a label a text), so that label marks the program;
-- it is learned from the run: where the dispatch before was waited for
-  (anything but an unsampled prefill) the device is idle at the launch,
-  and the first operation to start after it is this dispatch's; the
-  commonest such label over a program's dispatches is its marker (a
-  program launched only behind unwaited work has none: nothing is read);
-- execution ``i`` then starts at the first marker of its program from the
-  dispatch's launch on (less ``SLACK_NS`` for the two clocks) and after
-  the start of execution ``i - 1``, and runs to the start of the next.
+- the k-th event of the two serving programs from the window's first
+  traced dispatch on IS the k-th traced dispatch, and an operation belongs
+  to the event it starts in;
+- where the window's first traced dispatch lies on that line is a shift
+  the trace does not state (the profiler starts a step before the window's
+  first whole step, with that step's dispatches on the line): the least
+  shift at which every event is the dispatch's phase, starts no earlier
+  than the dispatch was launched (less ``SLACK_NS`` for the two clocks),
+  and each event name (a jit and the fingerprint of one compiled program:
+  a chunk shape's two prefill programs have two) goes with one program of
+  the dispatches and one only.
 
-Shared by the readers beside it; no ``read`` of its own."""
+A trace without that line reads nothing.  Shared by the readers beside it;
+no ``read`` of its own."""
 
+import re
 from dataclasses import dataclass
 
 import numpy as np
@@ -32,6 +36,9 @@ from chipbench import reduce
 from chipbench.reducers import program_spans
 
 SITES = {"prefill": "serve/prefill_fn", "decode": "serve/step_fn"}
+# the serve loop's two named jits (``inference/serving.py``), as the
+# ``XLA Modules`` line names their events: ``jit_serve_decode(<digits>)``
+MODULES = {"jit_serve_prefill": "prefill", "jit_serve_decode": "decode"}
 SLACK_NS = 1e6
 
 
@@ -40,13 +47,6 @@ def program_of(dispatch):
     prefill whether it takes the head (two programs a chunk shape)."""
     return (dispatch["phase"], dispatch["batch"], dispatch["tokens"],
             min(int(dispatch.get("head_rows", 1)), 1))
-
-
-def waited_for(dispatch):
-    """False for the one dispatch the serve loop launches and leaves: a
-    prefill chunk that nothing is sampled from."""
-    return not (dispatch["phase"] == "prefill"
-                and dispatch.get("head_rows", 1) == 0)
 
 
 @dataclass
@@ -62,28 +62,45 @@ class Executions:
     label: np.ndarray       # per operation: index into ``Trace.labels``
 
 
-def markers(dispatches, launched, start, end, label):
-    """{program: label of its first operation}, from the dispatches that
-    were launched at an idle device behind waited-for work."""
-    seen = {}
-    busy_until = np.maximum.accumulate(end) if len(end) else end
-    for i, d in enumerate(dispatches):
-        if i and not waited_for(dispatches[i - 1]):
+def serving_events(trace):
+    """(start, end, phase, name) of the first device's executions of the
+    two serving programs, in order of their start: arrays, ``phase`` a
+    key of ``SITES`` and ``name`` an index into ``trace.module_names``."""
+    line = trace.modules[0]
+    phase = np.asarray([MODULES.get(re.sub(r"\(\d+\)$", "", name), "")
+                        for name in trace.module_names])
+    order = np.argsort(line.start, kind="stable")
+    order = order[phase[line.label[order]] != ""]
+    start, name = line.start[order], line.label[order]
+    return start, start + line.dur[order], phase[name], name
+
+
+def shift_of(programs, launched, start, phase, name):
+    """The event that is the first dispatch: the least ``j`` at which
+    event ``j + i`` can be dispatch ``i`` for every ``i`` the line reaches
+    (the module docstring's three conditions); None where there is none
+    that covers half of the dispatches."""
+    phases = np.asarray([p[0] for p in programs])
+    for j in range(len(start)):
+        n = min(len(programs), len(start) - j)
+        if 2 * n < len(programs):
+            return None
+        if np.any(phase[j:j + n] != phases[:n]) or \
+                np.any(start[j:j + n] < launched[:n] - SLACK_NS):
             continue
-        k = int(np.searchsorted(start, launched[i], side="left"))
-        if k >= len(start) or (k and busy_until[k - 1] > launched[i]):
-            continue        # nothing after it, or the device was not idle
-        if i + 1 < len(launched) and start[k] >= launched[i + 1]:
-            continue        # this dispatch's operations are not in the trace
-        seen.setdefault(program_of(d), []).append(int(label[k]))
-    return {p: max(set(found), key=found.count) for p, found in seen.items()}
+        pairs = set(zip(programs[:n], name[j:j + n].tolist()))
+        if len({p for p, _ in pairs}) == len({m for _, m in pairs}) \
+                == len(pairs):
+            return j
+    return None
 
 
 def executions(run):
     """:class:`Executions` of a traced serving run, or None without a
-    trace, traced dispatches, a clock fit, or a marker for every program
-    the traced dispatches ran."""
-    if run.trace is None or not run.trace.ops or not run.traced_steps:
+    trace that has the ``XLA Modules`` line, traced dispatches, a clock
+    fit, or a place on that line where the dispatches fit."""
+    if run.trace is None or not run.trace.ops or not run.trace.modules \
+            or not run.traced_steps:
         return None
     offset = program_spans.clock_offset_ns(run)
     if offset is None:
@@ -93,29 +110,24 @@ def executions(run):
                         key=lambda d: d["t0_ns"])
     if not dispatches:
         return None
+    launched = np.asarray([d["t0_ns"] for d in dispatches]) + offset
+    ran_from, ran_to, phase, name = serving_events(run.trace)
+    shift = shift_of([program_of(d) for d in dispatches], launched,
+                     ran_from, phase, name)
+    if shift is None:
+        return None
+    ran_from, ran_to = (x[shift:shift + len(dispatches)]
+                        for x in (ran_from, ran_to))
     line = run.trace.ops[0]
     lo, hi = run.trace.window
     order = np.argsort(line.start, kind="stable")
     start, label = line.start[order], line.label[order]
-    end = start + line.dur[order]
-    launched = np.asarray([d["t0_ns"] for d in dispatches]) + offset
-    marker = markers(dispatches, launched, start, end, label)
-    if any(program_of(d) not in marker for d in dispatches):
-        return None
-    where = {p: np.flatnonzero(label == m) for p, m in marker.items()}
     first = np.full(len(dispatches), len(start))
-    after = 0
-    for i, d in enumerate(dispatches):
-        found = where[program_of(d)]
-        found = found[(found >= after)
-                      & (start[found] >= launched[i] - SLACK_NS)]
-        if not len(found):
-            break           # the trace ends before this dispatch ran
-        first[i] = found[0]
-        after = found[0] + 1
-    owner = np.searchsorted(first, np.arange(len(start)), side="right") - 1
+    first[:len(ran_from)] = np.searchsorted(start, ran_from, side="left")
+    owner = np.searchsorted(ran_from, start, side="right") - 1
+    owner[start >= ran_to[np.maximum(owner, 0)]] = -1   # another program's
     return Executions(dispatches, first, owner, np.clip(start, lo, hi),
-                      np.clip(end, lo, hi), label)
+                      np.clip(start + line.dur[order], lo, hi), label)
 
 
 def device_seconds(run, phase):

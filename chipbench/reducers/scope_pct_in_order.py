@@ -6,8 +6,8 @@ ran in by launch order, and its scope read from the table of THAT
 dispatch's program (``telemetry.op_scopes(site, arg_shapes)``; a chunk
 shape compiles two prefill programs, with the head and without, told
 apart by the shape of the rows the head runs on).  None where the program
-has no such table, no trace, no traced dispatch, or a program whose first
-operation could not be learned."""
+has no such table, no trace, no traced dispatch, or a trace on whose
+``XLA Modules`` line the dispatches cannot be found."""
 
 import numpy as np
 

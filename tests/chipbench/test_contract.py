@@ -204,6 +204,75 @@ def test_layer_metric_files_agree_with_the_entries(bench, root):
         assert not [layer for layer in layers if layer not in perf]
 
 
+# ---- the repo's own per-layer list, an entry at a time -----------------
+# One measurement an entry (chipbench/README.md, "A per-layer metric"):
+# what makes a measurement is the reader, its arguments, what it moves and
+# where it is filed.  Two entries that agree in all of it are copies; a
+# ``benchmark`` PR joins the second one's cells to the first one's list
+# (PR 53 joined 59 such entries into 20 and gave back 39 of 128 places).
+SPEC_KEY = ("reducer", "args", "moves", "unit", "better", "source", "layer")
+# The cells whose entries a ``benchmark`` PR has joined: a copy among THEIR
+# entries is a fault.  A later PR that brings a cell may edit no entry, so
+# it can only bring copies under new names; they pass here and wait for
+# the next ``benchmark`` PR, which joins them and adds the cell to this set.
+JOINED = {"train-pythia-1.4b-s2048", "serve-olmo2-1b-chat",
+          "serve-olmo2-1b-docbatch", "train-pythia-6.9b-fsdp4",
+          "serve-glm5-ep16-longctx", "serve-trinity-mini-ep8-mixedq",
+          "serve-kimi-k2-ep32-agentctx", "train-mellum2-12b-ep4-s8192",
+          "serve-granite4-h-micro-chatfull",
+          "serve-granite4-h-small-ep2-chatfull"}
+# A copy that has to stand: ``tests/unit/test_run_ahead.py`` (outside the
+# benchmark's paths, so no ``benchmark`` PR may edit it) holds
+# ``ahead_pct.docbatch`` to its name and its one cell, so the granite
+# cells' ``ahead_pct.chatfull`` could not join it (PERF.md section 7).
+PINNED = {"ahead_pct.docbatch"}
+FOLDER = os.path.join(REPO, "chipbench", "layer_metrics")
+OWN = _json(REPO, "BENCHMARK.json")
+OWN_FILES = sorted(f[:-5] for f in os.listdir(FOLDER))
+
+
+OWN_SPECS = {name: _json(FOLDER, name + ".json") for name in OWN_FILES}
+SPEC_KEYS = {name: json.dumps([spec.get(k) for k in SPEC_KEY],
+                              sort_keys=True)
+             for name, spec in OWN_SPECS.items()}
+
+
+@pytest.mark.parametrize("name", [m["name"] for m in OWN["per_layer"]])
+def test_an_entry_is_a_measurement_of_its_own_and_agrees_with_its_file(name):
+    """The entry has its file and the two agree field by field; the file
+    has a reader; every cell it lists is a cell of the benchmark, once,
+    in the benchmark's order; and no other entry of the ``JOINED`` cells
+    is the same reader with the same arguments moving the same metric
+    under the same layer."""
+    (entry,) = [m for m in OWN["per_layer"] if m["name"] == name]
+    spec = OWN_SPECS[name]
+    assert set(spec) == set(entry) | {"reducer", "args"}
+    assert {k: spec[k] for k in entry} == entry
+    assert os.path.exists(os.path.join(
+        REPO, "chipbench", "reducers", spec["reducer"] + ".py"))
+    cells = [w["name"] for w in OWN["workloads"]]
+    listed = entry.get("workloads", cells)
+    assert listed == [c for c in cells if c in listed] and listed
+    copies = [m["name"] for m in OWN["per_layer"]
+              if SPEC_KEYS[m["name"]] == SPEC_KEYS[name]
+              and JOINED & set(m.get("workloads", cells))
+              and m["name"] not in PINNED - {name}]
+    assert copies == [name] or not JOINED & set(listed) or name in PINNED
+
+
+@pytest.mark.parametrize("name", OWN_FILES)
+def test_a_layer_metric_file_has_its_entry(name):
+    assert [m["name"] for m in OWN["per_layer"]].count(name) == 1
+    assert OWN_SPECS[name]["name"] == name
+
+
+def test_the_per_layer_list_keeps_room():
+    """The driver admits 128 entries and refuses the 129th before any
+    run; what stands is the count after PR 53's joins, and later PRs'
+    additions show here."""
+    assert len(OWN["per_layer"]) == len(OWN_FILES) <= 128
+
+
 @pytest.mark.parametrize("key", list(COUNTS) + WIDTHS)
 def test_reduced_may_name_a_count_and_never_a_width(key):
     """Each key alone in ``reduced``, held at half of what is published

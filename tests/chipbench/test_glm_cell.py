@@ -5,10 +5,11 @@ and run untraced and traced; the repo's own configuration is held to the
 published widths and to the arithmetic of its cut.
 
 The cell itself (``serve-glm5-ep16-longctx``) is in ``BENCHMARK.json``
-with its thirteen ``.longctx`` metrics; its entries are held to ISSUE 32's
-list here.  The last tests repeat, at toy size, why the configuration
-seeds its embeddings at unit spread (PERF.md section 6): under the old
-spread two forwards that are not bit-equal select other keys and the
+with its thirteen metrics (five under ``.longctx``, eight through the
+``.serve`` lists it shares); its entries are held to ISSUE 32's list
+here.  The last tests repeat, at toy size, why the configuration seeds its
+embeddings at unit spread (PERF.md section 6): under the old spread two
+forwards that are not bit-equal select other keys and the
 check cannot judge the model."""
 
 import json
@@ -32,38 +33,10 @@ METRICS = os.path.join(cells.ROOT, "chipbench", "layer_metrics")
 @pytest.fixture(scope="module")
 def checkout(tmp_path_factory):
     """``tree.make``'s benchmark plus one cell: ``tiny-glm`` under
-    ``tiny-closed``, reading the ``.longctx`` metrics through files of
-    its own."""
-    tmp = tree.make(tmp_path_factory.mktemp("glm_tree"))
-    held = tree.data("tiny-glm")
-    with open(os.path.join(tmp, "chipbench", "configs", "tiny-glm.json"),
-              "w") as f:
-        json.dump(held, f)
-    with open(os.path.join(tmp, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    folder = os.path.join(tmp, "chipbench", "layer_metrics")
-    bench["configs"].append({
-        "name": "tiny-glm", "source": held["source"],
-        "file": "chipbench/configs/tiny-glm.json",
-        "reduced": held["reduced"], "why": "toy width"})
-    bench["workloads"].append({
-        "name": "tiny-glm", "config": "tiny-glm", "traffic": "tiny-closed",
-        "chips": 1, "why": "made up for the tests"})
-    for metric in bench["end_to_end"]:
-        if CELL in metric.get("workloads", ()):
-            metric["workloads"].append("tiny-glm")
-    for name in LONGCTX:
-        with open(os.path.join(METRICS, name + ".longctx.json")) as f:
-            spec = dict(json.load(f), name=f"tiny-glm.{name}",
-                        workloads=["tiny-glm"])
-        with open(os.path.join(folder, spec["name"] + ".json"), "w") as f:
-            json.dump(spec, f)
-        bench["per_layer"].append({k: spec[k] for k in (
-            "name", "unit", "better", "source", "layer", "moves",
-            "workloads")})
-    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
-    return tmp
+    ``tiny-closed``, reading what the cell reads through files of its
+    own."""
+    return tree.add_cell(tree.make(tmp_path_factory.mktemp("glm_tree")),
+                         "tiny-glm", CELL, "tiny-closed")
 
 
 def test_the_toy_cell_runs_and_is_correct(checkout):
@@ -128,13 +101,14 @@ def test_the_configuration_is_the_published_widths_and_the_stated_cut():
 def test_the_benchmark_gains_what_the_issue_lists_and_no_more():
     """One configuration, one cell on one chip, its name under
     ``serve_tok_s`` alone, thirteen metrics whose files agree with their
-    entries and whose readers exist, all at the end of their lists."""
-    with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    config, cell = bench["configs"][-1], bench["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
-        == (CELL, config["name"], "longctx-closed", 1)
-    assert [w["name"] for w in bench["workloads"]].count(CELL) == 1
+    entries and whose readers exist: found by NAME and by the cell's
+    membership of ``workloads``, wherever later PRs' additions and a
+    ``benchmark`` PR's joins (``compiles.serve``) have put them."""
+    bench = tree.bench()
+    (config,) = [c for c in bench["configs"] if c["name"] == "glm-5-ep16"]
+    (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) \
+        == (config["name"], "longctx-closed", 1)
     assert len(cell["why"]) <= 200 and len(config["why"]) <= 200
     assert [m["name"] for m in bench["end_to_end"]
             if CELL in m.get("workloads", ())] == ["serve_tok_s"]
@@ -142,23 +116,11 @@ def test_the_benchmark_gains_what_the_issue_lists_and_no_more():
         held = json.load(f)
     assert held["reduced"] == config["reduced"] == list(held["published"])
     assert held["source"] == config["source"]
-    entries = bench["per_layer"][-len(LONGCTX):]
-    assert {m["name"] for m in entries} == \
-        {name + ".longctx" for name in LONGCTX} == \
+    entries = tree.held_entries(CELL, moves="serve_tok_s")
+    assert sorted(tree.base(m["name"]) for m in entries) == sorted(LONGCTX)
+    # what only this cell reads keeps its ending
+    assert {m["name"] for m in entries if m["workloads"] == [CELL]} == \
         {f[:-5] for f in os.listdir(METRICS) if f.endswith(".longctx.json")}
-    assert not any(CELL in m.get("workloads", ())
-                   for m in bench["per_layer"][:-len(LONGCTX)])
-    with open(os.path.join(cells.ROOT, "PERF.md")) as f:
-        perf = f.read()
-    for entry in entries:
-        with open(os.path.join(METRICS, entry["name"] + ".json")) as f:
-            spec = json.load(f)
-        assert {k: spec[k] for k in entry} == entry
-        assert entry["moves"] == "serve_tok_s"
-        assert entry["workloads"] == [CELL]
-        assert os.path.exists(os.path.join(
-            cells.ROOT, "chipbench", "reducers", spec["reducer"] + ".py"))
-        assert entry["layer"] in perf
 
 
 def test_scope_shares_give_each_operation_to_its_dispatchs_program(

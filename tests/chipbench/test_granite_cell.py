@@ -52,39 +52,10 @@ def _sizes():
 @pytest.fixture(scope="module")
 def checkout(tmp_path_factory):
     """``tree.make``'s benchmark plus one cell: ``tiny-granite`` under
-    ``tiny-closed``, reading the ``.chatfull`` metrics through files of
-    its own."""
-    tmp = tree.make(tmp_path_factory.mktemp("granite_tree"))
-    held = tree.data("tiny-granite")
-    with open(os.path.join(tmp, "chipbench", "configs", "tiny-granite.json"),
-              "w") as f:
-        json.dump(held, f)
-    with open(os.path.join(tmp, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    folder = os.path.join(tmp, "chipbench", "layer_metrics")
-    bench["configs"].append({
-        "name": "tiny-granite", "source": held["source"],
-        "file": "chipbench/configs/tiny-granite.json",
-        "reduced": held["reduced"], "why": "toy width"})
-    bench["workloads"].append({
-        "name": "tiny-granite", "config": "tiny-granite",
-        "traffic": "tiny-closed", "chips": 1,
-        "why": "made up for the tests"})
-    for metric in bench["end_to_end"]:
-        if CELL in metric.get("workloads", ()):
-            metric["workloads"].append("tiny-granite")
-    for name in CHATFULL:
-        with open(os.path.join(METRICS, name + ".chatfull.json")) as f:
-            spec = dict(json.load(f), name=f"tiny-granite.{name}",
-                        workloads=["tiny-granite"])
-        with open(os.path.join(folder, spec["name"] + ".json"), "w") as f:
-            json.dump(spec, f)
-        bench["per_layer"].append({k: spec[k] for k in (
-            "name", "unit", "better", "source", "layer", "moves",
-            "workloads")})
-    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
-    return tmp
+    ``tiny-closed``, reading what the cell reads through files of its
+    own."""
+    return tree.add_cell(tree.make(tmp_path_factory.mktemp("granite_tree")),
+                         "tiny-granite", CELL, "tiny-closed")
 
 
 def test_the_toy_cell_runs_and_is_correct(checkout):
@@ -237,10 +208,10 @@ def test_the_configuration_is_the_published_widths_with_nothing_cut():
 
 def test_the_cells_entries_are_what_its_issue_listed():
     """One configuration, one cell on one chip, its name under
-    ``serve_tok_s`` alone, and the metrics of its ending, one block of
-    ``per_layer`` whose files agree with their entries and whose readers
-    exist; no other metric lists the cell.  Found BY NAME, wherever a
-    later PR's additions put the end of the lists."""
+    ``serve_tok_s`` alone, and the fifteen metrics that list it, whose
+    files agree with their entries and whose readers exist.  Found by the
+    cell's membership of ``workloads``, wherever a later PR's additions
+    put the end of the lists."""
     with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]
@@ -255,25 +226,13 @@ def test_the_cells_entries_are_what_its_issue_listed():
     assert held["reduced"] == config["reduced"] == []
     assert held["source"] == config["source"]
     assert len(bench["per_layer"]) <= 128        # the driver's contract
-    at = [i for i, m in enumerate(bench["per_layer"])
-          if CELL in m.get("workloads", ())]
-    assert at == list(range(at[0], at[0] + len(at)))      # one block
-    entries = bench["per_layer"][at[0]:at[-1] + 1]
-    assert [m["name"] for m in entries] == \
-        [name + ".chatfull" for name in CHATFULL]
-    assert {m["name"] for m in entries} == \
+    entries = tree.held_entries(CELL, moves="serve_tok_s")
+    assert sorted(tree.base(m["name"]) for m in entries) == sorted(CHATFULL)
+    # what reads this family's own scopes and sizes keeps its ending (the
+    # routed granite's cell joined those lists, PR 53); the rest the cell
+    # reads through the ``.serve`` lists it shares
+    assert {m["name"] for m in entries if ".chatfull" in m["name"]} == \
         {f[:-5] for f in os.listdir(METRICS) if f.endswith(".chatfull.json")}
-    with open(os.path.join(cells.ROOT, "PERF.md")) as f:
-        perf = f.read()
-    for entry in entries:
-        with open(os.path.join(METRICS, entry["name"] + ".json")) as f:
-            spec = json.load(f)
-        assert {k: spec[k] for k in entry} == entry
-        assert entry["moves"] == "serve_tok_s"
-        assert entry["workloads"] == [CELL]
-        assert os.path.exists(os.path.join(
-            cells.ROOT, "chipbench", "reducers", spec["reducer"] + ".py"))
-        assert entry["layer"] in perf
     # the shares of device time read by launch TIME (``scope_pct``), which
     # reads under the monolithic policy with the run-ahead
     scopes = {name: json.load(open(os.path.join(

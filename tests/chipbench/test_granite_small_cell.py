@@ -25,6 +25,12 @@ from test_contract import share_faults
 
 CELL = "serve-granite4-h-small-ep2-chatfull"
 CONFIG = "granite-4.0-h-small-ep2"
+# the entries of other cells whose lists this cell joined with PR 53: one
+# traced run on the chip read each at PR 51's reading (PERF.md section 5)
+JOINED = ["compiles", "idle_pct", "loop_host_ms", "prefill_pad_pct",
+          "decode_ms", "prefill_ms_per_ktok", "ahead_pct", "experts_pct",
+          "ragged_pct", "ssm_scan_pct", "ssm_conv_pct", "ssm_proj_pct",
+          "state_live_pct", "decode_hbm_pct", "ragged_roofline"]
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 TOY = tree.data("tiny-granite-routed")
 
@@ -310,8 +316,9 @@ def test_the_configuration_is_the_published_widths_and_a_stated_share():
 def test_the_benchmark_holds_the_cells_entries_by_name():
     """One configuration, one cell on one chip under the micro's mix, its
     name under ``serve_tok_s`` alone, ONE per-layer metric whose file
-    agrees with its entry: found by name, wherever later PRs' additions
-    put the end of the lists."""
+    agrees with its entry, beside the lists of other cells' entries it
+    joined: found by the cell's membership of ``workloads``, wherever later
+    PRs' additions put the end of the lists."""
     bench = _bench()
     (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]
     (config,) = [c for c in bench["configs"] if c["name"] == CONFIG]
@@ -324,10 +331,13 @@ def test_the_benchmark_holds_the_cells_entries_by_name():
     assert config["file"] == f"chipbench/configs/{CONFIG}.json"
     assert [m["name"] for m in bench["end_to_end"]
             if CELL in m.get("workloads", ())] == ["serve_tok_s"]
-    (entry,) = [m for m in bench["per_layer"]
-                if CELL in m.get("workloads", ())]
-    assert entry["name"] == "mfu_pct.chatmoe" and \
-        entry["moves"] == "serve_tok_s" and entry["workloads"] == [CELL]
+    entries = tree.held_entries(CELL, moves="serve_tok_s")
+    # its own entry, and the lists it joined (PR 53): each an entry whose
+    # reducer and arguments hold for this cell as they stand
+    (entry,) = [m for m in entries if m["workloads"] == [CELL]]
+    assert entry["name"] == "mfu_pct.chatmoe"
+    assert sorted(tree.base(m["name"]) for m in entries
+                  if m is not entry) == sorted(JOINED)
     with open(os.path.join(cells.ROOT, "chipbench", "layer_metrics",
                            "mfu_pct.chatmoe.json")) as f:
         spec = json.load(f)

@@ -21,14 +21,16 @@ CELL = "serve-kimi-k2-ep32-agentctx"
 AGENTCTX = ["latent_attn_pct", "experts_pct", "expert_load_ratio",
             "decode_ms", "prefill_ms_per_ktok", "prefill_pad_pct",
             "prefill_share_pct", "loop_host_ms", "idle_pct", "compiles",
-            "peak_hbm_gb", "latent_ctx_pct", "ctx_reread", "mfu_pct"]
+            "peak_hbm_gb", "ctx_reread", "mfu_pct"]
+# ``latent_ctx_pct`` left with PR 53: since PR 45 the dense prefill is one
+# Pallas kernel and no program of the cell has a ``latent_ctx`` scope
 # what a CPU run can read: no device plane in its trace and no memory
 # statistics, so what is read from the device's line (the shares of its
 # time by scope, a chunk's device time: ``reducers/launch_order.py``) and
 # the peak are left out, as on a program without the scopes
 ON_THE_CPU = [n for n in AGENTCTX
               if n not in ("latent_attn_pct", "experts_pct",
-                           "latent_ctx_pct", "prefill_ms_per_ktok",
+                           "prefill_ms_per_ktok",
                            "prefill_share_pct", "idle_pct", "peak_hbm_gb")]
 METRICS = os.path.join(cells.ROOT, "chipbench", "layer_metrics")
 
@@ -47,38 +49,10 @@ def _bench():
 @pytest.fixture(scope="module")
 def checkout(tmp_path_factory):
     """``tree.make``'s benchmark plus one cell: ``tiny-kimi`` under
-    ``tiny-closed``, reading the ``.agentctx`` metrics through files of
-    its own."""
-    tmp = tree.make(tmp_path_factory.mktemp("kimi_tree"))
-    held = tree.data("tiny-kimi")
-    with open(os.path.join(tmp, "chipbench", "configs", "tiny-kimi.json"),
-              "w") as f:
-        json.dump(held, f)
-    with open(os.path.join(tmp, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    folder = os.path.join(tmp, "chipbench", "layer_metrics")
-    bench["configs"].append({
-        "name": "tiny-kimi", "source": held["source"],
-        "file": "chipbench/configs/tiny-kimi.json",
-        "reduced": held["reduced"], "why": "toy width"})
-    bench["workloads"].append({
-        "name": "tiny-kimi", "config": "tiny-kimi", "traffic": "tiny-closed",
-        "chips": 1, "why": "made up for the tests"})
-    for metric in bench["end_to_end"]:
-        if CELL in metric.get("workloads", ()):
-            metric["workloads"].append("tiny-kimi")
-    for name in AGENTCTX:
-        with open(os.path.join(METRICS, name + ".agentctx.json")) as f:
-            spec = dict(json.load(f), name=f"tiny-kimi.{name}",
-                        workloads=["tiny-kimi"])
-        with open(os.path.join(folder, spec["name"] + ".json"), "w") as f:
-            json.dump(spec, f)
-        bench["per_layer"].append({k: spec[k] for k in (
-            "name", "unit", "better", "source", "layer", "moves",
-            "workloads")})
-    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
-    return tmp
+    ``tiny-closed``, reading what the cell reads through files of its
+    own."""
+    return tree.add_cell(tree.make(tmp_path_factory.mktemp("kimi_tree")),
+                         "tiny-kimi", CELL, "tiny-closed")
 
 
 def test_the_toy_cell_runs_chunked_and_is_correct(checkout):
@@ -101,7 +75,7 @@ def test_the_traced_toy_run_reads_every_agentctx_metric(checkout):
     metrics = {k: v["value"] for k, v in line["metrics"].items()}
     for name in ON_THE_CPU:
         assert isinstance(metrics[f"tiny-kimi.{name}"], float), name
-    assert "tiny-kimi.latent_ctx_pct" not in metrics
+    assert "tiny-kimi.latent_attn_pct" not in metrics
     # the toy's key blocks (512) are wider than its prompts: every chunk
     # after a prompt's first walks one whole block, so 0 < re-read
     assert 0 < metrics["tiny-kimi.ctx_reread"] < 512 / 20
@@ -188,9 +162,10 @@ def test_the_file_states_its_cut_and_keeps_every_published_width():
 
 def test_the_benchmark_contains_what_the_issue_lists():
     """One configuration, one cell on one chip, its name under
-    ``serve_tok_s`` alone, fourteen ``.agentctx`` metrics whose files agree
-    with their entries and whose readers exist.  CONTAINS, not ends with:
-    a later PR appends after these."""
+    ``serve_tok_s`` alone, thirteen metrics (fourteen less
+    ``latent_ctx_pct``, PR 53) whose files agree with their entries and
+    whose readers exist.  Found by the cell's membership of ``workloads``:
+    a later PR appends after these and a ``benchmark`` PR joins lists."""
     bench = _bench()
     config, = [c for c in bench["configs"] if c["name"] == "kimi-k2-ep32"]
     cell, = [w for w in bench["workloads"] if w["name"] == CELL]
@@ -204,35 +179,26 @@ def test_the_benchmark_contains_what_the_issue_lists():
     assert held["reduced"] == config["reduced"]
     assert held["source"] == config["source"]
     assert config["file"] == "chipbench/configs/kimi-k2-ep32.json"
-    entries = {m["name"]: m for m in bench["per_layer"]
-               if CELL in m.get("workloads", ())}
-    assert set(entries) == {name + ".agentctx" for name in AGENTCTX} == \
+    entries = {tree.base(m["name"]): m
+               for m in tree.held_entries(CELL, moves="serve_tok_s")}
+    assert sorted(entries) == sorted(AGENTCTX)
+    # what only this cell reads keeps its ending; the rest it reads
+    # through the ``.serve`` lists it shares
+    assert {m["name"] for m in entries.values()
+            if m["workloads"] == [CELL]} == \
         {f[:-5] for f in os.listdir(METRICS) if f.endswith(".agentctx.json")}
-    with open(os.path.join(cells.ROOT, "PERF.md")) as f:
-        perf = f.read()
-    for entry in entries.values():
-        with open(os.path.join(METRICS, entry["name"] + ".json")) as f:
-            spec = json.load(f)
-        assert {k: spec[k] for k in entry} == entry
-        assert entry["moves"] == "serve_tok_s"
-        assert entry["workloads"] == [CELL]
-        assert os.path.exists(os.path.join(
-            cells.ROOT, "chipbench", "reducers", spec["reducer"] + ".py"))
-        assert entry["layer"] in perf
-    # the three that read what ISSUE 41 adds to the program
-    assert entries["latent_ctx_pct.agentctx"]["source"] == "device_trace"
     # under the chunked policy a chunk is launched and left: what is read
     # of the device's line is cut by launch order, not by launch time, and
     # a chunk's cost is its device time, not its ``serve/prefill`` span
-    for name, reducer in (("latent_ctx_pct", "scope_pct_in_order"),
-                          ("latent_attn_pct", "scope_pct_in_order"),
+    for name, reducer in (("latent_attn_pct", "scope_pct_in_order"),
                           ("experts_pct", "scope_pct_in_order"),
                           ("prefill_ms_per_ktok", "phase_device_ms_per_ktok"),
                           ("prefill_share_pct", "phase_device_share_pct")):
+        assert entries[name]["name"] == name + ".agentctx"
         with open(os.path.join(METRICS, name + ".agentctx.json")) as f:
             assert json.load(f)["reducer"] == reducer
-        assert entries[name + ".agentctx"]["source"] == "device_trace"
-    assert entries["ctx_reread.agentctx"]["source"] == "program_counter"
+        assert entries[name]["source"] == "device_trace"
+    assert entries["ctx_reread"]["source"] == "program_counter"
     # no four-chip cell was added
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 

@@ -51,39 +51,10 @@ def _bench():
 @pytest.fixture(scope="module")
 def checkout(tmp_path_factory):
     """``tree.make``'s benchmark plus one cell: ``tiny-mellum`` under
-    ``tiny-pretrain``, reading the ``.moetrain`` metrics through files of
-    its own."""
-    tmp = tree.make(tmp_path_factory.mktemp("mellum_tree"))
-    held = tree.data("tiny-mellum")
-    with open(os.path.join(tmp, "chipbench", "configs", "tiny-mellum.json"),
-              "w") as f:
-        json.dump(held, f)
-    with open(os.path.join(tmp, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    folder = os.path.join(tmp, "chipbench", "layer_metrics")
-    bench["configs"].append({
-        "name": "tiny-mellum", "source": held["source"],
-        "file": "chipbench/configs/tiny-mellum.json",
-        "reduced": held["reduced"], "why": "toy width"})
-    bench["workloads"].append({
-        "name": "tiny-mellum", "config": "tiny-mellum",
-        "traffic": "tiny-pretrain", "chips": 1,
-        "why": "made up for the tests"})
-    for metric in bench["end_to_end"]:
-        if CELL in metric.get("workloads", ()):
-            metric["workloads"].append("tiny-mellum")
-    for name in MOETRAIN:
-        with open(os.path.join(METRICS, name + ".moetrain.json")) as f:
-            spec = dict(json.load(f), name=f"tiny-mellum.{name}",
-                        workloads=["tiny-mellum"])
-        with open(os.path.join(folder, spec["name"] + ".json"), "w") as f:
-            json.dump(spec, f)
-        bench["per_layer"].append({k: spec[k] for k in (
-            "name", "unit", "better", "source", "layer", "moves",
-            "workloads")})
-    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
-    return tmp
+    ``tiny-pretrain``, reading what the cell reads through files of its
+    own."""
+    return tree.add_cell(tree.make(tmp_path_factory.mktemp("mellum_tree")),
+                         "tiny-mellum", CELL, "tiny-pretrain")
 
 
 def test_the_toy_cell_trains_and_is_correct(checkout):
@@ -176,14 +147,16 @@ def test_the_benchmark_holds_what_the_issue_lists():
     moved = next(m for m in bench["end_to_end"]
                  if m["name"] == "train_tok_s_chip")
     assert CELL in moved["workloads"]
-    entries = {m["name"]: m for m in bench["per_layer"]}
-    for name in MOETRAIN:
-        entry = entries[name + ".moetrain"]
-        assert entry["workloads"] == [CELL] \
-            and entry["moves"] == "train_tok_s_chip"
+    # ten of the twenty-one the cell reads through the ``.train`` entries
+    # it joined (PR 53: same reducer, same arguments); what needs this
+    # family's own costs and scopes keeps the ending ``.moetrain``
+    entries = tree.held_entries(CELL, moves="train_tok_s_chip")
+    assert sorted(tree.base(m["name"]) for m in entries) == sorted(MOETRAIN)
+    assert {m["name"] for m in entries if m["workloads"] == [CELL]} == \
+        {f[:-5] for f in os.listdir(METRICS) if f.endswith(".moetrain.json")}
     loaded = cells.load_cell(CELL)
     assert {m["name"] for m in loaded.per_layer} >= {
-        n + ".moetrain" for n in MOETRAIN}
+        m["name"] for m in entries}
 
 
 def test_the_costs_against_operations_and_bytes_counted_by_hand():

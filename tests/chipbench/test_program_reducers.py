@@ -269,8 +269,8 @@ def test_traced_toy_serving_run_reports_the_span_metrics(checkout):
     # the host's own work is part of the step the benchmark times
     assert metrics["tiny-chat.loop_host_ms.chat"]["value"] < \
         metrics["tiny-chat.step_ms.chat"]["value"]
-    # no device plane in a CPU trace: the idle readers find nothing
-    assert "tiny-chat.idle_sample_pct.chat" not in metrics
+    # no device plane in a CPU trace: the device's readers find nothing
+    assert "tiny-chat.ragged_pct.chat" not in metrics
     assert "tiny-chat.idle_pct.chat" not in metrics
 
 

@@ -45,6 +45,25 @@ def test_busy_union_and_idle_share(trace):
     assert 0 < busy < window
 
 
+def test_the_modules_line_has_one_event_an_executed_program(trace):
+    """Three rounds of three jits: nine events under three names, each
+    spanning operations of ``XLA Ops`` and none overlapping the next
+    (``reducers/launch_order.py`` cuts the device's line by them)."""
+    (line,) = trace.modules
+    assert [n.partition("(")[0] for n in trace.module_names] == \
+        ["jit__lambda"] * 3 and len(set(trace.module_names)) == 3
+    assert list(line.label) == [0, 1, 2] * 3
+    start, end = line.start, line.start + line.dur
+    assert np.all(start[1:] >= end[:-1])
+    ops = trace.ops[0]
+    inside = (ops.start[:, None] >= start) & (ops.start[:, None] < end)
+    assert np.all(inside.sum(axis=1) == 1)      # every operation in one
+    assert np.all(inside.sum(axis=0) > 0)
+    # the operations' labels are not the programs': two lists
+    assert len(trace.labels) == 45 and not any(
+        "jit_" in label for label in trace.labels)
+
+
 def test_per_kernel_time(trace):
     kernels = reduce.op_seconds(trace, "pallas")
     # forward, dq and dk/dv of flash attention, and the ragged decode call

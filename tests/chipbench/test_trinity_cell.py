@@ -38,39 +38,10 @@ def _config():
 @pytest.fixture(scope="module")
 def checkout(tmp_path_factory):
     """``tree.make``'s benchmark plus one cell: ``tiny-trinity`` under
-    ``tiny-closed``, reading the ``.mixedq`` metrics through files of its
+    ``tiny-closed``, reading what the cell reads through files of its
     own."""
-    tmp = tree.make(tmp_path_factory.mktemp("trinity_tree"))
-    held = tree.data("tiny-trinity")
-    with open(os.path.join(tmp, "chipbench", "configs", "tiny-trinity.json"),
-              "w") as f:
-        json.dump(held, f)
-    with open(os.path.join(tmp, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    folder = os.path.join(tmp, "chipbench", "layer_metrics")
-    bench["configs"].append({
-        "name": "tiny-trinity", "source": held["source"],
-        "file": "chipbench/configs/tiny-trinity.json",
-        "reduced": held["reduced"], "why": "toy width"})
-    bench["workloads"].append({
-        "name": "tiny-trinity", "config": "tiny-trinity",
-        "traffic": "tiny-closed", "chips": 1,
-        "why": "made up for the tests"})
-    for metric in bench["end_to_end"]:
-        if CELL in metric.get("workloads", ()):
-            metric["workloads"].append("tiny-trinity")
-    for name in MIXEDQ:
-        with open(os.path.join(METRICS, name + ".mixedq.json")) as f:
-            spec = dict(json.load(f), name=f"tiny-trinity.{name}",
-                        workloads=["tiny-trinity"])
-        with open(os.path.join(folder, spec["name"] + ".json"), "w") as f:
-            json.dump(spec, f)
-        bench["per_layer"].append({k: spec[k] for k in (
-            "name", "unit", "better", "source", "layer", "moves",
-            "workloads")})
-    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
-    return tmp
+    return tree.add_cell(tree.make(tmp_path_factory.mktemp("trinity_tree")),
+                         "tiny-trinity", CELL, "tiny-closed")
 
 
 def test_the_toy_cell_runs_and_is_correct(checkout):
@@ -169,27 +140,26 @@ def test_the_configuration_is_the_published_widths_and_the_stated_cut():
     assert cfg["num_hidden_layers"] % cfg["global_attn_every_n_layers"] == 0
 
 
-# cell -> (configuration, traffic, the ending of its per-layer metrics).
-# By NAME, wherever the entries stand: a later PR appends to the same lists
-# (``test_glm_cell.py::test_the_benchmark_gains_what_the_issue_lists_and_no_
-# more`` held PR 32's entries to the END of them, which no later addition
-# at the end can leave true: it fails since this cell, and making it find
-# its entries by name is a ``benchmark`` PR's edit, PERF.md section 7 (ix))
+# cell -> (configuration, traffic, the ending of the metrics only it
+# reads, how many list it).  By the cell's membership of ``workloads``,
+# wherever the entries stand: a later PR appends to the same lists and a
+# ``benchmark`` PR joins a cell to an entry that already reads what it
+# needs (PR 53: ``compiles.serve``, ``decode_ms.serve``, ...)
 CELL_ENTRIES = {
-    "serve-glm5-ep16-longctx": ("glm-5-ep16", "longctx-closed", ".longctx"),
-    CELL: ("trinity-mini-ep8", "mixedq-closed", ".mixedq"),
+    "serve-glm5-ep16-longctx": ("glm-5-ep16", "longctx-closed", ".longctx",
+                                13),
+    CELL: ("trinity-mini-ep8", "mixedq-closed", ".mixedq", len(MIXEDQ)),
 }
 
 
 @pytest.mark.parametrize("name", list(CELL_ENTRIES))
 def test_a_cells_entries_are_what_its_issue_listed(name):
     """One configuration, one cell on one chip, its name under
-    ``serve_tok_s`` alone, and the metrics of its ending, one block of
-    ``per_layer`` whose files agree with their entries and whose readers
-    exist; no other metric lists the cell."""
-    config_name, mix, ending = CELL_ENTRIES[name]
-    with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    ``serve_tok_s`` alone, and the metrics that list it, whose files
+    agree with their entries and whose readers exist; those that list it
+    alone are the files of its ending."""
+    config_name, mix, ending, count = CELL_ENTRIES[name]
+    bench = tree.bench()
     (cell,) = [w for w in bench["workloads"] if w["name"] == name]
     (config,) = [c for c in bench["configs"] if c["name"] == config_name]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
@@ -201,23 +171,10 @@ def test_a_cells_entries_are_what_its_issue_listed(name):
         held = json.load(f)
     assert held["reduced"] == config["reduced"] == list(held["published"])
     assert held["source"] == config["source"]
-    at = [i for i, m in enumerate(bench["per_layer"])
-          if name in m.get("workloads", ())]
-    assert at == list(range(at[0], at[0] + len(at)))      # one block
-    entries = bench["per_layer"][at[0]:at[-1] + 1]
-    assert {m["name"] for m in entries} == \
+    entries = tree.held_entries(name, moves="serve_tok_s")
+    assert len(entries) == count
+    assert {m["name"] for m in entries if m["workloads"] == [name]} == \
         {f[:-5] for f in os.listdir(METRICS) if f.endswith(ending + ".json")}
-    with open(os.path.join(cells.ROOT, "PERF.md")) as f:
-        perf = f.read()
-    for entry in entries:
-        with open(os.path.join(METRICS, entry["name"] + ".json")) as f:
-            spec = json.load(f)
-        assert {k: spec[k] for k in entry} == entry
-        assert entry["moves"] == "serve_tok_s"
-        assert entry["workloads"] == [name]
-        assert os.path.exists(os.path.join(
-            cells.ROOT, "chipbench", "reducers", spec["reducer"] + ".py"))
-        assert entry["layer"] in perf
 
 
 def test_the_control_rounds_in_place_and_reads_what_the_control_reads():
@@ -234,8 +191,9 @@ def test_the_control_rounds_in_place_and_reads_what_the_control_reads():
 
 
 def test_this_cells_metrics_are_the_issues_fifteen():
-    assert {name + ".mixedq" for name in MIXEDQ} == \
-        {f[:-5] for f in os.listdir(METRICS) if f.endswith(".mixedq.json")}
+    assert sorted(tree.base(m["name"])
+                  for m in tree.entries_of(tree.bench(), CELL)) == \
+        sorted(MIXEDQ)
 
 
 def _sizes():

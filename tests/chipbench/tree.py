@@ -13,6 +13,7 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 DATA = os.path.join(HERE, "data")
+METRICS = os.path.join(REPO, "chipbench", "layer_metrics")
 
 CELLS = [   # name, configuration, traffic, a real cell whose metrics it takes
     ("tiny-train", "tiny-neox", "tiny-pretrain", "train-pythia-1.4b-s2048"),
@@ -128,6 +129,76 @@ def _json(*parts):
 def data(name):
     """A toy configuration or traffic mix of ``data/``."""
     return _json(DATA, name + ".json")
+
+
+def bench(root=REPO):
+    return _json(root, "BENCHMARK.json")
+
+
+def entries_of(bench, cell):
+    """The per-layer entries that list ``cell``, in their order.  What a
+    cell reports is asked of the lists and never of a name's ending: a
+    ``benchmark`` PR joins a cell to the entry that already reads what it
+    needs (``compiles.serve`` lists six cells), and every PR appends."""
+    return [m for m in bench["per_layer"] if cell in m.get("workloads", ())]
+
+
+def base(name):
+    """``decode_ms`` of ``decode_ms.serve``: a metric's name less the
+    ending that says whose it is."""
+    return name.rpartition(".")[0]
+
+
+def held_entries(cell, moves):
+    """``entries_of`` the repo's own ``cell``, each held to its file, its
+    reader, the end-to-end metric it moves and PERF.md's list of layers."""
+    entries = entries_of(bench(), cell)
+    with open(os.path.join(REPO, "PERF.md")) as f:
+        perf = f.read()
+    for entry in entries:
+        spec = _json(METRICS, entry["name"] + ".json")
+        assert {k: spec[k] for k in entry} == entry
+        assert entry["moves"] == moves
+        assert os.path.exists(os.path.join(
+            REPO, "chipbench", "reducers", spec["reducer"] + ".py"))
+        assert entry["layer"] in perf
+    return entries
+
+
+def add_cell(tmp, name, like, mix):
+    """One more cell in a made-up tree, as files and entries: the toy
+    configuration ``data/<name>.json`` under the traffic ``mix``,
+    reporting what the repo's cell ``like`` reports, each per-layer metric
+    through a file of its own named ``<name>.<base>``."""
+    held = data(name)
+    with open(os.path.join(tmp, "chipbench", "configs", name + ".json"),
+              "w") as f:
+        json.dump(held, f)
+    made = bench(tmp)
+    made["configs"].append({
+        "name": name, "source": held["source"],
+        "file": f"chipbench/configs/{name}.json",
+        "reduced": held["reduced"], "why": "toy width"})
+    made["workloads"].append({
+        "name": name, "config": name, "traffic": mix, "chips": 1,
+        "why": "made up for the tests"})
+    for metric in made["end_to_end"]:
+        if like in metric.get("workloads", ()):
+            metric["workloads"].append(name)
+    theirs = entries_of(bench(), like)
+    assert len({base(m["name"]) for m in theirs}) == len(theirs)
+    for metric in theirs:
+        spec = dict(_json(METRICS, metric["name"] + ".json"),
+                    name=f"{name}.{base(metric['name'])}", workloads=[name])
+        with open(os.path.join(tmp, "chipbench", "layer_metrics",
+                               spec["name"] + ".json"), "w") as f:
+            json.dump(spec, f)
+        made["per_layer"].append({k: spec[k] for k in (
+            "name", "unit", "better", "source", "layer", "moves",
+            "workloads")})
+    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
+        json.dump(made, f)
+    return tmp
 
 
 def _listing(tmp):
