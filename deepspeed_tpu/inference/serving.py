@@ -604,6 +604,9 @@ class ServingEngine:
 
         self._clock = clock if clock is not None else time.monotonic
         self._telemetry = telemetry
+        # the judge of each ``serve/loop`` and the sampler behind it, on
+        # whether or not telemetry is enabled (monitor/telemetry.py)
+        self.telemetry.watchdog.start()
         # profiling plane (monitor/profiling.py): route the serving jit
         # entry points through the CompileWatcher — shape-bucket churn
         # shows up as compile/* events, and a recompile storm flips
@@ -2038,7 +2041,8 @@ class ServingEngine:
         report = self._report
         report["t0_ns"] = time.perf_counter_ns()
         try:
-            with self.telemetry.span("serve/loop"):
+            with self.telemetry.step_span("serve/loop", report=report,
+                                          owner=self):
                 return self._step()
         finally:
             report["t1_ns"] = time.perf_counter_ns()

@@ -11,8 +11,9 @@ remaining blind spot — *why a step is slow on one chip*:
   miss with the observed wall time, the cumulative miss count, and a
   cause diff against the previous signature at that site (new shape vs
   new dtype vs new callable vs new static arg).  A sliding-window
-  recompile-storm verdict feeds the :class:`StepStallWatchdog` (compile
-  time is exempted from the stall threshold) and serving ``health()``.
+  recompile-storm verdict feeds serving ``health()`` (the
+  :class:`StepStallWatchdog` exempts compile time from its hang verdict by
+  the always-on compile account, not by this plane).
 * :class:`HbmTracker` folds periodic live-buffer snapshots
   (``jax.Device.memory_stats()``; backends without allocator stats skip
   quietly) into per-span peak attribution — frozen ``mem/<span>/*``
@@ -136,8 +137,8 @@ class CompileWatcher:
     and surfaced through serving ``health()``.  Cold misses (first
     compile at a site) are exempt: a process start compiles every entry
     point once and that is amortisation working, not churn.
-    The watchdog reads :meth:`compile_secs_since` so cold-start and
-    post-recompile steps stop risking false stall verdicts.
+    :meth:`compile_secs_since` says how much of an interval went to the
+    misses seen here (the stall watchdog reads the compile account).
     """
 
     def __init__(self, telemetry, storm_threshold=3, storm_window_s=60.0,
@@ -239,9 +240,8 @@ class CompileWatcher:
         return self._storm_active
 
     def compile_secs_since(self, t):
-        """Total observed compile seconds since monotonic time ``t`` —
-        the stall-watchdog exemption: a step that recompiled may
-        legitimately exceed the median-derived threshold by exactly this
+        """Total observed compile seconds since monotonic time ``t``: a
+        step that recompiled may legitimately take longer by exactly this
         much."""
         with self._lock:
             return sum(d for ts, d, _ in self._misses if ts >= t)

@@ -1,5 +1,5 @@
 """Unified telemetry spine: structured run-event stream, metrics registry,
-xprof spans, and a step-stall watchdog.
+xprof spans, and the record of every step that runs long.
 
 The reference threads observability through every layer (``monitor/``,
 ``utils/timer.py``, ``comms_logger``, flops profiler) but each fragment has
@@ -30,12 +30,17 @@ its own sink.  Here every subsystem writes into ONE process-local
   rotation.  ``MonitorMaster`` gains it as a fourth writer, so scalar
   monitor events, comm census, HBM gauges, heartbeats and stalls all land
   in the same replayable stream.
-* :class:`StepStallWatchdog` — a daemon thread fed a heartbeat from every
-  engine ``step()``; when the gap since the last beat exceeds a
-  configurable multiple of the rolling-median step time it logs and emits
-  a structured ``stall`` event.  This turns the silent-hang failure class
-  (a hung backend leaves zero in-band evidence)
-  into an observable one.
+* :class:`StepStallWatchdog` — one a :class:`Telemetry`, on like the ring:
+  the judge of every step's length at its close (a ``serve/loop``; a
+  trainer's time from one ``engine/train_batch`` to the next), a thread
+  that sleeps until a step is late and then samples every thread's stack,
+  every native thread's state and the machine's counters, and the record
+  of each step that ran long, with one word for where the time went
+  (:meth:`Telemetry.step_span`, :meth:`Telemetry.slow_steps`).  Fed a
+  heartbeat by the trainer it also gives the hang verdict it was made
+  for: a gap over a multiple of the rolling-median step emits a ``stall``
+  event, which turns the silent-hang failure class (a hung backend leaves
+  zero in-band evidence) into an observable one.
 
 Every event is one JSON object per line with at minimum ``ts`` (unix
 seconds), ``kind`` and ``name``.  The frozen per-kind schema lives in
@@ -101,6 +106,29 @@ class Span(NamedTuple):
     t1_ns: int
     key: Any
     attrs: Optional[dict]
+
+
+# What the judge at a step's close and the sampler behind it go by
+# (docs/telemetry.md "The slow-step record").  A step is slow when it took
+# over SLOW_STEP_MEDIANS medians of its kind AND over that median by
+# SLOW_STEP_EXCESS_NS, once its kind has SLOW_STEP_MIN_KIND steps.  From
+# the records: the stalls met were 3.7-8 medians in the train cells (1.8 s
+# on 0.49, 1.9-3.4 s on 0.41) and over 20 in serving; the largest honest
+# ratio inside one kind is 2.2 (a chunk over 14,336 cached entries against
+# one over none, 126.5 / 58.8 ms).
+SLOW_STEP_MEDIANS = 3
+SLOW_STEP_EXCESS_NS = 250_000_000
+SLOW_STEP_MIN_KIND = 8
+SLOW_STEP_WINDOW = 64           # steps of a kind its median runs over
+SLOW_STEP_KINDS = 256           # kinds remembered, the oldest dropped
+SLOW_STEPS_KEPT = 64            # records, in a store beside the ring
+SAMPLE_EVERY_NS = 50_000_000    # between two samples of a late step ...
+SAMPLES_MAX = 200               # ... and how many of them it gets
+SAMPLE_FRAMES = 12              # innermost Python frames of a stack
+SAMPLE_THREADS = 8              # native threads a record names
+# the words a record's ``where`` is one of
+SLOW_STEP_WHERE = ("compile", "host_python", "descheduled", "blocked_io",
+                   "runtime_wait", "other_thread", "caller", "unknown")
 
 
 class SpanRing:
@@ -176,6 +204,33 @@ class _OpenSpan:
             tel.emit("span", self.name, dur_ms=round(dur_ms, 3), step=step,
                      attrs=attrs or None)
         return False
+
+
+class _StepSpan(_OpenSpan):
+    """The outermost span of a step (:meth:`Telemetry.step_span`): a span
+    like any other in the ring, whose open and close the telemetry's
+    :class:`StepStallWatchdog` judges.  ``report`` is a serving loop's
+    report (the step is this span); with ``period`` the step is the time
+    from this open to the next one of the same ``owner`` on the thread."""
+
+    __slots__ = ("report", "period", "owner", "cpu0")
+
+    def __init__(self, tel, name, step, report, period, owner):
+        super().__init__(tel, name, step, None, None)
+        self.report, self.period, self.owner = report, period, id(owner)
+
+    def __enter__(self):
+        super().__enter__()
+        self.tel.watchdog.step_opened(self)
+        return self
+
+    def __exit__(self, *exc):
+        if self.period:         # the next open closes the step
+            return super().__exit__(*exc)
+        t1 = time.perf_counter_ns()
+        out = super().__exit__(*exc)
+        self.tel.watchdog.loop_closed(self, t1)
+        return out
 
 
 # ----------------------------------------------------------------------
@@ -896,6 +951,9 @@ class Telemetry:
         self.registry = MetricsRegistry()
         # every span, enabled or not (the hot path's one always-on record)
         self.ring = SpanRing()
+        # the judge of every step's length and the sampler behind it: on
+        # like the ring, its thread started by the engines
+        self.watchdog = StepStallWatchdog(self, hangs=False)
         self.sink = None
         self.config = None
         self.exporter = None
@@ -1083,6 +1141,30 @@ class Telemetry:
         ``perf_counter_ns`` bounds, ordered by start."""
         return self.ring.spans(since_ns, until_ns)
 
+    def step_span(self, name, step=None, report=None, period=False,
+                  owner=None):
+        """The outermost span of a step: recorded like :meth:`span`, and
+        judged by this object's watchdog when the step closes.  A serving
+        loop hands its ``report`` (the step is the span, its kind what the
+        report's dispatches launched); a trainer says ``period=True`` (the
+        step runs from this open to the next one of the same ``owner`` on
+        the thread, so it holds the caller's wait for the loss).  Costs
+        one ``time.thread_time_ns`` reading, a look-up and two comparisons
+        more than a span; docs/telemetry.md, "The slow-step record"."""
+        return _StepSpan(self, name, step, report, period, owner)
+
+    def slow_steps(self, since_ns=None, until_ns=None):
+        """The records of the steps that ran long (at most
+        ``SLOW_STEPS_KEPT``, oldest first) that lie inside the given
+        ``perf_counter_ns`` bounds: one dict a step with ``name``,
+        ``key``, ``kind``, ``t0_ns``, ``t1_ns``, ``median_ns``,
+        ``cpu_ns``, ``spans``, ``compiles``, ``samples``, ``threads``,
+        ``machine`` and ``where``.  docs/telemetry.md, "The slow-step
+        record"."""
+        return [dict(r) for r in tuple(self.watchdog.records)
+                if (since_ns is None or r["t0_ns"] >= since_ns)
+                and (until_ns is None or r["t1_ns"] <= until_ns)]
+
     @staticmethod
     def compile_log(since_ns=None, until_ns=None):
         """The compile account's records that closed inside the bounds,
@@ -1235,39 +1317,257 @@ class Telemetry:
         self.enabled = False
 
 
-_telemetry = Telemetry()
-
-
-def get_telemetry() -> Telemetry:
-    """The process-global telemetry instance (engine init configures it)."""
-    return _telemetry
-
-
 # ----------------------------------------------------------------------
-# step-stall watchdog
+# the steps that run long (judge, sampler, record) and the hang verdict
 # ----------------------------------------------------------------------
+_TICK_S = 1.0 / os.sysconf("SC_CLK_TCK")
+
+
+def _read(path):
+    """A small ``/proc`` file's text (its first 64 KiB), or None where it
+    cannot be read.  Three system calls: each one lets the interpreter's
+    lock go, and a reader beside a thread that runs Python code waits to
+    get it back."""
+    try:
+        fd = os.open(path, os.O_RDONLY)
+    except OSError:
+        return None
+    try:
+        return os.read(fd, 65536).decode("ascii", "replace")
+    except OSError:
+        return None
+    finally:
+        os.close(fd)
+
+
+def read_tasks(only=None, first=(), budget_ns=None):
+    """This process's native threads (``only``: those tids) from
+    ``/proc/self/task/*/stat``: ``{tid: (comm, state, user + system
+    ticks, major faults)}``, the tids of ``first`` ahead of the others.  A
+    thread that cannot be read is left out; so are, past ``budget_ns``,
+    the threads not read yet (while a thread runs Python code every read
+    costs the reader a wait for the interpreter's lock: 7 ms a file was
+    measured), and key None then says ``"partial"``."""
+    if only is None:
+        try:
+            only = os.listdir("/proc/self/task")
+        except OSError:
+            return {}
+    out, ahead = {}, [str(tid) for tid in first]
+    since = time.perf_counter_ns()
+    for tid in ahead + [t for t in map(str, only) if t not in ahead]:
+        if budget_ns is not None and tid not in ahead and \
+                time.perf_counter_ns() - since > budget_ns:
+            out[None] = "partial"
+            break
+        text = _read(f"/proc/self/task/{tid}/stat")
+        if text is None:
+            continue
+        # pid (comm) state ppid ... majflt(12) .. utime(14) stime(15)
+        left, right = text.find("("), text.rfind(")")
+        rest = text[right + 2:].split()
+        try:
+            out[int(tid)] = (text[left + 1:right], rest[0],
+                             int(rest[11]) + int(rest[12]), int(rest[9]))
+        except (IndexError, ValueError):
+            continue
+    return out
+
+
+def read_machine(tid=None):
+    """What the machine says of itself, as counters to take differences
+    of: ``steal_s`` and ``iowait_s`` (``/proc/stat``), ``pgmajfault``,
+    ``allocstall`` and ``compact_stall`` (``/proc/vmstat``),
+    ``pressure_cpu_us`` / ``_memory_us`` / ``_io_us`` (the ``some total``
+    of ``/proc/pressure/*``), the level ``load1``, and thread ``tid``'s
+    ``voluntary`` / ``nonvoluntary`` context switches.  A file that cannot
+    be read leaves its fields out."""
+    out = {}
+    try:
+        cpu = (_read("/proc/stat") or "").split("\n", 1)[0].split()
+        if len(cpu) > 8 and cpu[0] == "cpu":
+            out["iowait_s"] = int(cpu[5]) * _TICK_S
+            out["steal_s"] = int(cpu[8]) * _TICK_S
+        for line in (_read("/proc/vmstat") or "").splitlines():
+            name, _, value = line.partition(" ")
+            if name in ("pgmajfault", "compact_stall"):
+                out[name] = int(value)
+            elif name.startswith("allocstall"):
+                out["allocstall"] = out.get("allocstall", 0) + int(value)
+        for what in ("cpu", "memory", "io"):
+            some = (_read(f"/proc/pressure/{what}") or "").split("\n", 1)[0]
+            if some.startswith("some") and "total=" in some:
+                out[f"pressure_{what}_us"] = int(some.rpartition("total=")[2])
+        load = _read("/proc/loadavg")
+        if load:
+            out["load1"] = float(load.split()[0])
+        if tid is not None:
+            status = _read(f"/proc/self/task/{tid}/status") or ""
+            for line in status.splitlines():
+                if line.startswith(("voluntary_ctxt", "nonvoluntary_ctxt")):
+                    out[line.partition("_")[0]] = int(line.split()[-1])
+    except (IndexError, ValueError):
+        pass
+    return out
+
+
+def _stack(frame):
+    """The innermost ``SAMPLE_FRAMES`` frames, innermost first, as
+    ``file:line function``."""
+    out = []
+    while frame is not None and len(out) < SAMPLE_FRAMES:
+        code = frame.f_code
+        out.append(f"{code.co_filename}:{frame.f_lineno} {code.co_name}")
+        frame = frame.f_back
+    return tuple(out)
+
+
+def _in_runtime(frames):
+    """Whether a stack's innermost Python frame is JAX's: the thread is
+    inside (or on its way back from) the runtime."""
+    return bool(frames) and ("/jax/" in frames[0] or "/jaxlib/" in frames[0])
+
+
+def where_of(record):
+    """The one word of ``SLOW_STEP_WHERE`` for a record, by the rules of
+    docs/telemetry.md "The slow-step record", first match wins."""
+    half = (record["t1_ns"] - record["t0_ns"] - record["median_ns"]) / 2
+    if record["compiles"]:
+        return "compile"
+    if record["cpu_ns"] > half:
+        return "host_python"
+    samples, me = record["samples"], record["thread"]
+    machine = record["machine"]
+    mine = [s["tasks"][s["python"][me]] for s in samples
+            if s["python"].get(me) in s["tasks"]]
+    states = [task[1] for task in mine]
+
+    def most(count):
+        return bool(samples) and 2 * count >= len(samples)
+
+    late = max(record["late"], key=lambda note: note["late_ns"],
+               default=None)
+    if late is not None and late["late_ns"] < half:
+        late = None
+    # nobody of the process ran while the sampler overslept: the whole
+    # process was off the CPU
+    if late and 4 * late["process_cpu_ns"] < late["late_ns"]:
+        return "descheduled"
+    if most(states.count("R")) and (machine.get("steal_s", 0) > 0
+                                    or machine.get("nonvoluntary", 0) > 0):
+        return "descheduled"
+    sampled_s = (samples[-1]["t_ns"] - samples[0]["t_ns"]) / 1e9 \
+        if samples else 0.0
+    if "D" in states or (mine and mine[-1][3] > mine[0][3]) or (
+            machine.get("pgmajfault", 0) > 0
+            and machine.get("iowait_s", 0.0) > sampled_s / 2 > 0):
+        return "blocked_io"
+    # the sampler overslept while another Python thread ran: that thread
+    # held the interpreter's lock
+    if late and any(2 * cpu >= late["late_ns"]
+                    for name, cpu in late["threads"].items() if name != me):
+        return "other_thread"
+    if most(states.count("S")) and most(sum(
+            _in_runtime(s["stacks"].get(me)) for s in samples)):
+        return "runtime_wait"
+    running = {}
+    for s in samples:
+        for name, tid in s["python"].items():
+            if name != me and s["tasks"].get(tid, ("", "S"))[1] == "R":
+                running[name] = running.get(name, 0) + 1
+    if any(most(count) for count in running.values()):
+        return "other_thread"
+    if record.get("outside_ns", 0) >= half:
+        return "caller"
+    return "unknown"
+
+
+class _Sampled:
+    """What the sampler has of one late step."""
+
+    __slots__ = ("id", "samples", "first", "last", "machine")
+
+    def __init__(self, span_id):
+        self.id, self.samples = span_id, []
+        self.first = self.last = None   # the native threads, whole
+        self.machine = None             # the counters at the first sample
+
+
+class _Asleep(NamedTuple):
+    """Where the sampler meant to wake, and what it knew going to sleep."""
+    wake_at: int
+    process_cpu: int
+    python_cpu: dict        # thread name -> ticks
+    since: int
+
+
 class StepStallWatchdog:
-    """Detects hung training steps.
+    """The judge of every step's length, the sampler that looks at a step
+    while it is late, and the verdict on a step that never ends.  One a
+    :class:`Telemetry` (``telemetry.watchdog``), on like the ring; the
+    engines start its thread.  docs/telemetry.md, "The slow-step record".
 
-    The engine calls :meth:`beat` at every completed ``step()``; a daemon
-    thread polls and, when the gap since the last beat exceeds
-    ``max(stall_factor * rolling_median_step, min_stall_secs)``, logs a
-    warning and emits a structured ``stall`` event — once per stalled step,
-    so a long hang produces one event, not a flood.
+    **The judge** runs on the stepping thread at a step's close
+    (:class:`_StepSpan`): the step is compared with the running median
+    (last ``window``) of the steps of its kind and, past both
+    ``SLOW_STEP_*`` thresholds, leaves a record in ``records``
+    (:meth:`Telemetry.slow_steps`), one ``logger.warning`` line and, with
+    telemetry enabled, a ``stall`` event and incident.
 
-    With a :class:`~deepspeed_tpu.monitor.profiling.CompileWatcher`
-    attached (``compile_watcher``), observed compile time since the last
-    beat is EXEMPT from the gap: a cold-start or shape-churn step that
-    legitimately spends tens of seconds in XLA no longer risks a false
-    stall verdict — only the non-compile remainder is judged against the
-    threshold.
+    **The sampler** is the thread: it sleeps until the armed step's
+    deadline (its start + ``SLOW_STEP_EXCESS_NS`` + ``SLOW_STEP_MEDIANS``
+    x the largest median any kind has) and, if that step is still open
+    then, samples every ``SAMPLE_EVERY_NS`` until it closes: every
+    thread's Python stack, every native thread's state and CPU ticks and,
+    first and last, the machine's counters.  The judge takes the samples
+    into the record; a step it does not call slow drops them.
+
+    **The hang verdict** (``hangs``; the trainer with telemetry enabled
+    feeds :meth:`beat`) is what the class was before: once the time since
+    the last beat, less what the compile account saw compiled in it,
+    passes ``max(stall_factor * median beat, min_stall_secs)``, one
+    ``stall`` event a stalled step.  ``poll_interval_secs`` is no poll
+    any more: it bounds the thread's sleep while ``hangs`` is set.
     """
 
-    def __init__(self, telemetry: Telemetry, stall_factor=10.0,
-                 poll_interval_secs=1.0, min_stall_secs=1.0, window=64,
-                 cluster=None, cluster_poll_secs=30.0,
-                 compile_watcher=None):
+    def __init__(self, telemetry, stall_factor=10.0,
+                 poll_interval_secs=1.0, min_stall_secs=1.0,
+                 window=SLOW_STEP_WINDOW, cluster=None,
+                 cluster_poll_secs=30.0, hangs=True):
         self.telemetry = telemetry
+        self.window = int(window)
+        self.configure(hangs=hangs, stall_factor=stall_factor,
+                       poll_interval_secs=poll_interval_secs,
+                       min_stall_secs=min_stall_secs, cluster=cluster,
+                       cluster_poll_secs=cluster_poll_secs)
+        self._lock = threading.Lock()
+        self._stop = threading.Event()
+        self._thread = None
+        # the judge: a kind's last steps (ns) and its median
+        self.records = deque(maxlen=SLOW_STEPS_KEPT)
+        self._kinds = {}        # kind -> [its last steps (ns), median, n]
+        self._wait_ns = SLOW_STEP_EXCESS_NS
+        self._local = threading.local()     # .period: the open _StepSpan
+        # the step the sampler waits for: (span id, t0_ns, deadline_ns,
+        # thread ident), written by the stepping thread
+        self.armed = None
+        # the sampler: what it has of one late step (_Sampled), where it
+        # meant to wake (_Asleep) and how late it has been
+        self._sampled = None
+        self._asleep = None
+        self._cpu_rate = 0.0
+        self._late = deque(maxlen=8)
+        self.wakes = 0
+        self.read_tasks, self.read_machine = read_tasks, read_machine
+        self.clock_ns = time.perf_counter_ns
+        self.process_cpu_ns = time.process_time_ns
+
+    def configure(self, hangs, stall_factor=10.0, poll_interval_secs=1.0,
+                  min_stall_secs=1.0, cluster=None, cluster_poll_secs=30.0):
+        """Set what the hang verdict goes by and forget its beats (an
+        engine does so when it is made: ``hangs`` False leaves the judge
+        and the sampler alone on)."""
+        self.hangs = bool(hangs)
         self.stall_factor = float(stall_factor)
         self.poll_interval_secs = float(poll_interval_secs)
         self.min_stall_secs = float(min_stall_secs)
@@ -1275,23 +1575,22 @@ class StepStallWatchdog:
         # the watchdog doubles as the cross-rank straggler sentinel
         self.cluster = cluster
         self.cluster_poll_secs = float(cluster_poll_secs)
-        # profiling plane: compile time since the last beat is exempted
-        # from the stall gap (None -> no exemption)
-        self.compile_watcher = compile_watcher
         self._last_cluster_poll = None
         self._cluster_reported = None
-        self._lock = threading.Lock()
-        self._durations = deque(maxlen=window)
+        self._durations = deque(maxlen=self.window)
         self._last_beat = None
         self._last_step = -1
         self._stall_reported = False
-        self._stop = threading.Event()
-        self._thread = None
+        return self
 
     def start(self):
         if self._thread is None:
+            self._stop.clear()
+            # the thread holds no reference to this object between two
+            # wake-ups: a Telemetry that is dropped takes its thread along
             self._thread = threading.Thread(
-                target=self._run, daemon=True, name="ds-stall-watchdog")
+                target=self._run, args=(weakref.ref(self), self._stop),
+                daemon=True, name="ds-stall-watchdog")
             self._thread.start()
         return self
 
@@ -1301,11 +1600,315 @@ class StepStallWatchdog:
             self._thread.join(timeout=5.0)
             self._thread = None
 
+    # -- the judge (the stepping thread) -------------------------------
+    def step_opened(self, span):
+        t0 = span.t0
+        span.cpu0 = cpu = time.thread_time_ns()
+        if span.period:
+            local = self._local
+            before = getattr(local, "period", None)
+            local.period = span
+            if before is not None and before.owner == span.owner:
+                self.judge(before.name, before.step, (span.owner, "train"),
+                           before.t0, t0, cpu - before.cpu0, before.id,
+                           None, True)
+        self.armed = (span.id, t0, t0 + self._wait_ns,
+                      threading.get_ident())
+
+    def loop_closed(self, span, t1):
+        self.armed = None
+        t0, report = span.t0, span.report
+        # the thread's CPU time is a system call (6 us on the chip's host):
+        # read again only for a step that can be slow at all
+        cpu = time.thread_time_ns() if t1 - t0 > SLOW_STEP_EXCESS_NS \
+            else span.cpu0
+        # the kind of a loop: the phase and shape of what it launched
+        self.judge(span.name, span.step,
+                   (span.owner, *[(d["phase"], d["batch"], d["tokens"])
+                                  for d in report["dispatches"]
+                                  if d["t0_ns"] >= t0])
+                   if report else (span.owner,),
+                   t0, t1, cpu - span.cpu0, span.id, report)
+
+    def judge(self, name, key, kind, t0_ns, t1_ns, cpu_ns=0, span_id=None,
+              report=None, period=False):
+        """One closed step of ``kind`` (hashable; its first item the
+        owner): compare it with the median of its kind, remember it, and
+        return its record if it was slow (None otherwise).  The median is
+        that of the kind's last ``window`` steps as of the last multiple
+        of ``SLOW_STEP_MIN_KIND`` steps, so a sound step costs a look-up,
+        two comparisons and an append."""
+        took = t1_ns - t0_ns
+        state = self._kinds.get(kind)
+        if state is None:
+            if len(self._kinds) >= SLOW_STEP_KINDS:
+                del self._kinds[next(iter(self._kinds))]
+            state = self._kinds[kind] = [deque(maxlen=self.window), None, 0]
+        steps, median, _ = state
+        steps.append(took)
+        state[2] = n = state[2] + 1
+        if not n % SLOW_STEP_MIN_KIND:
+            state[1] = sorted(steps)[len(steps) // 2]
+            self._wait_ns = SLOW_STEP_EXCESS_NS + SLOW_STEP_MEDIANS * max(
+                s[1] for s in self._kinds.values() if s[1] is not None)
+        if median is not None and took > SLOW_STEP_MEDIANS * median \
+                and took - median > SLOW_STEP_EXCESS_NS:
+            return self._record(name, key, kind, t0_ns, t1_ns, median,
+                                cpu_ns, span_id, report, period, n)
+        return None
+
+    def _record(self, name, key, kind, t0, t1, median, cpu_ns, span_id,
+                report, period, n):
+        me = threading.current_thread()
+        with self._lock:
+            sampled = self._sampled
+            if sampled is None or sampled.id != span_id or span_id is None:
+                sampled = _Sampled(span_id)
+            samples = list(sampled.samples)
+        spans = self._tree(span_id, t0, t1)
+        for sample in samples:
+            inside = [s for s in spans
+                      if s["t0_ns"] <= sample["t_ns"] <= s["t1_ns"]]
+            sample["span"] = inside[-1]["name"] if inside else None
+        machine = {}
+        if sampled.machine is not None:
+            last = self.read_machine(me.native_id)
+            machine = {k: last[k] if k == "load1" else last[k] - v
+                       for k, v in sampled.machine.items() if k in last}
+        python = samples[-1]["python"] if samples else {}
+        names = {tid: thread for thread, tid in python.items()}
+        # from the step's first sample to a reading of the judge's own
+        first = sampled.first or {}
+        last = self.read_tasks() if first else {}
+        threads = sorted(
+            ({"tid": tid, "comm": task[0], "python": names.get(tid),
+              "cpu_s": (task[2] - first[tid][2]) * _TICK_S}
+             for tid, task in last.items()
+             if tid is not None and tid in first),
+            key=lambda t: t["cpu_s"], reverse=True)[:SAMPLE_THREADS]
+        notes = (*tuple(self._late), self.late_note(t1))
+        late = {n["from_ns"]: n for n in notes
+                if n is not None and n["to_ns"] > t0 and n["from_ns"] < t1}
+        record = {
+            "name": name, "key": key,
+            "kind": "+".join(k if isinstance(k, str) else
+                             "{}:{}x{}".format(*k) for k in kind[1:])
+                    or "idle",
+            "t0_ns": t0, "t1_ns": t1, "median_ns": median,
+            "cpu_ns": cpu_ns, "thread": me.name, "tid": me.native_id,
+            "spans": spans,
+            "compiles": [
+                {k: r[k] for k in ("name", "site", "cache", "t0_ns",
+                                   "t1_ns", "span")}
+                for r in _account.log(t0, t1)],
+            "samples": samples, "threads": threads, "machine": machine,
+            "late": sorted(late.values(), key=lambda n: n["from_ns"])}
+        if period:
+            # the part of a trainer's period under no program span
+            own = [s for s in spans if s["id"] == span_id]
+            record["outside_ns"] = (t1 - t0) - sum(
+                s["t1_ns"] - s["t0_ns"] for s in own)
+        if report is not None:
+            # what the loop launched
+            record["dispatches"] = [dict(d) for d in report["dispatches"]
+                                    if d["t0_ns"] >= t0]
+        record["where"] = where_of(record)
+        self.records.append(record)
+        self._tell(record, n)
+        return record
+
+    def _tree(self, span_id, t0, t1):
+        """The step's span and those beneath it, from the ring, ordered by
+        start (so the innermost of two that hold an instant comes last)."""
+        ring = getattr(self.telemetry, "ring", None)
+        if ring is None or span_id is None:
+            return []
+        inside, tree = ring.spans(t0, None), {span_id}
+        out = []
+        for s in inside:        # by start: a parent ahead of its children
+            if s.id == span_id or s.parent in tree:
+                tree.add(s.id)
+                out.append({"id": s.id, "parent": s.parent, "name": s.name,
+                            "t0_ns": s.t0_ns, "t1_ns": s.t1_ns})
+        return out
+
+    def _tell(self, record, n):
+        wall = (record["t1_ns"] - record["t0_ns"]) / 1e9
+        median = record["median_ns"] / 1e9
+        samples, machine = record["samples"], record["machine"]
+        span = samples[-1]["span"] if samples else None
+        other = next((t for t in record["threads"]
+                      if t["tid"] != record["tid"]), None)
+        late = max((n["late_ns"] for n in record["late"]), default=0)
+        logger.warning(
+            f"slow step: {record['name']} {record['key']} "
+            f"[{record['kind']}] took {wall:.3f}s (median of its kind "
+            f"{median:.4f}s), {record['cpu_ns'] / 1e9:.3f}s on the CPU: "
+            f"{record['where']}; in {span or 'no program span'}; "
+            + (f"busiest other thread {other['comm']} "
+               f"{other['cpu_s']:.2f}s; " if other else "")
+            + "".join(f"{k} +{machine[k]:.3g}; "
+                      for k in ("steal_s", "iowait_s", "compact_stall")
+                      if k in machine)
+            + f"sampler late {late / 1e9:.3f}s; {len(samples)} samples; "
+            f"t0_ns {record['t0_ns']} t1_ns {record['t1_ns']}")
+        tel = self.telemetry
+        if not getattr(tel, "enabled", False):
+            return
+        step = record["key"] if isinstance(record["key"], int) else n
+        threshold = max(SLOW_STEP_MEDIANS * median,
+                        median + SLOW_STEP_EXCESS_NS / 1e9)
+        tel.emit("stall", record["name"], step=step, gap_s=round(wall, 3),
+                 median_step_s=round(median, 6),
+                 threshold_s=round(threshold, 3), where=record["where"],
+                 cpu_s=round(record["cpu_ns"] / 1e9, 3), span=span)
+        incidents = getattr(tel, "incidents", None)
+        if incidents is not None:
+            incidents.trigger(
+                "stall", source=record["name"], step=step,
+                detail=f"slow step: {wall:.3f}s against a median of "
+                       f"{median:.4f}s ({record['where']})")
+
+    # -- the sampler (the thread) --------------------------------------
+    def _python_cpu(self):
+        """CPU ticks of the Python threads, by name."""
+        threads = {t.native_id: t.name for t in threading.enumerate()}
+        return {threads[tid]: task[2]
+                for tid, task in self.read_tasks(list(threads)).items()}
+
+    def late_note(self, now_ns):
+        """How far past the sampler's intended wake-up ``now_ns`` lies, if
+        by a sample's interval or more, with the CPU the process and its
+        Python threads used meanwhile (the process's less what it uses in
+        a sleep of the planned length, by the last sleep that ended on
+        time); None otherwise.  Read by the sampler when it wakes, and by
+        the judge when a slow step closes before the sampler has."""
+        asleep = self._asleep
+        if asleep is None or now_ns - asleep.wake_at < SAMPLE_EVERY_NS:
+            return None
+        usual = int(self._cpu_rate * (asleep.wake_at - asleep.since))
+        return {"from_ns": asleep.wake_at, "to_ns": now_ns,
+                "late_ns": now_ns - asleep.wake_at,
+                "process_cpu_ns": max(
+                    0, self.process_cpu_ns() - asleep.process_cpu - usual),
+                "threads": {
+                    name: int((ticks - asleep.python_cpu[name]) * _TICK_S
+                              * 1e9)
+                    for name, ticks in self._python_cpu().items()
+                    if name in asleep.python_cpu}}
+
+    def _stacks(self, now_ns, late_ns):
+        """A sample with every other thread's Python stack (one call under
+        the interpreter's lock), its native threads still to be read."""
+        me = threading.get_ident()
+        names = {t.ident: (t.name, t.native_id)
+                 for t in threading.enumerate()}
+        stacks, python = {}, {}
+        frames = sys._current_frames()
+        for ident, frame in frames.items():
+            if ident != me:
+                name, tid = names.get(ident, (f"thread-{ident}", None))
+                stacks[name], python[name] = _stack(frame), tid
+        # a kept frame keeps its locals alive: only the text stays
+        del frames, frame
+        return {"t_ns": now_ns, "late_ns": late_ns, "span": None,
+                "stacks": stacks, "python": python, "tasks": {}}
+
+    def wake(self, now_ns=None):
+        """One turn of the sampler (tests call it on their own clock):
+        note how late it is, judge a hang, sample the armed step if that
+        is past its deadline, and say when to wake next (ns)."""
+        now = self.clock_ns() if now_ns is None else now_ns
+        self.wakes += 1
+        note = self.late_note(now)
+        if note is not None:
+            self._late.append(note)
+        elif self._asleep is not None and now > self._asleep.since:
+            # CPU nanoseconds the process uses a nanosecond, while sound
+            self._cpu_rate = (self.process_cpu_ns()
+                              - self._asleep.process_cpu) \
+                / (now - self._asleep.since)
+        if self.hangs:
+            self.check(now / 1e9)
+            self.check_cluster(now / 1e9)
+        armed, sampled = self.armed, self._sampled
+        if armed is None or now < armed[2]:
+            # nothing is late: what was sampled of a step that has closed
+            # the judge took if it wanted it
+            if sampled is not None and (armed is None
+                                        or armed[0] != sampled.id):
+                with self._lock:
+                    self._sampled = None
+            wake_at = armed[2] if armed is not None else now + self._wait_ns
+        else:
+            if sampled is None or sampled.id != armed[0]:
+                sampled = _Sampled(armed[0])
+            wake_at = now + SAMPLE_EVERY_NS
+            if len(sampled.samples) < SAMPLES_MAX:
+                self._take(sampled, armed, now, note)
+            elif not self.hangs:
+                # sampled out, and no verdict to give: wait for the close
+                wake_at = now + self._wait_ns
+        if self.hangs:
+            wake_at = min(wake_at,
+                          now + int(self.poll_interval_secs * 1e9))
+        self._asleep = _Asleep(wake_at, self.process_cpu_ns(),
+                               self._python_cpu(), now)
+        return wake_at
+
+    def _take(self, sampled, armed, now, note):
+        """One sample of the armed step into ``sampled``: the stacks at
+        once, then the machine (a step's first sample) and the native
+        threads, the Python ones first."""
+        sample = self._stacks(now, note["late_ns"] if note else 0)
+        with self._lock:
+            sampled.samples.append(sample)
+            self._sampled = sampled
+        python = [tid for tid in sample["python"].values()
+                  if tid is not None]
+        if sampled.machine is None:
+            sampled.machine = self.read_machine(next(
+                (t.native_id for t in threading.enumerate()
+                 if t.ident == armed[3]), None))
+        tasks = self.read_tasks(first=python, budget_ns=SAMPLE_EVERY_NS // 2)
+        if sampled.first is None:
+            sample["tasks"] = sampled.first = tasks
+        else:
+            # keep of the native threads those a reader needs: the Python
+            # ones and whatever ran or was not asleep; and one copy of a
+            # stack that did not move
+            before = sampled.samples[-2]
+            sample["tasks"] = {
+                tid: task for tid, task in tasks.items()
+                if tid in python or tid is None or task[1] != "S"
+                or task[2] != sampled.last.get(tid, task)[2]}
+            for name, frames in sample["stacks"].items():
+                if before["stacks"].get(name) == frames:
+                    sample["stacks"][name] = before["stacks"][name]
+        sampled.last = tasks
+
+    @staticmethod
+    def _run(ref, stop):
+        timeout = 0.0
+        while not stop.wait(timeout):
+            watchdog = ref()
+            if watchdog is None:
+                return
+            try:
+                wake_at = watchdog.wake()
+                timeout = max(0.0, (wake_at - watchdog.clock_ns()) / 1e9)
+            except Exception as e:  # never kill the host process
+                logger.warning(f"stall watchdog failed: {e}")
+                timeout = 1.0
+            del watchdog
+
+    # -- the hang verdict ----------------------------------------------
     def beat(self, step, now=None):
         """Record a completed step; emits a ``heartbeat`` event carrying the
         measured step wall time.  ``now`` is injectable for deterministic
-        tests (FakeClock), defaulting to the monotonic clock."""
-        now = now if now is not None else time.monotonic()
+        tests (FakeClock), defaulting to ``time.perf_counter`` (the clock
+        of the ring and of the compile account)."""
+        now = now if now is not None else time.perf_counter()
         with self._lock:
             step_s = (now - self._last_beat
                       if self._last_beat is not None else None)
@@ -1327,10 +1930,10 @@ class StepStallWatchdog:
             return vals[len(vals) // 2]
 
     def check(self, now=None):
-        """One watchdog evaluation (the poll thread calls this; tests may
-        call it directly for determinism).  Returns True if a stall event
-        was emitted."""
-        now = now if now is not None else time.monotonic()
+        """One evaluation of the hang verdict (the thread calls this when
+        it wakes; tests may call it directly for determinism).  Returns
+        True if a stall event was emitted."""
+        now = now if now is not None else time.perf_counter()
         with self._lock:
             if self._last_beat is None or len(self._durations) < 2 or \
                     self._stall_reported:
@@ -1339,15 +1942,11 @@ class StepStallWatchdog:
             vals = sorted(self._durations)
             median = vals[len(vals) // 2]
         threshold = max(self.stall_factor * median, self.min_stall_secs)
-        gap = now - last_beat
-        if self.compile_watcher is not None:
-            # exempt observed compile time since the last beat: a step
-            # that recompiled may legitimately exceed the median-derived
-            # threshold by exactly its compile cost
-            try:
-                gap -= self.compile_watcher.compile_secs_since(last_beat)
-            except Exception:
-                pass
+        # a step that compiled may exceed the threshold by what the
+        # compile account saw compiled since the last beat
+        gap = now - last_beat - math.fsum(
+            min(_seconds(r), r["t1_ns"] / 1e9 - last_beat)
+            for r in _account.log(int(last_beat * 1e9), int(now * 1e9)))
         if gap <= threshold:
             return False
         with self._lock:
@@ -1374,7 +1973,7 @@ class StepStallWatchdog:
         runs every ``cluster_poll_secs``, not every watchdog poll."""
         if self.cluster is None:
             return None
-        now = now if now is not None else time.monotonic()
+        now = now if now is not None else time.perf_counter()
         if self._last_cluster_poll is not None and \
                 now - self._last_cluster_poll < self.cluster_poll_secs:
             return self._cluster_reported
@@ -1395,13 +1994,13 @@ class StepStallWatchdog:
         self._cluster_reported = rank
         return rank
 
-    def _run(self):
-        while not self._stop.wait(self.poll_interval_secs):
-            try:
-                self.check()
-                self.check_cluster()
-            except Exception as e:  # never kill the host process
-                logger.warning(f"stall watchdog check failed: {e}")
+
+_telemetry = Telemetry()
+
+
+def get_telemetry() -> Telemetry:
+    """The process-global telemetry instance (engine init configures it)."""
+    return _telemetry
 
 
 # ----------------------------------------------------------------------

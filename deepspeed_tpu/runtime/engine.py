@@ -46,8 +46,8 @@ from deepspeed_tpu import comm as dist
 from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.comm.quantize import CommQuantizer
 from deepspeed_tpu.monitor.monitor import MonitorMaster
-from deepspeed_tpu.monitor.telemetry import (MetricsDrain, StepStallWatchdog,
-                                             get_telemetry, in_setup_span,
+from deepspeed_tpu.monitor.telemetry import (MetricsDrain, get_telemetry,
+                                             in_setup_span,
                                              register_compiled)
 from deepspeed_tpu.parallel import groups
 from deepspeed_tpu.parallel.topology import FSDP_AXIS, build_mesh
@@ -346,18 +346,18 @@ class DeepSpeedEngine:
         # profiling plane (monitor/profiling.py): compile tracing + HBM
         # attribution + live roofline; None unless telemetry.profiling.enabled
         self._profiling = self.telemetry.profiling
-        self._watchdog = None
-        if self._tel_enabled and tc.stall_watchdog:
-            # distributed telemetry: the watchdog also runs the cross-rank
-            # straggler sweep over the shard aggregator (rank 0 owns one)
-            self._watchdog = StepStallWatchdog(
-                self.telemetry, stall_factor=tc.stall_factor,
-                poll_interval_secs=tc.stall_poll_secs,
-                min_stall_secs=tc.stall_min_secs,
-                cluster=self.telemetry.cluster,
-                compile_watcher=(self._profiling.compiles
-                                 if self._profiling is not None else None),
-            ).start()
+        # the telemetry's watchdog judges every step and samples a late one
+        # whether or not telemetry is enabled (monitor/telemetry.py "the
+        # steps that run long"); enabled, it also gives the hang verdict on
+        # the heartbeats and, in distributed mode, runs the cross-rank
+        # straggler sweep over the shard aggregator (rank 0 owns one)
+        hangs = bool(self._tel_enabled and tc.stall_watchdog)
+        watchdog = self.telemetry.watchdog.configure(
+            hangs=hangs, stall_factor=tc.stall_factor,
+            poll_interval_secs=tc.stall_poll_secs,
+            min_stall_secs=tc.stall_min_secs,
+            cluster=self.telemetry.cluster).start()
+        self._watchdog = watchdog if hangs else None
         self._last_batch_tokens = None
         # the flash kernels' plan of one step (``train/attn/*`` gauges):
         # None until the first batch shows its shape, then a dict, empty
@@ -1256,8 +1256,9 @@ class DeepSpeedEngine:
         # span ring either way (call to return; the loss comes back as a
         # device value, so this is host time unless a step blocks inside)
         t0 = time.perf_counter()
-        with self.telemetry.span("engine/train_batch",
-                                 step=self.global_steps), \
+        with self.telemetry.step_span("engine/train_batch",
+                                      step=self.global_steps, period=True,
+                                      owner=self), \
                 self._prof_track("train_batch"):
             loss = self._train_batch_inner(data_iter, batch)
         if self._tel_enabled:

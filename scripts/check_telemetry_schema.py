@@ -88,11 +88,15 @@ SCHEMA = {
         "required": {"ts": _NUM, "kind": str, "name": str, "step": int},
         "optional": {"step_ms": _NUM},
     },
+    # a step that never ends ("engine/step": the hang verdict) or one that
+    # ran long (the step's span name: the summary of a slow-step record,
+    # with the record's ``where``, the stepping thread's CPU seconds and
+    # the innermost program span of the last sample)
     "stall": {
         "required": {"ts": _NUM, "kind": str, "name": str, "step": int,
                      "gap_s": _NUM, "median_step_s": _NUM,
                      "threshold_s": _NUM},
-        "optional": {},
+        "optional": {"where": str, "cpu_s": _NUM, "span": str},
     },
     "meta": {
         "required": {"ts": _NUM, "kind": str, "name": str},
@@ -168,6 +172,13 @@ SCHEMA = {
         "optional": {"attrs": dict, "step": int},
     },
 }
+
+# FROZEN: the words a slow-step record's ``where`` (and the ``stall`` event
+# that carries its summary) is one of; byte-identical to
+# ``deepspeed_tpu.monitor.telemetry.SLOW_STEP_WHERE`` (a tier-1 test diffs
+# the two; the rules are in docs/telemetry.md "The slow-step record").
+SLOW_STEP_WHERE = ("compile", "host_python", "descheduled", "blocked_io",
+                   "runtime_wait", "other_thread", "caller", "unknown")
 
 # FROZEN vocabulary of span names the program passes to ``Telemetry.span``
 # — must stay byte-identical to ``deepspeed_tpu.monitor.telemetry.
@@ -504,6 +515,9 @@ def validate_event(event):
             problems.append(
                 f"{kind}: optional field {field!r} has type "
                 f"{type(value).__name__}")
+    if kind == "stall" and "where" in event and \
+            event["where"] not in SLOW_STEP_WHERE:
+        problems.append(f"stall: unknown where {event['where']!r}")
     if kind == "serve" and isinstance(event.get("name"), str) and \
             event["name"] not in SERVE_EVENTS:
         problems.append(f"serve: unknown event name {event['name']!r}")

@@ -120,12 +120,14 @@ def test_storm_rising_edge_and_decay(tmp_path):
 def test_compile_secs_since_and_watchdog_exemption(tmp_path):
     """A step that recompiled may exceed the stall threshold by exactly
     its compile cost — the watchdog must subtract observed compile time
-    instead of crying stall (satellite: FakeClock regression test)."""
+    instead of crying stall (satellite: FakeClock regression test).  The
+    watchdog reads the compile account, which is always on; the profiling
+    plane's ``CompileWatcher`` keeps its own ``compile_secs_since``."""
+    from deepspeed_tpu.monitor import telemetry as telemetry_module
     tel = _tel(tmp_path)
     clock = FakeClock(1000.0)
     cw = CompileWatcher(tel, storm_threshold=99, clock=clock)
-    wd = StepStallWatchdog(tel, stall_factor=1.0, min_stall_secs=0.0,
-                           compile_watcher=cw)
+    wd = StepStallWatchdog(tel, stall_factor=1.0, min_stall_secs=0.0)
     wd.beat(0, now=1000.0)
     wd.beat(1, now=1001.0)
     wd.beat(2, now=1002.0)                # median step 1s, threshold 1s
@@ -133,9 +135,19 @@ def test_compile_secs_since_and_watchdog_exemption(tmp_path):
     cw.note_miss("engine/train_step:1", ("fp", ()), 8.0)
     assert cw.compile_secs_since(1002.0) == pytest.approx(8.0)
     assert cw.compile_secs_since(1004.0) == 0.0
-    # 8.5s gap, 8s of it compile: exempted -> no stall
-    assert not wd.check(now=1010.5)
-    # same gap with no watcher attached IS a stall
+    # the same recompile as the account records it: 8 s that end at 1010
+    account = telemetry_module._account
+    compiled = {"t0_ns": int(1002e9), "t1_ns": int(1010e9), "name": "step",
+                "trace_s": 1.0, "lower_s": 1.0, "backend_s": 6.0,
+                "cache": "off", "site": "engine/train_step:1",
+                "shapes": None, "repeat": True}
+    account.records.append(compiled)
+    try:
+        # 8.5s gap, 8s of it compile: exempted -> no stall
+        assert not wd.check(now=1010.5)
+    finally:
+        account.records.remove(compiled)
+    # same gap with nothing compiled in it IS a stall
     wd2 = StepStallWatchdog(tel, stall_factor=1.0, min_stall_secs=0.0)
     wd2.beat(0, now=1000.0)
     wd2.beat(1, now=1001.0)
