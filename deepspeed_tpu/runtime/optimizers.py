@@ -34,19 +34,67 @@ SGD_OPTIMIZER = "sgd"
 ADAGRAD_OPTIMIZER = "adagrad"
 
 
-def _sr_cast(x32, key, dtype):
-    """Stochastic-round an fp32 array to ``dtype`` (bf16): add uniform noise
-    to the truncated mantissa bits, then truncate.  Unbiased in expectation,
-    so low-precision moment accumulation does not systematically lose the
-    (1-beta)-scaled increments the way nearest-rounding does — the reason
-    plain bf16 second moments decay under b2=0.999."""
+def _fmix32(h):
+    """murmur3's 32-bit finaliser on uint32 (array or scalar): a bijection
+    in which every input bit flips each output bit with a probability close
+    to one half.  Two multiplies and three xor-shifts an element."""
+    import jax.numpy as jnp
+    h = h ^ (h >> 16)
+    h = h * jnp.uint32(0x85EBCA6B)
+    h = h ^ (h >> 13)
+    h = h * jnp.uint32(0xC2B2AE35)
+    return h ^ (h >> 16)
+
+
+def _rounding_seed(count, ordinal: int):
+    """The uint32 scalar that sets one leaf's rounding noise for one step:
+    mixed from the step ``count`` and the leaf's ``ordinal`` in the flattened
+    tree, so that no two (step, leaf) pairs share a seed but by chance."""
+    import jax.numpy as jnp
+    step = _fmix32(count.astype(jnp.uint32) + jnp.uint32(0x9E3779B9))
+    return _fmix32(step ^ jnp.uint32(ordinal * 0x85EBCA77 & 0xFFFFFFFF))
+
+
+def _rounding_noise(seed, shape):
+    """One uint32 word an element of a leaf of ``shape``: ``_fmix32`` of
+    (the element's linear index in the GLOBAL leaf times an odd constant)
+    xor ``seed``.  The product is built from ``broadcasted_iota`` over the
+    leaf's own shape (no reshape) with the constant folded into each axis'
+    stride, so XLA computes the word inside the fusion that consumes it (a
+    small vector an axis and one add an element), a sharded leaf partitions
+    without a gather, and an element's noise is a function of (seed,
+    position) alone: the same on any mesh.  Without the constant the
+    finaliser's high half is measurably uneven over a run of consecutive
+    indices (a chi-square of its top byte over 2**20 of them reads 314
+    where 255 is due); with it both halves read as uniform.  (The index
+    wraps past 2**32 elements a leaf: the noise then repeats, still
+    uniform.)"""
+    import jax
+    import jax.numpy as jnp
+    spread = jnp.zeros(shape, jnp.uint32)
+    stride = 0x9E3779B1            # odd: 2**32 over the golden ratio
+    for axis in reversed(range(len(shape))):
+        spread = spread + jax.lax.broadcasted_iota(jnp.uint32, shape, axis) \
+            * jnp.uint32(stride & 0xFFFFFFFF)
+        stride *= shape[axis]
+    return _fmix32(spread ^ seed)
+
+
+def _sr_cast(x32, noise, dtype):
+    """Stochastic-round an fp32 array to ``dtype`` (bf16): add ``noise``
+    (uint32, 16 uniform bits an element) to the truncated mantissa bits,
+    then truncate.  Unbiased in expectation, so low-precision moment
+    accumulation does not systematically lose the (1-beta)-scaled increments
+    the way nearest-rounding does — the reason plain bf16 second moments
+    decay under b2=0.999.  The noise is ``_rounding_noise``'s: a counter
+    hash, no ``jax.random`` stream (two Threefry draws a leaf cost the
+    update more than its bytes did; PERF.md §6, PR 55)."""
     import jax
     import jax.numpy as jnp
     if dtype == jnp.float32:
         return x32
     bits = jax.lax.bitcast_convert_type(x32, jnp.uint32)
-    rnd = jax.random.bits(key, x32.shape, jnp.uint16).astype(jnp.uint32)
-    out = (bits + rnd) & jnp.uint32(0xFFFF0000)
+    out = (bits + noise) & jnp.uint32(0xFFFF0000)
     return jax.lax.bitcast_convert_type(out, jnp.float32).astype(dtype)
 
 
@@ -56,7 +104,12 @@ def _scale_by_adam_dtyped(b1, b2, eps, moment_dtype) -> optax.GradientTransforma
     step; the stored state is stochastically rounded down to the target dtype.
     Halves Adam's optimizer-state HBM (8 bytes/param -> 4 at bf16), which is
     what lets a >=1B-param model train on one 16 GB chip without host offload
-    (cf. reference ZeRO-Offload's motivation, runtime/zero/offload.py)."""
+    (cf. reference ZeRO-Offload's motivation, runtime/zero/offload.py).
+
+    The rounding's noise is one hashed word an element (``_rounding_noise``),
+    its low half for ``mu`` and its high half for ``nu``, seeded from the
+    step count and the leaf's place in the tree: reproducible from (step,
+    leaf, position) on any mesh and after a resumed checkpoint."""
     import jax
     import jax.numpy as jnp
 
@@ -73,13 +126,6 @@ def _scale_by_adam_dtyped(b1, b2, eps, moment_dtype) -> optax.GradientTransforma
         cf = count.astype(jnp.float32)
         bc1 = 1.0 - jnp.power(jnp.float32(b1), cf)
         bc2 = 1.0 - jnp.power(jnp.float32(b2), cf)
-        base = jax.random.fold_in(jax.random.key(0), count)
-        leaves, treedef = jax.tree_util.tree_flatten(updates)
-        n = max(1, len(leaves))
-        mu_keys = treedef.unflatten(list(jax.random.split(
-            jax.random.fold_in(base, 0), n))[:len(leaves)])
-        nu_keys = treedef.unflatten(list(jax.random.split(
-            jax.random.fold_in(base, 1), n))[:len(leaves)])
 
         mu32 = jax.tree_util.tree_map(
             lambda g, m: b1 * m.astype(jnp.float32) +
@@ -91,10 +137,15 @@ def _scale_by_adam_dtyped(b1, b2, eps, moment_dtype) -> optax.GradientTransforma
         out = jax.tree_util.tree_map(
             lambda m, v: (m / bc1) / (jnp.sqrt(v / bc2) + eps), mu32, nu32)
 
+        leaves, treedef = jax.tree_util.tree_flatten(mu32)
+        noise = treedef.unflatten([
+            _rounding_noise(_rounding_seed(count, i), jnp.shape(leaf))
+            for i, leaf in enumerate(leaves)])
         mu_new = jax.tree_util.tree_map(
-            lambda m, k: _sr_cast(m, k, moment_dtype), mu32, mu_keys)
+            lambda m, r: _sr_cast(m, r & jnp.uint32(0xFFFF), moment_dtype),
+            mu32, noise)
         nu_new = jax.tree_util.tree_map(
-            lambda v, k: _sr_cast(v, k, moment_dtype), nu32, nu_keys)
+            lambda v, r: _sr_cast(v, r >> 16, moment_dtype), nu32, noise)
         return out, optax.ScaleByAdamState(count=count, mu=mu_new, nu=nu_new)
 
     return optax.GradientTransformation(init, update)

@@ -832,3 +832,38 @@ def test_a_short_prefill_of_128_state_heads_leaves_the_pool_where_it_lies(
         "fusion", "dynamic-update-slice", "bitcast"}, state
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
     assert "grouped_expert_glu" in compiled.as_text()
+
+
+def test_bf16_moment_update_is_one_pass_at_the_memory_pace(topo):
+    """PR 55: the update of the 1.4b cell's largest stacked leaf (bf16
+    moments, bf16 gradients, float32 master; the norm, ``tx.update`` and
+    ``apply_updates`` of the engine's ``optimizer`` scope) by the compiler's
+    own account: 18 bytes a parameter (read 4 + 2 + 2 + 2, write 4 + 2 + 2)
+    and, since the rounding's noise is a counter hash, 40 operations a
+    parameter where two Threefry draws a leaf took 281 (50 ps a parameter on
+    the chip: the vector unit's pace, not the memory's 22)."""
+    import optax
+    from deepspeed_tpu.runtime.engine import _global_norm_f32
+    from deepspeed_tpu.runtime.optimizers import build_optimizer
+    chip = SingleDeviceSharding(topo.devices[0])
+    shape = (16, 2048, 8192)
+    tx = build_optimizer("adamw", {"lr": 1e-4, "weight_decay": 0.0,
+                                   "moment_dtype": "bfloat16"})
+
+    def update(params, opt_state, grads):
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, \
+            _global_norm_f32(grads)
+
+    params = {"w": _on(chip, shape, jnp.float32)}
+    opt_state = jax.tree_util.tree_map(
+        lambda x: _on(chip, x.shape, x.dtype), jax.eval_shape(tx.init, params))
+    compiled = jax.jit(update, donate_argnums=(0, 1)).lower(
+        params, opt_state, {"w": _on(chip, shape)}).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    n = np.prod(shape)
+    assert cost["bytes accessed"] / n == pytest.approx(18.0, abs=0.05)
+    assert cost["flops"] / n < 60
+    assert "rng-bit-generator" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
