@@ -100,16 +100,17 @@ CHUNK_COUNTS = ("ctx_entries", "chunk")
 # layer's grouped product (every dispatch of such a model), a latent
 # model's prefill over the pool (its prefill dispatches), a state-space
 # layer's one-row recurrence on the state pool (the decode dispatches of a
-# model with such layers)
+# model with such layers; a linear-attention layer's runs in XLA: "jnp")
 # (scripts/check_telemetry_schema.py DISPATCH_IMPLS, frozen)
 DISPATCH_IMPLS = ("kv_write", "experts", "latent", "state")
-# what a model with state-space layers adds to each prefill and decode
-# dispatch of ``last_step`` and to its ``serve/step`` span, from the host
-# (frozen in scripts/check_telemetry_schema.py): ``state_slots``, the rows
-# whose recurrent state the dispatch advanced (a prefill's one slot, a
-# decode step's served slots), and ``state_bytes``, the bytes of state and
-# of the convolution's last inputs it had to read and write for them, all
-# such layers (from shapes and dtypes)
+# what a model with state-space or linear-attention layers adds to each
+# prefill and decode dispatch of ``last_step`` and to its ``serve/step``
+# span, from the host (frozen in scripts/check_telemetry_schema.py):
+# ``state_slots``, the rows whose recurrent state the dispatch advanced (a
+# prefill's one slot, a decode step's served slots), and ``state_bytes``,
+# the bytes of state (and, of a state-space layer, of the convolution's
+# last inputs) it had to read and write for them, all such layers (from
+# shapes and dtypes)
 STATE_COUNTS = ("state_slots", "state_bytes")
 
 # A whole-prompt prefill longer than this pads to the next multiple of it,
@@ -360,10 +361,14 @@ class ServingEngine:
         # ``tables`` ends in the slot's ring
         window = int(getattr(self.config, "attn_window", 0) or 0)
         self.ring_pages = ring_pages(window, page_size) if window else 0
-        # a model with state-space layers keeps their recurrent state
-        # beside the page pools, a row a SLOT (ops/ssm.py HybridKVCache):
+        # a model with state-space or linear-attention layers keeps their
+        # recurrent state beside the page pools, a row a SLOT (ops/ssm.py
+        # HybridKVCache; its ``ssm`` member holds either kind's leaves):
         # no allocator, nothing to leak
-        self._stateful = bool(getattr(self.config, "has_ssm", False))
+        self._stateful = bool(getattr(self.config, "has_state", False))
+        # block-sparse attention layers keep compressed keys beside their
+        # pages, under the same tables (ops/block_sparse_attention.py)
+        self._sparse = bool(getattr(self.config, "has_sparse", False))
         with setup_span("setup/engine/pools"):
             caches = model.init_paged_caches(
                 num_pages, page_size, dtype=dtype,
@@ -400,7 +405,7 @@ class ServingEngine:
         self.caches = caches
         self.cache_dtype = dtype
         # bytes of recurrent state (and of the convolution's last inputs)
-        # a slot holds, all state-space layers; 0 for a model without
+        # a slot holds, all layers that keep one; 0 for a model without
         self.state_slot_bytes = sum(
             leaf.size * jnp.dtype(leaf.dtype).itemsize // max_batch
             for leaf in caches.ssm) if self._stateful else 0
@@ -495,7 +500,10 @@ class ServingEngine:
         # dispatch's one backend (models/transformer.py mix_ssm_paged: the
         # ``ssm_decode_update`` kernel or the jnp slice, step and masked
         # write); None for a model without such layers
-        self.state_impl = self.attention_impl if self._stateful else None
+        # a linear-attention layer's update is XLA's whatever the backend
+        self.state_impl = None if not self._stateful else \
+            self.attention_impl if getattr(self.config, "has_ssm", False) \
+            else "jnp"
         latent = bool(getattr(self.config, "is_latent", False))
         if latent:
             # the latent pools are written, and read by a decode step and
@@ -517,10 +525,12 @@ class ServingEngine:
         # started on what the one before it wrote; 0, and every prompt one
         # bucket, where no prefill onto a context already in the pool is
         # built: a window layer's ring is filled from an empty context
-        # (_refuse_for_ring), a selection over cached index keys takes one
-        # query (_refuse_unsupported)
+        # (_refuse_for_ring), a selection over cached index keys or over
+        # cached compressed keys takes one query (_refuse_unsupported,
+        # _refuse_for_sparse)
         self.prefill_piece_rows = PREFILL_PIECE_ROWS if (
             not self.ring_pages and (not latent or self._latent_dense)
+            and not self._sparse
             and PREFILL_PIECE_ROWS % page_size == 0) else 0
         self._paged_call = functools.partial(
             self.model.apply_with_paged_cache,
@@ -662,13 +672,18 @@ class ServingEngine:
                                           draft_params=draft_params)
         self._serve_event("serve/sched", **self.scheduler.meta())
         if self._stateful:
-            # one frozen event an engine: the state beside the pages, and
-            # what a dropped decode row costs (``_redo_state``)
+            # one frozen event an engine: the state beside the pages (a
+            # state-space layer's, with its convolution's last inputs and
+            # their ``conv_dtype``, or a linear-attention layer's matrix
+            # alone, ``kind: "linear"``), and what a dropped decode row
+            # costs (``_redo_state``)
+            conv = getattr(caches.ssm, "conv", None)
             self._serve_event(
                 "serve/state", layers=int(caches.ssm.state.shape[0]),
                 slot_bytes=int(self.state_slot_bytes),
                 dtype=jnp.dtype(caches.ssm.state.dtype).name,
-                conv_dtype=jnp.dtype(caches.ssm.conv.dtype).name,
+                **({"kind": "linear"} if conv is None else
+                   {"conv_dtype": jnp.dtype(conv.dtype).name}),
                 redo="prefill_from_zero")
         # incident plane: bundles snapshot this engine's health() and its
         # in-flight request traces alongside the flight-recorder dump
@@ -680,13 +695,16 @@ class ServingEngine:
 
     def _refuse_unsupported(self, tp_size, ep_size, decode_chunk=1):
         """What a latent-attention model, one with sliding-window layers,
-        or one with state-space layers (a recurrent state a slot beside
-        the pages) cannot be served with until someone builds it, refused
-        by name when the engine is made."""
+        one with state-space or linear-attention layers (a recurrent
+        state a slot beside the pages) or one with block-sparse attention
+        (compressed keys beside the pages) cannot be served with until
+        someone builds it, refused by name when the engine is made."""
         if getattr(self.config, "attn_window", 0):
             self._refuse_for_ring(tp_size, ep_size)
-        if getattr(self.config, "has_ssm", False):
+        if getattr(self.config, "has_state", False):
             self._refuse_for_state(tp_size, ep_size, decode_chunk)
+        if getattr(self.config, "has_sparse", False):
+            self._refuse_for_sparse(tp_size, ep_size)
         if not getattr(self.config, "is_latent", False):
             return
         selects = bool(getattr(self.config, "index_topk", 0))
@@ -746,30 +764,73 @@ class ServingEngine:
                 "the scanned periods' stacked weights have no sharding "
                 "rules yet")
 
-    def _refuse_for_state(self, tp_size, ep_size, decode_chunk):
-        """A state-space layer's state is the whole of one sequence's past
-        in one row a slot: it can be started from zero and advanced, not
-        shared, rolled back or cut at a page boundary."""
+    def _refuse_for_sparse(self, tp_size, ep_size):
+        """A block-sparse attention layer selects a query's blocks from
+        compressed keys: a prefill computes them from the keys it brings
+        and selects over those, from an empty context; a decode step
+        selects over the pool's for its one query."""
         if getattr(self.serving.prefix_cache, "enabled", False):
             raise ServingUnsupported(
-                "prefix_cache with state-space layers",
+                "prefix_cache with block-sparse attention",
+                "a prefill onto shared pages would select over compressed "
+                "keys already in the pool (the selection for T > 1 queries "
+                "on a pooled context is not built), and a page's last "
+                "compressed key averages keys of the page after it, which "
+                "another request's continuation would not share")
+        sched = self.serving.scheduler
+        policy = getattr(sched, "policy", "monolithic")
+        if policy != "monolithic":
+            raise ServingUnsupported(
+                f"scheduler.policy {policy!r} with block-sparse attention",
+                "a prefill chunk attends from a context already in the "
+                "pool, and the selection over cached compressed keys for "
+                "T > 1 queries is not built; only whole-prompt prefills "
+                "(monolithic) and T=1 decode steps are")
+        if getattr(getattr(sched, "speculative", None), "enabled", False):
+            raise ServingUnsupported(
+                "scheduler.speculative with block-sparse attention",
+                "a verify window is T > 1 queries on a pooled context, "
+                "and a rejected draft's compressed keys would have to be "
+                "taken back")
+        if tp_size > 1 or ep_size > 1:
+            raise ServingUnsupported(
+                "tp_size / ep_size > 1 with block-sparse attention",
+                "the compressed-key pool and the block gather have no "
+                "sharding rules yet")
+
+    def _state_layers_name(self):
+        """The kind of layers that keep a recurrent state, for a refusal
+        to name."""
+        return "state-space layers" if getattr(self.config, "has_ssm",
+                                               False) \
+            else "linear-attention layers"
+
+    def _refuse_for_state(self, tp_size, ep_size, decode_chunk):
+        """A state-space or linear-attention layer's state is the whole of
+        one sequence's past in one row a slot: it can be started from
+        zero and advanced, not shared, rolled back or cut at a page
+        boundary."""
+        layers = self._state_layers_name()
+        if getattr(self.serving.prefix_cache, "enabled", False):
+            raise ServingUnsupported(
+                f"prefix_cache with {layers}",
                 "a prefill onto shared pages would need the recurrent "
                 "state as it stood at the page boundary, and no snapshot "
                 "of it is kept")
         sched = self.serving.scheduler
         if getattr(getattr(sched, "speculative", None), "enabled", False):
             raise ServingUnsupported(
-                "scheduler.speculative with state-space layers",
+                f"scheduler.speculative with {layers}",
                 "a verify window advances the state by every drafted "
                 "token, and a rejected draft would have to roll it back")
         if int(decode_chunk) > 1:
             raise ServingUnsupported(
-                "decode_chunk > 1 with state-space layers",
+                f"decode_chunk > 1 with {layers}",
                 "the device scan runs on past a request's last token, "
                 "and the state it advanced there cannot be taken back")
         if tp_size > 1 or ep_size > 1:
             raise ServingUnsupported(
-                "tp_size / ep_size > 1 with state-space layers",
+                f"tp_size / ep_size > 1 with {layers}",
                 "the state pools and the scanned periods' stacked weights "
                 "have no sharding rules yet")
 
@@ -784,9 +845,14 @@ class ServingEngine:
                 "pages moved and re-seated in the receiving slot's ring")
         if self._stateful:
             raise ServingUnsupported(
-                f"{what} with state-space layers",
+                f"{what} with {self._state_layers_name()}",
                 "a handed-off or imported request would need its slot's "
                 "recurrent state moved with its pages")
+        if self._sparse:
+            raise ServingUnsupported(
+                f"{what} with block-sparse attention",
+                "a page's last compressed key averages keys of the page "
+                "after it: pages moved one by one would not carry it")
 
     def _seat(self, slot: int, req_id):
         """Point ``slot``'s row of the tables at the request's pages (and
@@ -1569,7 +1635,8 @@ class ServingEngine:
         self._report["prompt_tokens"] += sizes.get("real", 0)
         return out
 
-    def kernel_grid(self, phase, batch, tokens, starts, config=None):
+    def kernel_grid(self, phase, batch, tokens, starts, config=None,
+                    real=None):
         """(run, full) grid steps of the ragged paged-attention kernel in
         one dispatch, every layer of ``config`` (default the target
         model): the (q tile, kv step) pairs that hold keys, reckoned from
@@ -1579,7 +1646,9 @@ class ServingEngine:
         ``decode_chunk`` and ``spec_draft`` run ``tokens`` T=1 forwards,
         each one token further.  A window layer's call reads its ring (a
         decode step) or the prefill's own rows as pages, from the first
-        key inside the window."""
+        key inside the window.  A block-sparse attention layer's call reads
+        the sequences under ``sparse.dense_len`` alone (``real``: a
+        prefill's tokens, where its context ends)."""
         if self.attention_impl != "pallas":
             return 0, 0
         config = config or self.config
@@ -1592,6 +1661,9 @@ class ServingEngine:
         ring = self.ring_pages if windows else 0
         ctx = np.asarray(starts)[None, :] \
             + T * np.arange(1, calls + 1)[:, None]
+        if getattr(config, "has_sparse", False):
+            context = ctx if real is None else ctx - T + int(real)
+            ctx = np.where(context < config.sparse.dense_len, ctx, 0)
 
         def steps(n_layers, ctx, width, window=None, ring=None):
             key = (batch, T, id(config), width, window)
@@ -1610,7 +1682,7 @@ class ServingEngine:
                                            self.page_size, window),
                 n_layers * calls * tiles.grid_steps])
 
-        state_layers = sum(getattr(config, "ssm_pattern", None) or ())
+        state_layers = getattr(config, "state_layers", 0)
         run = steps(config.n_layers - len(windows) - state_layers, ctx,
                     self.tables.shape[1] - ring)
         for window in sorted(set(windows)):
@@ -1645,7 +1717,7 @@ class ServingEngine:
         head_rows = int(tokens if head_rows is None else head_rows)
         t0_ns = time.perf_counter_ns()
         kernel_grid, kernel_grid_full = self.kernel_grid(
-            phase, batch, tokens, starts, config)
+            phase, batch, tokens, starts, config, sizes.get("real"))
         # what the dispatch compiled: the draft model's call binds no
         # backend, so it resolves its own
         kv_write = self.attention_impl if config is None else \
@@ -1667,7 +1739,7 @@ class ServingEngine:
         elif self._stateful and config is None:
             # the rows whose state it advances: a prefill's one slot, a
             # decode step's served slots; each reads and writes its state
-            # and its convolution's last inputs in every such layer
+            # (and its convolution's last inputs) in every such layer
             rows = int(batch) if phase == "prefill" else \
                 int(np.count_nonzero(np.asarray(starts)))
             counted = dict(zip(STATE_COUNTS, (
@@ -2266,16 +2338,15 @@ class ServingEngine:
         if pools:
             leaks["pool_page_mismatch"] = pools
         if self._stateful:
-            # a state-space model's state is a row a slot: nothing to
-            # allocate or free, so all there is to audit is that the
-            # pools hold ``max_batch`` rows (``health()`` says the bytes)
+            # a recurrent state is a row a slot: nothing to allocate or
+            # free, so all there is to audit is that the pools hold
+            # ``max_batch`` rows (``health()`` says the bytes)
             ssm = self.caches.ssm
-            if (ssm.state.shape[1], ssm.conv.shape[1]) != \
-                    (self.max_batch,) * 2:
+            if any(leaf.shape[1] != self.max_batch for leaf in ssm):
                 leaks["state_slot_mismatch"] = {
                     "slot_bytes": self.state_slot_bytes,
-                    "state": tuple(ssm.state.shape),
-                    "conv": tuple(ssm.conv.shape)}
+                    **{name: tuple(leaf.shape)
+                       for name, leaf in ssm._asdict().items()}}
         unseated = [s for s, req in enumerate(self.slots)
                     if req is not None and self.ring_pages and list(
                         self.tables[s, -self.ring_pages:])

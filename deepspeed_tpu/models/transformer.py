@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.ops.attention import attention, reference_attention
+from deepspeed_tpu.ops.block_sparse_attention import SparseSizes
 from deepspeed_tpu.ops.decode_attention import (KVCache, decode_attention,
                                                 init_cache, update_cache)
 from deepspeed_tpu.parallel.topology import (BATCH_AXES, DP_AXIS, FSDP_AXIS,
@@ -98,6 +99,25 @@ class TransformerConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # ``lin_pattern``: per layer, whether its mixer is a linear-attention
+    # layer with a constant decay a head (Lightning Attention,
+    # ops/linear_attention.py) in place of attention: ``lin_heads`` heads
+    # of ``lin_head_dim`` for q, k and v alike (no grouping), a matrix
+    # state [head_dim, head_dim] a head, an RMSNorm over the heads'
+    # concatenated output and a sigmoid gate ahead of the output
+    # projection; per-head q/k norms under ``qk_norm``, rotary where
+    # ``rope_pattern`` says so
+    lin_pattern: Optional[Tuple[bool, ...]] = None
+    lin_heads: int = 0
+    lin_head_dim: int = 0
+    # Block-sparse attention in the attention layers (its ``SparseSizes``;
+    # ops/block_sparse_attention.py, InfLLM v2): a query of a context of
+    # ``dense_len`` or more attends ``topk`` blocks of ``block`` keys a
+    # key/value head, picked by its heads' scores over compressed keys
+    # (the mean of ``kernel`` keys every ``stride`` tokens); the first
+    # ``init_blocks`` blocks and those of the last ``window`` keys are
+    # always among them.  Under ``dense_len`` the layer attends densely
+    sparse: Optional[SparseSizes] = None
     attn_gate: bool = False                 # attention output x sigmoid(h
     #   wg_attn) ahead of ``wo`` (afmoe's gated attention)
     sandwich_norm: bool = False             # pre-norms AND post-norms on
@@ -200,6 +220,15 @@ class TransformerConfig:
     # default): the token keeps its identity and attention is a few
     # percent of the stream, as in a trained network
     init_embed_std: Optional[float] = None
+    # the seeded spread of an untied head's elements.  None is
+    # 1 / sqrt(hidden): logits of unit spread on a normed stream.  A model
+    # whose logits carry a multiplier (``final_logit_scale``, muP's
+    # base / hidden) presumes a trained head; seeded at 1 / sqrt(hidden)
+    # its largest logit is a fraction of 1, and a check that holds errors
+    # to the larger of 1 and the largest logit then sees nothing.
+    # ``1 / (final_logit_scale * sqrt(hidden))`` seeds logits of unit
+    # spread again
+    init_head_std: Optional[float] = None
 
     @property
     def is_moe(self):
@@ -233,11 +262,39 @@ class TransformerConfig:
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
+    def has_lin(self):
+        """Some layers' mixer is a linear-attention layer
+        (``lin_pattern``): a matrix state a slot beside the pages."""
+        return bool(self.lin_pattern) and any(self.lin_pattern)
+
+    def layer_lin(self, i):
+        return bool(self.lin_pattern) and bool(self.lin_pattern[i])
+
+    @property
+    def has_state(self):
+        """Some layers keep a recurrent state a slot in place of keys and
+        values (state-space or linear-attention layers)."""
+        return self.has_ssm or self.has_lin
+
+    def layer_state(self, i):
+        return self.layer_ssm(i) or self.layer_lin(i)
+
+    @property
+    def state_layers(self):
+        """How many layers keep such a state."""
+        return sum(self.layer_state(i) for i in range(self.n_layers))
+
+    @property
+    def has_sparse(self):
+        """The attention layers select blocks of keys (``sparse``)."""
+        return self.sparse is not None
+
+    @property
     def counts_serving(self):
         """A serving dispatch of this model returns ``SERVE_COUNTERS``
         (and is told which of its rows are tokens)."""
         return self.is_latent or (self.is_moe and self.moe_dropless) \
-            or self.attn_window > 0
+            or self.attn_window > 0 or self.has_sparse
 
     @property
     def attn_window(self):
@@ -275,9 +332,11 @@ class TransformerConfig:
         """Layers differ in structure, so params["layers"] is a list (and
         ``layer_period`` may stack the periods after it), for one of three
         reasons: some layers hold experts (MoE), the attention is latent,
-        or the kinds of mixer differ (state-space layers among attention
-        layers)."""
-        return self.is_moe or self.is_latent or self.has_ssm
+        or the kinds of mixer differ (state-space or linear-attention
+        layers among attention layers; block-sparse attention keeps its
+        layers listed too)."""
+        return self.is_moe or self.is_latent or self.has_state \
+            or self.has_sparse
 
     @property
     def keeps_flash_residuals(self):
@@ -381,6 +440,8 @@ class TransformerConfig:
         per_layer = (d * self.n_heads * dh + 2 * d * self.kv_heads * dh +
                      self.n_heads * dh * d)
         per_layer += 2 * d  # norms
+        if self.attn_gate:
+            per_layer += d * self.n_heads * dh
         if self.qk_norm and not self.is_latent:
             per_layer += (self.n_heads * dh + self.kv_heads * dh
                           if self.qk_norm == "rms_flat" else 2 * dh)
@@ -394,6 +455,13 @@ class TransformerConfig:
             mixer = d * (inner + conv + self.ssm_heads) + inner * d \
                 + (self.ssm_conv + 1) * conv + 3 * self.ssm_heads + inner
             total += sum(self.ssm_pattern) * (mixer + 2 * d - per_layer)
+        if self.has_lin:
+            # a linear-attention layer: q, k, v, the gate and the output
+            # projection of the inner width, the output norm, the q/k norms
+            inner = self.lin_heads * self.lin_head_dim
+            mixer = 5 * d * inner + inner \
+                + (2 * self.lin_head_dim if self.qk_norm else 0)
+            total += sum(self.lin_pattern) * (mixer + 2 * d - per_layer)
         if self.is_moe:
             # what this process HOLDS: the router's published width, the
             # held experts (all of them on the capacity path), the shared
@@ -747,15 +815,17 @@ class CausalTransformerLM:
         self.config = config
         self.gate = None
         c = config
-        for name in ("local_attn_pattern", "rope_pattern", "ssm_pattern"):
+        for name in ("local_attn_pattern", "rope_pattern", "ssm_pattern",
+                     "lin_pattern"):
             pattern = getattr(c, name)
             assert pattern is None or len(pattern) == c.n_layers, \
                 f"{name} has {len(pattern)} entries for {c.n_layers} layers"
         if c.rope_pattern is not None or c.layer_period:
             assert c.layers_listed, (
                 "rope_pattern / layer_period need a listed layer stack: a "
-                "model with expert layers, latent attention or "
-                "state-space layers (TransformerConfig.layers_listed)")
+                "model with expert layers, latent attention, state-space "
+                "layers, linear-attention layers or block-sparse attention "
+                "(TransformerConfig.layers_listed)")
         if c.has_ssm:
             assert c.ssm_heads % c.ssm_groups == 0 and c.ssm_state > 0 \
                 and c.ssm_head_dim > 0 and c.ssm_conv > 1, \
@@ -763,6 +833,19 @@ class CausalTransformerLM:
                 "ssm_head_dim, ssm_state, ssm_groups, ssm_conv)"
             assert not (c.is_latent or c.attn_window or c.parallel_block), \
                 "state-space layers stand among plain attention layers"
+        if c.has_lin:
+            assert c.lin_heads > 0 and c.lin_head_dim > 0, \
+                "a linear-attention layer needs lin_heads and lin_head_dim"
+            assert not (c.is_latent or c.attn_window or c.parallel_block
+                        or c.has_ssm), (
+                "linear-attention layers stand among plain (or "
+                "block-sparse) attention layers")
+        if c.has_sparse:
+            c.sparse.check()
+            assert not (c.is_latent or c.attn_window or c.use_alibi
+                        or c.attn_logit_softcap), (
+                "block-sparse attention selects over plain causal "
+                "grouped-query attention")
         if c.layer_period:
             lead, period = c.leading_layers, c.layer_period
             assert (c.n_layers - lead) % period == 0 and \
@@ -772,9 +855,9 @@ class CausalTransformerLM:
             for i in range(lead, c.n_layers):
                 at = lead + (i - lead) % period
                 assert (c.layer_window(i), c.layer_rotary(i),
-                        c.layer_ssm(i)) == \
+                        c.layer_ssm(i), c.layer_lin(i)) == \
                     (c.layer_window(at), c.layer_rotary(at),
-                     c.layer_ssm(at)), \
+                     c.layer_ssm(at), c.layer_lin(at)), \
                     f"layer {i} does not repeat layer {at}'s pattern"
         if config.is_moe and not config.moe_dropless:
             from deepspeed_tpu.moe.sharded_moe import TopKGate
@@ -812,8 +895,9 @@ class CausalTransformerLM:
         # dense() divides by sqrt(fan_in): the fan-in that gives the
         # embeddings' seeded spread
         embed_fan = d if c.init_embed_std is None else c.init_embed_std ** -2
+        head_fan = d if c.init_head_std is None else c.init_head_std ** -2
         if c.layers_listed:
-            return self._init_moe(rng, dtype, dense, embed_fan)
+            return self._init_moe(rng, dtype, dense, embed_fan, head_fan)
 
         layers = {
             "attn_norm": jnp.ones((L, d), dtype),
@@ -866,12 +950,12 @@ class CausalTransformerLM:
         if not c.use_rope and not c.use_alibi:
             params["pos_embed"] = dense(keys[8], (c.max_seq_len, d), d)
         if not c.tie_embeddings:
-            params["lm_head"] = dense(keys[9], (d, v), d)
+            params["lm_head"] = dense(keys[9], (d, v), head_fan)
             if c.lm_head_bias:
                 params["lm_head_b"] = jnp.zeros((v,), dtype)
         return params
 
-    def _init_moe(self, rng, dtype, dense, embed_fan):
+    def _init_moe(self, rng, dtype, dense, embed_fan, head_fan):
         """MoE variant: ``layers`` is a LIST of per-layer dicts (layers
         differ in structure, so the forward unrolls instead of scanning —
         reference MoE models interleave dense/expert layers the same way)."""
@@ -880,7 +964,8 @@ class CausalTransformerLM:
         dh, H, Hkv, E = c.head_dim, c.n_heads, c.kv_heads, c.moe_num_experts
         keys = jax.random.split(rng, c.n_layers + 4)
 
-        def one_layer(key, moe: bool, ssm: bool = False):
+        def one_layer(key, moe: bool, ssm: bool = False,
+                      lin: bool = False):
             ks = jax.random.split(key, 8)
             norm_keys = (("attn_post_norm", "mlp_post_norm")
                          if c.post_norm_only else ("attn_norm", "mlp_norm"))
@@ -888,6 +973,9 @@ class CausalTransformerLM:
                 norm_keys += ("attn_post_norm", "mlp_post_norm")
             if ssm:
                 layer = {"ssm": self._init_ssm(ks[0], dtype, dense)}
+                layer.update({k: jnp.ones((d,), dtype) for k in norm_keys})
+            elif lin:
+                layer = {"lin": self._init_lin(ks[0], dtype, dense)}
                 layer.update({k: jnp.ones((d,), dtype) for k in norm_keys})
             elif c.is_latent:
                 layer = self._init_latent_attn(ks[0], dtype, dense)
@@ -903,7 +991,7 @@ class CausalTransformerLM:
                 if c.attn_gate:
                     layer["wg_attn"] = dense(jax.random.fold_in(ks[0], 1),
                                              (d, H * dh), d)
-            if c.qk_norm and not ssm:
+            if c.qk_norm and not (ssm or lin):
                 qd, kd = ((H * dh, Hkv * dh) if c.qk_norm == "rms_flat"
                           else (dh, dh))
                 layer["q_norm"] = jnp.ones((qd,), dtype)
@@ -952,7 +1040,7 @@ class CausalTransformerLM:
             "tok_embed": dense(keys[-1], (v, d), embed_fan),
             "final_norm": jnp.ones((d,), dtype),
             "layers": [one_layer(keys[i], self._is_moe_layer(i),
-                                 c.layer_ssm(i))
+                                 c.layer_ssm(i), c.layer_lin(i))
                        for i in range(lead)],
         }
         if period:
@@ -962,13 +1050,13 @@ class CausalTransformerLM:
             params["periods"] = [
                 jax.vmap(functools.partial(
                     one_layer, moe=self._is_moe_layer(lead + j),
-                    ssm=c.layer_ssm(lead + j)))(
+                    ssm=c.layer_ssm(lead + j), lin=c.layer_lin(lead + j)))(
                         keys[lead + j:c.n_layers:period])
                 for j in range(period)]
         if not c.use_rope:
             params["pos_embed"] = dense(keys[-2], (c.max_seq_len, d), d)
         if not c.tie_embeddings:
-            params["lm_head"] = dense(keys[-3], (d, v), d)
+            params["lm_head"] = dense(keys[-3], (d, v), head_fan)
         return params
 
     def _init_ssm(self, key, dtype, dense):
@@ -1005,6 +1093,27 @@ class CausalTransformerLM:
             "norm": jnp.ones((inner,), dtype),
             "w_out": dense(ks[4], (inner, d), inner),
         }
+
+    def _init_lin(self, key, dtype, dense):
+        """One linear-attention layer's mixer: q, k and v of ``lin_heads``
+        heads of ``lin_head_dim`` each, the output gate ``wg``, the norm
+        over the heads' concatenated output, the output projection, and
+        under ``qk_norm`` a per-head norm weight for q and k.  The decay a
+        head is no weight (``ops/linear_attention.py decay_slopes``)."""
+        c = self.config
+        d, D = c.hidden_size, c.lin_head_dim
+        inner = c.lin_heads * D
+        ks = jax.random.split(key, 5)
+        w = {"wq": dense(ks[0], (d, inner), d),
+             "wk": dense(ks[1], (d, inner), d),
+             "wv": dense(ks[2], (d, inner), d),
+             "wg": dense(ks[3], (d, inner), d),
+             "norm": jnp.ones((inner,), dtype),
+             "wo": dense(ks[4], (inner, d), inner)}
+        if c.qk_norm:
+            w["q_norm"] = jnp.ones((D,), dtype)
+            w["k_norm"] = jnp.ones((D,), dtype)
+        return w
 
     def _init_latent_attn(self, key, dtype, dense):
         """One layer's latent-attention weights: the query's low-rank pair
@@ -1187,6 +1296,14 @@ class CausalTransformerLM:
         """Causal attention over the whole sequence, no cache (the trainer,
         ``apply``)."""
         c = self.config
+        if c.has_sparse and q.shape[1] >= c.sparse.dense_len:
+            # a whole sequence is its own context: at ``sparse.dense_len``
+            # or more every query attends its selected blocks, the
+            # selection a mask over the sequence's keys
+            from deepspeed_tpu.ops.block_sparse_attention import \
+                sparse_prefill_attention
+            return sparse_prefill_attention(q, k, v, c.sparse,
+                                            c.attn_scale), cache
         window = layer.get("attn_window")
         if c.attn_window and isinstance(window, int):
             # the layer's kind is static (a scanned period's place): its
@@ -1282,7 +1399,7 @@ class CausalTransformerLM:
         from deepspeed_tpu.ops.paged_attention import (paged_decode_attention,
                                                        write_paged)
         c = self.config
-        with (jax.named_scope("attn_full") if c.attn_window or c.has_ssm
+        with (jax.named_scope("attn_full") if c.attn_window or c.has_state
               else contextlib.nullcontext()):
             pools = write_paged(pools, index, block_tables, lengths, k, v,
                                 impl=impl, interpret=interpret)
@@ -1291,6 +1408,82 @@ class CausalTransformerLM:
                 softmax_scale=c.attn_scale, impl=impl, interpret=interpret,
                 logit_softcap=c.attn_logit_softcap, layer=index, items=items)
         return attn, pools
+
+    def mix_sparse_paged(self, q, k, v, layer, pools, *, index,
+                         block_tables, lengths, context, read_lengths, impl,
+                         interpret, items, counts=None):
+        """A block-sparse attention layer on the serving path
+        (``ops/block_sparse_attention.py``): layer ``index`` of the
+        STACKED ``SparseKVCache``, its keys and values written and read as
+        :meth:`mix_paged` does, its compressed keys beside them under the
+        same tables.
+
+        ``context`` [B] is each sequence's context at this dispatch (what
+        it held and the tokens it brings).  A sequence under
+        ``sparse.dense_len`` attends densely through the pages' own read
+        (``read_lengths``: its length there, 0 for the others, so that
+        read touches nothing of theirs; ``items`` is built from it); the
+        others attend their selected blocks.  A decode step (T = 1)
+        writes the compressed key its token completes, then reads the
+        compressed keys of its table and ``sparse.topk`` blocks of K/V a
+        (slot, key/value head) and nothing else of the pool; T > 1 is a
+        prefill FROM AN EMPTY CONTEXT (``lengths`` 0: ``ServingEngine``
+        refuses what would break that), which writes every compressed key
+        its rows start and applies the selection as a mask over the keys
+        it brings.  A decode dispatch counts ``selected`` /
+        ``context_keys`` over its real rows (a prefill counts none: its
+        early queries attend everything, and the ratio is the decode
+        step's)."""
+        from deepspeed_tpu.ops import block_sparse_attention as bsa
+        from deepspeed_tpu.ops.paged_attention import (PagedKVCache,
+                                                       paged_decode_attention,
+                                                       write_paged)
+        c = self.config
+        sizes = c.sparse
+        B, T = q.shape[:2]
+        read = functools.partial(
+            paged_decode_attention, softmax_scale=c.attn_scale, impl=impl,
+            interpret=interpret, layer=index, items=items)
+        with jax.named_scope("sparse_attn"):
+            pools = pools._replace(**write_paged(
+                PagedKVCache(pools.k_pages, pools.v_pages), index,
+                block_tables, lengths, k, v, impl=impl,
+                interpret=interpret)._asdict())
+        kv = PagedKVCache(pools.k_pages, pools.v_pages)
+        dense = (context < sizes.dense_len)[:, None, None, None]
+        if T > 1:
+            k, v = (k.astype(pools.k_pages.dtype),
+                    v.astype(pools.v_pages.dtype))     # as the pages hold them
+            with jax.named_scope("ckey_write"):
+                c_keys = bsa.compress_keys(k, sizes)
+                pools = pools._replace(c_pages=bsa.write_compressed_prefill(
+                    pools.c_pages, index, block_tables, c_keys))
+            with jax.named_scope("sparse_attn"):
+                attn = read(q, kv, block_tables, read_lengths)
+            if T < sizes.dense_len:     # no context of T rows selects
+                return attn, pools
+            chosen = jax.lax.cond(
+                jnp.all(dense), lambda: jnp.zeros_like(q),
+                lambda: bsa.sparse_prefill_attention(
+                    q, k, v, sizes, c.attn_scale, c=c_keys))
+            return jnp.where(dense, attn, chosen), pools
+        with jax.named_scope("ckey_write"):
+            pools = bsa.write_compressed_decode(pools, index, block_tables,
+                                                lengths, sizes)
+        with jax.named_scope("sparse_attn"):
+            attn = read(q, kv, block_tables, read_lengths)
+        chosen, attended = bsa.sparse_decode_attention(
+            q[:, 0], pools, index, block_tables, context, sizes,
+            c.attn_scale)
+        if counts is not None:
+            real = counts.real[:, 0]
+            counts.add(
+                selected=jnp.sum(jnp.where(
+                    real, jnp.where(dense[:, 0, 0, 0], context, attended),
+                    0)).astype(jnp.int32),
+                context_keys=jnp.sum(jnp.where(real, context, 0)
+                                     ).astype(jnp.int32))
+        return jnp.where(dense, attn, chosen[:, None]), pools
 
     @jax.named_scope("attn_window")
     def mix_ring(self, q, k, v, layer, pool, *, index, window, ring_tables,
@@ -1483,6 +1676,92 @@ class CausalTransformerLM:
                 conv_pool = jax.lax.dynamic_update_slice(
                     conv_pool, tail[b][None, None], (index, slots[b], 0))
         return delta, type(pool)(state_pool, conv_pool)
+
+    # ------------------------------------------------------------------
+    # The linear-attention mixer, in attention's place as the state-space
+    # one is: ``mix(h, weights, cache, positions) -> (delta, cache)``,
+    # ``positions`` None for a layer that carries none.
+    # ------------------------------------------------------------------
+    def _lin_mixer(self, h, w, state, positions, real=None):
+        """A linear-attention mixer with a constant decay a head over T
+        rows a sequence.  h: [B, T, d], normed; ``state`` [B, H, D, D]
+        float32: what the rows before row 0 left (zeros ahead of position
+        0); ``positions`` [B, T] turn q and k (None: no rotary); ``real``
+        [B]: how many of the T rows are tokens (None: all), the others
+        advance nothing.  Returns (delta [B, T, d], state).  A decode
+        dispatch (T = 1) hands as ``state`` the recurrence on the state
+        where it lies, ``state(q, k, v, slopes, scale=) -> (o, pool)``,
+        and gets the pool back in the state's place."""
+        from deepspeed_tpu.ops.linear_attention import (decay_slopes,
+                                                        linear_scan,
+                                                        linear_step)
+        c = self.config
+        B, T, _ = h.shape
+        H, D = c.lin_heads, c.lin_head_dim
+        with jax.named_scope("attn"):
+            q, k, v = ((h @ w[name]).reshape(B, T, H, D)
+                       for name in ("wq", "wk", "wv"))
+            if "q_norm" in w:
+                q = _norm(q, w["q_norm"], c.norm_eps, True)
+                k = _norm(k, w["k_norm"], c.norm_eps, True)
+            if positions is not None:
+                q, k = (_rope(x, positions, c.rope_theta) for x in (q, k))
+        with jax.named_scope("lin_attn"):
+            slopes, scale = decay_slopes(H), 1.0 / math.sqrt(D)
+            if T == 1:
+                row = (q[:, 0], k[:, 0], v[:, 0], slopes)
+                o, state = state(*row, scale=scale) if callable(state) \
+                    else linear_step(*row, state, scale)
+                o = o[:, None]
+            else:
+                o, state = linear_scan(q, k, v, slopes, state, real, scale)
+            # the norm over the heads' whole output first, then the gate
+            o = _norm(o.reshape(B, T, H * D).astype(jnp.float32), w["norm"],
+                      c.norm_eps, True) * jax.nn.sigmoid(
+                          (h @ w["wg"]).astype(jnp.float32))
+        with jax.named_scope("attn"):
+            return o.astype(h.dtype) @ w["wo"], state
+
+    def mix_lin_whole(self, h, w, cache, positions):
+        """The linear-attention mixer over whole sequences from an empty
+        state (the trainer, ``apply``)."""
+        c = self.config
+        delta, _ = self._lin_mixer(
+            h, w, jnp.zeros((h.shape[0], c.lin_heads, c.lin_head_dim,
+                             c.lin_head_dim), jnp.float32), positions)
+        return delta, cache
+
+    def mix_lin_paged(self, h, w, pool, positions, *, index, lengths,
+                      real_lengths, slots):
+        """A linear-attention layer on the serving path: its state lives
+        in ``pool`` (``ops/linear_attention.py LinearStateCache``) at
+        layer ``index`` of the linear layers' stack, a row a SLOT, read
+        and written in place, by :meth:`mix_ssm_paged`'s rules: ``slots``
+        [B] (a prefill) starts from zeros at ``lengths`` 0 and from the
+        slot's state otherwise, and advances it by the first
+        ``real_lengths`` rows; ``slots`` None is a decode dispatch, row b
+        is slot b, and a row at ``lengths`` 0 keeps its state bit for
+        bit."""
+        from deepspeed_tpu.ops.linear_attention import state_decode_update
+        from deepspeed_tpu.ops.ssm import read_slot_state, write_slot_state
+        (state_pool,) = pool
+        B = h.shape[0]
+        if slots is None:
+            delta, state_pool = self._lin_mixer(
+                h, w, functools.partial(state_decode_update, state_pool,
+                                        index, live=lengths > 0), positions)
+            return delta, type(pool)(state_pool)
+        with jax.named_scope("lin_attn"):   # one sequence a prefill dispatch
+            state = jnp.where(
+                (lengths == 0)[:, None, None, None], 0.0, jnp.stack([
+                    read_slot_state(state_pool, index, slots[b])
+                    for b in range(B)]))
+        delta, state = self._lin_mixer(h, w, state, positions, real_lengths)
+        with jax.named_scope("lin_attn"):
+            for b in range(B):
+                state_pool = write_slot_state(state_pool, index, slots[b],
+                                              state[b])
+        return delta, type(pool)(state_pool)
 
     def _latent_fresh(self, q, k, idx, layer, positions=None, counts=None,
                       context=None, impl=None, interpret=False):
@@ -1792,7 +2071,10 @@ class CausalTransformerLM:
         expert layer adds to.  ``rotary`` (static): whether this layer's q
         and k turn (``config.layer_rotary``).  A layer with ``ssm``
         weights has a state-space mixer in attention's place, and its
-        ``mix`` is ``mix(h, weights, cache) -> (delta, cache)``."""
+        ``mix`` is ``mix(h, weights, cache) -> (delta, cache)``; one with
+        ``lin`` weights a linear-attention mixer, ``mix(h, weights, cache,
+        positions) -> (delta, cache)``, ``positions`` None where
+        ``rotary`` is false."""
         c = self.config
         if c.parallel_block:
             # GPT-J / parallel-residual NeoX: both sub-blocks read the
@@ -1812,6 +2094,9 @@ class CausalTransformerLM:
         h = _pre_norm(x, layer, "attn_norm", c)
         if "ssm" in layer:      # a state-space mixer in attention's place
             delta, cache = mix(h, layer["ssm"], cache)
+        elif "lin" in layer:    # a linear-attention one
+            delta, cache = mix(h, layer["lin"], cache,
+                               positions if rotary else None)
         else:
             delta, cache = self._attn_delta(h, layer, positions, mix, cache,
                                             rotary)
@@ -1828,6 +2113,7 @@ class CausalTransformerLM:
         (static; None: whatever ``layer["attn_window"]`` holds): the
         layer's sliding window, 0 for a full-attention layer."""
         mix = self.mix_ssm_whole if "ssm" in layer \
+            else self.mix_lin_whole if "lin" in layer \
             else self.mix_latent_whole if self.config.is_latent \
             else self.mix_full
         if window is not None:
@@ -2003,11 +2289,11 @@ class CausalTransformerLM:
         the decode forward stays a single scan.  (MoE models use a list of
         caches matching their per-layer params list.)"""
         c = self.config
-        if c.is_latent or c.has_ssm:
+        if c.is_latent or c.has_state or c.has_sparse:
             raise NotImplementedError(
-                "latent attention and state-space layers have no dense "
-                "KVCache path: serve them through the paged pools "
-                "(init_paged_caches)")
+                "latent attention, block-sparse attention, state-space "
+                "and linear-attention layers have no dense KVCache path: "
+                "serve them through the paged pools (init_paged_caches)")
         if c.is_moe:
             return [init_cache(batch, max_seq, c.kv_heads, c.head_dim, dtype)
                     for _ in range(c.n_layers)]
@@ -2074,7 +2360,10 @@ class CausalTransformerLM:
         state-space layers gets a ``HybridKVCache``: the stack for its
         attention layers alone, and for the others ``state_slots`` rows of
         recurrent state (float32) and of the convolution's last inputs
-        (``dtype``), a row a slot."""
+        (``dtype``), a row a slot; one with linear-attention layers the
+        same with a ``LinearStateCache`` (a matrix state a slot, no tail)
+        in ``ssm``'s place.  Block-sparse attention layers' stack is a
+        ``SparseKVCache``: the pages and the compressed keys."""
         from deepspeed_tpu.ops.paged_attention import (PagedKVCache,
                                                        WindowedKVCache,
                                                        paged_pool_shape,
@@ -2082,6 +2371,25 @@ class CausalTransformerLM:
         c = self.config
         assert not c.use_alibi, \
             "paged serving has no ALiBi: the paged kernels take no bias"
+
+        def attention_pools(layers):
+            """The stacked pools of ``layers`` attention layers."""
+            shape = paged_pool_shape(layers, num_pages, c.kv_heads,
+                                     page_size, c.head_dim)
+            if c.has_sparse:
+                from deepspeed_tpu.ops.block_sparse_attention import \
+                    init_sparse_pools
+                c.sparse.check(page_size)
+                assert shape[2] == c.kv_heads and \
+                    page_size % c.sparse.stride == 0, (
+                        "block-sparse attention reads its blocks out of "
+                        "pages of whole heads and whole compressed keys")
+                return init_sparse_pools(layers, num_pages, c.kv_heads,
+                                         page_size, c.head_dim,
+                                         c.sparse.stride, dtype)
+            return PagedKVCache(jnp.zeros(shape, dtype),
+                                jnp.zeros(shape, dtype))
+
         if c.attn_window:
             assert c.layers_listed, (
                 "window layers are served paged out of a listed layer "
@@ -2099,19 +2407,20 @@ class CausalTransformerLM:
             return WindowedKVCache(
                 full=stack(c.n_layers - n_window, num_pages),
                 ring=stack(n_window, ring))
-        if c.has_ssm:
+        if c.has_state:
+            from deepspeed_tpu.ops.linear_attention import \
+                init_linear_state_cache
             from deepspeed_tpu.ops.ssm import HybridKVCache, init_state_cache
             assert state_slots > 0, \
-                "a state-space model's pools need state_slots"
-            n_ssm = sum(c.ssm_pattern)
-            shape = paged_pool_shape(c.n_layers - n_ssm, num_pages,
-                                     c.kv_heads, page_size, c.head_dim)
+                "a model with a recurrent state needs state_slots"
+            n_state = c.state_layers
             return HybridKVCache(
-                full=PagedKVCache(jnp.zeros(shape, dtype),
-                                  jnp.zeros(shape, dtype)),
+                full=attention_pools(c.n_layers - n_state),
                 ssm=init_state_cache(
-                    n_ssm, state_slots, c.ssm_heads, c.ssm_head_dim,
-                    c.ssm_state, c.ssm_conv - 1, c.ssm_conv_dim, dtype))
+                    n_state, state_slots, c.ssm_heads, c.ssm_head_dim,
+                    c.ssm_state, c.ssm_conv - 1, c.ssm_conv_dim, dtype)
+                if c.has_ssm else init_linear_state_cache(
+                    n_state, state_slots, c.lin_heads, c.lin_head_dim))
         if c.is_latent:
             # one entry a token, [c_kv | k_rope], and the indexer's key:
             # two pools of unlike widths over the same pages
@@ -2122,10 +2431,7 @@ class CausalTransformerLM:
                 c.index_head_dim if c.index_topk else 0, dtype)
         # each stack made in place: a broadcast of one layer's pool and a
         # copy of it held four stacks at once, the process's HBM peak
-        shape = paged_pool_shape(c.n_layers, num_pages, c.kv_heads,
-                                 page_size, c.head_dim)
-        return PagedKVCache(k_pages=jnp.zeros(shape, dtype),
-                            v_pages=jnp.zeros(shape, dtype))
+        return attention_pools(c.n_layers)
 
     def apply_with_paged_cache(self, params, input_ids, caches, block_tables,
                                lengths, *, attn_backend=None,
@@ -2165,7 +2471,12 @@ class CausalTransformerLM:
         slot whose state each sequence starts from (zeros at ``lengths``
         0) and leaves advanced by its ``real_lengths`` rows; without
         ``state_slots`` the dispatch is a decode step, row b is slot b,
-        and a row at ``lengths`` 0 keeps its state (``mix_ssm_paged``).
+        and a row at ``lengths`` 0 keeps its state (``mix_ssm_paged``);
+        a model with linear-attention layers (``config.has_lin``) the
+        same (``mix_lin_paged``).  Block-sparse attention layers
+        (``config.has_sparse``) take each sequence's context at the
+        dispatch from ``lengths`` and ``real_lengths``
+        (``mix_sparse_paged``).
         """
         from deepspeed_tpu.ops.paged_attention import (paged_read_items,
                                                        resolve_paged_impl,
@@ -2190,7 +2501,7 @@ class CausalTransformerLM:
         else:
             # one backend for the write and the read of the pools
             impl = resolve_paged_impl(attn_backend, c.attn_logit_softcap)
-            full = caches.full if c.attn_window or c.has_ssm else caches
+            full = caches.full if c.attn_window or c.has_state else caches
             if c.attn_window:
                 # the table's last columns are each sequence's ring
                 page = full.k_pages.shape[3]
@@ -2218,13 +2529,23 @@ class CausalTransformerLM:
                         items=items, real_lengths=(
                             real_lengths if real_lengths is not None
                             else jnp.full((B,), T, jnp.int32)))
+            read_lengths, sparse = lengths + T, {}
+            if c.has_sparse:
+                # each sequence's context at this dispatch; the pages' own
+                # read serves those under ``sparse.dense_len`` alone
+                context = lengths + (T if real_lengths is None
+                                     else real_lengths)
+                read_lengths = jnp.where(context < c.sparse.dense_len,
+                                         read_lengths, 0)
+                sparse = dict(context=context, read_lengths=read_lengths)
             # the steps of the read that hold keys: once a dispatch, not a
             # layer
             items = paged_read_items((B, T, c.n_heads, c.head_dim), full,
-                                     block_tables, lengths + T, impl)
+                                     block_tables, read_lengths, impl)
             paged = dict(block_tables=block_tables, lengths=lengths,
-                         impl=impl, interpret=attn_interpret, items=items)
-            mixer = self.mix_paged
+                         impl=impl, interpret=attn_interpret, items=items,
+                         **sparse)
+            mixer = self.mix_sparse_paged if c.has_sparse else self.mix_paged
 
         def body(carry, inp, at=None, counts=counts):
             # the stacked pools stay ONE buffer through the layers: carried,
@@ -2233,13 +2554,21 @@ class CausalTransformerLM:
             # its traced place in its kind's stack
             x, pools = carry
             layer, i = inp
-            if not (c.attn_window or c.has_ssm):
-                mix = functools.partial(mixer, index=i, **paged)
-                x, pools, _ = self.block(x, layer, positions, mix, pools,
-                                         train=False, counts=counts)
+            attend = functools.partial(mixer, index=i, **paged)
+            if c.has_sparse:    # its decode step counts what it attended
+                attend = functools.partial(attend, counts=counts)
+            if not (c.attn_window or c.has_state):
+                x, pools, _ = self.block(
+                    x, layer, positions, attend, pools, train=False,
+                    counts=counts,
+                    rotary=True if at is None else c.layer_rotary(at))
                 return (x, pools), None
             kind, window = kind_of(at), c.layer_window(at)
-            if kind == "ssm":
+            if c.layer_lin(at):
+                mix = functools.partial(
+                    self.mix_lin_paged, index=i, lengths=lengths,
+                    real_lengths=real_lengths, slots=state_slots)
+            elif kind == "ssm":
                 mix = functools.partial(
                     self.mix_ssm_paged, index=i, lengths=lengths,
                     real_lengths=real_lengths, slots=state_slots,
@@ -2248,7 +2577,7 @@ class CausalTransformerLM:
                 mix = functools.partial(self.mix_ring, index=i,
                                         **ring_mix[window])
             else:
-                mix = functools.partial(mixer, index=i, **paged)
+                mix = attend
             x, pool, _ = self.block(x, layer, positions, mix,
                                     getattr(pools, kind), train=False,
                                     counts=counts,
@@ -2257,8 +2586,9 @@ class CausalTransformerLM:
 
         def kind_of(i):
             """Which of the dispatch's pools layer ``i`` keeps its cache
-            in: its kind of mixer."""
-            return "ssm" if c.layer_ssm(i) else \
+            in: its kind of mixer (a linear-attention layer's state lies
+            where a state-space layer's would)."""
+            return "ssm" if c.layer_state(i) else \
                 "ring" if c.layer_window(i) else "full"
 
         def place(i):

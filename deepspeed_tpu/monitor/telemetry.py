@@ -249,7 +249,8 @@ OP_PHASES = ("fwd", "bwd", "remat", "loss_head", "optimizer", "grad_reduce",
 # these where an instruction has one, in any program
 SERVE_SCOPES = ("select", "latent_attn", "router", "experts",
                 "shared_expert", "attn_window", "attn_full", "attn_gate",
-                "latent_ctx", "ssm_proj", "ssm_conv", "ssm_scan")
+                "latent_ctx", "ssm_proj", "ssm_conv", "ssm_scan", "lin_attn",
+                "block_select", "ckey_write", "sparse_attn")
 
 _MODEL_SCOPES = frozenset(("embed", "norm", "attn", "mlp"))
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")

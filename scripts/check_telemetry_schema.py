@@ -221,7 +221,8 @@ SERVE_COUNTERS = ("selected", "context_keys", "expert_pairs",
                   "expert_load_max", "expert_rows")
 SERVE_SCOPES = ("select", "latent_attn", "router", "experts",
                 "shared_expert", "attn_window", "attn_full", "attn_gate",
-                "latent_ctx", "ssm_proj", "ssm_conv", "ssm_scan")
+                "latent_ctx", "ssm_proj", "ssm_conv", "ssm_scan", "lin_attn",
+                "block_select", "ckey_write", "sparse_attn")
 # FROZEN: what a model with sliding-window layers adds to the same
 # dispatches and spans, reckoned on the host from the lengths
 # (byte-identical to ``deepspeed_tpu.inference.serving.WINDOW_COUNTS``;
@@ -288,7 +289,8 @@ SERVE_EVENTS = (
     # the once-per-engine record of a model with state-space layers
     # ("serve/state": layers / slot_bytes / dtype / conv_dtype, the
     # recurrent state it keeps a slot beside the pages, and redo, what a
-    # dropped decode row costs: "prefill_from_zero")
+    # dropped decode row costs: "prefill_from_zero"; a model with
+    # linear-attention layers says kind: "linear" in conv_dtype's place)
     "serve/state",
     # per-request lifecycle trace (RequestTracer): one event per state
     # transition, each carrying req_id plus the derived latencies so a

@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
-"""A control by hand for a serving cell of a model with state-space
-layers, beside ``chipbench/control_in_place.py`` (8-bit weights): the
+"""A control by hand for a serving cell of a model with state-space or
+linear-attention layers (``--lose state`` serves both: the matrix state of
+a linear layer is the ``state`` leaf of its cache too), beside ``chipbench/control_in_place.py`` (8-bit weights): the
 cell's own check (``chipbench/serve_cell.py``: three prompts at the mix's
 quantiles, prefill and 24 decoded tokens, against the float32 reference at
 ``LOGIT_TOL``), except that the slots' recurrent STATE (``--lose state``),

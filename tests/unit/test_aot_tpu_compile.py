@@ -867,3 +867,91 @@ def test_bf16_moment_update_is_one_pass_at_the_memory_pace(topo):
     assert cost["flops"] / n < 60
     assert "rng-bit-generator" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+SALA_DISPATCHES = {"decode_b16": (16, 1), "prefill_16384": (1, 16384)}
+SALA_SIZES = dict(pages=2113, slots=16)
+
+
+@pytest.fixture(scope="module")
+def sala_dispatch(topo):
+    """Each of ``SALA_DISPATCHES`` of a model with linear-attention layers
+    and block-sparse attention (the ``minicpm-sala`` configuration's eight
+    layers at their widths, the cell's pools, a vocabulary of 1,024), as
+    the engine dispatches it: both are told their real rows, a prefill its
+    slot too."""
+    import functools
+    import json
+    import os
+
+    from chipbench.families import minicpm_sala
+    with open(os.path.join(os.path.dirname(minicpm_sala.__file__), "..",
+                           "configs", "minicpm-sala.json")) as f:
+        cfg = json.load(f)
+    cfg.update(vocab_size=1024)
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = CausalTransformerLM(TransformerConfig(
+        **minicpm_sala.transformer_kwargs(cfg)))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _on(chip, x.shape, x.dtype), tree)
+
+    def ints(*shape):
+        return _on(chip, shape, jnp.int32)
+
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.bfloat16)))
+    caches = on_chip(jax.eval_shape(lambda: model.init_paged_caches(
+        SALA_SIZES["pages"], 128, state_slots=SALA_SIZES["slots"])))
+
+    def serve(params, ids, caches, tables, lengths, *told):
+        names = ("head_rows", "real_lengths", "state_slots") \
+            if len(told) == 3 else ("real_lengths",)
+        return model.apply_with_paged_cache(
+            params, ids, caches, tables, lengths, attn_backend="pallas",
+            **dict(zip(names, told)))
+
+    @functools.lru_cache(maxsize=None)
+    def compiled(name):
+        batch, tokens = SALA_DISPATCHES[name]
+        return jax.jit(serve, donate_argnums=(2,)).lower(
+            params, ints(batch, tokens), caches, ints(batch, 133),
+            ints(batch), *([ints(batch, 1), ints(batch), ints(batch)]
+                           if tokens > 1 else [ints(batch)])).compile()
+
+    return compiled
+
+
+@pytest.mark.parametrize("name", SALA_DISPATCHES)
+def test_sala_dispatch_copies_neither_the_pools_nor_the_state(
+        sala_dispatch, name):
+    """PR 29's rule for PR 56's pools: no dispatch re-lays or copies the
+    sparse layers' K/V stack (a decode step's gather of a compressed
+    key's 32 keys by single rows did: 277 MB a layer and step, until they
+    were read as two aligned runs of 16), the compressed keys (written and
+    read by whole row index: as [.., Hkv, page / 16, D] the scatter and the
+    gather each re-laid the pool their own way) or the linear layers'
+    matrix state (float32, a row a slot: updated in place by the layer
+    loop's fusions)."""
+    compiled = sala_dispatch(name)
+    tokens = SALA_DISPATCHES[name][1]
+    pages, slots = SALA_SIZES["pages"], SALA_SIZES["slots"]
+    text = compiled.as_text()
+    kv = {op for _, op in _pool_shaped(text, None, [
+        f"bf16[2,{pages},2,128,128]"])}
+    assert kv and kv <= IN_PLACE_OPS, kv
+    compressed = {op for _, op in _pool_shaped(text, None, [
+        f"bf16[2,{pages},16,128]"])}
+    assert compressed and "copy" not in compressed, compressed
+    state = {op for _, op in _pool_shaped(text, None, [
+        f"f32[6,{slots},32,128,128]"])}
+    assert state and state <= IN_PLACE_OPS | {
+        "fusion", "dynamic-update-slice"}, state
+    assert "paged_kv_write" in text
+    assert ("ragged_paged_attention_decode" if tokens == 1
+            else "ragged_paged_attention_prefill") in text
+    # a decode step holds no more than a few blocks' gather beside its
+    # operands; the 16k bucket two SwiGLU halves and a step of the mask
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        64 << 20 if tokens == 1 else 3 << 30)
